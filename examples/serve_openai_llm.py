@@ -1,6 +1,8 @@
 """OpenAI-compatible LLM serving.
 
 Run: python examples/serve_openai_llm.py
+As written the replica runs on the CPU: a replica only gets a chip if the
+deployment asks, e.g. LLMConfig(..., ray_actor_options={"num_tpus": 1}).
 Then: curl -s localhost:8000/v1/chat/completions -d \
   '{"model":"tiny","messages":[{"role":"user","content":"hi"}],"max_tokens":16}'
 """

@@ -2,8 +2,10 @@
 
 Run (CPU virtual mesh): JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     python examples/train_llama_dp.py
-On a TPU host the same script uses the local chips; multi-host pods get one
-trainer worker per host (ScalingConfig(num_workers=<hosts>, use_tpu=True)).
+As written the worker runs on the CPU: a worker only gets chips if the trainer
+asks, e.g. ScalingConfig(num_workers=1, use_tpu=True,
+resources_per_worker={"TPU": 1}) (use_tpu alone asks for a four-chip host);
+multi-host pods get one trainer worker per host (num_workers=<hosts>).
 """
 
 import numpy as np
@@ -47,7 +49,7 @@ if __name__ == "__main__":
     result = JaxTrainer(
         train_loop,
         train_loop_config={"steps": 20, "batch": 8, "seq_len": 64},
-        scaling_config=ScalingConfig(num_workers=1),
+        scaling_config=ScalingConfig(num_workers=1),  # CPU; see the docstring
         run_config=RunConfig(name="llama-dp-example"),
     ).fit()
     print("final:", result.metrics, "checkpoint:", result.checkpoint)

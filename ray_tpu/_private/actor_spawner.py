@@ -278,7 +278,7 @@ class ActorSpawner:
                 )
                 self._forget(st)
             return
-        pool_fp = (lease.needs_tpu, tuple(sorted(lease.env_vars.items())))
+        pool_fp = (lease.tpu_chips, tuple(sorted(lease.env_vars.items())))
         wid = None
         if self._poolable(lease):
             # pool pop: an idle compatible task worker becomes the actor's
@@ -299,7 +299,7 @@ class ActorSpawner:
                 P.SpawnWorker(
                     wid,
                     dict(lease.env_vars),
-                    lease.needs_tpu,
+                    lease.tpu_chips,
                     lease.fingerprint,
                     lease.packages,
                 )
@@ -513,7 +513,7 @@ class ActorSpawner:
             self._kill_worker(st.worker_id)
             return
         fp = (
-            st.lease.needs_tpu,
+            st.lease.tpu_chips,
             tuple(sorted(st.lease.env_vars.items())),
         )
         self._agent.adopt_idle_worker(st.worker_id, fp)

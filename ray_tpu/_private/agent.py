@@ -47,6 +47,7 @@ from typing import Any, Optional
 from ray_tpu._private import locktrace
 from ray_tpu._private import protocol as P
 from ray_tpu._private.ids import NodeID, ObjectID, WorkerID
+from ray_tpu.tpu.accelerator import ChipPool, chip_worker_env, chips_requested
 
 logger = logging.getLogger("ray_tpu.agent")
 
@@ -73,6 +74,7 @@ class NodeAgent:
         self.authkey = authkey
         self.head_address = address
         self.resources = dict(resources or {"CPU": float(os.cpu_count() or 1)})
+        self._chips = ChipPool(chips_requested(self.resources))
         self.labels = dict(labels or {})
         self.base_dir = base_dir or os.path.join(
             os.environ.get("TMPDIR", "/tmp"), f"rtpu-agent-{os.getpid()}"
@@ -975,7 +977,7 @@ class NodeAgent:
 
     @staticmethod
     def _lease_fp(lease: P.LeaseTask) -> tuple:
-        return (lease.needs_tpu, tuple(sorted(lease.env_vars.items())))
+        return (lease.tpu_chips, tuple(sorted(lease.env_vars.items())))
 
     def _trace_gate(self, spec) -> bool:
         """Record agent-plane spans for this lease? Same deterministic
@@ -1079,7 +1081,7 @@ class NodeAgent:
                 target=self._spawn_worker,
                 args=(
                     P.SpawnWorker(
-                        wid, dict(lease.env_vars), lease.needs_tpu, fp, packages=[]
+                        wid, dict(lease.env_vars), lease.tpu_chips, fp, packages=[]
                     ),
                 ),
                 daemon=True,
@@ -1106,6 +1108,29 @@ class NodeAgent:
             if idle and wid in idle:
                 idle.remove(wid)
         self._busy.pop(wid, None)
+
+    def _evict_idle_chip_workers(self):
+        """Retire this agent's idle pool workers that were spawned for a TPU
+        grant: they keep the device library loaded, and the chips are about
+        to go to another process."""
+        with self._lease_lock:
+            wids = [
+                wid
+                for fp, idle in self._fp_idle.items()
+                if fp[0]
+                for wid in idle
+            ]
+            for wid in wids:
+                self._retire_local_worker(wid)
+                self._agent_owned.pop(wid, None)
+        for wid in wids:
+            with self.workers_lock:
+                w = self.workers.get(wid)
+            if w is not None and w.get("proc") is not None:
+                try:
+                    w["proc"].terminate()
+                except OSError:
+                    pass
 
     def pop_idle_worker(self, fp: tuple) -> Optional[WorkerID]:
         """Dedicate an idle agent-owned pool worker to an actor (the
@@ -1397,7 +1422,7 @@ class NodeAgent:
                 paths.append(root)
         existing = env.get("PYTHONPATH", "")
         env["PYTHONPATH"] = os.pathsep.join(paths + ([existing] if existing else []))
-        if not msg.needs_tpu:
+        if not msg.tpu_chips:
             env.setdefault("JAX_PLATFORMS", "cpu")
         env.update({k: str(v) for k, v in msg.env_vars.items()})
         # runtime_env pip: build (or reuse) the offline venv against the
@@ -1431,6 +1456,15 @@ class NodeAgent:
                     P.WorkerDied(msg.worker_id, f"pip env failed: {e}")
                 )
                 return f"pip env failed: {e}"
+        argv = [
+            python_exe,
+            "-m",
+            "ray_tpu._private.worker_main",
+            self.worker_sock,
+            msg.worker_id.hex(),
+        ]
+        if msg.tpu_chips:
+            argv.append(str(msg.tpu_chips))
         # per-worker log capture (tailed to the head by the log monitor)
         env["PYTHONUNBUFFERED"] = "1"
         out_path = os.path.join(self.log_dir, f"worker-{msg.worker_id.hex()}.out")
@@ -1443,21 +1477,25 @@ class NodeAgent:
             if stdout is not None:
                 stdout.close()
             stdout = stderr = None
+        chips: list[int] = []
+        proc = None
         try:
+            if msg.tpu_chips:
+                # a worker sees exactly the chips it was granted, taken only
+                # once their previous holder has exited (mirror of the
+                # head's spawn); RuntimeError: they are still held
+                chips = self._chips.acquire(
+                    msg.tpu_chips,
+                    self._register_timeout_s,
+                    evict=self._evict_idle_chip_workers,
+                )
+                env.update(
+                    chip_worker_env(chips, self._chips.n_chips, msg.env_vars)
+                )
             proc = subprocess.Popen(
-                [
-                    python_exe,
-                    "-m",
-                    "ray_tpu._private.worker_main",
-                    self.worker_sock,
-                    msg.worker_id.hex(),
-                ],
-                env=env,
-                cwd=cwd,
-                stdout=stdout,
-                stderr=stderr,
+                argv, env=env, cwd=cwd, stdout=stdout, stderr=stderr
             )
-        except OSError as e:
+        except (OSError, RuntimeError) as e:
             self._on_local_worker_death(msg.worker_id)
             self._send(P.WorkerDied(msg.worker_id, f"spawn failed: {e}"))
             return f"spawn failed: {e}"
@@ -1465,6 +1503,7 @@ class NodeAgent:
             for fh in (stdout, stderr):
                 if fh is not None:
                     fh.close()
+            self._chips.bind(chips, proc)
         with self.workers_lock:
             self.workers[msg.worker_id] = {
                 "conn": None,
@@ -2109,6 +2148,7 @@ class NodeAgent:
                     proc.terminate()
                 except OSError:
                     pass
+        self._chips.drain()  # the chips are free when the agent is gone
         for listener in (self._worker_listener, self._data_listener):
             try:
                 listener.close()

@@ -49,6 +49,7 @@ from ray_tpu._private.ids import (
 from ray_tpu._private.object_store import MemoryStore, PlasmaClient, PlasmaStore
 from ray_tpu._private.serialization import SerializationContext, SerializedObject
 from ray_tpu._private.task_spec import TaskSpec, TaskType
+from ray_tpu.tpu.accelerator import ChipPool, chip_worker_env, chips_requested
 from ray_tpu.exceptions import (
     ActorDiedError,
     ObjectLostError,
@@ -129,6 +130,10 @@ class NodeState:
         # will free a worker shortly — growing it would spawn-storm).
         self.task_workers = 0
         self.starting_workers = 0
+        # chips spoken for by worker spawns still in flight here: a spawn
+        # for a TPU grant may wait for the previous holder's exit, and a
+        # second speculative one would only wait behind it
+        self.starting_chips = 0
         self.last_task_done_t = 0.0
         # Normal tasks leased to this node's agent for LOCAL dispatch
         # (two-level scheduling): task_id binary -> PendingTask. The head
@@ -349,6 +354,9 @@ class Controller:
         # half, a driver's init(config=...) knobs silently reset to
         # defaults inside agent-spawned workers (the PR 13 noted tail).
         self._child_env_overrides = config.override_env()
+        # the chips of THIS host, for workers this process spawns (agents
+        # keep their own): one process for each chip at a time
+        self._chips = ChipPool(chips_requested(head_resources))
         # Core scheduler/cluster-state lock. Registered as a SUBSYSTEM lock:
         # the sharded dispatch tables give some subsystems (KV) their own
         # lock, and locktrace asserts at runtime that no thread ever holds
@@ -4442,7 +4450,7 @@ class Controller:
             P.LeaseTask(
                 spec,
                 resolved_args,
-                bool(spec.resources.get("TPU")),
+                chips_requested(spec.resources),
                 lease_env,
             ),
         )
@@ -4514,7 +4522,7 @@ class Controller:
             P.LeaseActor(
                 spec,
                 resolved_args,
-                bool(spec.resources.get("TPU")),
+                chips_requested(spec.resources),
                 env_vars,
                 self._env_fingerprint(spec),
                 packages,
@@ -5081,14 +5089,15 @@ class Controller:
     @staticmethod
     def _env_fingerprint(spec: TaskSpec):
         """Workers are only reusable by tasks with the same environment needs
-        (TPU visibility is baked in at spawn; runtime_env vars likewise)."""
+        (the chips a worker sees are baked in at spawn; runtime_env vars
+        likewise)."""
         from ray_tpu._private.runtime_env_pip import normalize_pip_spec
 
         rt = spec.runtime_env or {}
         env_vars = rt.get("env_vars") or {}
         pip_spec = normalize_pip_spec(rt)
         return (
-            bool(spec.resources.get("TPU")),
+            chips_requested(spec.resources),
             tuple(sorted(env_vars.items())),
             rt.get("working_dir"),
             tuple(str(m) for m in (rt.get("py_modules") or ())),
@@ -5146,6 +5155,17 @@ class Controller:
                         break
                 if not evicted:
                     return None
+        if want[0]:
+            if node.starting_chips + want[0] > node.total.get("TPU", 0):
+                return None  # the spawns in flight already cover the chips
+            # One process for each chip: an idle worker that was spawned for
+            # a TPU grant keeps the device library loaded. It exits before
+            # this node's chips go to another process (the new worker's
+            # spawn waits in ChipPool.acquire until it has).
+            for i in range(len(idle) - 1, -1, -1):
+                if idle[i].fingerprint[0] and not idle[i].dead:
+                    self._kill_pooled_worker(idle.pop(i))
+            node.starting_chips += want[0]
         self.starting_workers += 1
         node.starting_workers += 1
         # Pinned by tests: agent-node actors NEVER take a head-side spawn
@@ -5209,6 +5229,7 @@ class Controller:
                 node = self.nodes.get(node_id)
                 if node is not None and node.starting_workers > 0:
                     node.starting_workers -= 1
+                    node.starting_chips -= chips_requested(spec_hint.resources)
                 if ok and not worker.dead:
                     # registered-then-died race: _on_worker_death may have run
                     # already (worker.dead set under this lock) — don't count
@@ -5220,6 +5241,9 @@ class Controller:
                 elif not ok:
                     worker.dead = True
                     logger.error("worker failed to register in time")
+                    if worker.proc is not None:
+                        # it may hold chips, and they only come back at exit
+                        worker.proc.terminate()
                 self.sched_cv.notify_all()
         except Exception as e:
             with self.lock:
@@ -5227,6 +5251,7 @@ class Controller:
                 node = self.nodes.get(node_id)
                 if node is not None and node.starting_workers > 0:
                     node.starting_workers -= 1
+                    node.starting_chips -= chips_requested(spec_hint.resources)
             logger.error("worker spawn failed:\n%s", traceback.format_exc())
             from ray_tpu.exceptions import RuntimeEnvSetupError
 
@@ -5267,7 +5292,8 @@ class Controller:
         )
         # Accelerator visibility: workers only see the TPU if their tasks ask
         # for it (reference: accelerators/tpu.py TPU_VISIBLE_CHIPS).
-        if not spec_hint.resources.get("TPU"):
+        tpu_chips = chips_requested(spec_hint.resources)
+        if not tpu_chips:
             env.setdefault("JAX_PLATFORMS", "cpu")
         # Data-plane visibility: the worker attaches ONLY its node's arena;
         # objects on other nodes come through the chunked pull protocol.
@@ -5314,25 +5340,35 @@ class Controller:
 
         pip_spec = normalize_pip_spec(spec_hint.runtime_env or {})
         python_exe = ensure_pip_env(pip_spec) if pip_spec else sys.executable
+        argv = [python_exe, "-m", "ray_tpu._private.worker_main", self.address, worker_id.hex()]
+        # A worker spawned for a TPU grant sees exactly the chips it was
+        # granted, taken only once their previous holder has exited.
+        chips: list[int] = []
+        if tpu_chips:
+            argv.append(str(tpu_chips))
+            chips = self._chips.acquire(
+                tpu_chips, self.config.worker_register_timeout_s
+            )
+            env.update(chip_worker_env(chips, self._chips.n_chips, env_overrides))
         # capture stdout/stderr to per-worker session files; a `print`
         # inside a task streams to the driver via the log monitor and stays
         # fetchable after the worker dies (reference: log_monitor.py)
-        stdout = stderr = None
-        log_paths = self._worker_log_paths(worker_id)
-        if log_paths is not None:
-            env["PYTHONUNBUFFERED"] = "1"  # lines must reach the file promptly
-            try:
-                stdout = open(log_paths[0], "ab", buffering=0)
-                stderr = open(log_paths[1], "ab", buffering=0)
-            except OSError:
-                # degrade to no-capture (deleted session dir, fd limit) —
-                # the worker must still spawn
-                if stdout is not None:
-                    stdout.close()
-                stdout = stderr = None
+        stdout = stderr = proc = None
         try:
+            log_paths = self._worker_log_paths(worker_id)
+            if log_paths is not None:
+                env["PYTHONUNBUFFERED"] = "1"  # lines must reach the file promptly
+                try:
+                    stdout = open(log_paths[0], "ab", buffering=0)
+                    stderr = open(log_paths[1], "ab", buffering=0)
+                except OSError:
+                    # degrade to no-capture (deleted session dir, fd limit) —
+                    # the worker must still spawn
+                    if stdout is not None:
+                        stdout.close()
+                    stdout = stderr = None
             proc = subprocess.Popen(
-                [python_exe, "-m", "ray_tpu._private.worker_main", self.address, worker_id.hex()],
+                argv,
                 env=env,
                 cwd=working_dir or None,
                 stdout=stdout,
@@ -5343,6 +5379,7 @@ class Controller:
             for fh in (stdout, stderr):
                 if fh is not None:
                     fh.close()
+            self._chips.bind(chips, proc)  # proc None: the chips come back
         self._register_log_meta(worker_id, pid=proc.pid, label=None)
         handle = WorkerHandle(worker_id, node_id, proc=proc)
         handle.fingerprint = self._env_fingerprint(spec_hint)
@@ -5379,7 +5416,7 @@ class Controller:
             P.SpawnWorker(
                 worker_id,
                 env_vars,
-                bool(spec_hint.resources.get("TPU")),
+                chips_requested(spec_hint.resources),
                 handle.fingerprint,
                 packages,
             )
@@ -7112,7 +7149,10 @@ class Controller:
                 if actor is not None:
                     if failed:
                         actor.state = "DEAD"
-                        actor.death_cause = "creation task failed"
+                        actor.death_cause = (
+                            "creation task failed"
+                            + self._creation_error_text(msg.results)
+                        )
                         self._journal("actor_dead", actor.actor_id.binary())
                         self.publish("actors", {"actor_id": actor.actor_id.hex(), "state": "DEAD", "reason": "creation task failed"})
                         self._drain_actor_queue(actor)
@@ -7147,6 +7187,19 @@ class Controller:
                 self._maybe_end_lease_and_idle(worker)
             self.sched_cv.notify_all()
         self._persist_state()
+
+    def _creation_error_text(self, results) -> str:
+        """': <the exception __init__ raised>' for an actor's death cause, so
+        that callers of a dead actor read why (a TPU grant without the chip,
+        say) and not only that it died."""
+        try:
+            payload = next(p for _, kind, p in results if kind == "error")
+            err = self.serialization.deserialize(
+                SerializedObject.from_buffer(payload)
+            )
+            return f": {getattr(err, 'cause', err)!r}"
+        except Exception:  # noqa: BLE001 — the cause is best-effort detail
+            return ""
 
     def _retry_failed_task(self, worker: WorkerHandle, pt: PendingTask, msg: P.TaskDone):
         spec = pt.spec
@@ -7871,6 +7924,9 @@ class Controller:
                     w.proc.wait(timeout=max(0.05, deadline - time.monotonic()))
                 except Exception:
                     w.proc.kill()
+        # chip holders, the already-killed ones included, are waited out:
+        # the chips must be free for whatever this host runs next
+        self._chips.drain()
         if self.listener is not None:
             try:
                 self.listener.close()

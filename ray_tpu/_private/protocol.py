@@ -833,7 +833,7 @@ class SpawnWorker:
 
     worker_id: WorkerID
     env_vars: dict
-    needs_tpu: bool
+    tpu_chips: int
     fingerprint: tuple
     # runtime-env payloads shipped by value: [(kind, name, zip_bytes)] where
     # kind in {"working_dir", "py_module"} (reference: working_dir packaging
@@ -859,7 +859,7 @@ class LeaseTask:
 
     spec: Any  # TaskSpec
     resolved_args: list
-    needs_tpu: bool
+    tpu_chips: int
     env_vars: dict
 
 
@@ -876,7 +876,7 @@ class LeaseActor:
 
     spec: Any  # TaskSpec (ACTOR_CREATION_TASK)
     resolved_args: list
-    needs_tpu: bool
+    tpu_chips: int
     env_vars: dict
     fingerprint: tuple
     # runtime-env payloads shipped by value, same shape as SpawnWorker's

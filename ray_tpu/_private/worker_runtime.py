@@ -373,6 +373,9 @@ class WorkerRuntime:
         self.conn = conn
         self.in_process = in_process
         self.authkey = authkey
+        # set by worker_main when the process cannot provide what it was
+        # spawned for; _invoke raises it for every task and actor creation
+        self.startup_error: Optional[BaseException] = None
         # direct actor-call listener (started in run() for process workers)
         self._direct_listener = None
         self.direct_address: Optional[str] = None
@@ -1795,6 +1798,10 @@ class WorkerRuntime:
         self._send(P.TaskDone(spec.task_id, results, actor_id=spec.actor_id, exec_ms=exec_ms))
 
     def _invoke(self, spec: TaskSpec, args, kwargs):
+        if self.startup_error is not None and spec.task_type != TaskType.ACTOR_TASK:
+            # this process could not provide what it was spawned for (a TPU
+            # grant without the chips): nothing new may start on it
+            raise self.startup_error
         self.current_task_name = spec.name
         # nested submits from this task inherit its tenant + priority
         _exec_ctx.tenant = getattr(spec, "tenant", None)

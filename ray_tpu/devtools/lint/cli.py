@@ -187,6 +187,23 @@ def main(argv=None) -> int:
         )
         return 2
 
+    if args.write_baseline and (
+        args.changed_only or (args.paths and args.baseline is None)
+    ):
+        # baseline.write rebuilds the file from THIS run's findings: a
+        # slice would silently delete every out-of-slice entry from the
+        # shared full-tree baseline (reviewed reasons included). Refused
+        # before the slice is computed: a clean tree has no changed files
+        # and would otherwise exit 0 here.
+        print(
+            "tpulint: --write-baseline requires a full-tree run "
+            "(a slice would truncate the shared baseline); drop "
+            "--changed-only/path args, or pass an explicit --baseline "
+            "file for a standalone slice baseline",
+            file=sys.stderr,
+        )
+        return 2
+
     changed_slice = False
     if args.changed_only:
         changed = _changed_files(cfg_root)
@@ -267,18 +284,6 @@ def main(argv=None) -> int:
     if args.write_baseline:
         if not baseline_path:
             print("tpulint: --write-baseline needs a baseline path", file=sys.stderr)
-            return 2
-        if changed_slice or (args.paths and args.baseline is None):
-            # baseline.write rebuilds the file from THIS run's findings: a
-            # slice would silently delete every out-of-slice entry from the
-            # shared full-tree baseline (reviewed reasons included)
-            print(
-                "tpulint: --write-baseline requires a full-tree run "
-                "(a slice would truncate the shared baseline); drop "
-                "--changed-only/path args, or pass an explicit --baseline "
-                "file for a standalone slice baseline",
-                file=sys.stderr,
-            )
             return 2
         baseline_mod.write(baseline_path, findings, old=base)
         print(

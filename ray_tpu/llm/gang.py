@@ -88,12 +88,7 @@ class EngineWorker:
                 # computations aren't implemented on the CPU backend");
                 # the gloo collectives backend can. Must be set before the
                 # backend initializes. TPU/GPU worlds are unaffected.
-                try:
-                    jax.config.update(
-                        "jax_cpu_collectives_implementation", "gloo"
-                    )
-                except Exception:  # noqa: BLE001 — older jaxlib: no option
-                    pass
+                jax.config.update("jax_cpu_collectives_implementation", "gloo")
             # must precede this process's first backend use; afterwards
             # jax.devices() is the GLOBAL device set across the gang
             jax.distributed.initialize(

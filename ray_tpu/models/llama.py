@@ -45,7 +45,10 @@ class LlamaConfig:
     rope_theta: float = 10000.0
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
-    # 'full' | 'ring' | 'ulysses' — ring/ulysses engage when the mesh has sp>1
+    # 'full' | 'ring' | 'ulysses' | 'splash' | 'flash'. ring/ulysses engage
+    # when the mesh has sp>1; splash/flash are Pallas TPU kernels with no
+    # partitioning rule: they need a tpu backend and an unsharded program,
+    # and raise anywhere else
     attention: str = "full"
     # route rmsnorm through the fused Pallas kernel (ray_tpu.ops.rmsnorm).
     # Opt-in: pallas_call has no partitioning rule, so under a sharded pjit
@@ -255,12 +258,23 @@ def init_params(key, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
     with its NamedSharding (no host-side full copy — jit init per leaf)."""
     shapes = _param_shapes(cfg)
     keys = jax.random.split(key, len(shapes))
+    # the contraction each attention projection takes part in: its weight
+    # is kept [.., e, h, hd] / [.., h, hd, e], so shape[-2] is not it. (Read
+    # as shape[-2], q/k/v came out 11-20x too large at Llama-3.2-3B widths,
+    # the softmax saturated, and the gradient norm grew ~140x every two
+    # layers: 158 at 2 layers, 8.7e7 at 8, on the chip.)
+    attn_fan_in = {
+        "wq": cfg.d_model, "wk": cfg.d_model, "wv": cfg.d_model,
+        "wo": cfg.n_heads * cfg.head_dim,
+    }
     params = {}
     for (name, shape), k in zip(sorted(shapes.items()), keys):
         if "norm" in name:
             maker = lambda shape=shape: jnp.ones(shape, cfg.dtype)
         else:
-            fan_in = shape[-2] if len(shape) > 1 else shape[0]
+            fan_in = attn_fan_in.get(
+                name, shape[-2] if len(shape) > 1 else shape[0]
+            )
             std = fan_in**-0.5
             maker = lambda k=k, shape=shape, std=std: (
                 jax.random.normal(k, shape, jnp.float32) * std
@@ -308,13 +322,22 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh: Optional[Mesh]):
         if mesh is not None and "sp" in mesh.axis_names
         else 1
     )
-    on_tpu = jax.default_backend() == "tpu"
-    # pallas kernels have no SPMD partitioning rule: only use them when the
-    # program isn't sharded over >1 device (single-chip or per-replica)
-    unsharded = mesh is None or all(s == 1 for s in mesh.shape.values())
-    needs_repeat = (
-        (sp > 1 and cfg.attention == "ulysses" and cfg.n_kv_heads % sp != 0)
-        or (cfg.attention in ("flash", "splash") and on_tpu and unsharded)
+    kernel = cfg.attention in ("flash", "splash")
+    if kernel:
+        # pallas kernels are TPU-only and have no SPMD partitioning rule
+        # (single-chip or per-replica programs only). Dense attention in
+        # their place would be a different program under the same name.
+        backend = jax.default_backend()
+        unsharded = mesh is None or all(s == 1 for s in mesh.shape.values())
+        if backend != "tpu" or not unsharded:
+            raise ValueError(
+                f"attention={cfg.attention!r} needs a tpu backend and an "
+                f"unsharded program (backend {backend!r}, mesh "
+                f"{dict(mesh.shape) if mesh is not None else None}); "
+                "use attention='full'"
+            )
+    needs_repeat = kernel or (
+        sp > 1 and cfg.attention == "ulysses" and cfg.n_kv_heads % sp != 0
     )
     groups = cfg.n_heads // cfg.n_kv_heads
     if needs_repeat and groups > 1:
@@ -324,9 +347,9 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh: Optional[Mesh]):
         return ring_attention(q, k, v, mesh, causal=True)
     if sp > 1 and cfg.attention == "ulysses":
         return ulysses_attention(q, k, v, mesh, causal=True)
-    if cfg.attention == "splash" and on_tpu and unsharded:
+    if cfg.attention == "splash":
         return _splash_attention(q, k, v)
-    if cfg.attention == "flash" and on_tpu and unsharded:
+    if cfg.attention == "flash":
         return _flash_attention(q, k, v)
     return dense_attention(q, k, v, causal=True)
 
@@ -365,8 +388,7 @@ def _splash_attention(q, k, v):
 def _flash_attention(q, k, v):
     """Pallas TPU flash attention: blockwise softmax in VMEM, never
     materializing the [B, H, S, S] score matrix in HBM — the single biggest
-    HBM-bandwidth lever for long sequences. CPU/virtual-mesh runs fall back
-    to the reference implementation (the kernel is TPU-only)."""
+    HBM-bandwidth lever for long sequences (the kernel is TPU-only)."""
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         flash_attention as _pallas_flash,
     )

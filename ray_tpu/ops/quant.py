@@ -39,7 +39,7 @@ def quantize_int8(x) -> tuple:
     """[rows, cols] float -> (int8 values, fp32 per-row scales [rows])."""
     x, orig_rows = pad_rows(x)
     rows, cols = x.shape
-    block = pick_block(rows)
+    block = pick_block(rows, cols)
     q, s = pl.pallas_call(
         _quant_kernel,
         out_shape=(
@@ -62,7 +62,7 @@ def dequantize_int8(q, scales, dtype=jnp.bfloat16):
     q, _ = pad_rows(q)
     scales, _ = pad_rows(scales)
     rows, cols = q.shape
-    block = pick_block(rows)
+    block = pick_block(rows, cols)
     out = pl.pallas_call(
         functools.partial(_dequant_kernel, out_dtype=dtype),
         out_shape=jax.ShapeDtypeStruct((rows, cols), dtype),

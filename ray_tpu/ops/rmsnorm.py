@@ -34,7 +34,7 @@ def _rmsnorm_fwd_2d(x2, w, eps):
         return x2
     x2, orig_rows = pad_rows(x2)
     rows, d = x2.shape
-    block = pick_block(rows)
+    block = pick_block(rows, d)
     # all refs 2-D: 1-D operands hit XLA/Mosaic layout mismatches on TPU
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),

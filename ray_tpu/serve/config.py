@@ -64,6 +64,19 @@ class DeploymentConfig:
     initial_health_grace_s: Optional[float] = None
     user_config: Optional[Any] = None
 
+    def __post_init__(self):
+        # what reaches the replica's actor; concurrency, name and restarts
+        # are the controller's. Anything else is refused rather than dropped:
+        # a dropped option is a replica on the wrong device.
+        unknown = set(self.ray_actor_options or ()) - {
+            "num_cpus", "num_tpus", "resources"
+        }
+        if unknown:
+            raise ValueError(
+                f"ray_actor_options keys {sorted(unknown)} are not supported "
+                "for serve replicas; use num_cpus, num_tpus or resources"
+            )
+
     def initial_replicas(self) -> int:
         if self.autoscaling_config:
             return self.autoscaling_config.min_replicas

@@ -9,9 +9,9 @@ cluster, named ``serve-controller``; a background reconcile loop:
   replica metrics  ->  autoscaling decisions between min/max
 
 TPU delta: a replica can be gang-scheduled on a pod slice via
-``ray_actor_options={"resources": {"TPU": n}}`` — the scheduler's
-slice-aware placement does the rest; multi-host replicas come from the LLM
-layer building a placement group per engine replica.
+``ray_actor_options={"num_tpus": n}`` (or ``{"resources": {"TPU": n}}``) —
+the scheduler's slice-aware placement does the rest; multi-host replicas
+come from the LLM layer building a placement group per engine replica.
 """
 
 from __future__ import annotations
@@ -395,19 +395,13 @@ class ServeControllerActor:
         from ray_tpu.serve.replica import ReplicaActor
 
         cls = ray_tpu.remote(ReplicaActor)
-        # only num_cpus and resources are honored; max_concurrency/name/
-        # max_restarts are controller-owned and user values would be ignored
-        dropped = [k for k in opts if k != "num_cpus"]
-        if dropped:
-            logger.warning(
-                "ray_actor_options keys %s are not honored for serve replicas "
-                "(controller owns concurrency/name/restarts); dropped for %s",
-                dropped, replica_name,
-            )
+        # the keys DeploymentConfig admits; concurrency, name and restarts
+        # are the controller's
         try:
             h = cls.options(
                 name=replica_name,
                 num_cpus=opts.get("num_cpus", 1),
+                num_tpus=opts.get("num_tpus"),
                 resources=resources,
                 # +2 headroom so control-plane calls (check_health,
                 # get_metrics, reconfigure) can't starve behind a saturated

@@ -29,6 +29,31 @@ TPU_GENERATIONS: dict[str, tuple[int, int, int]] = {
     "v6e": (8, 1, 2),
 }
 
+# Published peaks of ONE chip, keyed by ``jax.devices()[0].device_kind``. The
+# one table every utilization or roofline number divides by; a kind that is
+# not here is an error, never a default.
+CHIP_PEAKS: dict[str, dict] = {
+    "TPU v5 lite": {
+        "bf16_flops_per_s": 197e12,
+        "int8_ops_per_s": 393e12,
+        "hbm_bytes": 16e9,
+        "hbm_bytes_per_s": 819e9,
+        "source": 'Google Cloud documentation, "TPU v5e" (system architecture)',
+    },
+}
+
+
+def chip_peaks(device_kind: str) -> dict:
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks recorded for device_kind {device_kind!r}; "
+            f"add it to ray_tpu.tpu.topology.CHIP_PEAKS with its source "
+            f"(known: {sorted(CHIP_PEAKS)})"
+        ) from None
+
+
 _ACCEL_TYPE_RE = re.compile(r"^(v\d+[a-z]*|v5litepod)-(\d+)$")
 
 

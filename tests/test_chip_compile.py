@@ -1,0 +1,131 @@
+"""The main path's kernels, compiled at real widths for a described v5e.
+
+No chip is attached here: the TPU compiler that ships with jaxlib compiles
+for a ``v5e:2x2`` topology that is only described, and refuses what the chip
+would refuse (block shapes the tiling cannot take, more scoped VMEM than a
+kernel may use). Nothing runs, so these tests say nothing about results or
+speed; interpret-mode correctness lives in ``tests/test_ops.py``.
+
+The only file of its kind: the process that describes the topology loads
+libtpu and keeps it, so a second such file could land on another xdist
+worker and skip. The topology is described inside a fixture, never at
+import, in a ``skipif`` or in ``parametrize``.
+"""
+
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip (the next one warns)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def native_kernels(monkeypatch):
+    """``interpret()`` asks ``jax.default_backend()``, which is the CPU here:
+    steer the kernel modules to lower for the chip. ``ray_tpu.ops`` rebinds
+    the name ``rmsnorm`` to the function, so the module comes from
+    ``sys.modules``."""
+    import ray_tpu.ops  # noqa: F401 — loads the kernel modules
+
+    for name in ("ray_tpu.ops.rmsnorm", "ray_tpu.ops.quant"):
+        monkeypatch.setattr(sys.modules[name], "interpret", lambda: False)
+
+
+def _compiled_text(fn, one_chip, *shapes):
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in shapes
+    ]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+QKV = ((4, 2048, 24, 128), jnp.bfloat16)
+
+
+def _splash_fwd(q, k, v):
+    from ray_tpu.models.llama import _splash_attention
+
+    return _splash_attention(q, k, v)
+
+
+def _splash_bwd(q, k, v):
+    return jax.grad(
+        lambda *a: _splash_fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+    )(q, k, v)
+
+
+def _rmsnorm_fwd(x, w):
+    from ray_tpu.ops import rmsnorm
+
+    return rmsnorm(x, w)
+
+
+def _rmsnorm_grad(x, w):
+    # the backward is plain jnp and recomputes from x: the value keeps the
+    # forward kernel in the program, as a train step's loss does
+    return jax.value_and_grad(
+        lambda *a: _rmsnorm_fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1)
+    )(x, w)
+
+
+def _quantize(x):
+    from ray_tpu.ops.quant import quantize_int8
+
+    return quantize_int8(x)
+
+
+def _dequantize(q, s):
+    from ray_tpu.ops.quant import dequantize_int8
+
+    return dequantize_int8(q, s)
+
+
+X_NORM = ((8192, 3072), jnp.bfloat16)
+W_NORM = ((3072,), jnp.bfloat16)
+
+KERNELS = {
+    "splash_fwd": (_splash_fwd, (QKV, QKV, QKV)),
+    "splash_bwd": (_splash_bwd, (QKV, QKV, QKV)),
+    "rmsnorm_fwd": (_rmsnorm_fwd, (X_NORM, W_NORM)),
+    "rmsnorm_grad": (_rmsnorm_grad, (X_NORM, W_NORM)),
+    "quantize_int8": (_quantize, (((3072, 8192), jnp.bfloat16),)),
+    "dequantize_int8": (
+        _dequantize,
+        (((3072, 8192), jnp.int8), ((3072,), jnp.float32)),
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache, native_kernels):
+    fn, shapes = KERNELS[name]
+    text = _compiled_text(fn, one_chip, *shapes)
+    assert "tpu_custom_call" in text, f"{name}: no Pallas kernel in the program"

@@ -92,3 +92,37 @@ def test_int8_quant_preserves_matmul_quality():
     got = x @ w_deq
     rel = np.linalg.norm(np.asarray(got - ref)) / np.linalg.norm(np.asarray(ref))
     assert rel < 0.01, rel
+
+
+@pytest.mark.parametrize(
+    "rows,cols,expect",
+    [
+        (8192, 3072, 256),  # rmsnorm at Llama-3.2-3B width: the row cap
+        (3072, 8192, 128),  # quant at d_ff width: the VMEM budget halves it
+        (3072, 32768, 32),
+        (24, 1 << 22, 8),  # never below the sublane tile
+        (4, 128, 4),  # < 8 rows: one tiny block
+    ],
+)
+def test_pick_block_respects_row_width(rows, cols, expect):
+    """256 rows of 8192 bf16 are refused by the v5e compiler (scoped VMEM
+    20 MB against 16 MB): the block shrinks with the row width."""
+    from ray_tpu.ops._common import BLOCK_ELEMS, BLOCK_ROWS, pick_block
+
+    block = pick_block(rows, cols)
+    assert block == expect
+    assert rows % block == 0 and block <= BLOCK_ROWS
+    assert block * cols <= BLOCK_ELEMS or block <= 8
+
+
+def test_kernel_attention_raises_off_chip():
+    """'splash'/'flash' are TPU kernels: asking for them where they cannot
+    run raises instead of returning dense attention under their name."""
+    from ray_tpu.models import LlamaConfig, forward, init_params
+
+    for attention in ("splash", "flash"):
+        cfg = LlamaConfig.tiny(dtype=jnp.float32, attention=attention)
+        params = init_params(jax.random.PRNGKey(0), cfg)
+        tokens = jnp.zeros((1, 16), jnp.int32)
+        with pytest.raises(ValueError, match="needs a tpu backend"):
+            forward(params, tokens, cfg)

@@ -398,3 +398,180 @@ def test_pubsub_actor_and_node_events(ray_start_thread):
     assert [e["k"] for e in got] == [42]
     assert 0.3 < time.monotonic() - t0 < 5.0  # actually blocked, then woke
     t.join()
+
+
+# ------------------------------------------------------- one process per chip
+
+
+def test_chip_grant_check_refuses_wrong_platform_or_count():
+    """A worker process spawned for a TPU grant checks what JAX sees before
+    it takes work. Here JAX is on the CPU, so any grant is refused."""
+    from ray_tpu.tpu.accelerator import verify_chip_grant
+
+    with pytest.raises(RuntimeError, match="granted 1 TPU chip"):
+        verify_chip_grant(1)
+
+
+def test_chip_grant_check_passes_on_matching_devices(monkeypatch):
+    import jax
+
+    from ray_tpu.tpu.accelerator import verify_chip_grant
+
+    class Dev:
+        platform = "tpu"
+
+    monkeypatch.setattr(jax, "local_devices", lambda: [Dev(), Dev()])
+    verify_chip_grant(2)
+    with pytest.raises(RuntimeError, match="granted 4 TPU chip"):
+        verify_chip_grant(4)
+
+    def no_backend():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "local_devices", no_backend)
+    with pytest.raises(RuntimeError, match="no backend"):
+        verify_chip_grant(2)
+
+
+def test_poisoned_worker_fails_new_work_with_the_reason():
+    """worker_main hands a failed grant check to the runtime, which raises
+    it for every task and actor creation (never for calls on an actor that
+    already lives)."""
+    from ray_tpu._private.ids import WorkerID
+    from ray_tpu._private.task_spec import TaskType
+    from ray_tpu._private.worker_runtime import WorkerRuntime
+
+    class Spec:
+        task_type = TaskType.NORMAL_TASK
+        name = "f"
+
+    rt = WorkerRuntime(WorkerID.from_random(), conn=None, in_process=True)
+    rt.startup_error = RuntimeError("worker was granted 1 TPU chip(s) but ...")
+    with pytest.raises(RuntimeError, match="granted 1 TPU chip"):
+        rt._invoke(Spec(), (), {})
+
+
+def test_chip_worker_env_overrides_inherited_platform():
+    """A driver that keeps itself off the chip with JAX_PLATFORMS=cpu must
+    not hand that to a worker granted TPU."""
+    from ray_tpu.tpu.accelerator import chip_worker_env
+
+    whole_host = chip_worker_env([0, 1, 2, 3], 4)
+    assert whole_host == {"JAX_PLATFORMS": "tpu"}
+    one = chip_worker_env([1], 4)
+    assert one["JAX_PLATFORMS"] == "tpu"
+    assert one["TPU_VISIBLE_CHIPS"] == "1"
+    assert one["TPU_CHIPS_PER_HOST_BOUNDS"] == "1,1,1"
+    # what the task set itself is left to it (the grant check judges it)
+    assert "JAX_PLATFORMS" not in chip_worker_env([1], 4, {"JAX_PLATFORMS": "cpu"})
+
+
+def test_chip_pool_waits_for_the_previous_holder_to_exit():
+    from ray_tpu.tpu.accelerator import ChipPool
+
+    import subprocess
+
+    class Proc:
+        def __init__(self):
+            self.rc = None
+
+        def poll(self):
+            return self.rc
+
+        def wait(self, timeout=None):
+            if self.rc is None:
+                raise subprocess.TimeoutExpired("worker", timeout)
+            return self.rc
+
+        def kill(self):
+            self.rc = -9
+
+    pool = ChipPool(4)
+    with pytest.raises(RuntimeError, match="this host has 4"):
+        pool.acquire(8, 0.1)
+    first = pool.acquire(1, 0.1)
+    second = pool.acquire(1, 0.1)
+    assert first == [0] and second == [1]  # two live one-chip workers: disjoint
+    a, b = Proc(), Proc()
+    pool.bind(first, a)
+    pool.bind(second, b)
+    pair = pool.acquire(2, 0.1)
+    assert pair == [2, 3]  # an aligned group
+    pool.bind(pair, None)  # that spawn failed: the chips come back
+    # all four need both holders gone; evict() is how idle ones are retired
+    with pytest.raises(RuntimeError, match="have not exited"):
+        pool.acquire(4, 0.2)
+
+    def evict():
+        a.rc = b.rc = 0
+
+    whole = pool.acquire(4, 1.0, evict=evict)
+    assert whole == [0, 1, 2, 3]
+    # shutdown: a holder that does not exit in time is killed and waited
+    # for, so the chips are free for whatever the host runs next
+    last = Proc()
+    pool.bind(whole, last)
+    pool.drain(grace_s=0.1)
+    assert last.rc == -9
+
+
+def test_chip_count_comes_from_device_nodes(monkeypatch):
+    """init() counts chips without a JAX backend: device nodes first (a
+    one-chip machine cut from a four-chip host keeps the host's GKE
+    variables), the variables only where no node is exposed."""
+    import glob as glob_mod
+
+    from ray_tpu.tpu import accelerator
+    from ray_tpu.tpu.accelerator import TPUAcceleratorManager as M
+
+    monkeypatch.setenv("TPU_CHIPS_PER_HOST_BOUNDS", "2,2,1")
+    nodes = {"/dev/accel[0-9]*": [], "/dev/vfio/*": ["/dev/vfio/2", "/dev/vfio/vfio"]}
+    monkeypatch.setattr(accelerator.glob, "glob", lambda pat: nodes[pat])
+    assert M.get_current_node_num_accelerators() == 1
+    nodes["/dev/vfio/*"] = []
+    assert M.get_current_node_num_accelerators() == 4
+    monkeypatch.delenv("TPU_CHIPS_PER_HOST_BOUNDS")
+    monkeypatch.delenv("TPU_ACCELERATOR_TYPE", raising=False)
+    assert M.get_current_node_num_accelerators() == 0
+    assert glob_mod.glob is not None  # the real module is untouched
+
+
+def test_env_fingerprint_separates_chip_counts():
+    """A worker that sees one chip is not reused for a four-chip grant."""
+    from ray_tpu._private.controller import Controller
+
+    class Spec:
+        runtime_env = None
+
+        def __init__(self, resources):
+            self.resources = resources
+
+    fp = Controller._env_fingerprint
+    assert fp(Spec({"CPU": 1.0}))[0] == 0
+    assert fp(Spec({"TPU": 1.0}))[0] == 1
+    assert fp(Spec({"TPU": 4.0})) != fp(Spec({"TPU": 1.0}))
+
+
+def test_compile_cache_dir_choice(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: JAX's own handling, nothing else is
+    set. Not set: the fixed <checkout>/.jax_cache, never a temporary name."""
+    import os
+
+    import jax
+
+    from ray_tpu._private import jax_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    updates = []
+    monkeypatch.setattr(
+        jax.config, "update", lambda k, v: updates.append((k, v))
+    )
+    monkeypatch.setenv(jax_cache.ENV_VAR, str(tmp_path))
+    assert jax_cache.configure() == str(tmp_path)
+    assert updates == []
+    monkeypatch.delenv(jax_cache.ENV_VAR)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    fixed = os.path.join(repo, ".jax_cache")
+    assert jax_cache.configure() == fixed == jax_cache.cache_dir()
+    assert updates == [("jax_compilation_cache_dir", fixed)]
+    assert jax.config.jax_compilation_cache_dir == was

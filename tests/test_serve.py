@@ -848,3 +848,47 @@ def test_asgi_ingress_e2e(ray_start_thread):
     finally:
         ray_tpu.get(proxy.shutdown.remote(), timeout=30)
         serve.shutdown()
+
+
+@pytest.mark.parametrize(
+    "options,resources",
+    [
+        ({"num_tpus": 1}, {"TPU": 1.0}),
+        ({"resources": {"TPU": 1}}, {"TPU": 1.0}),
+        ({"num_cpus": 0.5, "num_tpus": 2}, {"TPU": 2.0}),
+    ],
+)
+def test_replica_actor_options_reach_the_replica(serve_instance, options, resources):
+    """num_tpus in ray_actor_options is honoured (it used to be dropped
+    with a warning): the replica's actor asks for the chips. No node here
+    has TPU, so the demand shows as pending."""
+    @serve.deployment(ray_actor_options=options)
+    def f(x):
+        return x
+
+    try:
+        serve.run(f.bind(), name="tpu-app", _wait_for_ready_s=0.5)
+    except RuntimeError:
+        pass  # no replica can start without a TPU node
+    from ray_tpu._private.worker import global_worker
+
+    def tpu_demand():
+        state = global_worker().controller._dispatch_request(
+            "autoscaler_state", None
+        )
+        return [
+            d["resources"]["TPU"]
+            for d in state["pending_demand"]
+            if "TPU" in d["resources"]
+        ]
+
+    deadline = time.time() + 30
+    while not tpu_demand() and time.time() < deadline:
+        time.sleep(0.05)
+    assert tpu_demand() == [resources["TPU"]]
+    serve.delete("tpu-app")
+
+
+def test_unknown_replica_actor_options_are_refused():
+    with pytest.raises(ValueError, match="max_restarts"):
+        serve.deployment(lambda x: x, ray_actor_options={"max_restarts": 3})
