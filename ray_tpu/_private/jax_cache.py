@@ -23,11 +23,19 @@ def cache_dir() -> str:
 
 
 def configure() -> str:
+    import jax
+
     path = cache_dir()
     if not os.environ.get(ENV_VAR):
-        import jax
-
         jax.config.update("jax_compilation_cache_dir", path)
+    # JAX leaves names and source lines out of the cache key, so a program
+    # compiled before its operations were given ``jax.named_scope`` names is
+    # found again without them (tests/test_platform.py shows it), and a
+    # device trace is attributed by those names (``op_name``). With them in
+    # the key, a cache shared with an older checkout gives an older program
+    # only where it is the same. The price: an edit that moves the traced
+    # lines compiles those programs once more (README, "Compile cache").
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
 
 

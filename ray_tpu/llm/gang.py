@@ -53,7 +53,7 @@ import ray_tpu
 from ray_tpu._private import locktrace
 from ray_tpu.llm.config import LLMConfig, SamplingParams
 from ray_tpu.llm.pacing import TokenPacer
-from ray_tpu.llm.server import _sampling_from_dict
+from ray_tpu.llm.server import sampling_from_body
 from ray_tpu.util.placement_group import placement_group, remove_placement_group
 from ray_tpu.util.scheduling_strategies import PlacementGroupSchedulingStrategy
 
@@ -759,14 +759,7 @@ class GangLLMServer:
 
     def completions(self, body: dict) -> dict:
         prompt = body.get("prompt", "")
-        params = _sampling_from_dict(
-            {
-                "max_tokens": body.get("max_tokens", 64),
-                "temperature": body.get("temperature", 0.0),
-                "top_k": body.get("top_k", 50),
-                "seed": body.get("seed"),
-            }
-        )
+        params = sampling_from_body(body)
         try:
             req = self.submit(prompt, params)
             self._wait_unary(req)
@@ -839,14 +832,7 @@ class GangLLMServer:
         """Generator of OpenAI ``text_completion`` chunk dicts — one per
         generated token, pumped by rank 0's scheduler (SSE at gang scale)."""
         prompt = body.get("prompt", "")
-        params = _sampling_from_dict(
-            {
-                "max_tokens": body.get("max_tokens", 64),
-                "temperature": body.get("temperature", 0.0),
-                "top_k": body.get("top_k", 50),
-                "seed": body.get("seed"),
-            }
-        )
+        params = sampling_from_body(body)
         try:
             req = self.submit(prompt, params)
         except (ValueError, RuntimeError) as e:

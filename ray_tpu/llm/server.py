@@ -9,16 +9,25 @@ behind the serve router.
 from __future__ import annotations
 
 import time
-from typing import Any, Optional
 
 from ray_tpu.llm.config import LLMConfig, SamplingParams
 from ray_tpu.llm.engine import JaxEngine
+from ray_tpu.util import tracing
 
 
-def _sampling_from_dict(d: Optional[dict]) -> SamplingParams:
-    d = dict(d or {})
-    allowed = {f for f in SamplingParams.__dataclass_fields__}
-    return SamplingParams(**{k: v for k, v in d.items() if k in allowed})
+def sampling_from_body(body: dict) -> SamplingParams:
+    """The sampling fields of an OpenAI-shaped request body. ``ignore_eos``
+    and ``seed`` are this server's extensions (vLLM's names): a load
+    generator that must get ``max_tokens`` tokens sets the first, a caller
+    that must get the same sample twice the second."""
+    seed = body.get("seed")
+    return SamplingParams(
+        max_tokens=int(body.get("max_tokens", 64)),
+        temperature=float(body.get("temperature", 0.0)),
+        top_k=int(body.get("top_k", 50)),
+        ignore_eos=bool(body.get("ignore_eos", False)),
+        seed=None if seed is None else int(seed),
+    )
 
 
 class LLMServer:
@@ -74,15 +83,10 @@ class LLMServer:
         if err is not None:
             return err
         prompt = body.get("prompt", "")
-        params = _sampling_from_dict(
-            {
-                "max_tokens": body.get("max_tokens", 64),
-                "temperature": body.get("temperature", 0.0),
-                "top_k": body.get("top_k", 50),
-            }
-        )
+        params = sampling_from_body(body)
         out = self.engine.generate(
-            prompt, sampling_params=params, lora=body.get("_lora")
+            prompt, sampling_params=params, lora=body.get("_lora"),
+            trace_ctx=tracing.current_context(),
         )
         return {
             "id": f"cmpl-{out.request_id}",
@@ -109,15 +113,10 @@ class LLMServer:
             return err
         messages = body.get("messages", [])
         prompt = self._render_chat(messages)
-        params = _sampling_from_dict(
-            {
-                "max_tokens": body.get("max_tokens", 64),
-                "temperature": body.get("temperature", 0.0),
-                "top_k": body.get("top_k", 50),
-            }
-        )
+        params = sampling_from_body(body)
         out = self.engine.generate(
-            prompt, sampling_params=params, lora=body.get("_lora")
+            prompt, sampling_params=params, lora=body.get("_lora"),
+            trace_ctx=tracing.current_context(),
         )
         return {
             "id": f"chatcmpl-{out.request_id}",
@@ -147,15 +146,10 @@ class LLMServer:
             yield err
             return
         prompt = body.get("prompt", "")
-        params = _sampling_from_dict(
-            {
-                "max_tokens": body.get("max_tokens", 64),
-                "temperature": body.get("temperature", 0.0),
-                "top_k": body.get("top_k", 50),
-            }
-        )
+        params = sampling_from_body(body)
         req = self.engine.submit(
-            prompt, sampling_params=params, lora=body.get("_lora")
+            prompt, sampling_params=params, lora=body.get("_lora"),
+            trace_ctx=tracing.current_context(),
         )
         created = int(time.time())
         for inc in self.engine.drain(req):
@@ -185,15 +179,10 @@ class LLMServer:
             yield err
             return
         prompt = self._render_chat(body.get("messages", []))
-        params = _sampling_from_dict(
-            {
-                "max_tokens": body.get("max_tokens", 64),
-                "temperature": body.get("temperature", 0.0),
-                "top_k": body.get("top_k", 50),
-            }
-        )
+        params = sampling_from_body(body)
         req = self.engine.submit(
-            prompt, sampling_params=params, lora=body.get("_lora")
+            prompt, sampling_params=params, lora=body.get("_lora"),
+            trace_ctx=tracing.current_context(),
         )
         created = int(time.time())
         first = True
@@ -237,6 +226,9 @@ class LLMServer:
         }
 
     def stats(self) -> dict:
+        """The engine's ``get_stats()``: slots, queue, the cumulative
+        counters, the latency histograms and ``engine_init_s`` (this
+        replica's engine constructor to its loop thread's first pass)."""
         return self.engine.get_stats()
 
     def check_health(self):

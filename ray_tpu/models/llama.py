@@ -32,6 +32,17 @@ from ray_tpu.parallel.ring_attention import (
 )
 from ray_tpu.parallel.ulysses import ulysses_attention
 
+# ``jax.named_scope`` names, one vocabulary for the train step and the
+# engine's programs (which add ``kv_write``, ``sampling``, ``prefix_seed``):
+# embed, norm, attn_qkv (projections and rope), attn_core (the kernel; in
+# decode, attention over the cache), attn_out, ffn, moe_ffn, lm_head, loss,
+# optimizer, grad_norm. They sit inside the scanned layer body, so every
+# layer's work pools under one name, and are metadata only: each lands in the
+# ``op_name`` of the operations traced under it, which is what a device trace
+# is attributed by. The backward pass needs none of its own: JAX writes
+# ``jvp(..)`` and ``transpose(jvp(..))`` around the forward scope.
+scope = jax.named_scope
+
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
@@ -287,6 +298,7 @@ def init_params(key, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
     return params
 
 
+@scope("norm")
 def _rmsnorm(x, w, eps, fused: bool = False):
     if fused:
         from ray_tpu.ops import rmsnorm as _fused_rmsnorm
@@ -412,22 +424,27 @@ def _layer(layer_params, x, positions, cfg: LlamaConfig, mesh: Optional[Mesh]):
         return with_sharding(mesh, y, *dims) if mesh is not None else y
 
     h = _rmsnorm(x, p["attn_norm"], cfg.rms_eps, cfg.fused_rmsnorm)
-    q = jnp.einsum("bte,ehd->bthd", h, p["wq"])
-    k = jnp.einsum("bte,ehd->bthd", h, p["wk"])
-    v = jnp.einsum("bte,ehd->bthd", h, p["wv"])
-    q = c(_rope(q, positions, cfg.rope_theta), "batch", "seq", "heads", "head_dim")
-    k = c(_rope(k, positions, cfg.rope_theta), "batch", "seq", "kv_heads", "head_dim")
-    attn = _attention(q, k, v, cfg, mesh)
-    x = x + c(jnp.einsum("bthd,hde->bte", attn, p["wo"]), "batch", "seq", "embed")
+    with scope("attn_qkv"):
+        q = jnp.einsum("bte,ehd->bthd", h, p["wq"])
+        k = jnp.einsum("bte,ehd->bthd", h, p["wk"])
+        v = jnp.einsum("bte,ehd->bthd", h, p["wv"])
+        q = c(_rope(q, positions, cfg.rope_theta), "batch", "seq", "heads", "head_dim")
+        k = c(_rope(k, positions, cfg.rope_theta), "batch", "seq", "kv_heads", "head_dim")
+    with scope("attn_core"):
+        attn = _attention(q, k, v, cfg, mesh)
+    with scope("attn_out"):
+        x = x + c(jnp.einsum("bthd,hde->bte", attn, p["wo"]), "batch", "seq", "embed")
 
     h = _rmsnorm(x, p["mlp_norm"], cfg.rms_eps, cfg.fused_rmsnorm)
     if cfg.moe_experts:
-        x2, aux = _moe_ffn(p, h, cfg, mesh)
-        return x + c(x2, "batch", "seq", "embed"), aux
-    gate = jnp.einsum("bte,ef->btf", h, p["w_gate"])
-    up = jnp.einsum("bte,ef->btf", h, p["w_up"])
-    ff = c(jax.nn.silu(gate) * up, "batch", "seq", "mlp")
-    x = x + c(jnp.einsum("btf,fe->bte", ff, p["w_down"]), "batch", "seq", "embed")
+        with scope("moe_ffn"):
+            x2, aux = _moe_ffn(p, h, cfg, mesh)
+            return x + c(x2, "batch", "seq", "embed"), aux
+    with scope("ffn"):
+        gate = jnp.einsum("bte,ef->btf", h, p["w_gate"])
+        up = jnp.einsum("bte,ef->btf", h, p["w_up"])
+        ff = c(jax.nn.silu(gate) * up, "batch", "seq", "mlp")
+        x = x + c(jnp.einsum("btf,fe->bte", ff, p["w_down"]), "batch", "seq", "embed")
     return x, jnp.zeros((), jnp.float32)
 
 
@@ -469,6 +486,7 @@ def _moe_ffn(p, h, cfg: LlamaConfig, mesh: Optional[Mesh]):
     return y.reshape(B, T, e).astype(h.dtype), aux
 
 
+@scope("embed")
 def _embed_lookup(table, tokens, cfg: LlamaConfig, mesh: Optional[Mesh]):
     """Token embedding. On a sharded mesh the row-gather is replaced by a
     one-hot matmul: SPMD cannot partition a gather from a table sharded on
@@ -584,6 +602,7 @@ def _pipeline_hidden(stacked, x, cfg: LlamaConfig, mesh: Mesh, pp: int, policy):
     return out.reshape(B, T, e), aux / jnp.float32(M)
 
 
+@scope("lm_head")
 def _project_logits(x, params, cfg: LlamaConfig, mesh: Optional[Mesh]):
     """Vocab projection shared by forward() and the training loss.
 
@@ -633,13 +652,14 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
         base = fused_cross_entropy(x, unembed, labels, mask=mask)
     else:
         logits = _project_logits(x, params, cfg, mesh)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-        if mask is not None:
-            denom = jnp.maximum(mask.sum(), 1)
-            base = (nll * mask).sum() / denom
-        else:
-            base = nll.mean()
+        with scope("loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+            if mask is not None:
+                denom = jnp.maximum(mask.sum(), 1)
+                base = (nll * mask).sum() / denom
+            else:
+                base = nll.mean()
     if cfg.moe_experts:
         return base + cfg.moe_aux_weight * aux
     return base
@@ -746,7 +766,8 @@ def _decode_forward(
     that kept 7B from fitting one v5e chip)."""
     B, T = tokens.shape
     S = cache["k"].shape[3]  # [L, B, K, S, D]
-    x = params["embed"][tokens].astype(cfg.dtype)
+    with scope("embed"):
+        x = params["embed"][tokens].astype(cfg.dtype)
 
     new_len = cache["length"] + T
     slot = jnp.arange(S)[None, None, :]  # [1, 1, S]
@@ -758,8 +779,6 @@ def _decode_forward(
         write_pos = jnp.where(valid, positions, S)
     else:
         write_pos = positions
-    layer_keys = _layer_keys(cfg)
-    stacked = {k: params[k] for k in layer_keys}
     bi = jnp.arange(B)[:, None, None]
     ki = jnp.arange(cfg.n_kv_heads)[None, :, None]
     pi = write_pos[:, None, :]  # [B, 1, T]
@@ -772,61 +791,74 @@ def _decode_forward(
     # measured 1.6x slower from those copies alone at 3B/B=16 on v5e).
     def body(l, carry):
         x, ck_all, cv_all = carry
-        p = {k: stacked[k][l] for k in layer_keys}
-        h = _rmsnorm(x, p["attn_norm"], cfg.rms_eps, cfg.fused_rmsnorm)
-        q = jnp.einsum("bte,ehd->bthd", h, p["wq"])
-        k = jnp.einsum("bte,ehd->bthd", h, p["wk"])
-        v = jnp.einsum("bte,ehd->bthd", h, p["wv"])
-        if loras is not None:
-            # per-sequence adapter gather + low-rank delta: W x + B(A x)
-            lp = {n: loras[n][l] for n in ("wq_a", "wq_b", "wv_a", "wv_b")}
-            q = q + jnp.einsum(
-                "btr,brhd->bthd",
-                jnp.einsum("bte,ber->btr", h, lp["wq_a"][adapter_ids]),
-                lp["wq_b"][adapter_ids],
-            )
-            v = v + jnp.einsum(
-                "btr,brhd->bthd",
-                jnp.einsum("bte,ber->btr", h, lp["wv_a"][adapter_ids]),
-                lp["wv_b"][adapter_ids],
-            )
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-        # cache is [B, K, S, D]: write the new [B, T, K, D] rows head-major
-        kh = k.transpose(0, 2, 1, 3)  # [B, K, T, D]
-        vh = v.transpose(0, 2, 1, 3)
-        ck_all = ck_all.at[l, bi, ki, pi].set(kh, mode="drop")
-        cv_all = cv_all.at[l, bi, ki, pi].set(vh, mode="drop")
-        ck = ck_all[l]
-        cv = cv_all[l]
+        # a layer's slice of a stacked weight is a copy on the chip (1.1 ms
+        # of a 14.7 ms decode step at 7B widths): take it inside the scope
+        # that uses it, so that it is booked there
+        def p(k):
+            return params[k][l]
 
-        if groups > 1:
-            # GQA without materializing repeated K/V: fold the group axis
-            # into the query instead (a jnp.repeat here would write+reread
-            # the whole cache ×groups per layer per step — at 3B/B=16 that
-            # alone is ~11 GB of HBM traffic per decode step)
-            qg = q.reshape(B, T, cfg.n_kv_heads, groups, cfg.head_dim)
-            s = jnp.einsum("btkgd,bksd->bktgs", qg, ck) * scale
-            s = jnp.where(seq_mask[:, None, :, None, :], s, -1e30)
-            w = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(x.dtype)
-            attn = jnp.einsum("bktgs,bksd->btkgd", w, cv).reshape(
-                B, T, cfg.n_heads, cfg.head_dim
-            )
-        else:
-            s = jnp.einsum("bthd,bhsd->bhts", q, ck) * scale
-            s = jnp.where(seq_mask[:, None, :, :], s, -1e30)
-            w = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(x.dtype)
-            attn = jnp.einsum("bhts,bhsd->bthd", w, cv)
-        x = x + jnp.einsum("bthd,hde->bte", attn, p["wo"])
+        h = _rmsnorm(x, p("attn_norm"), cfg.rms_eps, cfg.fused_rmsnorm)
+        with scope("attn_qkv"):
+            q = jnp.einsum("bte,ehd->bthd", h, p("wq"))
+            k = jnp.einsum("bte,ehd->bthd", h, p("wk"))
+            v = jnp.einsum("bte,ehd->bthd", h, p("wv"))
+            if loras is not None:
+                # per-sequence adapter gather + low-rank delta: W x + B(A x)
+                lp = {n: loras[n][l] for n in ("wq_a", "wq_b", "wv_a", "wv_b")}
+                q = q + jnp.einsum(
+                    "btr,brhd->bthd",
+                    jnp.einsum("bte,ber->btr", h, lp["wq_a"][adapter_ids]),
+                    lp["wq_b"][adapter_ids],
+                )
+                v = v + jnp.einsum(
+                    "btr,brhd->bthd",
+                    jnp.einsum("bte,ber->btr", h, lp["wv_a"][adapter_ids]),
+                    lp["wv_b"][adapter_ids],
+                )
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
+        with scope("kv_write"):
+            # cache is [B, K, S, D]: write the new [B, T, K, D] rows head-major
+            kh = k.transpose(0, 2, 1, 3)  # [B, K, T, D]
+            vh = v.transpose(0, 2, 1, 3)
+            ck_all = ck_all.at[l, bi, ki, pi].set(kh, mode="drop")
+            cv_all = cv_all.at[l, bi, ki, pi].set(vh, mode="drop")
 
-        h = _rmsnorm(x, p["mlp_norm"], cfg.rms_eps, cfg.fused_rmsnorm)
+        with scope("attn_core"):
+            ck = ck_all[l]
+            cv = cv_all[l]
+            if groups > 1:
+                # GQA without materializing repeated K/V: fold the group axis
+                # into the query instead (a jnp.repeat here would write+reread
+                # the whole cache ×groups per layer per step — at 3B/B=16 that
+                # alone is ~11 GB of HBM traffic per decode step)
+                qg = q.reshape(B, T, cfg.n_kv_heads, groups, cfg.head_dim)
+                s = jnp.einsum("btkgd,bksd->bktgs", qg, ck) * scale
+                s = jnp.where(seq_mask[:, None, :, None, :], s, -1e30)
+                w = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(x.dtype)
+                attn = jnp.einsum("bktgs,bksd->btkgd", w, cv).reshape(
+                    B, T, cfg.n_heads, cfg.head_dim
+                )
+            else:
+                s = jnp.einsum("bthd,bhsd->bhts", q, ck) * scale
+                s = jnp.where(seq_mask[:, None, :, :], s, -1e30)
+                w = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(x.dtype)
+                attn = jnp.einsum("bhts,bhsd->bthd", w, cv)
+        with scope("attn_out"):
+            x = x + jnp.einsum("bthd,hde->bte", attn, p("wo"))
+
+        h = _rmsnorm(x, p("mlp_norm"), cfg.rms_eps, cfg.fused_rmsnorm)
         if cfg.moe_experts:
-            x = x + _moe_decode_ffn(p, h, cfg)
+            with scope("moe_ffn"):
+                x = x + _moe_decode_ffn(
+                    {n: p(n) for n in _layer_keys(cfg) if n.startswith("moe_")}, h, cfg
+                )
         else:
-            ff = jax.nn.silu(
-                jnp.einsum("bte,ef->btf", h, p["w_gate"])
-            ) * jnp.einsum("bte,ef->btf", h, p["w_up"])
-            x = x + jnp.einsum("btf,fe->bte", ff, p["w_down"])
+            with scope("ffn"):
+                ff = jax.nn.silu(
+                    jnp.einsum("bte,ef->btf", h, p("w_gate"))
+                ) * jnp.einsum("bte,ef->btf", h, p("w_up"))
+                x = x + jnp.einsum("btf,fe->bte", ff, p("w_down"))
         return (x, ck_all, cv_all)
 
     x, new_k, new_v = jax.lax.fori_loop(
@@ -843,11 +875,12 @@ def _decode_forward(
         # vocab projection: [B, T, e] -> [B, 1, e]
         x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
     x = _rmsnorm(x, params["final_norm"], cfg.rms_eps, cfg.fused_rmsnorm)
-    unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-    logits = jnp.einsum(
-        "bte,ev->btv", x, unembed.astype(x.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    with scope("lm_head"):
+        unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
+        logits = jnp.einsum(
+            "bte,ev->btv", x, unembed.astype(x.dtype),
+            preferred_element_type=jnp.float32,
+        )
     return logits, new_cache
 
 

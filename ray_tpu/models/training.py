@@ -103,11 +103,13 @@ def make_train_step(
             return loss(p, batch, cfg, mesh)
 
         lval, grads = jax.value_and_grad(lf)(state.params)
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        params = optax.apply_updates(state.params, updates)
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("grad_norm"):
+            gnorm = optax.global_norm(grads)
         metrics = {"loss": lval, "grad_norm": gnorm, "step": state.step + 1}
         return TrainState(params, opt_state, state.step + 1), metrics
 

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("loss")
 def fused_cross_entropy(
     x,
     unembed,

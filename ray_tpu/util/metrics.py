@@ -88,6 +88,14 @@ class Histogram(Metric):
             counts[bisect.bisect_left(self.boundaries, value)] += 1
             self._sums[k] = self._sums.get(k, 0.0) + value
 
+    def read(self, tags: Optional[dict] = None) -> dict:
+        """One series' bucket counts (one more than the boundaries: the
+        last is what lay above them all) and sum."""
+        k = self._key(tags)
+        with self._lock:
+            counts = self._counts.get(k) or [0] * (len(self.boundaries) + 1)
+            return {"counts": list(counts), "sum": self._sums.get(k, 0.0)}
+
     def _hist_samples(self):
         with self._lock:
             return (

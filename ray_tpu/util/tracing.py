@@ -23,10 +23,18 @@ raylet → worker. Here, without an OTel dependency:
   tasks (deterministic by task id, so a sampled task gets its WHOLE chain).
   ``trace_sample_n=1`` records everything; ``0`` disables tracing.
 
-App-level :func:`span`/:func:`traced` remain and parent correctly under the
-executing task (context propagation rides a :class:`contextvars.ContextVar`,
-so spans opened inside asyncio actors — including across the
-``run_in_executor`` hand-off the async path uses — keep their parents).
+App-level :func:`span` parents correctly under the executing task (context
+propagation rides a :class:`contextvars.ContextVar`, so spans opened inside
+asyncio actors — including across the ``run_in_executor`` hand-off the async
+path uses — keep their parents).
+
+One clock with the device: in a process that has already imported JAX,
+:func:`span` and :func:`annotate` also enter a
+``jax.profiler.TraceAnnotation``, so the same call site lands in the
+profiler's ``.xplane.pb`` beside the device operations while a profiler
+session runs (and costs a check of one flag while none does). A process that
+has no JAX (controller, agents) never imports it from here. :func:`annotate`
+is the hot-loop form: profiler only, never the ring.
 """
 
 from __future__ import annotations
@@ -34,10 +42,11 @@ from __future__ import annotations
 import contextvars
 import itertools
 import os
+import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Callable, Optional
 
 _DEFAULT_BUFFER = 4096
@@ -46,7 +55,6 @@ _spans: deque = deque()
 _max_spans: Optional[int] = None  # resolved lazily (config/env)
 _dropped = 0
 _lock = threading.Lock()
-_exporter: Optional[Callable[[dict], None]] = None
 _id_counter = itertools.count(1)
 # (trace_id, span_id) of the innermost open app span / attached task context.
 # A ContextVar (not a threading.local): asyncio tasks copy their context at
@@ -61,12 +69,6 @@ _current: "contextvars.ContextVar[Optional[tuple]]" = contextvars.ContextVar(
 # span set the ContextVar.
 _context_provider: Optional[Callable[[], Optional[tuple]]] = None
 _sample_n_cache: Optional[int] = None
-
-
-def set_exporter(fn: Optional[Callable[[dict], None]]):
-    """Attach a per-span callback (e.g. an OTLP bridge)."""
-    global _exporter
-    _exporter = fn
 
 
 def set_context_provider(fn: Optional[Callable[[], Optional[tuple]]]):
@@ -204,11 +206,6 @@ def _append(rec: dict) -> None:
             _spans.popleft()
             _dropped += 1
         _spans.append(rec)
-    if _exporter is not None:
-        try:
-            _exporter(rec)
-        except Exception:  # noqa: BLE001 — exporters must not break tracing
-            pass
 
 
 def record_span(
@@ -246,14 +243,30 @@ def record_span(
     return rec
 
 
+_NO_ANNOTATION = nullcontext()
+
+
+def annotate(name: str, **attributes):
+    """Profiler-only span for hot loops (per loop pass, per chunk, per
+    launch): a ``jax.profiler.TraceAnnotation`` where this process has
+    imported JAX, else nothing. Never recorded in the ring, not gated by
+    ``trace_sample_n``: a profiler session is its own on switch."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NO_ANNOTATION
+    return jax.profiler.TraceAnnotation(name, **attributes)
+
+
 @contextmanager
 def span(name: str, **attributes):
     """App-level span: parents under the innermost open span, else the
-    executing task's exec span, else roots a fresh trace. A no-op when
-    tracing is disabled (``trace_sample_n=0`` means no recording, no
-    buffering, no shipping — the off switch is total)."""
+    executing task's exec span, else roots a fresh trace. With tracing
+    disabled nothing is recorded (``trace_sample_n=0`` means no recording,
+    no buffering, no shipping); the profiler annotation of :func:`annotate`
+    is entered either way."""
     if not enabled():
-        yield
+        with annotate(name, **attributes):
+            yield
         return
     parent_ctx = current_context()
     trace_id = parent_ctx[0] if parent_ctx else new_trace_id()
@@ -262,7 +275,8 @@ def span(name: str, **attributes):
     token = _current.set((trace_id, sid))
     start = time.time()
     try:
-        yield
+        with annotate(name, **attributes):
+            yield
     finally:
         _current.reset(token)
         record_span(
@@ -275,22 +289,6 @@ def span(name: str, **attributes):
             plane="app",
             **attributes,
         )
-
-
-def traced(name: Optional[str] = None):
-    """Decorator form of ``span``."""
-
-    def wrap(fn):
-        import functools
-
-        @functools.wraps(fn)
-        def inner(*args, **kwargs):
-            with span(name or fn.__qualname__):
-                return fn(*args, **kwargs)
-
-        return inner
-
-    return wrap
 
 
 def get_spans() -> list[dict]:

@@ -1,0 +1,498 @@
+"""Names, spans and counters inside the program (PR 26).
+
+- every ``jax.named_scope`` of ``models/llama.py``'s vocabulary is in the
+  ``op_name`` of the lowered train step and of the engine's programs;
+- the engine's counters balance, failures land under the stage that dropped
+  the request, an empty completion is counted, ``ignore_eos`` goes through
+  the HTTP body;
+- a request's spans share the caller's trace, nest under it and tile its
+  time; the loop's spans land in a profiler session; ``trace_sample_n=0``
+  records nothing; ``util.tracing`` imports no JAX by itself."""
+
+import glob
+import os
+import re
+import subprocess
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.llm import EngineConfig, JaxEngine, LLMConfig, ModelConfig, SamplingParams
+from ray_tpu.llm.engine import COUNTERS, LATENCIES
+from ray_tpu.llm.server import LLMServer, sampling_from_body
+from ray_tpu.models.llama import LlamaConfig, init_kv_cache
+from ray_tpu.models.training import make_train_step
+from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+from ray_tpu.util import metrics as app_metrics
+from ray_tpu.util import tracing
+
+LAYER = ("embed", "norm", "attn_qkv", "attn_core", "attn_out")
+PROGRAM_SCOPES = {
+    "step_fn": LAYER + ("ffn", "loss", "optimizer", "grad_norm"),
+    "step_fn_plain_loss": ("lm_head", "loss"),
+    "step_fn_moe": ("moe_ffn",),
+    "decode_fn": LAYER + ("ffn", "kv_write", "lm_head", "sampling"),
+    "chunk_mid": LAYER + ("ffn", "kv_write"),
+    "chunk_final": LAYER + ("ffn", "kv_write", "lm_head", "sampling"),
+    "seed_prefix": ("prefix_seed",),
+    "decode_fn_moe": ("moe_ffn",),
+}
+
+
+def _engine(**model_kwargs):
+    return JaxEngine(LLMConfig(
+        model=ModelConfig(model_id="tiny", tokenizer="byte", seed=0, model_kwargs=model_kwargs),
+        engine=EngineConfig(max_num_seqs=4, max_seq_len=128, prefill_buckets=(16, 32, 64, 128),
+                            prefill_chunk=32),
+    ))
+
+
+@pytest.fixture(scope="module")
+def engine():
+    eng = _engine()
+    yield eng
+    eng.shutdown()
+
+
+@pytest.fixture(scope="module")
+def server(engine):
+    srv = LLMServer.__new__(LLMServer)
+    srv.llm_config = engine.config
+    srv.engine = engine
+    return srv
+
+
+@pytest.fixture
+def ring(monkeypatch):
+    """Tracing on for every request, an empty ring; both restored after."""
+    monkeypatch.setenv("RAY_TPU_TRACE_SAMPLE_N", "1")
+    tracing._reset_sampling()
+    tracing.clear()
+    yield
+    tracing.clear()
+    monkeypatch.delenv("RAY_TPU_TRACE_SAMPLE_N")
+    tracing._reset_sampling()
+
+
+# ------------------------------------------------------------------- scopes
+
+
+def _shapes(tree):
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+
+
+def _one_device_mesh():
+    return build_mesh(MeshSpec(), devices=jax.devices()[:1])
+
+
+def _lower_step(**cfg_kwargs):
+    cfg = LlamaConfig.tiny(remat=True, **cfg_kwargs)
+    init_fn, step_fn = make_train_step(cfg, _one_device_mesh())
+    state = init_fn(jax.random.PRNGKey(0))
+    return step_fn.lower(state, {"tokens": jax.ShapeDtypeStruct((2, 33), jnp.int32)})
+
+
+def _lower_engine_program(eng, program):
+    while eng._loop_first_pass_t is None:  # the loop thread makes the pools' keys
+        time.sleep(0.01)
+    pool = eng._pools[0]
+    params, cache = _shapes(eng.params), _shapes(pool.cache)
+    one = jax.eval_shape(lambda: init_kv_cache(eng.model_cfg, 1, pool.stripe_len))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    keys = _shapes(pool.keys)
+    if program == "decode_fn":
+        return eng._decode_jit.lower(
+            params, cache, i32(pool.n_slots), f32(pool.n_slots), i32(pool.n_slots), keys)
+    if program == "chunk_mid":
+        return eng._chunk_mid_jit.lower(params, one, i32(1, 32), i32(1), i32(1))
+    if program == "chunk_final":
+        return eng._chunk_final_jit.lower(
+            params, cache, one, i32(1, 32), i32(1), i32(1), i32(), f32(), i32(),
+            jax.ShapeDtypeStruct(keys.shape[1:], keys.dtype))
+    if program == "seed_prefix":
+        kv = jax.ShapeDtypeStruct(one["k"].shape[:1] + (one["k"].shape[2], 16, one["k"].shape[4]),
+                                  one["k"].dtype)
+        return eng._seed_prefix_jit.lower(one, kv, kv)
+    raise KeyError(program)
+
+
+@pytest.fixture(scope="module")
+def lowered(engine):
+    """program name -> the scope paths of its lowered text, made once."""
+    cache = {}
+
+    def paths(program):
+        if program not in cache:
+            if program == "step_fn":
+                low = _lower_step()
+            elif program == "step_fn_plain_loss":
+                low = _lower_step(fused_ce=False)
+            elif program == "step_fn_moe":
+                low = _lower_step(moe_experts=4)
+            elif program == "decode_fn_moe":
+                moe = _engine(moe_experts=4)
+                try:
+                    low = _lower_engine_program(moe, "decode_fn")
+                finally:
+                    moe.shutdown()
+            else:
+                low = _lower_engine_program(engine, program)
+            cache[program] = set(re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True)))
+        return cache[program]
+
+    return paths
+
+
+@pytest.mark.parametrize(
+    "program, scope",
+    [(p, s) for p, scopes in PROGRAM_SCOPES.items() for s in scopes],
+)
+def test_scope_is_in_the_lowered_programs_op_names(lowered, program, scope):
+    # jit(step_fn)/jvp()/while/body/closed_call/ffn/mul, or the scope inside
+    # the transform that precedes it: jit(step_fn)/transpose(jvp(loss))/div
+    pattern = re.compile(rf"(^|/|\(){scope}(/|\))")
+    assert any(pattern.search(path) for path in lowered(program)), (
+        f"no operation of {program} carries the scope {scope!r}")
+
+
+def test_jitted_names_the_benchmark_reads_stay(engine):
+    assert engine._decode_jit.__name__ == "decode_fn"
+    assert engine._chunk_mid_jit.__name__ == "chunk_mid"
+    assert engine._chunk_final_jit.__name__ == "chunk_final"
+    _, step_fn = make_train_step(LlamaConfig.tiny(), _one_device_mesh())
+    assert step_fn.__name__ == "step_fn"
+
+
+# ----------------------------------------------------------------- counters
+
+
+def _counters(eng):
+    return eng.get_stats()["counters"]
+
+
+def _closed(c):
+    return sum(c["requests_finished"].values()) + sum(c["requests_failed"].values())
+
+
+def test_counters_balance_after_mixed_requests(engine):
+    before = _counters(engine)
+    reqs = [
+        # a letter of its own each: no prompt is served from the prefix cache
+        engine.submit(chr(97 + i) * n, sampling_params=SamplingParams(
+            max_tokens=m, temperature=t, ignore_eos=True, seed=n))
+        for i, (n, m, t) in enumerate([
+            (3, 5, 0.0), (40, 2, 0.8), (70, 9, 0.0), (10, 1, 0.0), (33, 6, 1.0), (90, 4, 0.0),
+            (5, 7, 0.0)])
+    ]
+    with pytest.raises(KeyError):
+        engine.submit("y", lora="no-such-adapter")
+    for r in reqs:
+        engine._await_done(r)
+    outs = [engine._output(r) for r in reqs]
+    s = engine.get_stats()
+    c = s["counters"]
+    assert set(COUNTERS) == {
+        k if not isinstance(v, dict) else f"{k}:{label}"
+        for k, v in c.items() for label in (v if isinstance(v, dict) else [None])
+    }
+    assert c["requests_submitted"] - before["requests_submitted"] == len(reqs) + 1
+    assert c["requests_submitted"] == _closed(c)
+    assert c["requests_failed"]["submit"] - before["requests_failed"]["submit"] == 1
+    assert c["requests_finished"]["length"] - before["requests_finished"]["length"] == len(reqs)
+    made = sum(len(o.token_ids) for o in outs)
+    assert made == 5 + 2 + 9 + 1 + 6 + 4 + 7
+    assert c["tokens_generated"] - before["tokens_generated"] == made
+    assert c["first_tokens"] - before["first_tokens"] == len(reqs)
+    assert c["prompt_tokens"] - before["prompt_tokens"] == sum(
+        len(o.prompt_token_ids) for o in outs)
+    assert c["decode_slot_steps"] <= c["decode_steps"] * s["max_num_seqs"]
+    assert c["prefill_chunks"]["final"] - before["prefill_chunks"]["final"] == len(reqs)
+    # prompts of 41, 71, 34 and 91 tokens (with BOS) in chunks of 32
+    assert c["prefill_chunks"]["mid"] - before["prefill_chunks"]["mid"] == 1 + 2 + 1 + 2
+    assert c["prompt_tokens_from_prefix"] == before["prompt_tokens_from_prefix"]
+    assert c["loop_passes"] > before["loop_passes"]
+    assert s["live_tokens"] == 0 and s["active_slots"] == 0
+    for o in outs:
+        m = o.metrics
+        assert m["queue_wait_s"] >= 0 and m["prefill_s"] > 0 and 0 <= m["slot"] < 4
+        assert m["ttft_s"] == pytest.approx(m["queue_wait_s"] + m["prefill_s"])
+
+
+def test_latency_histograms_count_every_finished_request(engine):
+    engine.generate("abc", sampling_params=SamplingParams(max_tokens=3, ignore_eos=True))
+    s = engine.get_stats()
+    finished = sum(s["counters"]["requests_finished"].values())
+    lat = s["latency"]
+    assert set(LATENCIES) <= set(lat)
+    assert len(lat["queue_wait_s"]["counts"]) == len(lat["boundaries"]) + 1
+    assert sum(lat["queue_wait_s"]["counts"]) == finished
+    assert sum(lat["prefill_s"]["counts"]) == finished
+    # a gap needs two tokens
+    assert 0 < sum(lat["token_gap_s"]["counts"]) <= finished
+    assert lat["prefill_s"]["sum"] > 0
+    assert 0 < s["engine_init_s"] < 600
+
+
+def test_an_engine_reads_its_own_series_of_the_process_histogram(engine):
+    from ray_tpu.llm.engine import LATENCY_BOUNDS, _latency_histogram
+
+    engine.generate("mine", sampling_params=SamplingParams(max_tokens=2, ignore_eos=True))
+    hist = _latency_histogram("prefill_s")
+    assert hist.read(engine._tag) == engine.get_stats()["latency"]["prefill_s"]
+    assert hist.read({"engine": "nobody"}) == {
+        "counts": [0] * (len(LATENCY_BOUNDS) + 1), "sum": 0.0}
+    assert f'llm_engine_prefill_s_count{{engine="{engine._tag["engine"]}"}}' in (
+        app_metrics.export_prometheus())
+
+
+def test_counters_reach_the_metrics_scrape(engine):
+    engine.generate("scrape", sampling_params=SamplingParams(max_tokens=2, ignore_eos=True))
+    text = app_metrics.export_prometheus()
+    assert "llm_engine_requests_submitted " in text
+    assert 'llm_engine_requests_finished{reason="length"}' in text
+    assert 'llm_engine_prefill_chunks{kind="final"}' in text
+    assert "llm_engine_queue_wait_s_bucket" in text
+    assert "llm_engine_live_tokens " in text
+
+
+@pytest.mark.parametrize("stage", ["admission", "decode"])
+def test_injected_failure_lands_under_its_stage(engine, monkeypatch, stage):
+    before = _counters(engine)
+
+    def boom(*a, **kw):
+        raise RuntimeError(f"injected {stage} failure")
+
+    with monkeypatch.context() as m:
+        m.setattr(engine, "_chunk_final_jit" if stage == "admission" else "_decode", boom)
+        req = engine.submit(
+            "fail me", sampling_params=SamplingParams(max_tokens=4, ignore_eos=True))
+        engine._await_done(req)
+    assert isinstance(req.error, RuntimeError) and "injected" in str(req.error)
+    c = _counters(engine)
+    for s in ("submit", "admission", "decode", "loop_exit"):
+        assert c["requests_failed"][s] - before["requests_failed"][s] == (s == stage)
+    assert c["requests_submitted"] == _closed(c)
+    # the engine still serves
+    out = engine.generate("after", sampling_params=SamplingParams(max_tokens=2, ignore_eos=True))
+    assert len(out.token_ids) == 2
+
+
+@pytest.mark.parametrize("stage", ["submit", "admission"])
+def test_an_empty_prompt_fails_its_request_and_not_the_loop(engine, stage):
+    """``submit`` refuses a prompt of no tokens; one that reaches the loop
+    all the same (an admission with no chunk) fails there alone."""
+    from ray_tpu.llm.engine import _Request
+
+    before = _counters(engine)
+    if stage == "submit":
+        with pytest.raises(ValueError, match="empty prompt"):
+            engine.generate(prompt_token_ids=[])
+    else:
+        with engine._count_lock:
+            engine._n["requests_submitted"] += 1
+        req = _Request("no-chunks", [], SamplingParams(max_tokens=2))
+        engine._waiting.put(req)
+        engine._await_done(req)
+        assert isinstance(req.error, IndexError)
+    c = _counters(engine)
+    for s in ("submit", "admission", "decode", "loop_exit"):
+        assert c["requests_failed"][s] - before["requests_failed"][s] == (s == stage)
+    assert c["requests_submitted"] == _closed(c)
+    assert engine._thread.is_alive()
+    out = engine.generate("after", sampling_params=SamplingParams(max_tokens=2, ignore_eos=True))
+    assert len(out.token_ids) == 2
+    assert engine.get_stats()["live_tokens"] == 0
+
+
+def test_loop_exit_failure_is_counted_once():
+    eng = _engine()
+    eng.shutdown()
+    req = eng.submit("nobody home", sampling_params=SamplingParams(max_tokens=2))
+    eng._await_done(req)
+    eng._await_done(req)
+    c = _counters(eng)
+    assert isinstance(req.error, RuntimeError)
+    assert c["requests_failed"]["loop_exit"] == 1 and c["requests_submitted"] == _closed(c) == 1
+
+
+@pytest.fixture
+def eos_first(engine, monkeypatch):
+    """A prompt whose first greedy token is the stop token."""
+    prompt = "the first token stops"
+    first = engine.generate(
+        prompt, sampling_params=SamplingParams(max_tokens=1, ignore_eos=True)).token_ids[0]
+    monkeypatch.setattr(engine.tokenizer, "eos_id", first)
+    return prompt
+
+
+def test_first_token_eos_counts_as_empty(engine, server, eos_first):
+    before = _counters(engine)
+    reply = server.completions({"prompt": eos_first, "max_tokens": 4})
+    assert reply["usage"]["completion_tokens"] == 0
+    assert reply["choices"][0]["finish_reason"] == "stop"
+    c = _counters(engine)
+    assert c["requests_empty"] - before["requests_empty"] == 1
+    assert c["requests_finished"]["stop"] - before["requests_finished"]["stop"] == 1
+    assert sum(c["requests_failed"].values()) == sum(before["requests_failed"].values())
+    out = engine.generate(eos_first, sampling_params=SamplingParams(max_tokens=4))
+    assert out.token_ids == [] and out.metrics["ttft_s"] is None
+    assert out.metrics["prefill_s"] > 0
+
+
+def test_ignore_eos_through_the_http_body(engine, server, eos_first):
+    before = _counters(engine)
+    reply = server.completions({"prompt": eos_first, "max_tokens": 4, "ignore_eos": True})
+    assert reply["usage"]["completion_tokens"] == 4
+    assert reply["choices"][0]["finish_reason"] == "length"
+    assert _counters(engine)["requests_empty"] == before["requests_empty"]
+
+
+@pytest.mark.parametrize("body, want", [
+    ({}, SamplingParams()),
+    ({"max_tokens": 7, "temperature": 0.5, "top_k": 3},
+     SamplingParams(max_tokens=7, temperature=0.5, top_k=3)),
+    ({"ignore_eos": True, "seed": 11}, SamplingParams(ignore_eos=True, seed=11)),
+    ({"stream": True, "model": "m", "prompt": "p"}, SamplingParams()),
+], ids=["defaults", "classic", "extensions", "other-keys"])
+def test_sampling_from_body(body, want):
+    assert sampling_from_body(body) == want
+
+
+def test_seed_through_the_http_body(server):
+    body = {"prompt": "seeded", "max_tokens": 6, "temperature": 1.0, "seed": 5,
+            "ignore_eos": True}
+    assert (server.completions(body)["choices"][0]["text"]
+            == server.completions(dict(body))["choices"][0]["text"])
+
+
+def test_live_tokens_gauge_equals_what_the_benchmark_reads_from_the_pools(engine, monkeypatch):
+    # decodes held back: slots are bound after their first token and stay put
+    with monkeypatch.context() as m:
+        m.setattr(engine, "_launch_decodes", lambda: False)
+        reqs = [
+            engine.submit("z" * n, sampling_params=SamplingParams(max_tokens=50, ignore_eos=True))
+            for n in (7, 20, 45)]
+        deadline = time.time() + 60
+        while time.time() < deadline and not all(r.out_tokens for r in reqs):
+            time.sleep(0.01)
+        assert all(len(r.out_tokens) == 1 for r in reqs)
+        # benchmark/serving.py bench_window_open's own sum
+        live = sum(
+            len(r.prompt_token_ids) + len(r.out_tokens)
+            for pool in engine._pools for r in list(pool.slots) if r is not None
+        )
+        assert engine.get_stats()["live_tokens"] == live == (8 + 21 + 46) + 3
+    for r in reqs:
+        engine._await_done(r)
+    assert engine.get_stats()["live_tokens"] == 0
+
+
+# -------------------------------------------------------------------- spans
+
+
+def test_request_spans_share_the_callers_trace_and_tile_the_request(engine, server, ring):
+    with tracing.span("caller"):
+        caller_ctx = tracing.current_context()
+        server.completions({"prompt": "trace me " * 6, "max_tokens": 5, "ignore_eos": True})
+    spans = {s["name"]: s for s in tracing.get_spans()}
+    assert set(spans) == {
+        "caller", "engine.request", "engine.queue_wait", "engine.prefill", "engine.decode"}
+    request = spans["engine.request"]
+    assert request["trace_id"] == caller_ctx[0] and request["parent_id"] == caller_ctx[1]
+    assert request["plane"] == "engine"
+    assert request["attributes"]["tokens"] == 5 and request["attributes"]["chunks"] == 2
+    assert request["attributes"]["finish_reason"] == "length"
+    phases = [spans[n] for n in ("engine.queue_wait", "engine.prefill", "engine.decode")]
+    for phase in phases:
+        assert phase["trace_id"] == caller_ctx[0] and phase["parent_id"] == request["span_id"]
+    assert phases[0]["start"] == request["start"] and phases[-1]["end"] == request["end"]
+    assert phases[0]["end"] == phases[1]["start"] and phases[1]["end"] == phases[2]["start"]
+    assert spans["caller"]["start"] <= request["start"] <= request["end"] <= spans["caller"]["end"]
+
+
+def test_a_request_without_a_caller_roots_its_own_trace(engine, ring):
+    engine.generate("alone", sampling_params=SamplingParams(max_tokens=2, ignore_eos=True))
+    spans = tracing.get_spans()
+    assert len(spans) == 4 and len({s["trace_id"] for s in spans}) == 1
+    assert [s for s in spans if s["name"] == "engine.request"][0]["parent_id"] is None
+
+
+def test_a_failed_request_has_the_phases_it_reached(engine, monkeypatch, ring):
+    def boom(*a, **kw):
+        raise RuntimeError("injected")
+
+    with monkeypatch.context() as m:
+        m.setattr(engine, "_chunk_final_jit", boom)
+        req = engine.submit("fail", sampling_params=SamplingParams(max_tokens=2))
+        engine._await_done(req)
+    spans = {s["name"]: s for s in tracing.get_spans()}
+    assert set(spans) == {"engine.request", "engine.queue_wait", "engine.prefill"}
+    assert "injected" in spans["engine.request"]["attributes"]["error"]
+    assert spans["engine.prefill"]["end"] == spans["engine.request"]["end"]
+
+
+def test_trace_sample_n_zero_records_nothing(engine, server, monkeypatch):
+    monkeypatch.setenv("RAY_TPU_TRACE_SAMPLE_N", "0")
+    tracing._reset_sampling()
+    tracing.clear()
+    try:
+        with tracing.span("caller"):
+            server.completions({"prompt": "quiet", "max_tokens": 2})
+        assert tracing.get_spans() == []
+    finally:
+        monkeypatch.delenv("RAY_TPU_TRACE_SAMPLE_N")
+        tracing._reset_sampling()
+
+
+def test_profiler_session_holds_the_engine_loops_annotations(engine, tmp_path):
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracing.annotate("test.window", cell="cpu"):
+            engine.generate("profile me " * 4,
+                            sampling_params=SamplingParams(max_tokens=3, ignore_eos=True))
+            time.sleep(0.05)  # the loop finds nothing to do and sleeps
+    finally:
+        jax.profiler.stop_trace()
+    found = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True)
+    assert found
+    names = set()
+    for plane in ProfileData.from_file(found[0]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(ev.name for ev in line.events if "." in ev.name)
+    assert {"test.window", "engine.pull_waiting", "engine.advance_admissions",
+            "engine.prefill_chunk", "engine.launch_decodes", "engine.decode_launch",
+            "engine.drain", "engine.fetch", "engine.idle_sleep"} <= names
+    # hot-loop spans never reach the ring
+    assert {s["name"] for s in tracing.get_spans() if s["name"].startswith("engine.")} <= {
+        "engine.request", "engine.queue_wait", "engine.prefill", "engine.decode"}
+
+
+def test_tracing_imports_no_jax_in_a_process_that_has_none():
+    code = (
+        "import sys\n"
+        "from ray_tpu.util import tracing\n"
+        "with tracing.annotate('a', x=1):\n"
+        "    pass\n"
+        "with tracing.span('b'):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'tracing imported jax'\n"
+        "assert [s['name'] for s in tracing.get_spans()] == ['b']\n"
+    )
+    env = dict(os.environ, RAY_TPU_TRACE_SAMPLE_N="1")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
+    done = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True,
+                          text=True, timeout=120)
+    assert done.returncode == 0, done.stderr[-2000:]
+
+
+def test_what_nothing_read_is_gone():
+    assert not hasattr(tracing, "set_exporter") and not hasattr(tracing, "traced")
+    assert not hasattr(tracing, "_exporter")

@@ -553,8 +553,10 @@ def test_env_fingerprint_separates_chip_counts():
 
 
 def test_compile_cache_dir_choice(monkeypatch, tmp_path):
-    """JAX_COMPILATION_CACHE_DIR set: JAX's own handling, nothing else is
-    set. Not set: the fixed <checkout>/.jax_cache, never a temporary name."""
+    """JAX_COMPILATION_CACHE_DIR set: JAX's own handling, no directory is
+    set in code. Not set: the fixed <checkout>/.jax_cache, never a temporary
+    name. Either way names and source lines go into the cache key, so that a
+    profile shows this checkout's ``jax.named_scope`` names (PR 26)."""
     import os
 
     import jax
@@ -567,11 +569,51 @@ def test_compile_cache_dir_choice(monkeypatch, tmp_path):
         jax.config, "update", lambda k, v: updates.append((k, v))
     )
     monkeypatch.setenv(jax_cache.ENV_VAR, str(tmp_path))
+    names_in_key = ("jax_compilation_cache_include_metadata_in_key", True)
     assert jax_cache.configure() == str(tmp_path)
-    assert updates == []
+    assert updates == [names_in_key]
+    del updates[:]
     monkeypatch.delenv(jax_cache.ENV_VAR)
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     fixed = os.path.join(repo, ".jax_cache")
     assert jax_cache.configure() == fixed == jax_cache.cache_dir()
-    assert updates == [("jax_compilation_cache_dir", fixed)]
+    assert updates == [("jax_compilation_cache_dir", fixed), names_in_key]
     assert jax.config.jax_compilation_cache_dir == was
+
+
+_CACHED_NAMES = """
+import os, re, sys
+import jax, jax.numpy as jnp
+jax.config.update("jax_platforms", "cpu")
+jax.config.update("jax_compilation_cache_dir", sys.argv[1])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+if sys.argv[3] == "1":
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+def f(x):
+    with jax.named_scope(sys.argv[2]):
+        return jnp.sin(x) @ x
+text = jax.jit(f).lower(jnp.ones((64, 64))).compile().as_text()
+print("NAMED" if re.search(r'op_name="[^"]*/ffn/', text) else "UNNAMED")
+"""
+
+
+@pytest.mark.parametrize("names_in_key", ["0", "1"])
+def test_a_cached_program_keeps_the_names_it_was_compiled_with(tmp_path, names_in_key):
+    """Why ``jax_cache.configure`` puts names into the cache key: the same
+    program under another ``jax.named_scope`` is found again in a cache keyed
+    without them, and comes back with the names of its first compilation."""
+    import os
+    import subprocess
+
+    def run(scope):
+        out = subprocess.run(
+            [sys.executable, "-c", _CACHED_NAMES, str(tmp_path), scope, names_in_key],
+            capture_output=True, text=True, timeout=120, check=True,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        )
+        return out.stdout.split()[-1]
+
+    assert run("before") == "UNNAMED"
+    assert run("ffn") == ("NAMED" if names_in_key == "1" else "UNNAMED")
+
