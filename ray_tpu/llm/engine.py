@@ -373,7 +373,7 @@ class JaxEngine:
                         temp, top_k, key, loras=None, adapter_id=None):
             """Last prompt chunk: prefill it, sample the first generated
             token IN-PROGRAM (no host sync on the admission path), and
-            scatter the finished stripe into the pool slot."""
+            copy the finished stripe into the pool slot."""
             last_logits, one = prefill(
                 params, one, tokens, cfg, lengths=length, start_pos=start,
                 loras=loras, adapter_ids=adapter_id,
@@ -387,11 +387,14 @@ class JaxEngine:
                 }
             with jax.named_scope("sampling"):
                 tok, new_key = sample_row(last_logits[0], temp, top_k, key)
-            return tok, new_key, cache
+            return tok, new_key, cache, one
 
-        # donate only the pool cache: the scratch stripe's shape matches no
-        # output, so donating it just triggers unusable-buffer warnings
-        self._chunk_final_jit = jax.jit(chunk_final, donate_argnums=(1,))
+        # donate the scratch stripe too and hand it back (the caller drops
+        # it): a stripe the program may not overwrite is copied before the
+        # chunk is written into it, and the v5e compiler then moved a whole
+        # 33 MB stripe between memory spaces once a layer (0.64 ms a run at
+        # 7B widths; PERF.md section 6, PR 27)
+        self._chunk_final_jit = jax.jit(chunk_final, donate_argnums=(1, 2))
 
         @jax.named_scope("prefix_seed")
         def seed_prefix(one, pk, pv):
@@ -933,7 +936,7 @@ class JaxEngine:
         slot = adm.slot
         pool.adapter_ids[slot] = req.lora_idx
         self._sync_adapter_ids(pool)
-        first_tok, new_key, pool.cache = self._chunk_final_jit(
+        first_tok, new_key, pool.cache, _ = self._chunk_final_jit(
             self.params, pool.cache, adm.one, t, l, s,
             jnp.int32(slot), temp, topk, req_key, **lora_kw
         )

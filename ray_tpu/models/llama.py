@@ -748,17 +748,71 @@ def init_lora_stack(cfg: LlamaConfig, n_adapters: int, rank: int):
     }
 
 
+# Widest batch whose rows ``_decode_forward`` writes as one contiguous
+# block each. On a v5e (PERF.md section 6, PR 27; a tensor and layer) a block
+# costs about 2 us and 5 ns for each of the row's K*T cache rows (a window
+# read, a select, an in-place ``dynamic_update_slice``: 11 us for a 256-token
+# chunk), the scatter 73-90 ns a cache row (150 us for the same chunk), both
+# linear in B. So the block wins wherever a row brings more than ~32 cache
+# rows, as every prompt chunk does, and loses at decode's T = 1 (B = 32: 69 us
+# against 23). The blocks are unrolled into the layer loop's body and the cap
+# only bounds that program: the engine's scratch stripe has B = 1, the
+# benchmark's probe B = 2; a wider gang batch (``llm/spmd.py``) is scattered.
+_BLOCK_WRITE_MAX_BATCH = 8
+
+
+def _write_block(c_all, new, l, b, start, ok):
+    """Write row ``b``'s new keys or values ``new`` [K, T, D], whose
+    positions are ``start + arange(T)``, into layer ``l`` of the carried
+    cache ``c_all`` [L, B, K, S, D] as ONE contiguous block, leaving exactly
+    the bytes the ``mode="drop"`` scatter leaves.
+
+    The block is the window ``[w, w + T)`` with ``w = min(start, S - T)``
+    computed here: ``dynamic_update_slice`` would clamp a start that runs
+    past the axis and silently shift every row, so the shift is made
+    explicit (``new`` rolled right by ``start - w``) and never left to the
+    clamp. The window's old bytes are read first and kept wherever the
+    scatter wrote nothing: padding (``ok`` [T] false), positions at or past
+    ``S``, and the slots before ``start`` that a shifted window covers."""
+    K, T, D = new.shape
+    S = c_all.shape[3]
+    w = jnp.clip(start, 0, S - T)
+    shift = start - w
+    at = (l, b, 0, w, 0)
+    old = jax.lax.dynamic_slice(c_all, at, (1, 1, K, T, D))
+    keep_new = (jnp.arange(T) >= shift) & jnp.roll(ok, shift)
+    block = jnp.where(
+        keep_new[None, :, None], jnp.roll(new, shift, axis=1), old[0, 0]
+    )
+    return jax.lax.dynamic_update_slice(c_all, block[None, None], at)
+
+
 def _decode_forward(
     params, cache, tokens, positions, cfg: LlamaConfig, valid=None,
     loras=None, adapter_ids=None, with_logits: bool = True,
-    logits_at=None,
+    logits_at=None, start_pos=None,
 ):
     """Shared prefill/decode body. tokens: [B, T]; positions: [B, T].
-    New k/v are scattered into the cache before attention so new tokens
+    New k/v are written into the cache before attention so new tokens
     attend to themselves and to all prior cache slots. ``valid`` [B, T]
-    marks real (non-padding) tokens; padding writes are dropped so later
-    decode steps never attend to stale slots. ``loras``/``adapter_ids``:
-    stacked LoRA adapters + per-sequence adapter index (0 = base).
+    marks real (non-padding) tokens; padding writes leave the cache's old
+    bytes where they are, so later decode steps never attend to stale slots
+    and whatever copies a stripe out (the engine's stripe-to-slot copy, the
+    prefix cache's store, the disaggregated hand-over) carries none.
+
+    Two forms of one write, chosen from what is static at trace time, with
+    the same bytes in the same slots. ``start_pos`` [B] is the caller's word
+    that row ``b``'s positions are ``start_pos[b] + arange(T)`` (every call
+    through ``prefill``): while ``B <= _BLOCK_WRITE_MAX_BATCH`` and the chunk
+    fits the cache (``T <= S``) each row is one contiguous block a tensor and
+    layer (``_write_block``: padding and the stripe's end keep old bytes).
+    Otherwise (``decode_step``: T = 1, every row at an unrelated position;
+    a wide batch) the ``[B, K, T]``-index scatter with ``mode="drop"``.
+    The cache's position axis is never sharded (``llm/spmd.py`` shards the
+    key-value heads), so a block partitions over heads as the scatter does.
+
+    ``loras``/``adapter_ids``: stacked LoRA adapters + per-sequence adapter
+    index (0 = base).
     ``logits_at`` [B]: project the LM head at ONLY this position per
     sequence (returns [B, 1, V]) — prefill needs one next-token
     distribution, and the full [B, T, V] projection is the single biggest
@@ -774,18 +828,33 @@ def _decode_forward(
     qpos = positions[:, :, None]  # [B, T, 1]
     seq_mask = slot <= qpos  # causal over absolute positions
 
-    if valid is not None:
-        # out-of-range index -> dropped by scatter mode='drop'
-        write_pos = jnp.where(valid, positions, S)
+    as_blocks = (
+        start_pos is not None and B <= _BLOCK_WRITE_MAX_BATCH and T <= S
+    )
+    if as_blocks:
+        ok = jnp.ones((B, T), bool) if valid is None else valid
+
+        def write(c_all, new, l):
+            for b in range(B):
+                c_all = _write_block(c_all, new[b], l, b, start_pos[b], ok[b])
+            return c_all
     else:
-        write_pos = positions
-    bi = jnp.arange(B)[:, None, None]
-    ki = jnp.arange(cfg.n_kv_heads)[None, :, None]
-    pi = write_pos[:, None, :]  # [B, 1, T]
+        if valid is not None:
+            # out-of-range index -> dropped by scatter mode='drop'
+            write_pos = jnp.where(valid, positions, S)
+        else:
+            write_pos = positions
+        bi = jnp.arange(B)[:, None, None]
+        ki = jnp.arange(cfg.n_kv_heads)[None, :, None]
+        pi = write_pos[:, None, :]  # [B, 1, T]
+
+        def write(c_all, new, l):
+            return c_all.at[l, bi, ki, pi].set(new, mode="drop")
+
     groups = cfg.n_heads // cfg.n_kv_heads
     scale = cfg.head_dim**-0.5
 
-    # fori_loop with the FULL cache as carry — the per-layer scatter updates
+    # fori_loop with the FULL cache as carry — the per-layer cache writes
     # alias in place (donated buffers), where a lax.scan carrying per-layer
     # cache slices as ys re-materializes the whole cache every step (decode
     # measured 1.6x slower from those copies alone at 3B/B=16 on v5e).
@@ -821,8 +890,8 @@ def _decode_forward(
             # cache is [B, K, S, D]: write the new [B, T, K, D] rows head-major
             kh = k.transpose(0, 2, 1, 3)  # [B, K, T, D]
             vh = v.transpose(0, 2, 1, 3)
-            ck_all = ck_all.at[l, bi, ki, pi].set(kh, mode="drop")
-            cv_all = cv_all.at[l, bi, ki, pi].set(vh, mode="drop")
+            ck_all = write(ck_all, kh, l)
+            cv_all = write(cv_all, vh, l)
 
         with scope("attn_core"):
             ck = ck_all[l]
@@ -907,6 +976,7 @@ def prefill(
         params, cache, tokens, positions, cfg, valid,
         loras=loras, adapter_ids=adapter_ids, with_logits=with_logits,
         logits_at=None if not with_logits else lengths - 1,
+        start_pos=start_pos,
     )
     cache["length"] = start_pos + lengths
     if not with_logits:

@@ -129,3 +129,52 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache, native_kernel
     fn, shapes = KERNELS[name]
     text = _compiled_text(fn, one_chip, *shapes)
     assert "tpu_custom_call" in text, f"{name}: no Pallas kernel in the program"
+
+
+def test_chunk_mid_writes_its_cache_rows_without_a_scatter(one_chip, no_compile_cache):
+    """The engine's ``chunk_mid`` body at the serving cell's widths
+    (Mistral-7B-v0.3, 16 layers, one 1,024-position stripe, a 256-token
+    chunk): the chunk's 2,048 key and value rows a layer go into the cache
+    as contiguous blocks, in place in the layer loop's carried cache. A
+    general scatter there cost 4.8 of the program's 18.2 ms on the chip
+    (PERF.md section 6, PR 27)."""
+    from ray_tpu.models.llama import (
+        LlamaConfig, init_kv_cache, init_params, prefill,
+    )
+
+    cfg = LlamaConfig(
+        vocab_size=32768, d_model=4096, n_layers=16, n_heads=32, n_kv_heads=8,
+        d_ff=14336, max_seq_len=1024, rope_theta=1e6, dtype=jnp.bfloat16,
+    )
+
+    def described(make):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    params = described(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    stripe = described(lambda: init_kv_cache(cfg, 1, 1024))
+    tokens = jax.ShapeDtypeStruct((1, 256), jnp.int32, sharding=one_chip)
+    scalar = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+
+    def chunk_mid(params, stripe, tokens, length, start):
+        _, stripe = prefill(
+            params, stripe, tokens, cfg, lengths=length, start_pos=start,
+            with_logits=False,
+        )
+        return stripe
+
+    text = (
+        jax.jit(chunk_mid, donate_argnums=(1,))
+        .lower(params, stripe, tokens, scalar, scalar)
+        .compile()
+        .as_text()
+    )
+    assert "scatter(" not in text
+    updates = [
+        line for line in text.splitlines()
+        if "dynamic-update-slice(" in line and "bf16[16,1,8,1024,128]" in line
+    ]
+    assert len(updates) == 2, updates
+    assert all("while/body" in line for line in updates), updates

@@ -554,3 +554,75 @@ def test_multi_step_decode_equivalence():
     finally:
         e1.shutdown()
         e2.shutdown()
+
+
+def test_chunked_prefill_to_the_stripes_end_matches_full_forward():
+    """Prompt chunks go into the scratch stripe as contiguous blocks
+    (``models/llama.py _write_block``). Through the engine: a 300-token
+    prompt in five chunks; then two prompts behind a 16-token prefix hit, so
+    every chunk starts off the chunk grid and the final one's bucketed width
+    passes the stripe's end (16 + 7 * 64 + 64 > 512), one of them
+    ``stripe_len - 1`` long. Each returns the tokens ``forward`` gives on the
+    same weights; no path but the block write is reachable from the engine's
+    prefill (B = 1, width <= stripe), so there is no fallback to count."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import forward, init_kv_cache, prefill
+
+    stripe = 512
+    eng = JaxEngine(LLMConfig(
+        model=ModelConfig(model_id="tiny", tokenizer="byte", seed=0),
+        engine=EngineConfig(
+            max_num_seqs=2, max_seq_len=stripe, prefill_chunk=64,
+            prefill_buckets=(16, 32, 64, 128),
+        ),
+    ))
+    try:
+        rng = np.random.default_rng(27)
+        first = [int(t) for t in rng.integers(1, 250, 300)]
+        # share 16 tokens with `first` and differ at the 17th: a hit at the
+        # 16-token bucket and at no wider one
+        def behind_prefix(n):
+            rest = [int(t) for t in rng.integers(1, 250, n - 16)]
+            rest[0] = (first[16] + 1) % 250 + 1
+            return first[:16] + rest
+
+        plans = [
+            (first, 6, 0, 4),
+            (behind_prefix(stripe - 1), 1, 16, 7),
+            (behind_prefix(500), 8, 16, 7),
+        ]
+        mids = finals = 0
+        for ids, n_new, hit, n_mid in plans:
+            out = eng.generate(
+                prompt_token_ids=ids,
+                sampling_params=SamplingParams(
+                    max_tokens=n_new, temperature=0.0, ignore_eos=True
+                ),
+            )
+            assert out.metrics["prefix_hit_tokens"] == hit
+            seq = list(ids)
+            for _ in range(n_new):
+                logits = forward(
+                    eng.params, jnp.asarray([seq], jnp.int32), eng.model_cfg
+                )
+                seq.append(int(jnp.argmax(logits[0, -1])))
+            assert out.token_ids == seq[len(ids):]
+            # a tiny model's argmax hardly feels a misplaced key: read the
+            # slot's keys and values back, against the prompt in one piece
+            n = len(ids)
+            _, ref = prefill(
+                eng.params, init_kv_cache(eng.model_cfg, 1, stripe),
+                jnp.asarray([ids], jnp.int32), eng.model_cfg,
+            )
+            slot = out.metrics["slot"]
+            for key in ("k", "v"):
+                np.testing.assert_allclose(
+                    np.asarray(eng._pools[0].cache[key][:, slot, :, :n]),
+                    np.asarray(ref[key][:, 0, :, :n]), rtol=2e-2, atol=2e-2,
+                )
+            mids, finals = mids + n_mid, finals + 1
+            chunks = eng.get_stats()["counters"]["prefill_chunks"]
+            assert chunks == {"mid": mids, "final": finals}
+    finally:
+        eng.shutdown()

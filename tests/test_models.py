@@ -352,3 +352,141 @@ def test_optimizer_moments_take_their_parameters_sharding():
         assert adam.nu[name].sharding == p.sharding, name
         sharded += len({s.device for s in adam.mu[name].addressable_shards}) == 4
     assert sharded >= 8
+
+
+# -- the prompt chunk's cache write: contiguous blocks against the scatter ----
+
+# the chunk under test: the cache's stripe, the chunk's padded width T, each
+# row's (start, length); `decode`: greedy decode steps run after it
+KV_WRITE_CASES = {
+    "first_chunk_at_0": dict(stripe=128, T=32, rows=[(0, 32)]),
+    "middle_chunk_at_256": dict(
+        stripe=1024, T=256, rows=[(256, 256)], with_logits=False
+    ),
+    "final_chunk_padded_then_decode": dict(
+        stripe=128, T=64, rows=[(32, 44)], decode=4
+    ),
+    "prefix_hit_start_10": dict(stripe=128, T=32, rows=[(10, 32)]),
+    "bucket_passes_stripe_end": dict(
+        stripe=128, T=64, rows=[(100, 27)], decode=1
+    ),
+    "valid_rows_past_stripe_end_dropped": dict(
+        stripe=128, T=64, rows=[(100, 64)]
+    ),
+    "start_at_or_past_stripe_end": dict(
+        stripe=128, T=32, rows=[(128, 32)]
+    ),
+    "batch_of_two_as_compare_py": dict(
+        stripe=516, T=512, rows=[(0, 96), (0, 300)], decode=4
+    ),
+    "batch_wider_than_block_cap_scatters": dict(
+        stripe=64, T=16, rows=[(i, 16 - i) for i in range(9)], decode=1
+    ),
+    "moe": dict(
+        cfg_kw=dict(moe_experts=4, moe_top_k=2, moe_capacity_factor=8.0),
+        stripe=128, T=64, rows=[(32, 44)], decode=2,
+    ),
+    "lora": dict(
+        lora=True, stripe=128, T=64, rows=[(32, 44), (10, 64)], decode=2
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KV_WRITE_CASES))
+def test_prefill_block_write_equals_scatter(name):
+    """``prefill`` writes each row's keys and values as one contiguous block;
+    the cache and the logits must equal, exactly, what the ``mode="drop"``
+    scatter leaves (``_decode_forward`` without ``start_pos``): padding and
+    rows past the stripe's end keep the cache's old bytes, a window that
+    would pass the stripe's end is not shifted by the clamp, and decode steps
+    after it read the same slots."""
+    from ray_tpu.models.llama import (
+        _BLOCK_WRITE_MAX_BATCH, _decode_forward, init_lora_stack,
+    )
+
+    case = KV_WRITE_CASES[name]
+    cfg = LlamaConfig.tiny(**case.get("cfg_kw", {}))
+    params = init_params(jax.random.PRNGKey(11), cfg)
+    rng = np.random.default_rng(11)
+    S, T, rows = case["stripe"], case["T"], case["rows"]
+    B = len(rows)
+    start = jnp.asarray([r[0] for r in rows], jnp.int32)
+    lengths = jnp.asarray([r[1] for r in rows], jnp.int32)
+    with_logits = case.get("with_logits", True)
+    lora_kw = {}
+    if case.get("lora"):
+        stack = init_lora_stack(cfg, 2, 4)
+        lora_kw = dict(
+            loras={
+                k: jnp.asarray(rng.normal(0, 0.1, v.shape), v.dtype)
+                for k, v in stack.items()
+            },
+            adapter_ids=jnp.asarray([1, 2][:B], jnp.int32),
+        )
+
+    # old bytes everywhere: what a write must keep is seen only if it differs
+    # from zero. Slots under `start` stand for the earlier chunks or prefix.
+    shape = init_kv_cache(cfg, B, S)["k"].shape
+    cache = {
+        "k": jnp.asarray(rng.normal(0, 1, shape), cfg.dtype),
+        "v": jnp.asarray(rng.normal(0, 1, shape), cfg.dtype),
+        "length": start,
+    }
+    tokens = jnp.asarray(rng.integers(1, cfg.vocab_size, (B, T)), jnp.int32)
+
+    def scatter_prefill(params, cache, tokens, lengths, start):
+        rel = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
+        logits, out = _decode_forward(
+            params, dict(cache), tokens, rel + start[:, None], cfg,
+            rel < lengths[:, None], with_logits=with_logits,
+            logits_at=lengths - 1 if with_logits else None, **lora_kw,
+        )
+        out["length"] = start + lengths
+        return (logits[:, 0] if with_logits else None), out
+
+    def block_prefill(params, cache, tokens, lengths, start):
+        return prefill(
+            params, dict(cache), tokens, cfg, lengths=lengths,
+            start_pos=start, with_logits=with_logits, **lora_kw,
+        )
+
+    text = str(jax.make_jaxpr(block_prefill)(params, cache, tokens, lengths, start))
+    if B <= _BLOCK_WRITE_MAX_BATCH:
+        assert "scatter" not in text and "dynamic_update_slice" in text
+    else:
+        assert "scatter" in text
+    assert "scatter" in str(
+        jax.make_jaxpr(scatter_prefill)(params, cache, tokens, lengths, start)
+    )
+
+    ref_logits, ref = jax.jit(scatter_prefill)(params, cache, tokens, lengths, start)
+    got_logits, got = jax.jit(block_prefill)(params, cache, tokens, lengths, start)
+    for key in ("k", "v", "length"):
+        np.testing.assert_array_equal(np.asarray(got[key]), np.asarray(ref[key]))
+    # the write did something, and kept the old bytes past each row's length
+    assert not np.array_equal(np.asarray(got["k"]), np.asarray(cache["k"])) or all(
+        s >= S for s, _ in rows
+    )
+    for b, (s, n) in enumerate(rows):
+        np.testing.assert_array_equal(
+            np.asarray(got["k"][:, b, :, min(s + n, S):]),
+            np.asarray(cache["k"][:, b, :, min(s + n, S):]),
+        )
+        np.testing.assert_array_equal(
+            np.asarray(got["v"][:, b, :, :min(s, S)]),
+            np.asarray(cache["v"][:, b, :, :min(s, S)]),
+        )
+    if with_logits:
+        np.testing.assert_array_equal(np.asarray(got_logits), np.asarray(ref_logits))
+        nxt = jnp.argmax(ref_logits, -1)
+    else:
+        assert got_logits is None
+        nxt = tokens[:, 0]
+    dec = jax.jit(lambda p, c, t: decode_step(p, c, t, cfg, **lora_kw))
+    for _ in range(case.get("decode", 0)):
+        ref_step, ref = dec(params, ref, nxt)
+        got_step, got = dec(params, got, nxt)
+        np.testing.assert_array_equal(np.asarray(got_step), np.asarray(ref_step))
+        for key in ("k", "v", "length"):
+            np.testing.assert_array_equal(np.asarray(got[key]), np.asarray(ref[key]))
+        nxt = jnp.argmax(ref_step, -1)
