@@ -82,13 +82,21 @@ class EngineConfig:
 
 @dataclasses.dataclass
 class ModelConfig:
-    model_id: str = "tiny"  # "tiny" | "llama2-7b" | "llama3-8b" | path
+    # a preset of ``resolve_llama_config``: "tiny" | "llama2-7b" |
+    # "llama3-8b" | "llama3.2-3b" | "llama3-70b" | "laguna-xs.2" |
+    # "laguna-tiny"
+    model_id: str = "tiny"
     tokenizer: str = "byte"  # "byte" | transformers tokenizer path
     checkpoint_path: Optional[str] = None  # ray_tpu.train pytree checkpoint
     seed: int = 0
-    # extra LlamaConfig overrides applied on top of the preset — e.g.
-    # {"moe_experts": 8, "moe_top_k": 2} serves a MoE variant (the engine
-    # decode path is dropless, models/llama.py:_moe_decode_ffn)
+    # extra LlamaConfig overrides applied on top of the preset, any field of
+    # it — e.g. {"moe_experts": 8, "moe_top_k": 2} serves a MoE variant (the
+    # serving path is dropless: tokens sorted by expert through a grouped
+    # matmul, at every batch size, models/llama.py:_moe_decode_ffn;
+    # "moe_shared_d_ff" adds a shared expert, "moe_routed_scale" scales the
+    # routed sum). The laguna presets' layers are not alike: a caller that
+    # sets "n_layers" sets "layer_types", "heads_per_layer" and "mlp_types"
+    # to that length too, or gets the pattern's first entries
     model_kwargs: dict = dataclasses.field(default_factory=dict)
 
 
@@ -109,6 +117,8 @@ def resolve_llama_config(model: "ModelConfig", engine: "EngineConfig", min_vocab
         "llama3-8b": LlamaConfig.llama3_8b,
         "llama3.2-3b": LlamaConfig.llama32_3b,
         "llama3-70b": LlamaConfig.llama3_70b,
+        "laguna-xs.2": LlamaConfig.laguna_xs2,
+        "laguna-tiny": LlamaConfig.laguna_tiny,
     }
     kw = dict(
         max_seq_len=engine.max_seq_len,

@@ -45,6 +45,9 @@ logger = logging.getLogger(__name__)
 # the ``/metrics`` scrape shows them). A name with a ``:`` is one label of a
 # family: ``requests_failed:decode`` is ``requests_failed`` with stage
 # ``decode``.
+_MOE_COUNTERS = ("moe_layer_steps", "moe_assignments", "moe_experts_touched",
+                 "moe_max_expert_load_sum")
+_MOE_PROGRAMS = ("decode", "chunk_mid", "chunk_final")
 COUNTERS = (
     "requests_submitted",
     "requests_finished:stop", "requests_finished:length",
@@ -59,9 +62,20 @@ COUNTERS = (
     "decode_steps", "decode_slot_steps",  # slot_steps: sum of active slots
     "prefill_chunks:mid", "prefill_chunks:final",
     "loop_passes", "loop_idle_sleeps",
+    # keys and values a decode step has to read: over decode steps and active
+    # slots, the slot's length, and what a sliding-window layer needs of it
+    # (min(length, window); stays 0 where the model has no window)
+    "decode_kv_tokens_global", "decode_kv_tokens_window",
+    # routed experts (``models/llama.py MOE_STATS``), summed over expert
+    # layers and over the runs of each program: the decode program hands its
+    # counts out beside its tokens, a prompt's middle chunks add theirs up on
+    # the device and its final chunk hands both out beside the first token,
+    # fetched with them. Every row a program routes counts, a dead slot's and
+    # a padded chunk's too: they touch experts as live ones do
+    *(f"{name}:{program}" for name in _MOE_COUNTERS for program in _MOE_PROGRAMS),
 )
 _LABEL = {"requests_finished": "reason", "requests_failed": "stage",
-          "prefill_chunks": "kind"}
+          "prefill_chunks": "kind", **dict.fromkeys(_MOE_COUNTERS, "program")}
 # request latencies: 1 ms to 200 s, a quarter more each bucket, so a median
 # read from the bucket counts is within an eighth of the truth
 LATENCY_BOUNDS = tuple(1e-3 * 1.25**i for i in range(56))
@@ -286,6 +300,9 @@ class JaxEngine:
         if ec.max_loras > 0:
             from ray_tpu.models.llama import init_lora_stack
 
+            if self.model_cfg.layer_types:
+                raise ValueError("LoRA adapters need layers that are alike")
+
             self.loras = init_lora_stack(
                 self.model_cfg, ec.max_loras, ec.lora_rank
             )
@@ -303,7 +320,16 @@ class JaxEngine:
         # sampler — they must agree or seeded runs diverge at token 2
         self._top_k_static = K = min(64, cfg.vocab_size)
 
-        lora_enabled = self.loras is not None
+        # a model with routed experts: each program takes a zeroed
+        # ``moe_stats`` leaf in with its cache and hands the counts out
+        # beside its tokens (``models/llama.py _ride_stats``). A dense
+        # model's programs hand out ``None`` there, which is no output.
+        routed = self._routed = bool(cfg.moe_experts)
+
+        def stats_in(cache):
+            if not routed:
+                return cache
+            return dict(cache, moe_stats=jnp.zeros((len(_MOE_COUNTERS),), jnp.int32))
 
         def sample_row(logits_row, temp, top_k, key):
             """Sample one token from [V] fp32 logits: greedy where temp<=0,
@@ -324,14 +350,15 @@ class JaxEngine:
             """Decode + in-program sampling with per-slot PRNG keys
             (per-request seeds stay reproducible across batch compositions)."""
             logits, cache = decode_step(
-                params, cache, tokens, cfg,
+                params, stats_in(cache), tokens, cfg,
                 loras=loras, adapter_ids=adapter_ids,
             )
+            stats = cache.pop("moe_stats", None)
             with jax.named_scope("sampling"):
                 next_tokens, new_keys = jax.vmap(sample_row)(
                     logits, temps, top_ks, keys
                 )
-            return next_tokens, cache, new_keys
+            return next_tokens, cache, new_keys, stats
 
         self._decode_jit = jax.jit(decode_fn, donate_argnums=(1,))
 
@@ -343,16 +370,18 @@ class JaxEngine:
             trip per K tokens."""
             def body(carry, _):
                 toks, cache, keys = carry
-                nt, cache, keys = decode_fn(
+                nt, cache, keys, stats = decode_fn(
                     params, cache, toks, temps, top_ks, keys,
                     loras=loras, adapter_ids=adapter_ids,
                 )
-                return (nt, cache, keys), nt
+                return (nt, cache, keys), (nt, stats)
 
-            (toks, cache, keys), out = jax.lax.scan(
+            (toks, cache, keys), (out, stats) = jax.lax.scan(
                 body, (tokens, cache, keys), None, length=n_steps
             )
-            return out, cache, keys  # out: [K, slots]
+            if stats is not None:
+                stats = stats.sum(axis=0)
+            return out, cache, keys, stats  # out: [K, slots]
 
         self._decode_multi_jit = jax.jit(decode_multi, donate_argnums=(1,))
         self._decode_n_steps = n_steps
@@ -374,10 +403,14 @@ class JaxEngine:
             """Last prompt chunk: prefill it, sample the first generated
             token IN-PROGRAM (no host sync on the admission path), and
             copy the finished stripe into the pool slot."""
+            mid_stats = one.get("moe_stats")  # the prompt's middle chunks'
             last_logits, one = prefill(
                 params, one, tokens, cfg, lengths=length, start_pos=start,
                 loras=loras, adapter_ids=adapter_id,
             )
+            stats = one.pop("moe_stats", None)
+            if stats is not None:  # rows: chunk_mid, chunk_final
+                stats = jnp.stack([mid_stats, stats - mid_stats])
             total = start[0] + length[0]
             with jax.named_scope("kv_write"):
                 cache = {
@@ -387,7 +420,7 @@ class JaxEngine:
                 }
             with jax.named_scope("sampling"):
                 tok, new_key = sample_row(last_logits[0], temp, top_k, key)
-            return tok, new_key, cache, one
+            return tok, new_key, cache, one, stats
 
         # donate the scratch stripe too and hand it back (the caller drops
         # it): a stripe the program may not overwrite is copied before the
@@ -401,9 +434,9 @@ class JaxEngine:
             """Copy a cached prefix KV [L, K, m, D] into the scratch stripe."""
             m = pk.shape[2]
             return {
+                **one,
                 "k": one["k"].at[:, 0, :, :m].set(pk),
                 "v": one["v"].at[:, 0, :, :m].set(pv),
-                "length": one["length"],
             }
 
         self._seed_prefix_jit = jax.jit(seed_prefix, donate_argnums=(0,))
@@ -417,7 +450,8 @@ class JaxEngine:
         self._rng_key = jax.random.PRNGKey(self.config.model.seed)
 
     def _decode(self, pool: _Pool, tokens, temps, top_ks, keys):
-        """Returns ([K, slots] tokens, cache, keys) — K = decode_steps."""
+        """Returns ([K, slots] tokens, cache, keys, routing counts or None)
+        — K = decode_steps."""
         fn = (
             self._decode_multi_jit
             if self._decode_n_steps > 1
@@ -425,17 +459,17 @@ class JaxEngine:
         )
         if self.loras is None:
             # no-LoRA configuration: the compiled program has no adapter args
-            out, cache, keys = fn(
+            out, cache, keys, stats = fn(
                 self.params, pool.cache, tokens, temps, top_ks, keys
             )
         else:
-            out, cache, keys = fn(
+            out, cache, keys, stats = fn(
                 self.params, pool.cache, tokens, temps, top_ks, keys,
                 loras=self.loras, adapter_ids=pool.adapter_ids_dev,
             )
         if self._decode_n_steps == 1:
             out = out[None]  # unify to [K, slots]
-        return out, cache, keys
+        return out, cache, keys, stats
 
     def _lora_kw(self, adapter_id: int) -> dict:
         import jax.numpy as jnp
@@ -867,6 +901,8 @@ class JaxEngine:
     def _start_admission(self, pool: "_Pool", slot: int, req: _Request) -> None:
         """Build the chunked-prefill plan for a slot (device work starts on
         the next _advance_admissions pass)."""
+        import jax.numpy as jnp
+
         from ray_tpu.models.llama import init_kv_cache
 
         req.admitted_t = time.time()
@@ -901,6 +937,9 @@ class JaxEngine:
             chunks.append((toks, len(piece), start, is_final))
             start += len(piece)
         one = init_kv_cache(self.model_cfg, 1, pool.stripe_len)
+        if self._routed:
+            # the prompt's chunks add their routing counts up in here
+            one["moe_stats"] = jnp.zeros((len(_MOE_COUNTERS),), jnp.int32)
         if prefix is not None:
             with tracing.annotate("engine.prefix_seed", tokens=m):
                 one = self._seed_prefix_jit(one, prefix["k"], prefix["v"])
@@ -936,7 +975,7 @@ class JaxEngine:
         slot = adm.slot
         pool.adapter_ids[slot] = req.lora_idx
         self._sync_adapter_ids(pool)
-        first_tok, new_key, pool.cache, _ = self._chunk_final_jit(
+        first_tok, new_key, pool.cache, _, stats = self._chunk_final_jit(
             self.params, pool.cache, adm.one, t, l, s,
             jnp.int32(slot), temp, topk, req_key, **lora_kw
         )
@@ -955,9 +994,11 @@ class JaxEngine:
             self._prefix_store(pool, slot, req.prompt_token_ids)
         try:
             first_tok.copy_to_host_async()
+            if stats is not None:
+                stats.copy_to_host_async()
         except Exception:  # noqa: BLE001 — platform without async copy
             pass
-        pool.first_pending.append((slot, req, first_tok))
+        pool.first_pending.append((slot, req, first_tok, stats))
 
     def _fail_admission(
         self, pool: "_Pool", adm: _Admission, e: BaseException,
@@ -1048,7 +1089,7 @@ class JaxEngine:
                     "engine.decode_launch", pool=pool.stripe_len,
                     active=len(active),
                 ):
-                    out, pool.cache, pool.keys = self._decode(
+                    out, pool.cache, pool.keys, stats = self._decode(
                         pool,
                         pool.dev_tokens,
                         jnp.asarray(pool.temps),
@@ -1058,11 +1099,22 @@ class JaxEngine:
                     pool.dev_tokens = out[-1]
                     try:
                         out.copy_to_host_async()
+                        if stats is not None:
+                            stats.copy_to_host_async()
                     except Exception:  # noqa: BLE001
                         pass
-                pool.inflight.append((out, active))
+                pool.inflight.append((out, active, stats))
                 self._n["decode_steps"] += self._decode_n_steps
                 self._n["decode_slot_steps"] += self._decode_n_steps * len(active)
+                lengths = [
+                    len(r.prompt_token_ids) + len(r.out_tokens) for r in active.values()
+                ]
+                self._n["decode_kv_tokens_global"] += self._decode_n_steps * sum(lengths)
+                window = self.model_cfg.sliding_window
+                if window:  # a model without one has no window layers to count for
+                    self._n["decode_kv_tokens_window"] += self._decode_n_steps * sum(
+                        min(n, window) for n in lengths
+                    )
                 launched = True
             except BaseException as e:  # noqa: BLE001 — device failure
                 self._fail_pool(pool, e)
@@ -1104,10 +1156,11 @@ class JaxEngine:
         for pool in self._pools:
             if pool.first_pending:
                 pending, pool.first_pending = pool.first_pending, []
-                for slot, req, tok in pending:
+                for slot, req, tok, stats in pending:
                     try:
                         with tracing.annotate("engine.fetch", what="first_token"):
                             t = int(np.asarray(tok))
+                            self._count_routing(stats, ("chunk_mid", "chunk_final"))
                     except BaseException as e:  # noqa: BLE001
                         self._fail_pool(pool, e)
                         break
@@ -1120,10 +1173,11 @@ class JaxEngine:
             has_active = any(r is not None for r in pool.slots)
             keep = runahead if has_active else 0
             while len(pool.inflight) > keep:
-                out, binding = pool.inflight.popleft()
+                out, binding, stats = pool.inflight.popleft()
                 try:
                     with tracing.annotate("engine.fetch", what="decode"):
                         arr = np.asarray(out)  # [K, slots]
+                        self._count_routing(stats, ("decode",))
                 except BaseException as e:  # noqa: BLE001
                     self._fail_pool(pool, e)
                     break
@@ -1140,6 +1194,16 @@ class JaxEngine:
                     req.pacer.note_block(n)
                 progressed = True
         return progressed
+
+    def _count_routing(self, stats, programs: tuple) -> None:
+        """Fold routing counts (None for a dense model; a row a program in
+        ``programs``) into the counters. Called inside the fetch of the tokens
+        they came out beside; their own copy to the host was started with the
+        tokens', so this waits for nothing the tokens did not wait for."""
+        if stats is not None:
+            for program, row in zip(programs, np.atleast_2d(np.asarray(stats))):
+                for name, value in zip(_MOE_COUNTERS, row):
+                    self._n[f"{name}:{program}"] += int(value)
 
     def _engine_loop(self):
         import jax

@@ -87,27 +87,51 @@ class LlamaConfig:
     # microbatch count for the GPipe schedule when the mesh has pp>1;
     # 0 = default 2*pp. Layers split into pp equal stages.
     pp_microbatches: int = 0
+    # width of one attention head; 0 = d_model // n_heads
+    head_width: int = 0
+    # expert width (0 = d_ff), a shared expert beside the routed ones (its
+    # width; 0 = none), and the factor on the routed experts' sum
+    moe_d_ff: int = 0
+    moe_shared_d_ff: int = 0
+    moe_routed_scale: float = 1.0
+    # --- layers that are not alike (``models/patterned.py`` runs them) ---
+    # ``layer_types``: 'full' | 'sliding' for each layer; () = every layer as
+    # this file has it, and none of the fields below is read. A sliding
+    # layer's query at position i sees keys j with 0 <= i - j < window.
+    layer_types: tuple = ()
+    heads_per_layer: tuple = ()  # query heads, one count an attention kind
+    mlp_types: tuple = ()  # 'dense' | 'sparse' (the moe_* fields) by layer
+    sliding_window: int = 0
+    # sliding layers rotate the whole head at this theta; full layers rotate
+    # the first ``rope_partial`` of it at ``rope_theta``, with YaRN inverse
+    # frequencies where ``yarn_factor`` is set (cos and sin times
+    # ``yarn_attention_factor``)
+    rope_theta_sliding: float = 10000.0
+    rope_partial: float = 1.0
+    yarn_factor: float = 0.0
+    yarn_original_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0
+    # sigmoid of a [d_model, heads] projection of the layer's normed input,
+    # times each head's attention output before wo
+    attn_gate: bool = False
+
+    def __post_init__(self):
+        for name in ("layer_types", "heads_per_layer", "mlp_types"):
+            value = tuple(getattr(self, name))
+            object.__setattr__(self, name, value)
+            if self.layer_types and len(value) != self.n_layers:
+                raise ValueError(
+                    f"{name} has {len(value)} entries for n_layers={self.n_layers}"
+                )
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_width or self.d_model // self.n_heads
 
     def num_params(self) -> int:
-        e, f, v = self.d_model, self.d_ff, self.vocab_size
-        h, kv, hd = self.n_heads, self.n_kv_heads, self.head_dim
-        if self.moe_experts:
-            ffn = e * self.moe_experts + self.moe_experts * 3 * e * f
-        else:
-            ffn = 3 * e * f  # w1, w3 (gate/up) + w2 (down)
-        per_layer = (
-            e * h * hd  # wq
-            + 2 * e * kv * hd  # wk, wv
-            + h * hd * e  # wo
-            + ffn
-            + 2 * e  # norms
-        )
-        out_head = 0 if self.tie_embeddings else v * e
-        return v * e + self.n_layers * per_layer + e + out_head
+        return sum(math.prod(shape) for shape in _param_shapes(self).values())
 
     # ---- presets ----
     @staticmethod
@@ -187,6 +211,70 @@ class LlamaConfig:
         d.update(kw)
         return LlamaConfig(**d)
 
+    @staticmethod
+    def laguna_xs2(**kw) -> "LlamaConfig":
+        """poolside Laguna-XS.2 (33B-A3B) as its config.json has it: layer 0
+        full attention with a dense feed-forward, then sliding, sliding,
+        sliding, full with 256 experts of width 512, 8 a token, and one
+        shared expert. A caller that cuts ``n_layers`` gets the first
+        entries of the three per-layer lists unless it gives its own.
+        Inferred, not in the config: the gate is one scalar a head
+        (``gating: true``), the router a float32 softmax, top 8 renormalised,
+        the shared expert ungated, no query/key norm."""
+        d = dict(
+            vocab_size=100352,
+            d_model=2048,
+            n_layers=40,
+            n_heads=48,
+            n_kv_heads=8,
+            head_width=128,
+            d_ff=8192,
+            max_seq_len=262144,
+            rms_eps=1e-6,
+            rope_theta=500000.0,
+            rope_partial=0.5,
+            yarn_factor=64.0,
+            yarn_original_len=4096,
+            yarn_beta_fast=64.0,
+            yarn_beta_slow=1.0,
+            yarn_attention_factor=1.4158883083359672,
+            rope_theta_sliding=10000.0,
+            sliding_window=512,
+            attn_gate=True,
+            moe_experts=256,
+            moe_top_k=8,
+            moe_d_ff=512,
+            moe_shared_d_ff=512,
+            moe_routed_scale=2.5,
+        )
+        d.update(kw)
+        n = d["n_layers"]
+        d.setdefault("layer_types", tuple(
+            "full" if i % 4 == 0 else "sliding" for i in range(n)))
+        d.setdefault("heads_per_layer", tuple(
+            48 if t == "full" else 64 for t in d["layer_types"]))
+        d.setdefault("mlp_types", ("dense",) + ("sparse",) * (n - 1))
+        return LlamaConfig(**d)
+
+    @staticmethod
+    def laguna_tiny(**kw) -> "LlamaConfig":
+        """Test-size model with ``laguna_xs2``'s pattern: a leading full,
+        dense layer and one period (sliding x3, full) of expert layers."""
+        d = dict(
+            vocab_size=256, d_model=64, n_layers=5, n_heads=6, n_kv_heads=2,
+            head_width=16, d_ff=128, max_seq_len=128, dtype=jnp.float32,
+            remat=False, rms_eps=1e-6, rope_theta=500000.0, rope_partial=0.5,
+            yarn_factor=4.0, yarn_original_len=16, yarn_beta_fast=8.0,
+            yarn_beta_slow=1.0, yarn_attention_factor=1.2,
+            rope_theta_sliding=10000.0, sliding_window=8, attn_gate=True,
+            moe_experts=16, moe_top_k=4, moe_d_ff=32, moe_shared_d_ff=32,
+            moe_routed_scale=2.5,
+            layer_types=("full", "sliding", "sliding", "sliding", "full"),
+            heads_per_layer=(6, 8, 8, 8, 6),
+            mlp_types=("dense", "sparse", "sparse", "sparse", "sparse"),
+        )
+        d.update(kw)
+        return LlamaConfig(**d)
 
 # Logical dims per parameter (leading 'layer' dim on stacked block params).
 _PARAM_DIMS = {
@@ -207,7 +295,16 @@ _PARAM_DIMS = {
     "moe_w_gate": (None, "expert", "embed", "mlp"),
     "moe_w_up": (None, "expert", "embed", "mlp"),
     "moe_w_down": (None, "expert", "mlp", "embed"),
+    "moe_shared_gate": (None, "embed", "mlp"),
+    "moe_shared_up": (None, "embed", "mlp"),
+    "moe_shared_down": (None, "mlp", "embed"),
 }
+# layers that are not alike (models/patterned.py): the leaves whose shape
+# follows the attention kind are stacked one kind at a time
+for _kind in ("full", "sliding"):
+    _PARAM_DIMS["wq_" + _kind] = _PARAM_DIMS["wq"]
+    _PARAM_DIMS["wo_" + _kind] = _PARAM_DIMS["wo"]
+    _PARAM_DIMS["wg_" + _kind] = (None, "embed", "heads")
 
 
 def param_logical_dims(path, leaf):
@@ -225,7 +322,30 @@ def param_shardings(cfg: LlamaConfig, mesh: Mesh, rules=None):
     }
 
 
+def _moe_shapes(cfg: LlamaConfig, n: int) -> dict[str, tuple]:
+    """The leaves of ``n`` stacked expert layers."""
+    e, E, f = cfg.d_model, cfg.moe_experts, cfg.moe_d_ff or cfg.d_ff
+    shapes = {
+        "moe_router": (n, e, E),
+        "moe_w_gate": (n, E, e, f),
+        "moe_w_up": (n, E, e, f),
+        "moe_w_down": (n, E, f, e),
+    }
+    if cfg.moe_shared_d_ff:
+        fs = cfg.moe_shared_d_ff
+        shapes.update({
+            "moe_shared_gate": (n, e, fs),
+            "moe_shared_up": (n, e, fs),
+            "moe_shared_down": (n, fs, e),
+        })
+    return shapes
+
+
 def _param_shapes(cfg: LlamaConfig) -> dict[str, tuple]:
+    if cfg.layer_types:
+        from ray_tpu.models.patterned import param_shapes
+
+        return param_shapes(cfg)
     e, f, v = cfg.d_model, cfg.d_ff, cfg.vocab_size
     h, kv, hd, L = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.n_layers
     shapes = {
@@ -239,15 +359,7 @@ def _param_shapes(cfg: LlamaConfig) -> dict[str, tuple]:
         "mlp_norm": (L, e),
     }
     if cfg.moe_experts:
-        E = cfg.moe_experts
-        shapes.update(
-            {
-                "moe_router": (L, e, E),
-                "moe_w_gate": (L, E, e, f),
-                "moe_w_up": (L, E, e, f),
-                "moe_w_down": (L, E, f, e),
-            }
-        )
+        shapes.update(_moe_shapes(cfg, L))
     else:
         shapes.update(
             {"w_gate": (L, e, f), "w_up": (L, e, f), "w_down": (L, f, e)}
@@ -260,7 +372,7 @@ def _param_shapes(cfg: LlamaConfig) -> dict[str, tuple]:
 def _layer_keys(cfg: LlamaConfig) -> tuple:
     base = ("wq", "wk", "wv", "wo", "attn_norm", "mlp_norm")
     if cfg.moe_experts:
-        return base + ("moe_router", "moe_w_gate", "moe_w_up", "moe_w_down")
+        return base + tuple(_moe_shapes(cfg, 1))
     return base + ("w_gate", "w_up", "w_down")
 
 
@@ -274,18 +386,20 @@ def init_params(key, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
     # as shape[-2], q/k/v came out 11-20x too large at Llama-3.2-3B widths,
     # the softmax saturated, and the gradient norm grew ~140x every two
     # layers: 158 at 2 layers, 8.7e7 at 8, on the chip.)
-    attn_fan_in = {
-        "wq": cfg.d_model, "wk": cfg.d_model, "wv": cfg.d_model,
-        "wo": cfg.n_heads * cfg.head_dim,
-    }
+    # (``wq_full``, ``wo_sliding``: a patterned model's leaves by kind)
+    def fan_in_of(name, shape):
+        if name.startswith(("wq", "wk", "wv")):
+            return cfg.d_model
+        if name.startswith("wo"):
+            return shape[-3] * shape[-2]
+        return shape[-2] if len(shape) > 1 else shape[0]
+
     params = {}
     for (name, shape), k in zip(sorted(shapes.items()), keys):
         if "norm" in name:
             maker = lambda shape=shape: jnp.ones(shape, cfg.dtype)
         else:
-            fan_in = attn_fan_in.get(
-                name, shape[-2] if len(shape) > 1 else shape[0]
-            )
+            fan_in = fan_in_of(name, shape)
             std = fan_in**-0.5
             maker = lambda k=k, shape=shape, std=std: (
                 jax.random.normal(k, shape, jnp.float32) * std
@@ -483,7 +597,19 @@ def _moe_ffn(p, h, cfg: LlamaConfig, mesh: Optional[Mesh]):
             top_k=cfg.moe_top_k,
             capacity_factor=cfg.moe_capacity_factor,
         )
-    return y.reshape(B, T, e).astype(h.dtype), aux
+    y = y.reshape(B, T, e).astype(h.dtype)
+    if cfg.moe_routed_scale != 1.0:
+        y = y * cfg.moe_routed_scale
+    if cfg.moe_shared_d_ff:
+        y = y + _shared_expert(p, h)
+    return y, aux
+
+
+def _shared_expert(p, h):
+    """The expert every token passes through, ungated. h: [..., e]."""
+    with scope("shared_expert"):
+        ff = jax.nn.silu(h @ p["moe_shared_gate"]) * (h @ p["moe_shared_up"])
+        return ff @ p["moe_shared_down"]
 
 
 @scope("embed")
@@ -516,6 +642,11 @@ def forward_hidden(
     layer stack runs as a GPipe pipeline over the pp axis
     (``parallel/pipeline.py`` — native PP where the reference only passes
     ``pipeline_parallel_size`` to vLLM, ``vllm_models.py:176-190``)."""
+    if cfg.layer_types:
+        from ray_tpu.models.patterned import forward_hidden as patterned_hidden
+
+        x = patterned_hidden(params, tokens, cfg, mesh, positions)
+        return (x, jnp.zeros((), jnp.float32)) if with_aux else x
     custom_positions = positions is not None
     if positions is None:
         positions = jnp.broadcast_to(
@@ -670,49 +801,86 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
 # ---------------------------------------------------------------------------
 
 
-def _moe_decode_ffn(p, h, cfg: LlamaConfig):
-    """Dropless routed expert FFN for the serving path. h: [B, T, e].
+# Rows of one call's routing counts (``_moe_decode_ffn``; summed over expert
+# layers by the caller): expert layers run, (token, expert) assignments,
+# experts that got at least one token, and the fullest expert's tokens.
+MOE_STATS = ("layer_steps", "assignments", "experts_touched", "max_expert_load")
+
+
+def _moe_decode_ffn(params, row, h, cfg: LlamaConfig):
+    """Dropless routed expert FFN for the serving path, and for ``forward``
+    of a model whose layers are not alike. ``params`` holds the stacked
+    ``moe_*`` leaves, ``row`` (static or traced) is this layer's row in them.
+    h: [B, T, e] -> ([B, T, e], routing counts int32 [4], ``MOE_STATS``).
 
     Inference must never drop tokens (a capacity overflow at prefill would
     silently corrupt the prompt — the reference's serving engine is likewise
     dropless), so instead of the training path's capacity buffers
-    (``parallel/moe.py``) this computes every expert on the decode batch and
-    mixes with renormalized top-k gate weights. For decode steps this is also
-    the HBM-optimal shape: all expert weights stream from HBM once regardless
-    of routing, and B*T is tiny. Prefill chunks pay E/top_k extra FFN FLOPs
-    for dropless-ness (attention + the dense projections dominate prefill;
-    a grouped-GEMM Pallas kernel is the known upgrade path). Numerically
-    identical to ``moe_dense`` whenever its capacity does not overflow, which
-    is what the decode-vs-forward exactness test pins."""
+    (``parallel/moe.py``) every token goes through exactly its top-k experts,
+    mixed with the renormalized gate weights (``topk_gates`` on float32
+    logits), times ``cfg.moe_routed_scale``, plus the shared expert where
+    ``cfg.moe_shared_d_ff`` is set.
+
+    One form at every size: the B*T*k assignments are sorted by expert and go
+    through three grouped matmuls (``ops/grouped_matmul.py``), so each expert
+    multiplies its own tokens only and only a touched expert's weights are
+    read. The form this replaced below 65 tokens, every expert over every
+    token as one batched einsum, streams all the weights whatever the routing:
+    on a v5e at 256 experts of 2048 x 512, 8 a token, a layer took 2.19 ms at
+    any batch against 0.57, 1.50, 1.98 and 2.46 ms grouped at 8, 32, 64 and
+    256 tokens (57, 165, 219 and 256 experts touched; PERF.md section 6, PR
+    28). The calls take the whole stacked bank as ``[layers * E, ..]`` with
+    this layer's group sizes at its own offset and zeros elsewhere: a layer's
+    slice of the bank handed to a kernel is a copy of it on the chip (1.6 GB
+    a layer at those widths).
+
+    Numerically identical to ``moe_dense`` whenever its capacity does not
+    overflow, which is what the decode-vs-forward exactness test pins."""
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
     from ray_tpu.parallel.moe import topk_gates
 
     B, T, e = h.shape
-    E = cfg.moe_experts
+    E, k = cfg.moe_experts, cfg.moe_top_k
     g = h.reshape(B * T, e)
     G = g.shape[0]
-    _, gate_vals, gate_idx = topk_gates({"router": p["moe_router"]}, g, cfg.moe_top_k)
-    # w[g, e] = sum_k gate_vals[g, k] * [gate_idx[g, k] == e]
-    wge = (
-        jax.nn.one_hot(gate_idx, E, dtype=jnp.float32) * gate_vals[..., None]
-    ).sum(axis=1).astype(g.dtype)
-    if G <= 64:
-        # decode steps (G = batch): one batched einsum over all experts —
-        # better MXU shapes than E sequential skinny matmuls
-        gate = jnp.einsum("gd,edf->egf", g, p["moe_w_gate"])
-        up = jnp.einsum("gd,edf->egf", g, p["moe_w_up"])
-        out = jnp.einsum("egf,efd->egd", jax.nn.silu(gate) * up, p["moe_w_down"])
-        y = jnp.einsum("egd,ge->gd", out, wge)
-    else:
-        # prefill chunks (G = B*chunk tokens): accumulate expert-by-expert so
-        # peak transient memory is [G, d_ff], not [E, G, d_ff]
-        def body(ei, y):
-            gate = g @ p["moe_w_gate"][ei]
-            up = g @ p["moe_w_up"][ei]
-            out = (jax.nn.silu(gate) * up) @ p["moe_w_down"][ei]
-            return y + out * wge[:, ei][:, None]
+    with scope("router"):
+        # float32 logits: in the model's own bf16 the 8th and 9th of 256
+        # experts swap for some tokens on rounding alone
+        _, gate_vals, gate_idx = topk_gates(
+            {"router": params["moe_router"][row].astype(jnp.float32)},
+            g.astype(jnp.float32), k,
+        )
+        # tokens an expert: a one-hot sum (a scatter-add is slow on the chip)
+        load = jax.nn.one_hot(gate_idx.reshape(-1), E, dtype=jnp.int32).sum(axis=0)
+        stats = jnp.stack([
+            jnp.int32(1), jnp.int32(G * k), (load > 0).sum(dtype=jnp.int32), load.max(),
+        ])
+    with scope("experts"):
+        order = jnp.argsort(gate_idx.reshape(-1))  # assignments by expert
+        rows = g[order // k]  # [G*k, e]: each assignment's token
+        n = params["moe_w_gate"].shape[0]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n * E,), jnp.int32), load, (row * E,)
+        )
 
-        y = jax.lax.fori_loop(0, E, body, jnp.zeros_like(g))
-    return y.reshape(B, T, e)
+        def bank(name):
+            w = params[name]
+            return w.reshape((n * E,) + w.shape[2:])
+
+        gate = grouped_matmul(rows, bank("moe_w_gate"), sizes)
+        up = grouped_matmul(rows, bank("moe_w_up"), sizes)
+        out = grouped_matmul(
+            jax.nn.silu(gate) * up, bank("moe_w_down"), sizes, jnp.float32
+        )
+        # back to token order: a gather, not a scatter-add
+        out = out[jnp.argsort(order)].reshape(G, k, e)
+        y = jnp.einsum("gkd,gk->gd", out, gate_vals) * cfg.moe_routed_scale
+        y = y.astype(g.dtype)
+    if cfg.moe_shared_d_ff:
+        y = y + _shared_expert(
+            {n: params[n][row] for n in params if n.startswith("moe_shared_")}, g
+        )
+    return y.reshape(B, T, e), stats
 
 
 def init_kv_cache(cfg: LlamaConfig, batch_size: int, max_len: Optional[int] = None):
@@ -787,6 +955,70 @@ def _write_block(c_all, new, l, b, start, ok):
     return jax.lax.dynamic_update_slice(c_all, block[None, None], at)
 
 
+def _ride_stats(cache, new_cache, stats) -> None:
+    """A cache that comes in with a ``moe_stats`` leaf (int32 [4],
+    ``MOE_STATS``) goes out with this call's routing counts added to it: how
+    the engine's programs get them out without a fetch of their own, and how
+    a prompt's chunks add theirs up on the device. Any other cache is left
+    as ``init_kv_cache`` made it."""
+    if stats and "moe_stats" in cache:
+        new_cache["moe_stats"] = cache["moe_stats"] + stats[0]
+
+
+def _cache_writer(cfg: LlamaConfig, S: int, positions, valid, start_pos):
+    """``write(c_all, new, l)`` for ``_decode_forward`` (and
+    ``models/patterned.py``): new keys or values [B, K, T, D] into layer ``l``
+    of the carried cache [L, B, K, S, D], as blocks or as the scatter (see
+    ``_decode_forward``)."""
+    B, T = positions.shape
+    as_blocks = (
+        start_pos is not None and B <= _BLOCK_WRITE_MAX_BATCH and T <= S
+    )
+    if as_blocks:
+        ok = jnp.ones((B, T), bool) if valid is None else valid
+
+        def write(c_all, new, l):
+            for b in range(B):
+                c_all = _write_block(c_all, new[b], l, b, start_pos[b], ok[b])
+            return c_all
+    else:
+        if valid is not None:
+            # out-of-range index -> dropped by scatter mode='drop'
+            write_pos = jnp.where(valid, positions, S)
+        else:
+            write_pos = positions
+        bi = jnp.arange(B)[:, None, None]
+        ki = jnp.arange(cfg.n_kv_heads)[None, :, None]
+        pi = write_pos[:, None, :]  # [B, 1, T]
+
+        def write(c_all, new, l):
+            return c_all.at[l, bi, ki, pi].set(new, mode="drop")
+    return write
+
+
+def _grouped_attention(q, k, v, mask):
+    """GQA over the keys the mask allows, without materializing repeated
+    K/V. q: [B, T, H, D]; k, v: [B, K, S, D] (head-major, as the cache keeps
+    them); mask: [B, T, S]."""
+    B, T, H, D = q.shape
+    K = k.shape[1]
+    qg = q.reshape(B, T, K, H // K, D)
+    s = jnp.einsum("btkgd,bksd->bktgs", qg, k) * D**-0.5
+    s = jnp.where(mask[:, None, :, None, :], s, -1e30)
+    w = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
+    return jnp.einsum("bktgs,bksd->btkgd", w, v).reshape(B, T, H, D)
+
+
+def _dense_ffn(h, p):
+    """SwiGLU of h [B, T, e]; ``p(name)`` hands out this layer's ``w_gate``,
+    ``w_up``, ``w_down`` when asked (a layer's slice of a stacked weight is a
+    copy on the chip: it is taken where it is used)."""
+    ff = jax.nn.silu(
+        jnp.einsum("bte,ef->btf", h, p("w_gate"))
+    ) * jnp.einsum("bte,ef->btf", h, p("w_up"))
+    return jnp.einsum("btf,fe->bte", ff, p("w_down"))
+
+
 def _decode_forward(
     params, cache, tokens, positions, cfg: LlamaConfig, valid=None,
     loras=None, adapter_ids=None, with_logits: bool = True,
@@ -818,6 +1050,13 @@ def _decode_forward(
     distribution, and the full [B, T, V] projection is the single biggest
     prefill allocation (0.5 GB/seq at 7B/128k-vocab scale: the allocation
     that kept 7B from fitting one v5e chip)."""
+    if cfg.layer_types:
+        from ray_tpu.models.patterned import decode_forward
+
+        return decode_forward(
+            params, cache, tokens, positions, cfg, valid, loras=loras,
+            with_logits=with_logits, logits_at=logits_at, start_pos=start_pos,
+        )
     B, T = tokens.shape
     S = cache["k"].shape[3]  # [L, B, K, S, D]
     with scope("embed"):
@@ -828,28 +1067,7 @@ def _decode_forward(
     qpos = positions[:, :, None]  # [B, T, 1]
     seq_mask = slot <= qpos  # causal over absolute positions
 
-    as_blocks = (
-        start_pos is not None and B <= _BLOCK_WRITE_MAX_BATCH and T <= S
-    )
-    if as_blocks:
-        ok = jnp.ones((B, T), bool) if valid is None else valid
-
-        def write(c_all, new, l):
-            for b in range(B):
-                c_all = _write_block(c_all, new[b], l, b, start_pos[b], ok[b])
-            return c_all
-    else:
-        if valid is not None:
-            # out-of-range index -> dropped by scatter mode='drop'
-            write_pos = jnp.where(valid, positions, S)
-        else:
-            write_pos = positions
-        bi = jnp.arange(B)[:, None, None]
-        ki = jnp.arange(cfg.n_kv_heads)[None, :, None]
-        pi = write_pos[:, None, :]  # [B, 1, T]
-
-        def write(c_all, new, l):
-            return c_all.at[l, bi, ki, pi].set(new, mode="drop")
+    write = _cache_writer(cfg, S, positions, valid, start_pos)
 
     groups = cfg.n_heads // cfg.n_kv_heads
     scale = cfg.head_dim**-0.5
@@ -859,7 +1077,7 @@ def _decode_forward(
     # cache slices as ys re-materializes the whole cache every step (decode
     # measured 1.6x slower from those copies alone at 3B/B=16 on v5e).
     def body(l, carry):
-        x, ck_all, cv_all = carry
+        x, ck_all, cv_all, *stats = carry
         # a layer's slice of a stacked weight is a copy on the chip (1.1 ms
         # of a 14.7 ms decode step at 7B widths): take it inside the scope
         # that uses it, so that it is booked there
@@ -901,13 +1119,7 @@ def _decode_forward(
                 # into the query instead (a jnp.repeat here would write+reread
                 # the whole cache ×groups per layer per step — at 3B/B=16 that
                 # alone is ~11 GB of HBM traffic per decode step)
-                qg = q.reshape(B, T, cfg.n_kv_heads, groups, cfg.head_dim)
-                s = jnp.einsum("btkgd,bksd->bktgs", qg, ck) * scale
-                s = jnp.where(seq_mask[:, None, :, None, :], s, -1e30)
-                w = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(x.dtype)
-                attn = jnp.einsum("bktgs,bksd->btkgd", w, cv).reshape(
-                    B, T, cfg.n_heads, cfg.head_dim
-                )
+                attn = _grouped_attention(q, ck, cv, seq_mask)
             else:
                 s = jnp.einsum("bthd,bhsd->bhts", q, ck) * scale
                 s = jnp.where(seq_mask[:, None, :, :], s, -1e30)
@@ -919,21 +1131,21 @@ def _decode_forward(
         h = _rmsnorm(x, p("mlp_norm"), cfg.rms_eps, cfg.fused_rmsnorm)
         if cfg.moe_experts:
             with scope("moe_ffn"):
-                x = x + _moe_decode_ffn(
-                    {n: p(n) for n in _layer_keys(cfg) if n.startswith("moe_")}, h, cfg
-                )
+                y, layer_stats = _moe_decode_ffn(params, l, h, cfg)
+                x = x + y
+                stats = [stats[0] + layer_stats]
         else:
             with scope("ffn"):
-                ff = jax.nn.silu(
-                    jnp.einsum("bte,ef->btf", h, p("w_gate"))
-                ) * jnp.einsum("bte,ef->btf", h, p("w_up"))
-                x = x + jnp.einsum("btf,fe->bte", ff, p("w_down"))
-        return (x, ck_all, cv_all)
+                x = x + _dense_ffn(h, p)
+        return (x, ck_all, cv_all, *stats)
 
-    x, new_k, new_v = jax.lax.fori_loop(
-        0, cfg.n_layers, body, (x, cache["k"], cache["v"])
+    # a model with routed experts carries its routing counts beside x
+    stats0 = (jnp.zeros((len(MOE_STATS),), jnp.int32),) if cfg.moe_experts else ()
+    x, new_k, new_v, *stats = jax.lax.fori_loop(
+        0, cfg.n_layers, body, (x, cache["k"], cache["v"], *stats0)
     )
     new_cache = {"k": new_k, "v": new_v, "length": new_len}
+    _ride_stats(cache, new_cache, stats)
     if not with_logits:
         # mid-chunk prefill: the caller only extends the KV cache — skip the
         # LM head (the vocab projection reads ~0.8 GB of weights at 128k

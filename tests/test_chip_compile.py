@@ -55,7 +55,9 @@ def native_kernels(monkeypatch):
     ``sys.modules``."""
     import ray_tpu.ops  # noqa: F401 — loads the kernel modules
 
-    for name in ("ray_tpu.ops.rmsnorm", "ray_tpu.ops.quant"):
+    import ray_tpu.ops.grouped_matmul  # noqa: F401 — not in the package's __init__
+
+    for name in ("ray_tpu.ops.rmsnorm", "ray_tpu.ops.quant", "ray_tpu.ops.grouped_matmul"):
         monkeypatch.setattr(sys.modules[name], "interpret", lambda: False)
 
 
@@ -108,6 +110,15 @@ def _dequantize(q, s):
     return dequantize_int8(q, s)
 
 
+def _grouped_matmul(rows, bank, sizes):
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+
+    return grouped_matmul(rows, bank, sizes)
+
+
+# a 256-token chunk's 2,048 assignments over four stacked banks of 256 experts
+# (Laguna-XS.2: 2,048 x 512 up, 512 x 2,048 down)
+GROUPS = ((4 * 256,), jnp.int32)
 X_NORM = ((8192, 3072), jnp.bfloat16)
 W_NORM = ((3072,), jnp.bfloat16)
 
@@ -116,6 +127,14 @@ KERNELS = {
     "splash_bwd": (_splash_bwd, (QKV, QKV, QKV)),
     "rmsnorm_fwd": (_rmsnorm_fwd, (X_NORM, W_NORM)),
     "rmsnorm_grad": (_rmsnorm_grad, (X_NORM, W_NORM)),
+    "grouped_matmul_up": (
+        _grouped_matmul,
+        (((2048, 2048), jnp.bfloat16), ((1024, 2048, 512), jnp.bfloat16), GROUPS),
+    ),
+    "grouped_matmul_down": (
+        _grouped_matmul,
+        (((2048, 512), jnp.bfloat16), ((1024, 512, 2048), jnp.bfloat16), GROUPS),
+    ),
     "quantize_int8": (_quantize, (((3072, 8192), jnp.bfloat16),)),
     "dequantize_int8": (
         _dequantize,
@@ -178,3 +197,39 @@ def test_chunk_mid_writes_its_cache_rows_without_a_scatter(one_chip, no_compile_
     ]
     assert len(updates) == 2, updates
     assert all("while/body" in line for line in updates), updates
+
+
+def test_patterned_chunk_mid_keeps_its_expert_banks_in_place(
+        one_chip, no_compile_cache, native_kernels):
+    """The engine's ``chunk_mid`` body at the Laguna-XS.2 cell's widths (5
+    layers, a 4,096-position stripe, a 256-token chunk): the grouped matmuls
+    are Pallas kernels under ``moe_ffn/experts``, they take the stacked banks
+    whole (a layer's slice handed to a kernel was a 1.6 GB copy a layer: 3.9
+    GB of temporaries), and the program fits beside 7.7 GB of weights."""
+    from ray_tpu.models.llama import LlamaConfig, init_kv_cache, init_params, prefill
+
+    cfg = LlamaConfig.laguna_xs2(n_layers=5, max_seq_len=4096)
+
+    def described(make):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    params = described(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    stripe = described(lambda: init_kv_cache(cfg, 1, 4096))
+    tokens = jax.ShapeDtypeStruct((1, 256), jnp.int32, sharding=one_chip)
+    scalar = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+
+    def chunk_mid(params, stripe, tokens, length, start):
+        return prefill(params, stripe, tokens, cfg, lengths=length, start_pos=start,
+                       with_logits=False)[1]
+
+    compiled = (
+        jax.jit(chunk_mid, donate_argnums=(1,))
+        .lower(params, stripe, tokens, scalar, scalar).compile()
+    )
+    kernels = [line for line in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels and all("moe_ffn/experts" in line for line in kernels), kernels[:2]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

@@ -1,0 +1,370 @@
+"""A model whose layers are not alike (``models/patterned.py``; Laguna-XS.2's
+pattern at test size): prefill and decode through the cache against the full
+forward pass and against the benchmark's plain reference, the window cut out
+of the stripe, the two forms of the expert layer, the rotary tables against a
+NumPy transcription of the published code, the published depth's parameter
+count, and the engine's routing and window counters and scopes."""
+
+import math
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.llm import EngineConfig, JaxEngine, LLMConfig, ModelConfig, SamplingParams
+from ray_tpu.models import patterned
+from ray_tpu.models.llama import (
+    LlamaConfig,
+    _moe_decode_ffn,
+    _param_shapes,
+    decode_step,
+    forward,
+    init_kv_cache,
+    init_params,
+    prefill,
+)
+
+CFG = LlamaConfig.laguna_tiny()
+# what benchmark/families/moe_window_gqa.py reads, for the reference
+PUBLISHED = {
+    "vocab_size": 256, "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 5,
+    "num_attention_heads": 6, "num_key_value_heads": 2, "head_dim": 16, "attention_bias": False,
+    "rms_norm_eps": 1e-6, "num_experts": 16, "num_experts_per_tok": 4, "moe_intermediate_size": 32,
+    "shared_expert_intermediate_size": 32, "tie_word_embeddings": False, "gating": True,
+    "sliding_window": 8, "moe_apply_router_weight_on_input": False, "moe_routed_scaling_factor": 2.5,
+    "rope_parameters": {
+        "full_attention": {"rope_theta": 500000, "rope_type": "yarn", "factor": 4,
+                           "original_max_position_embeddings": 16, "beta_slow": 1, "beta_fast": 8,
+                           "attention_factor": 1.2, "partial_rotary_factor": 0.5},
+        "sliding_attention": {"rope_type": "default", "rope_theta": 10000, "partial_rotary_factor": 1},
+    },
+    "layer_types": ["full_attention"] + ["sliding_attention"] * 3 + ["full_attention"],
+    "mlp_layer_types": ["dense"] + ["sparse"] * 4,
+    "num_attention_heads_per_layer": [6, 8, 8, 8, 6],
+}
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_params(jax.random.PRNGKey(7), CFG)
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return jax.random.randint(jax.random.PRNGKey(1), (2, 44), 0, CFG.vocab_size)
+
+
+@pytest.fixture(scope="module")
+def whole(params, tokens):
+    return forward(params, tokens, CFG)
+
+
+def test_the_family_maps_the_published_keys_onto_the_tiny_preset():
+    from benchmark.families import moe_window_gqa as family
+
+    got = LlamaConfig.laguna_tiny(**family.model_kwargs(PUBLISHED))
+    assert got == CFG
+    shapes = {k: s for k, (s, _) in family.param_shapes(PUBLISHED).items()}
+    assert shapes == _param_shapes(CFG)
+
+
+@pytest.mark.parametrize("align", [4, 128], ids=["window-cut-out", "whole-stripe"])
+@pytest.mark.parametrize("chunks", [(44,), (5, 16, 9)], ids=["one-prefill", "chunked-across-the-window"])
+def test_prefill_then_decode_equals_forward_and_the_reference(
+        params, tokens, whole, monkeypatch, align, chunks):
+    """Logits and every layer's keys and values: the prompt goes in as
+    ``chunks`` (the second form crosses the 8-token window inside a chunk and
+    between chunks), the rest a token at a time; against ``forward`` and
+    against ``benchmark/reference_moe_window.py`` on the same weights."""
+    from benchmark.reference_moe_window import Reference
+
+    monkeypatch.setattr(patterned, "_WINDOW_ALIGN", align)
+    B, T = tokens.shape
+    cache = init_kv_cache(CFG, B, 64)
+    at = 0
+    for n in chunks:
+        if at + n > 30:
+            n = 30 - at
+        logits, cache = prefill(
+            params, cache, tokens[:, at:at + n], CFG, start_pos=jnp.full((B,), at, jnp.int32))
+        at += n
+    assert at == 30
+    got = [logits]
+    for i in range(at, T - 1):
+        logits, cache = decode_step(params, cache, tokens[:, i], CFG)
+        got.append(logits)
+    got = jnp.stack(got, axis=1)  # positions 29 .. T-2
+    np.testing.assert_allclose(got, whole[:, 29:T - 1], atol=5e-5, rtol=1e-4)
+
+    ref = Reference(PUBLISHED, jax.local_devices()[:1])
+    want = ref.forward_rows(params, [np.asarray(r[:T - 1]) for r in tokens], last=T - 30,
+                            kv_rows=range(B))
+    np.testing.assert_allclose(got, np.stack(want["logits"]), atol=5e-5, rtol=1e-4)
+    for b in range(B):
+        for name, ref_kv in zip(("k", "v"), want["kv"][b]):
+            have = np.asarray(cache[name][:, b, :, :T - 1]).transpose(0, 2, 1, 3)  # [L, T, K, D]
+            np.testing.assert_allclose(have, ref_kv, atol=2e-5, rtol=1e-4)
+
+
+def test_padded_prefill_leaves_the_cache_and_logits_of_an_unpadded_one(params, tokens, monkeypatch):
+    monkeypatch.setattr(patterned, "_WINDOW_ALIGN", 4)
+    cache = init_kv_cache(CFG, 2, 64)
+    lengths = jnp.asarray([21, 13], jnp.int32)
+    logits, cache = prefill(params, cache, tokens[:, :32], CFG, lengths=lengths)
+    for b, n in enumerate([21, 13]):
+        alone, c1 = prefill(params, init_kv_cache(CFG, 1, 64), tokens[b:b + 1, :n], CFG)
+        np.testing.assert_allclose(logits[b], alone[0], atol=5e-5, rtol=1e-4)
+        np.testing.assert_allclose(cache["k"][:, b, :, :n], c1["k"][:, 0, :, :n], atol=2e-5)
+        assert not np.asarray(cache["k"][:, b, :, n:]).any()  # padding writes nothing
+
+
+def test_grouped_expert_form_equals_every_expert_form(params):
+    """``_moe_decode_ffn`` sorts tokens by expert; the same sum with every
+    expert run over every token and a zero weight where a token did not
+    choose it, written out here."""
+    row, k, E = 2, CFG.moe_top_k, CFG.moe_experts
+    for tokens in (3, 80):  # a decode batch, a chunk: less and more than one row tile
+        h = jax.random.normal(jax.random.PRNGKey(tokens), (1, tokens, CFG.d_model), jnp.float32)
+        grouped, stats = _moe_decode_ffn(params, row, h, CFG)
+        g = h[0]
+        probs = jax.nn.softmax(g @ params["moe_router"][row], axis=-1)
+        top, idx = jax.lax.top_k(probs, k)
+        weights = (jax.nn.one_hot(idx, E) * (top / top.sum(-1, keepdims=True))[..., None]).sum(1)
+        act = jax.nn.silu(jnp.einsum("gd,edf->egf", g, params["moe_w_gate"][row])) * jnp.einsum(
+            "gd,edf->egf", g, params["moe_w_up"][row])
+        every = jnp.einsum("egd,ge->gd", jnp.einsum("egf,efd->egd", act, params["moe_w_down"][row]), weights)
+        shared = (jax.nn.silu(g @ params["moe_shared_gate"][row]) * (g @ params["moe_shared_up"][row])
+                  ) @ params["moe_shared_down"][row]
+        np.testing.assert_allclose(grouped[0], CFG.moe_routed_scale * every + shared, atol=2e-5, rtol=1e-4)
+        layer_steps, assignments, touched, fullest = (int(x) for x in stats)
+        assert (layer_steps, assignments) == (1, tokens * k)
+        assert touched == len(set(np.asarray(idx).reshape(-1).tolist()))
+        assert fullest == np.bincount(np.asarray(idx).reshape(-1)).max()
+
+
+def _yarn_numpy(dim, base, factor, original, beta_fast, beta_slow):
+    """transformers ``_compute_yarn_parameters``, transcribed."""
+    def find_correction_dim(num_rotations):
+        return (dim * math.log(original / (num_rotations * 2 * math.pi))) / (2 * math.log(base))
+
+    low = max(math.floor(find_correction_dim(beta_fast)), 0)
+    high = min(math.ceil(find_correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    pos_freqs = base ** (np.arange(0, dim, 2).astype(np.float32) / dim)
+    inv_freq_extrapolation = 1.0 / pos_freqs
+    inv_freq_interpolation = 1.0 / (factor * pos_freqs)
+    ramp = np.clip((np.arange(dim // 2).astype(np.float32) - low) / (high - low), 0, 1)
+    inv_freq_extrapolation_factor = 1 - ramp
+    return (inv_freq_interpolation * (1 - inv_freq_extrapolation_factor)
+            + inv_freq_extrapolation * inv_freq_extrapolation_factor)
+
+
+@pytest.mark.parametrize("cfg", [CFG, LlamaConfig.laguna_xs2()], ids=["tiny", "published"])
+def test_yarn_and_the_half_rotation_against_numpy(cfg):
+    inv, factor = patterned.rope_inv_freq(cfg, "full")
+    rot = int(cfg.head_dim * cfg.rope_partial)
+    want = _yarn_numpy(rot, cfg.rope_theta, cfg.yarn_factor, cfg.yarn_original_len,
+                       cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+    np.testing.assert_allclose(inv, want, rtol=1e-6)
+    assert factor == cfg.yarn_attention_factor and len(inv) == rot // 2
+    # low frequencies are interpolated (divided by the factor), high ones kept
+    plain = 1.0 / cfg.rope_theta ** (np.arange(0, rot, 2) / rot)
+    np.testing.assert_allclose(inv[0], plain[0], rtol=1e-6)
+    np.testing.assert_allclose(inv[-1], plain[-1] / cfg.yarn_factor, rtol=1e-5)
+    inv_s, factor_s = patterned.rope_inv_freq(cfg, "sliding")
+    assert factor_s == 1.0 and len(inv_s) == cfg.head_dim // 2
+    # the rotation itself: first `rot` dims rotated in halves, the rest untouched
+    x = np.random.default_rng(0).normal(size=(1, 3, 2, cfg.head_dim)).astype(np.float32)
+    pos = np.asarray([[0, 5, 901]], np.int32)
+    got = np.asarray(patterned._rope(jnp.asarray(x), jnp.asarray(pos), inv, factor))
+    ang = pos[..., None].astype(np.float64) * want
+    cos, sin = np.cos(ang)[:, :, None, :] * factor, np.sin(ang)[:, :, None, :] * factor
+    x1, x2 = x[..., :rot // 2], x[..., rot // 2:rot]
+    np.testing.assert_allclose(got[..., :rot // 2], x1 * cos - x2 * sin, atol=2e-4)
+    np.testing.assert_allclose(got[..., rot // 2:rot], x2 * cos + x1 * sin, atol=2e-4)
+    np.testing.assert_array_equal(got[..., rot:], x[..., rot:])
+
+
+def test_published_depth_counts_its_parameters_and_traces_one_period():
+    cfg = LlamaConfig.laguna_xs2()
+    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    n = sum(math.prod(a.shape) for a in jax.tree.leaves(shapes))
+    assert n == cfg.num_params()
+    assert abs(n / 33.44e9 - 1) < 1e-3
+    pl = patterned.plan(cfg)
+    assert (pl.lead, pl.period, pl.reps, cfg.n_layers - pl.tail_from) == (1, 4, 9, 3)
+    # the served cut: layer 0 and one period, every layer its own body
+    cut = LlamaConfig.laguna_xs2(n_layers=5)
+    assert cut.layer_types == ("full", "sliding", "sliding", "sliding", "full")
+    assert abs(cut.num_params() / 3.87e9 - 1) < 5e-3
+
+
+def test_a_repeated_period_runs_under_one_loop_and_equals_the_unrolled_stack(monkeypatch):
+    """9 layers (layer 0, two periods): the loop's traced indices reach the
+    same rows as static ones."""
+    types = ("full",) + ("sliding", "sliding", "sliding", "full") * 2
+    cfg = LlamaConfig.laguna_tiny(
+        n_layers=9, layer_types=types, heads_per_layer=tuple(6 if t == "full" else 8 for t in types),
+        mlp_types=("dense",) + ("sparse",) * 8)
+    pl = patterned.plan(cfg)
+    assert (pl.lead, pl.period, pl.reps) == (1, 4, 2)
+    params = init_params(jax.random.PRNGKey(2), cfg)
+    toks = jax.random.randint(jax.random.PRNGKey(4), (1, 24), 0, cfg.vocab_size)
+    looped = forward(params, toks, cfg)
+    traced = []
+    feed_forward = patterned._feed_forward
+    monkeypatch.setattr(patterned, "_feed_forward",
+                        lambda *a: traced.append(1) or feed_forward(*a))
+    jax.make_jaxpr(lambda p, t: forward(p, t, cfg))(params, toks)
+    assert len(traced) == 1 + 4  # layer 0 and one period: 5 bodies for 9 layers
+    flat = patterned.Plan(9, 1, 0, pl.attn_index, pl.mlp_index)  # every layer its own body
+    monkeypatch.setattr(patterned, "plan", lambda c: flat)
+    unrolled = forward(params, toks, cfg)
+    monkeypatch.undo()
+    np.testing.assert_allclose(looped, unrolled, atol=5e-5, rtol=1e-4)
+    cache = init_kv_cache(cfg, 1, 32)
+    logits, cache = prefill(params, cache, toks[:, :23], cfg)
+    np.testing.assert_allclose(logits, looped[:, 22], atol=5e-5, rtol=1e-4)
+
+
+def test_pattern_errors_are_named():
+    with pytest.raises(ValueError, match="entries for n_layers"):
+        LlamaConfig.laguna_tiny(n_layers=4)
+    with pytest.raises(ValueError, match="differ in their query heads"):
+        patterned.plan(LlamaConfig.laguna_tiny(heads_per_layer=(6, 8, 8, 4, 6)))
+    with pytest.raises(ValueError, match="sliding_window"):
+        patterned.plan(LlamaConfig.laguna_tiny(sliding_window=0))
+
+
+# ------------------------------------------------------------------ the engine
+
+
+@pytest.fixture(scope="module")
+def engine():
+    eng = JaxEngine(LLMConfig(
+        model=ModelConfig(model_id="laguna-tiny", seed=3),
+        engine=EngineConfig(max_num_seqs=4, max_seq_len=128, dtype="float32",
+                            prefill_chunk=16, prefill_buckets=(8, 16, 32)),
+    ))
+    yield eng
+    eng.shutdown()
+
+
+def _routing(eng):
+    c = eng.get_stats()["counters"]
+    return {k: dict(c[k]) for k in c if k.startswith("moe_")}, c
+
+
+def test_engine_tokens_equal_greedy_over_the_full_forward(engine, monkeypatch):
+    monkeypatch.setattr(patterned, "_WINDOW_ALIGN", 4)
+    ids = [int(t) for t in np.random.default_rng(0).integers(32, 127, 50)]
+    out = engine.generate(prompt_token_ids=ids, sampling_params=SamplingParams(
+        max_tokens=6, temperature=0.0, ignore_eos=True))
+    seq = jnp.asarray([ids + list(out.token_ids)])
+    logits = forward(engine.params, seq, engine.model_cfg)
+    assert [int(t) for t in jnp.argmax(logits[0, len(ids) - 1:-1], -1)] == list(out.token_ids)
+
+
+def test_routing_and_window_counters_on_a_known_batch(engine):
+    cfg = engine.model_cfg
+    k, expert_layers, slots = cfg.moe_top_k, cfg.mlp_types.count("sparse"), 4
+    before, c0 = _routing(engine)
+    ids = [int(t) for t in np.random.default_rng(5).integers(32, 127, 37)]
+    engine.generate(prompt_token_ids=ids, sampling_params=SamplingParams(
+        max_tokens=5, temperature=0.0, ignore_eos=True))
+    deadline = time.time() + 10.0
+    while True:  # the run-ahead step's counts arrive with its fetch
+        after, c1 = _routing(engine)
+        if (after["moe_layer_steps"]["decode"] - before["moe_layer_steps"]["decode"]
+                == (c1["decode_steps"] - c0["decode_steps"]) * expert_layers) or time.time() > deadline:
+            break
+        time.sleep(0.01)
+    grew = {name: {p: after[name][p] - before[name][p] for p in after[name]} for name in after}
+    # 37 tokens: two 16-token middle chunks and a final chunk of width 8 (5 real)
+    assert c1["prefill_chunks"]["mid"] - c0["prefill_chunks"]["mid"] == 2
+    # a prompt's middle chunks add theirs up on the device; its final chunk hands both out
+    assert grew["moe_layer_steps"]["chunk_mid"] == 2 * expert_layers
+    assert grew["moe_assignments"]["chunk_mid"] == k * (16 + 16) * expert_layers
+    assert grew["moe_layer_steps"]["chunk_final"] == expert_layers
+    assert grew["moe_assignments"]["chunk_final"] == k * 8 * expert_layers
+    steps = c1["decode_steps"] - c0["decode_steps"]
+    assert steps >= 4
+    assert grew["moe_layer_steps"]["decode"] == steps * expert_layers
+    assert grew["moe_assignments"]["decode"] == k * slots * steps * expert_layers
+    for program in ("decode", "chunk_mid", "chunk_final"):
+        runs = grew["moe_layer_steps"][program]
+        assert runs <= grew["moe_experts_touched"][program] <= runs * cfg.moe_experts
+        assert grew["moe_max_expert_load_sum"][program] * cfg.moe_experts >= grew["moe_assignments"][program]
+    whole = c1["decode_kv_tokens_global"] - c0["decode_kv_tokens_global"]
+    window = c1["decode_kv_tokens_window"] - c0["decode_kv_tokens_window"]
+    assert 0 < window <= whole
+    assert window == steps * cfg.sliding_window  # every step's slot is past the window
+    assert whole >= steps * 37
+
+
+def test_a_dense_engine_counts_no_routing_and_no_window():
+    eng = JaxEngine(LLMConfig(model=ModelConfig(model_id="tiny", seed=1),
+                              engine=EngineConfig(max_num_seqs=2, max_seq_len=64, dtype="float32")))
+    try:
+        eng.generate("hello there", sampling_params=SamplingParams(max_tokens=4, ignore_eos=True))
+        routing, c = _routing(eng)
+        assert all(v == {"decode": 0, "chunk_mid": 0, "chunk_final": 0} for v in routing.values())
+        assert len(routing) == 4
+        assert c["decode_kv_tokens_window"] == 0 < c["decode_kv_tokens_global"]
+    finally:
+        eng.shutdown()
+
+
+INNER_SCOPES = {
+    "decode_fn": ("attn_core/window", "attn_core/global", "moe_ffn/router", "moe_ffn/experts",
+                  "moe_ffn/shared_expert", "attn_out/gate", "ffn", "kv_write", "sampling"),
+    "chunk_mid": ("attn_core/window", "attn_core/global", "moe_ffn/router", "moe_ffn/experts",
+                  "moe_ffn/shared_expert", "attn_out/gate"),
+}
+
+
+@pytest.fixture(scope="module")
+def lowered_paths(engine):
+    pool = engine._pools[0]
+    while pool.keys is None:  # the loop thread makes them on its first pass
+        time.sleep(0.01)
+    shapes = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)  # noqa: E731
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    params, cache = shapes(engine.params), shapes(pool.cache)
+    one = dict(jax.eval_shape(lambda: init_kv_cache(engine.model_cfg, 1, pool.stripe_len)),
+               moe_stats=i32(4))
+    low = {
+        "decode_fn": engine._decode_jit.lower(
+            params, cache, i32(4), jax.ShapeDtypeStruct((4,), jnp.float32), i32(4), shapes(pool.keys)),
+        "chunk_mid": engine._chunk_mid_jit.lower(params, one, i32(1, 16), i32(1), i32(1)),
+    }
+    return {k: set(re.findall(r'loc\("([^"]+)"', v.as_text(debug_info=True))) for k, v in low.items()}
+
+
+@pytest.mark.parametrize("program, scope", [(p, s) for p, ss in INNER_SCOPES.items() for s in ss])
+def test_inner_scopes_are_in_the_lowered_programs_op_names(lowered_paths, program, scope):
+    pattern = re.compile(rf"(^|/){scope}(/|$)")
+    assert any(pattern.search(path) for path in lowered_paths[program]), (program, scope)
+
+
+def test_uniform_moe_with_a_shared_expert_and_scale_serves_what_it_trains():
+    """Layers alike (``models/llama.py`` alone): ``_moe_ffn`` (the training
+    path, capacity ample) and ``_moe_decode_ffn`` (the serving path) apply the
+    same expert width, shared expert and routed scale."""
+    cfg = LlamaConfig.tiny(moe_experts=4, moe_top_k=2, moe_capacity_factor=8.0,
+                           moe_d_ff=48, moe_shared_d_ff=32, moe_routed_scale=2.5)
+    params = init_params(jax.random.PRNGKey(5), cfg)
+    assert params["moe_w_gate"].shape == (2, 4, 64, 48) and params["moe_shared_down"].shape == (2, 32, 64)
+    assert cfg.num_params() == sum(math.prod(p.shape) for p in params.values())
+    toks = jax.random.randint(jax.random.PRNGKey(6), (2, 12), 0, cfg.vocab_size)
+    whole = forward(params, toks, cfg)
+    logits, cache = prefill(params, init_kv_cache(cfg, 2, 16), toks[:, :11], cfg)
+    np.testing.assert_allclose(logits, whole[:, 10], atol=5e-5, rtol=1e-4)
+    logits, _ = decode_step(params, cache, toks[:, 11], cfg)
+    np.testing.assert_allclose(logits, whole[:, 11], atol=5e-5, rtol=1e-4)
+    plain = LlamaConfig.tiny(moe_experts=4, moe_top_k=2, moe_capacity_factor=8.0, moe_d_ff=48)
+    assert not np.allclose(forward({k: v for k, v in params.items() if "shared" not in k}, toks, plain), whole)
