@@ -9,7 +9,9 @@ reads it itself; nothing here sets another), else ``<checkout>/.jax_cache``.
 
 from __future__ import annotations
 
+import contextlib
 import os
+import threading
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
@@ -37,6 +39,47 @@ def configure() -> str:
     # lines compiles those programs once more (README, "Compile cache").
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
+
+
+# ``bypassed`` nests and may be entered from several threads (one engine
+# each): the flag is the process's, so the first one in turns it off and the
+# last one out puts back what the first found.
+_bypass_lock = threading.Lock()
+_bypass_depth = 0
+_bypass_was = True
+
+
+def _set_cache_enabled(enabled: bool) -> bool:
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    cc.reset_cache()  # JAX asks the flag once and remembers the answer
+    return was
+
+
+@contextlib.contextmanager
+def bypassed():
+    """Compile what runs inside without the persistent cache. For a program
+    whose result has another device layout than the default (a relayout): in
+    jax 0.9 such a program read back from the cache hands out the default
+    layout, values intact, on the CPU as on a v5e
+    (tests/test_platform.py shows it; PERF.md section 6, PR 29: the first run
+    of a checkout held its weights head-major and every later one did not).
+    A compile on another thread meanwhile only misses the cache."""
+    global _bypass_depth, _bypass_was
+    with _bypass_lock:
+        if _bypass_depth == 0:
+            _bypass_was = _set_cache_enabled(False)
+        _bypass_depth += 1
+    try:
+        yield
+    finally:
+        with _bypass_lock:
+            _bypass_depth -= 1
+            if _bypass_depth == 0:
+                _set_cache_enabled(_bypass_was)
 
 
 def entry_count() -> int:

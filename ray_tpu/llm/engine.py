@@ -94,6 +94,14 @@ def _metric(kind: str, name: str, **kw):
         return _registered[name]
 
 
+def _order_of(leaf) -> Optional[tuple]:
+    """The axes of a device array from major to minor as the device holds
+    them; ``None`` for a host array, which has no layout. (Row-major is not
+    every leaf's default on a TPU: a narrow last axis is moved inward.)"""
+    layout = getattr(getattr(leaf, "format", None), "layout", None)
+    return tuple(layout.major_to_minor) if layout is not None else None
+
+
 def _latency_histogram(name: str) -> "app_metrics.Histogram":
     """One series an engine (tag ``engine``): ``get_stats()["latency"]`` is
     read back from it, so there is one set of bucket counts."""
@@ -264,6 +272,54 @@ class JaxEngine:
         ]
 
     # -- model setup --------------------------------------------------------
+
+    @property
+    def params(self):
+        return self._params
+
+    @params.setter
+    def params(self, tree):
+        """Whatever tree is assigned (``init_params``', a restored one, one
+        made outside; ``None`` to drop the weights first) has the leaves
+        ``models/llama.py serving_layouts`` names relaid on the device once,
+        before a program sees them. The caller's arrays are copied, not
+        donated: a tree shared with the engine stays whole in the caller's
+        hands. Names, shapes, dtypes, shardings and values stay; a program is
+        compiled for the layout its argument has, so a swap compiles
+        nothing. ``get_stats()["params_relaid"]`` counts what is held so,
+        from the arrays themselves."""
+        import jax
+        from jax.experimental.layout import Format, Layout
+
+        from ray_tpu._private import jax_cache
+        from ray_tpu.models.llama import param_shardings, serving_layouts
+
+        self._params = None  # the old tree goes first: two do not fit
+        rule = serving_layouts(tree or {})
+        due = [k for k, order in rule.items() if _order_of(tree[k]) != order]
+        if due:
+            tree = dict(tree)
+            with jax_cache.bypassed():  # read back from it, a relayout is none
+                for name in due:  # a leaf at a time: one leaf's copy is the peak
+                    x = tree[name]
+                    if not isinstance(x, jax.Array):  # a restored leaf is the host's
+                        x = jax.device_put(x, self._mesh and param_shardings(
+                            self.model_cfg, self._mesh)[name])
+                    tree[name] = jax.device_put(
+                        x, Format(Layout(major_to_minor=rule[name]), x.sharding)
+                    )
+        self._params = tree
+        # read back from the arrays: what is held, not what was asked
+        relaid = [tree[k] for k, order in rule.items() if _order_of(tree[k]) == order]
+        if len(relaid) != len(rule):
+            logger.warning(
+                "%d of %d parameter leaves did not take their device layout: "
+                "the programs copy a layer's slice of them before they multiply",
+                len(rule) - len(relaid), len(rule),
+            )
+        self._params_relaid = {"leaves": len(relaid), "bytes": sum(x.nbytes for x in relaid)}
+        for k, v in self._params_relaid.items():
+            _metric("Gauge", "params_relaid_" + k).set(v)
 
     def _build_model(self):
         import jax
@@ -772,6 +828,10 @@ class JaxEngine:
             },
             # constructor entered to the loop thread's first pass
             "engine_init_s": _between(self._t_init, self._loop_first_pass_t),
+            # parameter leaves held in the device layout the model's rule names
+            # (the stacked attention input projections, head-major): 0 says
+            # the programs copy a layer's slice before they multiply
+            "params_relaid": dict(self._params_relaid),
         }
 
     def _live_tokens(self) -> int:

@@ -369,6 +369,29 @@ def _param_shapes(cfg: LlamaConfig) -> dict[str, tuple]:
     return shapes
 
 
+# How a served leaf lies on the chip. A matmul reads a layer's slice of a
+# stacked leaf in place only where the axis it contracts is one of the
+# leaf's two minor axes: the chip tiles those two. ``wo`` [.., h, hd, e] and
+# the feed-forward leaves [.., e, f] are stored that way. A stacked attention
+# input projection [.., e, h, hd] is not: it contracts ``e`` and is tiled over
+# heads x head width, so every layer of every decode step and prefill chunk
+# copied its slice (50 MB a layer at Mistral-7B widths, 1.1 of a 14.7 ms
+# decode step: PERF.md section 6, PR 29). Held head-major, ``e`` lies beside
+# the head width; logical shape, values and sharding are what they were.
+HEAD_MAJOR = (0, 2, 1, 3)
+
+
+def serving_layouts(names) -> dict[str, tuple]:
+    """name -> ``major_to_minor`` for the leaves among ``names`` that the
+    engine holds in another device layout than the default, from what a leaf
+    is (``_PARAM_DIMS``): stacked, of rank 4, contracting its ``embed`` axis
+    with the heads and the head width behind it."""
+    return {
+        name: HEAD_MAJOR for name in names
+        if len(dims := _PARAM_DIMS.get(name, ())) == 4 and dims[:2] == (None, "embed")
+    }
+
+
 def _layer_keys(cfg: LlamaConfig) -> tuple:
     base = ("wq", "wk", "wv", "wo", "attn_norm", "mlp_norm")
     if cfg.moe_experts:

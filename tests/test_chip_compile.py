@@ -69,6 +69,25 @@ def _compiled_text(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+_SERVED = {
+    # the serving cells' configurations: (slots, stripe), and the width e of
+    # a layer's projection slice [1, e, h, 128]
+    "mistral-7b-serve-l16": (32, 1024, 4096),
+    "laguna-xs.2-serve-l5": (32, 4096, 2048),
+}
+
+
+def _served_config(name):
+    from ray_tpu.models.llama import LlamaConfig
+
+    if name.startswith("laguna"):
+        return LlamaConfig.laguna_xs2(n_layers=5, max_seq_len=4096)
+    return LlamaConfig(
+        vocab_size=32768, d_model=4096, n_layers=16, n_heads=32, n_kv_heads=8,
+        d_ff=14336, max_seq_len=1024, rope_theta=1e6, dtype=jnp.bfloat16,
+    )
+
+
 QKV = ((4, 2048, 24, 128), jnp.bfloat16)
 
 
@@ -157,14 +176,9 @@ def test_chunk_mid_writes_its_cache_rows_without_a_scatter(one_chip, no_compile_
     as contiguous blocks, in place in the layer loop's carried cache. A
     general scatter there cost 4.8 of the program's 18.2 ms on the chip
     (PERF.md section 6, PR 27)."""
-    from ray_tpu.models.llama import (
-        LlamaConfig, init_kv_cache, init_params, prefill,
-    )
+    from ray_tpu.models.llama import init_kv_cache, init_params, prefill
 
-    cfg = LlamaConfig(
-        vocab_size=32768, d_model=4096, n_layers=16, n_heads=32, n_kv_heads=8,
-        d_ff=14336, max_seq_len=1024, rope_theta=1e6, dtype=jnp.bfloat16,
-    )
+    cfg = _served_config("mistral-7b-serve-l16")
 
     def described(make):
         return jax.tree.map(
@@ -206,9 +220,9 @@ def test_patterned_chunk_mid_keeps_its_expert_banks_in_place(
     are Pallas kernels under ``moe_ffn/experts``, they take the stacked banks
     whole (a layer's slice handed to a kernel was a 1.6 GB copy a layer: 3.9
     GB of temporaries), and the program fits beside 7.7 GB of weights."""
-    from ray_tpu.models.llama import LlamaConfig, init_kv_cache, init_params, prefill
+    from ray_tpu.models.llama import init_kv_cache, init_params, prefill
 
-    cfg = LlamaConfig.laguna_xs2(n_layers=5, max_seq_len=4096)
+    cfg = _served_config("laguna-xs.2-serve-l5")
 
     def described(make):
         return jax.tree.map(
@@ -233,3 +247,93 @@ def test_patterned_chunk_mid_keeps_its_expert_banks_in_place(
                if 'custom_call_target="tpu_custom_call"' in line]
     assert kernels and all("moe_ffn/experts" in line for line in kernels), kernels[:2]
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# ---- the engine's layout of the stacked attention input projections ---------
+
+
+def _projection_slice_ops(text, e, head_width=128):
+    """The operations of the entry computation and of the layer loop's body
+    (not of a fusion's own computation) whose result, or one of whose
+    results, has the shape of one layer's slice of a stacked attention input
+    projection: ``[1, e, h, 128]`` or ``[e, h, 128]``. A matmul that reads
+    the stacked leaf in place leaves none: its fusion takes the leaf whole
+    and the layer's index."""
+    import re
+
+    fused = set(re.findall(r"kind=k\w+, calls=(%[\w.\-]+)", text))
+    shape = re.compile(r"\[(?:1,)?%d,\d+,%d\]" % (e, head_width))
+    found, computation = [], None
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            head = line.split()
+            computation = head[1] if head[0] == "ENTRY" else head[0]
+            continue
+        if computation in fused or " = " not in line:
+            continue
+        rest = line.split(" = ", 1)[1]
+        depth = 0
+        for end, ch in enumerate(rest):  # the result's type: an array or a tuple
+            depth += (ch == "(") - (ch == ")")
+            if ch == " " and depth == 0:
+                break
+        op = rest[end + 1:].split("(", 1)[0]
+        if (shape.search(rest[:end]) and op not in ("parameter", "get-tuple-element")
+                and not op.endswith("-done")):
+            found.append(line.strip()[:200])
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode_step", "chunk_mid"])
+@pytest.mark.parametrize("served", sorted(_SERVED))
+def test_served_programs_read_a_layers_projection_slice_in_place(
+        served, program, one_chip, no_compile_cache, native_kernels):
+    """``decode_step`` over every slot and the 256-token
+    ``prefill(..., with_logits=False)`` at the serving cells' shapes, the
+    parameters in the formats the engine's rule gives
+    (``models/llama.py serving_layouts``): no operation outside a matmul's
+    own fusion yields a layer's slice of ``wq``, ``wk`` or ``wv``. Under the
+    default layout each slice is copied first (tiles over heads x head
+    width, contraction over ``d_model``): 3 such operations in Mistral's
+    decode step, 5 in its chunk, 13 and 24 in Laguna's (PERF.md section 6,
+    PR 29; on the chip 1.1 of a 14.7 ms decode step)."""
+    from jax.experimental.layout import Format, Layout
+
+    from ray_tpu.models.llama import (
+        decode_step, init_kv_cache, init_params, prefill, serving_layouts,
+    )
+
+    cfg = _served_config(served)
+    slots, stripe, e = _SERVED[served]
+
+    def described(make, orders=None):
+        tree = jax.eval_shape(make)
+        orders = serving_layouts(tree) if orders is None else orders
+        return {
+            k: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=(
+                Format(Layout(major_to_minor=orders[k]), one_chip) if k in orders else one_chip))
+            for k, x in tree.items()
+        }
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if program == "decode_step":
+        def fn(params, cache, tokens):
+            return decode_step(params, cache, tokens, cfg)
+
+        rest = (described(lambda: init_kv_cache(cfg, slots, stripe), {}), i32(slots))
+    else:
+        def fn(params, one, tokens, length, start):
+            return prefill(params, one, tokens, cfg, lengths=length, start_pos=start,
+                           with_logits=False)[1]
+
+        rest = (described(lambda: init_kv_cache(cfg, 1, stripe), {}), i32(1, 256), i32(1), i32(1))
+
+    def count(orders):
+        params = described(lambda: init_params(jax.random.PRNGKey(0), cfg), orders)
+        text = jax.jit(fn, donate_argnums=(1,)).lower(params, *rest).compile().as_text()
+        return _projection_slice_ops(text, e)
+
+    assert count(None) == []
+    assert count({})  # the guard sees the copies where the layout is the default

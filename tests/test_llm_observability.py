@@ -259,6 +259,27 @@ def test_counters_reach_the_metrics_scrape(engine):
     assert "llm_engine_live_tokens " in text
 
 
+def test_relaid_parameter_leaves_are_counted_and_follow_a_swap():
+    from ray_tpu.models.llama import init_params
+
+    def gauges():
+        return {k: float(v) for k, v in re.findall(
+            r"^llm_engine_params_relaid_(leaves|bytes) (\S+)$",
+            app_metrics.export_prometheus(), re.M)}
+
+    eng = _engine()
+    try:
+        held = {k: eng.params[k].nbytes for k in ("wq", "wk", "wv")}
+        want = {"leaves": 3, "bytes": sum(held.values())}
+        assert eng.get_stats()["params_relaid"] == want == gauges()
+        eng.params = None
+        assert eng.get_stats()["params_relaid"] == {"leaves": 0, "bytes": 0} == gauges()
+        eng.params = init_params(jax.random.PRNGKey(1), eng.model_cfg)
+        assert eng.get_stats()["params_relaid"] == want == gauges()
+    finally:
+        eng.shutdown()
+
+
 @pytest.mark.parametrize("stage", ["admission", "decode"])
 def test_injected_failure_lands_under_its_stage(engine, monkeypatch, stage):
     before = _counters(engine)
