@@ -92,7 +92,7 @@ class ModelConfig:
     # extra LlamaConfig overrides applied on top of the preset, any field of
     # it — e.g. {"moe_experts": 8, "moe_top_k": 2} serves a MoE variant (the
     # serving path is dropless: tokens sorted by expert through a grouped
-    # matmul, at every batch size, models/llama.py:_moe_decode_ffn;
+    # matmul, at every batch size, models/patterned.py:_moe_decode_ffn;
     # "moe_shared_d_ff" adds a shared expert, "moe_routed_scale" scales the
     # routed sum). The laguna presets' layers are not alike: a caller that
     # sets "n_layers" sets "layer_types", "heads_per_layer" and "mlp_types"

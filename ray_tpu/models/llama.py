@@ -1,4 +1,8 @@
-"""Llama-family decoder, TPU-first.
+"""Llama-family decoder, TPU-first: the config, the parameters, the
+whole-sequence path the train step traces, and the entry points of the path
+through a cache (``init_kv_cache``, ``prefill``, ``decode_step``), whose one
+body for every model is ``models/patterned.py decode_forward``. This file
+imports that module; nothing there imports this one.
 
 Pure-functional JAX: params are a pytree of stacked per-layer arrays scanned
 with ``lax.scan`` (one compiled layer body regardless of depth — keeps XLA
@@ -21,27 +25,25 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh
 
+from ray_tpu.models import patterned
+from ray_tpu.models.patterned import (
+    _embed_lookup,
+    _moe_shapes,
+    _param_shapes,
+    _project_logits,
+    _rmsnorm,
+    _shared_expert,
+    decode_forward,
+    scope,
+)
 from ray_tpu.parallel.mesh import logical_sharding, with_sharding
 from ray_tpu.parallel.ring_attention import (
     dense_attention,
-    full_attention_reference,
     ring_attention,
 )
 from ray_tpu.parallel.ulysses import ulysses_attention
-
-# ``jax.named_scope`` names, one vocabulary for the train step and the
-# engine's programs (which add ``kv_write``, ``sampling``, ``prefix_seed``):
-# embed, norm, attn_qkv (projections and rope), attn_core (the kernel; in
-# decode, attention over the cache), attn_out, ffn, moe_ffn, lm_head, loss,
-# optimizer, grad_norm. They sit inside the scanned layer body, so every
-# layer's work pools under one name, and are metadata only: each lands in the
-# ``op_name`` of the operations traced under it, which is what a device trace
-# is attributed by. The backward pass needs none of its own: JAX writes
-# ``jvp(..)`` and ``transpose(jvp(..))`` around the forward scope.
-scope = jax.named_scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,10 +58,10 @@ class LlamaConfig:
     rope_theta: float = 10000.0
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
-    # 'full' | 'ring' | 'ulysses' | 'splash' | 'flash'. ring/ulysses engage
-    # when the mesh has sp>1; splash/flash are Pallas TPU kernels with no
-    # partitioning rule: they need a tpu backend and an unsharded program,
-    # and raise anywhere else
+    # 'full' | 'ring' | 'ulysses' | 'splash'. ring/ulysses engage when the
+    # mesh has sp>1; splash is a Pallas TPU kernel with no partitioning
+    # rule: it needs a tpu backend and an unsharded program, and raises
+    # anywhere else
     attention: str = "full"
     # route rmsnorm through the fused Pallas kernel (ray_tpu.ops.rmsnorm).
     # Opt-in: pallas_call has no partitioning rule, so under a sharded pjit
@@ -94,10 +96,12 @@ class LlamaConfig:
     moe_d_ff: int = 0
     moe_shared_d_ff: int = 0
     moe_routed_scale: float = 1.0
-    # --- layers that are not alike (``models/patterned.py`` runs them) ---
-    # ``layer_types``: 'full' | 'sliding' for each layer; () = every layer as
-    # this file has it, and none of the fields below is read. A sliding
-    # layer's query at position i sees keys j with 0 <= i - j < window.
+    # --- layers that are not alike (``models/patterned.py plan``) ---
+    # ``layer_types``: 'full' | 'sliding' for each layer; () = every layer a
+    # full one of ``n_heads`` query heads, and the fields below keep their
+    # defaults (``attn_gate``, ``yarn_factor`` and ``rope_partial`` are
+    # refused without it). A sliding layer's query at position i sees keys j
+    # with 0 <= i - j < window.
     layer_types: tuple = ()
     heads_per_layer: tuple = ()  # query heads, one count an attention kind
     mlp_types: tuple = ()  # 'dense' | 'sparse' (the moe_* fields) by layer
@@ -118,6 +122,8 @@ class LlamaConfig:
     attn_gate: bool = False
 
     def __post_init__(self):
+        if self.attention not in ("full", "ring", "ulysses", "splash"):
+            raise ValueError(f"unknown attention {self.attention!r}")
         for name in ("layer_types", "heads_per_layer", "mlp_types"):
             value = tuple(getattr(self, name))
             object.__setattr__(self, name, value)
@@ -322,53 +328,6 @@ def param_shardings(cfg: LlamaConfig, mesh: Mesh, rules=None):
     }
 
 
-def _moe_shapes(cfg: LlamaConfig, n: int) -> dict[str, tuple]:
-    """The leaves of ``n`` stacked expert layers."""
-    e, E, f = cfg.d_model, cfg.moe_experts, cfg.moe_d_ff or cfg.d_ff
-    shapes = {
-        "moe_router": (n, e, E),
-        "moe_w_gate": (n, E, e, f),
-        "moe_w_up": (n, E, e, f),
-        "moe_w_down": (n, E, f, e),
-    }
-    if cfg.moe_shared_d_ff:
-        fs = cfg.moe_shared_d_ff
-        shapes.update({
-            "moe_shared_gate": (n, e, fs),
-            "moe_shared_up": (n, e, fs),
-            "moe_shared_down": (n, fs, e),
-        })
-    return shapes
-
-
-def _param_shapes(cfg: LlamaConfig) -> dict[str, tuple]:
-    if cfg.layer_types:
-        from ray_tpu.models.patterned import param_shapes
-
-        return param_shapes(cfg)
-    e, f, v = cfg.d_model, cfg.d_ff, cfg.vocab_size
-    h, kv, hd, L = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.n_layers
-    shapes = {
-        "embed": (v, e),
-        "final_norm": (e,),
-        "wq": (L, e, h, hd),
-        "wk": (L, e, kv, hd),
-        "wv": (L, e, kv, hd),
-        "wo": (L, h, hd, e),
-        "attn_norm": (L, e),
-        "mlp_norm": (L, e),
-    }
-    if cfg.moe_experts:
-        shapes.update(_moe_shapes(cfg, L))
-    else:
-        shapes.update(
-            {"w_gate": (L, e, f), "w_up": (L, e, f), "w_down": (L, f, e)}
-        )
-    if not cfg.tie_embeddings:
-        shapes["unembed"] = (e, v)
-    return shapes
-
-
 # How a served leaf lies on the chip. A matmul reads a layer's slice of a
 # stacked leaf in place only where the axis it contracts is one of the
 # leaf's two minor axes: the chip tiles those two. ``wo`` [.., h, hd, e] and
@@ -435,18 +394,6 @@ def init_params(key, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
     return params
 
 
-@scope("norm")
-def _rmsnorm(x, w, eps, fused: bool = False):
-    if fused:
-        from ray_tpu.ops import rmsnorm as _fused_rmsnorm
-
-        # one VMEM pass; output dtype = x.dtype (model weights share cfg.dtype)
-        return _fused_rmsnorm(x, w, eps)
-    x32 = x.astype(jnp.float32)
-    scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (x32 * scale).astype(x.dtype) * w
-
-
 def _rope(x, positions, theta):
     """x: [B, T, H, D], positions: [B, T]."""
     d = x.shape[-1]
@@ -464,18 +411,18 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh: Optional[Mesh]):
 
     The dense path is GQA-native (kv heads contracted directly, never
     repeated — ``jnp.repeat`` over a tp-sharded heads axis forces SPMD to
-    replicate the tensor). Ring/Ulysses/flash kernels expect equal head
+    replicate the tensor). The Ulysses and splash kernels expect equal head
     counts, so those paths still expand kv heads first."""
     sp = (
         mesh.shape.get("sp", 1)
         if mesh is not None and "sp" in mesh.axis_names
         else 1
     )
-    kernel = cfg.attention in ("flash", "splash")
+    kernel = cfg.attention == "splash"
     if kernel:
-        # pallas kernels are TPU-only and have no SPMD partitioning rule
+        # the pallas kernel is TPU-only and has no SPMD partitioning rule
         # (single-chip or per-replica programs only). Dense attention in
-        # their place would be a different program under the same name.
+        # its place would be a different program under the same name.
         backend = jax.default_backend()
         unsharded = mesh is None or all(s == 1 for s in mesh.shape.values())
         if backend != "tpu" or not unsharded:
@@ -498,8 +445,6 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh: Optional[Mesh]):
         return ulysses_attention(q, k, v, mesh, causal=True)
     if cfg.attention == "splash":
         return _splash_attention(q, k, v)
-    if cfg.attention == "flash":
-        return _flash_attention(q, k, v)
     return dense_attention(q, k, v, causal=True)
 
 
@@ -532,24 +477,6 @@ def _splash_attention(q, k, v):
     )
     out = jax.vmap(kernel)(qt, kt, vt)
     return jnp.swapaxes(out, 1, 2).astype(q.dtype)
-
-
-def _flash_attention(q, k, v):
-    """Pallas TPU flash attention: blockwise softmax in VMEM, never
-    materializing the [B, H, S, S] score matrix in HBM — the single biggest
-    HBM-bandwidth lever for long sequences (the kernel is TPU-only)."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        flash_attention as _pallas_flash,
-    )
-
-    # [B, T, H, D] -> [B, H, T, D]
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    out = _pallas_flash(
-        qt, kt, vt, causal=True, sm_scale=1.0 / math.sqrt(q.shape[-1])
-    )
-    return jnp.swapaxes(out, 1, 2)
 
 
 def _layer(layer_params, x, positions, cfg: LlamaConfig, mesh: Optional[Mesh]):
@@ -628,28 +555,6 @@ def _moe_ffn(p, h, cfg: LlamaConfig, mesh: Optional[Mesh]):
     return y, aux
 
 
-def _shared_expert(p, h):
-    """The expert every token passes through, ungated. h: [..., e]."""
-    with scope("shared_expert"):
-        ff = jax.nn.silu(h @ p["moe_shared_gate"]) * (h @ p["moe_shared_up"])
-        return ff @ p["moe_shared_down"]
-
-
-@scope("embed")
-def _embed_lookup(table, tokens, cfg: LlamaConfig, mesh: Optional[Mesh]):
-    """Token embedding. On a sharded mesh the row-gather is replaced by a
-    one-hot matmul: SPMD cannot partition a gather from a table sharded on
-    vocab (tp) and embed (fsdp) — it replicates the output ("involuntary
-    full rematerialization") — while a matmul contracts the sharded vocab
-    dim with a psum and lands directly in activation sharding. The backward
-    pass likewise becomes a matmul instead of a scatter-add."""
-    sharded = mesh is not None and any(s > 1 for s in mesh.shape.values())
-    if not sharded:
-        return table[tokens].astype(cfg.dtype)
-    onehot = jax.nn.one_hot(tokens, table.shape[0], dtype=cfg.dtype)
-    return jnp.einsum("btv,ve->bte", onehot, table.astype(cfg.dtype))
-
-
 def forward_hidden(
     params,
     tokens,
@@ -666,9 +571,7 @@ def forward_hidden(
     (``parallel/pipeline.py`` — native PP where the reference only passes
     ``pipeline_parallel_size`` to vLLM, ``vllm_models.py:176-190``)."""
     if cfg.layer_types:
-        from ray_tpu.models.patterned import forward_hidden as patterned_hidden
-
-        x = patterned_hidden(params, tokens, cfg, mesh, positions)
+        x = patterned.forward_hidden(params, tokens, cfg, mesh, positions)
         return (x, jnp.zeros((), jnp.float32)) if with_aux else x
     custom_positions = positions is not None
     if positions is None:
@@ -756,24 +659,6 @@ def _pipeline_hidden(stacked, x, cfg: LlamaConfig, mesh: Mesh, pp: int, policy):
     return out.reshape(B, T, e), aux / jnp.float32(M)
 
 
-@scope("lm_head")
-def _project_logits(x, params, cfg: LlamaConfig, mesh: Optional[Mesh]):
-    """Vocab projection shared by forward() and the training loss.
-
-    bf16 operands + fp32 accumulation: the MXU's native mode. Casting the
-    OPERANDS to fp32 would quarter matmul throughput on the vocab
-    projection (~20% of total train FLOPs) for no meaningful precision
-    gain — accumulation is fp32 either way."""
-    unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-    logits = jnp.einsum(
-        "bte,ev->btv", x, unembed.astype(x.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    if mesh is not None:
-        logits = with_sharding(mesh, logits, "batch", "seq", "vocab")
-    return logits
-
-
 def forward(
     params,
     tokens,
@@ -824,88 +709,6 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
 # ---------------------------------------------------------------------------
 
 
-# Rows of one call's routing counts (``_moe_decode_ffn``; summed over expert
-# layers by the caller): expert layers run, (token, expert) assignments,
-# experts that got at least one token, and the fullest expert's tokens.
-MOE_STATS = ("layer_steps", "assignments", "experts_touched", "max_expert_load")
-
-
-def _moe_decode_ffn(params, row, h, cfg: LlamaConfig):
-    """Dropless routed expert FFN for the serving path, and for ``forward``
-    of a model whose layers are not alike. ``params`` holds the stacked
-    ``moe_*`` leaves, ``row`` (static or traced) is this layer's row in them.
-    h: [B, T, e] -> ([B, T, e], routing counts int32 [4], ``MOE_STATS``).
-
-    Inference must never drop tokens (a capacity overflow at prefill would
-    silently corrupt the prompt — the reference's serving engine is likewise
-    dropless), so instead of the training path's capacity buffers
-    (``parallel/moe.py``) every token goes through exactly its top-k experts,
-    mixed with the renormalized gate weights (``topk_gates`` on float32
-    logits), times ``cfg.moe_routed_scale``, plus the shared expert where
-    ``cfg.moe_shared_d_ff`` is set.
-
-    One form at every size: the B*T*k assignments are sorted by expert and go
-    through three grouped matmuls (``ops/grouped_matmul.py``), so each expert
-    multiplies its own tokens only and only a touched expert's weights are
-    read. The form this replaced below 65 tokens, every expert over every
-    token as one batched einsum, streams all the weights whatever the routing:
-    on a v5e at 256 experts of 2048 x 512, 8 a token, a layer took 2.19 ms at
-    any batch against 0.57, 1.50, 1.98 and 2.46 ms grouped at 8, 32, 64 and
-    256 tokens (57, 165, 219 and 256 experts touched; PERF.md section 6, PR
-    28). The calls take the whole stacked bank as ``[layers * E, ..]`` with
-    this layer's group sizes at its own offset and zeros elsewhere: a layer's
-    slice of the bank handed to a kernel is a copy of it on the chip (1.6 GB
-    a layer at those widths).
-
-    Numerically identical to ``moe_dense`` whenever its capacity does not
-    overflow, which is what the decode-vs-forward exactness test pins."""
-    from ray_tpu.ops.grouped_matmul import grouped_matmul
-    from ray_tpu.parallel.moe import topk_gates
-
-    B, T, e = h.shape
-    E, k = cfg.moe_experts, cfg.moe_top_k
-    g = h.reshape(B * T, e)
-    G = g.shape[0]
-    with scope("router"):
-        # float32 logits: in the model's own bf16 the 8th and 9th of 256
-        # experts swap for some tokens on rounding alone
-        _, gate_vals, gate_idx = topk_gates(
-            {"router": params["moe_router"][row].astype(jnp.float32)},
-            g.astype(jnp.float32), k,
-        )
-        # tokens an expert: a one-hot sum (a scatter-add is slow on the chip)
-        load = jax.nn.one_hot(gate_idx.reshape(-1), E, dtype=jnp.int32).sum(axis=0)
-        stats = jnp.stack([
-            jnp.int32(1), jnp.int32(G * k), (load > 0).sum(dtype=jnp.int32), load.max(),
-        ])
-    with scope("experts"):
-        order = jnp.argsort(gate_idx.reshape(-1))  # assignments by expert
-        rows = g[order // k]  # [G*k, e]: each assignment's token
-        n = params["moe_w_gate"].shape[0]
-        sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((n * E,), jnp.int32), load, (row * E,)
-        )
-
-        def bank(name):
-            w = params[name]
-            return w.reshape((n * E,) + w.shape[2:])
-
-        gate = grouped_matmul(rows, bank("moe_w_gate"), sizes)
-        up = grouped_matmul(rows, bank("moe_w_up"), sizes)
-        out = grouped_matmul(
-            jax.nn.silu(gate) * up, bank("moe_w_down"), sizes, jnp.float32
-        )
-        # back to token order: a gather, not a scatter-add
-        out = out[jnp.argsort(order)].reshape(G, k, e)
-        y = jnp.einsum("gkd,gk->gd", out, gate_vals) * cfg.moe_routed_scale
-        y = y.astype(g.dtype)
-    if cfg.moe_shared_d_ff:
-        y = y + _shared_expert(
-            {n: params[n][row] for n in params if n.startswith("moe_shared_")}, g
-        )
-    return y.reshape(B, T, e), stats
-
-
 def init_kv_cache(cfg: LlamaConfig, batch_size: int, max_len: Optional[int] = None):
     """KV cache [L, B, KV_HEADS, S, D] — head-major so each (batch, head)
     attention read streams a contiguous S×D block from HBM (position-major
@@ -939,255 +742,6 @@ def init_lora_stack(cfg: LlamaConfig, n_adapters: int, rank: int):
     }
 
 
-# Widest batch whose rows ``_decode_forward`` writes as one contiguous
-# block each. On a v5e (PERF.md section 6, PR 27; a tensor and layer) a block
-# costs about 2 us and 5 ns for each of the row's K*T cache rows (a window
-# read, a select, an in-place ``dynamic_update_slice``: 11 us for a 256-token
-# chunk), the scatter 73-90 ns a cache row (150 us for the same chunk), both
-# linear in B. So the block wins wherever a row brings more than ~32 cache
-# rows, as every prompt chunk does, and loses at decode's T = 1 (B = 32: 69 us
-# against 23). The blocks are unrolled into the layer loop's body and the cap
-# only bounds that program: the engine's scratch stripe has B = 1, the
-# benchmark's probe B = 2; a wider gang batch (``llm/spmd.py``) is scattered.
-_BLOCK_WRITE_MAX_BATCH = 8
-
-
-def _write_block(c_all, new, l, b, start, ok):
-    """Write row ``b``'s new keys or values ``new`` [K, T, D], whose
-    positions are ``start + arange(T)``, into layer ``l`` of the carried
-    cache ``c_all`` [L, B, K, S, D] as ONE contiguous block, leaving exactly
-    the bytes the ``mode="drop"`` scatter leaves.
-
-    The block is the window ``[w, w + T)`` with ``w = min(start, S - T)``
-    computed here: ``dynamic_update_slice`` would clamp a start that runs
-    past the axis and silently shift every row, so the shift is made
-    explicit (``new`` rolled right by ``start - w``) and never left to the
-    clamp. The window's old bytes are read first and kept wherever the
-    scatter wrote nothing: padding (``ok`` [T] false), positions at or past
-    ``S``, and the slots before ``start`` that a shifted window covers."""
-    K, T, D = new.shape
-    S = c_all.shape[3]
-    w = jnp.clip(start, 0, S - T)
-    shift = start - w
-    at = (l, b, 0, w, 0)
-    old = jax.lax.dynamic_slice(c_all, at, (1, 1, K, T, D))
-    keep_new = (jnp.arange(T) >= shift) & jnp.roll(ok, shift)
-    block = jnp.where(
-        keep_new[None, :, None], jnp.roll(new, shift, axis=1), old[0, 0]
-    )
-    return jax.lax.dynamic_update_slice(c_all, block[None, None], at)
-
-
-def _ride_stats(cache, new_cache, stats) -> None:
-    """A cache that comes in with a ``moe_stats`` leaf (int32 [4],
-    ``MOE_STATS``) goes out with this call's routing counts added to it: how
-    the engine's programs get them out without a fetch of their own, and how
-    a prompt's chunks add theirs up on the device. Any other cache is left
-    as ``init_kv_cache`` made it."""
-    if stats and "moe_stats" in cache:
-        new_cache["moe_stats"] = cache["moe_stats"] + stats[0]
-
-
-def _cache_writer(cfg: LlamaConfig, S: int, positions, valid, start_pos):
-    """``write(c_all, new, l)`` for ``_decode_forward`` (and
-    ``models/patterned.py``): new keys or values [B, K, T, D] into layer ``l``
-    of the carried cache [L, B, K, S, D], as blocks or as the scatter (see
-    ``_decode_forward``)."""
-    B, T = positions.shape
-    as_blocks = (
-        start_pos is not None and B <= _BLOCK_WRITE_MAX_BATCH and T <= S
-    )
-    if as_blocks:
-        ok = jnp.ones((B, T), bool) if valid is None else valid
-
-        def write(c_all, new, l):
-            for b in range(B):
-                c_all = _write_block(c_all, new[b], l, b, start_pos[b], ok[b])
-            return c_all
-    else:
-        if valid is not None:
-            # out-of-range index -> dropped by scatter mode='drop'
-            write_pos = jnp.where(valid, positions, S)
-        else:
-            write_pos = positions
-        bi = jnp.arange(B)[:, None, None]
-        ki = jnp.arange(cfg.n_kv_heads)[None, :, None]
-        pi = write_pos[:, None, :]  # [B, 1, T]
-
-        def write(c_all, new, l):
-            return c_all.at[l, bi, ki, pi].set(new, mode="drop")
-    return write
-
-
-def _grouped_attention(q, k, v, mask):
-    """GQA over the keys the mask allows, without materializing repeated
-    K/V. q: [B, T, H, D]; k, v: [B, K, S, D] (head-major, as the cache keeps
-    them); mask: [B, T, S]."""
-    B, T, H, D = q.shape
-    K = k.shape[1]
-    qg = q.reshape(B, T, K, H // K, D)
-    s = jnp.einsum("btkgd,bksd->bktgs", qg, k) * D**-0.5
-    s = jnp.where(mask[:, None, :, None, :], s, -1e30)
-    w = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bktgs,bksd->btkgd", w, v).reshape(B, T, H, D)
-
-
-def _dense_ffn(h, p):
-    """SwiGLU of h [B, T, e]; ``p(name)`` hands out this layer's ``w_gate``,
-    ``w_up``, ``w_down`` when asked (a layer's slice of a stacked weight is a
-    copy on the chip: it is taken where it is used)."""
-    ff = jax.nn.silu(
-        jnp.einsum("bte,ef->btf", h, p("w_gate"))
-    ) * jnp.einsum("bte,ef->btf", h, p("w_up"))
-    return jnp.einsum("btf,fe->bte", ff, p("w_down"))
-
-
-def _decode_forward(
-    params, cache, tokens, positions, cfg: LlamaConfig, valid=None,
-    loras=None, adapter_ids=None, with_logits: bool = True,
-    logits_at=None, start_pos=None,
-):
-    """Shared prefill/decode body. tokens: [B, T]; positions: [B, T].
-    New k/v are written into the cache before attention so new tokens
-    attend to themselves and to all prior cache slots. ``valid`` [B, T]
-    marks real (non-padding) tokens; padding writes leave the cache's old
-    bytes where they are, so later decode steps never attend to stale slots
-    and whatever copies a stripe out (the engine's stripe-to-slot copy, the
-    prefix cache's store, the disaggregated hand-over) carries none.
-
-    Two forms of one write, chosen from what is static at trace time, with
-    the same bytes in the same slots. ``start_pos`` [B] is the caller's word
-    that row ``b``'s positions are ``start_pos[b] + arange(T)`` (every call
-    through ``prefill``): while ``B <= _BLOCK_WRITE_MAX_BATCH`` and the chunk
-    fits the cache (``T <= S``) each row is one contiguous block a tensor and
-    layer (``_write_block``: padding and the stripe's end keep old bytes).
-    Otherwise (``decode_step``: T = 1, every row at an unrelated position;
-    a wide batch) the ``[B, K, T]``-index scatter with ``mode="drop"``.
-    The cache's position axis is never sharded (``llm/spmd.py`` shards the
-    key-value heads), so a block partitions over heads as the scatter does.
-
-    ``loras``/``adapter_ids``: stacked LoRA adapters + per-sequence adapter
-    index (0 = base).
-    ``logits_at`` [B]: project the LM head at ONLY this position per
-    sequence (returns [B, 1, V]) — prefill needs one next-token
-    distribution, and the full [B, T, V] projection is the single biggest
-    prefill allocation (0.5 GB/seq at 7B/128k-vocab scale: the allocation
-    that kept 7B from fitting one v5e chip)."""
-    if cfg.layer_types:
-        from ray_tpu.models.patterned import decode_forward
-
-        return decode_forward(
-            params, cache, tokens, positions, cfg, valid, loras=loras,
-            with_logits=with_logits, logits_at=logits_at, start_pos=start_pos,
-        )
-    B, T = tokens.shape
-    S = cache["k"].shape[3]  # [L, B, K, S, D]
-    with scope("embed"):
-        x = params["embed"][tokens].astype(cfg.dtype)
-
-    new_len = cache["length"] + T
-    slot = jnp.arange(S)[None, None, :]  # [1, 1, S]
-    qpos = positions[:, :, None]  # [B, T, 1]
-    seq_mask = slot <= qpos  # causal over absolute positions
-
-    write = _cache_writer(cfg, S, positions, valid, start_pos)
-
-    groups = cfg.n_heads // cfg.n_kv_heads
-    scale = cfg.head_dim**-0.5
-
-    # fori_loop with the FULL cache as carry — the per-layer cache writes
-    # alias in place (donated buffers), where a lax.scan carrying per-layer
-    # cache slices as ys re-materializes the whole cache every step (decode
-    # measured 1.6x slower from those copies alone at 3B/B=16 on v5e).
-    def body(l, carry):
-        x, ck_all, cv_all, *stats = carry
-        # a layer's slice of a stacked weight is a copy on the chip (1.1 ms
-        # of a 14.7 ms decode step at 7B widths): take it inside the scope
-        # that uses it, so that it is booked there
-        def p(k):
-            return params[k][l]
-
-        h = _rmsnorm(x, p("attn_norm"), cfg.rms_eps, cfg.fused_rmsnorm)
-        with scope("attn_qkv"):
-            q = jnp.einsum("bte,ehd->bthd", h, p("wq"))
-            k = jnp.einsum("bte,ehd->bthd", h, p("wk"))
-            v = jnp.einsum("bte,ehd->bthd", h, p("wv"))
-            if loras is not None:
-                # per-sequence adapter gather + low-rank delta: W x + B(A x)
-                lp = {n: loras[n][l] for n in ("wq_a", "wq_b", "wv_a", "wv_b")}
-                q = q + jnp.einsum(
-                    "btr,brhd->bthd",
-                    jnp.einsum("bte,ber->btr", h, lp["wq_a"][adapter_ids]),
-                    lp["wq_b"][adapter_ids],
-                )
-                v = v + jnp.einsum(
-                    "btr,brhd->bthd",
-                    jnp.einsum("bte,ber->btr", h, lp["wv_a"][adapter_ids]),
-                    lp["wv_b"][adapter_ids],
-                )
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
-        with scope("kv_write"):
-            # cache is [B, K, S, D]: write the new [B, T, K, D] rows head-major
-            kh = k.transpose(0, 2, 1, 3)  # [B, K, T, D]
-            vh = v.transpose(0, 2, 1, 3)
-            ck_all = write(ck_all, kh, l)
-            cv_all = write(cv_all, vh, l)
-
-        with scope("attn_core"):
-            ck = ck_all[l]
-            cv = cv_all[l]
-            if groups > 1:
-                # GQA without materializing repeated K/V: fold the group axis
-                # into the query instead (a jnp.repeat here would write+reread
-                # the whole cache ×groups per layer per step — at 3B/B=16 that
-                # alone is ~11 GB of HBM traffic per decode step)
-                attn = _grouped_attention(q, ck, cv, seq_mask)
-            else:
-                s = jnp.einsum("bthd,bhsd->bhts", q, ck) * scale
-                s = jnp.where(seq_mask[:, None, :, :], s, -1e30)
-                w = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(x.dtype)
-                attn = jnp.einsum("bhts,bhsd->bthd", w, cv)
-        with scope("attn_out"):
-            x = x + jnp.einsum("bthd,hde->bte", attn, p("wo"))
-
-        h = _rmsnorm(x, p("mlp_norm"), cfg.rms_eps, cfg.fused_rmsnorm)
-        if cfg.moe_experts:
-            with scope("moe_ffn"):
-                y, layer_stats = _moe_decode_ffn(params, l, h, cfg)
-                x = x + y
-                stats = [stats[0] + layer_stats]
-        else:
-            with scope("ffn"):
-                x = x + _dense_ffn(h, p)
-        return (x, ck_all, cv_all, *stats)
-
-    # a model with routed experts carries its routing counts beside x
-    stats0 = (jnp.zeros((len(MOE_STATS),), jnp.int32),) if cfg.moe_experts else ()
-    x, new_k, new_v, *stats = jax.lax.fori_loop(
-        0, cfg.n_layers, body, (x, cache["k"], cache["v"], *stats0)
-    )
-    new_cache = {"k": new_k, "v": new_v, "length": new_len}
-    _ride_stats(cache, new_cache, stats)
-    if not with_logits:
-        # mid-chunk prefill: the caller only extends the KV cache — skip the
-        # LM head (the vocab projection reads ~0.8 GB of weights at 128k
-        # vocab; chunked admission would pay it once per chunk otherwise)
-        return None, new_cache
-    if logits_at is not None:
-        # gather the single requested hidden state per sequence BEFORE the
-        # vocab projection: [B, T, e] -> [B, 1, e]
-        x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
-    x = _rmsnorm(x, params["final_norm"], cfg.rms_eps, cfg.fused_rmsnorm)
-    with scope("lm_head"):
-        unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-        logits = jnp.einsum(
-            "bte,ev->btv", x, unembed.astype(x.dtype),
-            preferred_element_type=jnp.float32,
-        )
-    return logits, new_cache
-
-
 def prefill(
     params, cache, tokens, cfg: LlamaConfig, lengths=None,
     loras=None, adapter_ids=None, start_pos=None, with_logits: bool = True,
@@ -1207,7 +761,7 @@ def prefill(
         start_pos = jnp.zeros((B,), jnp.int32)
     positions = rel + start_pos[:, None]
     valid = rel < lengths[:, None]
-    logits, cache = _decode_forward(
+    logits, cache = decode_forward(
         params, cache, tokens, positions, cfg, valid,
         loras=loras, adapter_ids=adapter_ids, with_logits=with_logits,
         logits_at=None if not with_logits else lengths - 1,
@@ -1226,7 +780,7 @@ def decode_step(
     if tokens.ndim == 1:
         tokens = tokens[:, None]
     positions = cache["length"][:, None]
-    logits, cache = _decode_forward(
+    logits, cache = decode_forward(
         params, cache, tokens, positions, cfg,
         loras=loras, adapter_ids=adapter_ids,
     )

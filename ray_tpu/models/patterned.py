@@ -1,24 +1,35 @@
-"""Decoders whose layers are not alike: full and sliding-window attention
+"""A decoder as a pattern of layers, and the one body that carries tokens
+through the cache for every model the engine serves.
+
+``plan(cfg)`` reads a config as ``lead`` layers, then ``reps`` times a period
+of ``period`` layers, then the rest. A decoder whose layers are alike
+(``cfg.layer_types == ()``) is the period-1 pattern: no lead, one layer
+``n_layers`` times, full attention with ``n_heads`` query heads, dense or (with
+``moe_experts``) routed-expert feed-forwards, its projections in ``wq`` /
+``wo``. One whose layers are not alike mixes full and sliding-window attention
 layers with their own query-head counts and rotary settings, a per-head gate
-on the attention output, and dense and routed-expert feed-forwards, mixed by a
-per-layer pattern (``LlamaConfig.layer_types``, ``heads_per_layer``,
-``mlp_types``; poolside Laguna-XS.2 is the published instance,
-``LlamaConfig.laguna_xs2``).
+on the attention output, and dense and expert feed-forwards, by
+``cfg.layer_types``, ``heads_per_layer`` and ``mlp_types`` (poolside
+Laguna-XS.2 is the published instance, ``LlamaConfig.laguna_xs2``).
 
-``models/llama.py`` stays the entry point: its ``forward``, ``prefill``,
-``decode_step`` and ``init_kv_cache`` hand on to this module whenever
-``cfg.layer_types`` is set, so the engine and everything else that serves or
-checks a model calls the same four functions for both.
+``models/llama.py`` is the entry point and imports this module, never the
+other way round: its ``prefill`` and ``decode_step`` call ``decode_forward``
+here for every config, its ``forward_hidden`` hands a model whose layers are
+not alike to ``forward_hidden`` here (one device; the whole-sequence path of a
+uniform model, with its scan, remat policy, pipeline and mesh constraints,
+stays there), and the pieces both need (``_rmsnorm``, ``_project_logits``,
+``_embed_lookup``, ``_shared_expert``, the parameter shapes) live here.
 
-Parameters are one flat ``name -> array`` dict, as ``models/llama.py`` has
-it. Leaves whose shape every layer shares (``wk``, ``wv``, the two norms) are
-stacked over all layers; the others by the group their shape follows:
-``wq_full`` / ``wq_sliding`` (and ``wo_``, ``wg_``) by attention kind,
-``w_gate`` .. over the dense feed-forward layers, ``moe_*`` over the expert
-layers. The layer stack is traced as its leading layers, then one body of a
-whole period under a ``fori_loop`` (the published 40 layers: layer 0, nine
-times [sliding, sliding, sliding, full], three more sliding), never one body
-a layer.
+Parameters are one flat ``name -> array`` dict. Leaves whose shape every
+layer shares (``wk``, ``wv``, the two norms) are stacked over all layers; the
+others by the group their shape follows: the query and output projections by
+attention kind (``wq_full`` / ``wq_sliding``, ``wo_``, ``wg_``; plain ``wq`` /
+``wo`` where the layers are alike), ``w_gate`` .. over the dense feed-forward
+layers, ``moe_*`` over the expert layers. The stack is traced as its leading
+layers, then one body of a whole period under a ``fori_loop`` (a uniform
+model: one layer body; the published Laguna's 40 layers: layer 0, nine times
+[sliding, sliding, sliding, full], three more sliding), never one body a
+layer.
 
 The cache is ``init_kv_cache``'s: ``[L, B, K, S, D]``, every layer a whole
 stripe. A sliding layer reads only its window from it (a slice of at most
@@ -27,6 +38,7 @@ bandwidth now and memory only once a layer may own a shorter stripe."""
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -37,24 +49,23 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from ray_tpu.models.llama import (
-    MOE_STATS,
-    LlamaConfig,
-    _cache_writer,
-    _dense_ffn,
-    _embed_lookup,
-    _grouped_attention,
-    _moe_decode_ffn,
-    _moe_shapes,
-    _project_logits,
-    _ride_stats,
-    _rmsnorm,
-    scope,
-)
+from ray_tpu.parallel.mesh import with_sharding
+
+# ``jax.named_scope`` names, one vocabulary for the train step and the
+# engine's programs (which add ``kv_write``, ``sampling``, ``prefix_seed``):
+# embed, norm, attn_qkv (projections and rope), attn_core (the kernel; in
+# decode, attention over the cache), attn_out, ffn, moe_ffn, lm_head, loss,
+# optimizer, grad_norm. They sit inside the layer body, so every layer's work
+# pools under one name, and are metadata only: each lands in the ``op_name``
+# of the operations traced under it, which is what a device trace is
+# attributed by. The backward pass needs none of its own: JAX writes
+# ``jvp(..)`` and ``transpose(jvp(..))`` around the forward scope.
+scope = jax.named_scope
 
 # a window's first position in the stripe is rounded down to a multiple of
 # this, so that the slice starts on a tile of the cache's position axis
 _WINDOW_ALIGN = 128
+# the name under ``attn_core`` of a layer whose attention kind is named
 _SCOPE_OF_KIND = {"full": "global", "sliding": "window"}
 
 
@@ -65,24 +76,39 @@ _SCOPE_OF_KIND = {"full": "global", "sliding": "window"}
 class Plan:
     """How the layer stack is traced: ``lead`` layers one by one, ``reps``
     times a period of ``period`` layers in one loop body, then the rest.
-    ``attn_index[l]`` / ``mlp_index[l]``: layer l's row in the stack of its
-    attention kind / feed-forward kind."""
+    ``kinds[l]``: layer l's (attention kind, query heads, feed-forward kind).
+    ``attn_index[l]`` / ``mlp_index[l]``: its row in the stack of its
+    attention kind / feed-forward kind. ``by_kind``: the layers are not
+    alike, so the projections' leaves and the scope under ``attn_core``
+    carry the attention kind's name."""
 
     lead: int
     period: int
     reps: int
+    kinds: tuple
     attn_index: tuple
     mlp_index: tuple
+    by_kind: bool
 
     @property
     def tail_from(self) -> int:
         return self.lead + self.period * self.reps
 
+    def leaf(self, name: str, kind: str) -> str:
+        """The leaf that holds projection ``name`` of attention kind ``kind``."""
+        return f"{name}_{kind}" if self.by_kind else name
+
 
 @functools.lru_cache(maxsize=None)
-def plan(cfg: LlamaConfig) -> Plan:
+def plan(cfg) -> Plan:
     L = cfg.n_layers
-    kinds = list(zip(cfg.layer_types, cfg.heads_per_layer, cfg.mlp_types))
+    if cfg.layer_types:
+        kinds = list(zip(cfg.layer_types, cfg.heads_per_layer, cfg.mlp_types))
+    else:
+        if cfg.attn_gate or cfg.yarn_factor or cfg.rope_partial != 1.0:
+            # the whole-sequence path of a uniform model knows none of them
+            raise ValueError("attn_gate, yarn_factor and rope_partial need layer_types")
+        kinds = [("full", cfg.n_heads, "sparse" if cfg.moe_experts else "dense")] * L
     for t, h, m in kinds:
         if t not in _SCOPE_OF_KIND or m not in ("dense", "sparse"):
             raise ValueError(f"unknown layer kind ({t!r}, {m!r})")
@@ -91,9 +117,9 @@ def plan(cfg: LlamaConfig) -> Plan:
     for t in _SCOPE_OF_KIND:
         if len({h for kt, h, _ in kinds if kt == t}) > 1:
             raise ValueError(f"{t} attention layers differ in their query heads")
-    if "sliding" in cfg.layer_types and cfg.sliding_window <= 0:
+    if any(t == "sliding" for t, _, _ in kinds) and cfg.sliding_window <= 0:
         raise ValueError("sliding layers need sliding_window")
-    if "sparse" in cfg.mlp_types and not cfg.moe_experts:
+    if any(m == "sparse" for _, _, m in kinds) and not cfg.moe_experts:
         raise ValueError("sparse layers need moe_experts")
     # the split that traces the fewest layer bodies
     best = None
@@ -116,11 +142,31 @@ def plan(cfg: LlamaConfig) -> Plan:
         mlp_index.append(seen.setdefault(m, 0))
         seen[t] += 1
         seen[m] += 1
-    return Plan(lead, period, reps, tuple(attn_index), tuple(mlp_index))
+    return Plan(lead, period, reps, tuple(kinds), tuple(attn_index), tuple(mlp_index),
+                bool(cfg.layer_types))
 
 
-def param_shapes(cfg: LlamaConfig) -> dict[str, tuple]:
-    plan(cfg)  # validates the pattern
+def _moe_shapes(cfg, n: int) -> dict[str, tuple]:
+    """The leaves of ``n`` stacked expert layers."""
+    e, E, f = cfg.d_model, cfg.moe_experts, cfg.moe_d_ff or cfg.d_ff
+    shapes = {
+        "moe_router": (n, e, E),
+        "moe_w_gate": (n, E, e, f),
+        "moe_w_up": (n, E, e, f),
+        "moe_w_down": (n, E, f, e),
+    }
+    if cfg.moe_shared_d_ff:
+        fs = cfg.moe_shared_d_ff
+        shapes.update({
+            "moe_shared_gate": (n, e, fs),
+            "moe_shared_up": (n, e, fs),
+            "moe_shared_down": (n, fs, e),
+        })
+    return shapes
+
+
+def _param_shapes(cfg) -> dict[str, tuple]:
+    pl = plan(cfg)  # validates the pattern
     e, v, L = cfg.d_model, cfg.vocab_size, cfg.n_layers
     kv, hd = cfg.n_kv_heads, cfg.head_dim
     shapes = {
@@ -131,16 +177,13 @@ def param_shapes(cfg: LlamaConfig) -> dict[str, tuple]:
         "attn_norm": (L, e),
         "mlp_norm": (L, e),
     }
-    for kind in _SCOPE_OF_KIND:
-        n = cfg.layer_types.count(kind)
-        if not n:
-            continue
-        h = cfg.heads_per_layer[cfg.layer_types.index(kind)]
-        shapes["wq_" + kind] = (n, e, h, hd)
-        shapes["wo_" + kind] = (n, h, hd, e)
+    for kind, h in {t: h for t, h, _ in pl.kinds}.items():
+        n = sum(t == kind for t, _, _ in pl.kinds)
+        shapes[pl.leaf("wq", kind)] = (n, e, h, hd)
+        shapes[pl.leaf("wo", kind)] = (n, h, hd, e)
         if cfg.attn_gate:
-            shapes["wg_" + kind] = (n, e, h)
-    n_dense = cfg.mlp_types.count("dense")
+            shapes[pl.leaf("wg", kind)] = (n, e, h)
+    n_dense = sum(m == "dense" for _, _, m in pl.kinds)
     if n_dense:
         f = cfg.d_ff
         shapes.update({"w_gate": (n_dense, e, f), "w_up": (n_dense, e, f),
@@ -152,16 +195,265 @@ def param_shapes(cfg: LlamaConfig) -> dict[str, tuple]:
     return shapes
 
 
+# ------------------------------------------- pieces every path shares
+
+
+@scope("norm")
+def _rmsnorm(x, w, eps, fused: bool = False):
+    if fused:
+        from ray_tpu.ops import rmsnorm as _fused_rmsnorm
+
+        # one VMEM pass; output dtype = x.dtype (model weights share cfg.dtype)
+        return _fused_rmsnorm(x, w, eps)
+    x32 = x.astype(jnp.float32)
+    scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * scale).astype(x.dtype) * w
+
+
+
+@scope("embed")
+def _embed_lookup(table, tokens, cfg, mesh: Optional[Mesh]):
+    """Token embedding. On a sharded mesh the row-gather is replaced by a
+    one-hot matmul: SPMD cannot partition a gather from a table sharded on
+    vocab (tp) and embed (fsdp) — it replicates the output ("involuntary
+    full rematerialization") — while a matmul contracts the sharded vocab
+    dim with a psum and lands directly in activation sharding. The backward
+    pass likewise becomes a matmul instead of a scatter-add."""
+    sharded = mesh is not None and any(s > 1 for s in mesh.shape.values())
+    if not sharded:
+        return table[tokens].astype(cfg.dtype)
+    onehot = jax.nn.one_hot(tokens, table.shape[0], dtype=cfg.dtype)
+    return jnp.einsum("btv,ve->bte", onehot, table.astype(cfg.dtype))
+
+
+
+@scope("lm_head")
+def _project_logits(x, params, cfg, mesh: Optional[Mesh]):
+    """Vocab projection shared by forward() and the training loss.
+
+    bf16 operands + fp32 accumulation: the MXU's native mode. Casting the
+    OPERANDS to fp32 would quarter matmul throughput on the vocab
+    projection (~20% of total train FLOPs) for no meaningful precision
+    gain — accumulation is fp32 either way."""
+    unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
+    logits = jnp.einsum(
+        "bte,ev->btv", x, unembed.astype(x.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    if mesh is not None:
+        logits = with_sharding(mesh, logits, "batch", "seq", "vocab")
+    return logits
+
+
+
+def _shared_expert(p, h):
+    """The expert every token passes through, ungated. h: [..., e]."""
+    with scope("shared_expert"):
+        ff = jax.nn.silu(h @ p["moe_shared_gate"]) * (h @ p["moe_shared_up"])
+        return ff @ p["moe_shared_down"]
+
+
+
+# --------------------------------- pieces of the path through a cache
+
+
+# Rows of one call's routing counts (``_moe_decode_ffn``; summed over expert
+# layers by the caller): expert layers run, (token, expert) assignments,
+# experts that got at least one token, and the fullest expert's tokens.
+MOE_STATS = ("layer_steps", "assignments", "experts_touched", "max_expert_load")
+
+
+def _moe_decode_ffn(params, row, h, cfg):
+    """Dropless routed expert FFN for the serving path, and for ``forward``
+    of a model whose layers are not alike. ``params`` holds the stacked
+    ``moe_*`` leaves, ``row`` (static or traced) is this layer's row in them.
+    h: [B, T, e] -> ([B, T, e], routing counts int32 [4], ``MOE_STATS``).
+
+    Inference must never drop tokens (a capacity overflow at prefill would
+    silently corrupt the prompt — the reference's serving engine is likewise
+    dropless), so instead of the training path's capacity buffers
+    (``parallel/moe.py``) every token goes through exactly its top-k experts,
+    mixed with the renormalized gate weights (``topk_gates`` on float32
+    logits), times ``cfg.moe_routed_scale``, plus the shared expert where
+    ``cfg.moe_shared_d_ff`` is set.
+
+    One form at every size: the B*T*k assignments are sorted by expert and go
+    through three grouped matmuls (``ops/grouped_matmul.py``), so each expert
+    multiplies its own tokens only and only a touched expert's weights are
+    read. The form this replaced below 65 tokens, every expert over every
+    token as one batched einsum, streams all the weights whatever the routing:
+    on a v5e at 256 experts of 2048 x 512, 8 a token, a layer took 2.19 ms at
+    any batch against 0.57, 1.50, 1.98 and 2.46 ms grouped at 8, 32, 64 and
+    256 tokens (57, 165, 219 and 256 experts touched; PERF.md section 6, PR
+    28). The calls take the whole stacked bank as ``[layers * E, ..]`` with
+    this layer's group sizes at its own offset and zeros elsewhere: a layer's
+    slice of the bank handed to a kernel is a copy of it on the chip (1.6 GB
+    a layer at those widths).
+
+    Numerically identical to ``moe_dense`` whenever its capacity does not
+    overflow, which is what the decode-vs-forward exactness test pins."""
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+    from ray_tpu.parallel.moe import topk_gates
+
+    B, T, e = h.shape
+    E, k = cfg.moe_experts, cfg.moe_top_k
+    g = h.reshape(B * T, e)
+    G = g.shape[0]
+    with scope("router"):
+        # float32 logits: in the model's own bf16 the 8th and 9th of 256
+        # experts swap for some tokens on rounding alone
+        _, gate_vals, gate_idx = topk_gates(
+            {"router": params["moe_router"][row].astype(jnp.float32)},
+            g.astype(jnp.float32), k,
+        )
+        # tokens an expert: a one-hot sum (a scatter-add is slow on the chip)
+        load = jax.nn.one_hot(gate_idx.reshape(-1), E, dtype=jnp.int32).sum(axis=0)
+        stats = jnp.stack([
+            jnp.int32(1), jnp.int32(G * k), (load > 0).sum(dtype=jnp.int32), load.max(),
+        ])
+    with scope("experts"):
+        order = jnp.argsort(gate_idx.reshape(-1))  # assignments by expert
+        rows = g[order // k]  # [G*k, e]: each assignment's token
+        n = params["moe_w_gate"].shape[0]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n * E,), jnp.int32), load, (row * E,)
+        )
+
+        def bank(name):
+            w = params[name]
+            return w.reshape((n * E,) + w.shape[2:])
+
+        gate = grouped_matmul(rows, bank("moe_w_gate"), sizes)
+        up = grouped_matmul(rows, bank("moe_w_up"), sizes)
+        out = grouped_matmul(
+            jax.nn.silu(gate) * up, bank("moe_w_down"), sizes, jnp.float32
+        )
+        # back to token order: a gather, not a scatter-add
+        out = out[jnp.argsort(order)].reshape(G, k, e)
+        y = jnp.einsum("gkd,gk->gd", out, gate_vals) * cfg.moe_routed_scale
+        y = y.astype(g.dtype)
+    if cfg.moe_shared_d_ff:
+        y = y + _shared_expert(
+            {n: params[n][row] for n in params if n.startswith("moe_shared_")}, g
+        )
+    return y.reshape(B, T, e), stats
+
+
+
+# Widest batch whose rows ``decode_forward`` writes as one contiguous
+# block each. On a v5e (PERF.md section 6, PR 27; a tensor and layer) a block
+# costs about 2 us and 5 ns for each of the row's K*T cache rows (a window
+# read, a select, an in-place ``dynamic_update_slice``: 11 us for a 256-token
+# chunk), the scatter 73-90 ns a cache row (150 us for the same chunk), both
+# linear in B. So the block wins wherever a row brings more than ~32 cache
+# rows, as every prompt chunk does, and loses at decode's T = 1 (B = 32: 69 us
+# against 23). The blocks are unrolled into the layer loop's body and the cap
+# only bounds that program: the engine's scratch stripe has B = 1, the
+# benchmark's probe B = 2; a wider gang batch (``llm/spmd.py``) is scattered.
+_BLOCK_WRITE_MAX_BATCH = 8
+
+
+def _write_block(c_all, new, l, b, start, ok):
+    """Write row ``b``'s new keys or values ``new`` [K, T, D], whose
+    positions are ``start + arange(T)``, into layer ``l`` of the carried
+    cache ``c_all`` [L, B, K, S, D] as ONE contiguous block, leaving exactly
+    the bytes the ``mode="drop"`` scatter leaves.
+
+    The block is the window ``[w, w + T)`` with ``w = min(start, S - T)``
+    computed here: ``dynamic_update_slice`` would clamp a start that runs
+    past the axis and silently shift every row, so the shift is made
+    explicit (``new`` rolled right by ``start - w``) and never left to the
+    clamp. The window's old bytes are read first and kept wherever the
+    scatter wrote nothing: padding (``ok`` [T] false), positions at or past
+    ``S``, and the slots before ``start`` that a shifted window covers."""
+    K, T, D = new.shape
+    S = c_all.shape[3]
+    w = jnp.clip(start, 0, S - T)
+    shift = start - w
+    at = (l, b, 0, w, 0)
+    old = jax.lax.dynamic_slice(c_all, at, (1, 1, K, T, D))
+    keep_new = (jnp.arange(T) >= shift) & jnp.roll(ok, shift)
+    block = jnp.where(
+        keep_new[None, :, None], jnp.roll(new, shift, axis=1), old[0, 0]
+    )
+    return jax.lax.dynamic_update_slice(c_all, block[None, None], at)
+
+
+def _ride_stats(cache, new_cache, stats) -> None:
+    """A cache that comes in with a ``moe_stats`` leaf (int32 [4],
+    ``MOE_STATS``) goes out with this call's routing counts added to it: how
+    the engine's programs get them out without a fetch of their own, and how
+    a prompt's chunks add theirs up on the device. Any other cache is left
+    as ``init_kv_cache`` made it."""
+    if stats and "moe_stats" in cache:
+        new_cache["moe_stats"] = cache["moe_stats"] + stats[0]
+
+
+def _cache_writer(cfg, S: int, positions, valid, start_pos):
+    """``write(c_all, new, l)`` for ``decode_forward``: new keys or values
+    [B, K, T, D] into layer ``l`` of the carried cache [L, B, K, S, D], as
+    blocks or as the scatter (see ``decode_forward``)."""
+    B, T = positions.shape
+    as_blocks = (
+        start_pos is not None and B <= _BLOCK_WRITE_MAX_BATCH and T <= S
+    )
+    if as_blocks:
+        ok = jnp.ones((B, T), bool) if valid is None else valid
+
+        def write(c_all, new, l):
+            for b in range(B):
+                c_all = _write_block(c_all, new[b], l, b, start_pos[b], ok[b])
+            return c_all
+    else:
+        if valid is not None:
+            # out-of-range index -> dropped by scatter mode='drop'
+            write_pos = jnp.where(valid, positions, S)
+        else:
+            write_pos = positions
+        bi = jnp.arange(B)[:, None, None]
+        ki = jnp.arange(cfg.n_kv_heads)[None, :, None]
+        pi = write_pos[:, None, :]  # [B, 1, T]
+
+        def write(c_all, new, l):
+            return c_all.at[l, bi, ki, pi].set(new, mode="drop")
+    return write
+
+
+def _grouped_attention(q, k, v, mask):
+    """GQA over the keys the mask allows, without materializing repeated
+    K/V. q: [B, T, H, D]; k, v: [B, K, S, D] (head-major, as the cache keeps
+    them); mask: [B, T, S]."""
+    B, T, H, D = q.shape
+    K = k.shape[1]
+    qg = q.reshape(B, T, K, H // K, D)
+    s = jnp.einsum("btkgd,bksd->bktgs", qg, k) * D**-0.5
+    s = jnp.where(mask[:, None, :, None, :], s, -1e30)
+    w = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
+    return jnp.einsum("bktgs,bksd->btkgd", w, v).reshape(B, T, H, D)
+
+
+def _dense_ffn(h, p):
+    """SwiGLU of h [B, T, e]; ``p(name)`` hands out this layer's ``w_gate``,
+    ``w_up``, ``w_down`` when asked (a layer's slice of a stacked weight is a
+    copy on the chip: it is taken where it is used)."""
+    ff = jax.nn.silu(
+        jnp.einsum("bte,ef->btf", h, p("w_gate"))
+    ) * jnp.einsum("bte,ef->btf", h, p("w_up"))
+    return jnp.einsum("btf,fe->bte", ff, p("w_down"))
+
+
 # --------------------------------------------------------------------- rope
 
 
-def rope_inv_freq(cfg: LlamaConfig, kind: str):
+def rope_inv_freq(cfg, kind: str):
     """(inverse frequencies float32 [rotated / 2], factor on cos and sin) of
     one attention kind. A sliding layer rotates the whole head at
     ``rope_theta_sliding``; a full layer the first ``rope_partial`` of it at
     ``rope_theta``, with YaRN's blend of interpolated and extrapolated
     frequencies where ``yarn_factor`` is set (as transformers'
-    ``_compute_yarn_parameters`` computes them over the rotated dims)."""
+    ``_compute_yarn_parameters`` computes them over the rotated dims). A
+    uniform model's layers are full ones with neither: the whole head at
+    ``rope_theta``, factor 1."""
     if kind == "sliding":
         rot, theta = cfg.head_dim, cfg.rope_theta_sliding
     else:
@@ -203,67 +495,97 @@ def _rope(x, positions, inv_freq, factor):
 class _Layer:
     """One layer's static kind and its (static or traced) indices."""
 
-    def __init__(self, cfg: LlamaConfig, l_static: int, l, attn_i, mlp_i):
-        self.kind = cfg.layer_types[l_static]
-        self.sparse = cfg.mlp_types[l_static] == "sparse"
+    def __init__(self, pl: Plan, l_static: int, l, attn_i, mlp_i):
+        self.kind, _, mlp = pl.kinds[l_static]
+        self.sparse = mlp == "sparse"
         self.l, self.attn_i, self.mlp_i = l, attn_i, mlp_i
+        self.wq, self.wo, self.wg = (pl.leaf(n, self.kind) for n in ("wq", "wo", "wg"))
+        self.by_kind = pl.by_kind
+
+    def inner_scope(self):
+        """The name under ``attn_core``: ``global`` or ``window`` where the
+        model has kinds to tell apart, none where its layers are alike."""
+        return scope(_SCOPE_OF_KIND[self.kind]) if self.by_kind else contextlib.nullcontext()
 
 
-def _qkv(params, lay: _Layer, h, positions, cfg: LlamaConfig):
+def _qkv(params, lay: _Layer, h, positions, cfg, loras=None, adapter_ids=None):
+    """Rotated queries and keys, and values, of h [B, T, e]. ``loras`` (a
+    uniform model's, ``init_lora_stack``): each row's adapter
+    ``adapter_ids[b]`` adds its low-rank delta to q and v, W x + B (A x)."""
     inv_freq, factor = rope_inv_freq(cfg, lay.kind)
     with scope("attn_qkv"):
-        q = jnp.einsum("bte,ehd->bthd", h, params["wq_" + lay.kind][lay.attn_i])
+        q = jnp.einsum("bte,ehd->bthd", h, params[lay.wq][lay.attn_i])
         k = jnp.einsum("bte,ehd->bthd", h, params["wk"][lay.l])
         v = jnp.einsum("bte,ehd->bthd", h, params["wv"][lay.l])
+        if loras is not None:
+            lp = {n: loras[n][lay.l] for n in ("wq_a", "wq_b", "wv_a", "wv_b")}
+            q = q + jnp.einsum(
+                "btr,brhd->bthd",
+                jnp.einsum("bte,ber->btr", h, lp["wq_a"][adapter_ids]),
+                lp["wq_b"][adapter_ids],
+            )
+            v = v + jnp.einsum(
+                "btr,brhd->bthd",
+                jnp.einsum("bte,ber->btr", h, lp["wv_a"][adapter_ids]),
+                lp["wv_b"][adapter_ids],
+            )
         q = _rope(q, positions, inv_freq, factor)
         k = _rope(k, positions, inv_freq, factor)
     return q, k, v
 
 
-def _attn_out(params, lay: _Layer, x, h, attn, cfg: LlamaConfig):
+def _attn_out(params, lay: _Layer, x, h, attn, cfg):
     with scope("attn_out"):
         if cfg.attn_gate:
             with scope("gate"):
                 gate = jax.nn.sigmoid(jnp.einsum(
-                    "bte,eh->bth", h, params["wg_" + lay.kind][lay.attn_i],
+                    "bte,eh->bth", h, params[lay.wg][lay.attn_i],
                     preferred_element_type=jnp.float32,
                 ))
                 attn = (attn * gate[..., None]).astype(attn.dtype)
-        return x + jnp.einsum("bthd,hde->bte", attn, params["wo_" + lay.kind][lay.attn_i])
+        return x + jnp.einsum("bthd,hde->bte", attn, params[lay.wo][lay.attn_i])
 
 
-def _feed_forward(params, lay: _Layer, x, cfg: LlamaConfig):
-    """x + feed-forward(norm(x)), and the layer's routing counts (zeros for a
-    dense layer)."""
+def _feed_forward(params, lay: _Layer, x, cfg):
+    """x + feed-forward(norm(x)), and the layer's routing counts: zeros for
+    a dense layer of a model that has expert layers, None in a model with
+    none (which carries no counts)."""
     h = _rmsnorm(x, params["mlp_norm"][lay.l], cfg.rms_eps, cfg.fused_rmsnorm)
     if lay.sparse:
         with scope("moe_ffn"):
             y, stats = _moe_decode_ffn(params, lay.mlp_i, h, cfg)
             return x + y, stats
     with scope("ffn"):
+        # a layer's slice of a stacked weight is taken where it is used
         x = x + _dense_ffn(h, lambda name: params[name][lay.mlp_i])
-    return x, jnp.zeros((len(MOE_STATS),), jnp.int32)
+    return x, (jnp.zeros((len(MOE_STATS),), jnp.int32) if cfg.moe_experts else None)
 
 
-def _run_layers(cfg: LlamaConfig, layer_fn, carry):
-    """``carry = layer_fn(lay, carry)`` over the stack as ``plan`` splits it."""
+def _run_layers(cfg, layer_fn, carry):
+    """``carry = layer_fn(lay, carry)`` over the stack as ``plan`` splits it:
+    the only loop over layers on the cache path. The repeated period is one
+    ``fori_loop`` with the whole carry (for ``decode_forward`` the whole
+    cache) going round: the per-layer cache writes alias in place (donated
+    buffers), where a ``lax.scan`` carrying per-layer cache slices as ys
+    re-materializes the whole cache every step (decode measured 1.6x slower
+    from those copies alone at 3B/B=16 on v5e)."""
     pl = plan(cfg)
 
     def static(l):
-        return _Layer(cfg, l, l, pl.attn_index[l], pl.mlp_index[l])
+        return _Layer(pl, l, l, pl.attn_index[l], pl.mlp_index[l])
 
     for l in range(pl.lead):
         carry = layer_fn(static(l), carry)
     if pl.reps:
         first = [pl.lead + j for j in range(pl.period)]
         # a kind's rows advance by its count in one period
-        step_attn = [sum(cfg.layer_types[m] == cfg.layer_types[l] for m in first) for l in first]
-        step_mlp = [sum(cfg.mlp_types[m] == cfg.mlp_types[l] for m in first) for l in first]
+        step_attn = [sum(pl.kinds[m][0] == pl.kinds[l][0] for m in first) for l in first]
+        step_mlp = [sum(pl.kinds[m][2] == pl.kinds[l][2] for m in first) for l in first]
 
         def body(i, carry):
             for j, l in enumerate(first):
                 lay = _Layer(
-                    cfg, l, l + i * pl.period,
+                    pl, l, l + i * pl.period,
                     pl.attn_index[l] + i * step_attn[j],
                     pl.mlp_index[l] + i * step_mlp[j],
                 )
@@ -279,12 +601,12 @@ def _run_layers(cfg: LlamaConfig, layer_fn, carry):
 # ------------------------------------------------------------ whole sequence
 
 
-def forward_hidden(params, tokens, cfg: LlamaConfig, mesh: Optional[Mesh] = None,
-                   positions=None):
-    """tokens: [B, T] -> final hidden states [B, T, d_model], every expert
-    layer dropless on this device (``_moe_decode_ffn``). One device: a mesh
-    with an axis over 1 is refused (training this model over ``ep`` is not
-    here yet)."""
+def forward_hidden(params, tokens, cfg, mesh: Optional[Mesh] = None, positions=None):
+    """tokens: [B, T] -> final hidden states [B, T, d_model] of a model whose
+    layers are not alike, every expert layer dropless on this device
+    (``_moe_decode_ffn``). One device: a mesh with an axis over 1 is refused
+    (training this model over ``ep`` is not here yet). A uniform model's
+    whole-sequence path is ``models/llama.py forward_hidden``."""
     if mesh is not None and any(s > 1 for s in mesh.shape.values()):
         raise NotImplementedError("models/patterned.py runs on one device")
     B, T = tokens.shape
@@ -300,7 +622,7 @@ def forward_hidden(params, tokens, cfg: LlamaConfig, mesh: Optional[Mesh] = None
     def layer(lay: _Layer, x):
         h = _rmsnorm(x, params["attn_norm"][lay.l], cfg.rms_eps, cfg.fused_rmsnorm)
         q, k, v = _qkv(params, lay, h, positions, cfg)
-        with scope("attn_core"), scope(_SCOPE_OF_KIND[lay.kind]):
+        with scope("attn_core"), lay.inner_scope():
             attn = _grouped_attention(
                 q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), masks[lay.kind]
             )
@@ -328,42 +650,81 @@ def _window_slice(c_all, l, first, width: int):
     return jax.vmap(row)(jnp.arange(B), first)
 
 
-def decode_forward(params, cache, tokens, positions, cfg: LlamaConfig, valid=None,
-                   loras=None, with_logits: bool = True, logits_at=None, start_pos=None):
-    """``models/llama.py _decode_forward`` for a patterned model: the same
-    arguments and results, the same cache write. Row b's positions are
-    consecutive from ``positions[b, 0]`` (``prefill`` and ``decode_step``
-    make no others), which is what lets a sliding layer cut its window out
-    of the stripe: the ``window + T - 1`` positions its queries can see,
-    from a start rounded down to ``_WINDOW_ALIGN``; where that is the whole
-    stripe, the stripe under the window's mask."""
-    if loras is not None:
+def decode_forward(
+    params, cache, tokens, positions, cfg, valid=None, loras=None, adapter_ids=None,
+    with_logits: bool = True, logits_at=None, start_pos=None,
+):
+    """The body of ``prefill`` and ``decode_step`` for every model. tokens:
+    [B, T]; positions: [B, T]. New k/v are written into the cache before
+    attention so new tokens attend to themselves and to all prior cache
+    slots. ``valid`` [B, T] marks real (non-padding) tokens; padding writes
+    leave the cache's old bytes where they are, so later decode steps never
+    attend to stale slots and whatever copies a stripe out (the engine's
+    stripe-to-slot copy, the prefix cache's store, the disaggregated
+    hand-over) carries none.
+
+    Two forms of one write, chosen from what is static at trace time, with
+    the same bytes in the same slots. ``start_pos`` [B] is the caller's word
+    that row ``b``'s positions are ``start_pos[b] + arange(T)`` (every call
+    through ``prefill``): while ``B <= _BLOCK_WRITE_MAX_BATCH`` and the chunk
+    fits the cache (``T <= S``) each row is one contiguous block a tensor and
+    layer (``_write_block``: padding and the stripe's end keep old bytes).
+    Otherwise (``decode_step``: T = 1, every row at an unrelated position;
+    a wide batch) the ``[B, K, T]``-index scatter with ``mode="drop"``.
+    The cache's position axis is never sharded (``llm/spmd.py`` shards the
+    key-value heads), so a block partitions over heads as the scatter does.
+
+    Row b's positions are consecutive from ``positions[b, 0]`` (``prefill``
+    and ``decode_step`` make no others), which is what lets a sliding layer
+    cut its window out of the stripe: the ``window + T - 1`` positions its
+    queries can see, from a start rounded down to ``_WINDOW_ALIGN``; where
+    that is the whole stripe, the stripe under the window's mask.
+
+    ``loras``/``adapter_ids``: stacked LoRA adapters + per-sequence adapter
+    index (0 = base), over layers that are alike.
+    ``with_logits=False`` (a prompt's middle chunk) only extends the cache
+    and skips the LM head (the vocab projection reads ~0.8 GB of weights at
+    128k vocab; chunked admission would pay it once per chunk otherwise).
+    ``logits_at`` [B]: project the LM head at ONLY this position per
+    sequence (returns [B, 1, V]) — prefill needs one next-token
+    distribution, and the full [B, T, V] projection is the single biggest
+    prefill allocation (0.5 GB/seq at 7B/128k-vocab scale: the allocation
+    that kept 7B from fitting one v5e chip)."""
+    pl = plan(cfg)
+    kinds = {kind for kind, _, _ in pl.kinds}
+    if loras is not None and pl.by_kind:
         raise NotImplementedError("LoRA adapters over layers that are not alike")
     B, T = tokens.shape
-    S = cache["k"].shape[3]
-    W, A = cfg.sliding_window, _WINDOW_ALIGN
+    S = cache["k"].shape[3]  # [L, B, K, S, D]
     with scope("embed"):
         x = params["embed"][tokens].astype(cfg.dtype)
     write = _cache_writer(cfg, S, positions, valid, start_pos)
 
     qpos = positions[:, :, None]  # [B, T, 1]
     slot = jnp.arange(S)[None, None, :]
+    W, A = cfg.sliding_window, _WINDOW_ALIGN
     span = -(-(W + T - 1 + A - 1) // A) * A  # covers the window from an aligned start
     whole = {"full": True, "sliding": span >= S}
-    if not whole["sliding"]:
+    if "sliding" in kinds and not whole["sliding"]:
         first = jnp.clip((positions[:, 0] - W + 1) // A * A, 0, S - span)  # [B]
         wslot = first[:, None, None] + jnp.arange(span)[None, None, :]
         window_mask = (wslot <= qpos) & (qpos - wslot < W)
-    masks = {"full": slot <= qpos, "sliding": (slot <= qpos) & (qpos - slot < W)}
+
+    def stripe_mask(kind):  # causal over absolute positions, and within the window
+        seen = slot <= qpos
+        return seen & (qpos - slot < W) if kind == "sliding" else seen
+
+    masks = {kind: stripe_mask(kind) for kind in _SCOPE_OF_KIND if kind in kinds}
 
     def layer(lay: _Layer, carry):
-        x, ck_all, cv_all, stats = carry
+        x, ck_all, cv_all, *stats = carry
         h = _rmsnorm(x, params["attn_norm"][lay.l], cfg.rms_eps, cfg.fused_rmsnorm)
-        q, k, v = _qkv(params, lay, h, positions, cfg)
+        q, k, v = _qkv(params, lay, h, positions, cfg, loras, adapter_ids)
         with scope("kv_write"):
+            # the cache is head-major: the new [B, T, K, D] rows go in as [B, K, T, D]
             ck_all = write(ck_all, k.transpose(0, 2, 1, 3), lay.l)
             cv_all = write(cv_all, v.transpose(0, 2, 1, 3), lay.l)
-        with scope("attn_core"), scope(_SCOPE_OF_KIND[lay.kind]):
+        with scope("attn_core"), lay.inner_scope():
             if whole[lay.kind]:
                 attn = _grouped_attention(q, ck_all[lay.l], cv_all[lay.l], masks[lay.kind])
             else:
@@ -373,15 +734,18 @@ def decode_forward(params, cache, tokens, positions, cfg: LlamaConfig, valid=Non
                 )
         x = _attn_out(params, lay, x, h, attn, cfg)
         x, layer_stats = _feed_forward(params, lay, x, cfg)
-        return x, ck_all, cv_all, stats + layer_stats
+        return (x, ck_all, cv_all, *(s + layer_stats for s in stats))
 
-    stats0 = jnp.zeros((len(MOE_STATS),), jnp.int32)
-    x, new_k, new_v, stats = _run_layers(cfg, layer, (x, cache["k"], cache["v"], stats0))
+    # a model with routed experts carries its routing counts beside x
+    stats0 = (jnp.zeros((len(MOE_STATS),), jnp.int32),) if cfg.moe_experts else ()
+    x, new_k, new_v, *stats = _run_layers(cfg, layer, (x, cache["k"], cache["v"], *stats0))
     new_cache = {"k": new_k, "v": new_v, "length": cache["length"] + T}
-    _ride_stats(cache, new_cache, [stats] if cfg.moe_experts else [])
+    _ride_stats(cache, new_cache, stats)
     if not with_logits:
         return None, new_cache
     if logits_at is not None:
+        # the one requested hidden state a sequence BEFORE the vocab
+        # projection: [B, T, e] -> [B, 1, e]
         x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
     x = _rmsnorm(x, params["final_norm"], cfg.rms_eps, cfg.fused_rmsnorm)
     return _project_logits(x, params, cfg, None), new_cache

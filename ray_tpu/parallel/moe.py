@@ -43,7 +43,7 @@ def topk_gates(params, x, top_k: int):
 
     The single source of truth for the gate math — shared by the capacity
     path below and the dropless serving path
-    (``models/llama._moe_decode_ffn``); the decode-vs-forward exactness test
+    (``models/patterned._moe_decode_ffn``); the decode-vs-forward exactness test
     pins the two staying numerically identical.
 
     Returns (probs [G, E] f32, gate_vals [G, k] f32, gate_idx [G, k])."""

@@ -558,7 +558,7 @@ def test_multi_step_decode_equivalence():
 
 def test_chunked_prefill_to_the_stripes_end_matches_full_forward():
     """Prompt chunks go into the scratch stripe as contiguous blocks
-    (``models/llama.py _write_block``). Through the engine: a 300-token
+    (``models/patterned.py _write_block``). Through the engine: a 300-token
     prompt in five chunks; then two prompts behind a 16-token prefix hit, so
     every chunk starts off the chunk grid and the final one's bucketed width
     passes the stripe's end (16 + 7 * 64 + 64 > 512), one of them

@@ -116,13 +116,15 @@ def test_pick_block_respects_row_width(rows, cols, expect):
 
 
 def test_kernel_attention_raises_off_chip():
-    """'splash'/'flash' are TPU kernels: asking for them where they cannot
-    run raises instead of returning dense attention under their name."""
+    """'splash' is a TPU kernel: asking for it where it cannot run raises
+    instead of returning dense attention under its name, and so does a name
+    the config does not know."""
     from ray_tpu.models import LlamaConfig, forward, init_params
 
-    for attention in ("splash", "flash"):
-        cfg = LlamaConfig.tiny(dtype=jnp.float32, attention=attention)
-        params = init_params(jax.random.PRNGKey(0), cfg)
-        tokens = jnp.zeros((1, 16), jnp.int32)
-        with pytest.raises(ValueError, match="needs a tpu backend"):
-            forward(params, tokens, cfg)
+    cfg = LlamaConfig.tiny(dtype=jnp.float32, attention="splash")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jnp.zeros((1, 16), jnp.int32)
+    with pytest.raises(ValueError, match="needs a tpu backend"):
+        forward(params, tokens, cfg)
+    with pytest.raises(ValueError, match="attention"):
+        LlamaConfig.tiny(attention="flash")

@@ -1,12 +1,21 @@
-"""A model whose layers are not alike (``models/patterned.py``; Laguna-XS.2's
-pattern at test size): prefill and decode through the cache against the full
-forward pass and against the benchmark's plain reference, the window cut out
-of the stripe, the two forms of the expert layer, the rotary tables against a
-NumPy transcription of the published code, the published depth's parameter
-count, and the engine's routing and window counters and scopes."""
+"""The one body that carries tokens through the cache (``models/patterned.py``),
+for every kind of model it serves: layers alike (dense, with LoRA adapters,
+with routed experts) and not alike (Laguna-XS.2's pattern at test size).
+Prefill and decode through the cache against the full forward pass and,
+for the patterned model, against the benchmark's plain reference; the window
+cut out of the stripe, the two forms of the expert layer, the rotary tables
+against a NumPy transcription of the published code, the published depth's
+parameter count, the engine's routing and window counters and scopes, and
+which way the model modules import each other."""
 
+import ast
+import dataclasses
+import functools
 import math
+import pathlib
 import re
+import subprocess
+import sys
 import time
 
 import jax
@@ -18,14 +27,14 @@ from ray_tpu.llm import EngineConfig, JaxEngine, LLMConfig, ModelConfig, Samplin
 from ray_tpu.models import patterned
 from ray_tpu.models.llama import (
     LlamaConfig,
-    _moe_decode_ffn,
-    _param_shapes,
     decode_step,
     forward,
     init_kv_cache,
+    init_lora_stack,
     init_params,
     prefill,
 )
+from ray_tpu.models.patterned import _moe_decode_ffn, _param_shapes
 
 CFG = LlamaConfig.laguna_tiny()
 # what benchmark/families/moe_window_gqa.py reads, for the reference
@@ -52,14 +61,42 @@ def params():
     return init_params(jax.random.PRNGKey(7), CFG)
 
 
-@pytest.fixture(scope="module")
-def tokens():
-    return jax.random.randint(jax.random.PRNGKey(1), (2, 44), 0, CFG.vocab_size)
+_MOE = dict(moe_experts=4, moe_top_k=2, moe_capacity_factor=8.0)  # ample: ``forward`` drops nothing
+MODELS = {
+    "dense": LlamaConfig.tiny(),
+    "dense-lora": LlamaConfig.tiny(),
+    "moe": LlamaConfig.tiny(**_MOE),
+    "moe-shared": LlamaConfig.tiny(**_MOE, moe_d_ff=48, moe_shared_d_ff=32, moe_routed_scale=2.5),
+    "laguna": CFG,
+}
+ADAPTERS = (1, 2)  # the adapter of each of the two rows, where there are any
 
 
-@pytest.fixture(scope="module")
-def whole(params, tokens):
-    return forward(params, tokens, CFG)
+@functools.lru_cache(maxsize=None)
+def _model(name):
+    """(cfg, params, the LoRA arguments of ``prefill`` / ``decode_step`` for
+    the given rows, tokens [2, 44], ``forward``'s logits). With adapters,
+    ``forward`` runs a row at a time on weights with the row's adapter
+    folded into ``wq`` and ``wv``."""
+    cfg = MODELS[name]
+    params = init_params(jax.random.PRNGKey(7), cfg)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 44), 0, cfg.vocab_size)
+    if "lora" not in name:
+        return cfg, params, lambda rows=(0, 1): {}, toks, forward(params, toks, cfg)
+    rng = np.random.default_rng(7)
+    loras = {k: jnp.asarray(rng.normal(0, 0.1, v.shape), v.dtype)
+             for k, v in init_lora_stack(cfg, 2, 4).items()}
+
+    def folded(a):
+        return dict(
+            params,
+            wq=params["wq"] + jnp.einsum("ler,lrhd->lehd", loras["wq_a"][:, a], loras["wq_b"][:, a]),
+            wv=params["wv"] + jnp.einsum("ler,lrhd->lehd", loras["wv_a"][:, a], loras["wv_b"][:, a]),
+        )
+
+    whole = jnp.concatenate([forward(folded(a), toks[b:b + 1], cfg) for b, a in enumerate(ADAPTERS)])
+    return cfg, params, lambda rows=(0, 1): dict(
+        loras=loras, adapter_ids=jnp.asarray([ADAPTERS[b] for b in rows], jnp.int32)), toks, whole
 
 
 def test_the_family_maps_the_published_keys_onto_the_tiny_preset():
@@ -71,54 +108,77 @@ def test_the_family_maps_the_published_keys_onto_the_tiny_preset():
     assert shapes == _param_shapes(CFG)
 
 
-@pytest.mark.parametrize("align", [4, 128], ids=["window-cut-out", "whole-stripe"])
-@pytest.mark.parametrize("chunks", [(44,), (5, 16, 9)], ids=["one-prefill", "chunked-across-the-window"])
-def test_prefill_then_decode_equals_forward_and_the_reference(
-        params, tokens, whole, monkeypatch, align, chunks):
+def _tol(cfg):  # the capacity form of ``forward``'s expert layers sums in another order
+    return dict(atol=5e-5, rtol=1e-4) if cfg.layer_types else dict(atol=2e-4, rtol=2e-4)
+
+
+_CHUNKS = {"one-prefill": (44,), "chunked-across-the-window": (5, 16, 9)}
+
+
+@pytest.mark.parametrize("model, chunks, align", [
+    *((m, "chunked-across-the-window", 128) for m in MODELS if m != "laguna"),
+    *(("laguna", c, a) for c in _CHUNKS for a in (4, 128)),
+], ids=lambda v: {4: "window-cut-out", 128: "whole-stripe"}.get(v, v))
+def test_prefill_then_decode_equals_forward_and_the_reference(monkeypatch, model, chunks, align):
     """Logits and every layer's keys and values: the prompt goes in as
     ``chunks`` (the second form crosses the 8-token window inside a chunk and
-    between chunks), the rest a token at a time; against ``forward`` and
-    against ``benchmark/reference_moe_window.py`` on the same weights."""
-    from benchmark.reference_moe_window import Reference
-
+    between chunks), the rest a token at a time; against ``forward`` and, for
+    the patterned model, against ``benchmark/reference_moe_window.py`` on the
+    same weights."""
+    cfg, params, lora_kw, tokens, whole = _model(model)
     monkeypatch.setattr(patterned, "_WINDOW_ALIGN", align)
+    tol = _tol(cfg)
     B, T = tokens.shape
-    cache = init_kv_cache(CFG, B, 64)
+    cache = init_kv_cache(cfg, B, 64)
     at = 0
-    for n in chunks:
+    for n in _CHUNKS[chunks]:
         if at + n > 30:
             n = 30 - at
         logits, cache = prefill(
-            params, cache, tokens[:, at:at + n], CFG, start_pos=jnp.full((B,), at, jnp.int32))
+            params, cache, tokens[:, at:at + n], cfg, start_pos=jnp.full((B,), at, jnp.int32),
+            **lora_kw())
         at += n
     assert at == 30
     got = [logits]
     for i in range(at, T - 1):
-        logits, cache = decode_step(params, cache, tokens[:, i], CFG)
+        logits, cache = decode_step(params, cache, tokens[:, i], cfg, **lora_kw())
         got.append(logits)
     got = jnp.stack(got, axis=1)  # positions 29 .. T-2
-    np.testing.assert_allclose(got, whole[:, 29:T - 1], atol=5e-5, rtol=1e-4)
+    np.testing.assert_allclose(got, whole[:, 29:T - 1], **tol)
+    if not cfg.layer_types:
+        return
+    from benchmark.reference_moe_window import Reference
 
     ref = Reference(PUBLISHED, jax.local_devices()[:1])
     want = ref.forward_rows(params, [np.asarray(r[:T - 1]) for r in tokens], last=T - 30,
                             kv_rows=range(B))
-    np.testing.assert_allclose(got, np.stack(want["logits"]), atol=5e-5, rtol=1e-4)
+    np.testing.assert_allclose(got, np.stack(want["logits"]), **tol)
     for b in range(B):
         for name, ref_kv in zip(("k", "v"), want["kv"][b]):
             have = np.asarray(cache[name][:, b, :, :T - 1]).transpose(0, 2, 1, 3)  # [L, T, K, D]
             np.testing.assert_allclose(have, ref_kv, atol=2e-5, rtol=1e-4)
 
 
-def test_padded_prefill_leaves_the_cache_and_logits_of_an_unpadded_one(params, tokens, monkeypatch):
+@pytest.mark.parametrize("model", list(MODELS))
+def test_padded_prefill_leaves_the_cache_and_logits_of_an_unpadded_one(monkeypatch, model):
+    """Right-padded prompts write nothing past their length, and the decode
+    steps after them (past the padded slots of the shorter row) give
+    ``forward``'s logits."""
+    cfg, params, lora_kw, tokens, whole = _model(model)
     monkeypatch.setattr(patterned, "_WINDOW_ALIGN", 4)
-    cache = init_kv_cache(CFG, 2, 64)
-    lengths = jnp.asarray([21, 13], jnp.int32)
-    logits, cache = prefill(params, cache, tokens[:, :32], CFG, lengths=lengths)
-    for b, n in enumerate([21, 13]):
-        alone, c1 = prefill(params, init_kv_cache(CFG, 1, 64), tokens[b:b + 1, :n], CFG)
-        np.testing.assert_allclose(logits[b], alone[0], atol=5e-5, rtol=1e-4)
+    tol = _tol(cfg)
+    lengths = [21, 13]
+    logits, cache = prefill(params, init_kv_cache(cfg, 2, 64), tokens[:, :32], cfg,
+                            lengths=jnp.asarray(lengths, jnp.int32), **lora_kw())
+    for b, n in enumerate(lengths):
+        alone, c1 = prefill(params, init_kv_cache(cfg, 1, 64), tokens[b:b + 1, :n], cfg, **lora_kw((b,)))
+        np.testing.assert_allclose(logits[b], alone[0], **tol)
         np.testing.assert_allclose(cache["k"][:, b, :, :n], c1["k"][:, 0, :, :n], atol=2e-5)
         assert not np.asarray(cache["k"][:, b, :, n:]).any()  # padding writes nothing
+    for i in range(4):
+        at = jnp.asarray(lengths) + i
+        logits, cache = decode_step(params, cache, tokens[jnp.arange(2), at], cfg, **lora_kw())
+        np.testing.assert_allclose(logits, whole[jnp.arange(2), at], **tol)
 
 
 def test_grouped_expert_form_equals_every_expert_form(params):
@@ -203,32 +263,86 @@ def test_published_depth_counts_its_parameters_and_traces_one_period():
     assert abs(cut.num_params() / 3.87e9 - 1) < 5e-3
 
 
-def test_a_repeated_period_runs_under_one_loop_and_equals_the_unrolled_stack(monkeypatch):
-    """9 layers (layer 0, two periods): the loop's traced indices reach the
-    same rows as static ones."""
-    types = ("full",) + ("sliding", "sliding", "sliding", "full") * 2
-    cfg = LlamaConfig.laguna_tiny(
-        n_layers=9, layer_types=types, heads_per_layer=tuple(6 if t == "full" else 8 for t in types),
-        mlp_types=("dense",) + ("sparse",) * 8)
+_NINE = ("full",) + ("sliding", "sliding", "sliding", "full") * 2
+LOOPED = {
+    # layer 0 and two periods; a uniform stack is no lead and a period of one layer
+    "laguna-9-layers": (LlamaConfig.laguna_tiny(
+        n_layers=9, layer_types=_NINE, heads_per_layer=tuple(6 if t == "full" else 8 for t in _NINE),
+        mlp_types=("dense",) + ("sparse",) * 8), (1, 4, 2)),
+    "dense-3-layers": (LlamaConfig.tiny(n_layers=3), (0, 1, 3)),
+    "moe-shared-3-layers": (dataclasses.replace(MODELS["moe-shared"], n_layers=3), (0, 1, 3)),
+}
+
+
+@pytest.mark.parametrize("name", list(LOOPED))
+def test_a_repeated_period_runs_under_one_loop_and_equals_the_unrolled_stack(monkeypatch, name):
+    """The loop's traced indices reach the same rows as static ones: logits
+    and cache of the looped stack equal those of the stack traced a layer at
+    a time."""
+    cfg, split = LOOPED[name]
     pl = patterned.plan(cfg)
-    assert (pl.lead, pl.period, pl.reps) == (1, 4, 2)
+    assert (pl.lead, pl.period, pl.reps) == split
     params = init_params(jax.random.PRNGKey(2), cfg)
     toks = jax.random.randint(jax.random.PRNGKey(4), (1, 24), 0, cfg.vocab_size)
-    looped = forward(params, toks, cfg)
+
+    def through_the_cache(p, t):
+        return prefill(p, init_kv_cache(cfg, 1, 32), t, cfg)
+
+    looped, looped_cache = through_the_cache(params, toks[:, :23])
+    whole = forward(params, toks, cfg)
+    np.testing.assert_allclose(looped, whole[:, 22], atol=5e-5, rtol=1e-4)
     traced = []
     feed_forward = patterned._feed_forward
     monkeypatch.setattr(patterned, "_feed_forward",
                         lambda *a: traced.append(1) or feed_forward(*a))
-    jax.make_jaxpr(lambda p, t: forward(p, t, cfg))(params, toks)
-    assert len(traced) == 1 + 4  # layer 0 and one period: 5 bodies for 9 layers
-    flat = patterned.Plan(9, 1, 0, pl.attn_index, pl.mlp_index)  # every layer its own body
+    jax.make_jaxpr(through_the_cache)(params, toks)
+    assert len(traced) == pl.lead + pl.period  # 5 bodies for 9 layers, 1 for a uniform stack
+    flat = dataclasses.replace(pl, lead=cfg.n_layers, period=1, reps=0)  # every layer its own body
     monkeypatch.setattr(patterned, "plan", lambda c: flat)
-    unrolled = forward(params, toks, cfg)
-    monkeypatch.undo()
+    unrolled, unrolled_cache = through_the_cache(params, toks[:, :23])
+    assert len(traced) == pl.lead + pl.period + cfg.n_layers
     np.testing.assert_allclose(looped, unrolled, atol=5e-5, rtol=1e-4)
-    cache = init_kv_cache(cfg, 1, 32)
-    logits, cache = prefill(params, cache, toks[:, :23], cfg)
-    np.testing.assert_allclose(logits, looped[:, 22], atol=5e-5, rtol=1e-4)
+    for key in ("k", "v"):
+        np.testing.assert_allclose(looped_cache[key], unrolled_cache[key], atol=2e-5, rtol=1e-4)
+    if cfg.layer_types:  # the whole-sequence pass of a patterned model runs the same loop
+        np.testing.assert_allclose(whole, forward(params, toks, cfg), atol=5e-5, rtol=1e-4)
+
+
+def test_the_model_modules_import_one_way():
+    """``models/patterned.py`` (the plan, the loop, the one body) needs
+    nothing of ``ray_tpu.models``: loaded by its path in a fresh interpreter,
+    no module of the package is imported (``import ray_tpu.models.patterned``
+    would run the package's ``__init__``, which imports ``llama``). And no
+    ``import`` inside a function of ``ray_tpu/models/`` names a sibling
+    module: there is no cycle left to get round."""
+    models = pathlib.Path(patterned.__file__).parent
+    code = (
+        "import importlib.util, sys\n"
+        f"spec = importlib.util.spec_from_file_location('lower', {str(models / 'patterned.py')!r})\n"
+        "mod = importlib.util.module_from_spec(spec)\n"
+        "sys.modules['lower'] = mod\n"
+        "spec.loader.exec_module(mod)\n"
+        "print(sorted(m for m in sys.modules if m.startswith('ray_tpu.models')))\n"
+    )
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True, check=True,
+                         env={"JAX_PLATFORMS": "cpu", "PYTHONPATH": str(models.parent.parent),
+                              "PATH": ""})
+    assert out.stdout.strip() == "[]", out.stdout
+
+    def names_a_sibling(node):
+        if isinstance(node, ast.ImportFrom):
+            return node.level > 0 or (node.module or "").startswith("ray_tpu.models")
+        return isinstance(node, ast.Import) and any(
+            a.name.startswith("ray_tpu.models") for a in node.names)
+
+    for path in sorted(models.glob("*.py")):
+        tree = ast.parse(path.read_text())
+        for fn in ast.walk(tree):
+            if isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
+                inside = [n.lineno for n in ast.walk(fn) if names_a_sibling(n)]
+                assert not inside, f"{path.name}:{inside} imports a sibling inside {fn.name}"
+        if path.name == "patterned.py":
+            assert not [n.lineno for n in ast.walk(tree) if names_a_sibling(n)]
 
 
 def test_pattern_errors_are_named():
@@ -352,7 +466,7 @@ def test_inner_scopes_are_in_the_lowered_programs_op_names(lowered_paths, progra
 
 
 def test_uniform_moe_with_a_shared_expert_and_scale_serves_what_it_trains():
-    """Layers alike (``models/llama.py`` alone): ``_moe_ffn`` (the training
+    """Layers alike: ``models/llama.py _moe_ffn`` (the training
     path, capacity ample) and ``_moe_decode_ffn`` (the serving path) apply the
     same expert width, shared expert and routed scale."""
     cfg = LlamaConfig.tiny(moe_experts=4, moe_top_k=2, moe_capacity_factor=8.0,
