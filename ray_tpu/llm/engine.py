@@ -66,6 +66,18 @@ COUNTERS = (
     # slots, the slot's length, and what a sliding-window layer needs of it
     # (min(length, window); stays 0 where the model has no window)
     "decode_kv_tokens_global", "decode_kv_tokens_window",
+    # keys and values a decode step does read: over the same steps and slots,
+    # the positions the decode kernel's blocks cover between a slot's bounds
+    # (``ops/decode_attention.py positions_read``) in a full layer and in a
+    # sliding-window one (0 where the model has none); the slot's whole
+    # stripe in a pool whose steps keep the einsum (``_Pool.reads_blocks``).
+    # Like the tokens beside them they are counted from the lengths the loop
+    # holds at a launch: behind the device's by the run-ahead, one length for
+    # all of a launch's steps, active slots only (the kernel also walks a
+    # freed slot's stripe up to the length it ran on to: PERF.md section 7).
+    # tokens over positions is the read's efficiency, positions over
+    # decode_slot_steps x stripe what it still touches of the stripe
+    "decode_kv_positions_read", "decode_kv_positions_read_window",
     # routed experts (``models/llama.py MOE_STATS``), summed over expert
     # layers and over the runs of each program: the decode program hands its
     # counts out beside its tokens, a prompt's middle chunks add theirs up on
@@ -172,10 +184,13 @@ class _Pool:
     positions each, with its own compiled decode program. Short requests
     route to short pools so they never pin max_seq_len-sized KV memory."""
 
-    def __init__(self, stripe_len: int, n_slots: int, model_cfg):
+    def __init__(self, stripe_len: int, n_slots: int, model_cfg, params):
         from collections import deque
 
+        import jax
+
         from ray_tpu.models.llama import init_kv_cache
+        from ray_tpu.models.patterned import reads_blocks
 
         self.stripe_len = stripe_len
         self.n_slots = n_slots
@@ -195,6 +210,22 @@ class _Pool:
         self.inflight: "deque" = deque()
         # first tokens from final prefill chunks awaiting host arrival
         self.first_pending: list = []
+        # whether this pool's decode steps read its stripes through the
+        # decode kernel: asked once, of the layer that decides it, with the
+        # arrays the steps run on
+        self.reads_blocks = reads_blocks(
+            stripe_len, self.cache["k"], *jax.tree.leaves(params)
+        )
+
+    def positions_read(self, lo, hi) -> int:
+        """Positions a decode step reads of the stripes of slots bounded
+        ``[lo, hi)`` (arrays, an entry a slot) in a layer: the kernel's
+        blocks, or whole stripes where the steps keep the einsum."""
+        if not self.reads_blocks:
+            return self.stripe_len * len(hi)
+        from ray_tpu.ops.decode_attention import positions_read
+
+        return int(positions_read(lo, hi, self.stripe_len).sum())
 
 
 class JaxEngine:
@@ -266,7 +297,7 @@ class JaxEngine:
                 "one slot (long requests would silently truncate)"
             )
         self._pools = [
-            _Pool(b, n, self.model_cfg)
+            _Pool(b, n, self.model_cfg, self.params)
             for b, n in sorted(zip(buckets, counts))
             if n > 0
         ]
@@ -1166,14 +1197,20 @@ class JaxEngine:
                 pool.inflight.append((out, active, stats))
                 self._n["decode_steps"] += self._decode_n_steps
                 self._n["decode_slot_steps"] += self._decode_n_steps * len(active)
-                lengths = [
-                    len(r.prompt_token_ids) + len(r.out_tokens) for r in active.values()
-                ]
-                self._n["decode_kv_tokens_global"] += self._decode_n_steps * sum(lengths)
+                lengths = np.fromiter(
+                    (len(r.prompt_token_ids) + len(r.out_tokens) for r in active.values()),
+                    np.int64, len(active),
+                )
+                steps = self._decode_n_steps
+                self._n["decode_kv_tokens_global"] += steps * int(lengths.sum())
+                self._n["decode_kv_positions_read"] += steps * pool.positions_read(0, lengths)
                 window = self.model_cfg.sliding_window
                 if window:  # a model without one has no window layers to count for
-                    self._n["decode_kv_tokens_window"] += self._decode_n_steps * sum(
-                        min(n, window) for n in lengths
+                    self._n["decode_kv_tokens_window"] += steps * int(
+                        np.minimum(lengths, window).sum()
+                    )
+                    self._n["decode_kv_positions_read_window"] += steps * pool.positions_read(
+                        lengths - window, lengths
                     )
                 launched = True
             except BaseException as e:  # noqa: BLE001 — device failure

@@ -32,8 +32,10 @@ model: one layer body; the published Laguna's 40 layers: layer 0, nine times
 layer.
 
 The cache is ``init_kv_cache``'s: ``[L, B, K, S, D]``, every layer a whole
-stripe. A sliding layer reads only its window from it (a slice of at most
-``window + T`` positions a row, rounded to the tiling), so the window saves
+stripe. A decode step reads a row's stripe between the row's own bounds, whole
+blocks of it (``ops/decode_attention.py``); a prompt's chunk reads the whole
+stripe, and in a sliding layer only its window (a slice of at most
+``window + T`` positions a row, rounded to the tiling). So the window saves
 bandwidth now and memory only once a layer may own a shorter stripe."""
 
 from __future__ import annotations
@@ -49,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from ray_tpu.ops.decode_attention import block_size, decode_attention
 from ray_tpu.parallel.mesh import with_sharding
 
 # ``jax.named_scope`` names, one vocabulary for the train step and the
@@ -650,6 +653,88 @@ def _window_slice(c_all, l, first, width: int):
     return jax.vmap(row)(jnp.arange(B), first)
 
 
+def reads_blocks(stripe: int, *arrays) -> bool:
+    """Whether a decode step (one new token a row) over a cache of ``stripe``
+    positions a slot reads through the kernel (``ops/decode_attention.py``)
+    or keeps the einsum: the one place that decides it. ``arrays`` are what
+    the step runs on, the cache and the parameters, as the caller holds them
+    or as a trace sees them: the engine asks once a pool with its arrays
+    (``llm/engine.py _Pool.reads_blocks``, for its counter), ``_cache_reader``
+    with the tracers of the same arrays, and both get the same answer.
+
+    The kernel wants a stripe of whole blocks and everything on one device.
+    An argument committed to a ``NamedSharding`` carries its mesh in its
+    type, inside a trace too (``llm/spmd.py`` and ``llm/gang.py`` jit over a
+    mesh with the key-value heads sharded over ``tp``; an engine under
+    ``tensor_parallel_degree`` shards its parameters); a mesh of one device
+    is one device. What a type does not carry cannot be seen here: an
+    uncommitted argument that only a ``jit``'s ``in_shardings`` spreads over
+    a mesh reads as one device (no caller in this repo places its arrays
+    so; ``tests/test_patterned.py`` traces the ways they do)."""
+    return block_size(stripe) is not None and all(
+        jax.typeof(x).sharding.mesh.size <= 1 for x in arrays
+    )
+
+
+def _cache_reader(cfg, params, cache, positions, kinds):
+    """``read(q, ck_all, cv_all, lay)`` for ``decode_forward``: attention of
+    the queries [B, T, H, D] over layer ``lay.l`` of the carried cache
+    [L, B, K, S, D] -> [B, T, H, D]. Two forms of one read, chosen from what
+    is static at trace time, under the same mask (causal over absolute
+    positions, and within the window in a sliding layer).
+
+    One new token a row (``T == 1``: every ``decode_step``), a stripe of
+    whole blocks and everything on one device: the Pallas kernel
+    (``ops/decode_attention.py``), which takes the carried cache where it
+    lies and reads row ``b`` between its own bounds, ``[0, pos + 1)`` in a
+    full layer and ``[pos - W + 1, pos + 1)`` in a sliding one. Over a mesh
+    the einsum stays: the partitioner splits it over the key-value heads with
+    no communication, and would hand a kernel it cannot split the whole
+    gathered cache.
+
+    Anything else (``prefill``'s chunks, a tiny cache): ``_grouped_attention``
+    over the layer's whole stripe; a sliding layer cuts the ``window + T - 1``
+    positions its queries can see out of the stripe first, from a start
+    rounded down to ``_WINDOW_ALIGN`` (where that is the whole stripe, the
+    stripe under the window's mask)."""
+    T = positions.shape[1]
+    S = cache["k"].shape[3]
+    W, A = cfg.sliding_window, _WINDOW_ALIGN
+    if T == 1 and reads_blocks(S, cache["k"], *jax.tree.leaves(params)):
+        hi = positions[:, 0] + 1
+        lo = {"full": jnp.zeros_like(hi), "sliding": jnp.maximum(hi - W, 0)}
+
+        def read(q, ck_all, cv_all, lay):
+            return decode_attention(q[:, 0], ck_all, cv_all, lay.l, lo[lay.kind], hi)[:, None]
+
+        return read
+
+    qpos = positions[:, :, None]  # [B, T, 1]
+    slot = jnp.arange(S)[None, None, :]
+    span = -(-(W + T - 1 + A - 1) // A) * A  # covers the window from an aligned start
+    whole = {"full": True, "sliding": span >= S}
+    if "sliding" in kinds and not whole["sliding"]:
+        first = jnp.clip((positions[:, 0] - W + 1) // A * A, 0, S - span)  # [B]
+        wslot = first[:, None, None] + jnp.arange(span)[None, None, :]
+        window_mask = (wslot <= qpos) & (qpos - wslot < W)
+
+    def stripe_mask(kind):
+        seen = slot <= qpos
+        return seen & (qpos - slot < W) if kind == "sliding" else seen
+
+    masks = {kind: stripe_mask(kind) for kind in _SCOPE_OF_KIND if kind in kinds}
+
+    def read(q, ck_all, cv_all, lay):
+        if whole[lay.kind]:
+            return _grouped_attention(q, ck_all[lay.l], cv_all[lay.l], masks[lay.kind])
+        return _grouped_attention(
+            q, _window_slice(ck_all, lay.l, first, span),
+            _window_slice(cv_all, lay.l, first, span), window_mask,
+        )
+
+    return read
+
+
 def decode_forward(
     params, cache, tokens, positions, cfg, valid=None, loras=None, adapter_ids=None,
     with_logits: bool = True, logits_at=None, start_pos=None,
@@ -675,10 +760,10 @@ def decode_forward(
     key-value heads), so a block partitions over heads as the scatter does.
 
     Row b's positions are consecutive from ``positions[b, 0]`` (``prefill``
-    and ``decode_step`` make no others), which is what lets a sliding layer
-    cut its window out of the stripe: the ``window + T - 1`` positions its
-    queries can see, from a start rounded down to ``_WINDOW_ALIGN``; where
-    that is the whole stripe, the stripe under the window's mask.
+    and ``decode_step`` make no others), which is what lets the read
+    (``_cache_reader``) bound a row by its first position: a decode step's
+    kernel between the row's own bounds, a sliding layer's window cut out of
+    the stripe.
 
     ``loras``/``adapter_ids``: stacked LoRA adapters + per-sequence adapter
     index (0 = base), over layers that are alike.
@@ -699,22 +784,7 @@ def decode_forward(
     with scope("embed"):
         x = params["embed"][tokens].astype(cfg.dtype)
     write = _cache_writer(cfg, S, positions, valid, start_pos)
-
-    qpos = positions[:, :, None]  # [B, T, 1]
-    slot = jnp.arange(S)[None, None, :]
-    W, A = cfg.sliding_window, _WINDOW_ALIGN
-    span = -(-(W + T - 1 + A - 1) // A) * A  # covers the window from an aligned start
-    whole = {"full": True, "sliding": span >= S}
-    if "sliding" in kinds and not whole["sliding"]:
-        first = jnp.clip((positions[:, 0] - W + 1) // A * A, 0, S - span)  # [B]
-        wslot = first[:, None, None] + jnp.arange(span)[None, None, :]
-        window_mask = (wslot <= qpos) & (qpos - wslot < W)
-
-    def stripe_mask(kind):  # causal over absolute positions, and within the window
-        seen = slot <= qpos
-        return seen & (qpos - slot < W) if kind == "sliding" else seen
-
-    masks = {kind: stripe_mask(kind) for kind in _SCOPE_OF_KIND if kind in kinds}
+    read = _cache_reader(cfg, params, cache, positions, kinds)
 
     def layer(lay: _Layer, carry):
         x, ck_all, cv_all, *stats = carry
@@ -725,13 +795,7 @@ def decode_forward(
             ck_all = write(ck_all, k.transpose(0, 2, 1, 3), lay.l)
             cv_all = write(cv_all, v.transpose(0, 2, 1, 3), lay.l)
         with scope("attn_core"), lay.inner_scope():
-            if whole[lay.kind]:
-                attn = _grouped_attention(q, ck_all[lay.l], cv_all[lay.l], masks[lay.kind])
-            else:
-                attn = _grouped_attention(
-                    q, _window_slice(ck_all, lay.l, first, span),
-                    _window_slice(cv_all, lay.l, first, span), window_mask,
-                )
+            attn = read(q, ck_all, cv_all, lay)
         x = _attn_out(params, lay, x, h, attn, cfg)
         x, layer_stats = _feed_forward(params, lay, x, cfg)
         return (x, ck_all, cv_all, *(s + layer_stats for s in stats))

@@ -1,0 +1,187 @@
+"""Decode attention over a slot-striped cache: one new token a row, each row
+reading its keys and values between its own bounds and nothing else of its
+stripe.
+
+``models/patterned.py decode_forward`` carries the whole cache
+``[L, B, K, S, D]`` round its layer loop. The einsum it falls back to
+(``_grouped_attention``) is handed layer ``l`` whole and masks over all ``S``
+positions, so a decode step streams every stripe at any length (PERF.md
+section 5: 2.94 of a 13.79 ms Mistral step, "the same with 2 slots active or
+31"). This kernel takes the carried cache where it lies (in HBM, never an
+operand sliced out of it: a layer's slice is a 134 MB copy), the layer index
+and the bounds as scalars, and for row ``b`` copies the blocks of the
+position axis that hold ``[lo[b], hi[b])`` into VMEM, all ``K`` key-value
+heads of a block at once, double-buffered; a block outside the bounds costs
+no DMA and no compute. One invocation walks all rows' live blocks as one
+stream, so the next row's first block is in flight while this row's last is
+multiplied.
+
+Same mathematics as the einsum under the same mask: keys, values and queries
+as they are stored; scores, the running maximum, the sum and the output
+accumulator in float32 across blocks (online softmax); the mask exact inside
+the first and last block; ``H // K`` query heads share a key-value head's
+block without repeating it. Off the TPU it runs in Pallas interpret mode."""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops._common import _SUBLANE, interpret
+
+# Positions a block: all K key-value heads of one row, [K, BLOCK, D] (0.25 MB
+# of bf16 at K = 8, D = 128: 0.6 us of DMA for keys and values together).
+# Measured on a v5e at the serving cells' shapes (PERF.md section 6, PR 31; us
+# a layer at 128 / 256 / 512): 71 / 82 / 107 for 32 slots of 1,024 with 304
+# live, 222 / 228 / 246 for slots of 4,096 with 1,107 live, 105 / 119 / 152 for
+# a 512-position window: the copies stream at 690-730 GB/s at every size, so
+# the smallest block, which reads least past a row's bounds, wins. A stripe
+# that is no whole number of blocks is not this kernel's.
+BLOCK = 128
+_MASKED = -1e30  # finite: exp(_MASKED - m) is 0 and nothing is inf - inf
+
+
+def block_size(stripe: int) -> Optional[int]:
+    """Positions a block of a ``stripe``-position cache, or None where the
+    kernel does not apply (the caller keeps the einsum)."""
+    return BLOCK if stripe % BLOCK == 0 else None
+
+
+def _whole_blocks(stripe: int) -> int:
+    bs = block_size(stripe)
+    if bs is None:
+        raise ValueError(f"a {stripe}-position stripe is no whole number of {BLOCK}-position blocks")
+    return bs
+
+
+def _clamp(lo, hi, stripe: int, xp=jnp):
+    """Bounds the kernel can walk: at least one position, inside the stripe
+    (a dead slot's length runs on past its stripe). ``xp``: ``jnp`` for the
+    kernel's operands, ``np`` for the host's count of the same walk."""
+    hi = xp.clip(hi, 1, stripe)
+    return xp.clip(lo, 0, hi - 1), hi
+
+
+def positions_read(lo, hi, stripe: int):
+    """Positions the kernel's blocks cover for rows bounded ``[lo, hi)`` in a
+    cache of ``stripe`` positions a slot (whole blocks): what it reads of
+    each of keys and values, a key-value head. Integers or NumPy arrays of
+    them, on the host: the engine counts with it. The bounds go through the
+    ``_clamp`` the kernel's go through, and the blocks between them are the
+    kernel's ``lo // bs`` to ``(hi - 1) // bs``."""
+    bs = _whole_blocks(stripe)
+    lo, hi = _clamp(np.asarray(lo), np.asarray(hi), stripe, np)
+    return ((hi - 1) // bs - lo // bs + 1) * bs
+
+
+def _kernel(layer_ref, lo_ref, hi_ref, q_ref, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, sem, *, bs: int, scale: float):
+    B, K, G, D = q_ref.shape
+    layer = layer_ref[0]
+
+    def copies(b, blk, buf):
+        at = pl.ds(pl.multiple_of(blk * bs, bs), bs)
+        return (
+            pltpu.make_async_copy(k_hbm.at[layer, b, :, at, :], k_buf.at[buf], sem.at[0, buf]),
+            pltpu.make_async_copy(v_hbm.at[layer, b, :, at, :], v_buf.at[buf], sem.at[1, buf]),
+        )
+
+    def fetch(b, blk, buf):
+        for copy in copies(b, blk, buf):
+            copy.start()
+
+    fetch(0, lo_ref[0] // bs, 0)
+
+    def row(b, buf):
+        lo, hi = lo_ref[b], hi_ref[b]
+        last = (hi - 1) // bs
+        q = q_ref[b]  # [K, G, D]
+
+        def block(blk, carry):
+            m, den, acc, buf = carry
+            other = 1 - buf
+
+            @pl.when(blk < last)
+            def _():
+                fetch(b, blk + 1, other)
+
+            @pl.when(jnp.logical_and(blk == last, b + 1 < B))
+            def _():  # the next row's first block, while this row's last is multiplied
+                fetch(b + 1, lo_ref[b + 1] // bs, other)
+
+            k_copy, v_copy = copies(b, blk, buf)
+            k_copy.wait()
+            k = k_buf[buf]  # [K, bs, D]
+            s = jnp.concatenate([
+                jax.lax.dot_general(
+                    q[h], k[h], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) for h in range(K)
+            ], axis=0) * scale  # [K * G, bs]
+            pos = blk * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(jnp.logical_and(pos >= lo, pos < hi), s, _MASKED)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            den = alpha * den + p.sum(axis=-1, keepdims=True)
+            v_copy.wait()
+            v = v_buf[buf]
+            pv = jnp.concatenate([
+                jnp.dot(
+                    p[h * G:(h + 1) * G].astype(v.dtype), v[h],
+                    preferred_element_type=jnp.float32,
+                ) for h in range(K)
+            ], axis=0)  # [K * G, D]
+            return m_new, den, alpha * acc + pv, other
+
+        m0 = jnp.full((K * G, 1), _MASKED, jnp.float32)
+        den0 = jnp.zeros((K * G, 1), jnp.float32)
+        acc0 = jnp.zeros((K * G, D), jnp.float32)
+        _, den, acc, buf = jax.lax.fori_loop(
+            lo // bs, last + 1, block, (m0, den0, acc0, buf)
+        )
+        o_ref[b] = (acc / den).reshape(K, G, D).astype(o_ref.dtype)
+        return buf
+
+    jax.lax.fori_loop(0, B, row, 0)
+
+
+def decode_attention(q, ck_all, cv_all, layer, lo, hi):
+    """Attention of one query token a row over layer ``layer`` of a carried
+    cache. q: [B, H, D]; ck_all, cv_all: [L, B, K, S, D], ``S`` a whole number
+    of blocks (``block_size``); layer: an int or an int32 scalar (traced
+    under the layer loop); lo, hi: [B] int32, row ``b`` attends to positions
+    ``lo[b] <= s < hi[b]`` -> [B, H, D] in q's dtype."""
+    B, H, D = q.shape
+    _, _, K, S, _ = ck_all.shape
+    bs = _whole_blocks(S)
+    G = H // K
+    # a key-value head's query heads are its matmul's rows: whole sublanes
+    Gp = -(-G // _SUBLANE) * _SUBLANE
+    qg = q.reshape(B, K, G, D)
+    if Gp != G:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
+    lo, hi = (x.astype(jnp.int32) for x in _clamp(lo, hi, S))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        functools.partial(_kernel, bs=bs, scale=D**-0.5),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        in_specs=[smem, smem, smem, vmem,
+                  pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=vmem,
+        scratch_shapes=[
+            pltpu.VMEM((2, K, bs, D), ck_all.dtype),
+            pltpu.VMEM((2, K, bs, D), cv_all.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+        interpret=interpret(),
+        name="decode_attention",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), lo, hi, qg, ck_all, cv_all)
+    return out[:, :, :G].reshape(B, H, D)

@@ -57,7 +57,10 @@ def native_kernels(monkeypatch):
 
     import ray_tpu.ops.grouped_matmul  # noqa: F401 — not in the package's __init__
 
-    for name in ("ray_tpu.ops.rmsnorm", "ray_tpu.ops.quant", "ray_tpu.ops.grouped_matmul"):
+    import ray_tpu.ops.decode_attention  # noqa: F401 — nor this one
+
+    for name in ("ray_tpu.ops.rmsnorm", "ray_tpu.ops.quant", "ray_tpu.ops.grouped_matmul",
+                 "ray_tpu.ops.decode_attention"):
         monkeypatch.setattr(sys.modules[name], "interpret", lambda: False)
 
 
@@ -135,6 +138,20 @@ def _grouped_matmul(rows, bank, sizes):
     return grouped_matmul(rows, bank, sizes)
 
 
+def _decode_attention(q, ck_all, cv_all, layer, lo, hi):
+    from ray_tpu.ops.decode_attention import decode_attention
+
+    return decode_attention(q, ck_all, cv_all, layer, lo, hi)
+
+
+def _decode_attention_shapes(layers, stripe, heads, slots=32):
+    """One new token a slot over the serving cells' caches: ``heads`` query
+    heads over 8 key-value heads of width 128."""
+    cache = ((layers, slots, 8, stripe, 128), jnp.bfloat16)
+    bounds = ((slots,), jnp.int32)
+    return (((slots, heads, 128), jnp.bfloat16), cache, cache, ((), jnp.int32), bounds, bounds)
+
+
 # a 256-token chunk's 2,048 assignments over four stacked banks of 256 experts
 # (Laguna-XS.2: 2,048 x 512 up, 512 x 2,048 down)
 GROUPS = ((4 * 256,), jnp.int32)
@@ -154,6 +171,11 @@ KERNELS = {
         _grouped_matmul,
         (((2048, 512), jnp.bfloat16), ((1024, 512, 2048), jnp.bfloat16), GROUPS),
     ),
+    # Mistral-7B's 4 query heads a key-value head; Laguna-XS.2's 6 in a full
+    # layer and 8 in a sliding one
+    "decode_attention_4_a_group": (_decode_attention, _decode_attention_shapes(16, 1024, 32)),
+    "decode_attention_6_a_group": (_decode_attention, _decode_attention_shapes(5, 4096, 48)),
+    "decode_attention_8_a_group": (_decode_attention, _decode_attention_shapes(5, 4096, 64)),
     "quantize_int8": (_quantize, (((3072, 8192), jnp.bfloat16),)),
     "dequantize_int8": (
         _dequantize,
@@ -284,6 +306,51 @@ def _projection_slice_ops(text, e, head_width=128):
     return found
 
 
+def _served_programs(cfg, slots, stripe, one_chip, relaid=True):
+    """``decode_step`` over every slot, and the 256-token ``prefill`` without
+    and with logits (the engine's ``chunk_mid`` and ``chunk_final`` bodies):
+    name -> (function, described arguments), the parameters in the formats
+    the engine's rule gives (``models/llama.py serving_layouts``) or, with
+    ``relaid=False``, in the default ones."""
+    from jax.experimental.layout import Format, Layout
+
+    from ray_tpu.models.llama import (
+        decode_step, init_kv_cache, init_params, prefill, serving_layouts,
+    )
+
+    def described(make, relaid):
+        tree = jax.eval_shape(make)
+        orders = serving_layouts(tree) if relaid else {}
+        return {
+            k: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=(
+                Format(Layout(major_to_minor=orders[k]), one_chip) if k in orders else one_chip))
+            for k, x in tree.items()
+        }
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = described(lambda: init_params(jax.random.PRNGKey(0), cfg), relaid)
+    chunk = (params, described(lambda: init_kv_cache(cfg, 1, stripe), False),
+             i32(1, 256), i32(1), i32(1))
+    return {
+        "decode_step": (
+            lambda p, c, t: decode_step(p, c, t, cfg),
+            (params, described(lambda: init_kv_cache(cfg, slots, stripe), False), i32(slots)),
+        ),
+        "chunk_mid": (
+            lambda p, o, t, n, s: prefill(p, o, t, cfg, lengths=n, start_pos=s,
+                                          with_logits=False)[1], chunk),
+        "chunk_final": (
+            lambda p, o, t, n, s: prefill(p, o, t, cfg, lengths=n, start_pos=s), chunk),
+    }
+
+
+def _program_text(program):
+    fn, args = program
+    return jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()
+
+
 @pytest.mark.parametrize("program", ["decode_step", "chunk_mid"])
 @pytest.mark.parametrize("served", sorted(_SERVED))
 def test_served_programs_read_a_layers_projection_slice_in_place(
@@ -297,43 +364,89 @@ def test_served_programs_read_a_layers_projection_slice_in_place(
     width, contraction over ``d_model``): 3 such operations in Mistral's
     decode step, 5 in its chunk, 13 and 24 in Laguna's (PERF.md section 6,
     PR 29; on the chip 1.1 of a 14.7 ms decode step)."""
-    from jax.experimental.layout import Format, Layout
-
-    from ray_tpu.models.llama import (
-        decode_step, init_kv_cache, init_params, prefill, serving_layouts,
-    )
-
     cfg = _served_config(served)
     slots, stripe, e = _SERVED[served]
 
-    def described(make, orders=None):
-        tree = jax.eval_shape(make)
-        orders = serving_layouts(tree) if orders is None else orders
-        return {
-            k: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=(
-                Format(Layout(major_to_minor=orders[k]), one_chip) if k in orders else one_chip))
-            for k, x in tree.items()
-        }
+    def count(relaid):
+        programs = _served_programs(cfg, slots, stripe, one_chip, relaid)
+        return _projection_slice_ops(_program_text(programs[program]), e)
 
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    assert count(True) == []
+    assert count(False)  # the guard sees the copies where the layout is the default
 
-    if program == "decode_step":
-        def fn(params, cache, tokens):
-            return decode_step(params, cache, tokens, cfg)
 
-        rest = (described(lambda: init_kv_cache(cfg, slots, stripe), {}), i32(slots))
-    else:
-        def fn(params, one, tokens, length, start):
-            return prefill(params, one, tokens, cfg, lengths=length, start_pos=start,
-                           with_logits=False)[1]
+# ---- the decode step's read of the cache, and the chunk programs beside it ---
 
-        rest = (described(lambda: init_kv_cache(cfg, 1, stripe), {}), i32(1, 256), i32(1), i32(1))
 
-    def count(orders):
-        params = described(lambda: init_params(jax.random.PRNGKey(0), cfg), orders)
-        text = jax.jit(fn, donate_argnums=(1,)).lower(params, *rest).compile().as_text()
-        return _projection_slice_ops(text, e)
+def _yields_a_layer_of_the_cache(text, slots, stripe, heads=8, width=128):
+    """The operations, in any computation, whose result is one layer of the
+    cache (``[slots, 8, stripe, 128]``, with or without a leading 1), and the
+    copies of the whole cache."""
+    import re
 
-    assert count(None) == []
-    assert count({})  # the guard sees the copies where the layout is the default
+    layer = re.compile(r"= \w+\[(?:1,)?%d,%d,%d,%d\]\S* (?!parameter|get-tuple-element)"
+                       % (slots, heads, stripe, width))
+    whole = re.compile(r"= \w+\[\d+,%d,%d,%d,%d\]\S* copy\(" % (slots, heads, stripe, width))
+    return [line.strip()[:160] for line in text.splitlines()
+            if layer.search(line) or whole.search(line)]
+
+
+def _same_program(text):
+    """Compiled text without what names a source line: metadata, the
+    stack-frame tables, a Mosaic kernel's bytecode; instructions renumbered
+    by first appearance."""
+    import re
+
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r'backend_config="[^"]*"', "", text)
+    text = "\n".join(
+        line for line in text.splitlines()
+        if not line.startswith(("FileNames", "FunctionNames", "FileLocations", "StackFrames"))
+        and not re.match(r"\d+ ", line))
+    seen = {}
+    return re.sub(r"%[\w.\-]+", lambda m: seen.setdefault(m.group(0), f"%{len(seen)}"), text)
+
+
+@pytest.mark.parametrize("served", sorted(_SERVED))
+def test_decode_step_reads_the_cache_where_it_lies(
+        served, one_chip, no_compile_cache, native_kernels, monkeypatch):
+    """``decode_step`` at the serving cells' shapes runs one
+    ``decode_attention`` kernel a traced layer on the carried cache whole:
+    nothing in the program yields a layer of the cache (the einsum's
+    ``ck_all[l]`` is a 134 MB slice a tensor and layer in Mistral's cell, 268
+    MB in Laguna's, read whole at any length: PERF.md section 6, PR 31) and
+    nothing copies the cache."""
+    from ray_tpu.models import patterned
+
+    cfg = _served_config(served)
+    slots, stripe, _ = _SERVED[served]
+    text = _program_text(_served_programs(cfg, slots, stripe, one_chip)["decode_step"])
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line and "attn_core" in line]
+    # one a traced layer: Mistral's stack is one loop body, Laguna's five layers
+    # are the leading one and one period
+    assert len(kernels) == (cfg.n_layers if cfg.layer_types else 1), kernels
+    assert _yields_a_layer_of_the_cache(text, slots, stripe) == []
+
+    monkeypatch.setattr(patterned, "reads_blocks", lambda *a: False)
+    einsum = _program_text(_served_programs(cfg, slots, stripe, one_chip)["decode_step"])
+    assert _yields_a_layer_of_the_cache(einsum, slots, stripe)  # the guard sees the slices
+
+
+@pytest.mark.parametrize("program", ["chunk_mid", "chunk_final"])
+@pytest.mark.parametrize("served", sorted(_SERVED))
+def test_chunk_programs_are_what_they_are_without_the_decode_kernel(
+        served, program, one_chip, no_compile_cache, native_kernels, monkeypatch):
+    """A prompt's chunk (``T > 1``) keeps the einsum: the 256-token ``prefill``
+    without and with logits compiles to the text it compiles to with the
+    kernel's selection switched off, source lines apart."""
+    from ray_tpu.models import patterned
+
+    cfg = _served_config(served)
+    slots, stripe, _ = _SERVED[served]
+    text = _same_program(_program_text(_served_programs(cfg, slots, stripe, one_chip)[program]))
+    assert "decode_attention" not in text
+    monkeypatch.setattr(patterned, "reads_blocks", lambda *a: False)
+    # new functions, so that they are traced anew
+    assert _same_program(
+        _program_text(_served_programs(cfg, slots, stripe, one_chip)[program])) == text

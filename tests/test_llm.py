@@ -489,23 +489,31 @@ def test_prefix_cache_hit_and_equivalence(engine):
     assert warm_other.metrics["prefix_hit_tokens"] > 0
 
 
-def test_seq_len_bucket_pools():
+@pytest.mark.parametrize("dtype, buckets", [
+    ("bfloat16", (128, 256)), ("float32", (128, 256)), ("float32", (32, 128))])
+def test_seq_len_bucket_pools(dtype, buckets):
     """Stripe pools: short chats run in short-stripe slots; long requests
     land in the long pool; both produce identical results to a single-pool
-    engine (greedy)."""
+    engine (greedy). At the engine's default bf16 both pools' stripes are
+    whole blocks of ``ops/decode_attention.py``, so every decode step on
+    either side reads through the kernel, which gives a request the same
+    numbers in a stripe of any length. A 32-position stripe keeps the einsum,
+    whose scores are rounded to the model's dtype where the kernel's stay
+    float32: the two forms agree to the token in float32, and in bf16 to
+    rounding (``tests/test_decode_attention.py``)."""
+    short_stripe, long_stripe = buckets
+    common = dict(
+        max_num_seqs=4, max_seq_len=long_stripe, dtype=dtype,
+        prefill_buckets=(16, 32, 64, 128),
+    )
     base = LLMConfig(
         model=ModelConfig(model_id="tiny", tokenizer="byte", seed=0),
-        engine=EngineConfig(
-            max_num_seqs=4, max_seq_len=128,
-            prefill_buckets=(16, 32, 64, 128),
-        ),
+        engine=EngineConfig(**common),
     )
     pooled = LLMConfig(
         model=ModelConfig(model_id="tiny", tokenizer="byte", seed=0),
         engine=EngineConfig(
-            max_num_seqs=4, max_seq_len=128,
-            prefill_buckets=(16, 32, 64, 128),
-            seq_len_buckets=(32, 128), seqs_per_bucket=(2, 2),
+            **common, seq_len_buckets=buckets, seqs_per_bucket=(2, 2),
             enable_prefix_caching=False,
         ),
     )
@@ -515,7 +523,9 @@ def test_seq_len_bucket_pools():
         sp_short = SamplingParams(max_tokens=6, temperature=0.0)
         sp_long = SamplingParams(max_tokens=40, temperature=0.0)
         short_prompt = "hi there"
-        long_prompt = "tell me a long story " * 3
+        # too long for the short stripe with its 40 new tokens
+        long_prompt = "tell me a long story " * (short_stripe // 21 + 1)
+        assert len(short_prompt) + 6 < short_stripe < len(long_prompt) + 40 < long_stripe
         r1s = e1.generate(short_prompt, sampling_params=sp_short)
         r2s = e2.generate(short_prompt, sampling_params=sp_short)
         assert r1s.token_ids == r2s.token_ids
@@ -523,7 +533,7 @@ def test_seq_len_bucket_pools():
         r2l = e2.generate(long_prompt, sampling_params=sp_long)
         assert r1l.token_ids == r2l.token_ids
         pools = e2.get_stats()["pools"]
-        assert [p["stripe_len"] for p in pools] == [32, 128]
+        assert [p["stripe_len"] for p in pools] == list(buckets)
     finally:
         e1.shutdown()
         e2.shutdown()
@@ -556,7 +566,8 @@ def test_multi_step_decode_equivalence():
         e2.shutdown()
 
 
-def test_chunked_prefill_to_the_stripes_end_matches_full_forward():
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_chunked_prefill_to_the_stripes_end_matches_full_forward(dtype):
     """Prompt chunks go into the scratch stripe as contiguous blocks
     (``models/patterned.py _write_block``). Through the engine: a 300-token
     prompt in five chunks; then two prompts behind a 16-token prefix hit, so
@@ -564,7 +575,16 @@ def test_chunked_prefill_to_the_stripes_end_matches_full_forward():
     passes the stripe's end (16 + 7 * 64 + 64 > 512), one of them
     ``stripe_len - 1`` long. Each returns the tokens ``forward`` gives on the
     same weights; no path but the block write is reachable from the engine's
-    prefill (B = 1, width <= stripe), so there is no fallback to count."""
+    prefill (B = 1, width <= stripe), so there is no fallback to count.
+
+    The first token is the prefill's, whose attention is ``forward``'s
+    einsum: equal to the token at either dtype. The later ones come from
+    decode steps, which read the 512-position stripe through the decode
+    kernel (``ops/decode_attention.py``): its scores stay float32 where
+    ``forward`` rounds them to the model's dtype. In float32 they are
+    ``forward``'s greedy tokens; at the engine's default bf16 each is a token
+    ``forward`` puts within bf16's rounding of its best, given the engine's
+    tokens before it."""
     import jax.numpy as jnp
 
     from ray_tpu.models.llama import forward, init_kv_cache, prefill
@@ -574,7 +594,7 @@ def test_chunked_prefill_to_the_stripes_end_matches_full_forward():
         model=ModelConfig(model_id="tiny", tokenizer="byte", seed=0),
         engine=EngineConfig(
             max_num_seqs=2, max_seq_len=stripe, prefill_chunk=64,
-            prefill_buckets=(16, 32, 64, 128),
+            prefill_buckets=(16, 32, 64, 128), dtype=dtype,
         ),
     ))
     try:
@@ -601,13 +621,20 @@ def test_chunked_prefill_to_the_stripes_end_matches_full_forward():
                 ),
             )
             assert out.metrics["prefix_hit_tokens"] == hit
-            seq = list(ids)
-            for _ in range(n_new):
-                logits = forward(
-                    eng.params, jnp.asarray([seq], jnp.int32), eng.model_cfg
-                )
-                seq.append(int(jnp.argmax(logits[0, -1])))
-            assert out.token_ids == seq[len(ids):]
+            # ``forward`` over the prompt and the engine's tokens, one pass:
+            # row n - 1 + i is what it makes of the i-th new token
+            logits = np.asarray(forward(
+                eng.params, jnp.asarray([ids + out.token_ids[:-1]], jnp.int32),
+                eng.model_cfg,
+            )[0, len(ids) - 1:], np.float32)
+            assert len(logits) == len(out.token_ids) == n_new
+            best = logits.argmax(-1)
+            assert out.token_ids[0] == best[0]
+            if dtype == "float32":
+                assert out.token_ids == best.tolist()
+            else:  # bf16 keeps 8 bits: four steps of the largest logit's rounding
+                behind = logits.max(-1) - logits[np.arange(n_new), out.token_ids]
+                assert (behind <= 4 * 2.0**-8 * np.abs(logits).max()).all(), behind
             # a tiny model's argmax hardly feels a misplaced key: read the
             # slot's keys and values back, against the prompt in one piece
             n = len(ids)

@@ -181,6 +181,103 @@ def test_padded_prefill_leaves_the_cache_and_logits_of_an_unpadded_one(monkeypat
         np.testing.assert_allclose(logits, whole[jnp.arange(2), at], **tol)
 
 
+@pytest.mark.parametrize("model", ["dense", "dense-lora", "moe", "laguna"])
+def test_decode_steps_through_the_kernel_give_the_einsums_tokens(monkeypatch, model):
+    """16 greedy ``decode_step``s over a cache of whole blocks, which go
+    through ``ops/decode_attention.py`` (interpreted), against the same steps
+    with the kernel's selection switched off: the einsum over the whole
+    stripe that every decode step ran before. Row 0 crosses a block's end on
+    its way, row 1 stays inside the first block, and the patterned model's
+    window starts mid-block."""
+    from ray_tpu.ops.decode_attention import BLOCK
+
+    cfg, params, lora_kw, _, _ = _model(model)
+    lengths = jnp.asarray([BLOCK - 6, 30], jnp.int32)
+    prompt = jax.random.randint(jax.random.PRNGKey(3), (2, BLOCK), 0, cfg.vocab_size)
+
+    traced = _count_kernel_calls(monkeypatch)
+
+    def greedy(read_blocks):
+        if not read_blocks:
+            monkeypatch.setattr(patterned, "reads_blocks", lambda *a: False)
+        step = jax.jit(lambda cache, toks: decode_step(params, cache, toks, cfg, **lora_kw()))
+        logits, cache = prefill(params, init_kv_cache(cfg, 2, 2 * BLOCK), prompt, cfg,
+                                lengths=lengths, **lora_kw())
+        tokens, rows = [], [logits]
+        for _ in range(16):
+            tokens.append(jnp.argmax(rows[-1], -1))
+            logits, cache = step(cache, tokens[-1])
+            rows.append(logits)
+        return np.asarray(jnp.stack(tokens)), np.asarray(jnp.stack(rows))
+
+    tokens, logits = greedy(True)
+    through_the_kernel = len(traced)
+    want_tokens, want_logits = greedy(False)
+    # one call a layer of the traced stack: the leading layers and one period
+    assert through_the_kernel == (5 if cfg.layer_types else 1) == len(traced)
+    np.testing.assert_array_equal(tokens, want_tokens)
+    np.testing.assert_allclose(logits, want_logits, atol=5e-5, rtol=1e-4)
+
+
+def _count_kernel_calls(monkeypatch):
+    """The kernel's calls, as a layer loop's body is traced."""
+    traced = []
+    kernel = patterned.decode_attention
+    monkeypatch.setattr(patterned, "decode_attention",
+                        lambda *a: traced.append(a[3]) or kernel(*a))
+    return traced
+
+
+def _placed(how, cfg, slots, stripe):
+    """``decode_step``'s arguments and ``jit`` options as each caller places
+    them, on four virtual devices."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    def cache_on(mesh):  # ``llm/spmd.py``: key-value heads over ``tp``, made where they lie
+        kv = NamedSharding(mesh, P(None, None, "tp", None, None))
+        shardings = {"k": kv, "v": kv, "length": NamedSharding(mesh, P())}
+        return jax.jit(lambda: init_kv_cache(cfg, slots, stripe), out_shardings=shardings)(), shardings
+
+    if how == "one-device":  # ``JaxEngine`` with no mesh
+        return init_params(jax.random.PRNGKey(7), cfg), init_kv_cache(cfg, slots, stripe), {}
+    if how == "mesh-of-one-device":  # ``JaxEngine(config, mesh=<a mesh of one device>)``
+        mesh = build_mesh(MeshSpec(), devices=jax.devices()[:1])
+        cache, _ = cache_on(mesh)
+        return init_params(jax.random.PRNGKey(7), cfg, mesh=mesh), cache, {}
+    mesh = build_mesh(MeshSpec(dp=2, tp=2), devices=jax.devices()[:4])
+    params = init_params(jax.random.PRNGKey(7), cfg, mesh=mesh)
+    if how == "engine-tp2":  # ``JaxEngine(tensor_parallel_degree=2)``: only the parameters on the mesh
+        return params, init_kv_cache(cfg, slots, stripe), {}
+    cache, shardings = cache_on(mesh)  # ``llm/spmd.py`` and, through it, ``llm/gang.py``
+    return params, cache, dict(out_shardings=(NamedSharding(mesh, P()), shardings))
+
+
+@pytest.mark.parametrize("how, kernel_calls", [
+    ("one-device", 1), ("mesh-of-one-device", 1), ("engine-tp2", 0), ("spmd-tp2", 0)])
+def test_a_decode_step_over_a_mesh_keeps_the_einsum(monkeypatch, how, kernel_calls):
+    """``reads_blocks`` sees a mesh on the type of what the step is traced
+    with: one kernel call a traced layer where everything lies on one device,
+    none where the parameters or the cache lie on four, placed and jitted as
+    ``llm/spmd.py`` and a ``JaxEngine`` under ``tensor_parallel_degree`` do
+    (a Pallas call under the partitioner would be handed the whole gathered
+    cache). Asked with the arrays themselves, as the engine asks for its
+    counter, it answers what the trace does."""
+    from ray_tpu.ops.decode_attention import BLOCK
+
+    cfg = MODELS["dense"]
+    params, cache, options = _placed(how, cfg, 2, BLOCK)
+    traced = _count_kernel_calls(monkeypatch)
+    step = jax.jit(lambda params, cache, toks: decode_step(params, cache, toks, cfg),
+                   donate_argnums=(1,), **options)
+    asked = patterned.reads_blocks(BLOCK, cache["k"], *jax.tree.leaves(params))
+    logits, _ = step(params, cache, jnp.asarray([3, 5], jnp.int32))
+    assert len(traced) == kernel_calls and asked == bool(kernel_calls)
+    assert bool(jnp.isfinite(logits).all())
+    assert not patterned.reads_blocks(BLOCK + 8, jnp.zeros(1))  # no whole blocks: the einsum anywhere
+
+
 def test_grouped_expert_form_equals_every_expert_form(params):
     """``_moe_decode_ffn`` sorts tokens by expert; the same sum with every
     expert run over every token and a zero weight where a token did not
@@ -429,6 +526,90 @@ def test_a_dense_engine_counts_no_routing_and_no_window():
         assert all(v == {"decode": 0, "chunk_mid": 0, "chunk_final": 0} for v in routing.values())
         assert len(routing) == 4
         assert c["decode_kv_tokens_window"] == 0 < c["decode_kv_tokens_global"]
+    finally:
+        eng.shutdown()
+
+
+def test_positions_read_counts_the_blocks_each_slots_bounds_cover():
+    """Two requests at once in 256-position stripes, the longer crossing the
+    first block's end while it decodes: ``decode_kv_positions_read`` (and the
+    window layers' ``_window``) are ``ops/decode_attention.py positions_read``
+    summed over the lengths the loop held at each launch, no less than the
+    tokens needed and no more than the active slots' whole stripes."""
+    from ray_tpu.ops.decode_attention import BLOCK, positions_read
+
+    stripe = 2 * BLOCK
+    eng = JaxEngine(LLMConfig(
+        model=ModelConfig(model_id="laguna-tiny", seed=3),
+        engine=EngineConfig(max_num_seqs=4, max_seq_len=stripe, dtype="float32",
+                            prefill_chunk=64, prefill_buckets=(16, 32, 64)),
+    ))
+    try:
+        window = eng.model_cfg.sliding_window
+        want = {"full": 0, "window": 0, "whole": 0}
+        launch = eng._decode
+
+        def counting(pool, *args):  # called by the loop right before it counts
+            for r in pool.slots:
+                if r is not None:
+                    n = len(r.prompt_token_ids) + len(r.out_tokens)
+                    want["full"] += eng._decode_n_steps * positions_read(0, n, stripe)
+                    want["window"] += eng._decode_n_steps * positions_read(n - window, n, stripe)
+                    want["whole"] += eng._decode_n_steps * stripe
+            return launch(pool, *args)
+
+        eng._decode = counting
+        rng = np.random.default_rng(9)
+        p = SamplingParams(max_tokens=24, temperature=0.0, ignore_eos=True)
+        reqs = [eng.submit(prompt_token_ids=[int(t) for t in rng.integers(32, 127, n)],
+                           sampling_params=p) for n in (BLOCK - 10, 21)]
+        for r in reqs:
+            assert r.done.wait(timeout=120)
+        c = eng.get_stats()["counters"]
+        assert c["decode_kv_positions_read"] == want["full"] > 0
+        assert c["decode_kv_positions_read_window"] == want["window"] > 0
+        assert c["decode_kv_tokens_global"] <= c["decode_kv_positions_read"] <= want["whole"]
+        assert c["decode_kv_tokens_window"] <= c["decode_kv_positions_read_window"] <= want["whole"]
+        assert want["whole"] == c["decode_slot_steps"] * stripe
+        # some steps read one block of the long request's stripe, some both; the
+        # window never more than two
+        assert BLOCK * c["decode_slot_steps"] < c["decode_kv_positions_read"] < want["whole"]
+        assert c["decode_kv_positions_read_window"] <= c["decode_kv_positions_read"]
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("placed", ["no-mesh", "mesh-of-one-device", "tp2"])
+def test_an_engine_counts_the_form_its_decode_steps_take(monkeypatch, placed):
+    """The engine's counter and the body's choice are one answer
+    (``patterned.reads_blocks``, asked once a pool): where the traced decode
+    program calls the kernel, ``decode_kv_positions_read`` counts blocks;
+    where it keeps the einsum (parameters over a mesh), whole stripes. A mesh
+    of one device is one device on both sides."""
+    from ray_tpu.ops.decode_attention import BLOCK
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    mesh = {"no-mesh": None,
+            "mesh-of-one-device": build_mesh(MeshSpec(), devices=jax.devices()[:1]),
+            "tp2": build_mesh(MeshSpec(dp=2, tp=2), devices=jax.devices()[:4])}[placed]
+    traced = _count_kernel_calls(monkeypatch)
+    stripe = 2 * BLOCK
+    eng = JaxEngine(LLMConfig(
+        model=ModelConfig(model_id="tiny", tokenizer="byte", seed=3),
+        engine=EngineConfig(max_num_seqs=2, max_seq_len=stripe, dtype="float32",
+                            prefill_buckets=(16, 32), enable_prefix_caching=False,
+                            tensor_parallel_degree=2 if placed == "tp2" else 1),
+    ), mesh=mesh)
+    try:
+        out = eng.generate(prompt_token_ids=list(range(40, 60)), sampling_params=SamplingParams(
+            max_tokens=8, temperature=0.0, ignore_eos=True))
+        assert len(out.token_ids) == 8
+        c = eng.get_stats()["counters"]
+        [pool] = eng._pools
+        assert pool.reads_blocks == bool(traced) == (placed != "tp2")
+        per_slot_step = BLOCK if pool.reads_blocks else stripe  # 28 positions at most: one block
+        assert c["decode_kv_positions_read"] == c["decode_slot_steps"] * per_slot_step > 0
+        assert c["decode_kv_positions_read_window"] == 0  # no window layers in this model
     finally:
         eng.shutdown()
 
