@@ -84,7 +84,7 @@ class EngineConfig:
 class ModelConfig:
     # a preset of ``resolve_llama_config``: "tiny" | "llama2-7b" |
     # "llama3-8b" | "llama3.2-3b" | "llama3-70b" | "laguna-xs.2" |
-    # "laguna-tiny"
+    # "laguna-tiny" | "kanana-2-30b-a3b" | "kanana-tiny"
     model_id: str = "tiny"
     tokenizer: str = "byte"  # "byte" | transformers tokenizer path
     checkpoint_path: Optional[str] = None  # ray_tpu.train pytree checkpoint
@@ -119,6 +119,8 @@ def resolve_llama_config(model: "ModelConfig", engine: "EngineConfig", min_vocab
         "llama3-70b": LlamaConfig.llama3_70b,
         "laguna-xs.2": LlamaConfig.laguna_xs2,
         "laguna-tiny": LlamaConfig.laguna_tiny,
+        "kanana-2-30b-a3b": LlamaConfig.kanana2_30b_a3b,
+        "kanana-tiny": LlamaConfig.kanana_tiny,
     }
     kw = dict(
         max_seq_len=engine.max_seq_len,
@@ -131,6 +133,21 @@ def resolve_llama_config(model: "ModelConfig", engine: "EngineConfig", min_vocab
     if cfg.vocab_size < min_vocab:
         cfg = _dc.replace(cfg, vocab_size=min_vocab)
     return cfg
+
+
+def refuse_latent(cfg, module: str) -> None:
+    """``llm/spmd.py`` and ``llm/gang.py`` have their own copies of the
+    programs that fill, copy and read the cache, and a sharded engine places
+    it on a mesh; none of them knows a latent-attention cache (one shared
+    rotated key and one latent a token, no key-value heads to shard). They
+    refuse such a model by name rather than serve it wrongly."""
+    if cfg.kv_latent_rank:
+        raise NotImplementedError(
+            f"{module}: a latent-attention model (kv_latent_rank="
+            f"{cfg.kv_latent_rank}) is served on one device by llm/engine.py "
+            "JaxEngine with tensor_parallel_degree=1; this path has no rule "
+            "for its cache"
+        )
 
 
 @dataclasses.dataclass

@@ -78,6 +78,20 @@ COUNTERS = (
     # tokens over positions is the read's efficiency, positions over
     # decode_slot_steps x stripe what it still touches of the stripe
     "decode_kv_positions_read", "decode_kv_positions_read_window",
+    # the same pair for the layers of a latent-attention model (a token there
+    # is one shared rotated key and one latent; the kernel's blocks are
+    # longer, ``ops/decode_attention.py LATENT_BLOCKS``); such a model has no
+    # full or window layers, so the four above stay 0 for it
+    "decode_kv_tokens_latent", "decode_kv_positions_read_latent",
+    # a prompt chunk's attention, by program: the chunk's real tokens, and
+    # over them the cached positions each attends to (a token at position p
+    # sees p + 1: what came before the chunk and the chunk up to itself)
+    "prefill_query_tokens:chunk_mid", "prefill_query_tokens:chunk_final",
+    "prefill_attended_positions:chunk_mid", "prefill_attended_positions:chunk_final",
+    # tokens whose keys and values ``prefix_seed`` programs copied into a
+    # scratch stripe (``prompt_tokens_from_prefix`` counts the same tokens at
+    # admission; this one counts the copies)
+    "prefix_seed_tokens",
     # routed experts (``models/llama.py MOE_STATS``), summed over expert
     # layers and over the runs of each program: the decode program hands its
     # counts out beside its tokens, a prompt's middle chunks add theirs up on
@@ -87,7 +101,9 @@ COUNTERS = (
     *(f"{name}:{program}" for name in _MOE_COUNTERS for program in _MOE_PROGRAMS),
 )
 _LABEL = {"requests_finished": "reason", "requests_failed": "stage",
-          "prefill_chunks": "kind", **dict.fromkeys(_MOE_COUNTERS, "program")}
+          "prefill_chunks": "kind",
+          **dict.fromkeys((*_MOE_COUNTERS, "prefill_query_tokens",
+                           "prefill_attended_positions"), "program")}
 # request latencies: 1 ms to 200 s, a quarter more each bucket, so a median
 # read from the bucket counts is within an eighth of the truth
 LATENCY_BOUNDS = tuple(1e-3 * 1.25**i for i in range(56))
@@ -194,7 +210,19 @@ class _Pool:
 
         self.stripe_len = stripe_len
         self.n_slots = n_slots
-        self.cache = init_kv_cache(model_cfg, n_slots, stripe_len)
+        self.latent = bool(model_cfg.kv_latent_rank)
+        device = jax.local_devices()[0]
+        before = (device.memory_stats() or {}).get("bytes_in_use")
+        self.cache = jax.block_until_ready(init_kv_cache(model_cfg, n_slots, stripe_len))
+        after = (device.memory_stats() or {}).get("bytes_in_use")
+        # bytes a token of all layers: as the arrays' shapes give them, and as
+        # the device holds them (a minor axis narrower than the chip's 128
+        # lanes is padded to them); None where the backend reports no memory
+        tokens = n_slots * stripe_len
+        self.kv_bytes_per_token = (self.cache["k"].nbytes + self.cache["v"].nbytes) / tokens
+        self.kv_bytes_per_token_held = (
+            None if before is None or after is None else (after - before) / tokens
+        )
         self.slots: list[Optional[_Request]] = [None] * n_slots
         self.temps = np.zeros((n_slots,), np.float32)
         self.top_ks = np.full((n_slots,), 50, np.int32)
@@ -214,7 +242,7 @@ class _Pool:
         # decode kernel: asked once, of the layer that decides it, with the
         # arrays the steps run on
         self.reads_blocks = reads_blocks(
-            stripe_len, self.cache["k"], *jax.tree.leaves(params)
+            stripe_len, self.cache["k"], *jax.tree.leaves(params), latent=self.latent
         )
 
     def positions_read(self, lo, hi) -> int:
@@ -225,7 +253,7 @@ class _Pool:
             return self.stripe_len * len(hi)
         from ray_tpu.ops.decode_attention import positions_read
 
-        return int(positions_read(lo, hi, self.stripe_len).sum())
+        return int(positions_read(lo, hi, self.stripe_len, self.latent).sum())
 
 
 class JaxEngine:
@@ -364,7 +392,12 @@ class JaxEngine:
         self.model_cfg = resolve_llama_config(
             mc, ec, min_vocab=self.tokenizer.vocab_size
         )
-        if ec.tensor_parallel_degree > 1 or ec.sequence_parallel_degree > 1:
+        sharded = ec.tensor_parallel_degree > 1 or ec.sequence_parallel_degree > 1
+        if sharded or (self._mesh is not None and self._mesh.size > 1):
+            from ray_tpu.llm.config import refuse_latent
+
+            refuse_latent(self.model_cfg, "llm/engine.py over a mesh")
+        if sharded:
             from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 
             if self._mesh is None:
@@ -841,12 +874,15 @@ class JaxEngine:
             "max_num_seqs": sum(p.n_slots for p in self._pools),
             "pools": [
                 {"stripe_len": p.stripe_len, "n_slots": p.n_slots,
-                 "active": sum(s is not None for s in p.slots)}
+                 "active": sum(s is not None for s in p.slots),
+                 "kv_bytes_per_token": p.kv_bytes_per_token,
+                 "kv_bytes_per_token_held": p.kv_bytes_per_token_held}
                 for p in self._pools
             ],
             "prefix_cache_hits": self._prefix_hits,
             "prefix_cache_misses": self._prefix_misses,
             "prefix_cache_entries": len(self._prefix_cache),
+            "prefix_cache_bytes": self._prefix_bytes,
             # cumulative since the engine started
             "counters": self._counters_view(),
             # prompt plus generated tokens of the bound slots: what a decode
@@ -1034,6 +1070,7 @@ class JaxEngine:
         if prefix is not None:
             with tracing.annotate("engine.prefix_seed", tokens=m):
                 one = self._seed_prefix_jit(one, prefix["k"], prefix["v"])
+            self._n["prefix_seed_tokens"] += m
         pool.admitting[slot] = _Admission(req, slot, one, chunks, m)
 
     def _advance_admission(self, pool: "_Pool", adm: _Admission) -> None:
@@ -1047,6 +1084,11 @@ class JaxEngine:
         req = adm.req
         req.chunks_run += 1
         self._n["prefill_chunks:final" if is_final else "prefill_chunks:mid"] += 1
+        program = "chunk_final" if is_final else "chunk_mid"
+        self._n["prefill_query_tokens:" + program] += eff_len
+        self._n["prefill_attended_positions:" + program] += (
+            eff_len * start + eff_len * (eff_len + 1) // 2
+        )
         lora_kw = self._lora_kw(req.lora_idx)
         t = jnp.asarray(toks)
         l = jnp.asarray([eff_len], jnp.int32)
@@ -1202,8 +1244,12 @@ class JaxEngine:
                     np.int64, len(active),
                 )
                 steps = self._decode_n_steps
-                self._n["decode_kv_tokens_global"] += steps * int(lengths.sum())
-                self._n["decode_kv_positions_read"] += steps * pool.positions_read(0, lengths)
+                tokens, read = (
+                    ("decode_kv_tokens_latent", "decode_kv_positions_read_latent")
+                    if pool.latent else ("decode_kv_tokens_global", "decode_kv_positions_read")
+                )
+                self._n[tokens] += steps * int(lengths.sum())
+                self._n[read] += steps * pool.positions_read(0, lengths)
                 window = self.model_cfg.sliding_window
                 if window:  # a model without one has no window layers to count for
                     self._n["decode_kv_tokens_window"] += steps * int(

@@ -26,7 +26,12 @@ import functools
 import logging
 from typing import Optional
 
-from ray_tpu.llm.config import LLMConfig, SamplingParams, resolve_llama_config
+from ray_tpu.llm.config import (
+    LLMConfig,
+    SamplingParams,
+    refuse_latent,
+    resolve_llama_config,
+)
 
 
 def _pad_bucket(n: int, buckets) -> int:
@@ -56,6 +61,7 @@ class SPMDGenerator:
         self.model_cfg = resolve_llama_config(
             mc, ec, min_vocab=self.tokenizer.vocab_size
         )
+        refuse_latent(self.model_cfg, "llm/spmd.py")
         if mesh is None:
             n = len(jax.devices())
             if (

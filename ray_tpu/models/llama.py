@@ -120,10 +120,28 @@ class LlamaConfig:
     # sigmoid of a [d_model, heads] projection of the layer's normed input,
     # times each head's attention output before wo
     attn_gate: bool = False
+    # --- latent attention (layer type 'latent'; DeepSeek-V3's MLA) ---
+    # ``kv_latent_rank`` > 0: a token's cache entry a layer is one normed
+    # latent of that width and one rotated key of ``qk_rope_dim`` that all
+    # heads share; each head's query is ``qk_nope_dim`` wide against the key
+    # expanded from the latent plus ``qk_rope_dim`` against the shared key,
+    # its value ``v_head_dim`` wide, expanded from the latent too
+    kv_latent_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # rotate the pairs (2i, 2i+1) of the rotated part, not (i, i + half)
+    rope_interleave: bool = False
+    # the router's scores: 'softmax' over the experts, or 'sigmoid' of each
+    # logit with a selection bias (``moe_router_bias``) that takes part in
+    # the choice of the top k and not in their weights
+    moe_scoring: str = "softmax"
 
     def __post_init__(self):
         if self.attention not in ("full", "ring", "ulysses", "splash"):
             raise ValueError(f"unknown attention {self.attention!r}")
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown moe_scoring {self.moe_scoring!r}")
         for name in ("layer_types", "heads_per_layer", "mlp_types"):
             value = tuple(getattr(self, name))
             object.__setattr__(self, name, value)
@@ -282,6 +300,54 @@ class LlamaConfig:
         d.update(kw)
         return LlamaConfig(**d)
 
+    @staticmethod
+    def kanana2_30b_a3b(**kw) -> "LlamaConfig":
+        """kakaocorp Kanana-2-30B-A3B (``model_type: deepseek_v3``) as its
+        config.json has it: 48 latent-attention layers (no query latent: 32
+        heads of 128 + 64, a 512-wide key-value latent and one 64-wide
+        rotated key a token, values 128 a head, interleaved rotation), layer
+        0 a dense SwiGLU of 6144, then 128 experts of width 768, 6 a token by
+        a sigmoid router with a selection bias, weights renormalised and
+        times 2.448, beside two shared experts (one SwiGLU of 1536). A caller
+        that cuts ``n_layers`` gets the first entries of the per-layer lists.
+        Not in the config: the bias is a buffer the training moves (seeded
+        small here), no group step (``n_group`` 1)."""
+        d = dict(
+            vocab_size=128256, d_model=2048, n_layers=48, n_heads=32, n_kv_heads=1,
+            d_ff=6144, max_seq_len=32768, rms_eps=1e-6, rope_theta=1e6,
+            kv_latent_rank=512, qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
+            rope_interleave=True, moe_experts=128, moe_top_k=6, moe_d_ff=768,
+            moe_shared_d_ff=1536, moe_routed_scale=2.448, moe_scoring="sigmoid",
+        )
+        d.update(kw)
+        return LlamaConfig(**_latent_lists(d))
+
+    @staticmethod
+    def kanana_tiny(**kw) -> "LlamaConfig":
+        """Test-size model with ``kanana2_30b_a3b``'s pattern: a leading
+        dense layer, then expert layers, all latent attention."""
+        d = dict(
+            vocab_size=256, d_model=64, n_layers=3, n_heads=4, n_kv_heads=1,
+            d_ff=128, max_seq_len=128, dtype=jnp.float32, remat=False, rms_eps=1e-6,
+            rope_theta=1e6, kv_latent_rank=32, qk_nope_dim=16, qk_rope_dim=8,
+            v_head_dim=16, rope_interleave=True, moe_experts=16, moe_top_k=3,
+            moe_d_ff=32, moe_shared_d_ff=64, moe_routed_scale=2.448,
+            moe_scoring="sigmoid",
+        )
+        d.update(kw)
+        return LlamaConfig(**_latent_lists(d))
+
+
+def _latent_lists(d: dict) -> dict:
+    """The per-layer lists of a model whose layers are all latent attention,
+    one dense feed-forward and then expert ones, for its depth."""
+    n = d["n_layers"]
+    d.setdefault("layer_types", ("latent",) * n)
+    d.setdefault("heads_per_layer", (d["n_heads"],) * n)
+    d.setdefault("mlp_types", ("dense",) + ("sparse",) * (n - 1))
+    return d
+
+
 # Logical dims per parameter (leading 'layer' dim on stacked block params).
 _PARAM_DIMS = {
     "embed": ("vocab", "embed"),
@@ -298,6 +364,7 @@ _PARAM_DIMS = {
     "mlp_norm": (None, "norm"),
     # MoE variant: per-layer expert banks (expert dim -> ep mesh axis)
     "moe_router": (None, "embed", None),
+    "moe_router_bias": (None, None),
     "moe_w_gate": (None, "expert", "embed", "mlp"),
     "moe_w_up": (None, "expert", "embed", "mlp"),
     "moe_w_down": (None, "expert", "mlp", "embed"),
@@ -311,6 +378,20 @@ for _kind in ("full", "sliding"):
     _PARAM_DIMS["wq_" + _kind] = _PARAM_DIMS["wq"]
     _PARAM_DIMS["wo_" + _kind] = _PARAM_DIMS["wo"]
     _PARAM_DIMS["wg_" + _kind] = (None, "embed", "heads")
+# latent attention (one device: ``llm/spmd.py`` and ``llm/gang.py`` refuse
+# it): the query projection as any other, the latent's down-projection
+# [.., e, rank + rope] (it contracts ``embed`` as a feed-forward leaf does, in
+# place), its norm, and a head's two halves of the up-projection, stored so
+# that both the expansion (contracts the rank) and the absorbed form
+# (contracts the head's width) read a layer's slice in place
+_PARAM_DIMS.update({
+    "wq_latent": _PARAM_DIMS["wq"],
+    "wo_latent": _PARAM_DIMS["wo"],
+    "wkv_a_latent": (None, "embed", None),
+    "kv_norm_latent": (None, "norm"),
+    "wuk_latent": (None, "heads", "head_dim", None),
+    "wuv_latent": (None, "heads", None, "head_dim"),
+})
 
 
 def param_logical_dims(path, leaf):
@@ -338,6 +419,12 @@ def param_shardings(cfg: LlamaConfig, mesh: Mesh, rules=None):
 # decode step: PERF.md section 6, PR 29). Held head-major, ``e`` lies beside
 # the head width; logical shape, values and sharding are what they were.
 HEAD_MAJOR = (0, 2, 1, 3)
+# A latent model's query head is 128 + 64 wide, no whole number of 128-lane
+# tiles, and head-major the v5e compiler relaid all of ``wq_latent`` (126 MB
+# at 5 layers) in every decode step and every chunk (0.45 of a 9.06 ms step).
+# It wants ``embed`` itself on the lanes and the head's width on the
+# sublanes: PERF.md section 6, PR 33
+EMBED_MINOR = (0, 2, 3, 1)
 
 
 def serving_layouts(names) -> dict[str, tuple]:
@@ -346,7 +433,7 @@ def serving_layouts(names) -> dict[str, tuple]:
     is (``_PARAM_DIMS``): stacked, of rank 4, contracting its ``embed`` axis
     with the heads and the head width behind it."""
     return {
-        name: HEAD_MAJOR for name in names
+        name: EMBED_MINOR if name == "wq_latent" else HEAD_MAJOR for name in names
         if len(dims := _PARAM_DIMS.get(name, ())) == 4 and dims[:2] == (None, "embed")
     }
 
@@ -372,6 +459,8 @@ def init_params(key, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
     def fan_in_of(name, shape):
         if name.startswith(("wq", "wk", "wv")):
             return cfg.d_model
+        if name.startswith(("wuk", "wuv")):  # both expand the latent
+            return cfg.kv_latent_rank
         if name.startswith("wo"):
             return shape[-3] * shape[-2]
         return shape[-2] if len(shape) > 1 else shape[0]
@@ -381,8 +470,9 @@ def init_params(key, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
         if "norm" in name:
             maker = lambda shape=shape: jnp.ones(shape, cfg.dtype)
         else:
-            fan_in = fan_in_of(name, shape)
-            std = fan_in**-0.5
+            # the selection bias is a buffer the training moves: small and
+            # not zero, so that the choice and the weights can differ
+            std = 0.05 if name == "moe_router_bias" else fan_in_of(name, shape) ** -0.5
             maker = lambda k=k, shape=shape, std=std: (
                 jax.random.normal(k, shape, jnp.float32) * std
             ).astype(cfg.dtype)
@@ -709,16 +799,34 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
 # ---------------------------------------------------------------------------
 
 
+_LANES = 128  # the minor axis of a tile on the chip
+
+
 def init_kv_cache(cfg: LlamaConfig, batch_size: int, max_len: Optional[int] = None):
     """KV cache [L, B, KV_HEADS, S, D] — head-major so each (batch, head)
     attention read streams a contiguous S×D block from HBM (position-major
     put the head axis inside, making every read a 256-byte stride: decode
-    measured ~5x off the bandwidth roofline on v5e because of it)."""
+    measured ~5x off the bandwidth roofline on v5e because of it).
+
+    One rule for every model: ``k`` and ``v`` are two rank-5 leaves whose
+    ``D`` need not be equal. A latent-attention model has one key-value
+    "head": ``k`` holds the rotated key all heads share, ``v`` the normed
+    latent (``kv_latent_rank``), which is the rest of the key and the whole
+    value of the absorbed form. The rotated key's ``qk_rope_dim`` numbers lie
+    at the front of a row of whole 128-lane tiles, zeros behind them: the
+    chip pads a narrower minor axis to 128 lanes anyway (a 64-wide bfloat16
+    row holds 256 bytes either way), and the decode kernel's copies cannot
+    take part of a lane tile (the v5e compiler: "slice shape along dimension
+    4 must be aligned to tiling (128), but is 64")."""
     max_len = max_len or cfg.max_seq_len
-    shape = (cfg.n_layers, batch_size, cfg.n_kv_heads, max_len, cfg.head_dim)
+    lead = (cfg.n_layers, batch_size, cfg.n_kv_heads, max_len)
+    k_dim, v_dim = (
+        (-(-cfg.qk_rope_dim // _LANES) * _LANES, cfg.kv_latent_rank) if cfg.kv_latent_rank
+        else (cfg.head_dim, cfg.head_dim)
+    )
     return {
-        "k": jnp.zeros(shape, cfg.dtype),
-        "v": jnp.zeros(shape, cfg.dtype),
+        "k": jnp.zeros(lead + (k_dim,), cfg.dtype),
+        "v": jnp.zeros(lead + (v_dim,), cfg.dtype),
         "length": jnp.zeros((batch_size,), jnp.int32),
     }
 

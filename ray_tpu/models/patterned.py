@@ -10,7 +10,11 @@ of ``period`` layers, then the rest. A decoder whose layers are alike
 layers with their own query-head counts and rotary settings, a per-head gate
 on the attention output, and dense and expert feed-forwards, by
 ``cfg.layer_types``, ``heads_per_layer`` and ``mlp_types`` (poolside
-Laguna-XS.2 is the published instance, ``LlamaConfig.laguna_xs2``).
+Laguna-XS.2 is the published instance, ``LlamaConfig.laguna_xs2``). A third
+attention kind, ``latent`` (DeepSeek-V3's; kakaocorp Kanana-2-30B-A3B is the
+published instance, ``LlamaConfig.kanana2_30b_a3b``), caches one normed
+latent and one rotated key a token for all heads; it does not mix with the
+other two, because the cache has one shape (``init_kv_cache``).
 
 ``models/llama.py`` is the entry point and imports this module, never the
 other way round: its ``prefill`` and ``decode_step`` call ``decode_forward``
@@ -51,7 +55,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from ray_tpu.ops.decode_attention import block_size, decode_attention
+from ray_tpu.ops.decode_attention import (
+    block_size,
+    decode_attention,
+    latent_decode_attention,
+)
 from ray_tpu.parallel.mesh import with_sharding
 
 # ``jax.named_scope`` names, one vocabulary for the train step and the
@@ -69,7 +77,12 @@ scope = jax.named_scope
 # this, so that the slice starts on a tile of the cache's position axis
 _WINDOW_ALIGN = 128
 # the name under ``attn_core`` of a layer whose attention kind is named
-_SCOPE_OF_KIND = {"full": "global", "sliding": "window"}
+_SCOPE_OF_KIND = {"full": "global", "sliding": "window", "latent": "latent"}
+# key positions a block when a prompt's chunk reads a latent cache: the
+# largest of these that divides the stripe (else the stripe whole). A block's
+# float32 scores are [B, heads, T, block]: 34 MB at 32 heads and a 256-token
+# chunk
+_LATENT_KEY_BLOCKS = (1024, 512, 256, 128)
 
 
 # ------------------------------------------------------------------ the plan
@@ -108,9 +121,13 @@ def plan(cfg) -> Plan:
     if cfg.layer_types:
         kinds = list(zip(cfg.layer_types, cfg.heads_per_layer, cfg.mlp_types))
     else:
-        if cfg.attn_gate or cfg.yarn_factor or cfg.rope_partial != 1.0:
+        if (cfg.attn_gate or cfg.yarn_factor or cfg.rope_partial != 1.0
+                or cfg.kv_latent_rank or cfg.moe_scoring != "softmax"):
             # the whole-sequence path of a uniform model knows none of them
-            raise ValueError("attn_gate, yarn_factor and rope_partial need layer_types")
+            raise ValueError(
+                "attn_gate, yarn_factor, rope_partial, kv_latent_rank and "
+                "moe_scoring need layer_types"
+            )
         kinds = [("full", cfg.n_heads, "sparse" if cfg.moe_experts else "dense")] * L
     for t, h, m in kinds:
         if t not in _SCOPE_OF_KIND or m not in ("dense", "sparse"):
@@ -122,6 +139,16 @@ def plan(cfg) -> Plan:
             raise ValueError(f"{t} attention layers differ in their query heads")
     if any(t == "sliding" for t, _, _ in kinds) and cfg.sliding_window <= 0:
         raise ValueError("sliding layers need sliding_window")
+    if any(t == "latent" for t, _, _ in kinds) != bool(cfg.kv_latent_rank) or (
+        cfg.kv_latent_rank and (
+            any(t != "latent" for t, _, _ in kinds) or cfg.n_kv_heads != 1
+            or cfg.attn_gate or not (cfg.qk_nope_dim and cfg.qk_rope_dim and cfg.v_head_dim)
+        )
+    ):
+        raise ValueError(
+            "latent layers need kv_latent_rank, qk_nope_dim, qk_rope_dim and v_head_dim, "
+            "n_kv_heads 1 and no attn_gate, and do not mix with full or sliding layers"
+        )
     if any(m == "sparse" for _, _, m in kinds) and not cfg.moe_experts:
         raise ValueError("sparse layers need moe_experts")
     # the split that traces the fewest layer bodies
@@ -158,6 +185,8 @@ def _moe_shapes(cfg, n: int) -> dict[str, tuple]:
         "moe_w_up": (n, E, e, f),
         "moe_w_down": (n, E, f, e),
     }
+    if cfg.moe_scoring == "sigmoid":
+        shapes["moe_router_bias"] = (n, E)
     if cfg.moe_shared_d_ff:
         fs = cfg.moe_shared_d_ff
         shapes.update({
@@ -175,13 +204,25 @@ def _param_shapes(cfg) -> dict[str, tuple]:
     shapes = {
         "embed": (v, e),
         "final_norm": (e,),
-        "wk": (L, e, kv, hd),
-        "wv": (L, e, kv, hd),
         "attn_norm": (L, e),
         "mlp_norm": (L, e),
     }
+    if not cfg.kv_latent_rank:
+        shapes.update({"wk": (L, e, kv, hd), "wv": (L, e, kv, hd)})
     for kind, h in {t: h for t, h, _ in pl.kinds}.items():
         n = sum(t == kind for t, _, _ in pl.kinds)
+        if kind == "latent":
+            r, nope, rope, vd = (cfg.kv_latent_rank, cfg.qk_nope_dim, cfg.qk_rope_dim,
+                                 cfg.v_head_dim)
+            shapes.update({
+                "wq_latent": (n, e, h, nope + rope),
+                "wkv_a_latent": (n, e, r + rope),
+                "kv_norm_latent": (n, r),
+                "wuk_latent": (n, h, nope, r),
+                "wuv_latent": (n, h, r, vd),
+                "wo_latent": (n, h, vd, e),
+            })
+            continue
         shapes[pl.leaf("wq", kind)] = (n, e, h, hd)
         shapes[pl.leaf("wo", kind)] = (n, h, hd, e)
         if cfg.attn_gate:
@@ -277,7 +318,7 @@ def _moe_decode_ffn(params, row, h, cfg):
     dropless), so instead of the training path's capacity buffers
     (``parallel/moe.py``) every token goes through exactly its top-k experts,
     mixed with the renormalized gate weights (``topk_gates`` on float32
-    logits), times ``cfg.moe_routed_scale``, plus the shared expert where
+    logits: softmax, or sigmoid with the selection bias), times ``cfg.moe_routed_scale``, plus the shared expert where
     ``cfg.moe_shared_d_ff`` is set.
 
     One form at every size: the B*T*k assignments are sorted by expert and go
@@ -305,10 +346,10 @@ def _moe_decode_ffn(params, row, h, cfg):
     with scope("router"):
         # float32 logits: in the model's own bf16 the 8th and 9th of 256
         # experts swap for some tokens on rounding alone
-        _, gate_vals, gate_idx = topk_gates(
-            {"router": params["moe_router"][row].astype(jnp.float32)},
-            g.astype(jnp.float32), k,
-        )
+        router = {"router": params["moe_router"][row].astype(jnp.float32)}
+        if cfg.moe_scoring == "sigmoid":
+            router["bias"] = params["moe_router_bias"][row].astype(jnp.float32)
+        _, gate_vals, gate_idx = topk_gates(router, g.astype(jnp.float32), k)
         # tokens an expert: a one-hot sum (a scatter-add is slow on the chip)
         load = jax.nn.one_hot(gate_idx.reshape(-1), E, dtype=jnp.int32).sum(axis=0)
         stats = jnp.stack([
@@ -456,13 +497,16 @@ def rope_inv_freq(cfg, kind: str):
     frequencies where ``yarn_factor`` is set (as transformers'
     ``_compute_yarn_parameters`` computes them over the rotated dims). A
     uniform model's layers are full ones with neither: the whole head at
-    ``rope_theta``, factor 1."""
+    ``rope_theta``, factor 1. A latent layer rotates ``qk_rope_dim`` numbers
+    at ``rope_theta``."""
     if kind == "sliding":
         rot, theta = cfg.head_dim, cfg.rope_theta_sliding
+    elif kind == "latent":  # the rotated part of a query, and the shared key
+        rot, theta = cfg.qk_rope_dim, cfg.rope_theta
     else:
         rot, theta = int(cfg.head_dim * cfg.rope_partial), cfg.rope_theta
     inv = (1.0 / theta ** (np.arange(0, rot, 2, dtype=np.float32) / rot)).astype(np.float32)
-    if kind == "sliding" or not cfg.yarn_factor:
+    if kind != "full" or not cfg.yarn_factor:
         return inv, 1.0
 
     def correction_dim(rotations):
@@ -479,16 +523,23 @@ def rope_inv_freq(cfg, kind: str):
     return inv.astype(np.float32), cfg.yarn_attention_factor
 
 
-def _rope(x, positions, inv_freq, factor):
+def _rope(x, positions, inv_freq, factor, interleave: bool = False):
     """x: [B, T, H, D], positions: [B, T]. Rotates the first
-    ``2 * len(inv_freq)`` dims of each head (halves paired, as
-    ``models/llama.py _rope``) and passes the rest through."""
+    ``2 * len(inv_freq)`` dims of each head and passes the rest through. The
+    rotated dims are paired by halves, ``(i, i + rot/2)``, as ``models/llama.py
+    _rope``, or with ``interleave`` as neighbours, ``(2i, 2i + 1)``."""
     rot = 2 * len(inv_freq)
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B, T, rot/2]
     cos = (jnp.cos(angles) * factor)[:, :, None, :]
     sin = (jnp.sin(angles) * factor)[:, :, None, :]
-    x1, x2 = jnp.split(x[..., :rot].astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    xr = x[..., :rot].astype(jnp.float32)
+    if interleave:
+        pairs = xr.reshape(xr.shape[:-1] + (rot // 2, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).reshape(xr.shape)
+    else:
+        x1, x2 = jnp.split(xr, 2, axis=-1)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return jnp.concatenate([out.astype(x.dtype), x[..., rot:]], axis=-1)
 
 
@@ -504,6 +555,7 @@ class _Layer:
         self.l, self.attn_i, self.mlp_i = l, attn_i, mlp_i
         self.wq, self.wo, self.wg = (pl.leaf(n, self.kind) for n in ("wq", "wo", "wg"))
         self.by_kind = pl.by_kind
+        self.latent = self.kind == "latent"
 
     def inner_scope(self):
         """The name under ``attn_core``: ``global`` or ``window`` where the
@@ -537,8 +589,62 @@ def _qkv(params, lay: _Layer, h, positions, cfg, loras=None, adapter_ids=None):
     return q, k, v
 
 
-def _attn_out(params, lay: _Layer, x, h, attn, cfg):
+def _latent_qkv(params, lay: _Layer, h, positions, cfg):
+    """A latent layer's projections of h [B, T, e]: each head's query in its
+    two parts, (q_nope [B, T, H, nope], q_rope [B, T, H, rope], rotated), and
+    what the cache holds of a token: the rotated key all heads share
+    [B, T, 1, rope] and the normed latent [B, T, 1, rank]."""
+    inv_freq, factor = rope_inv_freq(cfg, "latent")
+    r, nope = cfg.kv_latent_rank, cfg.qk_nope_dim
+    i = lay.attn_i
+    with scope("attn_qkv"):
+        q = jnp.einsum("bte,ehd->bthd", h, params["wq_latent"][i])
+        kv = jnp.einsum("bte,er->btr", h, params["wkv_a_latent"][i])
+        c = _rmsnorm(kv[..., :r], params["kv_norm_latent"][i], cfg.rms_eps, cfg.fused_rmsnorm)
+        q_rope = _rope(q[..., nope:], positions, inv_freq, factor, cfg.rope_interleave)
+        k_rope = _rope(kv[:, :, None, r:], positions, inv_freq, factor, cfg.rope_interleave)
+    return (q[..., :nope], q_rope), k_rope, c[:, :, None, :]
+
+
+def _latent_scale(cfg) -> float:
+    return (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+
+
+def _latent_expand(params, lay: _Layer, c):
+    """Every head's keys (the part that is not rotated) and values of the
+    latents c [B, S, rank] -> ([B, S, H, nope], [B, S, H, v])."""
+    return (jnp.einsum("bsr,hnr->bshn", c, params["wuk_latent"][lay.attn_i]),
+            jnp.einsum("bsr,hrv->bshv", c, params["wuv_latent"][lay.attn_i]))
+
+
+def _latent_expanded(params, lay: _Layer, q, k_rope, c, mask, cfg):
+    """Latent attention in its expanded form over keys that are all at hand:
+    every head's keys and values expanded from the latents. q: ``_latent_qkv``'s
+    pair; k_rope [B, S, rope], c [B, S, rank]; mask [B, T, S] -> [B, T, H, v]."""
+    q_nope, q_rope = q
+    k_nope, v = _latent_expand(params, lay, c)
+    s = (
+        jnp.einsum("bthn,bshn->bhts", q_nope, k_nope, preferred_element_type=jnp.float32)
+        + jnp.einsum("bthd,bsd->bhts", q_rope, k_rope, preferred_element_type=jnp.float32)
+    ) * _latent_scale(cfg)
+    s = jnp.where(mask[:, None], s, -1e30)
+    w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhts,bshv->bthv", w, v)
+
+
+def _latent_absorb(params, lay: _Layer, q_nope):
+    """Each head's query through its half of the key up-projection: scores
+    against the latents themselves. [B, T, H, nope] -> [B, T, H, rank]."""
+    return jnp.einsum("bthn,hnr->bthr", q_nope, params["wuk_latent"][lay.attn_i])
+
+
+def _attn_out(params, lay: _Layer, x, h, attn, cfg, from_latent: bool = False):
+    """x + the heads' outputs through ``wo``. ``from_latent``: ``attn`` is the
+    absorbed form's context in the latent's space [B, T, H, rank] and goes
+    through each head's half of the value up-projection first."""
     with scope("attn_out"):
+        if from_latent:
+            attn = jnp.einsum("bthr,hrv->bthv", attn, params["wuv_latent"][lay.attn_i])
         if cfg.attn_gate:
             with scope("gate"):
                 gate = jax.nn.sigmoid(jnp.einsum(
@@ -621,14 +727,21 @@ def forward_hidden(params, tokens, cfg, mesh: Optional[Mesh] = None, positions=N
         "full": jnp.broadcast_to(back >= 0, (B, T, T)),
         "sliding": jnp.broadcast_to((back >= 0) & (back < cfg.sliding_window), (B, T, T)),
     }
+    masks["latent"] = masks["full"]
 
     def layer(lay: _Layer, x):
         h = _rmsnorm(x, params["attn_norm"][lay.l], cfg.rms_eps, cfg.fused_rmsnorm)
-        q, k, v = _qkv(params, lay, h, positions, cfg)
+        if lay.latent:
+            q, k, v = _latent_qkv(params, lay, h, positions, cfg)
+        else:
+            q, k, v = _qkv(params, lay, h, positions, cfg)
         with scope("attn_core"), lay.inner_scope():
-            attn = _grouped_attention(
-                q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), masks[lay.kind]
-            )
+            if lay.latent:  # the whole sequence is at hand: the expanded form
+                attn = _latent_expanded(params, lay, q, k[:, :, 0], v[:, :, 0], masks["latent"], cfg)
+            else:
+                attn = _grouped_attention(
+                    q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), masks[lay.kind]
+                )
         x = _attn_out(params, lay, x, h, attn, cfg)
         return _feed_forward(params, lay, x, cfg)[0]
 
@@ -653,7 +766,7 @@ def _window_slice(c_all, l, first, width: int):
     return jax.vmap(row)(jnp.arange(B), first)
 
 
-def reads_blocks(stripe: int, *arrays) -> bool:
+def reads_blocks(stripe: int, *arrays, latent: bool = False) -> bool:
     """Whether a decode step (one new token a row) over a cache of ``stripe``
     positions a slot reads through the kernel (``ops/decode_attention.py``)
     or keeps the einsum: the one place that decides it. ``arrays`` are what
@@ -662,7 +775,8 @@ def reads_blocks(stripe: int, *arrays) -> bool:
     (``llm/engine.py _Pool.reads_blocks``, for its counter), ``_cache_reader``
     with the tracers of the same arrays, and both get the same answer.
 
-    The kernel wants a stripe of whole blocks and everything on one device.
+    The kernel wants a stripe of whole blocks (``latent``: of a latent
+    model's, which are longer) and everything on one device.
     An argument committed to a ``NamedSharding`` carries its mesh in its
     type, inside a trace too (``llm/spmd.py`` and ``llm/gang.py`` jit over a
     mesh with the key-value heads sharded over ``tp``; an engine under
@@ -671,9 +785,109 @@ def reads_blocks(stripe: int, *arrays) -> bool:
     uncommitted argument that only a ``jit``'s ``in_shardings`` spreads over
     a mesh reads as one device (no caller in this repo places its arrays
     so; ``tests/test_patterned.py`` traces the ways they do)."""
-    return block_size(stripe) is not None and all(
+    return block_size(stripe, latent) is not None and all(
         jax.typeof(x).sharding.mesh.size <= 1 for x in arrays
     )
+
+
+def _chunk_expands(cfg, T: int) -> bool:
+    """Which form ``T`` new tokens a row take over a latent cache: whether
+    each block's keys and values are expanded from its latents first (2 *
+    rank * heads * (nope + v) operations a key position, then 2 * heads *
+    (nope + rope + v) a query and key position), or the queries are absorbed
+    through ``wuk`` and meet the latents themselves (2 * heads * (2 * rank +
+    rope) a pair, nothing a key position): the form that needs fewer
+    operations at this width. At 32 heads of 128 + 64 and 128 on a rank of
+    512 that is the expanded one from 171 tokens up. On a v5e a whole final
+    chunk of 256 tokens (5 layers, 4 of them with 128 experts) behind 12,288
+    / 20,480 cached tokens took 17.8 / 22.6 ms absorbed and 16.1 / 20.0 ms
+    expanded (``benchmark/tools/latent_chunk_forms.py``; PERF.md section 6,
+    PR 33): 13.6 against 17.8 M operations a key position."""
+    r, nope, v = cfg.kv_latent_rank, cfg.qk_nope_dim, cfg.v_head_dim
+    return T * (2 * r - nope - v) > r * (nope + v)
+
+
+def _latent_reader(cfg, params, cache, positions):
+    """``read(q, ck_all, cv_all, lay)`` for ``decode_forward`` over a latent
+    cache: ck_all [L, B, 1, S, 128] the shared rotated keys, cv_all
+    [L, B, 1, S, rank] the normed latents; q ``_latent_qkv``'s pair. Returns
+    the read and whether it hands out the context in the latent's space
+    [B, T, H, rank] (the absorbed form; ``_attn_out`` expands it) or each
+    head's own [B, T, H, v].
+
+    One new token a row, a stripe of whole blocks, one device: the absorbed
+    form through the decode kernel (``ops/decode_attention.py
+    latent_decode_attention``), row ``b`` between ``0`` and ``pos + 1``.
+    Anything else (a prompt's chunk, a tiny cache): blocks of key positions
+    up to the furthest row's last query and no further, with a running
+    maximum, sum and context in float32, so that neither the work nor any
+    temporary follows the stripe where the rows are shorter; absorbed or
+    expanded a block at a time, by the chunk's width (``_chunk_expands``)."""
+    B, T = positions.shape
+    S = cache["k"].shape[3]
+    scale = _latent_scale(cfg)
+    # a cached key's row is the rotated key and zeros behind it
+    # (``init_kv_cache``): the rotated query gets the same zeros
+    pad = ((0, 0),) * 3 + ((0, cache["k"].shape[-1] - cfg.qk_rope_dim),)
+    if T == 1 and reads_blocks(S, cache["k"], *jax.tree.leaves(params), latent=True):
+        hi = positions[:, 0] + 1
+
+        def read(q, ck_all, cv_all, lay):
+            q_nope, q_rope = q[0], jnp.pad(q[1], pad)
+            ql = _latent_absorb(params, lay, q_nope)
+            return latent_decode_attention(
+                q_rope[:, 0], ql[:, 0], ck_all, cv_all, lay.l, jnp.zeros_like(hi), hi, scale
+            )[:, None]
+
+        return read, True
+
+    bk = next((b for b in _LATENT_KEY_BLOCKS if S % b == 0), S)
+    # row b's queries are consecutive from positions[b, 0]: the last sees furthest
+    n_blocks = jnp.minimum(jnp.max(positions[:, -1]) // bk + 1, S // bk)
+    expands = _chunk_expands(cfg, T)
+
+    def read(q, ck_all, cv_all, lay):
+        q_nope, q_rope = q[0], jnp.pad(q[1], pad)
+        H = q_nope.shape[2]
+        ql = None if expands else _latent_absorb(params, lay, q_nope)
+        width = cfg.v_head_dim if expands else cfg.kv_latent_rank
+
+        def block(i, carry):
+            m, den, acc = carry
+            at = (lay.l, 0, 0, i * bk, 0)
+            kb = jax.lax.dynamic_slice(ck_all, at, (1, B, 1, bk, ck_all.shape[-1]))[0, :, 0]
+            cb = jax.lax.dynamic_slice(cv_all, at, (1, B, 1, bk, cv_all.shape[-1]))[0, :, 0]
+            s = jnp.einsum("bthd,bsd->bhts", q_rope, kb, preferred_element_type=jnp.float32)
+            if expands:
+                k_nope, vb = _latent_expand(params, lay, cb)
+                s = s + jnp.einsum("bthn,bshn->bhts", q_nope, k_nope,
+                                   preferred_element_type=jnp.float32)
+            else:
+                s = s + jnp.einsum("bthr,bsr->bhts", ql, cb, preferred_element_type=jnp.float32)
+            seen = (i * bk + jnp.arange(bk))[None, None, :] <= positions[:, :, None]  # [B, T, bk]
+            s = jnp.where(seen[:, None], s * scale, -1e30)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            den = alpha * den + p.sum(axis=-1)
+            p = p.astype(cb.dtype)
+            if expands:
+                pv = jnp.einsum("bhts,bshv->bhtv", p, vb, preferred_element_type=jnp.float32)
+            else:
+                pv = jnp.einsum("bhts,bsr->bhtr", p, cb, preferred_element_type=jnp.float32)
+            return m_new, den, alpha[..., None] * acc + pv
+
+        # block 0 holds position 0, which every query sees: no row's maximum
+        # is still the mask's when a block past its last query comes
+        init = (jnp.full((B, H, T), -1e30, jnp.float32), jnp.zeros((B, H, T), jnp.float32),
+                jnp.zeros((B, H, T, width), jnp.float32))
+        if bk == S:
+            _, den, acc = block(0, init)
+        else:
+            _, den, acc = jax.lax.fori_loop(0, n_blocks, block, init)
+        return (acc / den[..., None]).transpose(0, 2, 1, 3).astype(q_rope.dtype)
+
+    return read, not expands
 
 
 def _cache_reader(cfg, params, cache, positions, kinds):
@@ -779,24 +993,39 @@ def decode_forward(
     kinds = {kind for kind, _, _ in pl.kinds}
     if loras is not None and pl.by_kind:
         raise NotImplementedError("LoRA adapters over layers that are not alike")
+    if "latent" in kinds and any(
+        jax.typeof(x).sharding.mesh.size > 1 for x in (cache["k"], *jax.tree.leaves(params))
+    ):
+        raise NotImplementedError(
+            "models/patterned.py: latent attention runs on one device (no rule "
+            "places its cache or its projections on a mesh)"
+        )
     B, T = tokens.shape
     S = cache["k"].shape[3]  # [L, B, K, S, D]
     with scope("embed"):
         x = params["embed"][tokens].astype(cfg.dtype)
     write = _cache_writer(cfg, S, positions, valid, start_pos)
-    read = _cache_reader(cfg, params, cache, positions, kinds)
+    if "latent" in kinds:  # every layer is one (``plan``)
+        read, from_latent = _latent_reader(cfg, params, cache, positions)
+    else:
+        read, from_latent = _cache_reader(cfg, params, cache, positions, kinds), False
 
     def layer(lay: _Layer, carry):
         x, ck_all, cv_all, *stats = carry
         h = _rmsnorm(x, params["attn_norm"][lay.l], cfg.rms_eps, cfg.fused_rmsnorm)
-        q, k, v = _qkv(params, lay, h, positions, cfg, loras, adapter_ids)
+        if lay.latent:  # k: the shared rotated key; v: the normed latent
+            q, k, v = _latent_qkv(params, lay, h, positions, cfg)
+        else:
+            q, k, v = _qkv(params, lay, h, positions, cfg, loras, adapter_ids)
         with scope("kv_write"):
+            if lay.latent:  # zeros up to the cache's row of whole lane tiles (``init_kv_cache``)
+                k = jnp.pad(k, ((0, 0),) * 3 + ((0, ck_all.shape[-1] - k.shape[-1]),))
             # the cache is head-major: the new [B, T, K, D] rows go in as [B, K, T, D]
             ck_all = write(ck_all, k.transpose(0, 2, 1, 3), lay.l)
             cv_all = write(cv_all, v.transpose(0, 2, 1, 3), lay.l)
         with scope("attn_core"), lay.inner_scope():
             attn = read(q, ck_all, cv_all, lay)
-        x = _attn_out(params, lay, x, h, attn, cfg)
+        x = _attn_out(params, lay, x, h, attn, cfg, from_latent)
         x, layer_stats = _feed_forward(params, lay, x, cfg)
         return (x, ck_all, cv_all, *(s + layer_stats for s in stats))
 

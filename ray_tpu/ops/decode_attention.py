@@ -20,7 +20,15 @@ Same mathematics as the einsum under the same mask: keys, values and queries
 as they are stored; scores, the running maximum, the sum and the output
 accumulator in float32 across blocks (online softmax); the mask exact inside
 the first and last block; ``H // K`` query heads share a key-value head's
-block without repeating it. Off the TPU it runs in Pallas interpret mode."""
+block without repeating it. Off the TPU it runs in Pallas interpret mode.
+
+A latent-attention layer (``models/patterned.py``, absorbed form) is the same
+walk over one key-value "head" whose key lies in two leaves: the rotated key
+all heads share in ``k`` (64 wide) and the normed latent in ``v`` (512 wide),
+which is also the value. ``latent_decode_attention`` hands the kernel a second
+query (the heads' queries absorbed through the key up-projection) for the
+latent, so a block's score is ``q . k + q_latent . v`` and each block of the
+latent is copied once for both uses."""
 
 from __future__ import annotations
 
@@ -44,17 +52,26 @@ from ray_tpu.ops._common import _SUBLANE, interpret
 # the smallest block, which reads least past a row's bounds, wins. A stripe
 # that is no whole number of blocks is not this kernel's.
 BLOCK = 128
+# A latent layer's block is one head of 64 + 512 numbers a position: a quarter
+# of the bytes of 8 heads of 2 x 128, so it takes four times the positions to
+# keep the copies as long against the walk's fixed cost a block (reckoned from
+# the sizes above, not swept); shorter where the stripe is no multiple of it
+LATENT_BLOCKS = (512, 256, BLOCK)
 _MASKED = -1e30  # finite: exp(_MASKED - m) is 0 and nothing is inf - inf
 
 
-def block_size(stripe: int) -> Optional[int]:
-    """Positions a block of a ``stripe``-position cache, or None where the
-    kernel does not apply (the caller keeps the einsum)."""
-    return BLOCK if stripe % BLOCK == 0 else None
+def block_size(stripe: int, latent: bool = False) -> Optional[int]:
+    """Positions a block of a ``stripe``-position cache (``latent``: of a
+    latent-attention model's), or None where the kernel does not apply (the
+    caller keeps the einsum)."""
+    for bs in LATENT_BLOCKS if latent else (BLOCK,):
+        if stripe % bs == 0:
+            return bs
+    return None
 
 
-def _whole_blocks(stripe: int) -> int:
-    bs = block_size(stripe)
+def _whole_blocks(stripe: int, latent: bool = False) -> int:
+    bs = block_size(stripe, latent)
     if bs is None:
         raise ValueError(f"a {stripe}-position stripe is no whole number of {BLOCK}-position blocks")
     return bs
@@ -68,21 +85,25 @@ def _clamp(lo, hi, stripe: int, xp=jnp):
     return xp.clip(lo, 0, hi - 1), hi
 
 
-def positions_read(lo, hi, stripe: int):
+def positions_read(lo, hi, stripe: int, latent: bool = False):
     """Positions the kernel's blocks cover for rows bounded ``[lo, hi)`` in a
     cache of ``stripe`` positions a slot (whole blocks): what it reads of
     each of keys and values, a key-value head. Integers or NumPy arrays of
     them, on the host: the engine counts with it. The bounds go through the
     ``_clamp`` the kernel's go through, and the blocks between them are the
     kernel's ``lo // bs`` to ``(hi - 1) // bs``."""
-    bs = _whole_blocks(stripe)
+    bs = _whole_blocks(stripe, latent)
     lo, hi = _clamp(np.asarray(lo), np.asarray(hi), stripe, np)
     return ((hi - 1) // bs - lo // bs + 1) * bs
 
 
-def _kernel(layer_ref, lo_ref, hi_ref, q_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sem, *, bs: int, scale: float):
-    B, K, G, D = q_ref.shape
+def _kernel(layer_ref, lo_ref, hi_ref, q_ref, *rest, bs: int, scale: float, latent: bool):
+    # ``latent``: a second query [B, K, G, Dv] for the values' leaf comes in
+    # behind the first, and a block's score is q . k + q_latent . v
+    ql_ref, rest = (rest[0], rest[1:]) if latent else (None, rest)
+    k_hbm, v_hbm, o_ref, k_buf, v_buf, sem = rest
+    B, K, G, _ = q_ref.shape
+    D = v_buf.shape[-1]  # the output is as wide as a value
     layer = layer_ref[0]
 
     def copies(b, blk, buf):
@@ -102,6 +123,15 @@ def _kernel(layer_ref, lo_ref, hi_ref, q_ref, k_hbm, v_hbm, o_ref,
         lo, hi = lo_ref[b], hi_ref[b]
         last = (hi - 1) // bs
         q = q_ref[b]  # [K, G, D]
+        ql = ql_ref[b] if latent else None
+
+        def scores(h, k, v):
+            s = jax.lax.dot_general(
+                q[h], k[h], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            if latent:
+                s = s + jax.lax.dot_general(
+                    ql[h], v[h], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            return s
 
         def block(blk, carry):
             m, den, acc, buf = carry
@@ -118,20 +148,21 @@ def _kernel(layer_ref, lo_ref, hi_ref, q_ref, k_hbm, v_hbm, o_ref,
             k_copy, v_copy = copies(b, blk, buf)
             k_copy.wait()
             k = k_buf[buf]  # [K, bs, D]
-            s = jnp.concatenate([
-                jax.lax.dot_general(
-                    q[h], k[h], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ) for h in range(K)
-            ], axis=0) * scale  # [K * G, bs]
+            if latent:  # the latent is part of the key
+                v_copy.wait()
+            v = v_buf[buf] if latent else None
+            s = jnp.concatenate(
+                [scores(h, k, v) for h in range(K)], axis=0
+            ) * scale  # [K * G, bs]
             pos = blk * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(jnp.logical_and(pos >= lo, pos < hi), s, _MASKED)
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new)
             den = alpha * den + p.sum(axis=-1, keepdims=True)
-            v_copy.wait()
-            v = v_buf[buf]
+            if not latent:
+                v_copy.wait()
+                v = v_buf[buf]
             pv = jnp.concatenate([
                 jnp.dot(
                     p[h * G:(h + 1) * G].astype(v.dtype), v[h],
@@ -152,36 +183,59 @@ def _kernel(layer_ref, lo_ref, hi_ref, q_ref, k_hbm, v_hbm, o_ref,
     jax.lax.fori_loop(0, B, row, 0)
 
 
+def _walk(queries, ck_all, cv_all, layer, lo, hi, scale: float, latent: bool, name: str):
+    """The kernel's call. queries: one [B, H, Dk], or for ``latent`` two,
+    [B, H, Dk] and [B, H, Dv] -> [B, H, Dv] in the first's dtype."""
+    B, H, _ = queries[0].shape
+    _, _, K, S, Dk = ck_all.shape
+    Dv = cv_all.shape[-1]
+    bs = _whole_blocks(S, latent)
+    G = H // K
+    # a key-value head's query heads are its matmul's rows: whole sublanes
+    Gp = -(-G // _SUBLANE) * _SUBLANE
+
+    def grouped(q):
+        qg = q.reshape(B, K, G, q.shape[-1])
+        return qg if Gp == G else jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
+
+    lo, hi = (x.astype(jnp.int32) for x in _clamp(lo, hi, S))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        functools.partial(_kernel, bs=bs, scale=scale, latent=latent),
+        out_shape=jax.ShapeDtypeStruct((B, K, Gp, Dv), queries[0].dtype),
+        in_specs=[smem, smem, smem] + [vmem] * len(queries)
+        + [pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=vmem,
+        scratch_shapes=[
+            pltpu.VMEM((2, K, bs, Dk), ck_all.dtype),
+            pltpu.VMEM((2, K, bs, Dv), cv_all.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+        interpret=interpret(),
+        name=name,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), lo, hi, *map(grouped, queries), ck_all, cv_all)
+    return out[:, :, :G].reshape(B, H, Dv)
+
+
 def decode_attention(q, ck_all, cv_all, layer, lo, hi):
     """Attention of one query token a row over layer ``layer`` of a carried
     cache. q: [B, H, D]; ck_all, cv_all: [L, B, K, S, D], ``S`` a whole number
     of blocks (``block_size``); layer: an int or an int32 scalar (traced
     under the layer loop); lo, hi: [B] int32, row ``b`` attends to positions
     ``lo[b] <= s < hi[b]`` -> [B, H, D] in q's dtype."""
-    B, H, D = q.shape
-    _, _, K, S, _ = ck_all.shape
-    bs = _whole_blocks(S)
-    G = H // K
-    # a key-value head's query heads are its matmul's rows: whole sublanes
-    Gp = -(-G // _SUBLANE) * _SUBLANE
-    qg = q.reshape(B, K, G, D)
-    if Gp != G:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
-    lo, hi = (x.astype(jnp.int32) for x in _clamp(lo, hi, S))
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
-        functools.partial(_kernel, bs=bs, scale=D**-0.5),
-        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
-        in_specs=[smem, smem, smem, vmem,
-                  pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=vmem,
-        scratch_shapes=[
-            pltpu.VMEM((2, K, bs, D), ck_all.dtype),
-            pltpu.VMEM((2, K, bs, D), cv_all.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-        interpret=interpret(),
-        name="decode_attention",
-    )(jnp.asarray(layer, jnp.int32).reshape(1), lo, hi, qg, ck_all, cv_all)
-    return out[:, :, :G].reshape(B, H, D)
+    return _walk((q,), ck_all, cv_all, layer, lo, hi, q.shape[-1] ** -0.5, False,
+                 "decode_attention")
+
+
+def latent_decode_attention(q_rope, q_latent, ck_all, cv_all, layer, lo, hi, scale: float):
+    """The absorbed form of latent attention for one query token a row.
+    q_rope: [B, H, Dr] against the shared rotated keys ck_all [L, B, 1, S, Dr];
+    q_latent: [B, H, R] (each head's query through its half of the key
+    up-projection) against the normed latents cv_all [L, B, 1, S, R], which
+    are the values too -> each head's context in the latent's space
+    [B, H, R]. ``scale``: one over the root of the width of a head's whole
+    key, which the caller knows and the cache does not. ``S`` is a whole
+    number of ``block_size(S, latent=True)`` blocks."""
+    return _walk((q_rope, q_latent), ck_all, cv_all, layer, lo, hi, scale, True,
+                 "latent_decode_attention")

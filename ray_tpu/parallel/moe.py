@@ -46,10 +46,21 @@ def topk_gates(params, x, top_k: int):
     (``models/patterned._moe_decode_ffn``); the decode-vs-forward exactness test
     pins the two staying numerically identical.
 
+    Two scorings. A router of ``router`` alone: softmax over the experts,
+    the k largest, renormalised. A router that also has a selection ``bias``
+    [E] (DeepSeek-V3's ``noaux_tc`` without groups): the sigmoid of each
+    logit, the top k chosen by score plus bias, which is no part of their
+    weights: those are the chosen scores themselves over their sum.
+
     Returns (probs [G, E] f32, gate_vals [G, k] f32, gate_idx [G, k])."""
-    logits = x @ params["router"]  # [G, E]
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, top_k)  # [G, k]
+    logits = (x @ params["router"]).astype(jnp.float32)  # [G, E]
+    if "bias" in params:
+        probs = jax.nn.sigmoid(logits)
+        _, gate_idx = jax.lax.top_k(probs + params["bias"].astype(jnp.float32), top_k)
+        gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, top_k)  # [G, k]
     gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
     return probs, gate_vals, gate_idx
 

@@ -83,6 +83,8 @@ _SERVED = {
 def _served_config(name):
     from ray_tpu.models.llama import LlamaConfig
 
+    if name.startswith("kanana"):
+        return LlamaConfig.kanana2_30b_a3b(n_layers=5, max_seq_len=24576)
     if name.startswith("laguna"):
         return LlamaConfig.laguna_xs2(n_layers=5, max_seq_len=4096)
     return LlamaConfig(
@@ -144,6 +146,25 @@ def _decode_attention(q, ck_all, cv_all, layer, lo, hi):
     return decode_attention(q, ck_all, cv_all, layer, lo, hi)
 
 
+def _latent_decode_attention(q_rope, q_latent, ck_all, cv_all, layer, lo, hi):
+    from ray_tpu.ops.decode_attention import latent_decode_attention
+
+    return latent_decode_attention(q_rope, q_latent, ck_all, cv_all, layer, lo, hi, 192 ** -0.5)
+
+
+def _latent_decode_attention_shapes(layers=5, slots=24, stripe=24576, heads=32):
+    """One new token a slot over the Kanana-2 cell's cache: 32 query heads on
+    one shared key in two leaves, the rotated key in a 128-lane row (a 64-wide
+    row is refused: its copies would take half a lane tile) and the 512-wide
+    latent, which is the value too."""
+    bounds = ((slots,), jnp.int32)
+    return (
+        ((slots, heads, 128), jnp.bfloat16), ((slots, heads, 512), jnp.bfloat16),
+        ((layers, slots, 1, stripe, 128), jnp.bfloat16),
+        ((layers, slots, 1, stripe, 512), jnp.bfloat16), ((), jnp.int32), bounds, bounds,
+    )
+
+
 def _decode_attention_shapes(layers, stripe, heads, slots=32):
     """One new token a slot over the serving cells' caches: ``heads`` query
     heads over 8 key-value heads of width 128."""
@@ -176,6 +197,8 @@ KERNELS = {
     "decode_attention_4_a_group": (_decode_attention, _decode_attention_shapes(16, 1024, 32)),
     "decode_attention_6_a_group": (_decode_attention, _decode_attention_shapes(5, 4096, 48)),
     "decode_attention_8_a_group": (_decode_attention, _decode_attention_shapes(5, 4096, 64)),
+    "latent_decode_attention_32_on_one_key": (
+        _latent_decode_attention, _latent_decode_attention_shapes()),
     "quantize_int8": (_quantize, (((3072, 8192), jnp.bfloat16),)),
     "dequantize_int8": (
         _dequantize,
@@ -450,3 +473,42 @@ def test_chunk_programs_are_what_they_are_without_the_decode_kernel(
     # new functions, so that they are traced anew
     assert _same_program(
         _program_text(_served_programs(cfg, slots, stripe, one_chip)[program])) == text
+
+
+def test_latent_programs_hold_nothing_as_long_as_the_stripe_and_copy_no_leaf(
+        one_chip, no_compile_cache, native_kernels, monkeypatch):
+    """The Kanana-2 cell's decode step (24 slots of 24,576) and a 256-token
+    final chunk over one such stripe, 5 layers at published widths, the
+    parameters in the formats the engine's rule gives. The decode step reads
+    its latents through the kernel (one call in layer 0's body, one in the
+    expert layers' loop, beside the three grouped matmuls); neither program's
+    temporaries follow the stripe (a [32, 256, 24576] float32 score block
+    alone is 0.8 GB; the chunk walks 1,024-position key blocks up to its
+    row's length); and neither relays ``wq_latent`` whole, which both did
+    with the leaf head-major or in the default layout (a 192-wide head is no
+    whole number of lane tiles: 0.45 of a 9.06 ms decode step on the chip,
+    PERF.md section 6, PR 33)."""
+    cfg = _served_config("kanana-2-30b-a3b-serve-l5")
+    whole_leaf = "bf16[5,2048,32,192]"
+
+    def compiled(name):
+        fn, args = _served_programs(cfg, 24, 24576, one_chip)[name]
+        return jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+
+    def relays(text):
+        return [line.strip()[:120] for line in text.splitlines()
+                if " copy(" in line and line.split(" = ", 1)[-1].startswith(whole_leaf)]
+
+    step = compiled("decode_step")
+    text = step.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 5
+    assert step.memory_analysis().temp_size_in_bytes < 64e6
+    assert relays(text) == []
+    chunk = compiled("chunk_final")
+    assert chunk.memory_analysis().temp_size_in_bytes < 256e6
+    assert relays(chunk.as_text()) == []
+    # the guard sees the copy where the leaf is head-major as the other models' are
+    from ray_tpu.models import llama
+
+    monkeypatch.setattr(llama, "EMBED_MINOR", llama.HEAD_MAJOR)
+    assert relays(compiled("decode_step").as_text())
