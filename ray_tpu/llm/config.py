@@ -12,6 +12,14 @@ import dataclasses
 from typing import Any, Optional
 
 
+# The most rows one middle-chunk program runs (``llm/engine.py
+# _advance_admissions``: a pool's admissions whose next chunk is a middle chunk
+# are rows of one launch), whatever ``max_concurrent_admissions`` is: the rows
+# ``models/patterned.py`` writes into the cache as blocks (a wider batch falls
+# back to the scatter, 150 us a row and layer where a block costs 11).
+CHUNK_ROWS_MAX = 8
+
+
 @dataclasses.dataclass
 class SamplingParams:
     max_tokens: int = 64
@@ -75,8 +83,14 @@ class EngineConfig:
     decode_runahead: int = 1
     # concurrent chunked admissions per pool: each holds a stripe-sized
     # scratch KV until its final chunk lands, so this bounds transient HBM
-    # (admissions * stripe KV) and per-pass prefill work; too low serializes
-    # admission waves and lets slot occupancy decay before the batch fills.
+    # (admissions * stripe KV, and while a middle-chunk program of several
+    # rows runs, its rows' stripes once more) and per-pass prefill work; too
+    # low serializes admission waves and lets slot occupancy decay before
+    # the batch fills. A pool's admissions whose next chunk is a middle
+    # chunk run as rows of one launch, so this is also the most rows that
+    # program has, and a replica compiles it at every row count up to this
+    # before it is ready (a pool of latent attention keeps one row a launch:
+    # ``llm/engine.py JaxEngine.__init__``).
     max_concurrent_admissions: int = 4
 
 
@@ -174,13 +188,20 @@ class LLMConfig:
         return self.name or self.model.model_id
 
     def compile_budget_s(self) -> float:
-        """Worst-case replica startup: one XLA compile per prefill bucket +
-        one decode program per KV pool, doubled for sharded (gang) meshes
-        whose jax.distributed world must also rendezvous. Serve uses this as
-        ``initial_health_grace_s`` so a slow first jit is STARTING, not dead."""
+        """Worst-case replica startup. The engine runs its whole program set
+        before it is ready (``llm/engine.py _warm_programs``): a final chunk
+        per prefill bucket, the middle chunk at every row count up to
+        ``max_concurrent_admissions``, and one decode program per KV pool; a
+        checkout's first start compiles them all (4-11 s a chunk program on a
+        v5e, 160-215 s a serving cell's whole set: PERF.md section 6, PR 34).
+        Doubled for sharded (gang) meshes whose jax.distributed world must
+        also rendezvous. Serve uses this as ``initial_health_grace_s`` so a
+        slow first jit is STARTING, not dead."""
         if self.startup_grace_s is not None:
             return self.startup_grace_s
         e = self.engine
-        programs = len(e.prefill_buckets) + max(len(e.seq_len_buckets), 1)
+        pools = max(len(e.seq_len_buckets), 1)
+        programs = (len(e.prefill_buckets) + min(e.max_concurrent_admissions, CHUNK_ROWS_MAX)
+                    + 1) * pools
         sharded = e.tensor_parallel_degree * e.sequence_parallel_degree > 1
         return 120.0 + 30.0 * programs * (2 if sharded else 1)

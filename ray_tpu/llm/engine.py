@@ -32,7 +32,9 @@ from typing import Any, Callable, Iterator, Optional
 
 import numpy as np
 
-from ray_tpu.llm.config import EngineConfig, LLMConfig, ModelConfig, SamplingParams
+from ray_tpu.llm.config import (
+    CHUNK_ROWS_MAX, EngineConfig, LLMConfig, ModelConfig, SamplingParams,
+)
 from ray_tpu.llm.pacing import TokenPacer
 from ray_tpu.llm.tokenizer import get_tokenizer
 from ray_tpu.util import metrics as app_metrics
@@ -60,7 +62,12 @@ COUNTERS = (
     # decoded for a slot that had finished or was re-bound (run-ahead)
     "tokens_discarded",
     "decode_steps", "decode_slot_steps",  # slot_steps: sum of active slots
+    # a prompt's chunks by kind, one a prompt chunk: a row of a chunk program
     "prefill_chunks:mid", "prefill_chunks:final",
+    # launches of the chunk programs: admissions whose next chunks are of one
+    # program run as rows of one launch, so chunks over programs is the mean
+    # number of rows a launch carried
+    "prefill_programs:mid", "prefill_programs:final",
     "loop_passes", "loop_idle_sleeps",
     # keys and values a decode step has to read: over decode steps and active
     # slots, the slot's length, and what a sliding-window layer needs of it
@@ -101,7 +108,7 @@ COUNTERS = (
     *(f"{name}:{program}" for name in _MOE_COUNTERS for program in _MOE_PROGRAMS),
 )
 _LABEL = {"requests_finished": "reason", "requests_failed": "stage",
-          "prefill_chunks": "kind",
+          "prefill_chunks": "kind", "prefill_programs": "kind",
           **dict.fromkeys((*_MOE_COUNTERS, "prefill_query_tokens",
                            "prefill_attended_positions"), "program")}
 # request latencies: 1 ms to 200 s, a quarter more each bucket, so a median
@@ -241,6 +248,7 @@ class _Pool:
         # whether this pool's decode steps read its stripes through the
         # decode kernel: asked once, of the layer that decides it, with the
         # arrays the steps run on
+        self.chunk_rows = 1  # the most rows of one middle-chunk launch (the engine sets it)
         self.reads_blocks = reads_blocks(
             stripe_len, self.cache["k"], *jax.tree.leaves(params), latent=self.latent
         )
@@ -254,6 +262,168 @@ class _Pool:
         from ray_tpu.ops.decode_attention import positions_read
 
         return int(positions_read(lo, hi, self.stripe_len, self.latent).sum())
+
+
+def programs(cfg, decode_steps: int = 1) -> dict:
+    """The bodies of an engine's device programs for model ``cfg``, by the
+    name each is jitted under (``JaxEngine._compile``; a profile names a
+    module ``jit_<name>``): plain functions, so that a test can lower them for
+    a described chip without building an engine."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import decode_step, init_kv_cache, prefill
+
+    # one static top-K for the decode program AND the prefill first-token
+    # sampler — they must agree or seeded runs diverge at token 2
+    K = top_k_static(cfg)
+
+    # a model with routed experts: each program takes a zeroed
+    # ``moe_stats`` leaf in with its cache and hands the counts out
+    # beside its tokens (``models/llama.py _ride_stats``). A dense
+    # model's programs hand out ``None`` there, which is no output.
+    routed = bool(cfg.moe_experts)
+
+    def stats_in(cache):
+        if not routed:
+            return cache
+        return dict(cache, moe_stats=jnp.zeros((len(_MOE_COUNTERS),), jnp.int32))
+
+    def sample_row(logits_row, temp, top_k, key):
+        """Sample one token from [V] fp32 logits: greedy where temp<=0,
+        else top-k/temperature categorical. The ONE sampler — the decode
+        program vmaps it and the prefill first token calls it directly,
+        so seeded runs cannot diverge at token 2."""
+        greedy = jnp.argmax(logits_row, -1)
+        vals, idxs = jax.lax.top_k(logits_row, K)
+        rank_ok = jnp.arange(K) < top_k
+        scaled = jnp.where(rank_ok, vals / jnp.maximum(temp, 1e-6), -jnp.inf)
+        key, sub = jax.random.split(key)
+        sampled = idxs[jax.random.categorical(sub, scaled)]
+        tok = jnp.where(temp <= 0.0, greedy, sampled).astype(jnp.int32)
+        return tok, key
+
+    def decode_fn(params, cache, tokens, temps, top_ks, keys,
+                  loras=None, adapter_ids=None):
+        """Decode + in-program sampling with per-slot PRNG keys
+        (per-request seeds stay reproducible across batch compositions)."""
+        logits, cache = decode_step(
+            params, stats_in(cache), tokens, cfg,
+            loras=loras, adapter_ids=adapter_ids,
+        )
+        stats = cache.pop("moe_stats", None)
+        with jax.named_scope("sampling"):
+            next_tokens, new_keys = jax.vmap(sample_row)(
+                logits, temps, top_ks, keys
+            )
+        return next_tokens, cache, new_keys, stats
+
+    n_steps = max(1, decode_steps)
+
+    def decode_multi(params, cache, tokens, temps, top_ks, keys,
+                     loras=None, adapter_ids=None):
+        """K decode steps in one program (lax.scan): one host round
+        trip per K tokens."""
+        def body(carry, _):
+            toks, cache, keys = carry
+            nt, cache, keys, stats = decode_fn(
+                params, cache, toks, temps, top_ks, keys,
+                loras=loras, adapter_ids=adapter_ids,
+            )
+            return (nt, cache, keys), (nt, stats)
+
+        (toks, cache, keys), (out, stats) = jax.lax.scan(
+            body, (tokens, cache, keys), None, length=n_steps
+        )
+        if stats is not None:
+            stats = stats.sum(axis=0)
+        return out, cache, keys, stats  # out: [K, slots]
+
+    def chunk_mid(params, ones, tokens, lengths, starts,
+                  loras=None, adapter_ids=None):
+        """Extend each row's scratch stripe with its prompt's next chunk
+        — no LM head (mid-chunks of chunked prefill never need logits).
+        ``ones``: a stripe a row; ``tokens`` [rows, C]. A single row's
+        stripe is the cache ``prefill`` extends where it lies; several rows'
+        are stacked into one (a copy of each) and handed back a row each (a
+        second copy; both under ``kv_write``: PERF.md section 6, PR 34). The
+        launch's routing counts ride with the first row's."""
+        if len(ones) == 1:
+            cache = ones[0]
+        else:
+            with jax.named_scope("kv_write"):
+                cache = {
+                    k: jnp.concatenate([one[k] for one in ones], axis=0 if k == "length" else 1)
+                    for k in ("k", "v", "length")
+                }
+            if routed:
+                cache["moe_stats"] = ones[0]["moe_stats"]
+        _, cache = prefill(
+            params, cache, tokens, cfg, lengths=lengths, start_pos=starts,
+            loras=loras, adapter_ids=adapter_ids, with_logits=False,
+        )
+        if len(ones) == 1:
+            return (cache,)
+        with jax.named_scope("kv_write"):
+            return tuple(
+                {**one, **{k: cache[k][:, i:i + 1] for k in ("k", "v")},
+                 "length": cache["length"][i:i + 1],
+                 **({"moe_stats": cache["moe_stats"]} if routed and i == 0 else {})}
+                for i, one in enumerate(ones)
+            )
+
+    def chunk_final(params, cache, one, tokens, length, start, slot,
+                    temp, top_k, key, loras=None, adapter_ids=None):
+        """Last prompt chunk: prefill it, sample the first generated
+        token IN-PROGRAM (no host sync on the admission path), and
+        copy the finished stripe into the pool slot. One row a launch: a
+        row's arithmetic on the chip is not to the bit what it is beside a
+        companion (PERF.md section 6, PR 34), and the chunk that gives a
+        request its first token behind a seeded prefix has to give what it
+        gave behind the computed one."""
+        mid_stats = one.get("moe_stats")  # the prompt's middle chunks'
+        last_logits, one = prefill(
+            params, one, tokens, cfg, lengths=length, start_pos=start,
+            loras=loras, adapter_ids=adapter_ids,
+        )
+        stats = one.pop("moe_stats", None)
+        if stats is not None:  # rows: chunk_mid, chunk_final
+            stats = jnp.stack([mid_stats, stats - mid_stats])
+        total = start[0] + length[0]
+        with jax.named_scope("kv_write"):
+            cache = {
+                "k": cache["k"].at[:, slot].set(one["k"][:, 0]),
+                "v": cache["v"].at[:, slot].set(one["v"][:, 0]),
+                "length": cache["length"].at[slot].set(total),
+            }
+        with jax.named_scope("sampling"):
+            tok, new_key = sample_row(last_logits[0], temp, top_k, key)
+        return tok, new_key, cache, one, stats
+
+    def new_stripe(stripe_len):
+        """A zeroed scratch stripe (a program, so that it can be placed:
+        ``JaxEngine._compile``)."""
+        one = init_kv_cache(cfg, 1, stripe_len)
+        if routed:  # the prompt's chunks add their routing counts up in here
+            one["moe_stats"] = jnp.zeros((len(_MOE_COUNTERS),), jnp.int32)
+        return one
+
+    @jax.named_scope("prefix_seed")
+    def seed_prefix(one, pk, pv):
+        """Copy a cached prefix KV [L, K, m, D] into the scratch stripe."""
+        m = pk.shape[2]
+        return {
+            **one,
+            "k": one["k"].at[:, 0, :, :m].set(pk),
+            "v": one["v"].at[:, 0, :, :m].set(pv),
+        }
+
+    return dict(decode_fn=decode_fn, decode_multi=decode_multi, chunk_mid=chunk_mid,
+                chunk_final=chunk_final, new_stripe=new_stripe, seed_prefix=seed_prefix)
+
+
+def top_k_static(cfg) -> int:
+    return min(64, cfg.vocab_size)
 
 
 class JaxEngine:
@@ -275,7 +445,18 @@ class JaxEngine:
         self._loop_first_pass_t: Optional[float] = None
         self._build_model()
         self._build_pools()
+        # the most rows a pool's middle-chunk program runs: what is alive in
+        # a pass decides how many it has, up to a pool's admissions (and the
+        # rows ``prefill`` writes as blocks). A latent pool's chunks stay one
+        # to a launch: their attention walks key blocks as far as the
+        # furthest row has cached with every row, in plain XLA, and on a v5e
+        # rows of long documents ran longer in one launch than one after
+        # another (PERF.md section 6, PR 34)
+        rows = max(1, min(config.engine.max_concurrent_admissions, CHUNK_ROWS_MAX))
+        for pool in self._pools:
+            pool.chunk_rows = 1 if pool.latent else rows
         self._compile()
+        self._warm_programs()
         self._waiting: "queue.Queue[_Request]" = queue.Queue()
         self._backlog: list[_Request] = []  # engine-thread-owned FIFO
         self._stop = threading.Event()
@@ -343,9 +524,10 @@ class JaxEngine:
         ``models/llama.py serving_layouts`` names relaid on the device once,
         before a program sees them. The caller's arrays are copied, not
         donated: a tree shared with the engine stays whole in the caller's
-        hands. Names, shapes, dtypes, shardings and values stay; a program is
-        compiled for the layout its argument has, so a swap compiles
-        nothing. ``get_stats()["params_relaid"]`` counts what is held so,
+        hands. Names, shapes, dtypes, shardings and values stay, and every
+        other leaf is the caller's own buffer, committed where it lies; a
+        program is compiled for the layout and the kind of argument it is
+        handed, so a swap compiles nothing. ``get_stats()["params_relaid"]`` counts what is held so,
         from the arrays themselves."""
         import jax
         from jax.experimental.layout import Format, Layout
@@ -367,6 +549,16 @@ class JaxEngine:
                     tree[name] = jax.device_put(
                         x, Format(Layout(major_to_minor=rule[name]), x.sharding)
                     )
+        if tree:
+            # every leaf committed to where it lies (no copy): a program is
+            # compiled for the kind of argument it is handed, and a tree made
+            # eagerly and one made by a program with ``out_shardings`` would
+            # each compile the whole set once
+            tree = {
+                k: x if not isinstance(x, jax.Array) or x.committed
+                else jax.device_put(x, x.sharding)
+                for k, x in tree.items()
+            }
         self._params = tree
         # read back from the arrays: what is held, not what was asked
         relaid = [tree[k] for k, order in rule.items() if _order_of(tree[k]) == order]
@@ -429,137 +621,35 @@ class JaxEngine:
 
     def _compile(self):
         import jax
-        import jax.numpy as jnp
-
-        from ray_tpu.models.llama import decode_step, prefill
 
         cfg = self.model_cfg
-        ec = self.config.engine
-
-        # one static top-K for the decode program AND the prefill first-token
-        # sampler — they must agree or seeded runs diverge at token 2
-        self._top_k_static = K = min(64, cfg.vocab_size)
-
-        # a model with routed experts: each program takes a zeroed
-        # ``moe_stats`` leaf in with its cache and hands the counts out
-        # beside its tokens (``models/llama.py _ride_stats``). A dense
-        # model's programs hand out ``None`` there, which is no output.
-        routed = self._routed = bool(cfg.moe_experts)
-
-        def stats_in(cache):
-            if not routed:
-                return cache
-            return dict(cache, moe_stats=jnp.zeros((len(_MOE_COUNTERS),), jnp.int32))
-
-        def sample_row(logits_row, temp, top_k, key):
-            """Sample one token from [V] fp32 logits: greedy where temp<=0,
-            else top-k/temperature categorical. The ONE sampler — the decode
-            program vmaps it and the prefill first token calls it directly,
-            so seeded runs cannot diverge at token 2."""
-            greedy = jnp.argmax(logits_row, -1)
-            vals, idxs = jax.lax.top_k(logits_row, K)
-            rank_ok = jnp.arange(K) < top_k
-            scaled = jnp.where(rank_ok, vals / jnp.maximum(temp, 1e-6), -jnp.inf)
-            key, sub = jax.random.split(key)
-            sampled = idxs[jax.random.categorical(sub, scaled)]
-            tok = jnp.where(temp <= 0.0, greedy, sampled).astype(jnp.int32)
-            return tok, key
-
-        def decode_fn(params, cache, tokens, temps, top_ks, keys,
-                      loras=None, adapter_ids=None):
-            """Decode + in-program sampling with per-slot PRNG keys
-            (per-request seeds stay reproducible across batch compositions)."""
-            logits, cache = decode_step(
-                params, stats_in(cache), tokens, cfg,
-                loras=loras, adapter_ids=adapter_ids,
-            )
-            stats = cache.pop("moe_stats", None)
-            with jax.named_scope("sampling"):
-                next_tokens, new_keys = jax.vmap(sample_row)(
-                    logits, temps, top_ks, keys
-                )
-            return next_tokens, cache, new_keys, stats
-
-        self._decode_jit = jax.jit(decode_fn, donate_argnums=(1,))
-
-        n_steps = max(1, ec.decode_steps)
-
-        def decode_multi(params, cache, tokens, temps, top_ks, keys,
-                         loras=None, adapter_ids=None):
-            """K decode steps in one program (lax.scan): one host round
-            trip per K tokens."""
-            def body(carry, _):
-                toks, cache, keys = carry
-                nt, cache, keys, stats = decode_fn(
-                    params, cache, toks, temps, top_ks, keys,
-                    loras=loras, adapter_ids=adapter_ids,
-                )
-                return (nt, cache, keys), (nt, stats)
-
-            (toks, cache, keys), (out, stats) = jax.lax.scan(
-                body, (tokens, cache, keys), None, length=n_steps
-            )
-            if stats is not None:
-                stats = stats.sum(axis=0)
-            return out, cache, keys, stats  # out: [K, slots]
-
-        self._decode_multi_jit = jax.jit(decode_multi, donate_argnums=(1,))
-        self._decode_n_steps = n_steps
-
-        def chunk_mid(params, one, tokens, length, start,
-                      loras=None, adapter_id=None):
-            """Extend the scratch stripe with one prompt chunk — no LM head
-            (mid-chunks of chunked prefill never need logits)."""
-            _, one = prefill(
-                params, one, tokens, cfg, lengths=length, start_pos=start,
-                loras=loras, adapter_ids=adapter_id, with_logits=False,
-            )
-            return one
-
-        self._chunk_mid_jit = jax.jit(chunk_mid, donate_argnums=(1,))
-
-        def chunk_final(params, cache, one, tokens, length, start, slot,
-                        temp, top_k, key, loras=None, adapter_id=None):
-            """Last prompt chunk: prefill it, sample the first generated
-            token IN-PROGRAM (no host sync on the admission path), and
-            copy the finished stripe into the pool slot."""
-            mid_stats = one.get("moe_stats")  # the prompt's middle chunks'
-            last_logits, one = prefill(
-                params, one, tokens, cfg, lengths=length, start_pos=start,
-                loras=loras, adapter_ids=adapter_id,
-            )
-            stats = one.pop("moe_stats", None)
-            if stats is not None:  # rows: chunk_mid, chunk_final
-                stats = jnp.stack([mid_stats, stats - mid_stats])
-            total = start[0] + length[0]
-            with jax.named_scope("kv_write"):
-                cache = {
-                    "k": cache["k"].at[:, slot].set(one["k"][:, 0]),
-                    "v": cache["v"].at[:, slot].set(one["v"][:, 0]),
-                    "length": cache["length"].at[slot].set(total),
-                }
-            with jax.named_scope("sampling"):
-                tok, new_key = sample_row(last_logits[0], temp, top_k, key)
-            return tok, new_key, cache, one, stats
-
+        self._top_k_static = top_k_static(cfg)
+        self._decode_n_steps = max(1, self.config.engine.decode_steps)
+        fns = programs(cfg, self._decode_n_steps)
+        self._decode_jit = jax.jit(fns["decode_fn"], donate_argnums=(1,))
+        self._decode_multi_jit = jax.jit(fns["decode_multi"], donate_argnums=(1,))
+        self._chunk_mid_jit = jax.jit(fns["chunk_mid"], donate_argnums=(1,))
         # donate the scratch stripe too and hand it back (the caller drops
         # it): a stripe the program may not overwrite is copied before the
         # chunk is written into it, and the v5e compiler then moved a whole
         # 33 MB stripe between memory spaces once a layer (0.64 ms a run at
         # 7B widths; PERF.md section 6, PR 27)
-        self._chunk_final_jit = jax.jit(chunk_final, donate_argnums=(1, 2))
+        self._chunk_final_jit = jax.jit(fns["chunk_final"], donate_argnums=(1, 2))
+        from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
-        @jax.named_scope("prefix_seed")
-        def seed_prefix(one, pk, pv):
-            """Copy a cached prefix KV [L, K, m, D] into the scratch stripe."""
-            m = pk.shape[2]
-            return {
-                **one,
-                "k": one["k"].at[:, 0, :, :m].set(pk),
-                "v": one["v"].at[:, 0, :, :m].set(pv),
-            }
-
-        self._seed_prefix_jit = jax.jit(seed_prefix, donate_argnums=(0,))
+        # a scratch stripe is committed where it is made: a chunk program then
+        # sees the same kind of argument in a prompt's first chunk as behind
+        # another, and in any mix of the two among its rows (an uncommitted
+        # one was a second compilation of each program)
+        self._new_stripe_jit = jax.jit(
+            fns["new_stripe"], static_argnums=(0,),
+            out_shardings=(
+                NamedSharding(self._mesh, PartitionSpec())
+                if self._mesh is not None and self._mesh.size > 1
+                else SingleDeviceSharding(jax.local_devices()[0])
+            ),
+        )
+        self._seed_prefix_jit = jax.jit(fns["seed_prefix"], donate_argnums=(0,))
         # tiny device-side updates that keep the decode chain host-free
         self._set_tok_jit = jax.jit(
             lambda toks, slot, tok: toks.at[slot].set(tok), donate_argnums=(0,)
@@ -568,6 +658,81 @@ class JaxEngine:
             lambda keys, slot, key: keys.at[slot].set(key), donate_argnums=(0,)
         )
         self._rng_key = jax.random.PRNGKey(self.config.model.seed)
+
+    def _chunk_widths(self, pool: _Pool) -> tuple[Optional[int], list[int]]:
+        """The chunk programs a pool's admissions can ask for: the middle
+        chunk's width (None where no prompt has one) and the final chunk's
+        widths, as ``_start_admission`` plans them."""
+        chunk = self.config.engine.prefill_chunk
+        longest = pool.stripe_len - 1  # a prompt leaves room for one token
+        piece = min(chunk, longest) if chunk else longest
+        reach = [b for b in sorted(self.config.engine.prefill_buckets) if b < piece] + [piece]
+        finals = sorted({min(self._bucket(n), pool.stripe_len) for n in reach})
+        return (chunk if 0 < chunk < longest else None), finals
+
+    def _warm_programs(self) -> None:
+        """Run every program the loop can launch once, on throwaway rows,
+        before the loop takes requests: a pool at a time, the middle chunk at
+        every row count, each final width, the prefix store's cuts and seeds,
+        and the decode program. Running is what fills a ``jit``'s own cache,
+        so nothing is left for the first burst of requests to compile
+        (warm-up traffic that sends a request at a time never reaches a
+        program of two rows, and a window that compiles is not measured). A
+        checkout's first start compiles them all here
+        (``LLMConfig.compile_budget_s``); later starts fetch them from the
+        compile cache. The rows write one token at position 0 of slot 0,
+        which holds no request and which an admission overwrites whole."""
+        import jax
+        import jax.numpy as jnp
+
+        rng_key = self._rng_key
+        jax.random.PRNGKey(0)  # a seeded request's key is a program too
+        for i, pool in enumerate(self._pools):
+            pool.keys = jax.random.split(
+                jax.random.PRNGKey(self.config.model.seed ^ (0x5EED + i)),
+                pool.n_slots,
+            )
+            pool.dev_tokens = jnp.zeros((pool.n_slots,), jnp.int32)
+            self._sync_adapter_ids(pool)
+            mid, finals = self._chunk_widths(pool)
+            stripe = pool.stripe_len
+
+            def throwaway(rows: int) -> dict:  # rows of one token at position 0
+                return dict(
+                    ones=tuple(self._new_stripe_jit(stripe) for _ in range(rows)),  # noqa: B023
+                    toks=np.zeros((rows, mid), np.int32), lens=[1] * rows,  # noqa: B023
+                    starts=[0] * rows, adapters=[0] * rows,
+                )
+
+            for rows in range(1, pool.chunk_rows + 1 if mid else 1):
+                args = throwaway(rows)
+                for _ in range(2):  # fresh stripes, then a chunk program's own
+                    args["ones"] = self._run_chunk_mid(**args)
+            for width in finals:
+                # a first chunk's stripe is fresh, a later one's comes out of
+                # a chunk program: both kinds of argument
+                stripes = [self._new_stripe_jit(stripe)]
+                if mid:
+                    stripes += self._run_chunk_mid(**throwaway(1))
+                for one in stripes:
+                    self._run_chunk_final(
+                        pool, one, np.zeros((1, width), np.int32), 1, 0, 0, 0.0, 1, None, 0)
+            if self.config.engine.enable_prefix_caching:
+                # the store's cut of a slot at each bucket, and the program
+                # that seeds a stripe with one
+                for b in self.config.engine.prefill_buckets:
+                    if b < pool.stripe_len:
+                        self._seed_prefix_jit(
+                            self._new_stripe_jit(stripe),
+                            pool.cache["k"][:, 0, :, :b], pool.cache["v"][:, 0, :, :b])
+            for _ in range(2):  # the cache, keys and tokens as a chunk left them, then as a step did
+                out, pool.cache, pool.keys, _ = self._decode(
+                    pool, pool.dev_tokens, jnp.asarray(pool.temps),
+                    jnp.asarray(pool.top_ks), pool.keys,
+                )
+                pool.dev_tokens = out[-1]
+            jax.block_until_ready((pool.cache, pool.dev_tokens))
+        self._rng_key = rng_key
 
     def _decode(self, pool: _Pool, tokens, temps, top_ks, keys):
         """Returns ([K, slots] tokens, cache, keys, routing counts or None)
@@ -591,15 +756,14 @@ class JaxEngine:
             out = out[None]  # unify to [K, slots]
         return out, cache, keys, stats
 
-    def _lora_kw(self, adapter_id: int) -> dict:
+    def _lora_kw(self, adapter_ids: list) -> dict:
+        """A chunk program's adapter arguments, an id a row (none in a
+        no-LoRA configuration: the compiled program has no adapter args)."""
         import jax.numpy as jnp
 
         if self.loras is None:
             return {}
-        return dict(
-            loras=self.loras,
-            adapter_id=jnp.asarray([adapter_id], jnp.int32),
-        )
+        return dict(loras=self.loras, adapter_ids=jnp.asarray(adapter_ids, jnp.int32))
 
     def _sync_adapter_ids(self, pool: _Pool):
         if self.loras is not None:
@@ -1028,10 +1192,6 @@ class JaxEngine:
     def _start_admission(self, pool: "_Pool", slot: int, req: _Request) -> None:
         """Build the chunked-prefill plan for a slot (device work starts on
         the next _advance_admissions pass)."""
-        import jax.numpy as jnp
-
-        from ray_tpu.models.llama import init_kv_cache
-
         req.admitted_t = time.time()
         req.pool_stripe, req.slot = pool.stripe_len, slot
         ids = req.prompt_token_ids
@@ -1063,64 +1223,70 @@ class JaxEngine:
             toks[0, : len(piece)] = piece
             chunks.append((toks, len(piece), start, is_final))
             start += len(piece)
-        one = init_kv_cache(self.model_cfg, 1, pool.stripe_len)
-        if self._routed:
-            # the prompt's chunks add their routing counts up in here
-            one["moe_stats"] = jnp.zeros((len(_MOE_COUNTERS),), jnp.int32)
+        one = self._new_stripe_jit(pool.stripe_len)
         if prefix is not None:
             with tracing.annotate("engine.prefix_seed", tokens=m):
                 one = self._seed_prefix_jit(one, prefix["k"], prefix["v"])
             self._n["prefix_seed_tokens"] += m
         pool.admitting[slot] = _Admission(req, slot, one, chunks, m)
 
-    def _advance_admission(self, pool: "_Pool", adm: _Admission) -> None:
-        """Dispatch ONE prompt chunk (device-async). The final chunk
-        samples the first token in-program and activates the slot."""
-        import jax
-        import jax.numpy as jnp
-
+    def _count_chunk(self, adm: _Admission) -> tuple:
+        """Take ``adm``'s next chunk off its plan and count it: one a prompt
+        chunk, whatever launch carries it."""
         toks, eff_len, start, is_final = adm.chunks[adm.idx]
         adm.idx += 1
-        req = adm.req
-        req.chunks_run += 1
-        self._n["prefill_chunks:final" if is_final else "prefill_chunks:mid"] += 1
-        program = "chunk_final" if is_final else "chunk_mid"
+        adm.req.chunks_run += 1
+        kind, program = ("final", "chunk_final") if is_final else ("mid", "chunk_mid")
+        self._n["prefill_chunks:" + kind] += 1
         self._n["prefill_query_tokens:" + program] += eff_len
         self._n["prefill_attended_positions:" + program] += (
             eff_len * start + eff_len * (eff_len + 1) // 2
         )
-        lora_kw = self._lora_kw(req.lora_idx)
-        t = jnp.asarray(toks)
-        l = jnp.asarray([eff_len], jnp.int32)
-        s = jnp.asarray([start], jnp.int32)
-        if not is_final:
-            adm.one = self._chunk_mid_jit(
-                self.params, adm.one, t, l, s, **lora_kw
-            )
-            return
-        K = self._top_k_static
-        if req.params.seed is not None:
-            req_key = jax.random.PRNGKey(req.params.seed)
-        else:
-            self._rng_key, req_key = jax.random.split(self._rng_key)
-        temp = jnp.float32(req.params.temperature)
-        topk = jnp.int32(min(max(1, req.params.top_k), K))
-        slot = adm.slot
+        return toks, eff_len, start
+
+    def _launch_mid_chunks(self, pool: "_Pool", adms: list) -> None:
+        """Dispatch ONE ``chunk_mid`` (device-async) whose rows are the next
+        middle chunks of ``adms``: each row's stripe comes back extended."""
+        plan = [self._count_chunk(adm) for adm in adms]
+        self._n["prefill_programs:mid"] += 1
+        ones = self._run_chunk_mid(
+            ones=tuple(adm.one for adm in adms),
+            toks=np.concatenate([toks for toks, _, _ in plan]),
+            lens=[eff_len for _, eff_len, _ in plan],
+            starts=[start for _, _, start in plan],
+            adapters=[adm.req.lora_idx for adm in adms],
+        )
+        for adm, one in zip(adms, ones):
+            adm.one = one
+
+    def _run_chunk_mid(self, ones: tuple, toks, lens: list, starts: list, adapters: list) -> tuple:
+        """The device side of a middle-chunk launch, a row an entry."""
+        import jax.numpy as jnp
+
+        return self._chunk_mid_jit(
+            self.params, ones, jnp.asarray(toks), jnp.asarray(lens, jnp.int32),
+            jnp.asarray(starts, jnp.int32), **self._lora_kw(adapters)
+        )
+
+    def _launch_final_chunk(self, pool: "_Pool", adm: _Admission) -> None:
+        """Dispatch a prompt's final chunk (device-async), one row a launch
+        (``programs``' ``chunk_final`` says why): it samples the first token
+        in-program and activates the slot."""
+        toks, eff_len, start = self._count_chunk(adm)
+        self._n["prefill_programs:final"] += 1
+        req, slot = adm.req, adm.slot
+        # decode truncates to the program's static top-K; clamp here so
+        # first token and all later tokens agree
+        top_k = min(max(1, req.params.top_k), self._top_k_static)
         pool.adapter_ids[slot] = req.lora_idx
         self._sync_adapter_ids(pool)
-        first_tok, new_key, pool.cache, _, stats = self._chunk_final_jit(
-            self.params, pool.cache, adm.one, t, l, s,
-            jnp.int32(slot), temp, topk, req_key, **lora_kw
-        )
-        pool.keys = self._set_key_jit(pool.keys, jnp.int32(slot), new_key)
-        pool.dev_tokens = self._set_tok_jit(
-            pool.dev_tokens, jnp.int32(slot), first_tok
+        first_tok, stats = self._run_chunk_final(
+            pool, adm.one, toks, eff_len, start, slot,
+            req.params.temperature, top_k, req.params.seed, req.lora_idx,
         )
         pool.slots[slot] = req
         pool.temps[slot] = req.params.temperature
-        # decode truncates to the program's static top-K; clamp here so
-        # first token and all later tokens agree
-        pool.top_ks[slot] = min(max(1, req.params.top_k), K)
+        pool.top_ks[slot] = top_k
         del pool.admitting[slot]
         if req.prefix_hit_tokens == 0 and req.lora_idx == 0:
             # LoRA'd prefixes are adapter-specific: never shared
@@ -1132,6 +1298,30 @@ class JaxEngine:
         except Exception:  # noqa: BLE001 — platform without async copy
             pass
         pool.first_pending.append((slot, req, first_tok, stats))
+
+    def _run_chunk_final(self, pool: "_Pool", one, toks, eff_len: int, start: int, slot: int,
+                         temperature: float, top_k: int, seed: Optional[int], adapter: int):
+        """The device side of a final-chunk launch: the pool's cache, keys
+        and next input tokens take the slot's new values. Returns the first
+        token and the routing counts (or None), both still on the device."""
+        import jax
+        import jax.numpy as jnp
+
+        if seed is not None:
+            req_key = jax.random.PRNGKey(seed)
+        else:
+            self._rng_key, req_key = jax.random.split(self._rng_key)
+        first_tok, new_key, pool.cache, _, stats = self._chunk_final_jit(
+            self.params, pool.cache, one, jnp.asarray(toks),
+            jnp.asarray([eff_len], jnp.int32), jnp.asarray([start], jnp.int32),
+            jnp.int32(slot), jnp.float32(temperature), jnp.int32(top_k),
+            req_key, **self._lora_kw([adapter])
+        )
+        pool.keys = self._set_key_jit(pool.keys, jnp.int32(slot), new_key)
+        pool.dev_tokens = self._set_tok_jit(
+            pool.dev_tokens, jnp.int32(slot), first_tok
+        )
+        return first_tok, stats
 
     def _fail_admission(
         self, pool: "_Pool", adm: _Admission, e: BaseException,
@@ -1189,21 +1379,44 @@ class JaxEngine:
         return progressed
 
     def _advance_admissions(self) -> bool:
+        """One chunk of every admission. A pool's admissions whose next
+        chunk is a middle chunk (all are ``prefill_chunk`` wide) run as rows
+        of ONE ``chunk_mid``, as many rows as are due: a read of the weights
+        then serves every prompt that waits for it. A final chunk is a
+        launch of its own. A launch that raises fails its rows' requests,
+        and no others."""
         progressed = False
         for pool in self._pools:
+            launches, mids = [], None  # mids: the middle-chunk launch with room left
             for adm in list(pool.admitting.values()):
                 try:
-                    # inside the try: an admission with no chunk (an empty
-                    # prompt) fails that request, not the loop
-                    _, eff_len, _, is_final = adm.chunks[adm.idx]
-                    with tracing.annotate(
-                        "engine.prefill_chunk", request=adm.req.request_id,
-                        tokens=eff_len, final=is_final,
-                    ):
-                        self._advance_admission(pool, adm)
-                    progressed = True
+                    # an admission with no chunk (an empty prompt) fails that
+                    # request, not the loop
+                    is_final = adm.chunks[adm.idx][3]
                 except BaseException as e:  # noqa: BLE001
                     self._fail_admission(pool, adm, e)
+                    continue
+                if is_final:
+                    launches.append((True, [adm]))
+                    continue
+                if mids is None or len(mids) == pool.chunk_rows:
+                    mids = []
+                    launches.append((False, mids))
+                mids.append(adm)
+            for is_final, adms in launches:
+                try:
+                    with tracing.annotate(
+                        "engine.prefill_chunk", rows=len(adms), final=is_final,
+                        requests=",".join(adm.req.request_id for adm in adms),
+                    ):
+                        if is_final:
+                            self._launch_final_chunk(pool, adms[0])
+                        else:
+                            self._launch_mid_chunks(pool, adms)
+                    progressed = True
+                except BaseException as e:  # noqa: BLE001
+                    for adm in adms:
+                        self._fail_admission(pool, adm, e)
         return progressed
 
     def _launch_decodes(self) -> bool:
@@ -1349,15 +1562,6 @@ class JaxEngine:
                     self._n[f"{name}:{program}"] += int(value)
 
     def _engine_loop(self):
-        import jax
-
-        for i, pool in enumerate(self._pools):
-            pool.keys = jax.random.split(
-                jax.random.PRNGKey(self.config.model.seed ^ (0x5EED + i)),
-                pool.n_slots,
-            )
-            pool.dev_tokens = jax.numpy.zeros((pool.n_slots,), jax.numpy.int32)
-
         # the four stages stay attributes looked up on ``self`` each pass:
         # the benchmark wraps them by name. Loop spans go to the profiler
         # only (``tracing.annotate``), never to the ring.

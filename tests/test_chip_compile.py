@@ -297,18 +297,14 @@ def test_patterned_chunk_mid_keeps_its_expert_banks_in_place(
 # ---- the engine's layout of the stacked attention input projections ---------
 
 
-def _projection_slice_ops(text, e, head_width=128):
-    """The operations of the entry computation and of the layer loop's body
-    (not of a fusion's own computation) whose result, or one of whose
-    results, has the shape of one layer's slice of a stacked attention input
-    projection: ``[1, e, h, 128]`` or ``[e, h, 128]``. A matmul that reads
-    the stacked leaf in place leaves none: its fusion takes the leaf whole
-    and the layer's index."""
+def _ops_outside_fusions(text):
+    """(computation, result type, operation, line) of every instruction of
+    the compiled text that is not inside a fusion's own computation: the
+    entry computation's, a loop body's."""
     import re
 
     fused = set(re.findall(r"kind=k\w+, calls=(%[\w.\-]+)", text))
-    shape = re.compile(r"\[(?:1,)?%d,\d+,%d\]" % (e, head_width))
-    found, computation = [], None
+    computation = None
     for line in text.splitlines():
         if line and not line[0].isspace():
             head = line.split()
@@ -322,11 +318,24 @@ def _projection_slice_ops(text, e, head_width=128):
             depth += (ch == "(") - (ch == ")")
             if ch == " " and depth == 0:
                 break
-        op = rest[end + 1:].split("(", 1)[0]
-        if (shape.search(rest[:end]) and op not in ("parameter", "get-tuple-element")
-                and not op.endswith("-done")):
-            found.append(line.strip()[:200])
-    return found
+        yield computation, rest[:end], rest[end + 1:].split("(", 1)[0], line
+
+
+def _projection_slice_ops(text, e, head_width=128):
+    """The operations of the entry computation and of the layer loop's body
+    (not of a fusion's own computation) whose result, or one of whose
+    results, has the shape of one layer's slice of a stacked attention input
+    projection: ``[1, e, h, 128]`` or ``[e, h, 128]``. A matmul that reads
+    the stacked leaf in place leaves none: its fusion takes the leaf whole
+    and the layer's index."""
+    import re
+
+    shape = re.compile(r"\[(?:1,)?%d,\d+,%d\]" % (e, head_width))
+    return [
+        line.strip()[:200] for _, result, op, line in _ops_outside_fusions(text)
+        if shape.search(result) and op not in ("parameter", "get-tuple-element")
+        and not op.endswith("-done")
+    ]
 
 
 def _served_programs(cfg, slots, stripe, one_chip, relaid=True):
@@ -512,3 +521,141 @@ def test_latent_programs_hold_nothing_as_long_as_the_stripe_and_copy_no_leaf(
 
     monkeypatch.setattr(llama, "EMBED_MINOR", llama.HEAD_MAJOR)
     assert relays(compiled("decode_step").as_text())
+
+
+# ---- middle chunks of several rows (``llm/engine.py programs``) -------------
+
+
+def _engine_programs(served, one_chip, rows=1):
+    """The engine's own program bodies at a serving cell's shapes, as
+    ``JaxEngine._compile`` jits them: name -> (function, donated, described
+    arguments), the middle chunk with ``rows`` rows of 256 tokens."""
+    from ray_tpu.llm.engine import programs
+    from ray_tpu.models.llama import init_kv_cache
+
+    cfg = _served_config(served)
+    slots, stripe, _ = _SERVED[served]
+    params, cache, tokens = _served_programs(cfg, slots, stripe, one_chip)["decode_step"][1]
+
+    def sds(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return sds(jnp.int32, *shape)
+
+    one = {k: sds(x.dtype, *x.shape)
+           for k, x in jax.eval_shape(lambda: init_kv_cache(cfg, 1, stripe)).items()}
+    if cfg.moe_experts:
+        one["moe_stats"] = i32(4)
+    fns = programs(cfg)
+    return {
+        "decode_fn": (fns["decode_fn"], (1,),
+                      (params, cache, tokens, sds(jnp.float32, slots), i32(slots),
+                       sds(jnp.uint32, slots, 2))),
+        "chunk_mid": (fns["chunk_mid"], (1,),
+                      (params, tuple(dict(one) for _ in range(rows)), i32(rows, 256), i32(rows),
+                       i32(rows))),
+    }
+
+
+def _engine_text(program):
+    fn, donated, args = program
+    return jax.jit(fn, donate_argnums=donated).lower(*args).compile().as_text()
+
+
+def _whole_stripe_ops(text, layers, stripe, heads=8, width=128):
+    """(computation, operation) of everything outside a fusion's own
+    computation whose result has the shape of whole scratch stripes
+    (``[layers, rows, 8, stripe, 128]``) or of a layer of them."""
+    import re
+
+    shape = re.compile(r"\[(?:%d,)?\d+,%d,%d,%d\]" % (layers, heads, stripe, width))
+    return [
+        (computation, op) for computation, result, op, _ in _ops_outside_fusions(text)
+        if shape.search(result) and not op.endswith("-done")
+        and op not in ("parameter", "get-tuple-element", "tuple", "while", "bitcast")
+    ]
+
+
+# whole-stripe operations that are not a layer's in-place block write, more in
+# the program of several rows than in the 1-row one: the copies that stack the
+# rows' stripes (keys and values) and hand each row's back
+_STACKING_COPIES = {
+    ("mistral-7b-serve-l16", 2): 5, ("mistral-7b-serve-l16", 4): 7,
+    ("laguna-xs.2-serve-l5", 2): 9, ("laguna-xs.2-serve-l5", 4): 23,
+}
+
+
+@pytest.mark.parametrize("rows", [2, 4])
+@pytest.mark.parametrize("served", sorted(_SERVED))
+def test_a_middle_chunk_of_several_rows_copies_no_stripe_a_layer(
+        served, rows, one_chip, no_compile_cache, native_kernels):
+    """The engine's ``chunk_mid`` with two and with four rows of 256 tokens
+    at the serving cells' shapes: the rows' scratch stripes are stacked once
+    a launch and handed back once, and the layers write into the stack in
+    place. Outside the in-place block writes (two a row and traced layer, as
+    in the 1-row program) the program holds at most ``_STACKING_COPIES`` more
+    whole-stripe operations than the 1-row one: a number that follows the
+    rows and not the layers (a copy a layer would add 16 in Mistral's cell, 5
+    in Laguna's, a tensor and row; an undonated stripe cost 0.64 ms a layer on
+    the chip, PERF.md section 6, PR 27; Laguna's count holds the pieces the
+    compiler moves a stripe in). In Mistral's cell the layers are one
+    loop body, which holds nothing but those writes."""
+    cfg = _served_config(served)
+    stripe = _SERVED[served][1]
+
+    def ops(n):
+        text = _engine_text(_engine_programs(served, one_chip, n)["chunk_mid"])
+        return _whole_stripe_ops(text, cfg.n_layers, stripe)
+
+    def copies(found):
+        return [op for _, op in found if op != "dynamic-update-slice"]
+
+    one, several = ops(1), ops(rows)
+    assert len(copies(several)) - len(copies(one)) <= _STACKING_COPIES[served, rows], (one, several)
+    writes = lambda found: sum(op == "dynamic-update-slice" for _, op in found)  # noqa: E731
+    assert writes(several) == rows * writes(one)
+    if not cfg.layer_types:  # one loop body for all layers
+        in_loop = [op for computation, op in several if "region" in computation]
+        assert in_loop == ["dynamic-update-slice"] * 2 * rows, several
+
+
+@pytest.mark.parametrize("served", sorted(_SERVED))
+def test_the_engines_decode_program_is_decode_step_and_the_one_sampler(
+        served, one_chip, no_compile_cache, native_kernels):
+    """``jit_decode_fn`` is not touched by what groups the chunk programs:
+    the engine's ``decode_fn`` compiles to the text of ``decode_step`` over
+    every slot with the one sampler mapped over its rows, spelled out here as
+    the engine had it before chunk programs took rows (PR 34), source lines
+    apart."""
+    from ray_tpu.models.llama import decode_step
+
+    cfg = _served_config(served)
+    fn, donated, args = _engine_programs(served, one_chip)["decode_fn"]
+    K = min(64, cfg.vocab_size)
+
+    def sample_row(logits_row, temp, top_k, key):
+        greedy = jnp.argmax(logits_row, -1)
+        vals, idxs = jax.lax.top_k(logits_row, K)
+        rank_ok = jnp.arange(K) < top_k
+        scaled = jnp.where(rank_ok, vals / jnp.maximum(temp, 1e-6), -jnp.inf)
+        key, sub = jax.random.split(key)
+        sampled = idxs[jax.random.categorical(sub, scaled)]
+        tok = jnp.where(temp <= 0.0, greedy, sampled).astype(jnp.int32)
+        return tok, key
+
+    def decode_fn(params, cache, tokens, temps, top_ks, keys):
+        if cfg.moe_experts:
+            cache = dict(cache, moe_stats=jnp.zeros((4,), jnp.int32))
+        logits, cache = decode_step(params, cache, tokens, cfg)
+        stats = cache.pop("moe_stats", None)
+        with jax.named_scope("sampling"):
+            next_tokens, new_keys = jax.vmap(sample_row)(logits, temps, top_ks, keys)
+        return next_tokens, cache, new_keys, stats
+
+    def same(text):  # a Mosaic kernel's bytecode names source lines too
+        import re
+
+        return _same_program(re.sub(r"backend_config=\{.*?\}(?=[,)\s]|$)", "", text, flags=re.M))
+
+    assert same(_engine_text((fn, donated, args))) == same(_engine_text((decode_fn, donated, args)))

@@ -108,7 +108,7 @@ def _lower_engine_program(eng, program):
         return eng._decode_jit.lower(
             params, cache, i32(pool.n_slots), f32(pool.n_slots), i32(pool.n_slots), keys)
     if program == "chunk_mid":
-        return eng._chunk_mid_jit.lower(params, one, i32(1, 32), i32(1), i32(1))
+        return eng._chunk_mid_jit.lower(params, (one,), i32(1, 32), i32(1), i32(1))
     if program == "chunk_final":
         return eng._chunk_final_jit.lower(
             params, cache, one, i32(1, 32), i32(1), i32(1), i32(), f32(), i32(),

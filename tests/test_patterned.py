@@ -635,7 +635,7 @@ def lowered_paths(engine):
     low = {
         "decode_fn": engine._decode_jit.lower(
             params, cache, i32(4), jax.ShapeDtypeStruct((4,), jnp.float32), i32(4), shapes(pool.keys)),
-        "chunk_mid": engine._chunk_mid_jit.lower(params, one, i32(1, 16), i32(1), i32(1)),
+        "chunk_mid": engine._chunk_mid_jit.lower(params, (one,), i32(1, 16), i32(1), i32(1)),
     }
     return {k: set(re.findall(r'loc\("([^"]+)"', v.as_text(debug_info=True))) for k, v in low.items()}
 
