@@ -98,7 +98,8 @@ class EngineConfig:
 class ModelConfig:
     # a preset of ``resolve_llama_config``: "tiny" | "llama2-7b" |
     # "llama3-8b" | "llama3.2-3b" | "llama3-70b" | "laguna-xs.2" |
-    # "laguna-tiny" | "kanana-2-30b-a3b" | "kanana-tiny"
+    # "laguna-tiny" | "kanana-2-30b-a3b" | "kanana-tiny" |
+    # "nemotron-3-super-120b-a12b" | "nemotron-tiny"
     model_id: str = "tiny"
     tokenizer: str = "byte"  # "byte" | transformers tokenizer path
     checkpoint_path: Optional[str] = None  # ray_tpu.train pytree checkpoint
@@ -135,6 +136,8 @@ def resolve_llama_config(model: "ModelConfig", engine: "EngineConfig", min_vocab
         "laguna-tiny": LlamaConfig.laguna_tiny,
         "kanana-2-30b-a3b": LlamaConfig.kanana2_30b_a3b,
         "kanana-tiny": LlamaConfig.kanana_tiny,
+        "nemotron-3-super-120b-a12b": LlamaConfig.nemotron3_super,
+        "nemotron-tiny": LlamaConfig.nemotron_tiny,
     }
     kw = dict(
         max_seq_len=engine.max_seq_len,
@@ -161,6 +164,19 @@ def refuse_latent(cfg, module: str) -> None:
             f"{cfg.kv_latent_rank}) is served on one device by llm/engine.py "
             "JaxEngine with tensor_parallel_degree=1; this path has no rule "
             "for its cache"
+        )
+
+
+def refuse_stateful(cfg, module: str) -> None:
+    """The same for a model with state-space layers: their slots hold a state
+    and a convolution tail beside keys and values, which those copies of the
+    cache's programs, a mesh and a hand-over of keys and values alone
+    (``llm/disagg.py``) do not know."""
+    if "ssm" in cfg.layer_types:
+        raise NotImplementedError(
+            f"{module}: a model with state-space layers is served on one device by "
+            "llm/engine.py JaxEngine with tensor_parallel_degree=1; this path has no "
+            "rule for a slot's recurrent state"
         )
 
 

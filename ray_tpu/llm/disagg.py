@@ -19,7 +19,9 @@ from typing import Any, Optional
 
 import numpy as np
 
-from ray_tpu.llm.config import LLMConfig, SamplingParams
+from ray_tpu.llm.config import (
+    LLMConfig, SamplingParams, refuse_stateful, resolve_llama_config,
+)
 
 
 class PrefillWorker:
@@ -31,6 +33,8 @@ class PrefillWorker:
         from ray_tpu.llm.tokenizer import get_tokenizer
         from ray_tpu.llm.engine import JaxEngine
 
+        # the hand-over is keys and values alone: before any weight is made
+        refuse_stateful(resolve_llama_config(llm_config.model, llm_config.engine), "llm/disagg.py")
         # reuse the engine's model construction, not its slot loop
         self._engine_shell = JaxEngine.__new__(JaxEngine)
         self._engine_shell.config = llm_config
@@ -83,6 +87,7 @@ class DecodeWorker:
         from ray_tpu.llm.engine import JaxEngine
         from ray_tpu.llm.tokenizer import get_tokenizer
 
+        refuse_stateful(resolve_llama_config(llm_config.model, llm_config.engine), "llm/disagg.py")
         shell = JaxEngine.__new__(JaxEngine)
         shell.config = llm_config
         shell.tokenizer = get_tokenizer(llm_config.model.tokenizer)

@@ -47,8 +47,12 @@ logger = logging.getLogger(__name__)
 # the ``/metrics`` scrape shows them). A name with a ``:`` is one label of a
 # family: ``requests_failed:decode`` is ``requests_failed`` with stage
 # ``decode``.
+# The routed experts' counts by program (``models/patterned.py
+# moe_stats_names``): a program of a model that holds a share of its experts
+# hands out the fifth, the assignments that fell on the experts held here of
+# those the router made; it stays 0 for every other model.
 _MOE_COUNTERS = ("moe_layer_steps", "moe_assignments", "moe_experts_touched",
-                 "moe_max_expert_load_sum")
+                 "moe_max_expert_load_sum", "moe_assignments_held")
 _MOE_PROGRAMS = ("decode", "chunk_mid", "chunk_final")
 COUNTERS = (
     "requests_submitted",
@@ -99,6 +103,11 @@ COUNTERS = (
     # scratch stripe (``prompt_tokens_from_prefix`` counts the same tokens at
     # admission; this one counts the copies)
     "prefix_seed_tokens",
+    # admissions of a model that keeps a recurrent state a slot, with the
+    # prefix cache on: no stored prefix is looked up for them and none is
+    # stored, because keys and values alone do not restore a slot
+    # (``_Pool.stateful``); stays 0 for every other model
+    "prefix_bypassed_stateful",
     # routed experts (``models/llama.py MOE_STATS``), summed over expert
     # layers and over the runs of each program: the decode program hands its
     # counts out beside its tokens, a prompt's middle chunks add theirs up on
@@ -213,7 +222,7 @@ class _Pool:
         import jax
 
         from ray_tpu.models.llama import init_kv_cache
-        from ray_tpu.models.patterned import reads_blocks
+        from ray_tpu.models.patterned import SSM_LEAVES, reads_blocks
 
         self.stripe_len = stripe_len
         self.n_slots = n_slots
@@ -227,8 +236,14 @@ class _Pool:
         # lanes is padded to them); None where the backend reports no memory
         tokens = n_slots * stripe_len
         self.kv_bytes_per_token = (self.cache["k"].nbytes + self.cache["v"].nbytes) / tokens
+        # what a slot holds whatever its length (a state-space layer's state
+        # and convolution tail): 0 for a model whose slots are stripes alone
+        state_bytes = sum(self.cache[k].nbytes for k in SSM_LEAVES if k in self.cache)
+        self.state_bytes_per_slot = state_bytes // n_slots
+        self.stateful = state_bytes > 0
         self.kv_bytes_per_token_held = (
-            None if before is None or after is None else (after - before) / tokens
+            None if before is None or after is None
+            else (after - before - state_bytes) / tokens
         )
         self.slots: list[Optional[_Request]] = [None] * n_slots
         self.temps = np.zeros((n_slots,), np.float32)
@@ -273,6 +288,14 @@ def programs(cfg, decode_steps: int = 1) -> dict:
     import jax.numpy as jnp
 
     from ray_tpu.models.llama import decode_step, init_kv_cache, prefill
+    from ray_tpu.models.patterned import SSM_LEAVES, moe_stats_names, plan
+
+    # what a slot holds, each leaf with the slot on axis 1: its stripes of keys
+    # and values, and for a model with state-space layers their state and
+    # convolution tail, which are stacked, unstacked, zeroed and copied into a
+    # slot with the stripes
+    slot_leaves = ("k", "v") + (SSM_LEAVES if plan(cfg).n_ssm else ())
+    n_stats = len(moe_stats_names(cfg))
 
     # one static top-K for the decode program AND the prefill first-token
     # sampler — they must agree or seeded runs diverge at token 2
@@ -287,7 +310,7 @@ def programs(cfg, decode_steps: int = 1) -> dict:
     def stats_in(cache):
         if not routed:
             return cache
-        return dict(cache, moe_stats=jnp.zeros((len(_MOE_COUNTERS),), jnp.int32))
+        return dict(cache, moe_stats=jnp.zeros((n_stats,), jnp.int32))
 
     def sample_row(logits_row, temp, top_k, key):
         """Sample one token from [V] fp32 logits: greedy where temp<=0,
@@ -354,7 +377,7 @@ def programs(cfg, decode_steps: int = 1) -> dict:
             with jax.named_scope("kv_write"):
                 cache = {
                     k: jnp.concatenate([one[k] for one in ones], axis=0 if k == "length" else 1)
-                    for k in ("k", "v", "length")
+                    for k in (*slot_leaves, "length")
                 }
             if routed:
                 cache["moe_stats"] = ones[0]["moe_stats"]
@@ -366,7 +389,7 @@ def programs(cfg, decode_steps: int = 1) -> dict:
             return (cache,)
         with jax.named_scope("kv_write"):
             return tuple(
-                {**one, **{k: cache[k][:, i:i + 1] for k in ("k", "v")},
+                {**one, **{k: cache[k][:, i:i + 1] for k in slot_leaves},
                  "length": cache["length"][i:i + 1],
                  **({"moe_stats": cache["moe_stats"]} if routed and i == 0 else {})}
                 for i, one in enumerate(ones)
@@ -392,8 +415,7 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         total = start[0] + length[0]
         with jax.named_scope("kv_write"):
             cache = {
-                "k": cache["k"].at[:, slot].set(one["k"][:, 0]),
-                "v": cache["v"].at[:, slot].set(one["v"][:, 0]),
+                **{k: cache[k].at[:, slot].set(one[k][:, 0]) for k in slot_leaves},
                 "length": cache["length"].at[slot].set(total),
             }
         with jax.named_scope("sampling"):
@@ -405,7 +427,7 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         ``JaxEngine._compile``)."""
         one = init_kv_cache(cfg, 1, stripe_len)
         if routed:  # the prompt's chunks add their routing counts up in here
-            one["moe_stats"] = jnp.zeros((len(_MOE_COUNTERS),), jnp.int32)
+            one["moe_stats"] = jnp.zeros((n_stats,), jnp.int32)
         return one
 
     @jax.named_scope("prefix_seed")
@@ -586,9 +608,10 @@ class JaxEngine:
         )
         sharded = ec.tensor_parallel_degree > 1 or ec.sequence_parallel_degree > 1
         if sharded or (self._mesh is not None and self._mesh.size > 1):
-            from ray_tpu.llm.config import refuse_latent
+            from ray_tpu.llm.config import refuse_latent, refuse_stateful
 
             refuse_latent(self.model_cfg, "llm/engine.py over a mesh")
+            refuse_stateful(self.model_cfg, "llm/engine.py over a mesh")
         if sharded:
             from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 
@@ -717,7 +740,7 @@ class JaxEngine:
                 for one in stripes:
                     self._run_chunk_final(
                         pool, one, np.zeros((1, width), np.int32), 1, 0, 0, 0.0, 1, None, 0)
-            if self.config.engine.enable_prefix_caching:
+            if self.config.engine.enable_prefix_caching and not pool.stateful:
                 # the store's cut of a slot at each bucket, and the program
                 # that seeds a stripe with one
                 for b in self.config.engine.prefill_buckets:
@@ -785,6 +808,11 @@ class JaxEngine:
         prompt (>=1 suffix token must remain to produce last-logits)."""
         if not self.config.engine.enable_prefix_caching:
             return None, 0
+        if self._pools[0].stateful:
+            # a stored prefix is keys and values; a slot of this model also
+            # needs the state its layers had reached at the boundary
+            self._n["prefix_bypassed_stateful"] += 1
+            return None, 0
         for b in sorted(self.config.engine.prefill_buckets, reverse=True):
             if b >= len(ids):
                 continue
@@ -803,7 +831,7 @@ class JaxEngine:
         budget (long-context entries are tens of MB each; an entry-only
         cap could pin gigabytes)."""
         ec = self.config.engine
-        if not ec.enable_prefix_caching:
+        if not ec.enable_prefix_caching or pool.stateful:
             return
         for b in ec.prefill_buckets:
             if b >= len(ids) or b > pool.stripe_len:
@@ -1040,7 +1068,8 @@ class JaxEngine:
                 {"stripe_len": p.stripe_len, "n_slots": p.n_slots,
                  "active": sum(s is not None for s in p.slots),
                  "kv_bytes_per_token": p.kv_bytes_per_token,
-                 "kv_bytes_per_token_held": p.kv_bytes_per_token_held}
+                 "kv_bytes_per_token_held": p.kv_bytes_per_token_held,
+                 "state_bytes_per_slot": p.state_bytes_per_slot}
                 for p in self._pools
             ],
             "prefix_cache_hits": self._prefix_hits,

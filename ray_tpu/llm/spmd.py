@@ -30,6 +30,7 @@ from ray_tpu.llm.config import (
     LLMConfig,
     SamplingParams,
     refuse_latent,
+    refuse_stateful,
     resolve_llama_config,
 )
 
@@ -62,6 +63,7 @@ class SPMDGenerator:
             mc, ec, min_vocab=self.tokenizer.vocab_size
         )
         refuse_latent(self.model_cfg, "llm/spmd.py")
+        refuse_stateful(self.model_cfg, "llm/spmd.py")
         if mesh is None:
             n = len(jax.devices())
             if (

@@ -136,12 +136,46 @@ class LlamaConfig:
     # logit with a selection bias (``moe_router_bias``) that takes part in
     # the choice of the top k and not in their weights
     moe_scoring: str = "softmax"
+    # --- blocks that are a mixer or a feed-forward alone, and state-space
+    # mixers (layer type 'ssm'; Mamba-2, as NVIDIA Nemotron-3-Super's 'M') ---
+    # a ``layer_types`` entry may also be 'ssm' or 'none' (no mixer), an
+    # ``mlp_types`` entry 'none' (no feed-forward); a block has at least one,
+    # and one norm in front of each it has. An 'ssm' layer keeps, for each
+    # sequence, a float32 state [ssm_heads, ssm_head_dim, ssm_state] and the
+    # last ``ssm_conv - 1`` inputs of its convolution, whatever the
+    # sequence's length (``init_kv_cache``); ``ssm_groups`` groups of heads
+    # share their B and C, and a prompt is scanned ``ssm_chunk`` tokens at a
+    # time (``ops/ssm.py``)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # full and sliding layers rotate their queries and keys (False: no
+    # position signal in attention; the state-space layers carry it)
+    attn_rope: bool = True
+    # an expert's form: 'swiglu' (three matrices) or 'relu2' (two: down(relu(up
+    # x) ** 2)); the shared expert has the same form
+    moe_activation: str = "swiglu"
+    # > 0: the routed experts live in a latent of this width, between a
+    # down-projection of the layer's input and an up-projection of their sum
+    # (the router and the shared expert read the input itself)
+    moe_latent_dim: int = 0
+    # > 0: this device holds experts ``moe_experts_first`` .. + held of the
+    # router's ``moe_experts`` (one share of an expert-parallel layer, without
+    # its exchange): the router scores and chooses over all of them, and what
+    # the absent ones would add to a token is left out
+    moe_experts_held: int = 0
+    moe_experts_first: int = 0
 
     def __post_init__(self):
         if self.attention not in ("full", "ring", "ulysses", "splash"):
             raise ValueError(f"unknown attention {self.attention!r}")
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown moe_scoring {self.moe_scoring!r}")
+        if self.moe_activation not in ("swiglu", "relu2"):
+            raise ValueError(f"unknown moe_activation {self.moe_activation!r}")
         for name in ("layer_types", "heads_per_layer", "mlp_types"):
             value = tuple(getattr(self, name))
             object.__setattr__(self, name, value)
@@ -337,6 +371,62 @@ class LlamaConfig:
         d.update(kw)
         return LlamaConfig(**_latent_lists(d))
 
+    @staticmethod
+    def nemotron3_super(**kw) -> "LlamaConfig":
+        """NVIDIA Nemotron-3-Super-120B-A12B (``model_type: nemotron_h``) as
+        its config.json has it: 88 blocks by ``hybrid_override_pattern``, each
+        a mixer or a feed-forward alone: ``M`` Mamba-2 (128 heads of 64, 8
+        groups, state 128, convolution 4, chunk 128), ``E`` 512 sigmoid-routed
+        relu^2 experts of width 2688 in a 1024-wide latent, 22 a token, times
+        5, beside a shared expert of 5376 on the input itself, ``*`` GQA of 32
+        query and 2 key-value heads of 128 without rotation. A caller that
+        cuts ``n_layers`` gets the pattern's first blocks unless it gives its
+        own ``pattern``; ``moe_experts_held`` and ``vocab_size`` give a
+        device's share. Not served: the multi-token-prediction module."""
+        d = dict(
+            vocab_size=131072, d_model=4096, n_layers=88, n_heads=32, n_kv_heads=2,
+            head_width=128, d_ff=2688, max_seq_len=262144, rms_eps=1e-5, attn_rope=False,
+            ssm_heads=128, ssm_head_dim=64, ssm_state=128, ssm_groups=8, ssm_conv=4,
+            ssm_chunk=128, moe_experts=512, moe_top_k=22, moe_d_ff=2688,
+            moe_shared_d_ff=5376, moe_routed_scale=5.0, moe_scoring="sigmoid",
+            moe_activation="relu2", moe_latent_dim=1024,
+            pattern="MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+                    "EMEMEMEMEM*EMEMEMEM*EMEMEMEME",
+        )
+        d.update(kw)
+        return LlamaConfig(**_pattern_lists(d))
+
+    @staticmethod
+    def nemotron_tiny(**kw) -> "LlamaConfig":
+        """Test-size model with ``nemotron3_super``'s blocks: the first 11 of
+        its pattern, 4 of 16 experts held."""
+        d = dict(
+            vocab_size=256, d_model=64, n_layers=11, n_heads=4, n_kv_heads=2, head_width=16,
+            d_ff=48, max_seq_len=128, dtype=jnp.float32, remat=False, rms_eps=1e-5,
+            attn_rope=False, ssm_heads=8, ssm_head_dim=16, ssm_state=16, ssm_groups=2,
+            ssm_conv=4, ssm_chunk=8, moe_experts=16, moe_top_k=6, moe_d_ff=48,
+            moe_shared_d_ff=96, moe_routed_scale=5.0, moe_scoring="sigmoid",
+            moe_activation="relu2", moe_latent_dim=32, moe_experts_held=4,
+            pattern="MEMEMEM*EME",
+        )
+        d.update(kw)
+        return LlamaConfig(**_pattern_lists(d))
+
+
+# a block of a ``nemotron_h`` pattern: (mixer, feed-forward)
+_BLOCKS = {"M": ("ssm", "none"), "E": ("none", "sparse"), "*": ("full", "none")}
+
+
+def _pattern_lists(d: dict) -> dict:
+    """The per-layer lists of a model given as a pattern of blocks that are a
+    mixer or a feed-forward alone, for its depth."""
+    blocks = [_BLOCKS[c] for c in d.pop("pattern")[: d["n_layers"]]]
+    d.setdefault("layer_types", tuple(t for t, _ in blocks))
+    d.setdefault("heads_per_layer", tuple(
+        d["n_heads"] if t == "full" else 0 for t in d["layer_types"]))
+    d.setdefault("mlp_types", tuple(m for _, m in blocks))
+    return d
+
 
 def _latent_lists(d: dict) -> dict:
     """The per-layer lists of a model whose layers are all latent attention,
@@ -394,6 +484,19 @@ _PARAM_DIMS.update({
 })
 
 
+# a state-space mixer's leaves and a latent expert layer's two projections
+# (one device: ``llm/config.py refuse_stateful``)
+_PARAM_DIMS.update({
+    "ssm_w_in": (None, "embed", None),
+    "ssm_w_out": (None, None, "embed"),
+    "ssm_conv_w": (None, None, None),
+    **dict.fromkeys(("ssm_conv_b", "ssm_dt_bias", "ssm_a_log", "ssm_d", "ssm_norm"),
+                    (None, None)),
+    "moe_latent_down": (None, "embed", None),
+    "moe_latent_up": (None, None, "embed"),
+})
+
+
 def param_logical_dims(path, leaf):
     """For ``ray_tpu.parallel.mesh.shard_params``: path -> logical dims."""
     name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
@@ -445,6 +548,25 @@ def _layer_keys(cfg: LlamaConfig) -> tuple:
     return base + ("w_gate", "w_up", "w_down")
 
 
+# the largest float32 draw ``init_params`` makes in one piece
+_DRAW_WHOLE_MAX_BYTES = 4 << 30
+# A state-space mixer's vectors, a value a head [n, H]: the step's bias so that
+# ``softplus(dt_bias)`` is log-uniform over Mamba-2's ``time_step_min`` ..
+# ``time_step_max`` (0.001 .. 0.1), the decay rate ``A = -exp(a_log)`` with A
+# uniform over 1 .. 16, the skip ``D`` one; the convolution's bias small.
+_SSM_VECTORS = {
+    "ssm_dt_bias": lambda k, shape: _inv_softplus(
+        jnp.exp(jax.random.uniform(k, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))),
+    "ssm_a_log": lambda k, shape: jnp.log(jax.random.uniform(k, shape, jnp.float32, 1.0, 16.0)),
+    "ssm_d": lambda k, shape: jnp.ones(shape, jnp.float32),
+    "ssm_conv_b": lambda k, shape: jax.random.normal(k, shape, jnp.float32) * 0.02,
+}
+
+
+def _inv_softplus(y):
+    return y + jnp.log(-jnp.expm1(-y))
+
+
 def init_params(key, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
     """Initialize params; if a mesh is given, each leaf is created directly
     with its NamedSharding (no host-side full copy — jit init per leaf)."""
@@ -469,6 +591,19 @@ def init_params(key, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
     for (name, shape), k in zip(sorted(shapes.items()), keys):
         if "norm" in name:
             maker = lambda shape=shape: jnp.ones(shape, cfg.dtype)
+        elif name in _SSM_VECTORS:
+            maker = lambda k=k, shape=shape, draw=_SSM_VECTORS[name]: draw(k, shape).astype(cfg.dtype)
+        elif math.prod(shape) * 4 > _DRAW_WHOLE_MAX_BYTES:
+            # a row of the leading axis at a time: the float32 draw of the
+            # whole leaf (7 GB for 5 layers of 128 experts of 1024 x 2688)
+            # does not fit beside the leaves already made
+            std = fan_in_of(name, shape) ** -0.5
+            maker = lambda k=k, shape=shape, std=std: jax.lax.map(
+                lambda kk: (jax.random.normal(kk, shape[1:], jnp.float32) * std).astype(cfg.dtype),
+                jax.random.split(k, shape[0]),
+            )
+            if mesh is None:
+                maker = jax.jit(maker)
         else:
             # the selection bias is a buffer the training moves: small and
             # not zero, so that the choice and the weights can differ
@@ -808,6 +943,14 @@ def init_kv_cache(cfg: LlamaConfig, batch_size: int, max_len: Optional[int] = No
     put the head axis inside, making every read a 256-byte stride: decode
     measured ~5x off the bandwidth roofline on v5e because of it).
 
+    ``k`` and ``v`` hold the attention layers alone (all of them, in every
+    model but one with blocks that have no attention). A model with
+    state-space layers has two more leaves, which are no stripes: a slot's
+    state and the tail of its convolution's inputs, a layer each, of one size
+    whatever the slot's length (``models/patterned.py ssm_cache_shapes``);
+    every leaf but ``length`` has the slot on axis 1, which is all that the
+    engine's programs that stack, unstack and copy slots know of them.
+
     One rule for every model: ``k`` and ``v`` are two rank-5 leaves whose
     ``D`` need not be equal. A latent-attention model has one key-value
     "head": ``k`` holds the rotated key all heads share, ``v`` the normed
@@ -819,16 +962,23 @@ def init_kv_cache(cfg: LlamaConfig, batch_size: int, max_len: Optional[int] = No
     take part of a lane tile (the v5e compiler: "slice shape along dimension
     4 must be aligned to tiling (128), but is 64")."""
     max_len = max_len or cfg.max_seq_len
-    lead = (cfg.n_layers, batch_size, cfg.n_kv_heads, max_len)
+    pl = patterned.plan(cfg)
+    lead = (pl.n_attention, batch_size, cfg.n_kv_heads, max_len)
     k_dim, v_dim = (
         (-(-cfg.qk_rope_dim // _LANES) * _LANES, cfg.kv_latent_rank) if cfg.kv_latent_rank
         else (cfg.head_dim, cfg.head_dim)
     )
-    return {
+    cache = {
         "k": jnp.zeros(lead + (k_dim,), cfg.dtype),
         "v": jnp.zeros(lead + (v_dim,), cfg.dtype),
         "length": jnp.zeros((batch_size,), jnp.int32),
     }
+    if pl.n_ssm:
+        cache.update({
+            name: jnp.zeros(shape, dtype)
+            for name, (shape, dtype) in patterned.ssm_cache_shapes(cfg, batch_size).items()
+        })
+    return cache
 
 
 def init_lora_stack(cfg: LlamaConfig, n_adapters: int, rank: int):

@@ -105,10 +105,37 @@ class Plan:
     attn_index: tuple
     mlp_index: tuple
     by_kind: bool
+    # a layer's row among the layers that have attention (its keys' and
+    # values' row in ``wk``, ``wv`` and the cache), any mixer (``attn_norm``)
+    # and a feed-forward (``mlp_norm``): the layer's own number in a model
+    # whose blocks all have attention and a feed-forward (``whole``)
+    kv_index: tuple = ()
+    mixer_index: tuple = ()
+    ffn_index: tuple = ()
 
     @property
     def tail_from(self) -> int:
         return self.lead + self.period * self.reps
+
+    @property
+    def n_attention(self) -> int:
+        return sum(t in _SCOPE_OF_KIND for t, _, _ in self.kinds)
+
+    @property
+    def n_ssm(self) -> int:
+        return sum(t == "ssm" for t, _, _ in self.kinds)
+
+    @property
+    def n_mixer(self) -> int:
+        return sum(t != "none" for t, _, _ in self.kinds)
+
+    @property
+    def n_ffn(self) -> int:
+        return sum(m != "none" for _, _, m in self.kinds)
+
+    @property
+    def whole(self) -> bool:
+        return self.n_attention == self.n_ffn == len(self.kinds)
 
     def leaf(self, name: str, kind: str) -> str:
         """The leaf that holds projection ``name`` of attention kind ``kind``."""
@@ -130,10 +157,18 @@ def plan(cfg) -> Plan:
             )
         kinds = [("full", cfg.n_heads, "sparse" if cfg.moe_experts else "dense")] * L
     for t, h, m in kinds:
-        if t not in _SCOPE_OF_KIND or m not in ("dense", "sparse"):
+        if t not in (*_SCOPE_OF_KIND, "ssm", "none") or m not in ("dense", "sparse", "none"):
             raise ValueError(f"unknown layer kind ({t!r}, {m!r})")
+        if t == m == "none":
+            raise ValueError("a block with neither a mixer nor a feed-forward")
         if h % cfg.n_kv_heads:
             raise ValueError(f"{h} query heads over {cfg.n_kv_heads} key-value heads")
+    if any(t == "ssm" for t, _, _ in kinds) and not (
+        cfg.ssm_heads and cfg.ssm_head_dim and cfg.ssm_state
+        and cfg.ssm_heads % cfg.ssm_groups == 0
+    ):
+        raise ValueError("ssm layers need ssm_heads (a multiple of ssm_groups), ssm_head_dim "
+                         "and ssm_state")
     for t in _SCOPE_OF_KIND:
         if len({h for kt, h, _ in kinds if kt == t}) > 1:
             raise ValueError(f"{t} attention layers differ in their query heads")
@@ -151,6 +186,10 @@ def plan(cfg) -> Plan:
         )
     if any(m == "sparse" for _, _, m in kinds) and not cfg.moe_experts:
         raise ValueError("sparse layers need moe_experts")
+    if cfg.moe_experts_held and not (
+        0 <= cfg.moe_experts_first <= cfg.moe_experts - cfg.moe_experts_held
+    ):
+        raise ValueError("moe_experts_first .. + moe_experts_held lie outside the router's experts")
     # the split that traces the fewest layer bodies
     best = None
     for lead in range(L):
@@ -165,36 +204,87 @@ def plan(cfg) -> Plan:
     _, lead, period, reps = best
     if reps == 1:  # nothing repeats: every layer its own body, no loop
         lead, period, reps = L, 1, 0
+    # a layer's row in each stack it has leaves in: counted by the key's kind
     seen: dict = {}
-    attn_index, mlp_index = [], []
+    rows = {name: [] for name in ("attn", "mlp", "kv", "mixer", "ffn")}
     for t, _, m in kinds:
-        attn_index.append(seen.setdefault(t, 0))
-        mlp_index.append(seen.setdefault(m, 0))
-        seen[t] += 1
-        seen[m] += 1
-    return Plan(lead, period, reps, tuple(kinds), tuple(attn_index), tuple(mlp_index),
-                bool(cfg.layer_types))
+        keys = {"attn": ("attn", t), "mlp": ("mlp", m), "kv": t in _SCOPE_OF_KIND,
+                "mixer": t != "none", "ffn": m != "none"}
+        for name, key in keys.items():
+            key = (name, key)
+            rows[name].append(seen.setdefault(key, 0))
+            seen[key] += 1
+    return Plan(lead, period, reps, tuple(kinds), tuple(rows["attn"]), tuple(rows["mlp"]),
+                bool(cfg.layer_types), tuple(rows["kv"]), tuple(rows["mixer"]),
+                tuple(rows["ffn"]))
 
 
 def _moe_shapes(cfg, n: int) -> dict[str, tuple]:
     """The leaves of ``n`` stacked expert layers."""
     e, E, f = cfg.d_model, cfg.moe_experts, cfg.moe_d_ff or cfg.d_ff
+    # the router scores every expert; the banks hold this device's, in the
+    # width the experts work in (the latent's where there is one). A relu^2
+    # expert has no gate matrix
+    held, w = cfg.moe_experts_held or E, cfg.moe_latent_dim or e
+    gated = cfg.moe_activation == "swiglu"
     shapes = {
         "moe_router": (n, e, E),
-        "moe_w_gate": (n, E, e, f),
-        "moe_w_up": (n, E, e, f),
-        "moe_w_down": (n, E, f, e),
+        **({"moe_w_gate": (n, held, w, f)} if gated else {}),
+        "moe_w_up": (n, held, w, f),
+        "moe_w_down": (n, held, f, w),
     }
     if cfg.moe_scoring == "sigmoid":
         shapes["moe_router_bias"] = (n, E)
+    if cfg.moe_latent_dim:
+        shapes.update({"moe_latent_down": (n, e, w), "moe_latent_up": (n, w, e)})
     if cfg.moe_shared_d_ff:
         fs = cfg.moe_shared_d_ff
         shapes.update({
-            "moe_shared_gate": (n, e, fs),
+            **({"moe_shared_gate": (n, e, fs)} if gated else {}),
             "moe_shared_up": (n, e, fs),
             "moe_shared_down": (n, fs, e),
         })
     return shapes
+
+
+def ssm_dims(cfg) -> dict:
+    """The widths of a state-space mixer: ``inner`` (heads x head width, what
+    the gate ``z`` and the input ``x`` are wide), ``bc`` (one of B and C: groups
+    x state), ``conv`` (what the convolution runs over: x, B and C), ``proj``
+    (the input projection: z, then x B C, then a step a head)."""
+    inner, bc = cfg.ssm_heads * cfg.ssm_head_dim, cfg.ssm_groups * cfg.ssm_state
+    return {"inner": inner, "bc": bc, "conv": inner + 2 * bc,
+            "proj": 2 * inner + 2 * bc + cfg.ssm_heads}
+
+
+def _ssm_shapes(cfg, n: int) -> dict[str, tuple]:
+    """The leaves of ``n`` stacked state-space mixers. The convolution's
+    weight lies [taps, channels] (the published [channels, 1, taps] with the
+    channels on the lanes)."""
+    d, e, H = ssm_dims(cfg), cfg.d_model, cfg.ssm_heads
+    return {
+        "ssm_w_in": (n, e, d["proj"]),
+        "ssm_conv_w": (n, cfg.ssm_conv, d["conv"]),
+        "ssm_conv_b": (n, d["conv"]),
+        "ssm_dt_bias": (n, H),
+        "ssm_a_log": (n, H),
+        "ssm_d": (n, H),
+        "ssm_norm": (n, d["inner"]),
+        "ssm_w_out": (n, d["inner"], e),
+    }
+
+
+def ssm_cache_shapes(cfg, batch_size: int) -> dict:
+    """name -> (shape, dtype) of what the cache holds beside keys and values
+    for a model with state-space layers, a row a layer and slot: the state
+    (float32: it sums a sequence's steps) and the last ``ssm_conv - 1`` inputs
+    of the convolution (channels on the lanes)."""
+    n = plan(cfg).n_ssm
+    return {
+        "ssm_state": ((n, batch_size, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+                      jnp.float32),
+        "ssm_conv": ((n, batch_size, cfg.ssm_conv - 1, ssm_dims(cfg)["conv"]), cfg.dtype),
+    }
 
 
 def _param_shapes(cfg) -> dict[str, tuple]:
@@ -204,12 +294,14 @@ def _param_shapes(cfg) -> dict[str, tuple]:
     shapes = {
         "embed": (v, e),
         "final_norm": (e,),
-        "attn_norm": (L, e),
-        "mlp_norm": (L, e),
+        "attn_norm": (pl.n_mixer, e),
+        "mlp_norm": (pl.n_ffn, e),
     }
     if not cfg.kv_latent_rank:
-        shapes.update({"wk": (L, e, kv, hd), "wv": (L, e, kv, hd)})
-    for kind, h in {t: h for t, h, _ in pl.kinds}.items():
+        shapes.update({"wk": (pl.n_attention, e, kv, hd), "wv": (pl.n_attention, e, kv, hd)})
+    if pl.n_ssm:
+        shapes.update(_ssm_shapes(cfg, pl.n_ssm))
+    for kind, h in {t: h for t, h, _ in pl.kinds if t in _SCOPE_OF_KIND}.items():
         n = sum(t == kind for t, _, _ in pl.kinds)
         if kind == "latent":
             r, nope, rope, vd = (cfg.kv_latent_rank, cfg.qk_nope_dim, cfg.qk_rope_dim,
@@ -232,8 +324,9 @@ def _param_shapes(cfg) -> dict[str, tuple]:
         f = cfg.d_ff
         shapes.update({"w_gate": (n_dense, e, f), "w_up": (n_dense, e, f),
                        "w_down": (n_dense, f, e)})
-    if n_dense < L:
-        shapes.update(_moe_shapes(cfg, L - n_dense))
+    n_sparse = sum(m == "sparse" for _, _, m in pl.kinds)
+    if n_sparse:
+        shapes.update(_moe_shapes(cfg, n_sparse))
     if not cfg.tie_embeddings:
         shapes["unembed"] = (e, v)
     return shapes
@@ -293,6 +386,8 @@ def _project_logits(x, params, cfg, mesh: Optional[Mesh]):
 def _shared_expert(p, h):
     """The expert every token passes through, ungated. h: [..., e]."""
     with scope("shared_expert"):
+        if "moe_shared_gate" not in p:  # a relu^2 expert: two matrices
+            return jnp.square(jax.nn.relu(h @ p["moe_shared_up"])) @ p["moe_shared_down"]
         ff = jax.nn.silu(h @ p["moe_shared_gate"]) * (h @ p["moe_shared_up"])
         return ff @ p["moe_shared_down"]
 
@@ -301,17 +396,30 @@ def _shared_expert(p, h):
 # --------------------------------- pieces of the path through a cache
 
 
+# what a cache holds beside keys and values for a model with state-space
+# layers (``ssm_cache_shapes``), in the order ``decode_forward`` carries them
+SSM_LEAVES = ("ssm_state", "ssm_conv")
+
 # Rows of one call's routing counts (``_moe_decode_ffn``; summed over expert
 # layers by the caller): expert layers run, (token, expert) assignments,
 # experts that got at least one token, and the fullest expert's tokens.
 MOE_STATS = ("layer_steps", "assignments", "experts_touched", "max_expert_load")
 
 
+def moe_stats_names(cfg) -> tuple:
+    """``MOE_STATS``, and for a model that holds a share of its experts
+    (``moe_experts_held``) the assignments that fell on the held ones:
+    ``assignments`` counts all the router made, ``experts_touched`` and
+    ``max_expert_load`` the held experts'."""
+    return MOE_STATS + (("assignments_held",) if cfg.moe_experts_held else ())
+
+
 def _moe_decode_ffn(params, row, h, cfg):
     """Dropless routed expert FFN for the serving path, and for ``forward``
     of a model whose layers are not alike. ``params`` holds the stacked
     ``moe_*`` leaves, ``row`` (static or traced) is this layer's row in them.
-    h: [B, T, e] -> ([B, T, e], routing counts int32 [4], ``MOE_STATS``).
+    h: [B, T, e] -> ([B, T, e], routing counts int32 [4], ``MOE_STATS``; [5]
+    for a share of the experts, ``moe_stats_names``).
 
     Inference must never drop tokens (a capacity overflow at prefill would
     silently corrupt the prompt — the reference's serving engine is likewise
@@ -335,12 +443,23 @@ def _moe_decode_ffn(params, row, h, cfg):
     a layer at those widths).
 
     Numerically identical to ``moe_dense`` whenever its capacity does not
-    overflow, which is what the decode-vs-forward exactness test pins."""
+    overflow, which is what the decode-vs-forward exactness test pins.
+
+    Three more forms of the same layer, by the config: an expert of two
+    matrices with a squared ReLU (``moe_activation``), the routed experts in a
+    latent between a down- and an up-projection (``moe_latent_dim``; scope
+    ``moe_latent_proj``), and a share of the experts held here
+    (``moe_experts_held``; a fifth count, ``moe_stats_names``)."""
     from ray_tpu.ops.grouped_matmul import grouped_matmul
     from ray_tpu.parallel.moe import topk_gates
 
     B, T, e = h.shape
     E, k = cfg.moe_experts, cfg.moe_top_k
+    # a device's share of the experts (``moe_experts_held`` of the router's
+    # E, from ``moe_experts_first``): the router chooses over all E, an
+    # assignment to an absent expert is sorted behind the held ones, belongs
+    # to no group and adds nothing
+    held, first = cfg.moe_experts_held or E, cfg.moe_experts_first
     g = h.reshape(B * T, e)
     G = g.shape[0]
     with scope("router"):
@@ -352,30 +471,49 @@ def _moe_decode_ffn(params, row, h, cfg):
         _, gate_vals, gate_idx = topk_gates(router, g.astype(jnp.float32), k)
         # tokens an expert: a one-hot sum (a scatter-add is slow on the chip)
         load = jax.nn.one_hot(gate_idx.reshape(-1), E, dtype=jnp.int32).sum(axis=0)
+        chosen = gate_idx.reshape(-1)
+        if cfg.moe_experts_held:
+            load = load[first:first + held]
+            chosen = jnp.where((chosen >= first) & (chosen < first + held), chosen - first, held)
+        n_held = load.sum()  # assignments that fell on the experts held here
         stats = jnp.stack([
             jnp.int32(1), jnp.int32(G * k), (load > 0).sum(dtype=jnp.int32), load.max(),
+            *((n_held,) if cfg.moe_experts_held else ()),
         ])
+    if cfg.moe_latent_dim:
+        with scope("moe_latent_proj"):
+            src = g @ params["moe_latent_down"][row]
+    else:
+        src = g
     with scope("experts"):
-        order = jnp.argsort(gate_idx.reshape(-1))  # assignments by expert
-        rows = g[order // k]  # [G*k, e]: each assignment's token
-        n = params["moe_w_gate"].shape[0]
+        order = jnp.argsort(chosen)  # assignments by expert
+        rows = src[order // k]  # [G*k, width]: each assignment's token
+        n = params["moe_w_up"].shape[0]
         sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((n * E,), jnp.int32), load, (row * E,)
+            jnp.zeros((n * held,), jnp.int32), load, (row * held,)
         )
 
         def bank(name):
             w = params[name]
-            return w.reshape((n * E,) + w.shape[2:])
+            return w.reshape((n * held,) + w.shape[2:])
 
-        gate = grouped_matmul(rows, bank("moe_w_gate"), sizes)
-        up = grouped_matmul(rows, bank("moe_w_up"), sizes)
-        out = grouped_matmul(
-            jax.nn.silu(gate) * up, bank("moe_w_down"), sizes, jnp.float32
-        )
+        if cfg.moe_activation == "relu2":
+            act = jnp.square(jax.nn.relu(grouped_matmul(rows, bank("moe_w_up"), sizes)))
+        else:
+            gate = grouped_matmul(rows, bank("moe_w_gate"), sizes)
+            up = grouped_matmul(rows, bank("moe_w_up"), sizes)
+            act = jax.nn.silu(gate) * up
+        out = grouped_matmul(act, bank("moe_w_down"), sizes, jnp.float32)
+        if cfg.moe_experts_held:
+            # the kernel leaves the rows no group owns unwritten
+            out = jnp.where((jnp.arange(G * k) < n_held)[:, None], out, 0.0)
         # back to token order: a gather, not a scatter-add
-        out = out[jnp.argsort(order)].reshape(G, k, e)
+        out = out[jnp.argsort(order)].reshape(G, k, src.shape[-1])
         y = jnp.einsum("gkd,gk->gd", out, gate_vals) * cfg.moe_routed_scale
         y = y.astype(g.dtype)
+    if cfg.moe_latent_dim:
+        with scope("moe_latent_proj"):
+            y = y @ params["moe_latent_up"][row]
     if cfg.moe_shared_d_ff:
         y = y + _shared_expert(
             {n: params[n][row] for n in params if n.startswith("moe_shared_")}, g
@@ -549,10 +687,13 @@ def _rope(x, positions, inv_freq, factor, interleave: bool = False):
 class _Layer:
     """One layer's static kind and its (static or traced) indices."""
 
-    def __init__(self, pl: Plan, l_static: int, l, attn_i, mlp_i):
-        self.kind, _, mlp = pl.kinds[l_static]
-        self.sparse = mlp == "sparse"
+    def __init__(self, pl: Plan, l_static: int, l, attn_i, mlp_i, rows=None):
+        self.kind, _, self.mlp = pl.kinds[l_static]
+        self.sparse = self.mlp == "sparse"
         self.l, self.attn_i, self.mlp_i = l, attn_i, mlp_i
+        # the layer's row among the layers with attention, with a mixer, with
+        # a feed-forward: its own number where every block has all of them
+        self.kv_i, self.mixer_i, self.ffn_i = (l, l, l) if pl.whole else rows
         self.wq, self.wo, self.wg = (pl.leaf(n, self.kind) for n in ("wq", "wo", "wg"))
         self.by_kind = pl.by_kind
         self.latent = self.kind == "latent"
@@ -570,8 +711,8 @@ def _qkv(params, lay: _Layer, h, positions, cfg, loras=None, adapter_ids=None):
     inv_freq, factor = rope_inv_freq(cfg, lay.kind)
     with scope("attn_qkv"):
         q = jnp.einsum("bte,ehd->bthd", h, params[lay.wq][lay.attn_i])
-        k = jnp.einsum("bte,ehd->bthd", h, params["wk"][lay.l])
-        v = jnp.einsum("bte,ehd->bthd", h, params["wv"][lay.l])
+        k = jnp.einsum("bte,ehd->bthd", h, params["wk"][lay.kv_i])
+        v = jnp.einsum("bte,ehd->bthd", h, params["wv"][lay.kv_i])
         if loras is not None:
             lp = {n: loras[n][lay.l] for n in ("wq_a", "wq_b", "wv_a", "wv_b")}
             q = q + jnp.einsum(
@@ -584,8 +725,9 @@ def _qkv(params, lay: _Layer, h, positions, cfg, loras=None, adapter_ids=None):
                 jnp.einsum("bte,ber->btr", h, lp["wv_a"][adapter_ids]),
                 lp["wv_b"][adapter_ids],
             )
-        q = _rope(q, positions, inv_freq, factor)
-        k = _rope(k, positions, inv_freq, factor)
+        if cfg.attn_rope:
+            q = _rope(q, positions, inv_freq, factor)
+            k = _rope(k, positions, inv_freq, factor)
     return q, k, v
 
 
@@ -655,11 +797,81 @@ def _attn_out(params, lay: _Layer, x, h, attn, cfg, from_latent: bool = False):
         return x + jnp.einsum("bthd,hde->bte", attn, params[lay.wo][lay.attn_i])
 
 
+def _ssm_mixer(params, lay: _Layer, h, state_all, conv_all, valid, cfg):
+    """A state-space mixer (Mamba-2) on the normed h [B, T, e] of the rows'
+    next tokens, from and into the carried state ``state_all`` [n, B, H, P, N]
+    and convolution tails ``conv_all`` [n, B, taps - 1, channels] at this
+    layer's row. ``valid`` [B, T] (or None: all) marks a row's real tokens, a
+    prefix of it: a token that is none gets a step of 0, which leaves the
+    state where the row's last real token put it, and the tail is cut where
+    the row ends. One token a row takes the recurrence's own line
+    (``ops/ssm.py ssm_step``), more the chunked scan (``ssm_scan``): the same
+    state either way. Returns (the mixer's output [B, T, e], the two carried
+    leaves).
+
+    Its scopes lie inside the attention's three, by what the work is (input
+    projections, the mixing itself, gate and output projection), under
+    ``ssm_mixer``; ``ssm_conv`` and ``ssm_scan`` or ``ssm_step`` inside
+    ``attn_core/ssm_mixer``."""
+    from ray_tpu.ops.ssm import causal_conv, ssm_scan, ssm_step
+
+    i = lay.attn_i  # the row among the state-space layers
+    d = ssm_dims(cfg)
+    Bsz, T, _ = h.shape
+    H, P, N, G = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups
+    f32 = jnp.float32
+    with scope("attn_qkv"), scope("ssm_mixer"):
+        proj = jnp.einsum("bte,ef->btf", h, params["ssm_w_in"][i])
+        z = proj[..., : d["inner"]]
+        xbc = proj[..., d["inner"]: d["inner"] + d["conv"]]
+        dt = jax.nn.softplus(proj[..., -H:].astype(f32) + params["ssm_dt_bias"][i].astype(f32))
+        if valid is not None:
+            dt = jnp.where(valid[..., None], dt, 0.0)
+    with scope("attn_core"), scope("ssm_mixer"):
+        with scope("ssm_conv"):
+            conv, seen = causal_conv(
+                conv_all[i], xbc, params["ssm_conv_w"][i], params["ssm_conv_b"][i])
+            xbc = jax.nn.silu(conv)
+            # the next tail: the taps - 1 inputs up to the row's last real
+            # token. Every token real (a decode step): the last ones, a plain
+            # slice; a padded row's end is its own, which makes it a gather
+            taps = cfg.ssm_conv - 1
+            if valid is None:
+                tail = seen[:, T:]
+            else:
+                tail = jax.vmap(
+                    lambda row, at: jax.lax.dynamic_slice(row, (at, 0), (taps, row.shape[1]))
+                )(seen, valid.sum(axis=1, dtype=jnp.int32))
+            conv_all = jax.lax.dynamic_update_index_in_dim(
+                conv_all, tail.astype(conv_all.dtype), i, 0)
+        x = xbc[..., : d["inner"]].reshape(Bsz, T, H, P)
+        b_in = xbc[..., d["inner"]: d["inner"] + d["bc"]].reshape(Bsz, T, G, N)
+        c_in = xbc[..., d["inner"] + d["bc"]:].reshape(Bsz, T, G, N)
+        a = -jnp.exp(params["ssm_a_log"][i].astype(f32))
+        skip = params["ssm_d"][i]
+        state = jax.lax.dynamic_index_in_dim(state_all, i, 0, keepdims=False)
+        if T == 1:
+            with scope("ssm_step"):
+                y, state = ssm_step(state, x[:, 0], dt[:, 0], a, b_in[:, 0], c_in[:, 0], skip)
+                y = y[:, None]
+        else:
+            with scope("ssm_scan"):
+                y, state = ssm_scan(state, x, dt, a, b_in, c_in, skip, cfg.ssm_chunk)
+        state_all = jax.lax.dynamic_update_index_in_dim(state_all, state, i, 0)
+    with scope("attn_out"), scope("ssm_mixer"):
+        # gate, then a norm a group of heads (the gate before the norm)
+        y = y.reshape(Bsz, T, G, d["inner"] // G) * jax.nn.silu(z.astype(f32)).reshape(
+            Bsz, T, G, d["inner"] // G)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.rms_eps)
+        y = y.reshape(Bsz, T, d["inner"]).astype(h.dtype) * params["ssm_norm"][i]
+        return jnp.einsum("btf,fe->bte", y, params["ssm_w_out"][i]), state_all, conv_all
+
+
 def _feed_forward(params, lay: _Layer, x, cfg):
     """x + feed-forward(norm(x)), and the layer's routing counts: zeros for
     a dense layer of a model that has expert layers, None in a model with
     none (which carries no counts)."""
-    h = _rmsnorm(x, params["mlp_norm"][lay.l], cfg.rms_eps, cfg.fused_rmsnorm)
+    h = _rmsnorm(x, params["mlp_norm"][lay.ffn_i], cfg.rms_eps, cfg.fused_rmsnorm)
     if lay.sparse:
         with scope("moe_ffn"):
             y, stats = _moe_decode_ffn(params, lay.mlp_i, h, cfg)
@@ -680,8 +892,10 @@ def _run_layers(cfg, layer_fn, carry):
     from those copies alone at 3B/B=16 on v5e)."""
     pl = plan(cfg)
 
+    tables = (pl.kv_index, pl.mixer_index, pl.ffn_index)
+
     def static(l):
-        return _Layer(pl, l, l, pl.attn_index[l], pl.mlp_index[l])
+        return _Layer(pl, l, l, pl.attn_index[l], pl.mlp_index[l], [t[l] for t in tables])
 
     for l in range(pl.lead):
         carry = layer_fn(static(l), carry)
@@ -690,6 +904,10 @@ def _run_layers(cfg, layer_fn, carry):
         # a kind's rows advance by its count in one period
         step_attn = [sum(pl.kinds[m][0] == pl.kinds[l][0] for m in first) for l in first]
         step_mlp = [sum(pl.kinds[m][2] == pl.kinds[l][2] for m in first) for l in first]
+        # rows among the layers with attention, a mixer, a feed-forward
+        step_rows = [sum(pl.kinds[m][0] in _SCOPE_OF_KIND for m in first),
+                     sum(pl.kinds[m][0] != "none" for m in first),
+                     sum(pl.kinds[m][2] != "none" for m in first)]
 
         def body(i, carry):
             for j, l in enumerate(first):
@@ -697,6 +915,7 @@ def _run_layers(cfg, layer_fn, carry):
                     pl, l, l + i * pl.period,
                     pl.attn_index[l] + i * step_attn[j],
                     pl.mlp_index[l] + i * step_mlp[j],
+                    [t[l] + i * step for t, step in zip(tables, step_rows)],
                 )
                 carry = layer_fn(lay, carry)
             return carry
@@ -718,6 +937,11 @@ def forward_hidden(params, tokens, cfg, mesh: Optional[Mesh] = None, positions=N
     whole-sequence path is ``models/llama.py forward_hidden``."""
     if mesh is not None and any(s > 1 for s in mesh.shape.values()):
         raise NotImplementedError("models/patterned.py runs on one device")
+    if not plan(cfg).whole:
+        raise NotImplementedError(
+            "models/patterned.py forward_hidden: blocks that are a mixer or a feed-forward "
+            "alone, and state-space mixers, run through the cache only (prefill, decode_step)"
+        )
     B, T = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
@@ -919,7 +1143,7 @@ def _cache_reader(cfg, params, cache, positions, kinds):
         lo = {"full": jnp.zeros_like(hi), "sliding": jnp.maximum(hi - W, 0)}
 
         def read(q, ck_all, cv_all, lay):
-            return decode_attention(q[:, 0], ck_all, cv_all, lay.l, lo[lay.kind], hi)[:, None]
+            return decode_attention(q[:, 0], ck_all, cv_all, lay.kv_i, lo[lay.kind], hi)[:, None]
 
         return read
 
@@ -940,10 +1164,10 @@ def _cache_reader(cfg, params, cache, positions, kinds):
 
     def read(q, ck_all, cv_all, lay):
         if whole[lay.kind]:
-            return _grouped_attention(q, ck_all[lay.l], cv_all[lay.l], masks[lay.kind])
+            return _grouped_attention(q, ck_all[lay.kv_i], cv_all[lay.kv_i], masks[lay.kind])
         return _grouped_attention(
-            q, _window_slice(ck_all, lay.l, first, span),
-            _window_slice(cv_all, lay.l, first, span), window_mask,
+            q, _window_slice(ck_all, lay.kv_i, first, span),
+            _window_slice(cv_all, lay.kv_i, first, span), window_mask,
         )
 
     return read
@@ -1009,30 +1233,50 @@ def decode_forward(
         read, from_latent = _latent_reader(cfg, params, cache, positions)
     else:
         read, from_latent = _cache_reader(cfg, params, cache, positions, kinds), False
+    if "ssm" in kinds and any(
+        jax.typeof(x).sharding.mesh.size > 1 for x in (cache["k"], *jax.tree.leaves(params))
+    ):
+        raise NotImplementedError(
+            "models/patterned.py: state-space layers run on one device (no rule places "
+            "their state or their projections on a mesh)"
+        )
+
+    # a model with routed experts carries its routing counts beside x, one
+    # with state-space layers their two leaves behind those
+    stats0 = (jnp.zeros((len(moe_stats_names(cfg)),), jnp.int32),) if cfg.moe_experts else ()
+    ssm0 = tuple(cache[name] for name in SSM_LEAVES) if "ssm" in kinds else ()
 
     def layer(lay: _Layer, carry):
-        x, ck_all, cv_all, *stats = carry
-        h = _rmsnorm(x, params["attn_norm"][lay.l], cfg.rms_eps, cfg.fused_rmsnorm)
-        if lay.latent:  # k: the shared rotated key; v: the normed latent
-            q, k, v = _latent_qkv(params, lay, h, positions, cfg)
-        else:
-            q, k, v = _qkv(params, lay, h, positions, cfg, loras, adapter_ids)
-        with scope("kv_write"):
-            if lay.latent:  # zeros up to the cache's row of whole lane tiles (``init_kv_cache``)
-                k = jnp.pad(k, ((0, 0),) * 3 + ((0, ck_all.shape[-1] - k.shape[-1]),))
-            # the cache is head-major: the new [B, T, K, D] rows go in as [B, K, T, D]
-            ck_all = write(ck_all, k.transpose(0, 2, 1, 3), lay.l)
-            cv_all = write(cv_all, v.transpose(0, 2, 1, 3), lay.l)
-        with scope("attn_core"), lay.inner_scope():
-            attn = read(q, ck_all, cv_all, lay)
-        x = _attn_out(params, lay, x, h, attn, cfg, from_latent)
-        x, layer_stats = _feed_forward(params, lay, x, cfg)
-        return (x, ck_all, cv_all, *(s + layer_stats for s in stats))
+        x, ck_all, cv_all, *rest = carry
+        stats, ssm = rest[:len(stats0)], rest[len(stats0):]
+        if lay.kind == "ssm":
+            h = _rmsnorm(x, params["attn_norm"][lay.mixer_i], cfg.rms_eps, cfg.fused_rmsnorm)
+            y, *ssm = _ssm_mixer(params, lay, h, *ssm, valid, cfg)
+            x = x + y
+        elif lay.kind != "none":
+            h = _rmsnorm(x, params["attn_norm"][lay.mixer_i], cfg.rms_eps, cfg.fused_rmsnorm)
+            if lay.latent:  # k: the shared rotated key; v: the normed latent
+                q, k, v = _latent_qkv(params, lay, h, positions, cfg)
+            else:
+                q, k, v = _qkv(params, lay, h, positions, cfg, loras, adapter_ids)
+            with scope("kv_write"):
+                if lay.latent:  # zeros up to the cache's row of whole lane tiles (``init_kv_cache``)
+                    k = jnp.pad(k, ((0, 0),) * 3 + ((0, ck_all.shape[-1] - k.shape[-1]),))
+                # the cache is head-major: the new [B, T, K, D] rows go in as [B, K, T, D]
+                ck_all = write(ck_all, k.transpose(0, 2, 1, 3), lay.kv_i)
+                cv_all = write(cv_all, v.transpose(0, 2, 1, 3), lay.kv_i)
+            with scope("attn_core"), lay.inner_scope():
+                attn = read(q, ck_all, cv_all, lay)
+            x = _attn_out(params, lay, x, h, attn, cfg, from_latent)
+        if lay.mlp != "none":
+            x, layer_stats = _feed_forward(params, lay, x, cfg)
+            stats = [s + layer_stats for s in stats]
+        return (x, ck_all, cv_all, *stats, *ssm)
 
-    # a model with routed experts carries its routing counts beside x
-    stats0 = (jnp.zeros((len(MOE_STATS),), jnp.int32),) if cfg.moe_experts else ()
-    x, new_k, new_v, *stats = _run_layers(cfg, layer, (x, cache["k"], cache["v"], *stats0))
-    new_cache = {"k": new_k, "v": new_v, "length": cache["length"] + T}
+    x, new_k, new_v, *rest = _run_layers(cfg, layer, (x, cache["k"], cache["v"], *stats0, *ssm0))
+    stats = rest[:len(stats0)]
+    new_cache = {"k": new_k, "v": new_v, "length": cache["length"] + T,
+                 **dict(zip(SSM_LEAVES, rest[len(stats0):]))}
     _ride_stats(cache, new_cache, stats)
     if not with_logits:
         return None, new_cache

@@ -523,6 +523,73 @@ def test_latent_programs_hold_nothing_as_long_as_the_stripe_and_copy_no_leaf(
     assert relays(compiled("decode_step").as_text())
 
 
+# ---- blocks that keep a state a slot (Nemotron-3-Super's cut) -----------------
+
+
+def _state_space_cut():
+    from ray_tpu.models.llama import LlamaConfig
+
+    return LlamaConfig.nemotron3_super(
+        n_layers=11, moe_experts_held=128, vocab_size=32768, max_seq_len=2048)
+
+
+def test_state_space_decode_step_moves_its_state_where_it_lies(
+        one_chip, no_compile_cache, native_kernels):
+    """The Nemotron-3-Super cell's decode step (64 slots of 2,048; 5 Mamba-2
+    blocks, 5 expert blocks holding 128 of 512 experts, one GQA block, at
+    published widths) compiles for the chip beside 9.3 GB of weights: the 1.3
+    GB of float32 state is updated in the donated cache (no copy of the leaf,
+    temporaries far under one layer's 0.27 GB), the held banks go through the
+    grouped-matmul kernels whole, and the attention block reads its stripe
+    through the decode kernel."""
+    fn, args = _served_programs(_state_space_cut(), 64, 2048, one_chip)["decode_step"]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    state = "f32[5,64,128,64,128]"
+    assert [line.strip()[:120] for line in text.splitlines()
+            if " copy(" in line and line.split(" = ", 1)[-1].startswith(state)] == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 128e6
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("moe_ffn/experts" in line for line in kernels) >= 2  # up and down, relu^2 between
+    assert sum("attn_core" in line for line in kernels) == 1
+    for scope in ("ssm_mixer/ssm_step", "ssm_mixer/ssm_conv", "moe_ffn/moe_latent_proj"):
+        assert scope in text, scope
+    # every token of a decode step is real: the convolution's next tail is a
+    # slice of its inputs, not a gather by each row's own end
+    assert [line.strip()[:120] for line in text.splitlines()
+            if " gather(" in line and "ssm_conv" in line] == []
+
+
+def test_state_space_final_chunk_fits_at_its_widest(one_chip, no_compile_cache, native_kernels):
+    """The cell's widest final chunk (1,024 tokens into one stripe, the
+    chunked scan over eight 128-token chunks a block): temporaries under 0.6
+    GB beside the weights and a 1.5 GB pool."""
+    from ray_tpu.models.llama import prefill
+
+    cfg = _state_space_cut()
+    _, (params, stripe, _, n, s) = _served_programs(cfg, 64, 2048, one_chip)["chunk_final"]
+    tokens = jax.ShapeDtypeStruct((1, 1024), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda p, o, t, n, s: prefill(p, o, t, cfg, lengths=n, start_pos=s), donate_argnums=(1,)
+    ).lower(params, stripe, tokens, n, s).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 600e6
+    assert "ssm_mixer/ssm_scan" in compiled.as_text()
+
+
+@pytest.mark.parametrize("served", sorted(_SERVED) + ["kanana-2-30b-a3b-serve-l5"])
+def test_the_other_families_decode_steps_hold_nothing_of_the_state_space_path(
+        served, one_chip, no_compile_cache, native_kernels):
+    """A model whose blocks all have attention and a feed-forward carries keys,
+    values and lengths alone through its decode step: no state-space scope, no
+    latent projection of experts, no fifth routing count."""
+    cfg = _served_config(served)
+    slots, stripe = {"kanana-2-30b-a3b-serve-l5": (24, 24576)}.get(served) or _SERVED[served][:2]
+    fn, args = _served_programs(cfg, slots, stripe, one_chip)["decode_step"]
+    assert set(args[1]) == {"k", "v", "length"}
+    text = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()
+    assert "ssm_" not in text and "moe_latent_proj" not in text
+
+
 # ---- middle chunks of several rows (``llm/engine.py programs``) -------------
 
 
