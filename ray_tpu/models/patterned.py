@@ -804,16 +804,16 @@ def _ssm_mixer(params, lay: _Layer, h, state_all, conv_all, valid, cfg):
     layer's row. ``valid`` [B, T] (or None: all) marks a row's real tokens, a
     prefix of it: a token that is none gets a step of 0, which leaves the
     state where the row's last real token put it, and the tail is cut where
-    the row ends. One token a row takes the recurrence's own line
-    (``ops/ssm.py ssm_step``), more the chunked scan (``ssm_scan``): the same
-    state either way. Returns (the mixer's output [B, T, e], the two carried
-    leaves).
+    the row ends. One token a row takes the recurrence's own line on the leaf
+    where it lies (``ops/ssm.py ssm_step_in_place``: a kernel where the state
+    tiles, ``ssm_step`` where not), more the chunked scan (``ssm_scan``): the
+    same state either way. Returns (the output [B, T, e], the two leaves).
 
     Its scopes lie inside the attention's three, by what the work is (input
     projections, the mixing itself, gate and output projection), under
     ``ssm_mixer``; ``ssm_conv`` and ``ssm_scan`` or ``ssm_step`` inside
     ``attn_core/ssm_mixer``."""
-    from ray_tpu.ops.ssm import causal_conv, ssm_scan, ssm_step
+    from ray_tpu.ops.ssm import causal_conv, ssm_scan, ssm_step_in_place
 
     i = lay.attn_i  # the row among the state-space layers
     d = ssm_dims(cfg)
@@ -849,15 +849,15 @@ def _ssm_mixer(params, lay: _Layer, h, state_all, conv_all, valid, cfg):
         c_in = xbc[..., d["inner"] + d["bc"]:].reshape(Bsz, T, G, N)
         a = -jnp.exp(params["ssm_a_log"][i].astype(f32))
         skip = params["ssm_d"][i]
-        state = jax.lax.dynamic_index_in_dim(state_all, i, 0, keepdims=False)
-        if T == 1:
+        if T == 1:  # a row handed out of the leaf would be a copy out and a copy in
             with scope("ssm_step"):
-                y, state = ssm_step(state, x[:, 0], dt[:, 0], a, b_in[:, 0], c_in[:, 0], skip)
-                y = y[:, None]
+                y, state_all = ssm_step_in_place(
+                    state_all, i, x[:, 0], dt[:, 0], a, b_in[:, 0], c_in[:, 0], skip)
         else:
+            state = jax.lax.dynamic_index_in_dim(state_all, i, 0, keepdims=False)
             with scope("ssm_scan"):
                 y, state = ssm_scan(state, x, dt, a, b_in, c_in, skip, cfg.ssm_chunk)
-        state_all = jax.lax.dynamic_update_index_in_dim(state_all, state, i, 0)
+            state_all = jax.lax.dynamic_update_index_in_dim(state_all, state, i, 0)
     with scope("attn_out"), scope("ssm_mixer"):
         # gate, then a norm a group of heads (the gate before the norm)
         y = y.reshape(Bsz, T, G, d["inner"] // G) * jax.nn.silu(z.astype(f32)).reshape(

@@ -59,8 +59,10 @@ def native_kernels(monkeypatch):
 
     import ray_tpu.ops.decode_attention  # noqa: F401 — nor this one
 
+    import ray_tpu.ops.ssm  # noqa: F401 — nor this one
+
     for name in ("ray_tpu.ops.rmsnorm", "ray_tpu.ops.quant", "ray_tpu.ops.grouped_matmul",
-                 "ray_tpu.ops.decode_attention"):
+                 "ray_tpu.ops.decode_attention", "ray_tpu.ops.ssm"):
         monkeypatch.setattr(sys.modules[name], "interpret", lambda: False)
 
 
@@ -152,6 +154,23 @@ def _latent_decode_attention(q_rope, q_latent, ck_all, cv_all, layer, lo, hi):
     return latent_decode_attention(q_rope, q_latent, ck_all, cv_all, layer, lo, hi, 192 ** -0.5)
 
 
+def _ssm_step_in_place(state_all, layer, x, dt, a, B, C, D):
+    from ray_tpu.ops.ssm import ssm_step_in_place
+
+    return ssm_step_in_place(state_all, layer, x, dt, a, B, C, D)
+
+
+def _ssm_step_shapes(layers=5, slots=64, heads=128, width=64, state=128, groups=8):
+    """One new token a slot on the Nemotron-3-Super cell's stacked state: 128
+    heads of [64, 128] float32 in 8 groups, the layer's row a scalar."""
+    f32 = jnp.float32
+    return (
+        ((layers, slots, heads, width, state), f32), ((), jnp.int32), ((slots, heads, width), f32),
+        ((slots, heads), f32), ((heads,), f32), ((slots, groups, state), f32),
+        ((slots, groups, state), f32), ((heads,), f32),
+    )
+
+
 def _latent_decode_attention_shapes(layers=5, slots=24, stripe=24576, heads=32):
     """One new token a slot over the Kanana-2 cell's cache: 32 query heads on
     one shared key in two leaves, the rotated key in a 128-lane row (a 64-wide
@@ -199,6 +218,7 @@ KERNELS = {
     "decode_attention_8_a_group": (_decode_attention, _decode_attention_shapes(5, 4096, 64)),
     "latent_decode_attention_32_on_one_key": (
         _latent_decode_attention, _latent_decode_attention_shapes()),
+    "ssm_step_in_place": (_ssm_step_in_place, _ssm_step_shapes()),
     "quantize_int8": (_quantize, (((3072, 8192), jnp.bfloat16),)),
     "dequantize_int8": (
         _dequantize,
@@ -533,31 +553,63 @@ def _state_space_cut():
         n_layers=11, moe_experts_held=128, vocab_size=32768, max_seq_len=2048)
 
 
+def _fusions_on_a_layer_of_the_state(text, scope="ssm_step"):
+    """The fusions under ``scope`` with a layer of the Nemotron cut's state
+    among their operands or results, in any view of its heads
+    ([64, 128, 64, 128], [64, 8, 16, 64, 128], with or without the layers in
+    front)."""
+    import re
+
+    a_layer = re.compile(r"f32\[(?:\d+,)?64,(?:128|8,16),64,128\]")
+    fusions = {m.group(1): line for line in text.splitlines()
+               if (m := re.search(r" fusion\(.*calls=(%[\w.\-]+)", line)) and scope in line}
+    computation, found = None, set()
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            computation = line.split()[0]
+        elif computation in fusions and a_layer.search(line):
+            found.add(computation)
+    return sorted(fusions[c].strip()[:160] for c in found)
+
+
 def test_state_space_decode_step_moves_its_state_where_it_lies(
-        one_chip, no_compile_cache, native_kernels):
+        one_chip, no_compile_cache, native_kernels, monkeypatch):
     """The Nemotron-3-Super cell's decode step (64 slots of 2,048; 5 Mamba-2
     blocks, 5 expert blocks holding 128 of 512 experts, one GQA block, at
     published widths) compiles for the chip beside 9.3 GB of weights: the 1.3
     GB of float32 state is updated in the donated cache (no copy of the leaf,
-    temporaries far under one layer's 0.27 GB), the held banks go through the
-    grouped-matmul kernels whole, and the attention block reads its stripe
-    through the decode kernel."""
-    fn, args = _served_programs(_state_space_cut(), 64, 2048, one_chip)["decode_step"]
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
-    text = compiled.as_text()
+    temporaries far under one layer's 0.27 GB) by one ``ssm_step`` kernel a
+    block on the leaf whole, and no fusion under that scope reads or writes a
+    layer of the state (XLA's own two made three passes over it: PERF.md
+    section 6, PR 38); the held banks go through the grouped-matmul kernels
+    whole, and the attention block reads its stripe through the decode
+    kernel."""
+    from ray_tpu.ops import ssm
+
+    def compiled():
+        fn, args = _served_programs(_state_space_cut(), 64, 2048, one_chip)["decode_step"]
+        return jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+
+    step = compiled()
+    text = step.as_text()
     state = "f32[5,64,128,64,128]"
     assert [line.strip()[:120] for line in text.splitlines()
             if " copy(" in line and line.split(" = ", 1)[-1].startswith(state)] == []
-    assert compiled.memory_analysis().temp_size_in_bytes < 128e6
+    assert step.memory_analysis().temp_size_in_bytes < 128e6
     kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     assert sum("moe_ffn/experts" in line for line in kernels) >= 2  # up and down, relu^2 between
-    assert sum("attn_core" in line for line in kernels) == 1
+    assert sum("attn_core" in line and "ssm_mixer" not in line for line in kernels) == 1
+    assert sum("attn_core/ssm_mixer/ssm_step" in line for line in kernels) == 5
+    assert _fusions_on_a_layer_of_the_state(text) == []
     for scope in ("ssm_mixer/ssm_step", "ssm_mixer/ssm_conv", "moe_ffn/moe_latent_proj"):
         assert scope in text, scope
     # every token of a decode step is real: the convolution's next tail is a
     # slice of its inputs, not a gather by each row's own end
     assert [line.strip()[:120] for line in text.splitlines()
             if " gather(" in line and "ssm_conv" in line] == []
+    # the guard sees XLA's two fusions where the plain line runs
+    monkeypatch.setattr(ssm, "step_groups", lambda *a: None)
+    assert len(_fusions_on_a_layer_of_the_state(compiled().as_text())) >= 2
 
 
 def test_state_space_final_chunk_fits_at_its_widest(one_chip, no_compile_cache, native_kernels):
