@@ -1394,6 +1394,10 @@ class WorkerRuntime:
                     object_id, "plasma", self._write_shm(object_id, sobj)
                 )
             return
+        # the put's own add_ref may still sit in the coalescer: it has to
+        # reach the head before the seal does, or the head finds an object
+        # nobody holds and frees it where it is sealed
+        self._coalescer.flush()
         if (
             sobj.total_bytes() > self.max_inline
             and self.client_mode

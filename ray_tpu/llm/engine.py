@@ -22,12 +22,15 @@ TP/SP: params and cache shard over a mesh via the model's logical rules
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import logging
 import queue
 import threading
 import time
 import uuid
+from collections import deque
+from contextlib import contextmanager
 from typing import Any, Callable, Iterator, Optional
 
 import numpy as np
@@ -72,7 +75,7 @@ COUNTERS = (
     # program run as rows of one launch, so chunks over programs is the mean
     # number of rows a launch carried
     "prefill_programs:mid", "prefill_programs:final",
-    "loop_passes", "loop_idle_sleeps",
+    # (the loop's passes and idle sleeps are its clock's: ``get_stats()["loop"]``)
     # keys and values a decode step has to read: over decode steps and active
     # slots, the slot's length, and what a sliding-window layer needs of it
     # (min(length, window); stays 0 where the model has no window)
@@ -124,6 +127,13 @@ _LABEL = {"requests_finished": "reason", "requests_failed": "stage",
 # read from the bucket counts is within an eighth of the truth
 LATENCY_BOUNDS = tuple(1e-3 * 1.25**i for i in range(56))
 LATENCIES = ("queue_wait_s", "prefill_s", "token_gap_s")
+# the loop's own clock (``get_stats()["loop"]``): the stages of a pass in the
+# order it runs them, the bounds of its histogram of pass durations (the
+# latency bounds' rule, from 0.1 ms to 200 s), and how many wall seconds keep
+# their longest pass
+LOOP_STAGES = ("pull_waiting", "advance_admissions", "launch_decodes", "drain", "idle_sleep")
+PASS_BOUNDS = tuple(1e-4 * 1.25**i for i in range(66))
+LONGEST_PASS_SECONDS = 120
 _registered: dict = {}
 _registered_lock = threading.Lock()
 
@@ -198,6 +208,67 @@ def _between(start: Optional[float], end: Optional[float]) -> Optional[float]:
     return None if start is None or end is None else end - start
 
 
+class _LoopClock:
+    """The engine loop's own time, kept whether or not a profiler runs: plain
+    numbers that the loop thread alone writes (no lock and no ``util.metrics``
+    object on the pass path; ``view`` copies them from any thread). Seconds by
+    stage since the first pass and, of them, the seconds inside fetches and
+    inside launch calls; a histogram of pass durations; and the longest pass
+    of each wall second that had one, for the last ``LONGEST_PASS_SECONDS``:
+    a pass of 12 s is one record followed by eleven seconds that have none."""
+
+    def __init__(self):
+        self.started_t: Optional[float] = None  # ``perf_counter`` at the first pass
+        self.passes = 0
+        self.idle_sleeps = 0
+        self.stage_s = dict.fromkeys(LOOP_STAGES, 0.0)
+        self.fetch_s = 0.0
+        self.launch_s = 0.0
+        self.pass_counts = [0] * (len(PASS_BOUNDS) + 1)
+        self.longest: deque = deque(maxlen=LONGEST_PASS_SECONDS)
+        self._call_s, self._call = 0.0, None  # this pass's longest fetch or launch
+
+    def call(self, kind: str, program: str, seconds: float) -> None:
+        """A fetch or a launch call of this pass took ``seconds``."""
+        if kind == "fetch":
+            self.fetch_s += seconds
+        else:
+            self.launch_s += seconds
+        if seconds > self._call_s:
+            self._call_s, self._call = seconds, f"{kind}:{program}"
+
+    def end_pass(self, wall_t: float, marks: tuple) -> None:
+        """``marks``: ``perf_counter`` at the pass's start and after each of
+        its stages; ``wall_t``: ``time.time()`` at its start."""
+        took = marks[-1] - marks[0]
+        stages = {name: b - a for name, a, b in zip(LOOP_STAGES, marks, marks[1:])}
+        for name, s in stages.items():
+            self.stage_s[name] += s
+        self.pass_counts[bisect.bisect_left(PASS_BOUNDS, took)] += 1
+        last = self.longest[-1] if self.longest else None
+        same_second = last is not None and int(last["t"]) == int(wall_t)
+        if not same_second or took > last["s"]:
+            record = {"t": wall_t, "s": took, "stage_s": stages,
+                      "call": self._call, "call_s": self._call_s}
+            if same_second:
+                self.longest[-1] = record
+            else:
+                self.longest.append(record)
+        self._call_s, self._call = 0.0, None
+        self.passes += 1  # last: whoever reads it finds the pass in all the rest
+
+    def view(self) -> dict:
+        return {
+            "passes": self.passes, "idle_sleeps": self.idle_sleeps,
+            # since the first pass; the stages add up to it less the pass in progress
+            "elapsed_s": _between(self.started_t, time.perf_counter()),
+            "stage_s": dict(self.stage_s),
+            "fetch_s": self.fetch_s, "launch_s": self.launch_s,
+            "pass_s": {"boundaries": list(PASS_BOUNDS), "counts": list(self.pass_counts)},
+            "longest_pass_by_second": list(self.longest),
+        }
+
+
 class _Admission:
     """Chunked-prefill state for one slot being filled (reference: vLLM
     chunked prefill — bounded prompt work interleaved with decode steps)."""
@@ -217,8 +288,6 @@ class _Pool:
     route to short pools so they never pin max_seq_len-sized KV memory."""
 
     def __init__(self, stripe_len: int, n_slots: int, model_cfg, params):
-        from collections import deque
-
         import jax
 
         from ray_tpu.models.llama import init_kv_cache
@@ -465,8 +534,13 @@ class JaxEngine:
         # this engine's series of the process's latency histograms
         self._tag = {"engine": uuid.uuid4().hex[:8]}
         self._loop_first_pass_t: Optional[float] = None
-        self._build_model()
-        self._build_pools()
+        self._loop = _LoopClock()
+        # the constructor's phases, seconds (``get_stats()["init"]``), and of
+        # ``_warm_programs`` the seconds by program
+        self._init_s: dict = {}
+        self._warm_s: dict = {}
+        self._init_phase(self._build_model)
+        self._init_phase(self._build_pools)
         # the most rows a pool's middle-chunk program runs: what is alive in
         # a pass decides how many it has, up to a pool's admissions (and the
         # rows ``prefill`` writes as blocks). A latent pool's chunks stay one
@@ -477,8 +551,8 @@ class JaxEngine:
         rows = max(1, min(config.engine.max_concurrent_admissions, CHUNK_ROWS_MAX))
         for pool in self._pools:
             pool.chunk_rows = 1 if pool.latent else rows
-        self._compile()
-        self._warm_programs()
+        self._init_phase(self._compile)
+        self._init_phase(self._warm_programs)
         self._waiting: "queue.Queue[_Request]" = queue.Queue()
         self._backlog: list[_Request] = []  # engine-thread-owned FIFO
         self._stop = threading.Event()
@@ -494,6 +568,11 @@ class JaxEngine:
             target=self._engine_loop, daemon=True, name="llm-engine"
         )
         self._thread.start()
+
+    def _init_phase(self, phase: Callable[[], None]) -> None:
+        t = time.perf_counter()
+        phase()
+        self._init_s[phase.__name__.lstrip("_") + "_s"] = time.perf_counter() - t
 
     def _build_pools(self):
         ec = self.config.engine
@@ -704,9 +783,20 @@ class JaxEngine:
         checkout's first start compiles them all here
         (``LLMConfig.compile_budget_s``); later starts fetch them from the
         compile cache. The rows write one token at position 0 of slot 0,
-        which holds no request and which an admission overwrites whole."""
+        which holds no request and which an admission overwrites whole. Each
+        program is waited for where it was run, so that ``_warm_s`` holds the
+        seconds by program (a pool's own set-up goes to its first)."""
         import jax
         import jax.numpy as jnp
+
+        mark = time.perf_counter()
+
+        def book(program: str, out) -> None:
+            nonlocal mark
+            jax.block_until_ready(out)
+            now = time.perf_counter()
+            self._warm_s[program] = self._warm_s.get(program, 0.0) + now - mark
+            mark = now
 
         rng_key = self._rng_key
         jax.random.PRNGKey(0)  # a seeded request's key is a program too
@@ -731,6 +821,7 @@ class JaxEngine:
                 args = throwaway(rows)
                 for _ in range(2):  # fresh stripes, then a chunk program's own
                     args["ones"] = self._run_chunk_mid(**args)
+                book(f"chunk_mid:rows={rows}", args["ones"])
             for width in finals:
                 # a first chunk's stripe is fresh, a later one's comes out of
                 # a chunk program: both kinds of argument
@@ -740,21 +831,22 @@ class JaxEngine:
                 for one in stripes:
                     self._run_chunk_final(
                         pool, one, np.zeros((1, width), np.int32), 1, 0, 0, 0.0, 1, None, 0)
+                book(f"chunk_final:width={width}", pool.cache)
             if self.config.engine.enable_prefix_caching and not pool.stateful:
                 # the store's cut of a slot at each bucket, and the program
                 # that seeds a stripe with one
                 for b in self.config.engine.prefill_buckets:
                     if b < pool.stripe_len:
-                        self._seed_prefix_jit(
+                        book("seed_prefix", self._seed_prefix_jit(
                             self._new_stripe_jit(stripe),
-                            pool.cache["k"][:, 0, :, :b], pool.cache["v"][:, 0, :, :b])
+                            pool.cache["k"][:, 0, :, :b], pool.cache["v"][:, 0, :, :b]))
             for _ in range(2):  # the cache, keys and tokens as a chunk left them, then as a step did
                 out, pool.cache, pool.keys, _ = self._decode(
                     pool, pool.dev_tokens, jnp.asarray(pool.temps),
                     jnp.asarray(pool.top_ks), pool.keys,
                 )
                 pool.dev_tokens = out[-1]
-            jax.block_until_ready((pool.cache, pool.dev_tokens))
+            book("decode", (pool.cache, pool.dev_tokens))
         self._rng_key = rng_key
 
     def _decode(self, pool: _Pool, tokens, temps, top_ks, keys):
@@ -811,7 +903,7 @@ class JaxEngine:
         if self._pools[0].stateful:
             # a stored prefix is keys and values; a slot of this model also
             # needs the state its layers had reached at the boundary
-            self._n["prefix_bypassed_stateful"] += 1
+            self._count({"prefix_bypassed_stateful": 1})
             return None, 0
         for b in sorted(self.config.engine.prefill_buckets, reverse=True):
             if b >= len(ids):
@@ -975,7 +1067,7 @@ class JaxEngine:
         """``trace_ctx``: the caller's ``tracing.current_context()``; the
         request's spans then share its trace id and nest under its span."""
         with self._count_lock:
-            self._n["requests_submitted"] += 1
+            self._count({"requests_submitted": 1})
         try:
             if prompt_token_ids is None:
                 if prompt is None:
@@ -993,7 +1085,7 @@ class JaxEngine:
                 lora_idx = self._lora_ids[lora]
         except Exception:
             with self._count_lock:
-                self._n["requests_failed:submit"] += 1
+                self._count({"requests_failed:submit": 1})
             raise
         req = _Request(
             uuid.uuid4().hex[:12], list(prompt_token_ids),
@@ -1086,8 +1178,14 @@ class JaxEngine:
                 "boundaries": list(LATENCY_BOUNDS),
                 **{k: _latency_histogram(k).read(self._tag) for k in LATENCIES},
             },
-            # constructor entered to the loop thread's first pass
+            # constructor entered to the loop thread's first pass, and the
+            # seconds of its phases, which add up to it (the last also by
+            # program: a middle chunk by rows, a final chunk by width)
             "engine_init_s": _between(self._t_init, self._loop_first_pass_t),
+            "init": {**self._init_s, "warm_programs_by_program_s": dict(self._warm_s)},
+            # the loop's own clock: where its time went, and the longest pass
+            # of every second (``_LoopClock``)
+            "loop": self._loop.view(),
             # parameter leaves held in the device layout the model's rule names
             # (the stacked attention input projections, head-major): 0 says
             # the programs copy a layer's slice before they multiply
@@ -1112,28 +1210,49 @@ class JaxEngine:
                 out[name] = value
         return out
 
+    def _count(self, deltas: dict) -> None:
+        """The one place a counter grows: ``deltas`` (counter name -> growth)
+        go into ``_n`` and, in the same call, onto the profiler's clock as one
+        instant event ``engine.counts`` whose attributes are the deltas under
+        the counters' own names. So a trace holds each count where its work
+        happened, and the events of a profiler session sum to the counters'
+        growth over it by construction. A launch's counts are written right
+        after the launch, what the device hands back at its fetch. The loop
+        thread's calls need no lock; the names that callers' threads also
+        count (``submit``, ``_close_request``) are counted under
+        ``_count_lock``."""
+        deltas = {name: value for name, value in deltas.items() if value}
+        if not deltas:
+            return
+        n = self._n
+        for name, value in deltas.items():
+            n[name] += value
+        tracing.mark("engine.counts", **deltas)
+
     # -- the end of a request ------------------------------------------------
 
     def _close_request(
         self, req: _Request, failed_stage: Optional[str] = None,
-        error: Optional[BaseException] = None,
+        error: Optional[BaseException] = None, at: Optional[float] = None,
     ) -> None:
         """The one place a request ends, finished or failed. Counts it once
         (the loop and a caller's thread that found the loop dead may both
         come here), records its latencies and spans from the timestamps it
-        carries, and only then wakes whoever waits for it."""
+        carries, and only then wakes whoever waits for it. ``at``: when it
+        ended, where that was earlier than this call (``_emit`` stamps a
+        finished request at its last token; ``_drain`` closes it once the
+        fetch's block is counted)."""
         with self._count_lock:
             if req.finished_t is not None:
                 return
-            req.finished_t = time.time()
+            req.finished_t = time.time() if at is None else at
             if failed_stage is not None:
                 if req.error is None:
                     req.error = error
-                self._n["requests_failed:" + failed_stage] += 1
+                self._count({"requests_failed:" + failed_stage: 1})
             else:
-                self._n["requests_finished:" + req.finish_reason] += 1
-                if not req.out_tokens:
-                    self._n["requests_empty"] += 1
+                self._count({"requests_finished:" + req.finish_reason: 1,
+                             "requests_empty": int(not req.out_tokens)})
                 self._observe_latencies(req)
             self._mirror_metrics()
         self._record_request_spans(req)
@@ -1155,8 +1274,12 @@ class JaxEngine:
                 _latency_histogram(name).observe(v, tags=self._tag)
 
     def _mirror_metrics(self) -> None:
-        """Fold the counters' growth into ``util.metrics`` (under
-        ``_count_lock``: ``_mirrored`` is shared with callers' threads)."""
+        """Fold the counters' growth, and the loop's seconds by stage and its
+        passes by duration, into ``util.metrics`` (under ``_count_lock``:
+        ``_mirrored`` is shared with callers' threads).
+        ``llm_engine_loop_stage_seconds`` says without a profiler whether the
+        loop waits for the chip (``drain``) or the chip for the loop;
+        ``llm_engine_loop_pass_seconds`` what a pass takes."""
         for name, value in self._n.items():
             family, _, label = name.partition(":")
             counter = _metric(
@@ -1167,6 +1290,19 @@ class JaxEngine:
                 counter, self._mirrored, name, value,
                 {_LABEL[family]: label} if label else None,
             )
+        stage_seconds = _metric("Counter", "loop_stage_seconds", tag_keys=("stage",))
+        for stage, seconds in list(self._loop.stage_s.items()):
+            app_metrics.fold_counter_delta(
+                stage_seconds, self._mirrored, "loop_stage_seconds:" + stage, seconds,
+                {"stage": stage},
+            )
+        # the passes since the last fold, by duration: the loop's rhythm beside
+        # the decode step, and whether a stall was one pass or many
+        counts, total = list(self._loop.pass_counts), sum(self._loop.stage_s.values())
+        had, had_total = self._mirrored.get("loop_pass_seconds", ((0,) * len(counts), 0.0))
+        _metric("Histogram", "loop_pass_seconds", boundaries=PASS_BOUNDS).add(
+            [c - h for c, h in zip(counts, had)], total - had_total)
+        self._mirrored["loop_pass_seconds"] = (counts, total)
         _metric("Gauge", "live_tokens").set(self._live_tokens())
 
     def _record_request_spans(self, req: _Request) -> None:
@@ -1230,13 +1366,13 @@ class JaxEngine:
         # LoRA'd requests never reuse base-model KV (the cached V lacks
         # the adapter delta) — and their prefixes are never stored either
         if req.lora_idx == 0:
-            prefix, m = self._prefix_lookup(ids)
+            with tracing.annotate("engine.prefix_lookup"):
+                prefix, m = self._prefix_lookup(ids)
         else:
             prefix, m = None, 0
         suffix = ids[m:]
         req.prefix_hit_tokens = m
-        self._n["prompt_tokens"] += len(ids)
-        self._n["prompt_tokens_from_prefix"] += m
+        self._count({"prompt_tokens": len(ids), "prompt_tokens_from_prefix": m})
         chunk = self.config.engine.prefill_chunk or len(suffix)
         pieces = [suffix[i : i + chunk] for i in range(0, len(suffix), chunk)]
         chunks = []
@@ -1252,32 +1388,50 @@ class JaxEngine:
             toks[0, : len(piece)] = piece
             chunks.append((toks, len(piece), start, is_final))
             start += len(piece)
-        one = self._new_stripe_jit(pool.stripe_len)
+        with tracing.annotate("engine.new_stripe"):
+            one = self._new_stripe_jit(pool.stripe_len)
         if prefix is not None:
-            with tracing.annotate("engine.prefix_seed", tokens=m):
+            with self._device_call("launch", "seed_prefix", "engine.prefix_seed"):
                 one = self._seed_prefix_jit(one, prefix["k"], prefix["v"])
-            self._n["prefix_seed_tokens"] += m
+                self._count({"prefix_seed_tokens": m})
         pool.admitting[slot] = _Admission(req, slot, one, chunks, m)
 
-    def _count_chunk(self, adm: _Admission) -> tuple:
-        """Take ``adm``'s next chunk off its plan and count it: one a prompt
-        chunk, whatever launch carries it."""
-        toks, eff_len, start, is_final = adm.chunks[adm.idx]
+    @contextmanager
+    def _device_call(self, kind: str, program: str, span: str):
+        """A ``fetch`` of what ``program`` handed back, or the ``launch`` of
+        it: under its profiler span, and timed on the loop's own clock."""
+        t = time.perf_counter()
+        try:
+            with tracing.annotate(span):
+                yield
+        finally:
+            self._loop.call(kind, program, time.perf_counter() - t)
+
+    @staticmethod
+    def _next_chunk(adm: _Admission) -> tuple:
+        """Take ``adm``'s next chunk off its plan."""
+        toks, eff_len, start, _ = adm.chunks[adm.idx]
         adm.idx += 1
         adm.req.chunks_run += 1
-        kind, program = ("final", "chunk_final") if is_final else ("mid", "chunk_mid")
-        self._n["prefill_chunks:" + kind] += 1
-        self._n["prefill_query_tokens:" + program] += eff_len
-        self._n["prefill_attended_positions:" + program] += (
-            eff_len * start + eff_len * (eff_len + 1) // 2
-        )
         return toks, eff_len, start
+
+    def _count_chunks(self, kind: str, plan: list) -> None:
+        """Count one launch of the ``kind`` (``mid``, ``final``) chunk program
+        whose rows are ``plan``, after it was dispatched: one a prompt chunk,
+        whatever launch carries it, and the launch itself."""
+        program = "chunk_" + kind
+        self._count({
+            "prefill_programs:" + kind: 1,
+            "prefill_chunks:" + kind: len(plan),
+            "prefill_query_tokens:" + program: sum(n for _, n, _ in plan),
+            "prefill_attended_positions:" + program: sum(
+                n * start + n * (n + 1) // 2 for _, n, start in plan),
+        })
 
     def _launch_mid_chunks(self, pool: "_Pool", adms: list) -> None:
         """Dispatch ONE ``chunk_mid`` (device-async) whose rows are the next
         middle chunks of ``adms``: each row's stripe comes back extended."""
-        plan = [self._count_chunk(adm) for adm in adms]
-        self._n["prefill_programs:mid"] += 1
+        plan = [self._next_chunk(adm) for adm in adms]
         ones = self._run_chunk_mid(
             ones=tuple(adm.one for adm in adms),
             toks=np.concatenate([toks for toks, _, _ in plan]),
@@ -1285,6 +1439,7 @@ class JaxEngine:
             starts=[start for _, _, start in plan],
             adapters=[adm.req.lora_idx for adm in adms],
         )
+        self._count_chunks("mid", plan)
         for adm, one in zip(adms, ones):
             adm.one = one
 
@@ -1292,17 +1447,18 @@ class JaxEngine:
         """The device side of a middle-chunk launch, a row an entry."""
         import jax.numpy as jnp
 
-        return self._chunk_mid_jit(
-            self.params, ones, jnp.asarray(toks), jnp.asarray(lens, jnp.int32),
-            jnp.asarray(starts, jnp.int32), **self._lora_kw(adapters)
-        )
+        with tracing.annotate("engine.chunk_transfer"):  # the host's arrays
+            args = (jnp.asarray(toks), jnp.asarray(lens, jnp.int32),
+                    jnp.asarray(starts, jnp.int32))
+            lora_kw = self._lora_kw(adapters)
+        with tracing.annotate("engine.chunk_call"):
+            return self._chunk_mid_jit(self.params, ones, *args, **lora_kw)
 
     def _launch_final_chunk(self, pool: "_Pool", adm: _Admission) -> None:
         """Dispatch a prompt's final chunk (device-async), one row a launch
         (``programs``' ``chunk_final`` says why): it samples the first token
         in-program and activates the slot."""
-        toks, eff_len, start = self._count_chunk(adm)
-        self._n["prefill_programs:final"] += 1
+        toks, eff_len, start = self._next_chunk(adm)
         req, slot = adm.req, adm.slot
         # decode truncates to the program's static top-K; clamp here so
         # first token and all later tokens agree
@@ -1313,13 +1469,15 @@ class JaxEngine:
             pool, adm.one, toks, eff_len, start, slot,
             req.params.temperature, top_k, req.params.seed, req.lora_idx,
         )
+        self._count_chunks("final", [(toks, eff_len, start)])
         pool.slots[slot] = req
         pool.temps[slot] = req.params.temperature
         pool.top_ks[slot] = top_k
         del pool.admitting[slot]
         if req.prefix_hit_tokens == 0 and req.lora_idx == 0:
             # LoRA'd prefixes are adapter-specific: never shared
-            self._prefix_store(pool, slot, req.prompt_token_ids)
+            with tracing.annotate("engine.prefix_store"):
+                self._prefix_store(pool, slot, req.prompt_token_ids)
         try:
             first_tok.copy_to_host_async()
             if stats is not None:
@@ -1336,20 +1494,22 @@ class JaxEngine:
         import jax
         import jax.numpy as jnp
 
-        if seed is not None:
-            req_key = jax.random.PRNGKey(seed)
-        else:
-            self._rng_key, req_key = jax.random.split(self._rng_key)
-        first_tok, new_key, pool.cache, _, stats = self._chunk_final_jit(
-            self.params, pool.cache, one, jnp.asarray(toks),
-            jnp.asarray([eff_len], jnp.int32), jnp.asarray([start], jnp.int32),
-            jnp.int32(slot), jnp.float32(temperature), jnp.int32(top_k),
-            req_key, **self._lora_kw([adapter])
-        )
-        pool.keys = self._set_key_jit(pool.keys, jnp.int32(slot), new_key)
-        pool.dev_tokens = self._set_tok_jit(
-            pool.dev_tokens, jnp.int32(slot), first_tok
-        )
+        with tracing.annotate("engine.chunk_transfer"):  # the host's arrays and scalars
+            if seed is not None:
+                req_key = jax.random.PRNGKey(seed)
+            else:
+                self._rng_key, req_key = jax.random.split(self._rng_key)
+            slot_dev = jnp.int32(slot)
+            args = (jnp.asarray(toks), jnp.asarray([eff_len], jnp.int32),
+                    jnp.asarray([start], jnp.int32), slot_dev,
+                    jnp.float32(temperature), jnp.int32(top_k), req_key)
+            lora_kw = self._lora_kw([adapter])
+        with tracing.annotate("engine.chunk_call"):
+            first_tok, new_key, pool.cache, _, stats = self._chunk_final_jit(
+                self.params, pool.cache, one, *args, **lora_kw)
+        with tracing.annotate("engine.slot_set"):  # the slot's key and next input token
+            pool.keys = self._set_key_jit(pool.keys, slot_dev, new_key)
+            pool.dev_tokens = self._set_tok_jit(pool.dev_tokens, slot_dev, first_tok)
         return first_tok, stats
 
     def _fail_admission(
@@ -1434,9 +1594,9 @@ class JaxEngine:
                 mids.append(adm)
             for is_final, adms in launches:
                 try:
-                    with tracing.annotate(
-                        "engine.prefill_chunk", rows=len(adms), final=is_final,
-                        requests=",".join(adm.req.request_id for adm in adms),
+                    with self._device_call(
+                        "launch", "chunk_final" if is_final else "chunk_mid",
+                        "engine.prefill_chunk",
                     ):
                         if is_final:
                             self._launch_final_chunk(pool, adms[0])
@@ -1460,10 +1620,7 @@ class JaxEngine:
             if not active or len(pool.inflight) > runahead:
                 continue
             try:
-                with tracing.annotate(
-                    "engine.decode_launch", pool=pool.stripe_len,
-                    active=len(active),
-                ):
+                with self._device_call("launch", "decode", "engine.decode_launch"):
                     out, pool.cache, pool.keys, stats = self._decode(
                         pool,
                         pool.dev_tokens,
@@ -1479,8 +1636,6 @@ class JaxEngine:
                     except Exception:  # noqa: BLE001
                         pass
                 pool.inflight.append((out, active, stats))
-                self._n["decode_steps"] += self._decode_n_steps
-                self._n["decode_slot_steps"] += self._decode_n_steps * len(active)
                 lengths = np.fromiter(
                     (len(r.prompt_token_ids) + len(r.out_tokens) for r in active.values()),
                     np.int64, len(active),
@@ -1490,16 +1645,18 @@ class JaxEngine:
                     ("decode_kv_tokens_latent", "decode_kv_positions_read_latent")
                     if pool.latent else ("decode_kv_tokens_global", "decode_kv_positions_read")
                 )
-                self._n[tokens] += steps * int(lengths.sum())
-                self._n[read] += steps * pool.positions_read(0, lengths)
+                counts = {
+                    "decode_steps": steps, "decode_slot_steps": steps * len(active),
+                    tokens: steps * int(lengths.sum()),
+                    read: steps * pool.positions_read(0, lengths),
+                }
                 window = self.model_cfg.sliding_window
                 if window:  # a model without one has no window layers to count for
-                    self._n["decode_kv_tokens_window"] += steps * int(
-                        np.minimum(lengths, window).sum()
-                    )
-                    self._n["decode_kv_positions_read_window"] += steps * pool.positions_read(
-                        lengths - window, lengths
-                    )
+                    counts["decode_kv_tokens_window"] = steps * int(
+                        np.minimum(lengths, window).sum())
+                    counts["decode_kv_positions_read_window"] = steps * pool.positions_read(
+                        lengths - window, lengths)
+                self._count(counts)
                 launched = True
             except BaseException as e:  # noqa: BLE001 — device failure
                 self._fail_pool(pool, e)
@@ -1535,7 +1692,13 @@ class JaxEngine:
         """Fetch arrived tokens (first tokens + completed decode programs)
         and run finish bookkeeping. Keeps up to ``decode_runahead`` decode
         programs in flight; over-decoded tokens of finished or re-admitted
-        slots are discarded via the per-program binding snapshot."""
+        slots are discarded via the per-program binding snapshot. What a
+        fetch brought is counted once, as one event, and the requests it
+        finished are closed after that, with the time ``_emit`` stamped at
+        their last token: whoever a close wakes finds the counters holding
+        its tokens, and ``finished_t`` (so ``token_gap_s`` and the ring's
+        ``engine.decode``) ends where it always did. The stream's terminator
+        and ``done`` follow by the rest of the block's emits."""
         progressed = False
         runahead = max(0, self.config.engine.decode_runahead)
         for pool in self._pools:
@@ -1543,89 +1706,124 @@ class JaxEngine:
                 pending, pool.first_pending = pool.first_pending, []
                 for slot, req, tok, stats in pending:
                     try:
-                        with tracing.annotate("engine.fetch", what="first_token"):
+                        with self._device_call("fetch", "first_token", "engine.fetch"):
                             t = int(np.asarray(tok))
-                            self._count_routing(stats, ("chunk_mid", "chunk_final"))
+                            counts = self._routing_counts(stats, ("chunk_mid", "chunk_final"))
                     except BaseException as e:  # noqa: BLE001
                         self._fail_pool(pool, e)
                         break
-                    if pool.slots[slot] is req:
-                        req.first_token_t = time.time()
-                        self._emit(pool, slot, t)
-                        # one, or none where the first sampled was a stop
-                        self._n["first_tokens"] += len(req.out_tokens)
-                        progressed = True
+                    ended = None
+                    try:
+                        if pool.slots[slot] is req:
+                            req.first_token_t = time.time()
+                            # one token, or none where the first sampled was a stop
+                            made, ended = self._emit(pool, slot, t)
+                            counts.update(tokens_generated=made, first_tokens=made)
+                            progressed = True
+                        self._count(counts)
+                    finally:
+                        if ended is not None:
+                            self._close_request(req, at=ended)
             has_active = any(r is not None for r in pool.slots)
             keep = runahead if has_active else 0
             while len(pool.inflight) > keep:
                 out, binding, stats = pool.inflight.popleft()
                 try:
-                    with tracing.annotate("engine.fetch", what="decode"):
+                    with self._device_call("fetch", "decode", "engine.fetch"):
                         arr = np.asarray(out)  # [K, slots]
-                        self._count_routing(stats, ("decode",))
+                        counts = self._routing_counts(stats, ("decode",))
                 except BaseException as e:  # noqa: BLE001
                     self._fail_pool(pool, e)
                     break
                 applied: dict[int, list] = {}
-                for k in range(arr.shape[0]):
-                    for slot, req in binding.items():
-                        if pool.slots[slot] is req:
-                            self._emit(pool, slot, int(arr[k, slot]))
-                            entry = applied.setdefault(id(req), [req, 0])
-                            entry[1] += 1
-                        else:
-                            self._n["tokens_discarded"] += 1
+                made = discarded = 0
+                done = []  # (request, when its last token was emitted)
+                try:
+                    for k in range(arr.shape[0]):
+                        for slot, req in binding.items():
+                            if pool.slots[slot] is req:
+                                appended, ended = self._emit(pool, slot, int(arr[k, slot]))
+                                made += appended
+                                if ended is not None:
+                                    done.append((req, ended))
+                                entry = applied.setdefault(id(req), [req, 0])
+                                entry[1] += 1
+                            else:
+                                discarded += 1
+                    counts.update(tokens_generated=made, tokens_discarded=discarded)
+                    self._count(counts)
+                finally:  # a slot that was freed is closed, whatever came after it
+                    for req, ended in done:
+                        self._close_request(req, at=ended)
                 for req, n in applied.values():
                     req.pacer.note_block(n)
                 progressed = True
         return progressed
 
-    def _count_routing(self, stats, programs: tuple) -> None:
-        """Fold routing counts (None for a dense model; a row a program in
-        ``programs``) into the counters. Called inside the fetch of the tokens
-        they came out beside; their own copy to the host was started with the
-        tokens', so this waits for nothing the tokens did not wait for."""
-        if stats is not None:
-            for program, row in zip(programs, np.atleast_2d(np.asarray(stats))):
-                for name, value in zip(_MOE_COUNTERS, row):
-                    self._n[f"{name}:{program}"] += int(value)
+    @staticmethod
+    def _routing_counts(stats, programs: tuple) -> dict:
+        """The routing counts a program handed out (None for a dense model; a
+        row a program in ``programs``), by counter name. Called inside the
+        fetch of the tokens they came out beside; their own copy to the host
+        was started with the tokens', so this waits for nothing the tokens did
+        not wait for."""
+        if stats is None:
+            return {}
+        return {
+            f"{name}:{program}": int(value)
+            for program, row in zip(programs, np.atleast_2d(np.asarray(stats)))
+            for name, value in zip(_MOE_COUNTERS, row)
+        }
 
     def _engine_loop(self):
         # the four stages stay attributes looked up on ``self`` each pass:
         # the benchmark wraps them by name. Loop spans go to the profiler
-        # only (``tracing.annotate``), never to the ring.
+        # only (``tracing.annotate``), never to the ring; the loop's own
+        # clock (``_LoopClock``) is read at the same five boundaries, and a
+        # pass starts where the last one ended.
         self._loop_first_pass_t = time.time()
-        n = self._n
+        clock, now = self._loop, time.perf_counter
+        t0 = clock.started_t = now()
         while not self._stop.is_set():
-            n["loop_passes"] += 1
+            wall_t = time.time()
             with tracing.annotate("engine.pull_waiting"):
                 progressed = self._pull_waiting()
+            t1 = now()
             with tracing.annotate("engine.advance_admissions"):
                 progressed |= self._advance_admissions()
+            t2 = now()
             with tracing.annotate("engine.launch_decodes"):
                 progressed |= self._launch_decodes()
+            t3 = now()
             with tracing.annotate("engine.drain"):
                 progressed |= self._drain()
+            t4 = t5 = now()
             if not progressed:
-                n["loop_idle_sleeps"] += 1
+                clock.idle_sleeps += 1
                 with tracing.annotate("engine.idle_sleep"):
                     time.sleep(0.002)
+                t5 = now()
+            clock.end_pass(wall_t, (t0, t1, t2, t3, t4, t5))
+            t0 = t5
 
-    def _emit(self, pool: "_Pool", slot: int, token: int):
+    def _emit(self, pool: "_Pool", slot: int, token: int) -> tuple:
         """Record a generated token for the request in `slot`; finish on
-        eos/max_tokens/stripe-full."""
+        eos/max_tokens/stripe-full. Returns (tokens appended: 1, or 0 for a
+        stop token; ``time.time()`` where the request finished, else None:
+        its slot is free then, and the caller closes it at that time once it
+        has counted)."""
         req = pool.slots[slot]
         if req is None:
-            return
+            return 0, None
         p = req.params
         eos = self.tokenizer.eos_id
         stop_ids = set(p.stop_token_ids or [])
         if not p.ignore_eos:
             stop_ids.add(eos)
         is_stop = token in stop_ids
-        if not is_stop:
+        made = int(not is_stop)
+        if made:
             req.out_tokens.append(token)
-            self._n["tokens_generated"] += 1
             req.stream_queue.put(
                 {
                     "token_id": token,
@@ -1641,4 +1839,5 @@ class JaxEngine:
             if pool.adapter_ids[slot]:
                 pool.adapter_ids[slot] = 0
                 self._sync_adapter_ids(pool)
-            self._close_request(req)
+            return made, time.time()
+        return made, None

@@ -88,6 +88,16 @@ class Histogram(Metric):
             counts[bisect.bisect_left(self.boundaries, value)] += 1
             self._sums[k] = self._sums.get(k, 0.0) + value
 
+    def add(self, counts: Sequence[int], total: float, tags: Optional[dict] = None):
+        """Fold in bucket counts kept elsewhere (one more than the
+        boundaries, as ``read`` gives them) and the sum of their values."""
+        k = self._key(tags)
+        with self._lock:
+            mine = self._counts.setdefault(k, [0] * (len(self.boundaries) + 1))
+            for i, c in enumerate(counts):
+                mine[i] += c
+            self._sums[k] = self._sums.get(k, 0.0) + total
+
     def read(self, tags: Optional[dict] = None) -> dict:
         """One series' bucket counts (one more than the boundaries: the
         last is what lay above them all) and sum."""

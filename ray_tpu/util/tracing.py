@@ -34,7 +34,8 @@ One clock with the device: in a process that has already imported JAX,
 profiler's ``.xplane.pb`` beside the device operations while a profiler
 session runs (and costs a check of one flag while none does). A process that
 has no JAX (controller, agents) never imports it from here. :func:`annotate`
-is the hot-loop form: profiler only, never the ring.
+is the hot-loop form: profiler only, never the ring; :func:`mark` is an
+instant that carries numbers (the engine's ``engine.counts``).
 """
 
 from __future__ import annotations
@@ -255,6 +256,15 @@ def annotate(name: str, **attributes):
     if jax is None:
         return _NO_ANNOTATION
     return jax.profiler.TraceAnnotation(name, **attributes)
+
+
+def mark(name: str, **attributes) -> None:
+    """An instant on the profiler's clock: :func:`annotate` entered and left
+    at once, so the event holds ``attributes`` (as its stats in the
+    ``.xplane.pb``, each under its own name) and no time. Profiler only,
+    never the ring; nothing where no session runs or the process has no JAX."""
+    with annotate(name, **attributes):
+        pass
 
 
 @contextmanager

@@ -213,3 +213,52 @@ def test_client_same_host_arena_probe(tmp_path):
             assert "ARENA: /rtpu-" in r.stdout
     finally:
         ray_tpu.shutdown()
+
+
+def test_client_pushed_put_holds_its_ref_before_the_seal(tmp_path):
+    """A put's own ``add_ref`` rides the submit coalescer, the chunked push
+    does not: with a window far longer than the push, the ref still reaches
+    the head first, so the head does not free the object where it seals it
+    (it did, one run in fifty under load, and the task that took the ref
+    waited for its argument for ever)."""
+    ray_tpu.init(num_cpus=2, mode="process")
+    try:
+        code = textwrap.dedent(
+            """
+            import os
+            os.environ["JAX_PLATFORMS"] = "cpu"
+            import numpy as np
+            import ray_tpu
+
+            ray_tpu.init(address={addr!r})
+            os.environ.pop("RAY_TPU_ARENA", None)  # the chunked push, as across hosts
+            big = np.arange(100_000, dtype=np.float64)
+            ref = ray_tpu.put(big)
+
+            @ray_tpu.remote
+            def total(x):
+                return float(x.sum())
+
+            assert ray_tpu.get(total.remote(ref), timeout=60) == float(big.sum())
+            ray_tpu.shutdown()
+            print("PUT-HELD")
+            """.replace("{addr!r}", repr(ray_tpu.cluster_address()))
+        )
+        r = subprocess.run(
+            [sys.executable, "-c", code],
+            capture_output=True,
+            text=True,
+            timeout=240,
+            env={
+                "PATH": "/usr/bin:/bin:/usr/local/bin",
+                "PYTHONPATH": "/root/repo",
+                "JAX_PLATFORMS": "cpu",
+                "HOME": "/root",
+                "RAY_TPU_OBJECT_TRANSFER_CHUNK_BYTES": "65536",
+                "RAY_TPU_SUBMIT_BATCH_WINDOW_MS": "500",
+            },
+        )
+        assert r.returncode == 0, r.stderr[-2000:]
+        assert "PUT-HELD" in r.stdout
+    finally:
+        ray_tpu.shutdown()

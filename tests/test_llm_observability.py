@@ -7,9 +7,17 @@
   the HTTP body;
 - a request's spans share the caller's trace, nest under it and tile its
   time; the loop's spans land in a profiler session; ``trace_sample_n=0``
-  records nothing; ``util.tracing`` imports no JAX by itself."""
+  records nothing; ``util.tracing`` imports no JAX by itself;
+- PR 40: every counter grows in ``JaxEngine._count`` alone, which writes the
+  growth as an ``engine.counts`` event on the profiler's clock (the events of
+  a session sum to the counters' growth, name by name), and the loop keeps its
+  own clock: seconds by stage, a histogram of passes and the longest pass of
+  every second (``get_stats()["loop"]``), and the constructor its phases
+  (``get_stats()["init"]``)."""
 
 import glob
+import inspect
+import json
 import os
 import re
 import subprocess
@@ -174,6 +182,14 @@ def _counters(eng):
     return eng.get_stats()["counters"]
 
 
+def _flat(counters):
+    """``get_stats()["counters"]`` back under ``COUNTERS``' own names."""
+    return {
+        k if not isinstance(v, dict) else f"{k}:{label}": v if not isinstance(v, dict) else v[label]
+        for k, v in counters.items() for label in (v if isinstance(v, dict) else [None])
+    }
+
+
 def _closed(c):
     return sum(c["requests_finished"].values()) + sum(c["requests_failed"].values())
 
@@ -195,10 +211,7 @@ def test_counters_balance_after_mixed_requests(engine):
     outs = [engine._output(r) for r in reqs]
     s = engine.get_stats()
     c = s["counters"]
-    assert set(COUNTERS) == {
-        k if not isinstance(v, dict) else f"{k}:{label}"
-        for k, v in c.items() for label in (v if isinstance(v, dict) else [None])
-    }
+    assert set(COUNTERS) == set(_flat(c))
     assert c["requests_submitted"] - before["requests_submitted"] == len(reqs) + 1
     assert c["requests_submitted"] == _closed(c)
     assert c["requests_failed"]["submit"] - before["requests_failed"]["submit"] == 1
@@ -214,7 +227,7 @@ def test_counters_balance_after_mixed_requests(engine):
     # prompts of 41, 71, 34 and 91 tokens (with BOS) in chunks of 32
     assert c["prefill_chunks"]["mid"] - before["prefill_chunks"]["mid"] == 1 + 2 + 1 + 2
     assert c["prompt_tokens_from_prefix"] == before["prompt_tokens_from_prefix"]
-    assert c["loop_passes"] > before["loop_passes"]
+    assert s["loop"]["passes"] > 0 and "loop_passes" not in c
     assert s["live_tokens"] == 0 and s["active_slots"] == 0
     for o in outs:
         m = o.metrics
@@ -495,6 +508,245 @@ def test_profiler_session_holds_the_engine_loops_annotations(engine, tmp_path):
         "engine.request", "engine.queue_wait", "engine.prefill", "engine.decode"}
 
 
+def _engine_events(trace_dir):
+    """[(name, start_ns, duration_ns, {stat: value})] of the ``engine.*``
+    host events of a profiler session's ``.xplane.pb``."""
+    from jax.profiler import ProfileData
+
+    found = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+    assert found
+    return [
+        (ev.name, ev.start_ns, ev.duration_ns, dict(ev.stats))
+        for plane in ProfileData.from_file(found[0]).planes if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events if ev.name.startswith("engine.")
+    ]
+
+
+def test_a_profiler_sessions_count_events_sum_to_the_counters_growth(engine, tmp_path, ring):
+    """The keyword arguments of a ``TraceAnnotation`` are the event's own
+    stats in the file (``XEvent.stats``, as ``ProfileData`` shows them: name
+    and whole number), so ``_n`` and the events cannot drift."""
+    params = SamplingParams(max_tokens=5, ignore_eos=True)
+    engine.generate("q" * 70, sampling_params=params)  # stored: the session's copy hits it
+    before = _flat(_counters(engine))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        reqs = [engine.submit(chr(105 + i) * n, sampling_params=SamplingParams(
+            max_tokens=m, ignore_eos=True)) for i, (n, m) in enumerate(
+                [(3, 5), (40, 2), (70, 9), (90, 4)])]
+        reqs.append(engine.submit("q" * 70 + "tail", sampling_params=params))
+        with pytest.raises(ValueError):
+            engine.submit(prompt_token_ids=[])
+        for r in reqs:
+            engine._await_done(r)
+        time.sleep(0.05)
+    finally:
+        jax.profiler.stop_trace()
+    growth = {k: v - before[k] for k, v in _flat(_counters(engine)).items() if v != before[k]}
+    events = [e for e in _engine_events(str(tmp_path)) if e[0] == "engine.counts"]
+    sums: dict = {}
+    for _, _, _, stats in events:
+        for name, value in stats.items():
+            assert isinstance(value, int) and value > 0 and name in COUNTERS
+            sums[name] = sums.get(name, 0) + value
+    assert sums == growth
+    # the session saw every kind of site: callers' threads, admissions with and
+    # without a prefix, launches of each program, fetches
+    assert {"requests_submitted", "requests_failed:submit", "requests_finished:length",
+            "prompt_tokens", "prompt_tokens_from_prefix", "prefix_seed_tokens",
+            "prefill_programs:mid", "prefill_chunks:mid", "prefill_programs:final",
+            "prefill_query_tokens:chunk_final", "prefill_attended_positions:chunk_mid",
+            "decode_steps", "decode_slot_steps", "decode_kv_tokens_global",
+            "decode_kv_positions_read", "tokens_generated", "first_tokens"} <= set(sums)
+    # one event a launch and one a fetch, not one a token
+    per_launch = [s for _, _, _, s in events if "decode_steps" in s]
+    assert sum(s["decode_steps"] for s in per_launch) == len(per_launch) == growth["decode_steps"]
+    assert len([s for _, _, _, s in events if "tokens_generated" in s]) < growth["tokens_generated"]
+    # an instant, entered and left at once: the quickest says what one costs
+    # (a thread may lose the core inside any one of them)
+    assert min(d for _, _, d, _ in events) < 1e5  # ns
+    # and never the ring
+    assert "engine.counts" not in {s["name"] for s in tracing.get_spans()}
+
+
+def test_a_finished_request_ends_at_its_last_token_and_wakes_after_the_count(engine, monkeypatch):
+    """``_emit`` stamps the end where it always was; ``_drain`` closes the
+    request once the fetch's block is counted, so its waiter finds the tokens
+    in the counters."""
+    ended, seen, emit, count = [], [], engine._emit, engine._count
+
+    def emitting(pool, slot, token):
+        r = pool.slots[slot]
+        made, at = emit(pool, slot, token)
+        if at is not None:
+            ended.append((r, at))
+        return made, at
+
+    def counting(deltas):  # the loop's thread, as ``emitting``
+        if "tokens_generated" in deltas:
+            seen.extend((r, at, time.time(), r.done.is_set(), r.finished_t) for r, at in ended)
+            ended.clear()
+        return count(deltas)
+
+    with monkeypatch.context() as m:
+        m.setattr(engine, "_emit", emitting)
+        m.setattr(engine, "_count", counting)
+        req = engine.submit("ends where", sampling_params=SamplingParams(max_tokens=4, ignore_eos=True))
+        engine._await_done(req)
+    ((_, at, counted_t, woken, closed_t),) = [e for e in seen if e[0] is req]
+    assert req.finish_reason == "length" and not woken and closed_t is None
+    assert req.first_token_t <= at == req.finished_t <= counted_t
+    assert _counters(engine)["tokens_generated"] >= 4
+
+
+def test_the_steps_of_a_launch_are_named_inside_its_span(engine, tmp_path):
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        engine.generate("name the host steps " * 3,
+                        sampling_params=SamplingParams(max_tokens=2, ignore_eos=True))
+    finally:
+        jax.profiler.stop_trace()
+    events = _engine_events(str(tmp_path))
+    spans = {}
+    for name, start, duration, _ in events:
+        spans.setdefault(name, []).append((start, start + duration))
+    outer = {"engine.chunk_transfer": "engine.prefill_chunk", "engine.chunk_call": "engine.prefill_chunk",
+             "engine.slot_set": "engine.prefill_chunk", "engine.prefix_store": "engine.prefill_chunk",
+             "engine.prefix_lookup": "engine.pull_waiting", "engine.new_stripe": "engine.pull_waiting"}
+    for inner, around in outer.items():
+        assert inner in spans, inner
+        for a, b in spans[inner]:
+            assert any(lo <= a and b <= hi for lo, hi in spans[around]), (inner, around)
+    # no span carries an attribute: the numbers ride on ``engine.counts``
+    assert all(not stats for name, _, _, stats in events if name != "engine.counts")
+
+
+def test_every_counter_grows_in_the_one_counting_method():
+    import ray_tpu.llm.engine as mod
+
+    grows = re.findall(r"^.*\b_n\[[^\]]*\]\s*\+=.*$|^.*\bn\[[^\]]*\]\s*\+=.*$",
+                       inspect.getsource(mod), re.M)
+    assert [g.strip() for g in grows] == ["n[name] += value"]
+    assert "n[name] += value" in inspect.getsource(mod.JaxEngine._count)
+
+
+def _after_passes(engine, n):
+    """Wait for ``n`` more passes of the loop to end (an idle loop makes one
+    every 2 ms): a pass in progress after this began after the call."""
+    target = engine._loop.passes + n
+    deadline = time.monotonic() + 60
+    while engine._loop.passes < target:
+        assert time.monotonic() < deadline, "the loop stands still"
+        time.sleep(0.001)
+
+
+def _one_slow_pass(engine, monkeypatch):
+    """One pass of the idle loop whose ``_launch_decodes`` sleeps 80 ms: the
+    test's own window, when the sleep began, and the loop's view once that
+    pass has ended. The pass that takes the patch began after ``t_open``: two
+    whole passes lie between."""
+    inner, slept = engine._launch_decodes, []
+
+    def slow():
+        if not slept:
+            slept.append(time.time())
+            time.sleep(0.08)
+        return inner()
+
+    t_open = time.time()
+    _after_passes(engine, 2)
+    with monkeypatch.context() as m:
+        m.setattr(engine, "_launch_decodes", slow)
+        _after_passes(engine, 2)  # the one that slept, and it has been recorded
+    return t_open, slept[0], time.time(), engine.get_stats()["loop"]
+
+
+def test_a_slow_pass_is_the_record_of_its_second(engine, monkeypatch):
+    for _ in range(5):  # again where the box stalled another pass of that second for longer
+        time.sleep(1.02 - time.time() % 1)  # a second that holds no earlier pass of length
+        t_open, slept_t, t_close, loop = _one_slow_pass(engine, monkeypatch)
+        records = loop["longest_pass_by_second"]
+        assert 0 < len(records) <= 120
+        seconds = [int(r["t"]) for r in records]
+        assert seconds == sorted(set(seconds))  # one record a second, in order
+        mine = [r for r in records if r["t"] <= slept_t <= r["t"] + r["s"]]
+        if mine:
+            break
+    (r,) = mine
+    assert t_open <= r["t"] <= slept_t <= t_close
+    assert r["s"] >= 0.08 and r["stage_s"]["launch_decodes"] >= 0.08
+    assert max(r["stage_s"], key=r["stage_s"].get) == "launch_decodes"
+    assert r["s"] == pytest.approx(sum(r["stage_s"].values()))
+    assert set(r["stage_s"]) == set(loop["stage_s"]) == {
+        "pull_waiting", "advance_admissions", "launch_decodes", "drain", "idle_sleep"}
+    # the pass is in the histogram's bucket for its duration
+    bounds, counts = loop["pass_s"]["boundaries"], loop["pass_s"]["counts"]
+    assert len(counts) == len(bounds) + 1 and sum(counts) >= loop["passes"]
+    assert sum(c for b, c in zip(bounds, counts) if b >= 0.08) + counts[-1] >= 1
+
+
+def test_the_loops_stage_seconds_tile_its_elapsed_time(engine):
+    """A pass starts where the last ended, so the stages' seconds are the
+    loop's elapsed time up to the last pass's end: no more than the time
+    since the first pass, and no less than it was two passes ago."""
+    reqs = [engine.submit("clock " * n, sampling_params=SamplingParams(max_tokens=6, ignore_eos=True))
+            for n in (2, 9, 14)]
+    for r in reqs:
+        engine._await_done(r)
+    earlier = engine.get_stats()["loop"]["elapsed_s"]
+    _after_passes(engine, 2)
+    loop = engine.get_stats()["loop"]
+    now = time.perf_counter() - engine._loop.started_t
+    assert earlier <= sum(loop["stage_s"].values()) <= now
+    assert loop["elapsed_s"] <= now
+    # a fetch and a launch lie inside their stages
+    assert 0 < loop["fetch_s"] <= loop["stage_s"]["drain"]
+    assert 0 < loop["launch_s"] <= (
+        loop["stage_s"]["pull_waiting"] + loop["stage_s"]["advance_admissions"]
+        + loop["stage_s"]["launch_decodes"])
+    assert 0 < loop["idle_sleeps"]
+    calls = {r["call"] for r in loop["longest_pass_by_second"]}
+    assert calls <= {None, "fetch:first_token", "fetch:decode", "launch:chunk_mid",
+                     "launch:chunk_final", "launch:decode", "launch:seed_prefix"}
+
+
+def test_the_constructors_phases_add_up_to_engine_init_s(engine):
+    s = engine.get_stats()
+    init = s["init"]
+    phases = ("build_model_s", "build_pools_s", "compile_s", "warm_programs_s")
+    assert all(init[k] >= 0 for k in phases)
+    assert sum(init[k] for k in phases) == pytest.approx(s["engine_init_s"], rel=0.05)
+    by_program = init["warm_programs_by_program_s"]
+    assert sum(by_program.values()) == pytest.approx(init["warm_programs_s"], rel=0.01)
+    # the middle chunk by rows, the final chunk by width, the seed, the decode step
+    assert {"chunk_mid:rows=1", "chunk_mid:rows=4", "chunk_final:width=16",
+            "chunk_final:width=32", "seed_prefix", "decode"} <= set(by_program)
+
+
+def test_get_stats_stays_json_serialisable(engine):
+    engine.generate("json", sampling_params=SamplingParams(max_tokens=2, ignore_eos=True))
+    s = engine.get_stats()
+    assert json.loads(json.dumps(s)).keys() == s.keys()
+    assert {"loop", "init", "counters", "latency"} <= set(s)
+
+
+def test_the_loops_stage_seconds_reach_the_metrics_scrape(engine):
+    engine.generate("stages", sampling_params=SamplingParams(max_tokens=2, ignore_eos=True))
+    text = app_metrics.export_prometheus()
+    got = {stage: float(v) for stage, v in re.findall(
+        r'^llm_engine_loop_stage_seconds\{stage="(\w+)"\} (\S+)$', text, re.M)}
+    loop = engine.get_stats()["loop"]
+    assert set(got) == set(loop["stage_s"])
+    assert got["idle_sleep"] > 0 and got["advance_admissions"] > 0
+    # and its passes by duration, folded with them when a request finishes
+    # (the process's engines share both series)
+    folded = int(re.search(r"^llm_engine_loop_pass_seconds_count (\d+)$", text, re.M).group(1))
+    seconds = float(re.search(r"^llm_engine_loop_pass_seconds_sum (\S+)$", text, re.M).group(1))
+    assert folded > 0 and seconds == pytest.approx(sum(got.values()))
+    assert len(re.findall(r"^llm_engine_loop_pass_seconds_bucket", text, re.M)) == len(
+        loop["pass_s"]["boundaries"]) + 1
+
+
 def test_tracing_imports_no_jax_in_a_process_that_has_none():
     code = (
         "import sys\n"
@@ -503,6 +755,7 @@ def test_tracing_imports_no_jax_in_a_process_that_has_none():
         "    pass\n"
         "with tracing.span('b'):\n"
         "    pass\n"
+        "tracing.mark('engine.counts', decode_steps=1)\n"
         "assert 'jax' not in sys.modules, 'tracing imported jax'\n"
         "assert [s['name'] for s in tracing.get_spans()] == ['b']\n"
     )
