@@ -69,6 +69,9 @@ COUNTERS = (
     # decoded for a slot that had finished or was re-bound (run-ahead)
     "tokens_discarded",
     "decode_steps", "decode_slot_steps",  # slot_steps: sum of active slots
+    # of decode_steps, those a prompt chunk's launch carried (no ``decode_fn``
+    # ran for them: the rows rode through ``chunk_mid`` or ``chunk_final``)
+    "decode_steps_in_chunk",
     # a prompt's chunks by kind, one a prompt chunk: a row of a chunk program
     "prefill_chunks:mid", "prefill_chunks:final",
     # launches of the chunk programs: admissions whose next chunks are of one
@@ -317,6 +320,7 @@ class _Pool:
         self.slots: list[Optional[_Request]] = [None] * n_slots
         self.temps = np.zeros((n_slots,), np.float32)
         self.top_ks = np.full((n_slots,), 50, np.int32)
+        self.sampler_dev = None  # the two on the device, until a slot's change (``sampler``)
         self.keys = None  # per-slot PRNG keys, set by the engine loop
         self.adapter_ids = np.zeros((n_slots,), np.int32)
         self.adapter_ids_dev = None
@@ -324,8 +328,9 @@ class _Pool:
         # without a host round trip (run-ahead)
         self.dev_tokens = None  # [n_slots] int32 on device
         self.admitting: dict[int, _Admission] = {}
-        # launched decode programs whose sampled tokens are still being
-        # fetched: (out_dev [K, slots], {slot: _Request} binding snapshot)
+        # launched decode steps whose sampled tokens are still being fetched:
+        # (out_dev [K, slots], {slot: _Request} binding snapshot, routing
+        # counts or None); a step that a chunk launch carried: ([slots], .., None)
         self.inflight: "deque" = deque()
         # first tokens from final prefill chunks awaiting host arrival
         self.first_pending: list = []
@@ -333,9 +338,22 @@ class _Pool:
         # decode kernel: asked once, of the layer that decides it, with the
         # arrays the steps run on
         self.chunk_rows = 1  # the most rows of one middle-chunk launch (the engine sets it)
+        # whether this pool's chunk programs take its decode rows (the engine
+        # sets it), and whether a chunk launch of this pass carried its step
+        self.carries = False
+        self.step_carried = False
         self.reads_blocks = reads_blocks(
             stripe_len, self.cache["k"], *jax.tree.leaves(params), latent=self.latent
         )
+
+    def sampler(self) -> tuple:
+        """The slots' temperatures and top-k on the device: transferred when
+        a slot's have changed (``sampler_dev`` dropped), not once a launch."""
+        import jax.numpy as jnp
+
+        if self.sampler_dev is None:
+            self.sampler_dev = (jnp.asarray(self.temps), jnp.asarray(self.top_ks))
+        return self.sampler_dev
 
     def positions_read(self, lo, hi) -> int:
         """Positions a decode step reads of the stripes of slots bounded
@@ -397,8 +415,11 @@ def programs(cfg, decode_steps: int = 1) -> dict:
 
     def decode_fn(params, cache, tokens, temps, top_ks, keys,
                   loras=None, adapter_ids=None):
-        """Decode + in-program sampling with per-slot PRNG keys
-        (per-request seeds stay reproducible across batch compositions)."""
+        """Decode + in-program sampling with per-slot PRNG keys: a request's
+        key is its own, so what its seed draws does not depend on what else
+        is in the batch. Its logits can: on a chip a row's numbers differ in
+        the last bit by what shares its launch (the other slots of a step,
+        and in a pool whose chunk launches carry the step, a prompt's chunk)."""
         logits, cache = decode_step(
             params, stats_in(cache), tokens, cfg,
             loras=loras, adapter_ids=adapter_ids,
@@ -409,6 +430,19 @@ def programs(cfg, decode_steps: int = 1) -> dict:
                 logits, temps, top_ks, keys
             )
         return next_tokens, cache, new_keys, stats
+
+    def sample_riders(logits, rows):
+        """The next input tokens and keys of a pool whose decode step rode
+        through a chunk program (``rows``: what ``decode_fn`` takes beside the
+        cache, and ``live``): each live row sampled by the one ``sample_row``,
+        every other row's token and key as they came in."""
+        with jax.named_scope("sampling"):
+            next_tokens, new_keys = jax.vmap(sample_row)(
+                logits, rows["temps"], rows["top_ks"], rows["keys"]
+            )
+            live = rows["live"]
+            return (jnp.where(live, next_tokens, rows["tokens"]),
+                    jnp.where(live[:, None], new_keys, rows["keys"]))
 
     n_steps = max(1, decode_steps)
 
@@ -431,7 +465,7 @@ def programs(cfg, decode_steps: int = 1) -> dict:
             stats = stats.sum(axis=0)
         return out, cache, keys, stats  # out: [K, slots]
 
-    def chunk_mid(params, ones, tokens, lengths, starts,
+    def chunk_mid(params, ones, tokens, lengths, starts, cache=None, rows=None,
                   loras=None, adapter_ids=None):
         """Extend each row's scratch stripe with its prompt's next chunk
         — no LM head (mid-chunks of chunked prefill never need logits).
@@ -439,45 +473,77 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         stripe is the cache ``prefill`` extends where it lies; several rows'
         are stacked into one (a copy of each) and handed back a row each (a
         second copy; both under ``kv_write``: PERF.md section 6, PR 34). The
-        launch's routing counts ride with the first row's."""
+        launch's routing counts ride with the first row's.
+
+        With the pool's ``cache`` and its ``rows`` (``tokens``, ``temps``,
+        ``top_ks``, ``keys`` as ``decode_fn`` takes them, and ``live`` [slots]:
+        the slots that decode in this launch) the launch carries the pool's
+        decode step: the rows that decode ride through the same read of the
+        weights and banks as the chunk's tokens (``models/patterned.py
+        decode_forward``), and the result ends with what ``decode_fn`` hands
+        back: ``(stripes, next tokens [slots], cache, keys)``. A launch that
+        carries none passes no live row; the counts of all its rows, the
+        decode rows' too, ride with the first stripe."""
         if len(ones) == 1:
-            cache = ones[0]
+            stripes = ones[0]
         else:
             with jax.named_scope("kv_write"):
-                cache = {
+                stripes = {
                     k: jnp.concatenate([one[k] for one in ones], axis=0 if k == "length" else 1)
                     for k in (*slot_leaves, "length")
                 }
             if routed:
-                cache["moe_stats"] = ones[0]["moe_stats"]
-        _, cache = prefill(
-            params, cache, tokens, cfg, lengths=lengths, start_pos=starts,
+                stripes["moe_stats"] = ones[0]["moe_stats"]
+        _, stripes, *rode = prefill(
+            params, stripes, tokens, cfg, lengths=lengths, start_pos=starts,
             loras=loras, adapter_ids=adapter_ids, with_logits=False,
+            beside=None if rows is None else (cache, rows["tokens"], rows["live"]),
         )
         if len(ones) == 1:
-            return (cache,)
-        with jax.named_scope("kv_write"):
-            return tuple(
-                {**one, **{k: cache[k][:, i:i + 1] for k in slot_leaves},
-                 "length": cache["length"][i:i + 1],
-                 **({"moe_stats": cache["moe_stats"]} if routed and i == 0 else {})}
-                for i, one in enumerate(ones)
-            )
+            out = (stripes,)
+        else:
+            with jax.named_scope("kv_write"):
+                out = tuple(
+                    {**one, **{k: stripes[k][:, i:i + 1] for k in slot_leaves},
+                     "length": stripes["length"][i:i + 1],
+                     **({"moe_stats": stripes["moe_stats"]} if routed and i == 0 else {})}
+                    for i, one in enumerate(ones)
+                )
+        if rows is None:
+            return out
+        logits, cache = rode
+        next_tokens, new_keys = sample_riders(logits, rows)
+        return out, next_tokens, cache, new_keys
 
     def chunk_final(params, cache, one, tokens, length, start, slot,
-                    temp, top_k, key, loras=None, adapter_ids=None):
+                    temp, top_k, key, rows=None, loras=None, adapter_ids=None):
         """Last prompt chunk: prefill it, sample the first generated
         token IN-PROGRAM (no host sync on the admission path), and
-        copy the finished stripe into the pool slot. One row a launch: a
-        row's arithmetic on the chip is not to the bit what it is beside a
-        companion (PERF.md section 6, PR 34), and the chunk that gives a
-        request its first token behind a seeded prefix has to give what it
-        gave behind the computed one."""
+        copy the finished stripe into the pool slot. One prompt a launch: two
+        prompts' final chunks are not rows of one program (PERF.md section 6,
+        PR 34).
+
+        With the pool's ``rows`` (as ``chunk_mid`` takes them) the launch
+        carries the pool's decode step as well: the live rows decode on
+        ``cache`` beside the chunk's tokens, and the result ends with ``(..,
+        next tokens [slots], keys)``, in which ``slot`` holds the request's
+        first token and its key (the tokens are also what the step's fetch
+        reads, so no later program may overwrite them): the slot this chunk
+        activates is no decode row of the same launch (``live[slot]`` is
+        false), and whatever a dead row left in its stripe the copy
+        overwrites. A row's logits can then
+        differ in the last bit by what shares its launch; a pool whose
+        answers have to be the same to the token whatever runs beside them
+        (a latent pool: ``JaxEngine.__init__``) passes no rows and runs the
+        chunk alone."""
         mid_stats = one.get("moe_stats")  # the prompt's middle chunks'
-        last_logits, one = prefill(
+        last_logits, one, *rode = prefill(
             params, one, tokens, cfg, lengths=length, start_pos=start,
             loras=loras, adapter_ids=adapter_ids,
+            beside=None if rows is None else (cache, rows["tokens"], rows["live"]),
         )
+        if rode:
+            logits, cache = rode
         stats = one.pop("moe_stats", None)
         if stats is not None:  # rows: chunk_mid, chunk_final
             stats = jnp.stack([mid_stats, stats - mid_stats])
@@ -489,7 +555,11 @@ def programs(cfg, decode_steps: int = 1) -> dict:
             }
         with jax.named_scope("sampling"):
             tok, new_key = sample_row(last_logits[0], temp, top_k, key)
-        return tok, new_key, cache, one, stats
+        if rows is None:
+            return tok, new_key, cache, one, stats
+        next_tokens, new_keys = sample_riders(logits, rows)
+        return (tok, new_key, cache, one, stats,
+                next_tokens.at[slot].set(tok), new_keys.at[slot].set(new_key))
 
     def new_stripe(stripe_len):
         """A zeroed scratch stripe (a program, so that it can be placed:
@@ -549,8 +619,35 @@ class JaxEngine:
         # rows of long documents ran longer in one launch than one after
         # another (PERF.md section 6, PR 34)
         rows = max(1, min(config.engine.max_concurrent_admissions, CHUNK_ROWS_MAX))
+        # A pool's chunk programs take its decode rows, so that a chunk launch
+        # can carry the pool's decode step (``_advance_admissions``): one form
+        # of each program a pool, the rows an input. Decided here, once, from
+        # what the pool and the model are:
+        # - not a latent pool: a row's logits differ in the last bit by what
+        #   shares its matmuls (PERF.md section 6, PR 34), and this pool's
+        #   answers are held to be the same to the token for a prompt seeded
+        #   from the prefix store and computed (a hit and a miss sent at once
+        #   would decode beside different chunks); its launches stay the
+        #   chunk's alone until that comparison allows a rounding (ROADMAP D12);
+        # - only a stack that is traced as one layer body (``plan(cfg).bodies``:
+        #   layers alike under one loop): every chunk form then holds a decode
+        #   program's worth of tracing and lowering more, and a start pays
+        #   that in Python for each form, fetched from the compile cache or
+        #   not. One body costs a v5e host half a second a form; Laguna's five
+        #   and Nemotron's eleven bodies, each with its own kernels, would
+        #   double 24 and 31 s of warm-up, a quarter of a replica's start
+        #   (PERF.md section 6, PR 41; ROADMAP S2: a start that restores its
+        #   executables lifts this);
+        # - not with adapters loaded (a row's adapter is indexed by row of a
+        #   batch), ``decode_steps`` over 1 (a carried step is one step) or
+        #   over a mesh.
+        from ray_tpu.models.patterned import plan
+
+        carries = (self.loras is None and config.engine.decode_steps <= 1
+                   and not self._spans_devices() and plan(self.model_cfg).bodies == 1)
         for pool in self._pools:
             pool.chunk_rows = 1 if pool.latent else rows
+            pool.carries = carries and not pool.latent
         self._init_phase(self._compile)
         self._init_phase(self._warm_programs)
         self._waiting: "queue.Queue[_Request]" = queue.Queue()
@@ -568,6 +665,19 @@ class JaxEngine:
             target=self._engine_loop, daemon=True, name="llm-engine"
         )
         self._thread.start()
+
+    def _spans_devices(self) -> bool:
+        return self._mesh is not None and self._mesh.size > 1
+
+    def _held(self, tree):
+        """``tree``'s arrays committed where they lie (no copy), on an engine
+        of one device; over a mesh they are left for the programs to place."""
+        import jax
+
+        if self._spans_devices():
+            return tree
+        return jax.tree.map(
+            lambda x: x if x.committed else jax.device_put(x, x.sharding), tree)
 
     def _init_phase(self, phase: Callable[[], None]) -> None:
         t = time.perf_counter()
@@ -730,7 +840,8 @@ class JaxEngine:
         fns = programs(cfg, self._decode_n_steps)
         self._decode_jit = jax.jit(fns["decode_fn"], donate_argnums=(1,))
         self._decode_multi_jit = jax.jit(fns["decode_multi"], donate_argnums=(1,))
-        self._chunk_mid_jit = jax.jit(fns["chunk_mid"], donate_argnums=(1,))
+        # (5: the pool's cache, where a launch takes the pool's decode rows)
+        self._chunk_mid_jit = jax.jit(fns["chunk_mid"], donate_argnums=(1, 5))
         # donate the scratch stripe too and hand it back (the caller drops
         # it): a stripe the program may not overwrite is copied before the
         # chunk is written into it, and the v5e compiler then moved a whole
@@ -747,7 +858,7 @@ class JaxEngine:
             fns["new_stripe"], static_argnums=(0,),
             out_shardings=(
                 NamedSharding(self._mesh, PartitionSpec())
-                if self._mesh is not None and self._mesh.size > 1
+                if self._spans_devices()
                 else SingleDeviceSharding(jax.local_devices()[0])
             ),
         )
@@ -783,9 +894,11 @@ class JaxEngine:
         checkout's first start compiles them all here
         (``LLMConfig.compile_budget_s``); later starts fetch them from the
         compile cache. The rows write one token at position 0 of slot 0,
-        which holds no request and which an admission overwrites whole. Each
-        program is waited for where it was run, so that ``_warm_s`` holds the
-        seconds by program (a pool's own set-up goes to its first)."""
+        which holds no request and which an admission overwrites whole; a
+        pool that ``carries`` hands its chunk programs its decode rows as the
+        loop does, none of them live. Each program is waited for where it was
+        run, so that ``_warm_s`` holds the seconds by program (a pool's own
+        set-up goes to its first)."""
         import jax
         import jax.numpy as jnp
 
@@ -801,11 +914,16 @@ class JaxEngine:
         rng_key = self._rng_key
         jax.random.PRNGKey(0)  # a seeded request's key is a program too
         for i, pool in enumerate(self._pools):
-            pool.keys = jax.random.split(
+            # what every program of the pool takes and hands back, of the kind
+            # a program hands it back: the first run of each is then the form
+            # the loop runs (an eager array is not committed, a program's
+            # result is, and each kind of argument is a compilation)
+            pool.cache = self._held(pool.cache)
+            pool.keys = self._held(jax.random.split(
                 jax.random.PRNGKey(self.config.model.seed ^ (0x5EED + i)),
                 pool.n_slots,
-            )
-            pool.dev_tokens = jnp.zeros((pool.n_slots,), jnp.int32)
+            ))
+            pool.dev_tokens = self._held(jnp.zeros((pool.n_slots,), jnp.int32))
             self._sync_adapter_ids(pool)
             mid, finals = self._chunk_widths(pool)
             stripe = pool.stripe_len
@@ -814,7 +932,7 @@ class JaxEngine:
                 return dict(
                     ones=tuple(self._new_stripe_jit(stripe) for _ in range(rows)),  # noqa: B023
                     toks=np.zeros((rows, mid), np.int32), lens=[1] * rows,  # noqa: B023
-                    starts=[0] * rows, adapters=[0] * rows,
+                    starts=[0] * rows, adapters=[0] * rows, pool=pool,  # noqa: B023
                 )
 
             for rows in range(1, pool.chunk_rows + 1 if mid else 1):
@@ -842,8 +960,7 @@ class JaxEngine:
                             pool.cache["k"][:, 0, :, :b], pool.cache["v"][:, 0, :, :b]))
             for _ in range(2):  # the cache, keys and tokens as a chunk left them, then as a step did
                 out, pool.cache, pool.keys, _ = self._decode(
-                    pool, pool.dev_tokens, jnp.asarray(pool.temps),
-                    jnp.asarray(pool.top_ks), pool.keys,
+                    pool, pool.dev_tokens, *pool.sampler(), pool.keys,
                 )
                 pool.dev_tokens = out[-1]
             book("decode", (pool.cache, pool.dev_tokens))
@@ -1428,9 +1545,11 @@ class JaxEngine:
                 n * start + n * (n + 1) // 2 for _, n, start in plan),
         })
 
-    def _launch_mid_chunks(self, pool: "_Pool", adms: list) -> None:
+    def _launch_mid_chunks(self, pool: "_Pool", adms: list, carry: Optional[dict] = None) -> None:
         """Dispatch ONE ``chunk_mid`` (device-async) whose rows are the next
-        middle chunks of ``adms``: each row's stripe comes back extended."""
+        middle chunks of ``adms``: each row's stripe comes back extended.
+        ``carry``: the slots (slot -> request) whose decode step the launch
+        carries."""
         plan = [self._next_chunk(adm) for adm in adms]
         ones = self._run_chunk_mid(
             ones=tuple(adm.one for adm in adms),
@@ -1438,26 +1557,83 @@ class JaxEngine:
             lens=[eff_len for _, eff_len, _ in plan],
             starts=[start for _, _, start in plan],
             adapters=[adm.req.lora_idx for adm in adms],
+            pool=pool, carry=carry,
         )
         self._count_chunks("mid", plan)
         for adm, one in zip(adms, ones):
             adm.one = one
 
-    def _run_chunk_mid(self, ones: tuple, toks, lens: list, starts: list, adapters: list) -> tuple:
-        """The device side of a middle-chunk launch, a row an entry."""
+    @staticmethod
+    def _takes_rows(pool: "_Pool", rows: int = 1) -> bool:
+        """Whether ``pool``'s chunk program of ``rows`` prompt rows takes the
+        pool's decode rows: in a pool that ``carries``, the final chunk and
+        the middle chunk of one row. A launch of several rows is rare where
+        prompts are short and its forms are as many as its row counts, each a
+        decode program's worth of tracing more at every start: it stays the
+        chunks' alone."""
+        return pool.carries and rows == 1
+
+    def _decode_rows(self, pool: "_Pool", carry: Optional[dict]) -> dict:
+        """What a chunk program of a pool that ``carries`` takes of the pool's
+        decode step beside the cache: the inputs ``decode_fn`` takes, and the
+        rows that decode in this launch (none without ``carry``)."""
         import jax.numpy as jnp
 
+        live = np.zeros((pool.n_slots,), bool)
+        live[list(carry or ())] = True
+        temps, top_ks = pool.sampler()
+        return dict(tokens=pool.dev_tokens, temps=temps, top_ks=top_ks, keys=pool.keys,
+                    live=jnp.asarray(live))
+
+    def _carried(self, pool: "_Pool", carry: Optional[dict], next_tokens) -> None:
+        """After a chunk launch of a pool that ``carries``: the pool's next
+        input tokens are the program's, and a step it carried goes on
+        ``pool.inflight`` with its binding as a decode launch's does (its
+        routing counts are among the chunk program's)."""
+        pool.dev_tokens = next_tokens
+        if not carry:
+            return
+        try:
+            next_tokens.copy_to_host_async()
+        except Exception:  # noqa: BLE001
+            pass
+        pool.inflight.append((next_tokens, carry, None))
+        pool.step_carried = True
+        self._count({**self._decode_counts(pool, carry, 1), "decode_steps_in_chunk": 1})
+
+    def _run_chunk_mid(self, ones: tuple, toks, lens: list, starts: list, adapters: list,
+                       pool: Optional["_Pool"] = None, carry: Optional[dict] = None) -> tuple:
+        """The device side of a middle-chunk launch, a row an entry. ``pool``:
+        the stripes' pool (None: found by their length); where it ``carries``
+        the one-row program takes its cache and decode rows, and with
+        ``carry`` they decode in this launch (a launch of several rows is the
+        chunks' alone: ``_takes_rows``)."""
+        import jax.numpy as jnp
+
+        if pool is None:
+            pool = next(p for p in self._pools if p.stripe_len == ones[0]["k"].shape[3])
+        takes = self._takes_rows(pool, len(ones))
         with tracing.annotate("engine.chunk_transfer"):  # the host's arrays
             args = (jnp.asarray(toks), jnp.asarray(lens, jnp.int32),
                     jnp.asarray(starts, jnp.int32))
             lora_kw = self._lora_kw(adapters)
+            if takes:
+                args += (pool.cache, self._decode_rows(pool, carry))
         with tracing.annotate("engine.chunk_call"):
-            return self._chunk_mid_jit(self.params, ones, *args, **lora_kw)
+            out = self._chunk_mid_jit(self.params, ones, *args, **lora_kw)
+        if not takes:
+            return out
+        ones, next_tokens, pool.cache, pool.keys = out
+        self._carried(pool, carry, next_tokens)
+        return ones
 
-    def _launch_final_chunk(self, pool: "_Pool", adm: _Admission) -> None:
-        """Dispatch a prompt's final chunk (device-async), one row a launch
+    def _launch_final_chunk(self, pool: "_Pool", adm: _Admission,
+                            carry: Optional[dict] = None) -> None:
+        """Dispatch a prompt's final chunk (device-async), one prompt a launch
         (``programs``' ``chunk_final`` says why): it samples the first token
-        in-program and activates the slot."""
+        in-program and activates the slot. ``carry``: the slots (slot ->
+        request) whose decode step the launch carries; the slot it activates
+        is bound after the launch and is none of them."""
         toks, eff_len, start = self._next_chunk(adm)
         req, slot = adm.req, adm.slot
         # decode truncates to the program's static top-K; clamp here so
@@ -1467,12 +1643,13 @@ class JaxEngine:
         self._sync_adapter_ids(pool)
         first_tok, stats = self._run_chunk_final(
             pool, adm.one, toks, eff_len, start, slot,
-            req.params.temperature, top_k, req.params.seed, req.lora_idx,
+            req.params.temperature, top_k, req.params.seed, req.lora_idx, carry=carry,
         )
         self._count_chunks("final", [(toks, eff_len, start)])
         pool.slots[slot] = req
         pool.temps[slot] = req.params.temperature
         pool.top_ks[slot] = top_k
+        pool.sampler_dev = None
         del pool.admitting[slot]
         if req.prefix_hit_tokens == 0 and req.lora_idx == 0:
             # LoRA'd prefixes are adapter-specific: never shared
@@ -1487,10 +1664,13 @@ class JaxEngine:
         pool.first_pending.append((slot, req, first_tok, stats))
 
     def _run_chunk_final(self, pool: "_Pool", one, toks, eff_len: int, start: int, slot: int,
-                         temperature: float, top_k: int, seed: Optional[int], adapter: int):
+                         temperature: float, top_k: int, seed: Optional[int], adapter: int,
+                         carry: Optional[dict] = None):
         """The device side of a final-chunk launch: the pool's cache, keys
-        and next input tokens take the slot's new values. Returns the first
-        token and the routing counts (or None), both still on the device."""
+        and next input tokens take the slot's new values (and, in a pool that
+        ``carries``, with ``carry`` those of the rows that decode in this
+        launch). Returns the first token and the routing counts (or None),
+        both still on the device."""
         import jax
         import jax.numpy as jnp
 
@@ -1504,9 +1684,15 @@ class JaxEngine:
                     jnp.asarray([start], jnp.int32), slot_dev,
                     jnp.float32(temperature), jnp.int32(top_k), req_key)
             lora_kw = self._lora_kw([adapter])
+            if pool.carries:
+                args += (self._decode_rows(pool, carry),)
         with tracing.annotate("engine.chunk_call"):
-            first_tok, new_key, pool.cache, _, stats = self._chunk_final_jit(
+            first_tok, new_key, pool.cache, _, stats, *rode = self._chunk_final_jit(
                 self.params, pool.cache, one, *args, **lora_kw)
+        if rode:  # the program set the slot's key and next input token itself
+            next_tokens, pool.keys = rode
+            self._carried(pool, carry, next_tokens)
+            return first_tok, stats
         with tracing.annotate("engine.slot_set"):  # the slot's key and next input token
             pool.keys = self._set_key_jit(pool.keys, slot_dev, new_key)
             pool.dev_tokens = self._set_tok_jit(pool.dev_tokens, slot_dev, first_tok)
@@ -1573,7 +1759,14 @@ class JaxEngine:
         of ONE ``chunk_mid``, as many rows as are due: a read of the weights
         then serves every prompt that waits for it. A final chunk is a
         launch of its own. A launch that raises fails its rows' requests,
-        and no others."""
+        and no others.
+
+        In a pool that ``carries``, the pass's first chunk launch carries the
+        pool's decode step where one is due (``_decode_due``: the rule
+        ``_launch_decodes`` launches by, which then launches none for that
+        pool in this pass): the rows that decode ride through the chunk's read
+        of the weights, one program where there were two. Later launches of
+        the pass, and passes with no chunk, run as they did."""
         progressed = False
         for pool in self._pools:
             launches, mids = [], None  # mids: the middle-chunk launch with room left
@@ -1593,39 +1786,72 @@ class JaxEngine:
                     launches.append((False, mids))
                 mids.append(adm)
             for is_final, adms in launches:
+                carry = None
+                if self._takes_rows(pool, len(adms)) and not pool.step_carried:
+                    carry = self._decode_due(pool)
                 try:
                     with self._device_call(
                         "launch", "chunk_final" if is_final else "chunk_mid",
                         "engine.prefill_chunk",
                     ):
                         if is_final:
-                            self._launch_final_chunk(pool, adms[0])
+                            self._launch_final_chunk(pool, adms[0], carry)
                         else:
-                            self._launch_mid_chunks(pool, adms)
+                            self._launch_mid_chunks(pool, adms, carry)
                     progressed = True
                 except BaseException as e:  # noqa: BLE001
                     for adm in adms:
                         self._fail_admission(pool, adm, e)
         return progressed
 
+    def _decode_due(self, pool: "_Pool") -> Optional[dict]:
+        """The slots (slot -> request) of ``pool``'s next decode step, or None
+        where none is due: no slot holds a request, or the run-ahead is full."""
+        active = {s: r for s, r in enumerate(pool.slots) if r is not None}
+        if not active or len(pool.inflight) > max(0, self.config.engine.decode_runahead):
+            return None
+        return active
+
+    def _decode_counts(self, pool: "_Pool", active: dict, steps: int) -> dict:
+        """What ``steps`` decode steps over the slots ``active`` count, from
+        the lengths the loop holds at their launch."""
+        lengths = np.fromiter(
+            (len(r.prompt_token_ids) + len(r.out_tokens) for r in active.values()),
+            np.int64, len(active),
+        )
+        tokens, read = (
+            ("decode_kv_tokens_latent", "decode_kv_positions_read_latent")
+            if pool.latent else ("decode_kv_tokens_global", "decode_kv_positions_read")
+        )
+        counts = {
+            "decode_steps": steps, "decode_slot_steps": steps * len(active),
+            tokens: steps * int(lengths.sum()),
+            read: steps * pool.positions_read(0, lengths),
+        }
+        window = self.model_cfg.sliding_window
+        if window:  # a model without one has no window layers to count for
+            counts["decode_kv_tokens_window"] = steps * int(
+                np.minimum(lengths, window).sum())
+            counts["decode_kv_positions_read_window"] = steps * pool.positions_read(
+                lengths - window, lengths)
+        return counts
+
     def _launch_decodes(self) -> bool:
         """One decode program per pool with active slots, chained on
-        device-resident tokens (no host sync on the launch path)."""
-        import jax.numpy as jnp
-
+        device-resident tokens (no host sync on the launch path). None for a
+        pool whose step a chunk launch of this pass carried."""
         launched = False
-        runahead = max(0, self.config.engine.decode_runahead)
         for pool in self._pools:
-            active = {s: r for s, r in enumerate(pool.slots) if r is not None}
-            if not active or len(pool.inflight) > runahead:
+            carried, pool.step_carried = pool.step_carried, False
+            active = None if carried else self._decode_due(pool)
+            if active is None:
                 continue
             try:
                 with self._device_call("launch", "decode", "engine.decode_launch"):
                     out, pool.cache, pool.keys, stats = self._decode(
                         pool,
                         pool.dev_tokens,
-                        jnp.asarray(pool.temps),
-                        jnp.asarray(pool.top_ks),
+                        *pool.sampler(),
                         pool.keys,
                     )
                     pool.dev_tokens = out[-1]
@@ -1636,27 +1862,7 @@ class JaxEngine:
                     except Exception:  # noqa: BLE001
                         pass
                 pool.inflight.append((out, active, stats))
-                lengths = np.fromiter(
-                    (len(r.prompt_token_ids) + len(r.out_tokens) for r in active.values()),
-                    np.int64, len(active),
-                )
-                steps = self._decode_n_steps
-                tokens, read = (
-                    ("decode_kv_tokens_latent", "decode_kv_positions_read_latent")
-                    if pool.latent else ("decode_kv_tokens_global", "decode_kv_positions_read")
-                )
-                counts = {
-                    "decode_steps": steps, "decode_slot_steps": steps * len(active),
-                    tokens: steps * int(lengths.sum()),
-                    read: steps * pool.positions_read(0, lengths),
-                }
-                window = self.model_cfg.sliding_window
-                if window:  # a model without one has no window layers to count for
-                    counts["decode_kv_tokens_window"] = steps * int(
-                        np.minimum(lengths, window).sum())
-                    counts["decode_kv_positions_read_window"] = steps * pool.positions_read(
-                        lengths - window, lengths)
-                self._count(counts)
+                self._count(self._decode_counts(pool, active, self._decode_n_steps))
                 launched = True
             except BaseException as e:  # noqa: BLE001 — device failure
                 self._fail_pool(pool, e)
@@ -1678,15 +1884,16 @@ class JaxEngine:
             self._fail_admission(pool, adm, e, stage="decode")
         pool.inflight.clear()
         pool.first_pending.clear()
-        pool.cache = init_kv_cache(self.model_cfg, pool.n_slots, pool.stripe_len)
-        pool.dev_tokens = jax.numpy.zeros((pool.n_slots,), jax.numpy.int32)
+        pool.step_carried = False
+        pool.cache = self._held(init_kv_cache(self.model_cfg, pool.n_slots, pool.stripe_len))
+        pool.dev_tokens = self._held(jax.numpy.zeros((pool.n_slots,), jax.numpy.int32))
         # keys may already point at the failed program's poisoned output
         # (reassigned in _launch_decodes before the error surfaced at
         # fetch): without fresh keys every future admission fails too
-        pool.keys = jax.random.split(
+        pool.keys = self._held(jax.random.split(
             jax.random.PRNGKey(self.config.model.seed ^ int(time.time())),
             pool.n_slots,
-        )
+        ))
 
     def _drain(self) -> bool:
         """Fetch arrived tokens (first tokens + completed decode programs)
@@ -1698,12 +1905,16 @@ class JaxEngine:
         their last token: whoever a close wakes finds the counters holding
         its tokens, and ``finished_t`` (so ``token_gap_s`` and the ring's
         ``engine.decode``) ends where it always did. The stream's terminator
-        and ``done`` follow by the rest of the block's emits."""
+        and ``done`` follow by the rest of the block's emits. A first token is
+        waited for where it was launched, but in a pool whose chunk launches
+        carry the decode step: there it is taken once it has arrived."""
         progressed = False
         runahead = max(0, self.config.engine.decode_runahead)
         for pool in self._pools:
             if pool.first_pending:
                 pending, pool.first_pending = pool.first_pending, []
+                if pool.carries:
+                    pending, pool.first_pending = self._arrived(pool, pending, runahead)
                 for slot, req, tok, stats in pending:
                     try:
                         with self._device_call("fetch", "first_token", "engine.fetch"):
@@ -1730,7 +1941,7 @@ class JaxEngine:
                 out, binding, stats = pool.inflight.popleft()
                 try:
                     with self._device_call("fetch", "decode", "engine.fetch"):
-                        arr = np.asarray(out)  # [K, slots]
+                        arr = np.atleast_2d(np.asarray(out))  # [K, slots] (a carried step's: [slots])
                         counts = self._routing_counts(stats, ("decode",))
                 except BaseException as e:  # noqa: BLE001
                     self._fail_pool(pool, e)
@@ -1759,6 +1970,24 @@ class JaxEngine:
                     req.pacer.note_block(n)
                 progressed = True
         return progressed
+
+    @staticmethod
+    def _arrived(pool: "_Pool", pending: list, runahead: int) -> tuple:
+        """``pending`` first tokens of a pool that carries, as (those to take
+        now, those left for a later pass). Nothing is queued behind a chunk
+        launch that carried the decode step, so a wait for its first token
+        would let the chip run dry while the loop comes round: a token is
+        taken in the pass that finds it arrived, and at the latest before the
+        fetch of a step that decoded its slot (the steps this pass fetches:
+        all but ``runahead``)."""
+        fetched = list(pool.inflight)[:max(0, len(pool.inflight) - runahead)]
+        decoded = {(slot, id(req)) for _, binding, _ in fetched for slot, req in binding.items()}
+        now, later = [], []
+        for entry in pending:
+            slot, req, tok, _ = entry
+            due = (slot, id(req)) in decoded or tok.is_ready()
+            (now if due else later).append(entry)
+        return now, later
 
     @staticmethod
     def _routing_counts(stats, programs: tuple) -> dict:

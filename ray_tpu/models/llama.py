@@ -1003,9 +1003,15 @@ def init_lora_stack(cfg: LlamaConfig, n_adapters: int, rank: int):
 def prefill(
     params, cache, tokens, cfg: LlamaConfig, lengths=None,
     loras=None, adapter_ids=None, start_pos=None, with_logits: bool = True,
+    beside=None,
 ):
     """Process a prompt batch. tokens: [B, T] (right-padded); lengths: [B].
     Returns (last-token logits [B, vocab] or None, cache).
+
+    ``beside``: ``(cache, tokens [B2], live [B2] or None)``, the rows of a
+    decode step that ride through the same read of the weights
+    (``models/patterned.py decode_forward``); the result then ends with their
+    ``decode_step``: ``(.., cache, logits [B2, vocab], their cache)``.
 
     ``start_pos`` [B]: absolute position of tokens[:, 0] — the SUFFIX
     prefill used by prefix caching and chunked admission (the cache already
@@ -1019,16 +1025,18 @@ def prefill(
         start_pos = jnp.zeros((B,), jnp.int32)
     positions = rel + start_pos[:, None]
     valid = rel < lengths[:, None]
-    logits, cache = decode_forward(
+    logits, cache, *rode = decode_forward(
         params, cache, tokens, positions, cfg, valid,
         loras=loras, adapter_ids=adapter_ids, with_logits=with_logits,
         logits_at=None if not with_logits else lengths - 1,
-        start_pos=start_pos,
+        start_pos=start_pos, beside=beside,
     )
     cache["length"] = start_pos + lengths
+    if rode:
+        rode = [rode[0][:, -1], rode[1]]
     if not with_logits:
-        return None, cache
-    return logits[:, 0], cache
+        return (None, cache, *rode)
+    return (logits[:, 0], cache, *rode)
 
 
 def decode_step(

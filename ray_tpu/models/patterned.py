@@ -137,6 +137,12 @@ class Plan:
     def whole(self) -> bool:
         return self.n_attention == self.n_ffn == len(self.kinds)
 
+    @property
+    def bodies(self) -> int:
+        """Layer bodies a pass through the stack is traced as: what a start
+        pays in tracing and lowering for every program that walks it."""
+        return self.lead + (self.period if self.reps else 0) + len(self.kinds) - self.tail_from
+
     def leaf(self, name: str, kind: str) -> str:
         """The leaf that holds projection ``name`` of attention kind ``kind``."""
         return f"{name}_{kind}" if self.by_kind else name
@@ -697,6 +703,8 @@ class _Layer:
         self.wq, self.wo, self.wg = (pl.leaf(n, self.kind) for n in ("wq", "wo", "wg"))
         self.by_kind = pl.by_kind
         self.latent = self.kind == "latent"
+        # the stack's last layer, traced on its own (not a pass of the loop)
+        self.last = l_static == len(pl.kinds) - 1 and isinstance(l, int)
 
     def inner_scope(self):
         """The name under ``attn_core``: ``global`` or ``window`` where the
@@ -797,36 +805,49 @@ def _attn_out(params, lay: _Layer, x, h, attn, cfg, from_latent: bool = False):
         return x + jnp.einsum("bthd,hde->bte", attn, params[lay.wo][lay.attn_i])
 
 
-def _ssm_mixer(params, lay: _Layer, h, state_all, conv_all, valid, cfg):
-    """A state-space mixer (Mamba-2) on the normed h [B, T, e] of the rows'
-    next tokens, from and into the carried state ``state_all`` [n, B, H, P, N]
-    and convolution tails ``conv_all`` [n, B, taps - 1, channels] at this
-    layer's row. ``valid`` [B, T] (or None: all) marks a row's real tokens, a
-    prefix of it: a token that is none gets a step of 0, which leaves the
-    state where the row's last real token put it, and the tail is cut where
-    the row ends. One token a row takes the recurrence's own line on the leaf
-    where it lies (``ops/ssm.py ssm_step_in_place``: a kernel where the state
-    tiles, ``ssm_step`` where not), more the chunked scan (``ssm_scan``): the
-    same state either way. Returns (the output [B, T, e], the two leaves).
-
-    Its scopes lie inside the attention's three, by what the work is (input
-    projections, the mixing itself, gate and output projection), under
-    ``ssm_mixer``; ``ssm_conv`` and ``ssm_scan`` or ``ssm_step`` inside
-    ``attn_core/ssm_mixer``."""
-    from ray_tpu.ops.ssm import causal_conv, ssm_scan, ssm_step_in_place
-
+def _ssm_in(params, lay: _Layer, h, valid, cfg):
+    """A state-space mixer's input projection of the normed h [B, T, e]: the
+    gate ``z``, what the convolution runs over (x, B and C) and the float32
+    step a head, 0 where ``valid`` [B, T] (or None: all) says a token is none.
+    Rows are independent here, so several sets of them go through as one
+    (``decode_forward``). The three parts of a mixer (this, ``_ssm_mix``,
+    ``_ssm_out``) keep their scopes inside the attention's three, by what the
+    work is, under ``ssm_mixer``; ``ssm_conv`` and ``ssm_scan`` or
+    ``ssm_step`` inside ``attn_core/ssm_mixer``."""
     i = lay.attn_i  # the row among the state-space layers
     d = ssm_dims(cfg)
-    Bsz, T, _ = h.shape
-    H, P, N, G = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups
     f32 = jnp.float32
     with scope("attn_qkv"), scope("ssm_mixer"):
         proj = jnp.einsum("bte,ef->btf", h, params["ssm_w_in"][i])
         z = proj[..., : d["inner"]]
         xbc = proj[..., d["inner"]: d["inner"] + d["conv"]]
-        dt = jax.nn.softplus(proj[..., -H:].astype(f32) + params["ssm_dt_bias"][i].astype(f32))
+        dt = jax.nn.softplus(
+            proj[..., -cfg.ssm_heads:].astype(f32) + params["ssm_dt_bias"][i].astype(f32))
         if valid is not None:
             dt = jnp.where(valid[..., None], dt, 0.0)
+    return z, xbc, dt
+
+
+def _ssm_mix(params, lay: _Layer, xbc, dt, state_all, conv_all, valid, cfg):
+    """The mixing itself for one set of rows, from and into its carried state
+    ``state_all`` [n, B, H, P, N] and convolution tails ``conv_all``
+    [n, B, taps - 1, channels] at this layer's row: the convolution over
+    ``xbc`` [B, T, channels], then the recurrence with the steps ``dt``
+    [B, T, H]. ``valid`` [B, T] (or None: all) marks a row's real tokens, a
+    prefix of it: a token that is none has a step of 0 (``_ssm_in``), which
+    leaves the state where the row's last real token put it, and the tail is
+    cut where the row ends. One token a row takes the recurrence's own line on
+    the leaf where it lies (``ops/ssm.py ssm_step_in_place``: a kernel where
+    the state tiles, ``ssm_step`` where not), more the chunked scan
+    (``ssm_scan``): the same state either way. Returns (y float32
+    [B, H, P] or [B, T, H, P], the two leaves)."""
+    from ray_tpu.ops.ssm import causal_conv, ssm_scan, ssm_step_in_place
+
+    i = lay.attn_i
+    d = ssm_dims(cfg)
+    Bsz, T, _ = xbc.shape
+    H, P, N, G = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups
+    f32 = jnp.float32
     with scope("attn_core"), scope("ssm_mixer"):
         with scope("ssm_conv"):
             conv, seen = causal_conv(
@@ -858,13 +879,23 @@ def _ssm_mixer(params, lay: _Layer, h, state_all, conv_all, valid, cfg):
             with scope("ssm_scan"):
                 y, state = ssm_scan(state, x, dt, a, b_in, c_in, skip, cfg.ssm_chunk)
             state_all = jax.lax.dynamic_update_index_in_dim(state_all, state, i, 0)
+    return y, state_all, conv_all
+
+
+def _ssm_out(params, lay: _Layer, y, z, cfg):
+    """Gate, grouped norm and output projection of the mixing's ``y`` (any
+    shape of ``z``'s [B, T, inner] numbers) -> [B, T, e]: row by row again."""
+    i = lay.attn_i
+    d = ssm_dims(cfg)
+    Bsz, T, _ = z.shape
+    G = cfg.ssm_groups
     with scope("attn_out"), scope("ssm_mixer"):
         # gate, then a norm a group of heads (the gate before the norm)
-        y = y.reshape(Bsz, T, G, d["inner"] // G) * jax.nn.silu(z.astype(f32)).reshape(
+        y = y.reshape(Bsz, T, G, d["inner"] // G) * jax.nn.silu(z.astype(jnp.float32)).reshape(
             Bsz, T, G, d["inner"] // G)
         y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.rms_eps)
-        y = y.reshape(Bsz, T, d["inner"]).astype(h.dtype) * params["ssm_norm"][i]
-        return jnp.einsum("btf,fe->bte", y, params["ssm_w_out"][i]), state_all, conv_all
+        y = y.reshape(Bsz, T, d["inner"]).astype(z.dtype) * params["ssm_norm"][i]
+        return jnp.einsum("btf,fe->bte", y, params["ssm_w_out"][i])
 
 
 def _feed_forward(params, lay: _Layer, x, cfg):
@@ -1173,9 +1204,48 @@ def _cache_reader(cfg, params, cache, positions, kinds):
     return read
 
 
+class _Rows:
+    """One set of rows on its way through the layers: its tokens [B, T] at
+    ``positions`` [B, T] (``valid`` [B, T] or None marks the real ones), the
+    cache it writes into and reads, and the form of each (``_cache_writer``,
+    ``_cache_reader`` or ``_latent_reader``), chosen from its own shapes."""
+
+    def __init__(self, cfg, params, kinds, cache, tokens, positions, valid, start_pos):
+        self.cache, self.tokens, self.positions, self.valid = cache, tokens, positions, valid
+        self.B, self.T = tokens.shape
+        self.write = _cache_writer(cfg, cache["k"].shape[3], positions, valid, start_pos)
+        if "latent" in kinds:  # every layer is one (``plan``)
+            self.read, self.from_latent = _latent_reader(cfg, params, cache, positions)
+        else:
+            self.read, self.from_latent = _cache_reader(cfg, params, cache, positions, kinds), False
+
+    def real(self):
+        """``valid``, or all of them."""
+        return jnp.ones((self.B, self.T), bool) if self.valid is None else self.valid
+
+
+def _join(parts):
+    """Each set's [B, T, ...] as one [1, all of their rows' tokens, ...]: what
+    multiplies by a weight runs on every set's rows together, and the weight
+    is read once. One set is itself."""
+    if len(parts) == 1:
+        return parts[0]
+    return jnp.concatenate([x.reshape((1, -1) + x.shape[2:]) for x in parts], axis=1)
+
+
+def _split(x, shapes):
+    """``_join``'s inverse: [1, all tokens, ...] as a [B, T, ...] for each
+    (B, T) of ``shapes`` again."""
+    if len(shapes) == 1:
+        return [x]
+    ends = np.cumsum([B * T for B, T in shapes])
+    return [x[:, end - B * T:end].reshape((B, T) + x.shape[2:])
+            for (B, T), end in zip(shapes, ends)]
+
+
 def decode_forward(
     params, cache, tokens, positions, cfg, valid=None, loras=None, adapter_ids=None,
-    with_logits: bool = True, logits_at=None, start_pos=None,
+    with_logits: bool = True, logits_at=None, start_pos=None, beside=None,
 ):
     """The body of ``prefill`` and ``decode_step`` for every model. tokens:
     [B, T]; positions: [B, T]. New k/v are written into the cache before
@@ -1203,6 +1273,25 @@ def decode_forward(
     kernel between the row's own bounds, a sliding layer's window cut out of
     the stripe.
 
+    ``beside``: ``(cache, tokens [B2], live [B2] or None)``, a second set of
+    rows that takes the same walk through the layers: the rows of a decode
+    step on a cache of their own, each at its cache's ``length``, beside a
+    prompt's chunk (``llm/engine.py programs``). Whatever multiplies by a
+    weight (the attention's and the state-space mixers' projections in and
+    out, the feed-forwards with their router, sort and grouped matmuls, the
+    head) runs on both sets' rows as one matrix (``_join``), so the weights
+    and the touched experts' banks are read once for both; whatever reads or
+    writes a cache runs for each set in its own form, under the scope it has
+    alone: the chunk's block write and attention over its stripe, the decode
+    rows' scatter and kernels between each row's own bounds. A row that is
+    not ``live`` writes nothing and leaves its length and state where they
+    were (its arithmetic is done and dropped, as a free slot's is in a decode
+    step). Every row gets the arithmetic it gets alone, in matmuls of more
+    rows: on a chip its numbers can differ in the last bit by what shares
+    the launch. The routing counts are of both sets' rows together and ride
+    out with the first cache. Returns ``(logits, cache, logits2 [B2, 1, V],
+    cache2)`` then. Not with ``loras``, nor over a latent cache.
+
     ``loras``/``adapter_ids``: stacked LoRA adapters + per-sequence adapter
     index (0 = base), over layers that are alike.
     ``with_logits=False`` (a prompt's middle chunk) only extends the cache
@@ -1217,6 +1306,10 @@ def decode_forward(
     kinds = {kind for kind, _, _ in pl.kinds}
     if loras is not None and pl.by_kind:
         raise NotImplementedError("LoRA adapters over layers that are not alike")
+    if beside is not None and (loras is not None or "latent" in kinds):
+        raise NotImplementedError(
+            "models/patterned.py: rows beside a chunk run without LoRA adapters and not "
+            "over a latent cache")
     if "latent" in kinds and any(
         jax.typeof(x).sharding.mesh.size > 1 for x in (cache["k"], *jax.tree.leaves(params))
     ):
@@ -1224,15 +1317,21 @@ def decode_forward(
             "models/patterned.py: latent attention runs on one device (no rule "
             "places its cache or its projections on a mesh)"
         )
-    B, T = tokens.shape
-    S = cache["k"].shape[3]  # [L, B, K, S, D]
     with scope("embed"):
-        x = params["embed"][tokens].astype(cfg.dtype)
-    write = _cache_writer(cfg, S, positions, valid, start_pos)
-    if "latent" in kinds:  # every layer is one (``plan``)
-        read, from_latent = _latent_reader(cfg, params, cache, positions)
-    else:
-        read, from_latent = _cache_reader(cfg, params, cache, positions, kinds), False
+        x = params["embed"][
+            _join([tokens] if beside is None else [tokens, beside[1][:, None]])
+        ].astype(cfg.dtype)
+    sets = [_Rows(cfg, params, kinds, cache, tokens, positions, valid, start_pos)]
+    if beside is not None:
+        cache2, tokens2, live = beside
+        sets.append(_Rows(cfg, params, kinds, cache2, tokens2[:, None], cache2["length"][:, None],
+                          None if live is None else live[:, None], None))
+    from_latent = sets[0].from_latent
+    shapes = [(rows.B, rows.T) for rows in sets]
+    positions = _join([rows.positions for rows in sets])
+    # the real tokens of all rows, where any set has tokens that are none
+    real = None if all(rows.valid is None for rows in sets) else _join(
+        [rows.real() for rows in sets])
     if "ssm" in kinds and any(
         jax.typeof(x).sharding.mesh.size > 1 for x in (cache["k"], *jax.tree.leaves(params))
     ):
@@ -1241,48 +1340,90 @@ def decode_forward(
             "their state or their projections on a mesh)"
         )
 
+    # A middle chunk beside decode rows: nothing reads the chunk's own rows
+    # behind the last layer's mixer, so where that layer is traced on its own
+    # its feed-forward is the decode rows' alone (a chunk alone has no reader
+    # of it at all, and the compiler drops it whole; a layer that is a pass of
+    # the loop runs for every row either way)
+    narrow = (beside is not None and not with_logits and pl.kinds[-1][2] != "none"
+              and (pl.reps == 0 or pl.tail_from < cfg.n_layers))
     # a model with routed experts carries its routing counts beside x, one
-    # with state-space layers their two leaves behind those
+    # with state-space layers each set's two leaves behind those
     stats0 = (jnp.zeros((len(moe_stats_names(cfg)),), jnp.int32),) if cfg.moe_experts else ()
-    ssm0 = tuple(cache[name] for name in SSM_LEAVES) if "ssm" in kinds else ()
+    ssm0 = tuple(
+        tuple(rows.cache[name] for name in SSM_LEAVES) if "ssm" in kinds else ()
+        for rows in sets)
 
     def layer(lay: _Layer, carry):
-        x, ck_all, cv_all, *rest = carry
-        stats, ssm = rest[:len(stats0)], rest[len(stats0):]
+        x, kv, stats, ssm = carry
         if lay.kind == "ssm":
             h = _rmsnorm(x, params["attn_norm"][lay.mixer_i], cfg.rms_eps, cfg.fused_rmsnorm)
-            y, *ssm = _ssm_mixer(params, lay, h, *ssm, valid, cfg)
-            x = x + y
+            z, xbc, dt = _ssm_in(params, lay, h, real, cfg)
+            mixed = [
+                _ssm_mix(params, lay, xbc_rows, dt_rows, *leaves, rows.valid, cfg)
+                for rows, xbc_rows, dt_rows, leaves in zip(
+                    sets, _split(xbc, shapes), _split(dt, shapes), ssm)
+            ]
+            ssm = tuple(tuple(leaves) for _, *leaves in mixed)
+            ys = [y for y, *_ in mixed]
+            y = ys[0] if len(sets) == 1 else _join(
+                [y.reshape(rows.B, rows.T, -1) for rows, y in zip(sets, ys)])
+            x = x + _ssm_out(params, lay, y, z, cfg)
         elif lay.kind != "none":
             h = _rmsnorm(x, params["attn_norm"][lay.mixer_i], cfg.rms_eps, cfg.fused_rmsnorm)
             if lay.latent:  # k: the shared rotated key; v: the normed latent
                 q, k, v = _latent_qkv(params, lay, h, positions, cfg)
             else:
                 q, k, v = _qkv(params, lay, h, positions, cfg, loras, adapter_ids)
-            with scope("kv_write"):
-                if lay.latent:  # zeros up to the cache's row of whole lane tiles (``init_kv_cache``)
-                    k = jnp.pad(k, ((0, 0),) * 3 + ((0, ck_all.shape[-1] - k.shape[-1]),))
-                # the cache is head-major: the new [B, T, K, D] rows go in as [B, K, T, D]
-                ck_all = write(ck_all, k.transpose(0, 2, 1, 3), lay.kv_i)
-                cv_all = write(cv_all, v.transpose(0, 2, 1, 3), lay.kv_i)
-            with scope("attn_core"), lay.inner_scope():
-                attn = read(q, ck_all, cv_all, lay)
-            x = _attn_out(params, lay, x, h, attn, cfg, from_latent)
+                q, k, v = (_split(t, shapes) for t in (q, k, v))
+            attn, new_kv = [], []
+            for j, rows in enumerate(sets):
+                ck_all, cv_all = kv[j]
+                # a latent model's q is a pair, and its rows are one set
+                qj, kj, vj = (q, k, v) if lay.latent else (q[j], k[j], v[j])
+                with scope("kv_write"):
+                    if lay.latent:  # zeros up to the cache's row of whole lane tiles (``init_kv_cache``)
+                        kj = jnp.pad(kj, ((0, 0),) * 3 + ((0, ck_all.shape[-1] - kj.shape[-1]),))
+                    # the cache is head-major: the new [B, T, K, D] rows go in as [B, K, T, D]
+                    ck_all = rows.write(ck_all, kj.transpose(0, 2, 1, 3), lay.kv_i)
+                    cv_all = rows.write(cv_all, vj.transpose(0, 2, 1, 3), lay.kv_i)
+                with scope("attn_core"), lay.inner_scope():
+                    attn.append(rows.read(qj, ck_all, cv_all, lay))
+                new_kv.append((ck_all, cv_all))
+            kv = tuple(new_kv)
+            x = _attn_out(params, lay, x, h, _join(attn), cfg, from_latent)
         if lay.mlp != "none":
+            if narrow and lay.last:
+                x = _split(x, shapes)[1]
             x, layer_stats = _feed_forward(params, lay, x, cfg)
-            stats = [s + layer_stats for s in stats]
-        return (x, ck_all, cv_all, *stats, *ssm)
+            stats = tuple(s + layer_stats for s in stats)
+        return (x, kv, stats, ssm)
 
-    x, new_k, new_v, *rest = _run_layers(cfg, layer, (x, cache["k"], cache["v"], *stats0, *ssm0))
-    stats = rest[:len(stats0)]
-    new_cache = {"k": new_k, "v": new_v, "length": cache["length"] + T,
-                 **dict(zip(SSM_LEAVES, rest[len(stats0):]))}
-    _ride_stats(cache, new_cache, stats)
-    if not with_logits:
-        return None, new_cache
+    x, kv, stats, ssm = _run_layers(
+        cfg, layer, (x, tuple((rows.cache["k"], rows.cache["v"]) for rows in sets), stats0, ssm0))
+    new_caches = []
+    for rows, (new_k, new_v), leaves in zip(sets, kv, ssm):
+        grew = rows.T if rows is sets[0] or rows.valid is None else rows.valid.sum(
+            axis=1, dtype=jnp.int32)
+        new_cache = {"k": new_k, "v": new_v, "length": rows.cache["length"] + grew,
+                     **dict(zip(SSM_LEAVES, leaves))}
+        _ride_stats(rows.cache, new_cache, stats)
+        new_caches.append(new_cache)
+    # the rows whose next token is asked for: one position a row of the first
+    # set (``logits_at``) or all of them, and every row of a second set
+    heads = [None, x] if narrow else _split(x, shapes)
     if logits_at is not None:
         # the one requested hidden state a sequence BEFORE the vocab
         # projection: [B, T, e] -> [B, 1, e]
-        x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
-    x = _rmsnorm(x, params["final_norm"], cfg.rms_eps, cfg.fused_rmsnorm)
-    return _project_logits(x, params, cfg, None), new_cache
+        heads[0] = jnp.take_along_axis(heads[0], logits_at[:, None, None], axis=1)
+    if not with_logits:
+        heads = heads[1:]
+    logits = []
+    if heads:
+        x = _rmsnorm(_join(heads), params["final_norm"], cfg.rms_eps, cfg.fused_rmsnorm)
+        logits = _split(_project_logits(x, params, cfg, None), [h.shape[:2] for h in heads])
+    if not with_logits:
+        logits = [None] + logits
+    if beside is None:
+        return logits[0], new_caches[0]
+    return logits[0], new_caches[0], logits[1], new_caches[1]

@@ -59,11 +59,11 @@ def _requests(lora):
 def _launches(eng, log):
     """Record each launch's rows as (chunk width, final or not)."""
     for name in ("_launch_mid_chunks", "_launch_final_chunk"):
-        def recording(pool, adms, inner=getattr(eng, name)):
+        def recording(pool, adms, carry=None, inner=getattr(eng, name)):
             rows = adms if isinstance(adms, list) else [adms]
             log.append([adm.chunks[adm.idx][0].shape[1:] + (adm.chunks[adm.idx][3],)
                         for adm in rows])
-            return inner(pool, adms)
+            return inner(pool, adms, carry)
 
         setattr(eng, name, recording)
 

@@ -611,8 +611,10 @@ def test_the_steps_of_a_launch_are_named_inside_its_span(engine, tmp_path):
     for name, start, duration, _ in events:
         spans.setdefault(name, []).append((start, start + duration))
     outer = {"engine.chunk_transfer": "engine.prefill_chunk", "engine.chunk_call": "engine.prefill_chunk",
-             "engine.slot_set": "engine.prefill_chunk", "engine.prefix_store": "engine.prefill_chunk",
+             "engine.prefix_store": "engine.prefill_chunk",
              "engine.prefix_lookup": "engine.pull_waiting", "engine.new_stripe": "engine.pull_waiting"}
+    if not engine._pools[0].carries:  # else the final chunk's program sets the slot's key and token
+        outer["engine.slot_set"] = "engine.prefill_chunk"
     for inner, around in outer.items():
         assert inner in spans, inner
         for a, b in spans[inner]:
