@@ -294,7 +294,7 @@ class _Pool:
         import jax
 
         from ray_tpu.models.llama import init_kv_cache
-        from ray_tpu.models.patterned import SSM_LEAVES, reads_blocks
+        from ray_tpu.models.patterned import STATE_LEAVES, reads_blocks
 
         self.stripe_len = stripe_len
         self.n_slots = n_slots
@@ -308,9 +308,10 @@ class _Pool:
         # lanes is padded to them); None where the backend reports no memory
         tokens = n_slots * stripe_len
         self.kv_bytes_per_token = (self.cache["k"].nbytes + self.cache["v"].nbytes) / tokens
-        # what a slot holds whatever its length (a state-space layer's state
-        # and convolution tail): 0 for a model whose slots are stripes alone
-        state_bytes = sum(self.cache[k].nbytes for k in SSM_LEAVES if k in self.cache)
+        # what a slot holds whatever its length (the state and convolution
+        # tails of the layers that keep one): 0 for a model whose slots are
+        # stripes alone
+        state_bytes = sum(self.cache[k].nbytes for k in STATE_LEAVES if k in self.cache)
         self.state_bytes_per_slot = state_bytes // n_slots
         self.stateful = state_bytes > 0
         self.kv_bytes_per_token_held = (
@@ -375,13 +376,13 @@ def programs(cfg, decode_steps: int = 1) -> dict:
     import jax.numpy as jnp
 
     from ray_tpu.models.llama import decode_step, init_kv_cache, prefill
-    from ray_tpu.models.patterned import SSM_LEAVES, moe_stats_names, plan
+    from ray_tpu.models.patterned import moe_stats_names, state_cache_shapes
 
     # what a slot holds, each leaf with the slot on axis 1: its stripes of keys
-    # and values, and for a model with state-space layers their state and
-    # convolution tail, which are stacked, unstacked, zeroed and copied into a
-    # slot with the stripes
-    slot_leaves = ("k", "v") + (SSM_LEAVES if plan(cfg).n_ssm else ())
+    # and values, and for a model with layers that keep a state their states
+    # and convolution tails (``STATE_LEAVES``), which are stacked, unstacked,
+    # zeroed and copied into a slot with the stripes
+    slot_leaves = ("k", "v", *state_cache_shapes(cfg, 1))
     n_stats = len(moe_stats_names(cfg))
 
     # one static top-K for the decode program AND the prefill first-token

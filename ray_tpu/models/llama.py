@@ -117,9 +117,11 @@ class LlamaConfig:
     yarn_beta_fast: float = 32.0
     yarn_beta_slow: float = 1.0
     yarn_attention_factor: float = 1.0
-    # sigmoid of a [d_model, heads] projection of the layer's normed input,
-    # times each head's attention output before wo
-    attn_gate: bool = False
+    # sigmoid of a projection of the layer's normed input, times the
+    # attention output before wo: True (or 'head') a value a head, ``wg``
+    # [d_model, heads] (Laguna's); 'channel' a value a channel of each head,
+    # ``wg`` [d_model, heads * head_dim] (Solar-Open2's)
+    attn_gate: Any = False
     # --- latent attention (layer type 'latent'; DeepSeek-V3's MLA) ---
     # ``kv_latent_rank`` > 0: a token's cache entry a layer is one normed
     # latent of that width and one rotated key of ``qk_rope_dim`` that all
@@ -168,6 +170,20 @@ class LlamaConfig:
     # the absent ones would add to a token is left out
     moe_experts_held: int = 0
     moe_experts_first: int = 0
+    # --- delta-rule linear attention (layer type 'kda'; Kimi Delta Attention,
+    # as upstage Solar-Open2's linear layers) ---
+    # a 'kda' layer keeps, for each sequence, a float32 state a head
+    # [kda_heads, kda_head_dim (key), kda_head_dim (value)] that a token both
+    # decays a key channel and erases from, and the last ``kda_conv - 1``
+    # inputs of its three convolutions (query, key, value), whatever the
+    # sequence's length and no keys (``init_kv_cache``). The decay and the
+    # output gate come through a low rank of the head's width (the published
+    # layer's default: a model whose rank differs brings the field with it);
+    # a prompt runs ``kda_chunk`` tokens at a time (``ops/kda.py``)
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_chunk: int = 64
 
     def __post_init__(self):
         if self.attention not in ("full", "ring", "ulysses", "splash"):
@@ -176,6 +192,8 @@ class LlamaConfig:
             raise ValueError(f"unknown moe_scoring {self.moe_scoring!r}")
         if self.moe_activation not in ("swiglu", "relu2"):
             raise ValueError(f"unknown moe_activation {self.moe_activation!r}")
+        if self.attn_gate not in (False, True, "head", "channel"):
+            raise ValueError(f"unknown attn_gate {self.attn_gate!r}")
         for name in ("layer_types", "heads_per_layer", "mlp_types"):
             value = tuple(getattr(self, name))
             object.__setattr__(self, name, value)
@@ -413,6 +431,46 @@ class LlamaConfig:
         return LlamaConfig(**_pattern_lists(d))
 
 
+    @staticmethod
+    def solar_open2_250b(**kw) -> "LlamaConfig":
+        """upstage Solar-Open2-250B (``model_type: solar_open2``) as its
+        config.json has it: 48 layers, each a mixer and an expert layer; the
+        layers ``gqa_layers`` (0, 4, .., 44) are GQA of 64 query and 8
+        key-value heads of 128 without rotation and with an output gate a
+        channel, the other 36 Kimi-delta linear attention (64 heads of 128,
+        keys and values alike, convolutions of 4 taps); 320 sigmoid-routed
+        SwiGLU experts of width 1280, 8 a token, weights renormalised, beside
+        one shared expert. A caller that cuts ``n_layers`` gives its own
+        ``gqa_layers`` (the attention layers among the layers it keeps) or
+        gets the published ones below its depth; ``moe_experts_held`` and
+        ``vocab_size`` give a device's share."""
+        d = dict(
+            vocab_size=196608, d_model=4096, n_layers=48, n_heads=64, n_kv_heads=8,
+            head_width=128, d_ff=10240, max_seq_len=1048576, rms_eps=1e-5, attn_rope=False,
+            attn_gate="channel", kda_heads=64, kda_head_dim=128, kda_conv=4, kda_chunk=64,
+            moe_experts=320, moe_top_k=8, moe_d_ff=1280, moe_shared_d_ff=1280,
+            moe_routed_scale=1.0, moe_scoring="sigmoid", gqa_layers=tuple(range(0, 48, 4)),
+        )
+        d.update(kw)
+        return LlamaConfig(**_gqa_lists(d))
+
+    @staticmethod
+    def solar_tiny(**kw) -> "LlamaConfig":
+        """Test-size model with ``solar_open2_250b``'s layers: two periods
+        (attention, then three delta-rule layers), 4 of 16 experts held, a
+        state of 16 x 16 a head (which does not tile: the plain step)."""
+        d = dict(
+            vocab_size=256, d_model=64, n_layers=8, n_heads=4, n_kv_heads=2, head_width=16,
+            d_ff=128, max_seq_len=128, dtype=jnp.float32, remat=False, rms_eps=1e-5,
+            attn_rope=False, attn_gate="channel", kda_heads=4, kda_head_dim=16, kda_conv=4,
+            kda_chunk=8, moe_experts=16, moe_top_k=4, moe_d_ff=32, moe_shared_d_ff=32,
+            moe_routed_scale=1.0, moe_scoring="sigmoid", moe_experts_held=4,
+            gqa_layers=(0, 4),
+        )
+        d.update(kw)
+        return LlamaConfig(**_gqa_lists(d))
+
+
 # a block of a ``nemotron_h`` pattern: (mixer, feed-forward)
 _BLOCKS = {"M": ("ssm", "none"), "E": ("none", "sparse"), "*": ("full", "none")}
 
@@ -425,6 +483,19 @@ def _pattern_lists(d: dict) -> dict:
     d.setdefault("heads_per_layer", tuple(
         d["n_heads"] if t == "full" else 0 for t in d["layer_types"]))
     d.setdefault("mlp_types", tuple(m for _, m in blocks))
+    return d
+
+
+def _gqa_lists(d: dict) -> dict:
+    """The per-layer lists of a model whose layers ``gqa_layers`` are full
+    attention and the others delta-rule linear attention, every one with
+    experts, for its depth."""
+    n = d["n_layers"]
+    gqa = set(d.pop("gqa_layers"))
+    d.setdefault("layer_types", tuple("full" if i in gqa else "kda" for i in range(n)))
+    d.setdefault("heads_per_layer", tuple(
+        d["n_heads"] if t == "full" else 0 for t in d["layer_types"]))
+    d.setdefault("mlp_types", ("sparse",) * n)
     return d
 
 
@@ -495,6 +566,13 @@ _PARAM_DIMS.update({
     "moe_latent_down": (None, "embed", None),
     "moe_latent_up": (None, None, "embed"),
 })
+# a delta-rule mixer's leaves (one device too)
+_PARAM_DIMS.update({
+    "kda_w_in": (None, "embed", None),
+    "kda_w_out": (None, None, "embed"),
+    **dict.fromkeys(("kda_conv_w", "kda_w_decay", "kda_w_gate"), (None, None, None)),
+    **dict.fromkeys(("kda_dt_bias", "kda_a_log", "kda_norm"), (None, None)),
+})
 
 
 def param_logical_dims(path, leaf):
@@ -561,6 +639,8 @@ _SSM_VECTORS = {
     "ssm_d": lambda k, shape: jnp.ones(shape, jnp.float32),
     "ssm_conv_b": lambda k, shape: jax.random.normal(k, shape, jnp.float32) * 0.02,
 }
+# a delta-rule mixer's decay: the same two draws, the bias a channel
+_SSM_VECTORS.update(kda_dt_bias=_SSM_VECTORS["ssm_dt_bias"], kda_a_log=_SSM_VECTORS["ssm_a_log"])
 
 
 def _inv_softplus(y):
@@ -944,10 +1024,11 @@ def init_kv_cache(cfg: LlamaConfig, batch_size: int, max_len: Optional[int] = No
     measured ~5x off the bandwidth roofline on v5e because of it).
 
     ``k`` and ``v`` hold the attention layers alone (all of them, in every
-    model but one with blocks that have no attention). A model with
-    state-space layers has two more leaves, which are no stripes: a slot's
-    state and the tail of its convolution's inputs, a layer each, of one size
-    whatever the slot's length (``models/patterned.py ssm_cache_shapes``);
+    model but one with layers that have no attention). A model with layers
+    that keep a state (state-space or delta-rule) has two more leaves a kind,
+    which are no stripes: a slot's state and the tail of its convolutions'
+    inputs, a layer each, of one size whatever the slot's length
+    (``models/patterned.py state_cache_shapes``, named in ``STATE_LEAVES``);
     every leaf but ``length`` has the slot on axis 1, which is all that the
     engine's programs that stack, unstack and copy slots know of them.
 
@@ -973,11 +1054,10 @@ def init_kv_cache(cfg: LlamaConfig, batch_size: int, max_len: Optional[int] = No
         "v": jnp.zeros(lead + (v_dim,), cfg.dtype),
         "length": jnp.zeros((batch_size,), jnp.int32),
     }
-    if pl.n_ssm:
-        cache.update({
-            name: jnp.zeros(shape, dtype)
-            for name, (shape, dtype) in patterned.ssm_cache_shapes(cfg, batch_size).items()
-        })
+    cache.update({
+        name: jnp.zeros(shape, dtype)
+        for name, (shape, dtype) in patterned.state_cache_shapes(cfg, batch_size).items()
+    })
     return cache
 
 

@@ -14,7 +14,11 @@ Laguna-XS.2 is the published instance, ``LlamaConfig.laguna_xs2``). A third
 attention kind, ``latent`` (DeepSeek-V3's; kakaocorp Kanana-2-30B-A3B is the
 published instance, ``LlamaConfig.kanana2_30b_a3b``), caches one normed
 latent and one rotated key a token for all heads; it does not mix with the
-other two, because the cache has one shape (``init_kv_cache``).
+other two, because the cache has one shape (``init_kv_cache``). Two kinds of
+mixer keep a state a sequence and no keys, whatever its length
+(``STATE_MIXERS``): ``ssm`` (Mamba-2; NVIDIA Nemotron-3-Super, whose blocks
+are a mixer or a feed-forward alone: kinds ``none``) and ``kda`` (the delta
+rule with a decay a channel; upstage Solar-Open2, ``ops/kda.py``).
 
 ``models/llama.py`` is the entry point and imports this module, never the
 other way round: its ``prefill`` and ``decode_step`` call ``decode_forward``
@@ -83,6 +87,19 @@ _SCOPE_OF_KIND = {"full": "global", "sliding": "window", "latent": "latent"}
 # float32 scores are [B, heads, T, block]: 34 MB at 32 heads and a 256-token
 # chunk
 _LATENT_KEY_BLOCKS = (1024, 512, 256, 128)
+# A prompt's chunk over a full layer's stripe scores every position of the
+# stripe at once (``_grouped_attention``: float32 [B, heads, T, S]) while that
+# is at most this many bytes, which holds every cell but one at the form its
+# programs were measured in (Laguna's four rows of 256 tokens over 4,096
+# positions and Nemotron's four of 1,024 over 2,048: 1.07 GB); past it, blocks
+# of ``_FULL_KEY_BLOCK`` key positions up to the furthest row's last query
+# with a running maximum, sum and context (``_cache_reader``): 64 heads of a
+# 1,024-token chunk over an 8,192-position stripe are 2.1 GB a row, 8.6 GB at
+# four rows of a launch, on a 16 GB chip (PERF.md section 6, PR 42)
+_STRIPE_SCORES_MAX_BYTES = 3 << 29
+_FULL_KEY_BLOCK = 512
+# queries and keys of a delta-rule layer: x / sqrt(sum x^2 + this)
+_L2_EPS = 1e-6
 
 
 # ------------------------------------------------------------------ the plan
@@ -126,6 +143,10 @@ class Plan:
         return sum(t == "ssm" for t, _, _ in self.kinds)
 
     @property
+    def n_kda(self) -> int:
+        return sum(t == "kda" for t, _, _ in self.kinds)
+
+    @property
     def n_mixer(self) -> int:
         return sum(t != "none" for t, _, _ in self.kinds)
 
@@ -163,7 +184,7 @@ def plan(cfg) -> Plan:
             )
         kinds = [("full", cfg.n_heads, "sparse" if cfg.moe_experts else "dense")] * L
     for t, h, m in kinds:
-        if t not in (*_SCOPE_OF_KIND, "ssm", "none") or m not in ("dense", "sparse", "none"):
+        if t not in (*_SCOPE_OF_KIND, "ssm", "kda", "none") or m not in ("dense", "sparse", "none"):
             raise ValueError(f"unknown layer kind ({t!r}, {m!r})")
         if t == m == "none":
             raise ValueError("a block with neither a mixer nor a feed-forward")
@@ -175,6 +196,11 @@ def plan(cfg) -> Plan:
     ):
         raise ValueError("ssm layers need ssm_heads (a multiple of ssm_groups), ssm_head_dim "
                          "and ssm_state")
+    if any(t == "kda" for t, _, _ in kinds) and not (
+        cfg.kda_heads and cfg.kda_head_dim and cfg.kda_chunk % 4 == 0
+    ):
+        raise ValueError("kda layers need kda_heads, kda_head_dim and a kda_chunk that is a "
+                         "multiple of 4")
     for t in _SCOPE_OF_KIND:
         if len({h for kt, h, _ in kinds if kt == t}) > 1:
             raise ValueError(f"{t} attention layers differ in their query heads")
@@ -280,17 +306,54 @@ def _ssm_shapes(cfg, n: int) -> dict[str, tuple]:
     }
 
 
-def ssm_cache_shapes(cfg, batch_size: int) -> dict:
-    """name -> (shape, dtype) of what the cache holds beside keys and values
-    for a model with state-space layers, a row a layer and slot: the state
-    (float32: it sums a sequence's steps) and the last ``ssm_conv - 1`` inputs
-    of the convolution (channels on the lanes)."""
-    n = plan(cfg).n_ssm
+def kda_dims(cfg) -> dict:
+    """The widths of a delta-rule mixer: ``inner`` (heads x head width: each
+    of query, key and value), ``rank`` (the low rank the decay and the output
+    gate come through: the head's width), ``conv`` (what the three
+    convolutions run over: q, k, v), ``proj`` (the input projection: q k v,
+    the two low ranks, then a writing strength a head)."""
+    inner, rank = cfg.kda_heads * cfg.kda_head_dim, cfg.kda_head_dim
+    return {"inner": inner, "rank": rank, "conv": 3 * inner,
+            "proj": 3 * inner + 2 * rank + cfg.kda_heads}
+
+
+def _kda_shapes(cfg, n: int) -> dict[str, tuple]:
+    """The leaves of ``n`` stacked delta-rule mixers. The three projections of
+    query, key and value, the two low ranks' first halves and the writing
+    strength are one matrix (``kda_w_in``: one read of the input); the three
+    convolutions one weight [taps, channels], no bias anywhere."""
+    d, e, H = kda_dims(cfg), cfg.d_model, cfg.kda_heads
     return {
-        "ssm_state": ((n, batch_size, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
-                      jnp.float32),
-        "ssm_conv": ((n, batch_size, cfg.ssm_conv - 1, ssm_dims(cfg)["conv"]), cfg.dtype),
+        "kda_w_in": (n, e, d["proj"]),
+        "kda_conv_w": (n, cfg.kda_conv, d["conv"]),
+        "kda_w_decay": (n, d["rank"], d["inner"]),
+        "kda_dt_bias": (n, d["inner"]),
+        "kda_a_log": (n, H),
+        "kda_w_gate": (n, d["rank"], d["inner"]),
+        "kda_norm": (n, cfg.kda_head_dim),
+        "kda_w_out": (n, d["inner"], e),
     }
+
+
+def state_cache_shapes(cfg, batch_size: int) -> dict:
+    """name -> (shape, dtype) of the ``STATE_LEAVES`` a model's cache holds, a
+    row a layer and slot: a state-space layer's state and the last
+    ``ssm_conv - 1`` inputs of its convolution, a delta-rule layer's state a
+    head and the last ``kda_conv - 1`` inputs of its three convolutions (the
+    states float32: they sum a sequence's steps; the tails in the served type,
+    channels on the lanes). Empty for a model whose slots are stripes alone."""
+    pl, shapes = plan(cfg), {}
+    if pl.n_ssm:
+        shapes["ssm_state"] = ((pl.n_ssm, batch_size, cfg.ssm_heads, cfg.ssm_head_dim,
+                                cfg.ssm_state), jnp.float32)
+        shapes["ssm_conv"] = ((pl.n_ssm, batch_size, cfg.ssm_conv - 1, ssm_dims(cfg)["conv"]),
+                              cfg.dtype)
+    if pl.n_kda:
+        shapes["kda_state"] = ((pl.n_kda, batch_size, cfg.kda_heads, cfg.kda_head_dim,
+                                cfg.kda_head_dim), jnp.float32)
+        shapes["kda_conv"] = ((pl.n_kda, batch_size, cfg.kda_conv - 1, kda_dims(cfg)["conv"]),
+                              cfg.dtype)
+    return shapes
 
 
 def _param_shapes(cfg) -> dict[str, tuple]:
@@ -307,6 +370,8 @@ def _param_shapes(cfg) -> dict[str, tuple]:
         shapes.update({"wk": (pl.n_attention, e, kv, hd), "wv": (pl.n_attention, e, kv, hd)})
     if pl.n_ssm:
         shapes.update(_ssm_shapes(cfg, pl.n_ssm))
+    if pl.n_kda:
+        shapes.update(_kda_shapes(cfg, pl.n_kda))
     for kind, h in {t: h for t, h, _ in pl.kinds if t in _SCOPE_OF_KIND}.items():
         n = sum(t == kind for t, _, _ in pl.kinds)
         if kind == "latent":
@@ -323,8 +388,8 @@ def _param_shapes(cfg) -> dict[str, tuple]:
             continue
         shapes[pl.leaf("wq", kind)] = (n, e, h, hd)
         shapes[pl.leaf("wo", kind)] = (n, h, hd, e)
-        if cfg.attn_gate:
-            shapes[pl.leaf("wg", kind)] = (n, e, h)
+        if cfg.attn_gate:  # a value a head, or a channel of each head
+            shapes[pl.leaf("wg", kind)] = (n, e, h * hd if cfg.attn_gate == "channel" else h)
     n_dense = sum(m == "dense" for _, _, m in pl.kinds)
     if n_dense:
         f = cfg.d_ff
@@ -401,10 +466,6 @@ def _shared_expert(p, h):
 
 # --------------------------------- pieces of the path through a cache
 
-
-# what a cache holds beside keys and values for a model with state-space
-# layers (``ssm_cache_shapes``), in the order ``decode_forward`` carries them
-SSM_LEAVES = ("ssm_state", "ssm_conv")
 
 # Rows of one call's routing counts (``_moe_decode_ffn``; summed over expert
 # layers by the caller): expert layers run, (token, expert) assignments,
@@ -797,23 +858,26 @@ def _attn_out(params, lay: _Layer, x, h, attn, cfg, from_latent: bool = False):
             attn = jnp.einsum("bthr,hrv->bthv", attn, params["wuv_latent"][lay.attn_i])
         if cfg.attn_gate:
             with scope("gate"):
+                # [B, T, H] a head, [B, T, H * D] a channel
                 gate = jax.nn.sigmoid(jnp.einsum(
                     "bte,eh->bth", h, params[lay.wg][lay.attn_i],
                     preferred_element_type=jnp.float32,
                 ))
-                attn = (attn * gate[..., None]).astype(attn.dtype)
+                gate = gate.reshape(attn.shape) if cfg.attn_gate == "channel" else gate[..., None]
+                attn = (attn * gate).astype(attn.dtype)
         return x + jnp.einsum("bthd,hde->bte", attn, params[lay.wo][lay.attn_i])
 
 
 def _ssm_in(params, lay: _Layer, h, valid, cfg):
     """A state-space mixer's input projection of the normed h [B, T, e]: the
-    gate ``z``, what the convolution runs over (x, B and C) and the float32
-    step a head, 0 where ``valid`` [B, T] (or None: all) says a token is none.
-    Rows are independent here, so several sets of them go through as one
-    (``decode_forward``). The three parts of a mixer (this, ``_ssm_mix``,
-    ``_ssm_out``) keep their scopes inside the attention's three, by what the
-    work is, under ``ssm_mixer``; ``ssm_conv`` and ``ssm_scan`` or
-    ``ssm_step`` inside ``attn_core/ssm_mixer``."""
+    what the mixing takes a set of rows at a time (what the convolution runs
+    over: x, B and C; the float32 step a head, 0 where ``valid`` [B, T] (or
+    None: all) says a token is none), and the gate ``z``. Rows are independent
+    here, so several sets of them go through as one (``decode_forward``). The
+    three parts of a mixer (this, ``_ssm_mix``, ``_ssm_out``) keep their
+    scopes inside the attention's three, by what the work is, under
+    ``ssm_mixer``; ``ssm_conv`` and ``ssm_scan`` or ``ssm_step`` inside
+    ``attn_core/ssm_mixer``."""
     i = lay.attn_i  # the row among the state-space layers
     d = ssm_dims(cfg)
     f32 = jnp.float32
@@ -825,7 +889,28 @@ def _ssm_in(params, lay: _Layer, h, valid, cfg):
             proj[..., -cfg.ssm_heads:].astype(f32) + params["ssm_dt_bias"][i].astype(f32))
         if valid is not None:
             dt = jnp.where(valid[..., None], dt, 0.0)
-    return z, xbc, dt
+    return (xbc, dt), z
+
+
+def _conv_through_tail(conv_all, i, x, w, b, valid):
+    """The short causal convolution of one set of rows ``x`` [B, T, channels]
+    behind the tails ``conv_all`` [n, B, taps - 1, channels] carries at row
+    ``i``, then SiLU, and the leaf with the next tail: the taps - 1 inputs up
+    to each row's last real token. Every token real (``valid`` None: a decode
+    step): the last ones, a plain slice; a padded row's end is its own, which
+    makes it a gather."""
+    from ray_tpu.ops.ssm import causal_conv
+
+    T, taps = x.shape[1], w.shape[0] - 1
+    conv, seen = causal_conv(conv_all[i], x, w, b)
+    if valid is None:
+        tail = seen[:, T:]
+    else:
+        tail = jax.vmap(
+            lambda row, at: jax.lax.dynamic_slice(row, (at, 0), (taps, row.shape[1]))
+        )(seen, valid.sum(axis=1, dtype=jnp.int32))
+    return jax.nn.silu(conv), jax.lax.dynamic_update_index_in_dim(
+        conv_all, tail.astype(conv_all.dtype), i, 0)
 
 
 def _ssm_mix(params, lay: _Layer, xbc, dt, state_all, conv_all, valid, cfg):
@@ -841,7 +926,7 @@ def _ssm_mix(params, lay: _Layer, xbc, dt, state_all, conv_all, valid, cfg):
     the state tiles, ``ssm_step`` where not), more the chunked scan
     (``ssm_scan``): the same state either way. Returns (y float32
     [B, H, P] or [B, T, H, P], the two leaves)."""
-    from ray_tpu.ops.ssm import causal_conv, ssm_scan, ssm_step_in_place
+    from ray_tpu.ops.ssm import ssm_scan, ssm_step_in_place
 
     i = lay.attn_i
     d = ssm_dims(cfg)
@@ -850,21 +935,8 @@ def _ssm_mix(params, lay: _Layer, xbc, dt, state_all, conv_all, valid, cfg):
     f32 = jnp.float32
     with scope("attn_core"), scope("ssm_mixer"):
         with scope("ssm_conv"):
-            conv, seen = causal_conv(
-                conv_all[i], xbc, params["ssm_conv_w"][i], params["ssm_conv_b"][i])
-            xbc = jax.nn.silu(conv)
-            # the next tail: the taps - 1 inputs up to the row's last real
-            # token. Every token real (a decode step): the last ones, a plain
-            # slice; a padded row's end is its own, which makes it a gather
-            taps = cfg.ssm_conv - 1
-            if valid is None:
-                tail = seen[:, T:]
-            else:
-                tail = jax.vmap(
-                    lambda row, at: jax.lax.dynamic_slice(row, (at, 0), (taps, row.shape[1]))
-                )(seen, valid.sum(axis=1, dtype=jnp.int32))
-            conv_all = jax.lax.dynamic_update_index_in_dim(
-                conv_all, tail.astype(conv_all.dtype), i, 0)
+            xbc, conv_all = _conv_through_tail(
+                conv_all, i, xbc, params["ssm_conv_w"][i], params["ssm_conv_b"][i], valid)
         x = xbc[..., : d["inner"]].reshape(Bsz, T, H, P)
         b_in = xbc[..., d["inner"]: d["inner"] + d["bc"]].reshape(Bsz, T, G, N)
         c_in = xbc[..., d["inner"] + d["bc"]:].reshape(Bsz, T, G, N)
@@ -896,6 +968,109 @@ def _ssm_out(params, lay: _Layer, y, z, cfg):
         y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.rms_eps)
         y = y.reshape(Bsz, T, d["inner"]).astype(z.dtype) * params["ssm_norm"][i]
         return jnp.einsum("btf,fe->bte", y, params["ssm_w_out"][i])
+
+
+def _kda_in(params, lay: _Layer, h, valid, cfg):
+    """A delta-rule mixer's input projection of the normed h [B, T, e]: what
+    the mixing takes a set of rows at a time (what the three convolutions run
+    over: q, k and v; the float32 log-decay a head and key channel ``g`` =
+    -exp(A_log) softplus(low rank + dt_bias) and the writing strength a head
+    ``beta`` = 2 sigmoid(.), in (0, 2): a state's eigenvalues in (-1, 1]),
+    and the output gate's low rank. A token that is none (``valid`` [B, T]
+    false) has ``beta`` 0 and ``g`` 0 (a decay of 1): it leaves the state
+    where the row's last real token put it. Scopes as ``_ssm_in``'s, under
+    ``kda_mixer``: ``kda_conv`` and ``kda_scan`` or ``kda_step`` inside
+    ``attn_core/kda_mixer``."""
+    i = lay.attn_i  # the row among the delta-rule layers
+    d = kda_dims(cfg)
+    f32 = jnp.float32
+    with scope("attn_qkv"), scope("kda_mixer"):
+        proj = jnp.einsum("bte,ef->btf", h, params["kda_w_in"][i])
+        qkv = proj[..., : d["conv"]]
+        low = proj[..., d["conv"]: d["conv"] + d["rank"]]
+        gate_low = proj[..., d["conv"] + d["rank"]: d["conv"] + 2 * d["rank"]]
+        step = jax.nn.softplus(
+            jnp.einsum("btr,rf->btf", low, params["kda_w_decay"][i],
+                       preferred_element_type=f32) + params["kda_dt_bias"][i].astype(f32))
+        g = -jnp.exp(params["kda_a_log"][i].astype(f32))[:, None] * step.reshape(
+            step.shape[:2] + (cfg.kda_heads, cfg.kda_head_dim))
+        beta = 2.0 * jax.nn.sigmoid(proj[..., -cfg.kda_heads:].astype(f32))
+        if valid is not None:
+            g = jnp.where(valid[..., None, None], g, 0.0)
+            beta = jnp.where(valid[..., None], beta, 0.0)
+    return (qkv, g, beta), gate_low
+
+
+def _kda_mix(params, lay: _Layer, qkv, g, beta, state_all, conv_all, valid, cfg):
+    """The mixing itself for one set of rows, from and into its carried state
+    ``state_all`` [n, B, H, K, V] and convolution tails ``conv_all``
+    [n, B, taps - 1, channels] at this layer's row: the three convolutions
+    over ``qkv`` [B, T, channels], queries and keys to unit length a head (the
+    query times K ** -0.5), then the delta rule with ``g`` [B, T, H, K] and
+    ``beta`` [B, T, H]. One token a row takes the rule's own line on the leaf
+    where it lies (``ops/kda.py kda_step_in_place``: a kernel where the state
+    tiles, ``kda_step`` where not), more the chunked form (``kda_scan``): the
+    same state either way. Returns (o float32 [B, H, V] or [B, T, H, V], the
+    two leaves)."""
+    from ray_tpu.ops.kda import kda_scan, kda_step_in_place
+
+    i = lay.attn_i
+    Bsz, T, _ = qkv.shape
+    H, D = cfg.kda_heads, cfg.kda_head_dim
+    with scope("attn_core"), scope("kda_mixer"):
+        with scope("kda_conv"):
+            qkv, conv_all = _conv_through_tail(
+                conv_all, i, qkv, params["kda_conv_w"][i], jnp.zeros((), jnp.float32), valid)
+            q, k, v = (qkv[..., j * H * D:(j + 1) * H * D].reshape(Bsz, T, H, D) for j in range(3))
+            q, k = (t * jax.lax.rsqrt((t * t).sum(axis=-1, keepdims=True) + _L2_EPS) for t in (q, k))
+            q = q * D ** -0.5
+        if T == 1:  # a row handed out of the leaf would be a copy out and a copy in
+            with scope("kda_step"):
+                o, state_all = kda_step_in_place(
+                    state_all, i, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+        else:
+            state = jax.lax.dynamic_index_in_dim(state_all, i, 0, keepdims=False)
+            with scope("kda_scan"):
+                o, state = kda_scan(state, q, k, v, g, beta, cfg.kda_chunk)
+            state_all = jax.lax.dynamic_update_index_in_dim(state_all, state, i, 0)
+    return o, state_all, conv_all
+
+
+def _kda_out(params, lay: _Layer, o, gate_low, cfg):
+    """Norm a head, gate a channel and output projection of the mixing's ``o``
+    (any shape of [B, T, inner] numbers) -> [B, T, e]: row by row again."""
+    i = lay.attn_i
+    Bsz, T, _ = gate_low.shape
+    H, D = cfg.kda_heads, cfg.kda_head_dim
+    f32 = jnp.float32
+    with scope("attn_out"), scope("kda_mixer"):
+        o = o.reshape(Bsz, T, H, D)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.rms_eps)
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "btr,rf->btf", gate_low, params["kda_w_gate"][i], preferred_element_type=f32))
+        o = o * params["kda_norm"][i].astype(f32) * gate.reshape(Bsz, T, H, D)
+        return jnp.einsum("btf,fe->bte", o.reshape(Bsz, T, H * D).astype(gate_low.dtype),
+                          params["kda_w_out"][i])
+
+
+# The mixers that keep a state a sequence, by layer kind: the three parts
+# ``decode_forward`` calls (the input projection of every set's rows as one,
+# ``-> (what the mixing takes a set at a time, what the output takes)``; the
+# mixing of one set, ``-> (y, *leaves)``; gate, norm and output projection of
+# all rows as one) and the ``STATE_LEAVES`` the mixing carries.
+STATE_MIXERS = {
+    "ssm": (_ssm_in, _ssm_mix, _ssm_out, ("ssm_state", "ssm_conv")),
+    "kda": (_kda_in, _kda_mix, _kda_out, ("kda_state", "kda_conv")),
+}
+# What a slot holds whatever its length, beside its stripes of keys and
+# values: every such leaf of every kind of mixer that keeps a state, [layers
+# of the kind, slots, ..] with the slot on axis 1 (``state_cache_shapes``).
+# The one list the engine's pool (``state_bytes_per_slot``, ``stateful``), its
+# chunk programs (what is stacked, unstacked, zeroed and copied into a slot
+# with the stripes), ``init_kv_cache``, ``decode_forward`` and
+# ``llm/config.py refuse_stateful`` read: a further kind adds a row above and
+# its shapes, and nothing asks which kind.
+STATE_LEAVES = tuple(name for *_, names in STATE_MIXERS.values() for name in names)
 
 
 def _feed_forward(params, lay: _Layer, x, cfg):
@@ -971,7 +1146,8 @@ def forward_hidden(params, tokens, cfg, mesh: Optional[Mesh] = None, positions=N
     if not plan(cfg).whole:
         raise NotImplementedError(
             "models/patterned.py forward_hidden: blocks that are a mixer or a feed-forward "
-            "alone, and state-space mixers, run through the cache only (prefill, decode_step)"
+            "alone, and mixers that keep a state, run through the cache only (prefill, "
+            "decode_step)"
         )
     B, T = tokens.shape
     if positions is None:
@@ -1165,7 +1341,12 @@ def _cache_reader(cfg, params, cache, positions, kinds):
     over the layer's whole stripe; a sliding layer cuts the ``window + T - 1``
     positions its queries can see out of the stripe first, from a start
     rounded down to ``_WINDOW_ALIGN`` (where that is the whole stripe, the
-    stripe under the window's mask)."""
+    stripe under the window's mask). A full layer whose scores over the whole
+    stripe would pass ``_STRIPE_SCORES_MAX_BYTES`` walks the stripe in blocks
+    of ``_FULL_KEY_BLOCK`` key positions instead, up to the furthest row's
+    last query and no further, with a running maximum, sum and context in
+    float32 (as ``_latent_reader`` walks a latent cache): neither the work nor
+    any temporary follows the stripe where the rows are shorter."""
     T = positions.shape[1]
     S = cache["k"].shape[3]
     W, A = cfg.sliding_window, _WINDOW_ALIGN
@@ -1192,8 +1373,44 @@ def _cache_reader(cfg, params, cache, positions, kinds):
         return seen & (qpos - slot < W) if kind == "sliding" else seen
 
     masks = {kind: stripe_mask(kind) for kind in _SCOPE_OF_KIND if kind in kinds}
+    B = positions.shape[0]
+    bk = _FULL_KEY_BLOCK
+    heads = max((h for t, h, _ in plan(cfg).kinds if t == "full"), default=0)
+    in_blocks = S % bk == 0 and S > bk and B * heads * T * S * 4 > _STRIPE_SCORES_MAX_BYTES
+    if in_blocks:  # row b's queries are consecutive from positions[b, 0]: the last sees furthest
+        n_blocks = jnp.minimum(jnp.max(positions[:, -1]) // bk + 1, S // bk)
+
+    def read_blocks(q, ck_all, cv_all, lay):
+        K, D = ck_all.shape[2], q.shape[-1]
+        G = q.shape[2] // K
+        qg = q.reshape(B, T, K, G, D)
+
+        def block(i, carry):
+            m, den, acc = carry
+            at = (lay.kv_i, 0, 0, i * bk, 0)
+            kb = jax.lax.dynamic_slice(ck_all, at, (1, B, K, bk, D))[0]
+            vb = jax.lax.dynamic_slice(cv_all, at, (1, B, K, bk, D))[0]
+            s = jnp.einsum("btkgd,bksd->bktgs", qg, kb, preferred_element_type=jnp.float32)
+            seen = (i * bk + jnp.arange(bk))[None, None, :] <= qpos  # [B, T, bk]
+            s = jnp.where(seen[:, None, :, None, :], s * D**-0.5, -1e30)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            den = alpha * den + p.sum(axis=-1)
+            pv = jnp.einsum("bktgs,bksd->bktgd", p.astype(vb.dtype), vb,
+                            preferred_element_type=jnp.float32)
+            return m_new, den, alpha[..., None] * acc + pv
+
+        # block 0 holds position 0, which every query sees: no row's maximum
+        # is still the mask's when a block past its last query comes
+        init = (jnp.full((B, K, T, G), -1e30, jnp.float32), jnp.zeros((B, K, T, G), jnp.float32),
+                jnp.zeros((B, K, T, G, D), jnp.float32))
+        _, den, acc = jax.lax.fori_loop(0, n_blocks, block, init)
+        return (acc / den[..., None]).transpose(0, 2, 1, 3, 4).reshape(B, T, K * G, D).astype(q.dtype)
 
     def read(q, ck_all, cv_all, lay):
+        if in_blocks and lay.kind == "full":
+            return read_blocks(q, ck_all, cv_all, lay)
         if whole[lay.kind]:
             return _grouped_attention(q, ck_all[lay.kv_i], cv_all[lay.kv_i], masks[lay.kind])
         return _grouped_attention(
@@ -1332,11 +1549,11 @@ def decode_forward(
     # the real tokens of all rows, where any set has tokens that are none
     real = None if all(rows.valid is None for rows in sets) else _join(
         [rows.real() for rows in sets])
-    if "ssm" in kinds and any(
+    if kinds & set(STATE_MIXERS) and any(
         jax.typeof(x).sharding.mesh.size > 1 for x in (cache["k"], *jax.tree.leaves(params))
     ):
         raise NotImplementedError(
-            "models/patterned.py: state-space layers run on one device (no rule places "
+            "models/patterned.py: layers that keep a state run on one device (no rule places "
             "their state or their projections on a mesh)"
         )
 
@@ -1348,27 +1565,28 @@ def decode_forward(
     narrow = (beside is not None and not with_logits and pl.kinds[-1][2] != "none"
               and (pl.reps == 0 or pl.tail_from < cfg.n_layers))
     # a model with routed experts carries its routing counts beside x, one
-    # with state-space layers each set's two leaves behind those
+    # with layers that keep a state each set's ``STATE_LEAVES`` behind those
     stats0 = (jnp.zeros((len(moe_stats_names(cfg)),), jnp.int32),) if cfg.moe_experts else ()
-    ssm0 = tuple(
-        tuple(rows.cache[name] for name in SSM_LEAVES) if "ssm" in kinds else ()
-        for rows in sets)
+    state0 = tuple(
+        {name: rows.cache[name] for name in STATE_LEAVES if name in rows.cache} for rows in sets)
 
     def layer(lay: _Layer, carry):
-        x, kv, stats, ssm = carry
-        if lay.kind == "ssm":
+        x, kv, stats, state = carry
+        if lay.kind in STATE_MIXERS:
+            project, mix, out, names = STATE_MIXERS[lay.kind]
             h = _rmsnorm(x, params["attn_norm"][lay.mixer_i], cfg.rms_eps, cfg.fused_rmsnorm)
-            z, xbc, dt = _ssm_in(params, lay, h, real, cfg)
+            parts, gate = project(params, lay, h, real, cfg)
             mixed = [
-                _ssm_mix(params, lay, xbc_rows, dt_rows, *leaves, rows.valid, cfg)
-                for rows, xbc_rows, dt_rows, leaves in zip(
-                    sets, _split(xbc, shapes), _split(dt, shapes), ssm)
+                mix(params, lay, *of_rows, *(leaves[name] for name in names), rows.valid, cfg)
+                for rows, leaves, *of_rows in zip(
+                    sets, state, *(_split(part, shapes) for part in parts))
             ]
-            ssm = tuple(tuple(leaves) for _, *leaves in mixed)
+            state = tuple({**leaves, **dict(zip(names, new))}
+                          for leaves, (_, *new) in zip(state, mixed))
             ys = [y for y, *_ in mixed]
             y = ys[0] if len(sets) == 1 else _join(
                 [y.reshape(rows.B, rows.T, -1) for rows, y in zip(sets, ys)])
-            x = x + _ssm_out(params, lay, y, z, cfg)
+            x = x + out(params, lay, y, gate, cfg)
         elif lay.kind != "none":
             h = _rmsnorm(x, params["attn_norm"][lay.mixer_i], cfg.rms_eps, cfg.fused_rmsnorm)
             if lay.latent:  # k: the shared rotated key; v: the normed latent
@@ -1397,16 +1615,15 @@ def decode_forward(
                 x = _split(x, shapes)[1]
             x, layer_stats = _feed_forward(params, lay, x, cfg)
             stats = tuple(s + layer_stats for s in stats)
-        return (x, kv, stats, ssm)
+        return (x, kv, stats, state)
 
-    x, kv, stats, ssm = _run_layers(
-        cfg, layer, (x, tuple((rows.cache["k"], rows.cache["v"]) for rows in sets), stats0, ssm0))
+    x, kv, stats, state = _run_layers(
+        cfg, layer, (x, tuple((rows.cache["k"], rows.cache["v"]) for rows in sets), stats0, state0))
     new_caches = []
-    for rows, (new_k, new_v), leaves in zip(sets, kv, ssm):
+    for rows, (new_k, new_v), leaves in zip(sets, kv, state):
         grew = rows.T if rows is sets[0] or rows.valid is None else rows.valid.sum(
             axis=1, dtype=jnp.int32)
-        new_cache = {"k": new_k, "v": new_v, "length": rows.cache["length"] + grew,
-                     **dict(zip(SSM_LEAVES, leaves))}
+        new_cache = {"k": new_k, "v": new_v, "length": rows.cache["length"] + grew, **leaves}
         _ride_stats(rows.cache, new_cache, stats)
         new_caches.append(new_cache)
     # the rows whose next token is asked for: one position a row of the first

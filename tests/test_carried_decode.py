@@ -14,7 +14,7 @@ import pytest
 from ray_tpu.llm import EngineConfig, JaxEngine, LLMConfig, ModelConfig, SamplingParams
 from ray_tpu.llm.engine import programs
 from ray_tpu.models.llama import LlamaConfig, init_kv_cache, init_params, prefill
-from ray_tpu.models.patterned import SSM_LEAVES, moe_stats_names, plan
+from ray_tpu.models.patterned import STATE_LEAVES, moe_stats_names, plan
 from tests.test_chunk_rows import _Compiles
 
 pytestmark = pytest.mark.timeout(900) if hasattr(pytest.mark, "timeout") else []
@@ -79,7 +79,7 @@ def _assert_slots_equal(got, want, before, rows_live, written_at, slots=range(SL
             rest = np.arange(STRIPE) != at
             np.testing.assert_array_equal(g[:, slot][:, :, rest], w[:, slot][:, :, rest])
             np.testing.assert_array_equal(g[:, slot][:, :, rest], b[:, slot][:, :, rest])
-    for name in SSM_LEAVES:
+    for name in STATE_LEAVES:
         if name in want:
             g, w, b = (np.asarray(c[name]) for c in (got, want, before))
             for slot in slots:
@@ -156,7 +156,7 @@ def test_a_carrying_final_chunk_is_the_decode_step_and_the_chunk(pool):
     length = np.asarray(cache["length"])
     np.testing.assert_array_equal(got_cache["length"], [*(length[:3] + 1), len(prompt)])
     np.testing.assert_array_equal(got_cache["length"], want_cache["length"])
-    for name in ("k", "v", *(n for n in SSM_LEAVES if n in want_cache)):
+    for name in ("k", "v", *(n for n in STATE_LEAVES if n in want_cache)):
         np.testing.assert_allclose(got_cache[name][:, 3], want_cache[name][:, 3], **TOL)
     _assert_slots_equal(got_cache, step_cache, cache, live, length, slots=range(3))
     np.testing.assert_array_equal(got_tokens, [*np.asarray(step_tokens)[:3], int(want_tok)])
@@ -182,7 +182,7 @@ def test_a_launch_that_carries_none_leaves_the_pool_as_it_was(pool):
         np.testing.assert_array_equal(after[name], cache[name])
     np.testing.assert_array_equal(tokens, rows["tokens"])
     np.testing.assert_array_equal(keys, rows["keys"])
-    for name in ("k", "v", "length", *(n for n in SSM_LEAVES if n in want)):
+    for name in ("k", "v", "length", *(n for n in STATE_LEAVES if n in want)):
         np.testing.assert_allclose(got[name], want[name], **TOL)
 
 

@@ -61,8 +61,10 @@ def native_kernels(monkeypatch):
 
     import ray_tpu.ops.ssm  # noqa: F401 — nor this one
 
+    import ray_tpu.ops.kda  # noqa: F401 — nor this one
+
     for name in ("ray_tpu.ops.rmsnorm", "ray_tpu.ops.quant", "ray_tpu.ops.grouped_matmul",
-                 "ray_tpu.ops.decode_attention", "ray_tpu.ops.ssm"):
+                 "ray_tpu.ops.decode_attention", "ray_tpu.ops.ssm", "ray_tpu.ops.kda"):
         monkeypatch.setattr(sys.modules[name], "interpret", lambda: False)
 
 
@@ -171,6 +173,21 @@ def _ssm_step_shapes(layers=5, slots=64, heads=128, width=64, state=128, groups=
     )
 
 
+def _kda_step_in_place(state_all, layer, q, k, v, g, beta):
+    from ray_tpu.ops.kda import kda_step_in_place
+
+    return kda_step_in_place(state_all, layer, q, k, v, g, beta)
+
+
+def _kda_step_shapes(layers=3, slots=64, heads=64, width=128):
+    """One new token a slot on the Solar-Open2 cell's stacked state: 64 heads
+    of [128, 128] float32, the layer's row a scalar."""
+    f32 = jnp.float32
+    a_head = ((slots, heads, width), f32)
+    return (((layers, slots, heads, width, width), f32), ((), jnp.int32), a_head, a_head, a_head,
+            a_head, ((slots, heads), f32))
+
+
 def _latent_decode_attention_shapes(layers=5, slots=24, stripe=24576, heads=32):
     """One new token a slot over the Kanana-2 cell's cache: 32 query heads on
     one shared key in two leaves, the rotated key in a 128-lane row (a 64-wide
@@ -219,6 +236,7 @@ KERNELS = {
     "latent_decode_attention_32_on_one_key": (
         _latent_decode_attention, _latent_decode_attention_shapes()),
     "ssm_step_in_place": (_ssm_step_in_place, _ssm_step_shapes()),
+    "kda_step_in_place": (_kda_step_in_place, _kda_step_shapes()),
     "quantize_int8": (_quantize, (((3072, 8192), jnp.bfloat16),)),
     "dequantize_int8": (
         _dequantize,
@@ -639,7 +657,72 @@ def test_the_other_families_decode_steps_hold_nothing_of_the_state_space_path(
     fn, args = _served_programs(cfg, slots, stripe, one_chip)["decode_step"]
     assert set(args[1]) == {"k", "v", "length"}
     text = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()
-    assert "ssm_" not in text and "moe_latent_proj" not in text
+    assert "ssm_" not in text and "kda_" not in text and "moe_latent_proj" not in text
+
+
+# ---- layers that keep a delta-rule state a slot (Solar-Open2's cut) ----------
+
+
+def _delta_rule_cut():
+    from ray_tpu.models.llama import LlamaConfig
+
+    return LlamaConfig.solar_open2_250b(
+        n_layers=4, gqa_layers=(3,), moe_experts_held=40, vocab_size=24576, max_seq_len=8192)
+
+
+def test_delta_rule_decode_step_moves_its_state_where_it_lies(
+        one_chip, no_compile_cache, native_kernels):
+    """The Solar-Open2 cell's decode step (64 slots of 8,192; three delta-rule
+    layers and one gated attention layer, each with 40 of 320 experts held, at
+    published widths) compiles for the chip beside 6.6 GB of weights: the 0.8
+    GB of float32 state is updated in the donated cache (no copy of the leaf,
+    temporaries far under one layer's 0.27 GB) by one ``kda_step`` kernel a
+    layer on the leaf whole, under ``kda_mixer``; the held banks go through
+    the grouped-matmul kernels whole, the attention layer reads its stripe
+    through the decode kernel and its gate is a channel's."""
+    fn, args = _served_programs(_delta_rule_cut(), 64, 8192, one_chip)["decode_step"]
+    assert set(args[1]) == {"k", "v", "length", "kda_state", "kda_conv"}
+    step = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text = step.as_text()
+    state = "f32[3,64,64,128,128]"
+    assert [line.strip()[:120] for line in text.splitlines()
+            if " copy(" in line and line.split(" = ", 1)[-1].startswith(state)] == []
+    assert step.memory_analysis().temp_size_in_bytes < 128e6
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("moe_ffn/experts" in line for line in kernels) >= 3  # gate, up and down
+    assert sum("attn_core" in line and "kda_mixer" not in line for line in kernels) == 1
+    assert sum("attn_core/kda_mixer/kda_step" in line for line in kernels) == 3
+    for scope in ("attn_qkv/kda_mixer", "kda_mixer/kda_step", "kda_mixer/kda_conv",
+                  "attn_out/kda_mixer", "attn_out/gate"):
+        assert scope in text, scope
+    # every token of a decode step is real: the convolutions' next tail is a
+    # slice of their inputs, not a gather by each row's own end
+    assert [line.strip()[:120] for line in text.splitlines()
+            if " gather(" in line and "kda_conv" in line] == []
+
+
+def test_delta_rule_middle_chunk_fits_at_its_widest(one_chip, no_compile_cache, native_kernels):
+    """The cell's widest launch (four rows of 1,024 tokens, the chunked rule
+    over sixteen 64-token chunks a row and layer, attention over the
+    8,192-position stripes in blocks of 512 key positions: scores of the
+    whole stripes would be 8.6 GB, and the launch did not load beside 6.6 GB
+    of weights and a 3 GB pool): temporaries under 3.5 GB."""
+    from ray_tpu.models.llama import prefill
+
+    cfg, rows = _delta_rule_cut(), 4
+    params, stripe, _, _, _ = _served_programs(cfg, 64, 8192, one_chip)["chunk_mid"][1]
+    stripes = {
+        k: jax.ShapeDtypeStruct((rows,) if k == "length" else (v.shape[0], rows) + v.shape[2:],
+                                v.dtype, sharding=one_chip)
+        for k, v in stripe.items()
+    }
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    compiled = jax.jit(
+        lambda p, o, t, n, s: prefill(p, o, t, cfg, lengths=n, start_pos=s, with_logits=False)[1],
+        donate_argnums=(1,),
+    ).lower(params, stripes, i32(rows, 1024), i32(rows), i32(rows)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.5e9
+    assert "kda_mixer/kda_scan" in compiled.as_text()
 
 
 # ---- middle chunks of several rows (``llm/engine.py programs``) -------------

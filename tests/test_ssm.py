@@ -26,11 +26,13 @@ from ray_tpu.models.llama import (
     init_params,
     prefill,
 )
-from ray_tpu.models.patterned import SSM_LEAVES, _param_shapes
+from ray_tpu.models.patterned import _param_shapes, state_cache_shapes
 from ray_tpu.ops import ssm
 from ray_tpu.ops.ssm import causal_conv, ssm_scan, ssm_step, ssm_step_in_place
 
 CFG = LlamaConfig.nemotron_tiny()
+# the leaves a slot of this model holds whatever its length
+STATE = tuple(state_cache_shapes(CFG, 1))
 # what benchmark/families/ssm_latent_moe.py reads, for the reference: the
 # configuration holds 4 of the router's 16 experts
 PUBLISHED = {
@@ -107,7 +109,7 @@ def test_published_depth_counts_its_parameters_and_the_cut_its_cache():
     assert cache["k"].shape == cache["v"].shape == (1, 64, 2, 2048, 128)
     assert cache["ssm_state"].shape == (5, 64, 128, 64, 128) and cache["ssm_state"].dtype == jnp.float32
     assert cache["ssm_conv"].shape == (5, 64, 3, 10240)
-    per_slot = sum(cache[k].size * cache[k].dtype.itemsize for k in SSM_LEAVES) // 64
+    per_slot = sum(cache[k].size * cache[k].dtype.itemsize for k in STATE) // 64
     assert per_slot == 5 * (128 * 64 * 128 * 4 + 3 * 10240 * 2) == 21_278_720
 
 
@@ -304,7 +306,7 @@ def test_a_padded_rows_state_is_the_rows_own(model):
         alone_logits, alone = prefill(params, init_kv_cache(CFG, 1, 64),
                                       jnp.asarray(tokens[b:b + 1, :n]), CFG)
         np.testing.assert_allclose(logits[b], alone_logits[0], **TOL)
-        for name in SSM_LEAVES:
+        for name in STATE:
             np.testing.assert_allclose(cache[name][:, b], alone[name][:, 0], atol=1e-5)
         for name in ("k", "v"):
             np.testing.assert_allclose(cache[name][:, b, :, :n], alone[name][:, 0, :, :n], atol=1e-5)
@@ -336,7 +338,7 @@ def test_a_prompt_in_chunks_of_a_multi_row_launch_equals_the_prompt_whole(model,
                 ones[b], done[b] = one, done[b] + 8
     cache = init_kv_cache(CFG, 3, 64)
     # a tenant's leftovers in every slot: the final chunk must overwrite them
-    cache = {k: (v + 1 if k in SSM_LEAVES else v) for k, v in cache.items()}
+    cache = {k: (v + 1 if k in STATE else v) for k, v in cache.items()}
     first = []
     for b, n in enumerate(lens):
         tail = np.zeros((1, 8), np.int32)
@@ -352,7 +354,7 @@ def test_a_prompt_in_chunks_of_a_multi_row_launch_equals_the_prompt_whole(model,
         logits, whole = prefill(params, init_kv_cache(CFG, 1, 64), jnp.asarray(tokens[b:b + 1, :n]), CFG)
         assert first[b] == int(jnp.argmax(logits[0]))
         assert int(cache["length"][slot]) == n
-        for name in SSM_LEAVES:
+        for name in STATE:
             np.testing.assert_allclose(cache[name][:, slot], whole[name][:, 0], atol=1e-5)
         for name in ("k", "v"):
             np.testing.assert_allclose(cache[name][:, slot, :, :n], whole[name][:, 0, :, :n], atol=1e-5)
