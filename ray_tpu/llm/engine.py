@@ -1258,6 +1258,7 @@ class JaxEngine:
                 return
 
     def get_stats(self) -> dict:
+        from ray_tpu.models.patterned import state_mixer_forms
         from ray_tpu.tpu.accelerator import device_report
 
         return {
@@ -1279,7 +1280,10 @@ class JaxEngine:
                  "active": sum(s is not None for s in p.slots),
                  "kv_bytes_per_token": p.kv_bytes_per_token,
                  "kv_bytes_per_token_held": p.kv_bytes_per_token_held,
-                 "state_bytes_per_slot": p.state_bytes_per_slot}
+                 "state_bytes_per_slot": p.state_bytes_per_slot,
+                 # which form its state mixers take for a chunk and a step
+                 # (``kernel`` or ``plain``): static a shape, asked where the trace asks
+                 "state_mixer_forms": state_mixer_forms(self.model_cfg)}
                 for p in self._pools
             ],
             "prefix_cache_hits": self._prefix_hits,

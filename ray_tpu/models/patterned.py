@@ -1009,9 +1009,9 @@ def _kda_mix(params, lay: _Layer, qkv, g, beta, state_all, conv_all, valid, cfg)
     query times K ** -0.5), then the delta rule with ``g`` [B, T, H, K] and
     ``beta`` [B, T, H]. One token a row takes the rule's own line on the leaf
     where it lies (``ops/kda.py kda_step_in_place``: a kernel where the state
-    tiles, ``kda_step`` where not), more the chunked form (``kda_scan``): the
-    same state either way. Returns (o float32 [B, H, V] or [B, T, H, V], the
-    two leaves)."""
+    tiles, ``kda_step`` where not), more the chunked form (``kda_scan``, a kernel
+    or ``kda_scan_plain`` likewise): the same state either way. Returns (o
+    float32 [B, H, V] or [B, T, H, V], the two leaves)."""
     from ray_tpu.ops.kda import kda_scan, kda_step_in_place
 
     i = lay.attn_i
@@ -1644,3 +1644,29 @@ def decode_forward(
     if beside is None:
         return logits[0], new_caches[0]
     return logits[0], new_caches[0], logits[1], new_caches[1]
+
+
+def state_mixer_forms(cfg) -> dict:
+    """kind -> {"chunk", "step"}: which form a model's mixers that keep a
+    state take for a prompt chunk and for a decode step, ``"kernel"`` or
+    ``"plain"``. Static a shape, and asked of the functions the trace asks
+    (``ops/ssm.py step_groups``; ``ops/kda.py scan_heads`` and ``step_heads``;
+    the state-space chunk has the one form, ``ssm_scan``), so a run says which
+    program it measured (``llm/engine.py get_stats()["pools"][i]
+    ["state_mixer_forms"]``). Empty for a model whose slots are stripes alone.
+    (At the module's end: the lines the served programs are traced from keep
+    their numbers, and with them the compile cache's keys.)"""
+    from ray_tpu.ops import kda, ssm
+
+    def form(tiles):
+        return "plain" if tiles is None else "kernel"
+
+    pl, forms = plan(cfg), {}
+    if pl.n_ssm:
+        forms["ssm"] = {"chunk": "plain", "step": form(ssm.step_groups(
+            cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups))}
+    if pl.n_kda:
+        H, D = cfg.kda_heads, cfg.kda_head_dim
+        forms["kda"] = {"chunk": form(kda.scan_heads(H, D, D, cfg.kda_chunk)),
+                        "step": form(kda.step_heads(H, D, D))}
+    return forms

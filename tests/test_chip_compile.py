@@ -188,6 +188,21 @@ def _kda_step_shapes(layers=3, slots=64, heads=64, width=128):
             a_head, ((slots, heads), f32))
 
 
+def _kda_scan(state, q, k, v, g, beta):
+    from ray_tpu.ops.kda import kda_scan
+
+    return kda_scan(state, q, k, v, g, beta, 64)
+
+
+def _kda_scan_shapes(rows=4, tokens=1024, heads=64, width=128):
+    """The Solar-Open2 cell's widest chunk launch on the delta rule alone: four
+    rows of 1,024 tokens, 64 heads of [128, 128] float32, chunks of 64."""
+    f32 = jnp.float32
+    a_token = ((rows, tokens, heads, width), f32)
+    return (((rows, heads, width, width), f32), a_token, a_token, a_token, a_token,
+            ((rows, tokens, heads), f32))
+
+
 def _latent_decode_attention_shapes(layers=5, slots=24, stripe=24576, heads=32):
     """One new token a slot over the Kanana-2 cell's cache: 32 query heads on
     one shared key in two leaves, the rotated key in a 128-lane row (a 64-wide
@@ -237,6 +252,7 @@ KERNELS = {
         _latent_decode_attention, _latent_decode_attention_shapes()),
     "ssm_step_in_place": (_ssm_step_in_place, _ssm_step_shapes()),
     "kda_step_in_place": (_kda_step_in_place, _kda_step_shapes()),
+    "kda_scan": (_kda_scan, _kda_scan_shapes()),
     "quantize_int8": (_quantize, (((3072, 8192), jnp.bfloat16),)),
     "dequantize_int8": (
         _dequantize,
@@ -701,15 +717,23 @@ def test_delta_rule_decode_step_moves_its_state_where_it_lies(
             if " gather(" in line and "kda_conv" in line] == []
 
 
-def test_delta_rule_middle_chunk_fits_at_its_widest(one_chip, no_compile_cache, native_kernels):
+@pytest.mark.parametrize("rows,width", [(4, 1024), (1, 128)], ids=["widest", "narrowest"])
+def test_delta_rule_middle_chunk_fits_at_its_widest(
+        rows, width, one_chip, no_compile_cache, native_kernels):
     """The cell's widest launch (four rows of 1,024 tokens, the chunked rule
     over sixteen 64-token chunks a row and layer, attention over the
     8,192-position stripes in blocks of 512 key positions: scores of the
     whole stripes would be 8.6 GB, and the launch did not load beside 6.6 GB
-    of weights and a 3 GB pool): temporaries under 3.5 GB."""
+    of weights and a 3 GB pool): temporaries under 3.5 GB (3.18 with the
+    chunked rule as a graph, 1.62 since it is a kernel). The chunked rule is
+    one ``kda_scan`` kernel a layer, three a launch, under the scope the
+    roofline share reads: Mosaic takes its blocks (a set of heads' [64, 256]
+    slabs of the projections where they lie), its float32 products at the
+    highest precision and its VMEM; and the same kernel at the narrowest
+    final chunk, one row of 128 tokens."""
     from ray_tpu.models.llama import prefill
 
-    cfg, rows = _delta_rule_cut(), 4
+    cfg = _delta_rule_cut()
     params, stripe, _, _, _ = _served_programs(cfg, 64, 8192, one_chip)["chunk_mid"][1]
     stripes = {
         k: jax.ShapeDtypeStruct((rows,) if k == "length" else (v.shape[0], rows) + v.shape[2:],
@@ -720,9 +744,17 @@ def test_delta_rule_middle_chunk_fits_at_its_widest(one_chip, no_compile_cache, 
     compiled = jax.jit(
         lambda p, o, t, n, s: prefill(p, o, t, cfg, lengths=n, start_pos=s, with_logits=False)[1],
         donate_argnums=(1,),
-    ).lower(params, stripes, i32(rows, 1024), i32(rows), i32(rows)).compile()
+    ).lower(params, stripes, i32(rows, width), i32(rows), i32(rows)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 3.5e9
-    assert "kda_mixer/kda_scan" in compiled.as_text()
+    lines = compiled.as_text().splitlines()
+    kernels = [line for line in lines if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("attn_core/kda_mixer/kda_scan" in line for line in kernels) == 3
+    # nothing else of an operand's size runs under its scope: the kernel reads
+    # the projections where the convolution and the decay's matmul wrote them
+    whole = (f"f32[{rows},{width},8192]", f"f32[{rows},{width},64,128]")
+    assert [line.strip()[:120] for line in lines
+            if "kda_mixer/kda_scan" in line and (" fusion(" in line or " copy(" in line)
+            and line.split(" = ", 1)[-1].startswith(whole)] == []
 
 
 # ---- middle chunks of several rows (``llm/engine.py programs``) -------------

@@ -101,6 +101,9 @@ def test_engine_counts_the_state_a_slot_holds_and_the_assignments_held(engine):
     (pool,) = stats["pools"]
     # 3 delta-rule layers: a float32 state [4, 16, 16] and 3 inputs of 192 channels
     assert pool["state_bytes_per_slot"] == 3 * (4 * 16 * 16 * 4 + 3 * 192 * 4)
+    # which program the run measured: the tiny preset's 16 x 16 state and
+    # chunks of 8 tile for neither kernel (``ops/kda.py scan_heads``, ``step_heads``)
+    assert pool["state_mixer_forms"] == {"kda": {"chunk": "plain", "step": "plain"}}
     # keys and values of the one attention layer: 2 heads of 16, float32
     assert pool["kv_bytes_per_token"] == 2 * 2 * 16 * 4
     c = stats["counters"]

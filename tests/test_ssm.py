@@ -519,6 +519,8 @@ def test_engine_counts_the_state_a_slot_holds_and_the_assignments_held(engine):
     (pool,) = stats["pools"]
     # 5 state-space blocks: a float32 state [8, 16, 16] and 3 inputs of 192 channels
     assert pool["state_bytes_per_slot"] == 5 * (8 * 16 * 16 * 4 + 3 * 192 * 4)
+    # the tiny preset's 16 x 16 state tiles for no kernel; a chunk has the one form
+    assert pool["state_mixer_forms"] == {"ssm": {"chunk": "plain", "step": "plain"}}
     # keys and values of the one attention block: 2 heads of 16, float32
     assert pool["kv_bytes_per_token"] == 2 * 2 * 16 * 4
     c = stats["counters"]
@@ -544,6 +546,7 @@ def test_a_model_whose_slots_are_stripes_alone_counts_no_state():
         eng.shutdown()
     assert first.token_ids == again.token_ids and again.metrics["prefix_hit_tokens"] == 32
     assert stats["pools"][0]["state_bytes_per_slot"] == 0
+    assert stats["pools"][0]["state_mixer_forms"] == {}
     assert stats["counters"]["prefix_bypassed_stateful"] == 0
     assert set(stats["counters"]["moe_assignments_held"].values()) == {0}
     assert sum(stats["counters"]["moe_assignments"].values()) > 0
