@@ -100,7 +100,7 @@ class ModelConfig:
     # "llama3-8b" | "llama3.2-3b" | "llama3-70b" | "laguna-xs.2" |
     # "laguna-tiny" | "kanana-2-30b-a3b" | "kanana-tiny" |
     # "nemotron-3-super-120b-a12b" | "nemotron-tiny" | "solar-open2-250b" |
-    # "solar-tiny"
+    # "solar-tiny" | "granite-4.0-h-micro" | "granite-tiny"
     model_id: str = "tiny"
     tokenizer: str = "byte"  # "byte" | transformers tokenizer path
     checkpoint_path: Optional[str] = None  # ray_tpu.train pytree checkpoint
@@ -141,6 +141,8 @@ def resolve_llama_config(model: "ModelConfig", engine: "EngineConfig", min_vocab
         "nemotron-tiny": LlamaConfig.nemotron_tiny,
         "solar-open2-250b": LlamaConfig.solar_open2_250b,
         "solar-tiny": LlamaConfig.solar_tiny,
+        "granite-4.0-h-micro": LlamaConfig.granite4_h_micro,
+        "granite-tiny": LlamaConfig.granite_tiny,
     }
     kw = dict(
         max_seq_len=engine.max_seq_len,

@@ -109,11 +109,11 @@ COUNTERS = (
     # scratch stripe (``prompt_tokens_from_prefix`` counts the same tokens at
     # admission; this one counts the copies)
     "prefix_seed_tokens",
-    # admissions of a model that keeps a recurrent state a slot, with the
-    # prefix cache on: no stored prefix is looked up for them and none is
-    # stored, because keys and values alone do not restore a slot
-    # (``_Pool.stateful``); stays 0 for every other model
-    "prefix_bypassed_stateful",
+    # a pool that keeps a state a slot (``_Pool.stateful``) stores and seeds
+    # a prefix as a snapshot of a slot (``_snapshot_store``): entries stored,
+    # seeded from and evicted, the bytes stored and the bytes seeded
+    "snapshots_stored", "snapshots_hit", "snapshots_evicted",
+    "snapshot_store_bytes", "snapshot_seed_bytes",
     # routed experts (``models/llama.py MOE_STATS``), summed over expert
     # layers and over the runs of each program: the decode program hands its
     # counts out beside its tokens, a prompt's middle chunks add theirs up on
@@ -204,6 +204,7 @@ class _Request:
         self.error: Optional[BaseException] = None
         self.lora_idx = lora_idx
         self.prefix_hit_tokens = 0
+        self.prefix_key = None  # of the snapshot it was seeded from
         self.pacer = TokenPacer()  # smooths multi-step token bursts for SSE
 
 
@@ -571,17 +572,31 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         return one
 
     @jax.named_scope("prefix_seed")
-    def seed_prefix(one, pk, pv):
-        """Copy a cached prefix KV [L, K, m, D] into the scratch stripe."""
+    def seed_prefix(one, pk, pv, state=None):
+        """Copy a cached prefix KV [L, K, m, D] into the scratch stripe (``state``: below)."""
         m = pk.shape[2]
         return {
-            **one,
+            **one, **(state or {}),
             "k": one["k"].at[:, 0, :, :m].set(pk),
             "v": one["v"].at[:, 0, :, :m].set(pv),
         }
 
+    # A pool whose slots hold a state stores a prefix as a snapshot of the
+    # scratch stripe a prompt's final chunk handed back: the state leaves are
+    # that stripe's own arrays (nothing is copied), and of its keys and values
+    # the first ``positions`` are cut out, a length of a few (static: one
+    # program a length, ``JaxEngine._snapshot_lengths``). ``seed_prefix`` with
+    # ``state`` starts a fresh stripe where the stored prompt ended: its state
+    # leaves are the entry's, as they are held (float32 the state); the
+    # prompt's tail then runs from the stored prompt's own length (what lies
+    # between it and the entry's ``m`` the tail overwrites or no query sees).
+    @jax.named_scope("prefix_store")
+    def store_snapshot(k, v, positions: int):
+        return k[:, 0, :, :positions], v[:, 0, :, :positions]
+
     return dict(decode_fn=decode_fn, decode_multi=decode_multi, chunk_mid=chunk_mid,
-                chunk_final=chunk_final, new_stripe=new_stripe, seed_prefix=seed_prefix)
+                chunk_final=chunk_final, new_stripe=new_stripe, seed_prefix=seed_prefix,
+                store_snapshot=store_snapshot)
 
 
 def top_k_static(cfg) -> int:
@@ -655,7 +670,9 @@ class JaxEngine:
         self._backlog: list[_Request] = []  # engine-thread-owned FIFO
         self._stop = threading.Event()
         # prefix cache: sha1(prompt[:bucket]) -> {k, v} device stripes
-        # (bucket-aligned lengths only, so jit specializations stay bounded)
+        # (bucket-aligned lengths only, so jit specializations stay bounded);
+        # in a pool that keeps a state a slot sha1(prompt) -> a snapshot of
+        # the slot behind the prompt (``_snapshot_store``)
         from collections import OrderedDict
 
         self._prefix_cache: "OrderedDict[bytes, dict]" = OrderedDict()
@@ -864,6 +881,10 @@ class JaxEngine:
             ),
         )
         self._seed_prefix_jit = jax.jit(fns["seed_prefix"], donate_argnums=(0,))
+        from ray_tpu.models.patterned import state_cache_shapes
+
+        self._state_leaves = tuple(state_cache_shapes(cfg, 1))
+        self._store_snapshot_jit = jax.jit(fns["store_snapshot"], static_argnums=(2,))
         # tiny device-side updates that keep the decode chain host-free
         self._set_tok_jit = jax.jit(
             lambda toks, slot, tok: toks.at[slot].set(tok), donate_argnums=(0,)
@@ -951,7 +972,15 @@ class JaxEngine:
                     self._run_chunk_final(
                         pool, one, np.zeros((1, width), np.int32), 1, 0, 0, 0.0, 1, None, 0)
                 book(f"chunk_final:width={width}", pool.cache)
-            if self.config.engine.enable_prefix_caching and not pool.stateful:
+            if self.config.engine.enable_prefix_caching and pool.stateful:
+                # a snapshot's cut at each length, and the seed from each
+                for m in self._snapshot_lengths(pool):
+                    one = self._new_stripe_jit(stripe)
+                    k, v = self._store_snapshot_jit(one["k"], one["v"], m)
+                    state = {name: one[name] for name in self._state_leaves}
+                    book("snapshot", self._seed_prefix_jit(
+                        self._new_stripe_jit(stripe), k, v, state))
+            elif self.config.engine.enable_prefix_caching:
                 # the store's cut of a slot at each bucket, and the program
                 # that seeds a stripe with one
                 for b in self.config.engine.prefill_buckets:
@@ -1013,16 +1042,16 @@ class JaxEngine:
             np.asarray(ids[:m], np.int32).tobytes()
         ).digest()
 
-    def _prefix_lookup(self, ids: list[int]):
+    def _prefix_lookup(self, ids: list[int], stripe: int):
         """Longest bucket-aligned cached prefix strictly shorter than the
-        prompt (>=1 suffix token must remain to produce last-logits)."""
+        prompt (>=1 suffix token must remain to produce last-logits); in a
+        pool that keeps a state a slot, the longest stored prompt of any
+        length (``_snapshot_lookup``). ``stripe``: the length of the stripe
+        to be seeded. Returns (entry, tokens, key)."""
         if not self.config.engine.enable_prefix_caching:
-            return None, 0
+            return None, 0, None
         if self._pools[0].stateful:
-            # a stored prefix is keys and values; a slot of this model also
-            # needs the state its layers had reached at the boundary
-            self._count({"prefix_bypassed_stateful": 1})
-            return None, 0
+            return self._snapshot_lookup(ids, stripe)
         for b in sorted(self.config.engine.prefill_buckets, reverse=True):
             if b >= len(ids):
                 continue
@@ -1031,9 +1060,9 @@ class JaxEngine:
             if entry is not None:
                 self._prefix_cache.move_to_end(key)
                 self._prefix_hits += 1
-                return entry, b
+                return entry, b, key
         self._prefix_misses += 1
-        return None, 0
+        return None, 0, None
 
     def _prefix_store(self, pool: _Pool, slot: int, ids: list[int]):
         """After a miss prefill: cache this prompt's KV at every bucket
@@ -1041,7 +1070,7 @@ class JaxEngine:
         budget (long-context entries are tens of MB each; an entry-only
         cap could pin gigabytes)."""
         ec = self.config.engine
-        if not ec.enable_prefix_caching or pool.stateful:
+        if not ec.enable_prefix_caching:
             return
         for b in ec.prefill_buckets:
             if b >= len(ids) or b > pool.stripe_len:
@@ -1055,12 +1084,94 @@ class JaxEngine:
             nbytes = int(k.nbytes + v.nbytes)
             self._prefix_cache[key] = {"k": k, "v": v, "nbytes": nbytes}
             self._prefix_bytes += nbytes
+        self._prefix_evict()
+
+    def _prefix_evict(self) -> int:
+        """Drop entries from the front until both budgets hold; how many went."""
+        ec, gone = self.config.engine, 0
         while self._prefix_cache and (
             len(self._prefix_cache) > ec.prefix_cache_entries
             or self._prefix_bytes > ec.prefix_cache_max_bytes
         ):
             _, old = self._prefix_cache.popitem(last=False)
             self._prefix_bytes -= old.get("nbytes", 0)
+            gone += 1
+        return gone
+
+    # A pool whose slots hold a state (``_Pool.stateful``: every cache with
+    # ``models/patterned.py STATE_LEAVES``) cannot be seeded at a bucket's
+    # boundary: the state exists only where a chunk ended. It stores what a
+    # finished prompt left instead, under the whole prompt and with its length
+    # ``P``: the state leaves of the scratch stripe the final chunk handed
+    # back (the stripe's own arrays: no copy) and the stripe's keys and values
+    # up to ``P``, rounded up to one of a few lengths. A later prompt that
+    # starts with the stored one is seeded from it and runs its tail from
+    # ``P``: a session's turn k + 1 behind its turn k. Stored after a hit as
+    # after a miss.
+
+    def _snapshot_lengths(self, pool: _Pool) -> list[int]:
+        """The lengths a snapshot's keys and values are held at in ``pool``:
+        multiples of the widest prompt chunk (and of a quarter of the longest
+        stripe where that is more: four forms a pool at most), the stripe's
+        own length the last. One store and one seed program each."""
+        ec = self.config.engine
+        step = max(max(ec.prefill_buckets), self._pools[-1].stripe_len // 4)
+        return [min(m, pool.stripe_len) for m in range(step, pool.stripe_len + step, step)]
+
+    def _snapshot_lookup(self, ids: list[int], stripe: int):
+        """The longest stored prompt that ``ids`` starts with and is strictly
+        longer than, at any length: one pass of the hash over the prompt, read
+        at every length an entry has (an entry counts where its keys and
+        values fit a stripe of ``stripe`` positions: another pool's may not)."""
+        import hashlib
+
+        lengths = sorted({e["length"] for e in self._prefix_cache.values()})
+        raw, sha, at, found = np.asarray(ids, np.int32).tobytes(), hashlib.sha1(), 0, None
+        for length in lengths:
+            if length >= len(ids):
+                break
+            sha.update(raw[4 * at:4 * length])
+            at = length
+            key = sha.copy().digest()
+            if key in self._prefix_cache and self._prefix_cache[key]["k"].shape[2] <= stripe:
+                found = key
+        if found is None:
+            self._prefix_misses += 1
+            return None, 0, None
+        entry = self._prefix_cache[found]
+        entry["uses"] += 1
+        self._prefix_cache.move_to_end(found)
+        self._prefix_hits += 1
+        return entry, entry["length"], found
+
+    def _snapshot_store(self, pool: _Pool, one: dict, req: _Request) -> None:
+        """``one``: the scratch stripe ``req``'s final chunk handed back.
+
+        Eviction is the budgets', from the front, with one change of order: a
+        hit touches the entry it used and the request then stores a longer
+        one, so plain recency would keep every session's dead turn fresh and
+        push live sessions out. The entry this request was seeded from, if no
+        other request has used it, goes to the front; one that several
+        prompts started from (a system prompt) keeps its place."""
+        if not self.config.engine.enable_prefix_caching:
+            return
+        ids = req.prompt_token_ids
+        key = self._prefix_key(ids, len(ids))
+        if key in self._prefix_cache:
+            self._prefix_cache.move_to_end(key)
+            return
+        m = next(m for m in self._snapshot_lengths(pool) if m >= len(ids))
+        k, v = self._store_snapshot_jit(one["k"], one["v"], m)
+        state = {name: one[name] for name in self._state_leaves}
+        nbytes = int(k.nbytes + v.nbytes + sum(x.nbytes for x in state.values()))
+        self._prefix_cache[key] = {"k": k, "v": v, "state": state, "nbytes": nbytes,
+                                   "length": len(ids), "uses": 0}
+        self._prefix_bytes += nbytes
+        seeded_from = self._prefix_cache.get(req.prefix_key)
+        if seeded_from is not None and seeded_from["uses"] == 1:
+            self._prefix_cache.move_to_end(req.prefix_key, last=False)
+        self._count({"snapshots_stored": 1, "snapshot_store_bytes": nbytes,
+                     "snapshots_evicted": self._prefix_evict()})
 
     # -- multi-LoRA ----------------------------------------------------------
 
@@ -1489,7 +1600,7 @@ class JaxEngine:
         # the adapter delta) — and their prefixes are never stored either
         if req.lora_idx == 0:
             with tracing.annotate("engine.prefix_lookup"):
-                prefix, m = self._prefix_lookup(ids)
+                prefix, m, req.prefix_key = self._prefix_lookup(ids, pool.stripe_len)
         else:
             prefix, m = None, 0
         suffix = ids[m:]
@@ -1514,7 +1625,11 @@ class JaxEngine:
             one = self._new_stripe_jit(pool.stripe_len)
         if prefix is not None:
             with self._device_call("launch", "seed_prefix", "engine.prefix_seed"):
-                one = self._seed_prefix_jit(one, prefix["k"], prefix["v"])
+                if "state" in prefix:  # a snapshot: the state leaves with the keys and values
+                    one = self._seed_prefix_jit(one, prefix["k"], prefix["v"], prefix["state"])
+                    self._count({"snapshots_hit": 1, "snapshot_seed_bytes": prefix["nbytes"]})
+                else:
+                    one = self._seed_prefix_jit(one, prefix["k"], prefix["v"])
                 self._count({"prefix_seed_tokens": m})
         pool.admitting[slot] = _Admission(req, slot, one, chunks, m)
 
@@ -1646,7 +1761,7 @@ class JaxEngine:
         top_k = min(max(1, req.params.top_k), self._top_k_static)
         pool.adapter_ids[slot] = req.lora_idx
         self._sync_adapter_ids(pool)
-        first_tok, stats = self._run_chunk_final(
+        first_tok, stats, one = self._run_chunk_final(
             pool, adm.one, toks, eff_len, start, slot,
             req.params.temperature, top_k, req.params.seed, req.lora_idx, carry=carry,
         )
@@ -1656,8 +1771,11 @@ class JaxEngine:
         pool.top_ks[slot] = top_k
         pool.sampler_dev = None
         del pool.admitting[slot]
-        if req.prefix_hit_tokens == 0 and req.lora_idx == 0:
-            # LoRA'd prefixes are adapter-specific: never shared
+        # LoRA'd prefixes are adapter-specific: never shared
+        if req.lora_idx == 0 and pool.stateful:  # after a hit as after a miss
+            with self._device_call("launch", "store_snapshot", "engine.prefix_store"):
+                self._snapshot_store(pool, one, req)
+        elif req.lora_idx == 0 and req.prefix_hit_tokens == 0:
             with tracing.annotate("engine.prefix_store"):
                 self._prefix_store(pool, slot, req.prompt_token_ids)
         try:
@@ -1675,7 +1793,8 @@ class JaxEngine:
         and next input tokens take the slot's new values (and, in a pool that
         ``carries``, with ``carry`` those of the rows that decode in this
         launch). Returns the first token and the routing counts (or None),
-        both still on the device."""
+        both still on the device, and the scratch stripe as the chunk left it
+        (what a snapshot of the prompt is cut from)."""
         import jax
         import jax.numpy as jnp
 
@@ -1692,16 +1811,16 @@ class JaxEngine:
             if pool.carries:
                 args += (self._decode_rows(pool, carry),)
         with tracing.annotate("engine.chunk_call"):
-            first_tok, new_key, pool.cache, _, stats, *rode = self._chunk_final_jit(
+            first_tok, new_key, pool.cache, one, stats, *rode = self._chunk_final_jit(
                 self.params, pool.cache, one, *args, **lora_kw)
         if rode:  # the program set the slot's key and next input token itself
             next_tokens, pool.keys = rode
             self._carried(pool, carry, next_tokens)
-            return first_tok, stats
+            return first_tok, stats, one
         with tracing.annotate("engine.slot_set"):  # the slot's key and next input token
             pool.keys = self._set_key_jit(pool.keys, slot_dev, new_key)
             pool.dev_tokens = self._set_tok_jit(pool.dev_tokens, slot_dev, first_tok)
-        return first_tok, stats
+        return first_tok, stats, one
 
     def _fail_admission(
         self, pool: "_Pool", adm: _Admission, e: BaseException,

@@ -184,6 +184,14 @@ class LlamaConfig:
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_chunk: int = 64
+    # --- the four scalars of IBM Granite (1: none; need ``layer_types``) ---
+    # on the looked-up embedding rows; on every branch (mixer, feed-forward)
+    # before it joins the stream; the attention scale in place of
+    # ``head_dim ** -0.5`` (0: that default); what divides the logits
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
         if self.attention not in ("full", "ring", "ulysses", "splash"):
@@ -201,6 +209,9 @@ class LlamaConfig:
                 raise ValueError(
                     f"{name} has {len(value)} entries for n_layers={self.n_layers}"
                 )
+        scalars = (self.embedding_multiplier, self.residual_multiplier, self.logits_scaling)
+        if not self.layer_types and (scalars != (1.0, 1.0, 1.0) or self.attention_multiplier):
+            raise ValueError("the Granite multipliers need layer_types (models/patterned.py)")
 
     @property
     def head_dim(self) -> int:
@@ -469,6 +480,47 @@ class LlamaConfig:
         )
         d.update(kw)
         return LlamaConfig(**_gqa_lists(d))
+
+    @staticmethod
+    def granite4_h_micro(**kw) -> "LlamaConfig":
+        """IBM Granite-4.0-H-Micro (``model_type: granitemoehybrid``, 3 B) as
+        its config.json has it: 40 layers, each a mixer under a dense SwiGLU
+        of 8192; layers 5, 15, 25, 35 GQA of 32 query and 8 key-value heads
+        of 64 without rotation, the other 36 Mamba-2 (64 heads of 64, one
+        group, state 128, convolution 4, chunk 256); the four multipliers; a
+        tied head; no routed experts. A caller that cuts ``n_layers`` gets
+        the published ``layer_types``' first entries unless it gives its own."""
+        d = dict(
+            vocab_size=100352, d_model=2048, n_layers=40, n_heads=32, n_kv_heads=8,
+            head_width=64, d_ff=8192, max_seq_len=131072, rms_eps=1e-5, attn_rope=False,
+            tie_embeddings=True, ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=1,
+            ssm_conv=4, ssm_chunk=256, embedding_multiplier=12.0, residual_multiplier=0.22,
+            attention_multiplier=0.015625, logits_scaling=8.0,
+        )
+        d.update(kw)
+        n = d["n_layers"]
+        d.setdefault("layer_types", tuple("full" if i % 10 == 5 else "ssm" for i in range(n)))
+        d.setdefault("heads_per_layer", tuple(
+            d["n_heads"] if t == "full" else 0 for t in d["layer_types"]))
+        d.setdefault("mlp_types", ("dense",) * n)
+        return LlamaConfig(**d)
+
+    @staticmethod
+    def granite_tiny(**kw) -> "LlamaConfig":
+        """Test-size model with ``granite4_h_micro``'s layers: two periods of
+        four (attention at 1 and 5), one group of heads whose 16 x 16 state
+        does not tile (the plain step), every scalar set and none of them 1
+        or its default's effect (the attention scale is not 16 ** -0.5)."""
+        d = dict(
+            vocab_size=256, d_model=64, n_layers=8, n_heads=4, n_kv_heads=2, head_width=16,
+            d_ff=128, max_seq_len=128, dtype=jnp.float32, remat=False, ssm_heads=8,
+            ssm_head_dim=16, ssm_state=16, ssm_chunk=8, embedding_multiplier=3.0,
+            residual_multiplier=0.5, attention_multiplier=0.125, logits_scaling=2.0,
+        )
+        d.update(kw)
+        d.setdefault("layer_types", tuple(
+            "full" if i % 4 == 1 else "ssm" for i in range(d["n_layers"])))
+        return LlamaConfig.granite4_h_micro(**d)
 
 
 # a block of a ``nemotron_h`` pattern: (mixer, feed-forward)

@@ -63,6 +63,7 @@ from ray_tpu.ops.decode_attention import (
     block_size,
     decode_attention,
     latent_decode_attention,
+    takes_heads_of,
 )
 from ray_tpu.parallel.mesh import with_sharding
 
@@ -418,6 +419,13 @@ def _rmsnorm(x, w, eps, fused: bool = False):
     return (x32 * scale).astype(x.dtype) * w
 
 
+def _times(x, m: float):
+    """``x * m`` in ``x``'s own type, and ``x`` itself where ``m`` is 1: a
+    model without the scalar keeps its graph. (The Granite multipliers:
+    ``LlamaConfig.embedding_multiplier`` on the looked-up rows,
+    ``residual_multiplier`` on a branch before it joins the stream.)"""
+    return x if m == 1.0 else x * jnp.asarray(m, x.dtype)
+
 
 @scope("embed")
 def _embed_lookup(table, tokens, cfg, mesh: Optional[Mesh]):
@@ -429,9 +437,11 @@ def _embed_lookup(table, tokens, cfg, mesh: Optional[Mesh]):
     pass likewise becomes a matmul instead of a scatter-add."""
     sharded = mesh is not None and any(s > 1 for s in mesh.shape.values())
     if not sharded:
-        return table[tokens].astype(cfg.dtype)
+        return _times(table[tokens].astype(cfg.dtype), cfg.embedding_multiplier)
     onehot = jax.nn.one_hot(tokens, table.shape[0], dtype=cfg.dtype)
-    return jnp.einsum("btv,ve->bte", onehot, table.astype(cfg.dtype))
+    return _times(
+        jnp.einsum("btv,ve->bte", onehot, table.astype(cfg.dtype)), cfg.embedding_multiplier
+    )
 
 
 
@@ -450,7 +460,7 @@ def _project_logits(x, params, cfg, mesh: Optional[Mesh]):
     )
     if mesh is not None:
         logits = with_sharding(mesh, logits, "batch", "seq", "vocab")
-    return logits
+    return logits if cfg.logits_scaling == 1.0 else logits / cfg.logits_scaling
 
 
 
@@ -773,6 +783,15 @@ class _Layer:
         return scope(_SCOPE_OF_KIND[self.kind]) if self.by_kind else contextlib.nullcontext()
 
 
+def _score_rescale(cfg) -> float:
+    """What the queries are multiplied by so that every form of the read
+    (``_grouped_attention``, the block walk, the decode kernel), each of which
+    scales its scores by ``head_dim ** -0.5``, scores by
+    ``cfg.attention_multiplier`` instead: their ratio, 1 where the field is 0.
+    Granite's 1/64 over 64 ** -0.5 is 1/8, exact in any float type."""
+    return cfg.attention_multiplier * cfg.head_dim ** 0.5 if cfg.attention_multiplier else 1.0
+
+
 def _qkv(params, lay: _Layer, h, positions, cfg, loras=None, adapter_ids=None):
     """Rotated queries and keys, and values, of h [B, T, e]. ``loras`` (a
     uniform model's, ``init_lora_stack``): each row's adapter
@@ -797,7 +816,7 @@ def _qkv(params, lay: _Layer, h, positions, cfg, loras=None, adapter_ids=None):
         if cfg.attn_rope:
             q = _rope(q, positions, inv_freq, factor)
             k = _rope(k, positions, inv_freq, factor)
-    return q, k, v
+    return _times(q, _score_rescale(cfg)), k, v
 
 
 def _latent_qkv(params, lay: _Layer, h, positions, cfg):
@@ -865,7 +884,8 @@ def _attn_out(params, lay: _Layer, x, h, attn, cfg, from_latent: bool = False):
                 ))
                 gate = gate.reshape(attn.shape) if cfg.attn_gate == "channel" else gate[..., None]
                 attn = (attn * gate).astype(attn.dtype)
-        return x + jnp.einsum("bthd,hde->bte", attn, params[lay.wo][lay.attn_i])
+        out = jnp.einsum("bthd,hde->bte", attn, params[lay.wo][lay.attn_i])
+        return x + _times(out, cfg.residual_multiplier)
 
 
 def _ssm_in(params, lay: _Layer, h, valid, cfg):
@@ -1081,10 +1101,11 @@ def _feed_forward(params, lay: _Layer, x, cfg):
     if lay.sparse:
         with scope("moe_ffn"):
             y, stats = _moe_decode_ffn(params, lay.mlp_i, h, cfg)
-            return x + y, stats
+            return x + _times(y, cfg.residual_multiplier), stats
     with scope("ffn"):
         # a layer's slice of a stacked weight is taken where it is used
-        x = x + _dense_ffn(h, lambda name: params[name][lay.mlp_i])
+        y = _dense_ffn(h, lambda name: params[name][lay.mlp_i])
+        x = x + _times(y, cfg.residual_multiplier)
     return x, (jnp.zeros((len(MOE_STATS),), jnp.int32) if cfg.moe_experts else None)
 
 
@@ -1207,7 +1228,9 @@ def reads_blocks(stripe: int, *arrays, latent: bool = False) -> bool:
     with the tracers of the same arrays, and both get the same answer.
 
     The kernel wants a stripe of whole blocks (``latent``: of a latent
-    model's, which are longer) and everything on one device.
+    model's, which are longer), heads as wide as its copies take (the first
+    of ``arrays`` is the cache's keys ``[.., D]``; a latent cache's rows are
+    whole lane tiles by ``init_kv_cache``) and everything on one device.
     An argument committed to a ``NamedSharding`` carries its mesh in its
     type, inside a trace too (``llm/spmd.py`` and ``llm/gang.py`` jit over a
     mesh with the key-value heads sharded over ``tp``; an engine under
@@ -1216,9 +1239,11 @@ def reads_blocks(stripe: int, *arrays, latent: bool = False) -> bool:
     uncommitted argument that only a ``jit``'s ``in_shardings`` spreads over
     a mesh reads as one device (no caller in this repo places its arrays
     so; ``tests/test_patterned.py`` traces the ways they do)."""
-    return block_size(stripe, latent) is not None and all(
-        jax.typeof(x).sharding.mesh.size <= 1 for x in arrays
-    )
+    if block_size(stripe, latent) is None:
+        return False
+    if not latent and not takes_heads_of(arrays[0]):
+        return False
+    return all(jax.typeof(x).sharding.mesh.size <= 1 for x in arrays)
 
 
 def _chunk_expands(cfg, T: int) -> bool:
@@ -1538,6 +1563,7 @@ def decode_forward(
         x = params["embed"][
             _join([tokens] if beside is None else [tokens, beside[1][:, None]])
         ].astype(cfg.dtype)
+        x = _times(x, cfg.embedding_multiplier)
     sets = [_Rows(cfg, params, kinds, cache, tokens, positions, valid, start_pos)]
     if beside is not None:
         cache2, tokens2, live = beside
@@ -1586,7 +1612,7 @@ def decode_forward(
             ys = [y for y, *_ in mixed]
             y = ys[0] if len(sets) == 1 else _join(
                 [y.reshape(rows.B, rows.T, -1) for rows, y in zip(sets, ys)])
-            x = x + out(params, lay, y, gate, cfg)
+            x = x + _times(out(params, lay, y, gate, cfg), cfg.residual_multiplier)
         elif lay.kind != "none":
             h = _rmsnorm(x, params["attn_norm"][lay.mixer_i], cfg.rms_eps, cfg.fused_rmsnorm)
             if lay.latent:  # k: the shared rotated key; v: the normed latent

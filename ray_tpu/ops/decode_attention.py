@@ -70,6 +70,17 @@ def block_size(stripe: int, latent: bool = False) -> Optional[int]:
     return None
 
 
+def takes_heads_of(cache_k) -> bool:
+    """Whether the kernel's copies take a cache whose rows are as wide as
+    ``cache_k``'s [.., D]: whole 128-lane tiles on the chip (a 64-wide head is
+    padded to a lane tile there and a copy takes no part of one; the v5e
+    compiler: "slice shape along dimension 4 must be aligned to tiling (128),
+    but is 64": IBM Granite-4.0-H's heads), any width interpreted. Where not,
+    the caller keeps the einsum over the stripe (``models/patterned.py
+    reads_blocks`` asks)."""
+    return interpret() or cache_k.shape[-1] % 128 == 0
+
+
 def _whole_blocks(stripe: int, latent: bool = False) -> int:
     bs = block_size(stripe, latent)
     if bs is None:

@@ -963,3 +963,68 @@ def test_a_chunk_launch_that_carries_the_decode_step_compiles_for_the_chip(
     copies = [line.strip()[:160] for _, result, op, line in _ops_outside_fusions(text)
               if op == "copy" and whole.search(result)]
     assert copies == []
+
+
+# ------------------------------------------------- Granite-4.0-H-Micro, whole
+
+
+def _granite_whole():
+    from ray_tpu.models.llama import LlamaConfig
+
+    return LlamaConfig.granite4_h_micro(max_seq_len=4096)
+
+
+def test_granite_decode_step_compiles_whole_and_moves_its_state_where_it_lies(
+        one_chip, no_compile_cache, native_kernels):
+    """The Granite cell's decode step, the model whole (24 slots of 4,096; 36
+    Mamba-2 and 4 GQA layers at published widths, four periods of ten under
+    one loop) compiles for the chip beside 6.4 GB of weights: the 1.8 GB of
+    float32 state is updated in the donated cache by one ``ssm_step`` kernel a
+    mamba layer of a period (nine: a tile is the one group's 64 heads, 2 MB),
+    and the attention layers, whose heads are 64 wide, half a lane tile, keep
+    the einsum over their stripes (the decode kernel's copies take no part of
+    a lane tile: the chip's compiler refused it, "slice shape along dimension
+    4 must be aligned to tiling (128), but is 64")."""
+    fn, args = _served_programs(_granite_whole(), 24, 4096, one_chip)["decode_step"]
+    step = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text = step.as_text()
+    state = "f32[36,24,64,64,128]"
+    assert [line.strip()[:120] for line in text.splitlines()
+            if " copy(" in line and line.split(" = ", 1)[-1].startswith(state)] == []
+    assert step.memory_analysis().temp_size_in_bytes < 256e6
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("attn_core/ssm_mixer/ssm_step" in line for line in kernels) == 9
+    assert sum("attn_core" in line and "ssm_mixer" not in line for line in kernels) == 0
+
+
+@pytest.mark.parametrize("rows,width", [(4, 1024), (1, 64)], ids=["widest", "narrowest"])
+def test_granite_chunk_programs_fit_at_their_widest(one_chip, no_compile_cache, native_kernels,
+                                                    rows, width):
+    """The engine's own chunk programs at the cell's shapes: a middle chunk
+    of four rows of 1,024 tokens (the scan over four 256-token chunks a layer,
+    attention over 4,096-position stripes in key blocks) and a final chunk of
+    64 tokens into a 24-slot pool, each well inside what the weights, the pool
+    and a 4.9 GB store of snapshots leave of the chip's 16 GB."""
+    from ray_tpu.llm.engine import programs
+    from ray_tpu.models.llama import init_kv_cache
+
+    cfg = _granite_whole()
+    params, cache, _ = _served_programs(cfg, 24, 4096, one_chip)["decode_step"][1]
+
+    def sds(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    one = {k: sds(x.dtype, *x.shape)
+           for k, x in jax.eval_shape(lambda: init_kv_cache(cfg, 1, 4096)).items()}
+    fns = programs(cfg)
+    i32 = lambda *shape: sds(jnp.int32, *shape)  # noqa: E731
+    if rows > 1:
+        compiled = jax.jit(fns["chunk_mid"], donate_argnums=(1,)).lower(
+            params, tuple(dict(one) for _ in range(rows)), i32(rows, width), i32(rows), i32(rows)
+        ).compile()
+    else:
+        compiled = jax.jit(fns["chunk_final"], donate_argnums=(1, 2)).lower(
+            params, cache, one, i32(1, width), i32(1), i32(1), i32(), sds(jnp.float32), i32(),
+            sds(jnp.uint32, 2)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.2e9
+    assert "ssm_mixer/ssm_scan" in compiled.as_text()

@@ -479,9 +479,11 @@ def test_engine_answers_as_the_reference_and_a_reused_slot_as_a_fresh_one(engine
     through the same slot, then the first again: the slot's second and third
     tenants see nothing of the state the one before left, each answer is the
     reference's greedy one, and the request sent twice answers alike. The
-    prefix cache is on and, for a model that keeps a state a slot, neither
-    stores nor seeds: counted."""
-    before = engine.get_stats()["counters"]["prefix_bypassed_stateful"]
+    prefix cache is on and a pool that keeps a state a slot stores a snapshot
+    of each prompt: the same prompt again is no hit (a token must remain), a
+    prompt that goes on from the first is seeded from it at its exact length
+    and answers as the reference does."""
+    before = engine.get_stats()["counters"]
     a, b = _prompt(0, 29), _prompt(1, 21)
     first = engine.generate(prompt_token_ids=a, sampling_params=SP)
     other = engine.generate(prompt_token_ids=b, sampling_params=SP)
@@ -489,10 +491,16 @@ def test_engine_answers_as_the_reference_and_a_reused_slot_as_a_fresh_one(engine
     assert first.token_ids == again.token_ids
     assert first.token_ids == _greedy_by_the_reference(engine, a, first.token_ids)
     assert other.token_ids == _greedy_by_the_reference(engine, b, other.token_ids)
-    stats = engine.get_stats()
     assert again.metrics["prefix_hit_tokens"] == 0
-    assert stats["counters"]["prefix_bypassed_stateful"] - before == 3
-    assert stats["prefix_cache_entries"] == 0 and stats["prefix_cache_bytes"] == 0
+    longer = a + _prompt(2, 12)
+    onward = engine.generate(prompt_token_ids=longer, sampling_params=SP)
+    assert onward.metrics["prefix_hit_tokens"] == 29
+    assert onward.token_ids == _greedy_by_the_reference(engine, longer, onward.token_ids)
+    stats = engine.get_stats()
+    c = stats["counters"]
+    assert c["snapshots_stored"] - before["snapshots_stored"] == 3
+    assert c["snapshots_hit"] - before["snapshots_hit"] == 1
+    assert stats["prefix_cache_entries"] == 3 and stats["prefix_cache_bytes"] > 0
 
 
 def test_requests_admitted_together_answer_as_each_alone(engine):
@@ -547,7 +555,7 @@ def test_a_model_whose_slots_are_stripes_alone_counts_no_state():
     assert first.token_ids == again.token_ids and again.metrics["prefix_hit_tokens"] == 32
     assert stats["pools"][0]["state_bytes_per_slot"] == 0
     assert stats["pools"][0]["state_mixer_forms"] == {}
-    assert stats["counters"]["prefix_bypassed_stateful"] == 0
+    assert stats["counters"]["snapshots_stored"] == stats["counters"]["snapshots_hit"] == 0
     assert set(stats["counters"]["moe_assignments_held"].values()) == {0}
     assert sum(stats["counters"]["moe_assignments"].values()) > 0
 
