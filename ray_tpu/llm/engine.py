@@ -52,10 +52,13 @@ logger = logging.getLogger(__name__)
 # ``decode``.
 # The routed experts' counts by program (``models/patterned.py
 # moe_stats_names``): a program of a model that holds a share of its experts
-# hands out the fifth, the assignments that fell on the experts held here of
-# those the router made; it stays 0 for every other model.
+# hands out the fifth and the sixth, the assignments that fell on the experts
+# held here of those the router made, and the blocks of sorted rows its expert
+# layers worked through (one a layer run, so ``moe_passes`` is
+# ``moe_layer_steps`` unless a run's held assignments overflowed its block and
+# took another); both stay 0 for every other model.
 _MOE_COUNTERS = ("moe_layer_steps", "moe_assignments", "moe_experts_touched",
-                 "moe_max_expert_load_sum", "moe_assignments_held")
+                 "moe_max_expert_load_sum", "moe_assignments_held", "moe_passes")
 _MOE_PROGRAMS = ("decode", "chunk_mid", "chunk_final")
 COUNTERS = (
     "requests_submitted",
@@ -114,7 +117,7 @@ COUNTERS = (
     # seeded from and evicted, the bytes stored and the bytes seeded
     "snapshots_stored", "snapshots_hit", "snapshots_evicted",
     "snapshot_store_bytes", "snapshot_seed_bytes",
-    # routed experts (``models/llama.py MOE_STATS``), summed over expert
+    # routed experts (``models/patterned.py moe_stats_names``), summed over expert
     # layers and over the runs of each program: the decode program hands its
     # counts out beside its tokens, a prompt's middle chunks add theirs up on
     # the device and its final chunk hands both out beside the first token,

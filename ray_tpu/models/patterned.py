@@ -485,17 +485,33 @@ MOE_STATS = ("layer_steps", "assignments", "experts_touched", "max_expert_load")
 
 def moe_stats_names(cfg) -> tuple:
     """``MOE_STATS``, and for a model that holds a share of its experts
-    (``moe_experts_held``) the assignments that fell on the held ones:
-    ``assignments`` counts all the router made, ``experts_touched`` and
-    ``max_expert_load`` the held experts'."""
-    return MOE_STATS + (("assignments_held",) if cfg.moe_experts_held else ())
+    (``moe_experts_held``) two more: the assignments that fell on the held
+    ones (``assignments`` counts all the router made, ``experts_touched`` and
+    ``max_expert_load`` the held experts') and the blocks of sorted rows the
+    layer worked through (``passes``: one a layer run unless more than a
+    block's rows fell on the held experts, ``held_block``)."""
+    return MOE_STATS + (("assignments_held", "passes") if cfg.moe_experts_held else ())
+
+
+def held_block(assignments: int, held: int, experts: int) -> int:
+    """Rows of the block an expert layer that holds ``held`` of the router's
+    ``experts`` works on, of the ``assignments`` (tokens x k) a call's router
+    makes: twice the rows expected to fall on the held experts, in whole row
+    tiles of the grouped matmul, at least one tile and at most all. From the
+    call's own shapes and the config's held share and nothing else; a layer
+    run on which more fell takes a second block (``_moe_decode_ffn``)."""
+    from ray_tpu.ops.grouped_matmul import TILING
+
+    tile = TILING[0]
+    twice = -(-2 * assignments * held // experts)
+    return min(assignments, max(tile, -(-twice // tile) * tile))
 
 
 def _moe_decode_ffn(params, row, h, cfg):
     """Dropless routed expert FFN for the serving path, and for ``forward``
     of a model whose layers are not alike. ``params`` holds the stacked
     ``moe_*`` leaves, ``row`` (static or traced) is this layer's row in them.
-    h: [B, T, e] -> ([B, T, e], routing counts int32 [4], ``MOE_STATS``; [5]
+    h: [B, T, e] -> ([B, T, e], routing counts int32 [4], ``MOE_STATS``; [6]
     for a share of the experts, ``moe_stats_names``).
 
     Inference must never drop tokens (a capacity overflow at prefill would
@@ -526,7 +542,23 @@ def _moe_decode_ffn(params, row, h, cfg):
     matrices with a squared ReLU (``moe_activation``), the routed experts in a
     latent between a down- and an up-projection (``moe_latent_dim``; scope
     ``moe_latent_proj``), and a share of the experts held here
-    (``moe_experts_held``; a fifth count, ``moe_stats_names``)."""
+    (``moe_experts_held``; two more counts, ``moe_stats_names``).
+
+    A layer that holds a share works on the assignments that fell on its
+    experts only. The sort puts those first, expert by expert, and the absent
+    ones behind them, so everything after the sort (the gather of each
+    assignment's token, the three grouped matmuls, the weighting and the sum
+    into the tokens' rows) runs on a block of the first ``held_block`` sorted
+    rows, a quarter of all at an eighth held, and on the next block only
+    while assignments on held experts are left: a loop of ``ceil(n_held /
+    block)`` turns, one on nearly every run, all ``G*k / block`` of them where
+    every assignment fell here, so nothing is dropped whatever the routing.
+    (The grouped matmul itself never spent a tile on the rows no group owns;
+    what the block saves is the gather, the select and the combine over all
+    ``G*k`` rows of ``width`` in float32, which were 5 of the 12.5 ms of a
+    1,024-token chunk's expert layers at 40 of 320 held: PERF.md section 6,
+    PR 45.) A model that holds all its experts keeps the one pass over all
+    rows, operation for operation."""
     from ray_tpu.ops.grouped_matmul import grouped_matmul
     from ray_tpu.parallel.moe import topk_gates
 
@@ -553,10 +585,12 @@ def _moe_decode_ffn(params, row, h, cfg):
             load = load[first:first + held]
             chosen = jnp.where((chosen >= first) & (chosen < first + held), chosen - first, held)
         n_held = load.sum()  # assignments that fell on the experts held here
-        stats = jnp.stack([
-            jnp.int32(1), jnp.int32(G * k), (load > 0).sum(dtype=jnp.int32), load.max(),
-            *((n_held,) if cfg.moe_experts_held else ()),
-        ])
+        stats = [jnp.int32(1), jnp.int32(G * k), (load > 0).sum(dtype=jnp.int32), load.max()]
+        if cfg.moe_experts_held:
+            block = held_block(G * k, held, E)
+            passes = -(-n_held // block)  # blocks that hold any of them
+            stats += [n_held, jnp.maximum(passes, 1)]
+        stats = jnp.stack(stats)
     if cfg.moe_latent_dim:
         with scope("moe_latent_proj"):
             src = g @ params["moe_latent_down"][row]
@@ -564,30 +598,56 @@ def _moe_decode_ffn(params, row, h, cfg):
         src = g
     with scope("experts"):
         order = jnp.argsort(chosen)  # assignments by expert
-        rows = src[order // k]  # [G*k, width]: each assignment's token
         n = params["moe_w_up"].shape[0]
-        sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((n * held,), jnp.int32), load, (row * held,)
-        )
 
         def bank(name):
             w = params[name]
             return w.reshape((n * held,) + w.shape[2:])
 
-        if cfg.moe_activation == "relu2":
-            act = jnp.square(jax.nn.relu(grouped_matmul(rows, bank("moe_w_up"), sizes)))
-        else:
-            gate = grouped_matmul(rows, bank("moe_w_gate"), sizes)
-            up = grouped_matmul(rows, bank("moe_w_up"), sizes)
-            act = jax.nn.silu(gate) * up
-        out = grouped_matmul(act, bank("moe_w_down"), sizes, jnp.float32)
+        def experts(rows, load):
+            """Each row through its expert: ``rows`` sorted by expert,
+            ``load`` of them each of this layer's experts. float32."""
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((n * held,), jnp.int32), load, (row * held,)
+            )
+            if cfg.moe_activation == "relu2":
+                act = jnp.square(jax.nn.relu(grouped_matmul(rows, bank("moe_w_up"), sizes)))
+            else:
+                gate = grouped_matmul(rows, bank("moe_w_gate"), sizes)
+                up = grouped_matmul(rows, bank("moe_w_up"), sizes)
+                act = jax.nn.silu(gate) * up
+            return grouped_matmul(act, bank("moe_w_down"), sizes, jnp.float32)
+
         if cfg.moe_experts_held:
-            # the kernel leaves the rows no group owns unwritten
-            out = jnp.where((jnp.arange(G * k) < n_held)[:, None], out, 0.0)
-        # back to token order: a gather, not a scatter-add
-        out = out[jnp.argsort(order)].reshape(G, k, src.shape[-1])
-        y = jnp.einsum("gkd,gk->gd", out, gate_vals) * cfg.moe_routed_scale
-        y = y.astype(g.dtype)
+            ends = jnp.cumsum(load)  # where each expert's sorted rows end
+            # whole blocks, so that the last window reads no row twice
+            blocks = jnp.pad(order, (0, -(G * k) % block))
+            weights = gate_vals.reshape(-1)
+
+            def one_block(p, y):
+                at = p * block
+                picked = jax.lax.dynamic_slice(blocks, (at,), (block,))
+                token = picked // k
+                out = experts(src[token], jnp.diff(jnp.clip(ends, at, at + block), prepend=at))
+                # the kernel leaves the rows no group owns unwritten
+                owned = at + jnp.arange(block) < n_held
+                out = jnp.where(owned[:, None], out * weights[picked][:, None], 0.0)
+                # each row onto its token's: a scatter-add of ``block`` rows (of
+                # the forms timed on a v5e at 1,024 tokens, a block of 2,048
+                # rows of 4,096: 0.42 ms, a one-hot product at the highest
+                # precision 0.29 but 4.3 against 1.6 at four such rows, a
+                # gather of [G, k] positions 0.53; PERF.md section 6, PR 45)
+                return y.at[token].add(out)
+
+            y = jax.lax.fori_loop(
+                0, passes, one_block, jnp.zeros((G, src.shape[-1]), jnp.float32)
+            )
+        else:
+            out = experts(src[order // k], load)  # [G*k, width]: each assignment's token
+            # back to token order: a gather, not a scatter-add
+            out = out[jnp.argsort(order)].reshape(G, k, src.shape[-1])
+            y = jnp.einsum("gkd,gk->gd", out, gate_vals)
+        y = (y * cfg.moe_routed_scale).astype(g.dtype)
     if cfg.moe_latent_dim:
         with scope("moe_latent_proj"):
             y = y @ params["moe_latent_up"][row]

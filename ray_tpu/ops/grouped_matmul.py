@@ -4,7 +4,10 @@ expert).
 
 The kernel is JAX's Pallas TPU ``megablox`` grouped matmul, which visits only
 the (row tile, group) pairs that exist, so a group's matrix is read once
-(twice where its rows straddle two tiles) and empty groups cost nothing.
+(twice where its rows straddle two tiles) and empty groups cost nothing. Rows
+that no group owns (the sizes sum to fewer than the rows: the assignments a
+device's share of the experts does not hold, sorted behind the held ones)
+cost no grid step either and are left unwritten.
 ``jax.lax.ragged_dot`` computes the same, and the v5e compiler has a kernel
 for it, but rewrites it under the name ``ragged-dot-none``: its device time
 then carries no ``jax.named_scope`` (a traced serving run booked 15 of a

@@ -123,6 +123,8 @@ def test_a_carrying_middle_chunk_is_the_chunk_then_the_decode_step(pool, n_strip
         alone = dict(zip(names, np.asarray(want_ones[0]["moe_stats"] - ones[0]["moe_stats"])))
         step = dict(zip(names, np.asarray(want_stats)))
         assert grew["layer_steps"] == alone["layer_steps"] == step["layer_steps"]
+        if cfg.moe_experts_held:  # a block of sorted rows a layer run, carried rows or not
+            assert grew["passes"] == grew["layer_steps"] and alone["passes"] == alone["layer_steps"]
         # every routed row counts, a row that is not live and a padded token
         # too; the last layer's feed-forward, where it is traced on its own,
         # is the decode rows' alone (nothing reads the chunk's rows behind it)

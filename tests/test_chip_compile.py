@@ -12,6 +12,7 @@ worker and skip. The topology is described inside a fixture, never at
 import, in a ``skipif`` or in ``parametrize``.
 """
 
+import re
 import sys
 
 import jax
@@ -755,6 +756,35 @@ def test_delta_rule_middle_chunk_fits_at_its_widest(
     assert [line.strip()[:120] for line in lines
             if "kda_mixer/kda_scan" in line and (" fusion(" in line or " copy(" in line)
             and line.split(" = ", 1)[-1].startswith(whole)] == []
+
+
+def test_delta_rule_chunk_works_on_a_block_of_the_held_assignments(
+        one_chip, no_compile_cache, native_kernels):
+    """A row of 1,024 tokens makes 8,192 assignments, of which an eighth falls
+    on the 40 experts held: the chunk's expert scope works on a block of 2,048
+    sorted rows under one loop, so nothing there is 8,192 rows of the model's
+    width in float32 (the parent's kernel output, its select, its gather back
+    to token order and the operand of the sum over a token's choices were:
+    134 MB each, 5 of a chunk's 12.5 ms), nor 8,192 rows of it in bfloat16
+    (the parent's gather of each assignment's token); the grouped matmuls
+    are still three a layer under the scope."""
+    from ray_tpu.models.llama import prefill
+    from ray_tpu.models.patterned import held_block
+
+    cfg = _delta_rule_cut()
+    assert held_block(1024 * 8, 40, 320) == 2048 and held_block(64 * 8, 40, 320) == 128
+    params, stripe, _, _, _ = _served_programs(cfg, 64, 8192, one_chip)["chunk_mid"][1]
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    lines = jax.jit(
+        lambda p, o, t, n, s: prefill(p, o, t, cfg, lengths=n, start_pos=s, with_logits=False)[1],
+        donate_argnums=(1,),
+    ).lower(params, stripe, i32(1, 1024), i32(1), i32(1)).compile().as_text().splitlines()
+    experts = [line for line in lines if "moe_ffn/experts" in line]
+    assert [line.strip()[:160] for line in experts
+            if re.search(r"(f32|bf16)\[8192,4096\]|f32\[1024,8,4096\]", line)] == []
+    assert any("f32[2048,4096]" in line for line in experts)
+    kernels = [line for line in experts if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) in (3, 9)  # one loop body over the layers, or a body each
 
 
 # ---- middle chunks of several rows (``llm/engine.py programs``) -------------

@@ -235,15 +235,22 @@ def test_the_engine_counts_the_state_a_slot_holds(engine):
 # ------------------------------------- the other families, as the parent had them
 
 # the decode step and a 16-token prompt chunk of each other family's tiny
-# preset as the parent commit (PR 43) lowered them: operations in all, a
-# digest of their histogram by name, and their matrix products (taken from a
-# checkout of the parent with this file's ``_digest``)
+# preset: operations in all, a digest of their histogram by name, and their
+# matrix products (taken with this file's ``_digest``). ``tiny``,
+# ``laguna_tiny`` and ``kanana_tiny`` hold the lowering of PR 43's commit (the
+# parent of PR 44), which PR 45 left as it was: a model that holds all its
+# experts, or has none, keeps its programs operation for operation.
+# ``nemotron_tiny`` and ``solar_tiny`` hold PR 45's: an expert layer that
+# holds a share works on a block of its assignments under one loop
+# (``models/patterned.py _moe_decode_ffn``; before it they lowered to
+# (4404, "08738863b0fd", 53), (3197, "b11c0716118a", 71) and
+# (4749, "e641a1fa2b18", 46), (4403, "bb8f9e0e53f5", 68))
 _PARENT = {
     "tiny": ((2189, "72306fc03fc3", 12), (595, "40278391e201", 10)),
     "laguna_tiny": ((11403, "582b0fc462ba", 73), (2948, "1f39bc3a537e", 63)),
     "kanana_tiny": ((5562, "8942df0a7722", 29), (2145, "b0945fb3ed34", 29)),
-    "nemotron_tiny": ((4404, "08738863b0fd", 53), (3197, "b11c0716118a", 71)),
-    "solar_tiny": ((4749, "e641a1fa2b18", 46), (4403, "bb8f9e0e53f5", 68)),
+    "nemotron_tiny": ((4667, "8438b9700414", 48), (3433, "bd252682d190", 66)),
+    "solar_tiny": ((4974, "91bcd69ad991", 42), (4630, "8142dad38778", 64)),
 }
 
 

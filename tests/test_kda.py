@@ -34,6 +34,7 @@ from ray_tpu.models.llama import (
 from ray_tpu.models.patterned import STATE_LEAVES, _param_shapes, state_cache_shapes
 from ray_tpu.ops import kda
 from ray_tpu.ops.kda import kda_scan, kda_step, kda_step_in_place
+from tests import held_experts
 
 # the cut the cell serves, at test size: three delta-rule layers and the
 # attention layer behind them, 4 of the router's 16 experts held
@@ -394,7 +395,7 @@ def test_a_prompt_in_chunks_of_a_multi_row_launch_equals_the_prompt_whole(model,
             jnp.asarray([done[b]], jnp.int32), jnp.int32(2 - b), jnp.float32(0.0), jnp.int32(1),
             jax.random.PRNGKey(0))
         first.append(int(tok))
-        assert stats.shape == (2, 5)  # chunk_mid's and chunk_final's counts, the held ones too
+        assert stats.shape == (2, 6)  # chunk_mid's and chunk_final's counts, the held ones and the blocks too
     for b, n in enumerate(lens):
         slot = 2 - b
         logits, whole = whole_prompt(params, init_kv_cache(CFG, 1, 64), jnp.asarray(tokens[b:b + 1, :n]))
@@ -504,17 +505,20 @@ def test_the_accepted_cells_chunks_score_their_stripes_whole():
 # ------------------------------------------------- a device's share of a layer
 
 
-def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("tokens", [12, 200], ids=["a-block-is-all", "a-block-is-a-third"])
+def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer(tokens):
     """16 experts over 8 devices, 2 each. Each share routes over all 16 and
     computes its own experts' part; what the eight add to a token, with what
     every device computes alike counted once (the shared expert), is what the
     plain reference gives for the layer with all 16 experts. Every assignment
-    falls on exactly one share."""
+    falls on exactly one share. At 12 tokens a share's block of sorted rows is
+    all 48 assignments, at 200 it is 256 of the 800."""
     from benchmark.reference_kda_moe import Reference
 
+    assert patterned.held_block(tokens * CFG.moe_top_k, 2, 16) == {12: 48, 200: 256}[tokens]
     params = init_params(jax.random.PRNGKey(5), dataclasses.replace(CFG, moe_experts_held=0))
     assert params["moe_w_up"].shape[:2] == (4, 16)
-    x = jax.random.normal(jax.random.PRNGKey(2), (1, 12, CFG.d_model))
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, tokens, CFG.d_model))
     h = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + CFG.rms_eps)  # mlp_norm is ones
     row = 2
     shared = patterned._shared_expert(
@@ -528,11 +532,24 @@ def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
         total = total + (y[0] - shared)
         counts = dict(zip(patterned.moe_stats_names(cfg), np.asarray(stats)))
         held, made = held + counts["assignments_held"], counts["assignments"]
-        assert counts["experts_touched"] <= 2
-    assert made == 12 * CFG.moe_top_k == held
+        assert counts["experts_touched"] <= 2 and counts["passes"] == 1
+    assert made == tokens * CFG.moe_top_k == held
     whole = Reference(dict(PUBLISHED, n_routed_experts=16), jax.local_devices()[:1])
     (after,), _ = whole._experts(params, row, [x])
     np.testing.assert_allclose(total, (after - x)[0], atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("fell", sorted(held_experts.HELD))
+def test_a_share_works_through_what_fell_on_it_a_block_at_a_time(fell, monkeypatch):
+    """4 of 32 experts held, 128 tokens of 4 choices: a block is 128 of the
+    512 sorted rows. Whatever the router does (every assignment on the held
+    experts: four blocks; none: the shared expert alone, counted as one
+    block; a block's rows exactly, and one more: a second block for one row)
+    the layer is what the form that works on all 512 rows gives, token for
+    token within float32 rounding, nothing dropped, and the counts are what
+    that form made of the same choices."""
+    cfg = dataclasses.replace(CFG, moe_experts=32, moe_experts_first=8)
+    held_experts.check_a_block_at_a_time(cfg, 128, 128, fell, monkeypatch, atol=2e-6)
 
 
 def test_the_eight_slices_of_the_vocabulary_add_up_to_the_whole_head():
@@ -575,7 +592,9 @@ _PARENT_DECODE = {
     "tiny": (2189, "72306fc03fc3", 12),
     "laguna_tiny": (11403, "582b0fc462ba", 73),
     "kanana_tiny": (5562, "8942df0a7722", 29),
-    "nemotron_tiny": (4404, "08738863b0fd", 53),
+    # PR 45's lowering (a block of the held assignments under one loop); the
+    # parent's was (4404, "08738863b0fd", 53)
+    "nemotron_tiny": (4667, "8438b9700414", 48),
 }
 
 

@@ -118,6 +118,8 @@ def test_engine_counts_the_state_a_slot_holds_and_the_assignments_held(engine):
     for program in ("decode", "chunk_mid", "chunk_final"):
         made, held = c["moe_assignments"][program], c["moe_assignments_held"][program]
         assert 0 < held < made and made % 4 == 0 and made >= 4 * c["moe_layer_steps"][program]
+        # a block of sorted rows a layer run: the tiny sizes overflow none
+        assert c["moe_passes"][program] == c["moe_layer_steps"][program] > 0
     # 4 of 16 experts held: about a quarter of what the router assigns
     assert 0.1 < sum(c["moe_assignments_held"].values()) / sum(c["moe_assignments"].values()) < 0.4
 

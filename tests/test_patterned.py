@@ -524,7 +524,8 @@ def test_a_dense_engine_counts_no_routing_and_no_window():
         eng.generate("hello there", sampling_params=SamplingParams(max_tokens=4, ignore_eos=True))
         routing, c = _routing(eng)
         assert all(v == {"decode": 0, "chunk_mid": 0, "chunk_final": 0} for v in routing.values())
-        assert len(routing) == 5  # the four routing counts, and the assignments held (PR 35)
+        # the four routing counts, the assignments held (PR 35) and the blocks (PR 45)
+        assert len(routing) == 6
         assert c["decode_kv_tokens_window"] == 0 < c["decode_kv_tokens_global"]
     finally:
         eng.shutdown()

@@ -29,6 +29,7 @@ from ray_tpu.models.llama import (
 from ray_tpu.models.patterned import _param_shapes, state_cache_shapes
 from ray_tpu.ops import ssm
 from ray_tpu.ops.ssm import causal_conv, ssm_scan, ssm_step, ssm_step_in_place
+from tests import held_experts
 
 CFG = LlamaConfig.nemotron_tiny()
 # the leaves a slot of this model holds whatever its length
@@ -348,7 +349,7 @@ def test_a_prompt_in_chunks_of_a_multi_row_launch_equals_the_prompt_whole(model,
             jnp.asarray([done[b]], jnp.int32), jnp.int32(2 - b), jnp.float32(0.0), jnp.int32(1),
             jax.random.PRNGKey(0))
         first.append(int(tok))
-        assert stats.shape == (2, 5)  # chunk_mid's and chunk_final's counts, the held ones too
+        assert stats.shape == (2, 6)  # chunk_mid's and chunk_final's counts, the held ones and the blocks too
     for b, n in enumerate(lens):
         slot = 2 - b
         logits, whole = prefill(params, init_kv_cache(CFG, 1, 64), jnp.asarray(tokens[b:b + 1, :n]), CFG)
@@ -395,18 +396,21 @@ def test_forward_refuses_blocks_that_run_through_the_cache_only():
 # ------------------------------------------------- a device's share of a layer
 
 
-def test_the_four_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("tokens", [12, 100], ids=["a-block-is-all", "a-block-is-two-thirds"])
+def test_the_four_shares_of_an_expert_layer_add_up_to_the_uncut_layer(tokens):
     """16 experts over 4 devices, 4 each. Each share routes over all 16 and
     computes its own experts' part; what the four add to a token, with what
     every device computes alike counted once (the shared expert; the
     up-projection is linear, so it may be applied share by share), is what
     the plain reference gives for the layer with all 16 experts. Every
-    assignment falls on exactly one share."""
+    assignment falls on exactly one share. At 12 tokens a share's block of
+    sorted rows is all 72 assignments, at 100 it is 384 of the 600."""
     from benchmark.reference_ssm_latent_moe import Reference
 
+    assert patterned.held_block(tokens * CFG.moe_top_k, 4, 16) == {12: 72, 100: 384}[tokens]
     params = init_params(jax.random.PRNGKey(5), dataclasses.replace(CFG, moe_experts_held=0))
     assert params["moe_w_up"].shape[:2] == (5, 16)
-    x = jax.random.normal(jax.random.PRNGKey(2), (1, 12, CFG.d_model))
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, tokens, CFG.d_model))
     h = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + CFG.rms_eps)  # mlp_norm is ones
     row = 2
     shared = patterned._shared_expert(
@@ -420,11 +424,24 @@ def test_the_four_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
         total = total + (y[0] - shared)
         counts = dict(zip(patterned.moe_stats_names(cfg), np.asarray(stats)))
         held, made = held + counts["assignments_held"], counts["assignments"]
-        assert counts["experts_touched"] <= 4
-    assert made == 12 * CFG.moe_top_k == held
+        assert counts["experts_touched"] <= 4 and counts["passes"] == 1
+    assert made == tokens * CFG.moe_top_k == held
     whole = Reference(dict(PUBLISHED, n_routed_experts=16), jax.local_devices()[:1])
     (after,), _ = whole._experts(params, row, [x])
     np.testing.assert_allclose(total, (after - x)[0], atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("fell", sorted(held_experts.HELD))
+def test_a_share_works_through_what_fell_on_it_a_block_at_a_time(fell, monkeypatch):
+    """8 of 48 relu^2 experts held in the latent, 64 tokens of 6 choices: a
+    block is 128 of the 384 sorted rows. Whatever the router does (every
+    assignment on the held experts: three blocks; none: the shared expert
+    alone, counted as one block; a block's rows exactly, and one more: a
+    second block for one row) the layer is what the form that works on all
+    384 rows gives, token for token within float32 rounding, nothing dropped,
+    and the counts are what that form made of the same choices."""
+    cfg = dataclasses.replace(CFG, moe_experts=48, moe_experts_held=8, moe_experts_first=16)
+    held_experts.check_a_block_at_a_time(cfg, 64, 128, fell, monkeypatch, atol=1e-5)
 
 
 def test_the_four_slices_of_the_vocabulary_add_up_to_the_whole_head():
@@ -536,6 +553,8 @@ def test_engine_counts_the_state_a_slot_holds_and_the_assignments_held(engine):
         made, held = c["moe_assignments"][program], c["moe_assignments_held"][program]
         # every routed row makes 6 assignments, a launch's rows in each of its layers
         assert 0 < held < made and made % 6 == 0 and made >= 6 * c["moe_layer_steps"][program]
+        # a block of sorted rows a layer run: the tiny sizes overflow none
+        assert c["moe_passes"][program] == c["moe_layer_steps"][program] > 0
     # 4 of 16 experts held: about a quarter of what the router assigns
     assert 0.1 < sum(c["moe_assignments_held"].values()) / sum(c["moe_assignments"].values()) < 0.4
 
