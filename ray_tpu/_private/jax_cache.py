@@ -38,6 +38,11 @@ def configure() -> str:
     # only where it is the same. The price: an edit that moves the traced
     # lines compiles those programs once more (README, "Compile cache").
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    # JAX keeps only what took a second to compile. A serving start runs some
+    # 75 programs under that (slices, updates, the samplers' scalars), and
+    # compiling them again cost every warm start 8-9 s (PERF.md section 6,
+    # PR 46): every program is kept, however short its compile.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     return path
 
 

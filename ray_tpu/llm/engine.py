@@ -35,6 +35,7 @@ from typing import Any, Callable, Iterator, Optional
 
 import numpy as np
 
+from ray_tpu._private import program_store
 from ray_tpu.llm.config import (
     CHUNK_ROWS_MAX, EngineConfig, LLMConfig, ModelConfig, SamplingParams,
 )
@@ -160,6 +161,56 @@ def _order_of(leaf) -> Optional[tuple]:
     every leaf's default on a TPU: a narrow last axis is moved inward.)"""
     layout = getattr(getattr(leaf, "format", None), "layout", None)
     return tuple(layout.major_to_minor) if layout is not None else None
+
+
+# the donated positions of the programs the loop launches (``JaxEngine._compile``)
+_DONATED = {
+    "_decode_jit": (1,), "_decode_multi_jit": (1,), "_chunk_mid_jit": (1, 5),
+    "_chunk_final_jit": (1, 2), "_new_stripe_jit": (), "_seed_prefix_jit": (0,),
+    "_store_snapshot_jit": (),
+}
+
+# What a start spends deriving its programs, by phase: JAX's own duration
+# events, and ``restore_s`` from ``JaxEngine._program``, summed into the dict
+# ``_warm_programs`` hangs here for the thread it runs on (JAX fires an event
+# on the thread that traced or compiled). A ``jit`` traced inside another
+# fires its own trace event before the outer one's, which holds its seconds
+# again: ``_traced`` keeps (start, seconds) of the traces booked so that an
+# outer one takes back what it encloses.
+_PHASE_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "compile_or_fetch_s",
+}
+_phases = threading.local()
+_listen_lock = threading.Lock()
+_listening = False
+
+
+def _book_phase(phase: str, seconds: float) -> None:
+    into = getattr(_phases, "into", None)
+    if into is None:
+        return
+    if phase == "trace_s":
+        start, whole, traced = time.time() - seconds, seconds, _phases.traced
+        while traced and traced[-1][0] >= start:
+            seconds -= traced.pop()[1]
+        traced.append((start, whole))
+    into[phase] = into.get(phase, 0.0) + seconds
+
+
+def _listen_to_jax() -> None:
+    """Once a process: JAX has no way to take a listener back."""
+    global _listening
+    import jax.monitoring
+
+    with _listen_lock:
+        if _listening:
+            return
+        _listening = True
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, seconds, **_: _book_phase(_PHASE_OF_EVENT[event], seconds)
+        if event in _PHASE_OF_EVENT else None)
 
 
 def _latency_histogram(name: str) -> "app_metrics.Histogram":
@@ -628,6 +679,7 @@ class JaxEngine:
         # ``_warm_programs`` the seconds by program
         self._init_s: dict = {}
         self._warm_s: dict = {}
+        self._warm_phases_s: dict = {}
         self._init_phase(self._build_model)
         self._init_phase(self._build_pools)
         # the most rows a pool's middle-chunk program runs: what is alive in
@@ -859,16 +911,18 @@ class JaxEngine:
         self._top_k_static = top_k_static(cfg)
         self._decode_n_steps = max(1, self.config.engine.decode_steps)
         fns = programs(cfg, self._decode_n_steps)
-        self._decode_jit = jax.jit(fns["decode_fn"], donate_argnums=(1,))
-        self._decode_multi_jit = jax.jit(fns["decode_multi"], donate_argnums=(1,))
+        self._decode_jit = jax.jit(fns["decode_fn"], donate_argnums=_DONATED["_decode_jit"])
+        self._decode_multi_jit = jax.jit(
+            fns["decode_multi"], donate_argnums=_DONATED["_decode_multi_jit"])
         # (5: the pool's cache, where a launch takes the pool's decode rows)
-        self._chunk_mid_jit = jax.jit(fns["chunk_mid"], donate_argnums=(1, 5))
+        self._chunk_mid_jit = jax.jit(fns["chunk_mid"], donate_argnums=_DONATED["_chunk_mid_jit"])
         # donate the scratch stripe too and hand it back (the caller drops
         # it): a stripe the program may not overwrite is copied before the
         # chunk is written into it, and the v5e compiler then moved a whole
         # 33 MB stripe between memory spaces once a layer (0.64 ms a run at
         # 7B widths; PERF.md section 6, PR 27)
-        self._chunk_final_jit = jax.jit(fns["chunk_final"], donate_argnums=(1, 2))
+        self._chunk_final_jit = jax.jit(
+            fns["chunk_final"], donate_argnums=_DONATED["_chunk_final_jit"])
         from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
         # a scratch stripe is committed where it is made: a chunk program then
@@ -883,7 +937,8 @@ class JaxEngine:
                 else SingleDeviceSharding(jax.local_devices()[0])
             ),
         )
-        self._seed_prefix_jit = jax.jit(fns["seed_prefix"], donate_argnums=(0,))
+        self._seed_prefix_jit = jax.jit(
+            fns["seed_prefix"], donate_argnums=_DONATED["_seed_prefix_jit"])
         from ray_tpu.models.patterned import state_cache_shapes
 
         self._state_leaves = tuple(state_cache_shapes(cfg, 1))
@@ -896,6 +951,92 @@ class JaxEngine:
             lambda keys, slot, key: keys.at[slot].set(key), donate_argnums=(0,)
         )
         self._rng_key = jax.random.PRNGKey(self.config.model.seed)
+        # The forms of those programs the loop launches (``_launch``): where
+        # the compile cache is in use, an engine of one device runs each as an
+        # executable restored from ``_private/program_store.py``, or compiled
+        # ahead of time and kept there for the next start; anywhere else the
+        # ``jit``s above run as they are. Over a mesh they stay: no cell runs
+        # one and nothing could check it.
+        self._device = jax.local_devices()[0]
+        self._restores = not self._spans_devices() and program_store.directory() is not None
+        self._programs: dict = {}
+        self._program_counts = {"restored": 0, "compiled": 0, "fallback": 0}
+        # what shaped the programs beside their arguments
+        self._program_context = {
+            "model": dataclasses.asdict(cfg), "engine": dataclasses.asdict(self.config.engine),
+        }
+
+    def _launch(self, form: tuple, jit_name: str, *args, static: tuple = (), **kwargs):
+        """Run the program ``form`` names (the program, its pool's stripe
+        length and whatever else tells two compilations of it apart; its
+        static arguments, ``static``, among them: an executable takes none)
+        on ``args``. With ``_restores`` that is the form's executable, loaded
+        or compiled on its first launch (``_program``); one that refuses its
+        arguments (it does so before anything runs or is donated) gives way
+        to the ``jit`` for good, as does every form of an engine without
+        ``_restores``: that call is the parent's to the letter."""
+        if not self._restores:
+            return getattr(self, jit_name)(*args, *static, **kwargs)
+        program = self._programs.get(form)
+        if program is None:
+            program = self._program(form, jit_name, args, static, kwargs)
+        try:
+            return program(*args, **kwargs)
+        except (TypeError, ValueError) as e:
+            import jax
+
+            if not isinstance(program, jax.stages.Compiled):
+                raise
+            logger.warning("program %s refused its arguments and gives way to its jit: %s",
+                           form, str(e).splitlines()[0])
+            self._program_counts["fallback"] += 1
+            self._programs[form] = program = self._jit_of(jit_name, static)
+            return program(*args, **kwargs)
+
+    def _jit_of(self, jit_name: str, static: tuple) -> Callable:
+        jitted = getattr(self, jit_name)
+        return lambda *args, **kwargs: jitted(*args, *static, **kwargs)
+
+    def _program(self, form: tuple, jit_name: str, args: tuple, static: tuple, kwargs: dict):
+        """The executable of ``form`` for arguments like ``args``: the store's,
+        found by a key that nothing is traced for, else lowered and compiled
+        here (through the compile cache) and written for the next start. A
+        file that cannot be loaded counts as a fallback and is written anew.
+        Inside ``jax_cache.bypassed()`` the ``jit`` runs and nothing is kept."""
+        if program_store.directory() is None:
+            return self._jit_of(jit_name, static)
+        name = ":".join(map(str, form))
+        t = time.perf_counter()
+        key = program_store.key(name, args, kwargs, _DONATED[jit_name], self._program_context)
+        outcome, program = "compiled", None
+        try:
+            program = program_store.load(name, key, self._device)
+        except Exception as e:  # noqa: BLE001 - a file cut short, another version's pickle
+            logger.warning("program %s: the kept file does not load (%s: %s); compiling",
+                           name, type(e).__name__, e)
+            outcome = "fallback"
+        _book_phase("restore_s", time.perf_counter() - t)
+        if program is None:
+            program = getattr(self, jit_name).lower(*args, *static, **kwargs).compile()
+            program_store.save(name, key, program)
+        else:
+            outcome = "restored"
+        self._program_counts[outcome] += 1
+        self._programs[form] = program
+        return program
+
+    def _new_stripe(self, stripe_len: int):
+        return self._launch(("new_stripe", stripe_len), "_new_stripe_jit", static=(stripe_len,))
+
+    def _seed_prefix(self, one: dict, k, v, state: Optional[dict] = None):
+        """``one`` seeded with a stored prefix's keys and values (and, of a
+        snapshot, its ``state`` leaves)."""
+        form = ("seed_prefix", one["k"].shape[3], k.shape[2], state is not None)
+        return self._launch(form, "_seed_prefix_jit", one, k, v, *(() if state is None else (state,)))
+
+    def _store_snapshot(self, one: dict, positions: int):
+        return self._launch(("store_snapshot", one["k"].shape[3], positions),
+                            "_store_snapshot_jit", one["k"], one["v"], static=(positions,))
 
     def _chunk_widths(self, pool: _Pool) -> tuple[Optional[int], list[int]]:
         """The chunk programs a pool's admissions can ask for: the middle
@@ -909,6 +1050,32 @@ class JaxEngine:
         return (chunk if 0 < chunk < longest else None), finals
 
     def _warm_programs(self) -> None:
+        """``_warm_pass``, and with ``_restores`` what keeps a start that
+        compiled its forms and one that restored them alike to everything
+        compiled after them. The compile cache's key holds names and source
+        lines (``jax_cache.configure``), and a function that JAX traces once
+        and reuses (``jnp.take``, the samplers' helpers: every inner ``jit``)
+        keeps the lines of the call site that traced it first. A start that
+        traced its forms has left such functions behind with the forms' lines,
+        a start that restored has not, so a program compiled later (a
+        deployment's next engine; the benchmark's weights and reference) got
+        another key in the first restored start and was compiled again: 20-36
+        s of that start's set-up on a v5e (PERF.md section 6, PR 46). So both
+        kinds of start drop JAX's trace caches before the pass, and one that
+        compiled drops them again and runs the pass once more, on its
+        executables: after either, the caches hold what one pass over
+        executables leaves."""
+        if not self._restores:
+            return self._warm_pass()
+        import jax
+
+        jax.clear_caches()
+        self._warm_pass()
+        if self._program_counts["compiled"] or self._program_counts["fallback"]:
+            jax.clear_caches()
+            self._warm_pass()
+
+    def _warm_pass(self) -> None:
         """Run every program the loop can launch once, on throwaway rows,
         before the loop takes requests: a pool at a time, the middle chunk at
         every row count, each final width, the prefix store's cuts and seeds,
@@ -917,8 +1084,8 @@ class JaxEngine:
         (warm-up traffic that sends a request at a time never reaches a
         program of two rows, and a window that compiles is not measured). A
         checkout's first start compiles them all here
-        (``LLMConfig.compile_budget_s``); later starts fetch them from the
-        compile cache. The rows write one token at position 0 of slot 0,
+        (``LLMConfig.compile_budget_s``); later starts restore them
+        (``_launch``) or fetch them from the compile cache. The rows write one token at position 0 of slot 0,
         which holds no request and which an admission overwrites whole; a
         pool that ``carries`` hands its chunk programs its decode rows as the
         loop does, none of them live. Each program is waited for where it was
@@ -928,6 +1095,8 @@ class JaxEngine:
         import jax.numpy as jnp
 
         mark = time.perf_counter()
+        _listen_to_jax()
+        _phases.into, _phases.traced = phases, traced = {}, []
 
         def book(program: str, out) -> None:
             nonlocal mark
@@ -935,7 +1104,15 @@ class JaxEngine:
             now = time.perf_counter()
             self._warm_s[program] = self._warm_s.get(program, 0.0) + now - mark
             mark = now
+            by_phase = self._warm_phases_s.setdefault(program, {})
+            for phase, seconds in phases.items():
+                by_phase[phase] = by_phase.get(phase, 0.0) + seconds
+            phases.clear()
+            traced.clear()
 
+        # an executable takes an array committed or not alike, so its one run
+        # is every kind's; a ``jit`` compiles once for each kind it is handed
+        kinds = 1 if self._restores else 2
         rng_key = self._rng_key
         jax.random.PRNGKey(0)  # a seeded request's key is a program too
         for i, pool in enumerate(self._pools):
@@ -955,21 +1132,21 @@ class JaxEngine:
 
             def throwaway(rows: int) -> dict:  # rows of one token at position 0
                 return dict(
-                    ones=tuple(self._new_stripe_jit(stripe) for _ in range(rows)),  # noqa: B023
+                    ones=tuple(self._new_stripe(stripe) for _ in range(rows)),  # noqa: B023
                     toks=np.zeros((rows, mid), np.int32), lens=[1] * rows,  # noqa: B023
                     starts=[0] * rows, adapters=[0] * rows, pool=pool,  # noqa: B023
                 )
 
             for rows in range(1, pool.chunk_rows + 1 if mid else 1):
                 args = throwaway(rows)
-                for _ in range(2):  # fresh stripes, then a chunk program's own
+                for _ in range(kinds):  # fresh stripes, then a chunk program's own
                     args["ones"] = self._run_chunk_mid(**args)
                 book(f"chunk_mid:rows={rows}", args["ones"])
             for width in finals:
                 # a first chunk's stripe is fresh, a later one's comes out of
                 # a chunk program: both kinds of argument
-                stripes = [self._new_stripe_jit(stripe)]
-                if mid:
+                stripes = [self._new_stripe(stripe)]
+                if mid and kinds == 2:
                     stripes += self._run_chunk_mid(**throwaway(1))
                 for one in stripes:
                     self._run_chunk_final(
@@ -978,45 +1155,37 @@ class JaxEngine:
             if self.config.engine.enable_prefix_caching and pool.stateful:
                 # a snapshot's cut at each length, and the seed from each
                 for m in self._snapshot_lengths(pool):
-                    one = self._new_stripe_jit(stripe)
-                    k, v = self._store_snapshot_jit(one["k"], one["v"], m)
+                    one = self._new_stripe(stripe)
+                    k, v = self._store_snapshot(one, m)
                     state = {name: one[name] for name in self._state_leaves}
-                    book("snapshot", self._seed_prefix_jit(
-                        self._new_stripe_jit(stripe), k, v, state))
+                    book("snapshot", self._seed_prefix(self._new_stripe(stripe), k, v, state))
             elif self.config.engine.enable_prefix_caching:
                 # the store's cut of a slot at each bucket, and the program
                 # that seeds a stripe with one
                 for b in self.config.engine.prefill_buckets:
                     if b < pool.stripe_len:
-                        book("seed_prefix", self._seed_prefix_jit(
-                            self._new_stripe_jit(stripe),
+                        book("seed_prefix", self._seed_prefix(
+                            self._new_stripe(stripe),
                             pool.cache["k"][:, 0, :, :b], pool.cache["v"][:, 0, :, :b]))
-            for _ in range(2):  # the cache, keys and tokens as a chunk left them, then as a step did
+            for _ in range(kinds):  # the cache, keys and tokens as a chunk left them, then as a step did
                 out, pool.cache, pool.keys, _ = self._decode(
                     pool, pool.dev_tokens, *pool.sampler(), pool.keys,
                 )
                 pool.dev_tokens = out[-1]
             book("decode", (pool.cache, pool.dev_tokens))
         self._rng_key = rng_key
+        _phases.into = None
 
     def _decode(self, pool: _Pool, tokens, temps, top_ks, keys):
         """Returns ([K, slots] tokens, cache, keys, routing counts or None)
         — K = decode_steps."""
-        fn = (
-            self._decode_multi_jit
-            if self._decode_n_steps > 1
-            else self._decode_jit
-        )
-        if self.loras is None:
-            # no-LoRA configuration: the compiled program has no adapter args
-            out, cache, keys, stats = fn(
-                self.params, pool.cache, tokens, temps, top_ks, keys
-            )
-        else:
-            out, cache, keys, stats = fn(
-                self.params, pool.cache, tokens, temps, top_ks, keys,
-                loras=self.loras, adapter_ids=pool.adapter_ids_dev,
-            )
+        # (a no-LoRA configuration's program has no adapter arguments)
+        adapters = {} if self.loras is None else dict(
+            loras=self.loras, adapter_ids=pool.adapter_ids_dev)
+        out, cache, keys, stats = self._launch(
+            ("decode", pool.stripe_len),
+            "_decode_multi_jit" if self._decode_n_steps > 1 else "_decode_jit",
+            self.params, pool.cache, tokens, temps, top_ks, keys, **adapters)
         if self._decode_n_steps == 1:
             out = out[None]  # unify to [K, slots]
         return out, cache, keys, stats
@@ -1164,7 +1333,7 @@ class JaxEngine:
             self._prefix_cache.move_to_end(key)
             return
         m = next(m for m in self._snapshot_lengths(pool) if m >= len(ids))
-        k, v = self._store_snapshot_jit(one["k"], one["v"], m)
+        k, v = self._store_snapshot(one, m)
         state = {name: one[name] for name in self._state_leaves}
         nbytes = int(k.nbytes + v.nbytes + sum(x.nbytes for x in state.values()))
         self._prefix_cache[key] = {"k": k, "v": v, "state": state, "nbytes": nbytes,
@@ -1418,7 +1587,9 @@ class JaxEngine:
             # seconds of its phases, which add up to it (the last also by
             # program: a middle chunk by rows, a final chunk by width)
             "engine_init_s": _between(self._t_init, self._loop_first_pass_t),
-            "init": {**self._init_s, "warm_programs_by_program_s": dict(self._warm_s)},
+            "init": {**self._init_s, "warm_programs_by_program_s": dict(self._warm_s),
+                     "warm_programs_phases_s": {p: dict(by) for p, by in self._warm_phases_s.items()},
+                     "programs": dict(self._program_counts)},
             # the loop's own clock: where its time went, and the longest pass
             # of every second (``_LoopClock``)
             "loop": self._loop.view(),
@@ -1625,14 +1796,13 @@ class JaxEngine:
             chunks.append((toks, len(piece), start, is_final))
             start += len(piece)
         with tracing.annotate("engine.new_stripe"):
-            one = self._new_stripe_jit(pool.stripe_len)
+            one = self._new_stripe(pool.stripe_len)
         if prefix is not None:
             with self._device_call("launch", "seed_prefix", "engine.prefix_seed"):
-                if "state" in prefix:  # a snapshot: the state leaves with the keys and values
-                    one = self._seed_prefix_jit(one, prefix["k"], prefix["v"], prefix["state"])
+                # (a snapshot: the state leaves with the keys and values)
+                one = self._seed_prefix(one, prefix["k"], prefix["v"], prefix.get("state"))
+                if "state" in prefix:
                     self._count({"snapshots_hit": 1, "snapshot_seed_bytes": prefix["nbytes"]})
-                else:
-                    one = self._seed_prefix_jit(one, prefix["k"], prefix["v"])
                 self._count({"prefix_seed_tokens": m})
         pool.admitting[slot] = _Admission(req, slot, one, chunks, m)
 
@@ -1743,7 +1913,8 @@ class JaxEngine:
             if takes:
                 args += (pool.cache, self._decode_rows(pool, carry))
         with tracing.annotate("engine.chunk_call"):
-            out = self._chunk_mid_jit(self.params, ones, *args, **lora_kw)
+            out = self._launch(("chunk_mid", pool.stripe_len, len(ones)), "_chunk_mid_jit",
+                               self.params, ones, *args, **lora_kw)
         if not takes:
             return out
         ones, next_tokens, pool.cache, pool.keys = out
@@ -1814,7 +1985,8 @@ class JaxEngine:
             if pool.carries:
                 args += (self._decode_rows(pool, carry),)
         with tracing.annotate("engine.chunk_call"):
-            first_tok, new_key, pool.cache, one, stats, *rode = self._chunk_final_jit(
+            first_tok, new_key, pool.cache, one, stats, *rode = self._launch(
+                ("chunk_final", pool.stripe_len, toks.shape[1]), "_chunk_final_jit",
                 self.params, pool.cache, one, *args, **lora_kw)
         if rode:  # the program set the slot's key and next input token itself
             next_tokens, pool.keys = rode

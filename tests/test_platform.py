@@ -570,15 +570,52 @@ def test_compile_cache_dir_choice(monkeypatch, tmp_path):
     )
     monkeypatch.setenv(jax_cache.ENV_VAR, str(tmp_path))
     names_in_key = ("jax_compilation_cache_include_metadata_in_key", True)
+    every_compile = ("jax_persistent_cache_min_compile_time_secs", 0)
     assert jax_cache.configure() == str(tmp_path)
-    assert updates == [names_in_key]
+    assert updates == [names_in_key, every_compile]
     del updates[:]
     monkeypatch.delenv(jax_cache.ENV_VAR)
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     fixed = os.path.join(repo, ".jax_cache")
     assert jax_cache.configure() == fixed == jax_cache.cache_dir()
-    assert updates == [("jax_compilation_cache_dir", fixed), names_in_key]
+    assert updates == [("jax_compilation_cache_dir", fixed), names_in_key, every_compile]
     assert jax.config.jax_compilation_cache_dir == was
+
+
+_EVERY_COMPILE = """
+import jax, jax.numpy as jnp
+from ray_tpu._private import jax_cache
+jax_cache.configure()
+floor = jax.config.jax_persistent_cache_min_compile_time_secs
+x = jnp.ones((8, 8))
+empty = jax_cache.entry_count()
+jax.jit(lambda x: x @ x + 1)(x).block_until_ready()
+outside = jax_cache.entry_count() - empty
+with jax_cache.bypassed():
+    jax.jit(lambda x: x @ x + 2)(x).block_until_ready()
+print("RESULT", floor, outside, jax_cache.entry_count() - empty - outside)
+"""
+
+
+def test_every_compile_is_kept_but_one_inside_the_bypass(tmp_path):
+    """``configure()`` leaves JAX's floor on what it persists at 0 seconds: a
+    program that compiles in milliseconds is written (a serving start runs
+    some 75 of them, and compiled them again at every start), and one
+    compiled inside ``bypassed()`` is still not."""
+    import os
+    import subprocess
+
+    from ray_tpu._private import jax_cache
+
+    out = subprocess.run(
+        [sys.executable, "-c", _EVERY_COMPILE], capture_output=True, text=True, timeout=120,
+        check=True, env={**os.environ, "JAX_PLATFORMS": "cpu", jax_cache.ENV_VAR: str(tmp_path)},
+    )
+    floor, outside, inside = [ln for ln in out.stdout.splitlines()
+                              if ln.startswith("RESULT")][-1].split()[1:]
+    assert float(floor) == 0
+    assert int(outside) >= 1
+    assert int(inside) == 0
 
 
 _CACHED_NAMES = """
