@@ -1,434 +1,18 @@
-"""Flagship benchmark: Llama train-step MFU on one TPU chip.
+"""Host-side probes of the runtime: the recorders of ``MICROBENCH.json``.
 
-Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline"}.
-Baseline: BASELINE.json north-star = 40% MFU (Llama DP train on v5e).
+``--check-floor`` gates the call path, the serve ladder, recovery and the
+other recorded rates against ``MICROBENCH.json`` on this host; ``--gang``,
+``--serve-ladder``, ``--actor-creation``, ``--fairshare``, ``--observability``,
+``--recovery``, ``--reconstruction`` and ``--transfer`` each record their own
+section. None of them needs a chip or touches one.
 
-Without a chip ``python bench.py`` exits nonzero and prints no value; the
-host-side probes (``--check-floor``, ``--gang``, ``--serve-ladder`` ...) keep
-their own flags. Peaks come from ``ray_tpu.tpu.topology.CHIP_PEAKS``.
+The chip's benchmark is ``benchmark/run.py`` (``BENCHMARK.json`` names its
+cells): ``python bench.py`` with no flag says so and exits nonzero.
 """
 
 import json
 import sys
 import time
-from typing import Optional
-
-
-def main():
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from ray_tpu._private import jax_cache
-    from ray_tpu.tpu.topology import chip_peaks
-
-    jax_cache.configure()
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        # a CPU timing is not a slower chip timing: nothing is printed
-        # under a device metric's name without the device
-        sys.exit(
-            f"bench.py measures the chip and found platform {dev.platform!r}; "
-            "run it on a TPU host (host-side probes keep their own flags, "
-            "e.g. --check-floor)"
-        )
-    peaks = chip_peaks(dev.device_kind)  # unknown kind: an error, no default
-    peak_flops = peaks["bf16_flops_per_s"]
-    hbm_bw = peaks["hbm_bytes_per_s"]
-
-    from ray_tpu.models import LlamaConfig
-    from ray_tpu.models.training import make_train_step, flops_per_token
-    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
-
-    # ~1.2B-param model (VERDICT r3 weak #4: measure the MFU headline
-    # on the largest train state the 16 GiB chip holds, not a 335M
-    # flatterer; bigger matmuls tile the MXU better). bf16 weights + bf16
-    # adam moments = 6.7 GiB, remat for activations.
-    cfg = LlamaConfig(
-        vocab_size=32000,
-        d_model=2048,
-        n_layers=16,
-        n_heads=16,
-        n_kv_heads=16,
-        d_ff=8192,
-        max_seq_len=2048,
-        dtype=jnp.bfloat16,
-        remat=True,
-        # splash attention (blockwise-causal Pallas kernel) and the plain
-        # CE path (at V=32k XLA overlaps the logit matmul better than the
-        # chunked scan)
-        attention="splash",
-        fused_ce=False,
-    )
-    batch, seq, steps, warmup = 4, 2048, 8, 2
-
-    mesh = build_mesh(MeshSpec(dp=1), devices=jax.devices()[:1])
-
-    def train_bench(cfg, batch, seq, steps, warmup):
-        """(tokens/s, mfu, final loss) for one config on the 1-chip mesh."""
-        init_fn, step_fn = make_train_step(cfg, mesh)
-        state = init_fn(jax.random.PRNGKey(0))
-        rng = np.random.default_rng(0)
-        batch_data = {
-            "tokens": jnp.asarray(
-                rng.integers(0, cfg.vocab_size, (batch, seq + 1)),
-                dtype=jnp.int32,
-            )
-        }
-        for _ in range(warmup):
-            state, metrics = step_fn(state, batch_data)
-        # a value fetch is a hard sync: the timer starts on a drained queue
-        float(metrics["loss"])
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            state, metrics = step_fn(state, batch_data)
-        final_loss = float(metrics["loss"])
-        dt = time.perf_counter() - t0
-        tps = batch * seq * steps / dt
-        return tps, tps * flops_per_token(cfg) / peak_flops, final_loss
-
-    tokens_per_sec, achieved_mfu, final_loss = train_bench(
-        cfg, batch, seq, steps, warmup
-    )
-    baseline_mfu = 0.40  # BASELINE.json north-star target
-
-    import gc
-
-    gc.collect()
-
-    # the 335M config of the earliest runs, reported alongside so the
-    # series stays comparable
-    cfg_335m = LlamaConfig(
-        vocab_size=32000,
-        d_model=1024,
-        n_layers=16,
-        n_heads=16,
-        n_kv_heads=16,
-        d_ff=4096,
-        max_seq_len=2048,
-        dtype=jnp.bfloat16,
-        remat=True,
-        attention="splash",
-        fused_ce=False,
-    )
-    tps_s, mfu_s, _ = train_bench(
-        cfg_335m, batch=8, seq=2048, steps=8, warmup=2
-    )
-    compat_335m = {
-        "model_params_335m": cfg_335m.num_params(),
-        "tokens_per_sec_335m": round(tps_s, 1),
-        "train_mfu_335m": round(mfu_s, 4),
-        "overhead_breakdown_335m": train_overhead_breakdown(
-            cfg_335m, mesh, batch=8, seq=2048,
-            peak_flops=peak_flops, hbm_bw=hbm_bw,
-        ),
-    }
-    gc.collect()
-
-    # free the training working set before the serving engine allocates its
-    # params + KV pools (a 7B engine does not fit next to train state).
-    # A phase that raises ends the run: a benchmark line with a phase
-    # missing reads as a benchmark that ran.
-    decode = decode_bench(hbm_bw)
-    gc.collect()
-    decode["ttft_tradeoff"] = ttft_tradeoff_sweep(headline=decode)
-    # if the latency-leaning knob setting meets the 400 ms SLO, say so
-    # explicitly (the headline engine stays throughput-tuned; serving
-    # configs pick their point on the published curve)
-    best = min(decode["ttft_tradeoff"], key=lambda e: e["ttft_ms_mean"])
-    decode["ttft_note"] = (
-        f"decode_steps={best['decode_steps']} reaches "
-        f"{best['ttft_ms_mean']}ms mean TTFT at "
-        f"{best['tokens_per_sec_incl_prefill']} tok/s incl prefill; "
-        "EngineConfig.decode_steps is the knob"
-    )
-    gc.collect()
-
-    print(
-        json.dumps(
-            {
-                "metric": "llama_train_mfu_1chip",
-                "value": round(achieved_mfu, 4),
-                "unit": "mfu_fraction",
-                "vs_baseline": round(achieved_mfu / baseline_mfu, 4),
-                "tokens_per_sec": round(tokens_per_sec, 1),
-                "platform": dev.platform,
-                "device_kind": dev.device_kind,
-                "device_count": len(jax.devices()),
-                "peaks_source": peaks["source"],
-                "model_params": cfg.num_params(),
-                "loss": final_loss,
-                **compat_335m,
-                **decode,
-            }
-        )
-    )
-
-
-def decode_bench(hbm_bw: float) -> dict:
-    """Serving-side numbers (VERDICT r2 weak #4 + r3 weak #3): steady-state
-    continuous-batching decode throughput at batch >=16 with a roofline
-    account (weights+KV bytes per step / the chip's HBM bandwidth),
-    time-to-first-token, and the prefix-cache TTFT win."""
-    import numpy as np
-
-    from ray_tpu.llm import EngineConfig, JaxEngine, LLMConfig, ModelConfig
-    from ray_tpu.llm.config import SamplingParams
-
-    # 3B bf16 params (~6.4 GB incl. tied embeddings) + 16 KV stripes of
-    # 1024 fit a v5e chip; 7B is at the 16 GB edge with full-logit
-    # prefill and OOMs on the second program execution
-    model_id, seqs, seq_len, gen_tokens = "llama3.2-3b", 16, 1024, 128
-
-    def build_engine(decode_steps: int) -> "JaxEngine":
-        return JaxEngine(
-            LLMConfig(
-                model=ModelConfig(model_id=model_id, tokenizer="byte", seed=0),
-                engine=EngineConfig(
-                    max_num_seqs=seqs,
-                    max_seq_len=seq_len,
-                    prefill_buckets=(32, 64, 128, 256, 512, 1024),
-                    # K steps per decode program + run-ahead (token-exact,
-                    # tested). K is ALSO the prefill/decode interleave
-                    # ratio: each admission chunk waits behind K decode
-                    # steps, so K trades TTFT against decode throughput —
-                    # the sweep below publishes the curve.
-                    decode_steps=decode_steps,
-                    decode_runahead=1,
-                    prefill_chunk=256,
-                ),
-            )
-        )
-
-    def cold_batch(engine, sp, prompt, tag: str):
-        """Submit a full batch of UNCACHED prompts; returns TTFT stats.
-        No per-stream drain threads here — 16 consumers contending with the
-        engine loop for the host CPU would inflate the very latencies being
-        measured (observed +50% mean TTFT)."""
-        t0 = time.perf_counter()
-        reqs = [
-            engine.submit(f"{tag} {i}: " * 4 + prompt, sampling_params=sp)
-            for i in range(seqs)
-        ]
-        for r in reqs:
-            r.done.wait()
-        dt = time.perf_counter() - t0
-        total_tokens = sum(len(r.out_tokens) for r in reqs)
-        ttfts = np.asarray(
-            [r.first_token_t - r.submitted_t for r in reqs], np.float64
-        )
-        return {
-            "reqs": reqs,
-            "dt": dt,
-            "total_tokens": total_tokens,
-            "prompt_tokens": sum(len(r.prompt_token_ids) for r in reqs),
-            "ttft_ms_mean": round(1e3 * float(ttfts.mean()), 1),
-            "ttft_ms_p50": round(1e3 * float(np.percentile(ttfts, 50)), 1),
-            "ttft_ms_p99": round(1e3 * float(np.percentile(ttfts, 99)), 1),
-        }
-
-    engine = build_engine(8)
-    try:
-        sp = SamplingParams(max_tokens=gen_tokens, temperature=0.0,
-                            ignore_eos=True)
-        prompt = "benchmark prompt: the quick brown fox jumps. " * 2
-        # warmup: compile the decode program AND every prefill bucket the
-        # timed prompts will use (cold TTFT must measure prefill, not XLA
-        # compilation)
-        engine.generate(prompt, sampling_params=sp)
-        # warm the exact shape class the timed prompts use (same pattern,
-        # different leading tokens so it cannot seed a prefix hit for them)
-        engine.generate("request w: " * 4 + prompt, sampling_params=sp)
-
-        # COLD prompts: each starts with unique leading text so no
-        # bucket-aligned prefix of the warmup (or of each other) hits the
-        # prefix cache — ttft metrics are the uncached baseline
-        cold = cold_batch(engine, sp, prompt, "request")
-        reqs, dt = cold["reqs"], cold["dt"]
-        total_tokens = cold["total_tokens"]
-
-        # steady-state decode throughput: all slots occupied, admission
-        # excluded (prompts prefilled before the timer via a long first
-        # token budget). Measured over the tail of generation. ONE stream
-        # is drained live for inter-token latency — what a single SSE
-        # client observes at full batch (multi-step decode delivers tokens
-        # in bursts of decode_steps: p50 is intra-burst ≈0, p99 is the
-        # decode-program interval).
-        sp2 = SamplingParams(max_tokens=gen_tokens, temperature=0.0,
-                             ignore_eos=True)
-        reqs2 = [
-            engine.submit(f"steady {i}: " * 4 + prompt, sampling_params=sp2)
-            for i in range(seqs)
-        ]
-        while any(r.first_token_t is None for r in reqs2):
-            time.sleep(0.005)
-        base = sum(len(r.out_tokens) for r in reqs2)
-        t1 = time.perf_counter()
-        arrivals = []
-        for _ in engine.drain(reqs2[0]):
-            arrivals.append(time.perf_counter())
-        for r in reqs2:
-            r.done.wait()
-        steady_dt = time.perf_counter() - t1
-        steady_tokens = sum(len(r.out_tokens) for r in reqs2) - base
-        gaps = np.diff(np.asarray(arrivals, np.float64))
-
-        # roofline: every decode step streams all weights + the active KV
-        # stripes from HBM; achieved steps/s vs bandwidth-implied ceiling
-        mp = engine.model_cfg.num_params()
-        weight_bytes = 2 * mp  # bf16
-        kv_bytes = sum(
-            int(p.cache["k"].nbytes + p.cache["v"].nbytes)
-            for p in engine._pools
-        )
-        step_time_ideal = (weight_bytes + kv_bytes) / hbm_bw
-        steps_per_s = (steady_tokens / max(seqs, 1)) / max(steady_dt, 1e-9)
-        roofline_frac = steps_per_s * step_time_ideal
-
-        # prefix-cache TTFT: same long shared preamble, fresh question.
-        # Two warm passes first: one populates the cache, one compiles the
-        # suffix-prefill program — the measured hit is steady-state.
-        shared = "system preamble: " + "context " * 20
-        engine.generate(shared + "warm?", sampling_params=sp)  # populate
-        engine.generate(shared + "compile", sampling_params=sp)  # hit+compile
-        cold_hits = engine.get_stats()["prefix_cache_hits"]
-        r = engine.generate(shared + "question two", sampling_params=sp)
-        hit = engine.get_stats()["prefix_cache_hits"] > cold_hits
-
-        # incl-prefill account (the r4 "30% unexplained gap"): the cold
-        # batch's wall clock = generation at the steady decode rate +
-        # admission work (chunked prefill programs serialized with decode
-        # on the one chip) + scheduler slack. Quantify each term.
-        steady_rate = steady_tokens / max(steady_dt, 1e-9)
-        est_gen_s = total_tokens / max(steady_rate, 1e-9)
-        prefill_plus_sched_s = max(dt - est_gen_s, 0.0)
-        incl_account = {
-            "prompt_tokens": cold["prompt_tokens"],
-            "est_gen_s": round(est_gen_s, 3),
-            "est_prefill_plus_sched_s": round(prefill_plus_sched_s, 3),
-            # fraction of the decode-only vs incl-prefill rate gap that the
-            # admission-time term accounts for (1.0 = fully explained)
-            "gap_explained_frac": round(
-                min(prefill_plus_sched_s / max(dt - est_gen_s, 1e-9), 1.0), 3
-            ),
-        }
-        return {
-            "decode_tokens_per_sec": round(steady_rate, 1),
-            "decode_tokens_per_sec_incl_prefill": round(total_tokens / dt, 1),
-            "decode_batch": seqs,
-            "decode_roofline_frac": round(roofline_frac, 3),
-            "ttft_ms_mean": cold["ttft_ms_mean"],
-            "ttft_ms_p50": cold["ttft_ms_p50"],
-            "ttft_ms_p99": cold["ttft_ms_p99"],
-            "intertoken_ms_p50": round(
-                1e3 * float(np.percentile(gaps, 50)), 2
-            ) if gaps.size else 0.0,
-            "intertoken_ms_p99": round(
-                1e3 * float(np.percentile(gaps, 99)), 2
-            ) if gaps.size else 0.0,
-            "incl_prefill_account": incl_account,
-            "prefix_cache_hit": bool(hit),
-            "prefix_hit_ttft_ms": round(1e3 * r.metrics["ttft_s"], 1),
-        }
-    finally:
-        engine.shutdown()
-
-
-def train_overhead_breakdown(
-    cfg, mesh, batch: int, seq: int, peak_flops: float, hbm_bw: float,
-    steps: int = 6,
-) -> dict:
-    """Account the non-matmul overhead behind a train-MFU number (VERDICT r5
-    weak #4: the 335M 0.409 sat unexplained for three rounds).
-
-    Roofline accounting of one measured step time (the two ideal times
-    OVERLAP — they are bounds on the same step, not additive slices):
-    - ``matmul_ideal_frac`` — model-FLOPs time at chip peak (== the MFU);
-    - ``hbm_ideal_frac`` — XLA cost-analysis total bytes / HBM bandwidth:
-      the step's memory-roofline time. Includes the matmuls' OWN operand
-      traffic, so it overlaps matmul_ideal_frac; when it exceeds it, the
-      step is memory-bound and the MFU gap is bandwidth, not flops;
-    - ``host_sync_frac`` — measured: per-step host value sync vs
-      free-running dispatch, as a fraction of the SYNCED step (the
-      sampling/host side of the serving analogy; overlapped ≈ 0 in the
-      free-running headline protocol);
-    - ``collective_frac`` — 0 on one chip by construction (reported so the
-      multi-chip variant of this entry has a defined slot);
-    - ``other_device_frac`` — 1 - max(matmul, hbm) fracs: step time neither
-      roofline explains (dispatch gaps, fusion boundaries, remat
-      recompute scheduling).
-    """
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from ray_tpu.models.training import flops_per_token, make_train_step
-
-    init_fn, step_fn = make_train_step(cfg, mesh)
-    state = init_fn(jax.random.PRNGKey(0))
-    rng = np.random.default_rng(0)
-    batch_data = {
-        "tokens": jnp.asarray(
-            rng.integers(0, cfg.vocab_size, (batch, seq + 1)), dtype=jnp.int32
-        )
-    }
-    # cost analysis of the COMPILED step: flops + bytes accessed
-    cost = {}
-    try:
-        compiled = step_fn.lower(state, batch_data).compile()
-        ca = compiled.cost_analysis()
-        ca = ca[0] if isinstance(ca, (list, tuple)) else ca
-        cost = {k: float(v) for k, v in ca.items() if k in ("flops", "bytes accessed")}
-    except Exception:  # noqa: BLE001 — backend without cost analysis
-        pass
-    for _ in range(2):
-        state, metrics = step_fn(state, batch_data)
-    float(metrics["loss"])
-    # free-running: one value sync at the end (the headline MFU protocol)
-    t0 = time.perf_counter()
-    for _ in range(steps):
-        state, metrics = step_fn(state, batch_data)
-    float(metrics["loss"])
-    t_chained = (time.perf_counter() - t0) / steps
-    # synced: fetch the loss every step — the delta is pure host round trip
-    t0 = time.perf_counter()
-    for _ in range(steps):
-        state, metrics = step_fn(state, batch_data)
-        float(metrics["loss"])
-    t_synced = (time.perf_counter() - t0) / steps
-    host_sync_s = max(t_synced - t_chained, 0.0)
-
-    model_flops = flops_per_token(cfg) * batch * seq
-    matmul_ideal_s = model_flops / peak_flops
-    hbm_ideal_s = cost.get("bytes accessed", 0.0) / hbm_bw
-    matmul_frac = matmul_ideal_s / t_chained
-    host_sync_frac = host_sync_s / t_synced
-    hbm_frac = min(hbm_ideal_s / t_chained, 1.0)
-    # rooflines overlap (hbm includes the matmuls' own operand traffic):
-    # the step is explained up to max(compute-bound, memory-bound); the
-    # residual is what neither ideal accounts for
-    other = max(1.0 - max(matmul_frac, hbm_frac), 0.0)
-    return {
-        "step_time_ms": round(1e3 * t_chained, 2),
-        "step_time_synced_ms": round(1e3 * t_synced, 2),
-        "matmul_ideal_frac": round(matmul_frac, 4),
-        "host_sync_frac": round(host_sync_frac, 4),
-        "hbm_ideal_frac": round(hbm_frac, 4),
-        "collective_frac": 0.0,
-        "other_device_frac": round(other, 4),
-        "xla_flops_per_step": cost.get("flops"),
-        "xla_bytes_per_step": cost.get("bytes accessed"),
-        "note": (
-            "matmul_ideal_frac IS the MFU. Rooflines, not a partition: "
-            "matmul/hbm fracs are overlapping lower bounds on the "
-            "free-running step (step_time_ms; hbm includes the matmuls' "
-            "own HBM operand traffic — hbm > matmul means memory-bound), "
-            "other = 1 - max(matmul, hbm) is the unexplained residual; "
-            "host_sync_frac is the per-step-synced protocol's host share "
-            "(host_sync / step_time_synced_ms) — the extra cost a caller "
-            "pays for fetching metrics every step"
-        ),
-    }
 
 
 def gang_bench() -> dict:
@@ -436,9 +20,8 @@ def gang_bench() -> dict:
     intertoken latency on a 2-worker CPU-gloo gang, swept over the
     decode-throughput knobs (``decode_steps`` × ``decode_runahead``).
 
-    A host measurement, behind its own flag (``--gang``) and never part of
-    ``main()``: it starts CPU child workers, and a parent that already holds
-    the chip must not. The gang's decode cost here is actor-RPC-bound: the
+    A host measurement, behind its own flag (``--gang``): it starts CPU child
+    workers. The gang's decode cost here is actor-RPC-bound: the
     quantity under test is how well multi-step + run-ahead amortize the
     per-plan round trip. One gang serves the whole sweep — the knobs are
     host-side (workers jit-specialize per decode_steps), so rows differ
@@ -582,92 +165,6 @@ def gang_bench() -> dict:
         if gang is not None:
             gang.shutdown()
         ray_tpu.shutdown()
-    return out
-
-
-def ttft_tradeoff_sweep(headline: Optional[dict] = None) -> list:
-    """The prefill/decode interleave knob (EngineConfig.decode_steps):
-    each admission chunk waits behind one K-step decode program, so small K
-    cuts TTFT and large K amortizes the per-program host round trip for
-    throughput. Publishes the measured curve (VERDICT r4 weak #2: expose
-    the knob and the tradeoff instead of a single throughput-tuned point).
-
-    The throughput-tuned point comes from the main decode bench
-    (``headline``); only the latency-leaning engine is built here — two
-    simultaneous-lifetime 3B engines would exhaust the 16 GiB chip."""
-    import gc
-
-    import jax
-
-    from ray_tpu.llm import EngineConfig, JaxEngine, LLMConfig, ModelConfig
-    from ray_tpu.llm.config import SamplingParams
-
-    # drop the previous engine's cached executables (they pin device
-    # buffers; a fresh 3B engine next to them OOMs)
-    jax.clear_caches()
-    gc.collect()
-
-    model_id, seqs, seq_len, gen_tokens = "llama3.2-3b", 16, 1024, 64
-    sweep = (2,)
-    out = []
-    if headline is not None and "ttft_ms_mean" in headline:
-        out.append(
-            {
-                "decode_steps": 8,
-                "ttft_ms_mean": headline["ttft_ms_mean"],
-                "ttft_ms_p99": headline.get("ttft_ms_p99"),
-                "tokens_per_sec_incl_prefill": headline.get(
-                    "decode_tokens_per_sec_incl_prefill"
-                ),
-            }
-        )
-    prompt = "benchmark prompt: the quick brown fox jumps. " * 2
-    for ds in sweep:
-        gc.collect()
-        engine = JaxEngine(
-            LLMConfig(
-                model=ModelConfig(model_id=model_id, tokenizer="byte", seed=0),
-                engine=EngineConfig(
-                    max_num_seqs=seqs,
-                    max_seq_len=seq_len,
-                    prefill_buckets=(32, 64, 128, 256, 512, 1024),
-                    decode_steps=ds,
-                    decode_runahead=1,
-                    prefill_chunk=256,
-                ),
-            )
-        )
-        try:
-            sp = SamplingParams(
-                max_tokens=gen_tokens, temperature=0.0, ignore_eos=True
-            )
-            engine.generate(prompt, sampling_params=sp)
-            engine.generate("request w: " * 4 + prompt, sampling_params=sp)
-            t0 = time.perf_counter()
-            reqs = [
-                engine.submit(f"sweep{ds} {i}: " * 4 + prompt, sampling_params=sp)
-                for i in range(seqs)
-            ]
-            for r in reqs:
-                r.done.wait()
-            dt = time.perf_counter() - t0
-            import numpy as _np
-
-            ttfts = [r.first_token_t - r.submitted_t for r in reqs]
-            out.append(
-                {
-                    "decode_steps": ds,
-                    "ttft_ms_mean": round(1e3 * float(_np.mean(ttfts)), 1),
-                    "ttft_ms_p99": round(
-                        1e3 * float(_np.percentile(ttfts, 99)), 1
-                    ),
-                    "tokens_per_sec_incl_prefill": round(
-                        sum(len(r.out_tokens) for r in reqs) / dt, 1
-                    ),
-                }
-            )
-        finally:
-            engine.shutdown()
     return out
 
 
@@ -1047,5 +544,7 @@ if __name__ == "__main__":
         # gang serving knobs on a 2-worker CPU gang: a host measurement
         print(json.dumps({"gang": gang_bench()}))
         sys.exit(0)
-    # a phase that raises ends the run with a traceback and a nonzero code
-    main()
+    sys.exit("bench.py holds the host-side probes (--check-floor, --gang, --serve-ladder, "
+             "--actor-creation, --fairshare, --observability, --recovery, --reconstruction, "
+             "--transfer); the chip's benchmark is "
+             "python3 benchmark/run.py --workload <a cell of BENCHMARK.json> --seed 0")

@@ -1,7 +1,8 @@
 """Where JAX's persistent compilation cache lives.
 
 Every process that may compile for the chip calls ``configure()`` before its
-first compile (``worker_main`` for workers granted ``TPU``, ``bench.py``).
+first compile (``worker_main`` for workers granted ``TPU``, the benchmark's
+tools).
 The directory is part of the cache key, so it is never a temporary name, a
 pid or a time: it is ``JAX_COMPILATION_CACHE_DIR`` where that is set (JAX
 reads it itself; nothing here sets another), else ``<checkout>/.jax_cache``.
@@ -12,6 +13,7 @@ from __future__ import annotations
 import contextlib
 import os
 import threading
+from typing import Optional
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
@@ -85,6 +87,32 @@ def bypassed():
             _bypass_depth -= 1
             if _bypass_depth == 0:
                 _set_cache_enabled(_bypass_was)
+
+
+def directory() -> Optional[str]:
+    """Where JAX keeps what it compiles, or None where it keeps nothing: no
+    directory is set (a process that neither called ``configure()`` nor was
+    given ``JAX_COMPILATION_CACHE_DIR``), the cache is disabled, or the
+    caller is inside ``bypassed()``."""
+    import jax
+
+    path = jax.config.jax_compilation_cache_dir
+    return path if path and jax.config.jax_enable_compilation_cache else None
+
+
+def forget_traces() -> bool:
+    """Drop what JAX has traced and compiled in this process, where a
+    compile cache is in use (``directory()``), and say whether it did. The
+    cache's key holds the source lines of whichever call site traced a shared
+    function first, so what a process has traced so far decides the key of
+    what it compiles next (``llm/engine.py _warm_programs``); with no cache
+    no key is looked up and nothing is dropped."""
+    import jax
+
+    if directory() is None:
+        return False
+    jax.clear_caches()
+    return True
 
 
 def entry_count() -> int:

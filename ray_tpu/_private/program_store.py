@@ -38,12 +38,10 @@ _JAX_FLAGS = ("jax_enable_x64", "jax_default_matmul_precision", "jax_default_prn
 
 def directory() -> Optional[str]:
     """Where programs are kept, or None where the compile cache is not in use."""
-    import jax
+    from ray_tpu._private import jax_cache
 
-    path = jax.config.jax_compilation_cache_dir
-    if not path or not jax.config.jax_enable_compilation_cache:
-        return None
-    return os.path.join(path, "programs")
+    path = jax_cache.directory()
+    return path and os.path.join(path, "programs")
 
 
 @functools.lru_cache(maxsize=4)

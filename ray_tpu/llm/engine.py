@@ -951,14 +951,14 @@ class JaxEngine:
             lambda keys, slot, key: keys.at[slot].set(key), donate_argnums=(0,)
         )
         self._rng_key = jax.random.PRNGKey(self.config.model.seed)
-        # The forms of those programs the loop launches (``_launch``): where
-        # the compile cache is in use, an engine of one device runs each as an
-        # executable restored from ``_private/program_store.py``, or compiled
-        # ahead of time and kept there for the next start; anywhere else the
-        # ``jit``s above run as they are. Over a mesh they stay: no cell runs
-        # one and nothing could check it.
+        # The forms of those programs the loop launches (``_launch``): an engine
+        # of one device runs each as an executable, restored from
+        # ``_private/program_store.py`` or lowered and compiled ahead of time
+        # and offered to it for the next start (whether anything is kept is
+        # the store's to say). Over a mesh the ``jit``s above run as they
+        # are: an executable is loaded for one device, no cell runs a mesh
+        # and nothing could check one there (ROADMAP D17).
         self._device = jax.local_devices()[0]
-        self._restores = not self._spans_devices() and program_store.directory() is not None
         self._programs: dict = {}
         self._program_counts = {"restored": 0, "compiled": 0, "fallback": 0}
         # what shaped the programs beside their arguments
@@ -970,12 +970,13 @@ class JaxEngine:
         """Run the program ``form`` names (the program, its pool's stripe
         length and whatever else tells two compilations of it apart; its
         static arguments, ``static``, among them: an executable takes none)
-        on ``args``. With ``_restores`` that is the form's executable, loaded
-        or compiled on its first launch (``_program``); one that refuses its
-        arguments (it does so before anything runs or is donated) gives way
-        to the ``jit`` for good, as does every form of an engine without
-        ``_restores``: that call is the parent's to the letter."""
-        if not self._restores:
+        on ``args``: the form's executable, loaded or compiled on its first
+        launch (``_program``). Two calls of the ``jit`` stay, each the only
+        way on its input: an engine that spans devices has no executables
+        (``_compile``), and an executable that refuses its arguments (it does
+        so before anything runs or is donated) gives way to the ``jit`` for
+        good."""
+        if self._spans_devices():
             return getattr(self, jit_name)(*args, *static, **kwargs)
         program = self._programs.get(form)
         if program is None:
@@ -990,21 +991,18 @@ class JaxEngine:
             logger.warning("program %s refused its arguments and gives way to its jit: %s",
                            form, str(e).splitlines()[0])
             self._program_counts["fallback"] += 1
-            self._programs[form] = program = self._jit_of(jit_name, static)
+            jitted = getattr(self, jit_name)
+            self._programs[form] = program = lambda *a, **kw: jitted(*a, *static, **kw)
             return program(*args, **kwargs)
-
-    def _jit_of(self, jit_name: str, static: tuple) -> Callable:
-        jitted = getattr(self, jit_name)
-        return lambda *args, **kwargs: jitted(*args, *static, **kwargs)
 
     def _program(self, form: tuple, jit_name: str, args: tuple, static: tuple, kwargs: dict):
         """The executable of ``form`` for arguments like ``args``: the store's,
         found by a key that nothing is traced for, else lowered and compiled
-        here (through the compile cache) and written for the next start. A
-        file that cannot be loaded counts as a fallback and is written anew.
-        Inside ``jax_cache.bypassed()`` the ``jit`` runs and nothing is kept."""
-        if program_store.directory() is None:
-            return self._jit_of(jit_name, static)
+        here (through the compile cache) and offered to the store for the
+        next start. A store with nowhere to keep programs (no cache
+        directory, or inside ``jax_cache.bypassed()``) finds none and keeps
+        none: the executable runs all the same. A file that cannot be loaded
+        counts as a fallback and is written anew."""
         name = ":".join(map(str, form))
         t = time.perf_counter()
         key = program_store.key(name, args, kwargs, _DONATED[jit_name], self._program_context)
@@ -1050,9 +1048,9 @@ class JaxEngine:
         return (chunk if 0 < chunk < longest else None), finals
 
     def _warm_programs(self) -> None:
-        """``_warm_pass``, and with ``_restores`` what keeps a start that
-        compiled its forms and one that restored them alike to everything
-        compiled after them. The compile cache's key holds names and source
+        """``_warm_pass``, and where the compile cache is in use what keeps a
+        start that compiled its forms and one that restored them alike to
+        everything compiled after them. The compile cache's key holds names and source
         lines (``jax_cache.configure``), and a function that JAX traces once
         and reuses (``jnp.take``, the samplers' helpers: every inner ``jit``)
         keeps the lines of the call site that traced it first. A start that
@@ -1064,18 +1062,21 @@ class JaxEngine:
         kinds of start drop JAX's trace caches before the pass, and one that
         compiled drops them again and runs the pass once more, on its
         executables: after either, the caches hold what one pass over
-        executables leaves."""
-        if not self._restores:
-            return self._warm_pass()
-        import jax
+        executables leaves. Whether a compile cache is there for a key to
+        matter to is ``jax_cache``'s to say (``forget_traces``): without one
+        every start compiles and one pass is all, as it is over a mesh, whose
+        ``jit``s no start restores."""
+        from ray_tpu._private import jax_cache
 
-        jax.clear_caches()
-        self._warm_pass()
-        if self._program_counts["compiled"] or self._program_counts["fallback"]:
-            jax.clear_caches()
-            self._warm_pass()
+        mark = time.perf_counter()  # the dropping is booked to the pass's first program
+        dropped = not self._spans_devices() and jax_cache.forget_traces()
+        self._warm_pass(mark)
+        if dropped and (self._program_counts["compiled"] or self._program_counts["fallback"]):
+            mark = time.perf_counter()
+            jax_cache.forget_traces()
+            self._warm_pass(mark)
 
-    def _warm_pass(self) -> None:
+    def _warm_pass(self, mark: float) -> None:
         """Run every program the loop can launch once, on throwaway rows,
         before the loop takes requests: a pool at a time, the middle chunk at
         every row count, each final width, the prefix store's cuts and seeds,
@@ -1089,12 +1090,11 @@ class JaxEngine:
         which holds no request and which an admission overwrites whole; a
         pool that ``carries`` hands its chunk programs its decode rows as the
         loop does, none of them live. Each program is waited for where it was
-        run, so that ``_warm_s`` holds the seconds by program (a pool's own
-        set-up goes to its first)."""
+        run, so that ``_warm_s`` holds the seconds by program since ``mark``
+        (a pool's own set-up goes to its first)."""
         import jax
         import jax.numpy as jnp
 
-        mark = time.perf_counter()
         _listen_to_jax()
         _phases.into, _phases.traced = phases, traced = {}, []
 
@@ -1112,7 +1112,7 @@ class JaxEngine:
 
         # an executable takes an array committed or not alike, so its one run
         # is every kind's; a ``jit`` compiles once for each kind it is handed
-        kinds = 1 if self._restores else 2
+        kinds = 2 if self._spans_devices() else 1
         rng_key = self._rng_key
         jax.random.PRNGKey(0)  # a seeded request's key is a program too
         for i, pool in enumerate(self._pools):

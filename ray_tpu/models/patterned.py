@@ -1298,7 +1298,7 @@ def reads_blocks(stripe: int, *arrays, latent: bool = False) -> bool:
     is one device. What a type does not carry cannot be seen here: an
     uncommitted argument that only a ``jit``'s ``in_shardings`` spreads over
     a mesh reads as one device (no caller in this repo places its arrays
-    so; ``tests/test_patterned.py`` traces the ways they do)."""
+    so; ``tests/test_patterned_stack.py`` traces the ways they do)."""
     if block_size(stripe, latent) is None:
         return False
     if not latent and not takes_heads_of(arrays[0]):

@@ -56,7 +56,7 @@ Two design decisions, both about float32's range and digits:
   ``exp(G_t - G_e)`` and ``exp(G_e - G_s)`` both have exponents <= 0 whatever
   the decays are, so neither overflows and one that underflows bounds a weight
   that is as small: the bound is 1, not a reckoning of the seeds
-  (``tests/test_kda.py`` runs decays of -40 a token). A pair in the same
+  (``tests/test_kda_forms.py`` runs decays of -40 a token). A pair in the same
   sub-block takes the same rule on the sub-block, 64 -> 16 -> 4 tokens, and
   inside ``_PAIR_BASE`` (4) the difference itself, ``exp(G_t - G_s)`` a channel
   (an elementwise product and a sum over K: a sixteenth of all pairs).
@@ -66,7 +66,7 @@ Two design decisions, both about float32's range and digits:
   ``a^k C(n - 2, k - 1)`` for keys that are alike (``a = beta cos``) and
   cancel to a result of order one: over a chunk of 64 that costs float32 five
   digits at ``a = 0.3``, and over a block of 16 all seven at ``a = 2``
-  (``tests/test_kda.py``: keys nearly the same, writing strengths near 2).
+  (``tests/test_kda_forms.py``: keys nearly the same, writing strengths near 2).
   So the diagonal blocks of ``_SOLVE_BASE`` (16) are inverted by forward
   substitution, a row at a time (16 elementwise steps on every block of the
   launch at once: nothing beside the rest), and the blocks are merged by block

@@ -110,6 +110,28 @@ def pytest_runtest_protocol(item, nextitem):
         signal.signal(signal.SIGALRM, old)
 
 
+# ``--dist loadfile`` hands files out from a queue, and a long file handed out
+# last is the run's tail. xdist orders that queue by a file's number of tests,
+# most first, which puts a file of two long cases at the very end (PR 47:
+# ``test_ssm_rows.py`` began 800 s into a run and ended it at 1,094 s). So the
+# queue keeps collection order, and the files that cannot be cut under 150 s
+# of cases (one parametrised test each, or one fixture's cases) are collected
+# first, one to a worker; a name that is gone does nothing.
+_LONGEST_FIRST = (
+    "test_ssm_rows.py", "test_carried_decode.py", "test_patterned_prefill.py",
+    "test_llm_layouts.py", "test_chunk_rows.py", "test_llm_pools.py",
+)
+
+
+def pytest_configure(config):
+    if hasattr(config.option, "loadscopereorder"):  # xdist's, where it is loaded
+        config.option.loadscopereorder = False
+
+
+def pytest_collection_modifyitems(items):
+    items.sort(key=lambda item: item.path.name not in _LONGEST_FIRST)  # stable: files stay whole
+
+
 # Test-run wall-time artifact: every run records its wall time into
 # TEST_RUN.json at the repo root under "last_run"; a run of the FULL fast
 # tier (`-m "not slow"`, no -k narrowing) additionally refreshes the sticky
@@ -127,9 +149,12 @@ def pytest_sessionfinish(session, exitstatus):
     import time
 
     t0 = getattr(session, "_rtpu_t0", None)
-    if t0 is None:
-        return
     cfg = session.config
+    # under xdist the process that started the run writes, once: each worker
+    # runs this hook too, with its own share of the outcomes, and the tracked
+    # file was whichever worker's ended last
+    if t0 is None or hasattr(cfg, "workerinput"):
+        return
     # the terminal reporter's stats fill incrementally as tests finish, so
     # they are complete here even though its summary prints later
     tr = cfg.pluginmanager.get_plugin("terminalreporter")

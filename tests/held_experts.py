@@ -1,8 +1,9 @@
 """An expert layer that holds a share of its experts, under routings made to
-order: what ``tests/test_kda.py`` and ``tests/test_ssm.py`` share to hold the
-block form of ``models/patterned.py _moe_decode_ffn`` against the form that
-works on every assignment (the one a model that holds all its experts keeps,
-which is the parent's program operation for operation)."""
+order: what ``tests/test_kda_shares.py`` and ``tests/test_ssm_shares.py``
+share to hold the block form of ``models/patterned.py _moe_decode_ffn``
+against the form that works on every assignment (the one a model that holds
+all its experts keeps, which is the parent's program operation for
+operation)."""
 
 import dataclasses
 

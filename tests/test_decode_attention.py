@@ -1,7 +1,7 @@
 """``ops/decode_attention.py`` in Pallas interpret mode against the einsum it
 stands in for (``models/patterned.py _grouped_attention``) under the same
 mask, and the host's count of what it reads. Compilation at the serving
-cells' widths for a described v5e is in ``tests/test_chip_compile.py``."""
+cells' widths for a described v5e is in ``tests/test_chip_compile_kernels.py``."""
 
 import jax
 import jax.numpy as jnp

@@ -1,0 +1,60 @@
+"""Granite-4.0-H at test size in the engine (``llm/engine.py``): its greedy
+tokens against the reference's, and the state a slot holds. The model and its
+path through the cache: ``tests/test_granite.py``."""
+
+import jax
+import numpy as np
+import pytest
+
+from ray_tpu.llm import EngineConfig, JaxEngine, LLMConfig, ModelConfig, SamplingParams
+from tests.test_granite import PUBLISHED
+
+
+@pytest.fixture(scope="module")
+def engine():
+    eng = JaxEngine(LLMConfig(
+        model=ModelConfig(model_id="granite-tiny"),
+        engine=EngineConfig(max_num_seqs=3, max_seq_len=64, dtype="float32",
+                            prefill_buckets=(8, 16, 32), prefill_chunk=8),
+    ))
+    yield eng
+    eng.shutdown()
+
+
+SP = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
+
+
+def _prompt(seed, n):
+    return [int(t) for t in np.random.default_rng(seed).integers(0, 256, n)]
+
+
+def _greedy_by_the_reference(engine, prompt, out):
+    from benchmark.reference_ssm_gqa_dense import Reference
+
+    ref = Reference(PUBLISHED, jax.local_devices()[:1])
+    row = np.asarray(prompt + out[:-1], np.int32)
+    logits = ref.forward_rows(engine.params, [row], last=len(out))["logits"][0]
+    return np.argmax(logits, -1).tolist()
+
+
+def test_the_engines_greedy_tokens_are_the_references(engine):
+    """Five prompts at once on three slots (middle chunks as rows of one
+    launch, batched decode steps, two waiting for a slot): every answer is the
+    reference's greedy one, teacher-forced on the engine's own tokens."""
+    prompts = [_prompt(10 + i, n) for i, n in enumerate((29, 27, 30, 12, 25))]
+    reqs = [engine.submit(prompt_token_ids=p, sampling_params=SP) for p in prompts]
+    for req in reqs:
+        engine._await_done(req)
+        assert req.error is None
+    for p, req in zip(prompts, reqs):
+        assert list(req.out_tokens) == _greedy_by_the_reference(engine, p, list(req.out_tokens))
+
+
+def test_the_engine_counts_the_state_a_slot_holds(engine):
+    stats = engine.get_stats()
+    (pool,) = stats["pools"]
+    # 6 mamba layers: a float32 state [8, 16, 16] and 3 inputs of 160 channels
+    assert pool["state_bytes_per_slot"] == 6 * (8 * 16 * 16 * 4 + 3 * 160 * 4)
+    assert pool["state_mixer_forms"] == {"ssm": {"chunk": "plain", "step": "plain"}}
+    # keys and values of the two attention layers: 2 heads of 16, float32
+    assert pool["kv_bytes_per_token"] == 2 * 2 * 2 * 16 * 4

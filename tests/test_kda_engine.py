@@ -3,14 +3,15 @@ cell serves, at test size) through ``JaxEngine``: answers against the
 benchmark's plain reference, a slot's second tenant and a fresh engine, rows
 of one launch, the state a slot holds and the assignments held as the engine
 counts them, and the paths with their own cache programs refusing the model by
-name. The operation, the model and the programs: ``tests/test_kda.py``."""
+name. The operation, the model and the programs: ``tests/test_kda.py`` and its
+parts."""
 
 import jax
 import numpy as np
 import pytest
 
 from ray_tpu.llm import EngineConfig, JaxEngine, LLMConfig, ModelConfig, SamplingParams
-from tests.test_kda import PUBLISHED
+from tests.kda_models import PUBLISHED
 
 @pytest.fixture(scope="module")
 def engine():

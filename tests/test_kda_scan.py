@@ -7,7 +7,7 @@ carried in from an earlier launch, rows of two lengths in one launch and a
 length that is no whole number of chunks; that float32 at the highest precision
 is what the tolerances hold; and that a shape which does not tile keeps the
 plain form and says so. The model's path through the cache and the step's
-kernel: ``tests/test_kda.py``."""
+kernel: ``tests/test_kda.py`` and its parts."""
 
 import functools
 
@@ -40,7 +40,7 @@ def STEPS(state, q, k, v, g, beta):
 
 
 def _inputs(T, seed=0, rate=1.0, beta_shift=0.0, alike=False, b=B):
-    """Operands as ``tests/test_kda.py _kda_inputs`` draws them: unit keys,
+    """Operands as ``tests/kda_models.py _kda_inputs`` draws them: unit keys,
     queries times K ** -0.5, log-decays log-uniform down to ``-rate`` a token,
     writing strengths 2 sigmoid(. + ``beta_shift``), a state that is not zero;
     ``alike``: every key within 0.05 of the first, and hardly a decay."""
@@ -62,7 +62,7 @@ def test_the_shapes_that_tile_take_the_kernel_and_the_others_the_plain_form():
     chunks lie side by side on the lanes), an odd pair count one set, the tiny
     preset's 16 x 16 state and its chunks of 8 none; and ``get_stats()`` hands
     out what ``state_mixer_forms`` says (``tests/test_kda_engine.py``,
-    ``tests/test_ssm.py`` read it off an engine)."""
+    ``tests/test_ssm_engine.py`` read it off an engine)."""
     assert kda.scan_heads(64, 128, 128, 64) == 4
     assert kda.scan_heads(6, 128, 128, 64) == 2
     assert kda.scan_heads(16, 128, 256, 32) == 8 and kda.scan_heads(8, 128, 128, 128) == 2
@@ -103,7 +103,7 @@ def test_the_shapes_that_tile_take_the_kernel_and_the_others_the_plain_form():
 def test_the_kernel_equals_the_plain_form_and_the_step_token_by_token(T, rate, beta_shift, alike):
     """From a state that is not zero: outputs and the state after the last
     token, against the plain form at float32's level and against ``kda_step``
-    at the tolerances ``tests/test_kda.py`` holds the plain form to."""
+    at the tolerances ``tests/test_kda_forms.py`` holds the plain form to."""
     args = _inputs(T, seed=T + int(10 * rate), rate=rate, beta_shift=beta_shift, alike=alike)
     got_o, got_s = KERNEL(*args)
     assert np.isfinite(np.asarray(got_o)).all() and np.isfinite(np.asarray(got_s)).all()

@@ -8,7 +8,6 @@ on mocked/virtual hardware (SURVEY §4).
 
 import threading
 
-import numpy as np
 import pytest
 
 from ray_tpu.llm import (
@@ -184,293 +183,6 @@ def test_llm_server_openai_shapes(engine):
     assert chat["choices"][0]["message"]["role"] == "assistant"
 
 
-def test_batch_processor(ray_start_thread):
-    from ray_tpu import data as rd
-    from ray_tpu.llm import ProcessorConfig, build_llm_processor
-
-    cfg = LLMConfig(
-        model=ModelConfig(model_id="tiny", tokenizer="byte"),
-        engine=EngineConfig(max_num_seqs=4, max_seq_len=64, prefill_buckets=(16, 32, 64)),
-    )
-    proc = build_llm_processor(
-        ProcessorConfig(
-            llm_config=cfg,
-            batch_size=4,
-            sampling_params={"max_tokens": 3, "temperature": 0.0, "ignore_eos": True},
-        )
-    )
-    ds = rd.from_items([{"prompt": f"p{i}"} for i in range(8)], parallelism=2)
-    rows = proc(ds).take_all()
-    assert len(rows) == 8
-    assert all(isinstance(r["generated_text"], str) for r in rows)
-
-
-def test_prefill_decode_disagg(ray_start_thread):
-    """Disagg path must produce the same greedy tokens as the unified engine."""
-    from ray_tpu import serve
-    from ray_tpu.llm import build_pd_disagg_app
-
-    cfg = LLMConfig(
-        model=ModelConfig(model_id="tiny", tokenizer="byte", seed=0),
-        engine=EngineConfig(max_num_seqs=2, max_seq_len=64, prefill_buckets=(16, 32, 64)),
-    )
-    app = build_pd_disagg_app(cfg)
-    handle = serve.run(app, name="pd")
-    out = handle.remote({"prompt": "abc", "max_tokens": 5}).result(timeout_s=300)
-    assert out["num_tokens"] == 5
-
-    # unified engine reference for the same model/prompt
-    eng = JaxEngine(cfg)
-    ref = eng.generate(
-        "abc", sampling_params=SamplingParams(max_tokens=5, temperature=0.0, ignore_eos=True)
-    )
-    eng.shutdown()
-    assert out["text"] == eng.tokenizer.decode(ref.token_ids)
-    serve.shutdown()
-
-
-def test_openai_router_routing():
-    from ray_tpu.llm.openai_api import OpenAIRouter
-    from ray_tpu.serve.proxy import Request
-
-    class FakeHandle:
-        class chat:
-            @staticmethod
-            def remote(body):
-                class R:
-                    @staticmethod
-                    def result(timeout_s=None):
-                        return {"ok": True, "got": body["model"]}
-
-                return R()
-
-    router = OpenAIRouter(m1=FakeHandle())
-    req = Request("GET", "/v1/models", {}, {}, b"")
-    out = router(req)
-    assert out["data"][0]["id"] == "m1"
-    req = Request(
-        "POST", "/v1/chat/completions", {}, {}, b'{"model": "m1", "messages": []}'
-    )
-    assert router(req)["ok"] is True
-    req = Request("POST", "/v1/chat/completions", {}, {}, b'{"model": "nope"}')
-    assert router(req)["error"]["code"] == 404
-
-
-def test_openai_sse_end_to_end(ray_start_thread):
-    """``stream: true`` through app → router → LLMServer → proxy as SSE
-    (reference: the OpenAI router's StreamingResponse path)."""
-    import json
-    import time
-    import urllib.request
-
-    from ray_tpu import serve
-    from ray_tpu.llm import build_openai_app
-
-    cfg = LLMConfig(
-        model=ModelConfig(model_id="tiny", tokenizer="byte", seed=0),
-        engine=EngineConfig(
-            max_num_seqs=2, max_seq_len=64, prefill_buckets=(16, 32, 64)
-        ),
-    )
-    serve.run(build_openai_app(cfg), name="llm-app", route_prefix="/")
-    _, port = serve.start_proxy(port=0)
-    deadline = time.time() + 30
-    while time.time() < deadline:
-        try:
-            with urllib.request.urlopen(
-                f"http://127.0.0.1:{port}/-/routes", timeout=5
-            ) as r:
-                if "/" in json.loads(r.read()):
-                    break
-        except Exception:
-            pass
-        time.sleep(0.2)
-    body = json.dumps(
-        {
-            "model": cfg.served_name,
-            "messages": [{"role": "user", "content": "hi"}],
-            "max_tokens": 4,
-            "stream": True,
-        }
-    ).encode()
-    req = urllib.request.Request(
-        f"http://127.0.0.1:{port}/v1/chat/completions",
-        data=body,
-        headers={"Content-Type": "application/json"},
-    )
-    with urllib.request.urlopen(req, timeout=300) as r:
-        assert r.headers.get("Content-Type") == "text/event-stream"
-        raw = r.read().decode()
-    events = [e for e in raw.split("\n\n") if e.startswith("data: ")]
-    assert events[-1] == "data: [DONE]"
-    chunks = [json.loads(e[len("data: ") :]) for e in events[:-1]]
-    assert all(c["object"] == "chat.completion.chunk" for c in chunks)
-    assert chunks[0]["choices"][0]["delta"].get("role") == "assistant"
-    assert chunks[-1]["choices"][0]["finish_reason"] in ("stop", "length")
-    # token deltas (all but the final finish chunk) are non-empty text
-    assert sum(len(c["choices"][0]["delta"].get("content", "")) for c in chunks) > 0
-    serve.shutdown()
-
-
-def test_multi_lora_engine():
-    """Stacked multi-LoRA: adapters change outputs per request within one
-    compiled program; the base slot stays bit-identical to a no-LoRA engine."""
-    import numpy as np
-
-    from ray_tpu.models.llama import init_lora_stack
-
-    cfg = LLMConfig(
-        model=ModelConfig(model_id="tiny", tokenizer="byte", seed=0),
-        engine=EngineConfig(
-            max_num_seqs=2, max_seq_len=64, prefill_buckets=(16, 32, 64),
-            max_loras=2, lora_rank=4,
-        ),
-    )
-    eng = JaxEngine(cfg)
-    p = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
-    base_out = eng.generate("hello world", sampling_params=p)
-
-    # a zero adapter must not change anything
-    zero = {
-        k: np.zeros(v.shape[:1] + v.shape[2:], np.float32)
-        for k, v in eng.loras.items()
-    }
-    eng.add_lora("zero", zero)
-    out_zero = eng.generate("hello world", sampling_params=p, lora="zero")
-    assert out_zero.token_ids == base_out.token_ids
-
-    # a random adapter must change the continuation
-    rng = np.random.default_rng(0)
-    rand = {
-        k: rng.normal(scale=0.5, size=v.shape[:1] + v.shape[2:]).astype(np.float32)
-        for k, v in eng.loras.items()
-    }
-    eng.add_lora("rand", rand)
-    out_rand = eng.generate("hello world", sampling_params=p, lora="rand")
-    assert out_rand.token_ids != base_out.token_ids
-
-    # base requests are unaffected by loaded adapters
-    again = eng.generate("hello world", sampling_params=p)
-    assert again.token_ids == base_out.token_ids
-
-    assert eng.list_loras() == ["rand", "zero"]
-    with pytest.raises(KeyError):
-        eng.generate("x", sampling_params=p, lora="nope")
-    with pytest.raises(RuntimeError):  # both slots in use
-        eng.add_lora("third", zero)
-    eng.remove_lora("zero")
-    eng.add_lora("third", zero)  # freed slot is reusable
-    eng.shutdown()
-
-    # no-LoRA engine agrees with the base path of the LoRA engine
-    cfg0 = LLMConfig(
-        model=ModelConfig(model_id="tiny", tokenizer="byte", seed=0),
-        engine=EngineConfig(
-            max_num_seqs=2, max_seq_len=64, prefill_buckets=(16, 32, 64)
-        ),
-    )
-    eng0 = JaxEngine(cfg0)
-    ref = eng0.generate("hello world", sampling_params=p)
-    eng0.shutdown()
-    assert ref.token_ids == base_out.token_ids
-
-
-def test_multi_lora_batched_mixed_adapters():
-    """Concurrent requests with DIFFERENT adapters share decode steps and
-    still match their sequential per-adapter results."""
-    import numpy as np
-
-    cfg = LLMConfig(
-        model=ModelConfig(model_id="tiny", tokenizer="byte", seed=0),
-        engine=EngineConfig(
-            max_num_seqs=4, max_seq_len=64, prefill_buckets=(16, 32, 64),
-            max_loras=2, lora_rank=4,
-        ),
-    )
-    eng = JaxEngine(cfg)
-    rng = np.random.default_rng(1)
-    for name in ("a", "b"):
-        eng.add_lora(
-            name,
-            {
-                k: rng.normal(scale=0.5, size=v.shape[:1] + v.shape[2:]).astype(
-                    np.float32
-                )
-                for k, v in eng.loras.items()
-            },
-        )
-    p = SamplingParams(max_tokens=5, temperature=0.0, ignore_eos=True)
-    # sequential references
-    ref_a = eng.generate("prompt one", sampling_params=p, lora="a").token_ids
-    ref_b = eng.generate("prompt two", sampling_params=p, lora="b").token_ids
-    ref_0 = eng.generate("prompt three", sampling_params=p).token_ids
-    # concurrent mixed batch
-    r1 = eng.submit("prompt one", sampling_params=p, lora="a")
-    r2 = eng.submit("prompt two", sampling_params=p, lora="b")
-    r3 = eng.submit("prompt three", sampling_params=p)
-    for r in (r1, r2, r3):
-        r.done.wait(timeout=120)
-    assert r1.out_tokens == ref_a
-    assert r2.out_tokens == ref_b
-    assert r3.out_tokens == ref_0
-    assert ref_a != ref_b
-    eng.shutdown()
-
-
-def test_lora_openai_model_id_routing(ray_start_thread):
-    """model='<base>:<adapter>' routes to the base deployment and applies
-    the adapter (reference: serve LoRA model-id convention)."""
-    import numpy as np
-
-    from ray_tpu import serve
-    from ray_tpu.llm import build_openai_app
-    from ray_tpu.serve.proxy import Request
-
-    cfg = LLMConfig(
-        model=ModelConfig(model_id="tiny", tokenizer="byte", seed=0),
-        engine=EngineConfig(
-            max_num_seqs=2, max_seq_len=64, prefill_buckets=(16, 32, 64),
-            max_loras=1, lora_rank=4,
-        ),
-    )
-    handle = serve.run(build_openai_app(cfg), name="lora-app", route_prefix="/")
-    # load an adapter on the replica dynamically
-    llm_handle = serve.get_deployment_handle(f"llm:{cfg.served_name}")
-    from ray_tpu.models.llama import LlamaConfig
-
-    L, e, r = 2, 64, 4  # tiny config dims
-    tiny = LlamaConfig.tiny(max_seq_len=64)
-    rng = np.random.default_rng(2)
-    adapter = {
-        "wq_a": rng.normal(scale=0.5, size=(tiny.n_layers, tiny.d_model, 4)).astype(np.float32),
-        "wq_b": rng.normal(scale=0.5, size=(tiny.n_layers, 4, tiny.n_heads, tiny.head_dim)).astype(np.float32),
-        "wv_a": rng.normal(scale=0.5, size=(tiny.n_layers, tiny.d_model, 4)).astype(np.float32),
-        "wv_b": rng.normal(scale=0.5, size=(tiny.n_layers, 4, tiny.n_kv_heads, tiny.head_dim)).astype(np.float32),
-    }
-    assert llm_handle.broadcast("load_lora", "tuned", adapter) == [True]
-
-    import json
-
-    def post(model):
-        body = json.dumps(
-            {"model": model, "prompt": "abc", "max_tokens": 4}
-        ).encode()
-        return handle.remote(
-            Request("POST", "/v1/completions", {}, {}, body)
-        ).result(timeout_s=300)
-
-    base = post(cfg.served_name)
-    tuned = post(f"{cfg.served_name}:tuned")
-    assert base["object"] == tuned["object"] == "text_completion"
-    assert base["choices"][0]["text"] != tuned["choices"][0]["text"]
-    missing = post("nope:tuned")
-    assert missing["error"]["code"] == 404
-    # valid base, unknown adapter -> OpenAI-style 404 (not a raw 500)
-    bad_adapter = post(f"{cfg.served_name}:absent")
-    assert bad_adapter["error"]["code"] == 404
-    serve.shutdown()
-
-
 def test_prefix_cache_hit_and_equivalence(engine):
     """Requests sharing a prompt prefix reuse cached KV (hit recorded) and
     produce EXACTLY the same tokens as a cold computation (reference role:
@@ -487,296 +199,3 @@ def test_prefix_cache_hit_and_equivalence(engine):
     # prefix reuse must not change results (greedy)
     assert warm_same.token_ids == cold.token_ids
     assert warm_other.metrics["prefix_hit_tokens"] > 0
-
-
-@pytest.mark.parametrize("dtype, buckets", [
-    ("bfloat16", (128, 256)), ("float32", (128, 256)), ("float32", (32, 128))])
-def test_seq_len_bucket_pools(dtype, buckets):
-    """Stripe pools: short chats run in short-stripe slots; long requests
-    land in the long pool; both produce identical results to a single-pool
-    engine (greedy). At the engine's default bf16 both pools' stripes are
-    whole blocks of ``ops/decode_attention.py``, so every decode step on
-    either side reads through the kernel, which gives a request the same
-    numbers in a stripe of any length. A 32-position stripe keeps the einsum,
-    whose scores are rounded to the model's dtype where the kernel's stay
-    float32: the two forms agree to the token in float32, and in bf16 to
-    rounding (``tests/test_decode_attention.py``)."""
-    short_stripe, long_stripe = buckets
-    common = dict(
-        max_num_seqs=4, max_seq_len=long_stripe, dtype=dtype,
-        prefill_buckets=(16, 32, 64, 128),
-    )
-    base = LLMConfig(
-        model=ModelConfig(model_id="tiny", tokenizer="byte", seed=0),
-        engine=EngineConfig(**common),
-    )
-    pooled = LLMConfig(
-        model=ModelConfig(model_id="tiny", tokenizer="byte", seed=0),
-        engine=EngineConfig(
-            **common, seq_len_buckets=buckets, seqs_per_bucket=(2, 2),
-            enable_prefix_caching=False,
-        ),
-    )
-    e1 = JaxEngine(base)
-    e2 = JaxEngine(pooled)
-    try:
-        sp_short = SamplingParams(max_tokens=6, temperature=0.0)
-        sp_long = SamplingParams(max_tokens=40, temperature=0.0)
-        short_prompt = "hi there"
-        # too long for the short stripe with its 40 new tokens
-        long_prompt = "tell me a long story " * (short_stripe // 21 + 1)
-        assert len(short_prompt) + 6 < short_stripe < len(long_prompt) + 40 < long_stripe
-        r1s = e1.generate(short_prompt, sampling_params=sp_short)
-        r2s = e2.generate(short_prompt, sampling_params=sp_short)
-        assert r1s.token_ids == r2s.token_ids
-        r1l = e1.generate(long_prompt, sampling_params=sp_long)
-        r2l = e2.generate(long_prompt, sampling_params=sp_long)
-        assert r1l.token_ids == r2l.token_ids
-        pools = e2.get_stats()["pools"]
-        assert [p["stripe_len"] for p in pools] == list(buckets)
-    finally:
-        e1.shutdown()
-        e2.shutdown()
-
-
-def test_multi_step_decode_equivalence():
-    """decode_steps=4 (K steps per device program) produces exactly the
-    single-step greedy tokens — only host round trips differ."""
-    one = LLMConfig(
-        model=ModelConfig(model_id="tiny", tokenizer="byte", seed=0),
-        engine=EngineConfig(max_num_seqs=2, max_seq_len=128,
-                            prefill_buckets=(16, 32, 64, 128),
-                            enable_prefix_caching=False),
-    )
-    multi = LLMConfig(
-        model=ModelConfig(model_id="tiny", tokenizer="byte", seed=0),
-        engine=EngineConfig(max_num_seqs=2, max_seq_len=128,
-                            prefill_buckets=(16, 32, 64, 128),
-                            enable_prefix_caching=False, decode_steps=4),
-    )
-    e1, e2 = JaxEngine(one), JaxEngine(multi)
-    try:
-        sp = SamplingParams(max_tokens=11, temperature=0.0, ignore_eos=True)
-        r1 = e1.generate("multi step decode test", sampling_params=sp)
-        r2 = e2.generate("multi step decode test", sampling_params=sp)
-        assert r1.token_ids == r2.token_ids
-        assert len(r2.token_ids) == 11  # max_tokens honored despite K=4
-    finally:
-        e1.shutdown()
-        e2.shutdown()
-
-
-@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-def test_chunked_prefill_to_the_stripes_end_matches_full_forward(dtype):
-    """Prompt chunks go into the scratch stripe as contiguous blocks
-    (``models/patterned.py _write_block``). Through the engine: a 300-token
-    prompt in five chunks; then two prompts behind a 16-token prefix hit, so
-    every chunk starts off the chunk grid and the final one's bucketed width
-    passes the stripe's end (16 + 7 * 64 + 64 > 512), one of them
-    ``stripe_len - 1`` long. Each returns the tokens ``forward`` gives on the
-    same weights; no path but the block write is reachable from the engine's
-    prefill (B = 1, width <= stripe), so there is no fallback to count.
-
-    The first token is the prefill's, whose attention is ``forward``'s
-    einsum: equal to the token at either dtype. The later ones come from
-    decode steps, which read the 512-position stripe through the decode
-    kernel (``ops/decode_attention.py``): its scores stay float32 where
-    ``forward`` rounds them to the model's dtype. In float32 they are
-    ``forward``'s greedy tokens; at the engine's default bf16 each is a token
-    ``forward`` puts within bf16's rounding of its best, given the engine's
-    tokens before it."""
-    import jax.numpy as jnp
-
-    from ray_tpu.models.llama import forward, init_kv_cache, prefill
-
-    stripe = 512
-    eng = JaxEngine(LLMConfig(
-        model=ModelConfig(model_id="tiny", tokenizer="byte", seed=0),
-        engine=EngineConfig(
-            max_num_seqs=2, max_seq_len=stripe, prefill_chunk=64,
-            prefill_buckets=(16, 32, 64, 128), dtype=dtype,
-        ),
-    ))
-    try:
-        rng = np.random.default_rng(27)
-        first = [int(t) for t in rng.integers(1, 250, 300)]
-        # share 16 tokens with `first` and differ at the 17th: a hit at the
-        # 16-token bucket and at no wider one
-        def behind_prefix(n):
-            rest = [int(t) for t in rng.integers(1, 250, n - 16)]
-            rest[0] = (first[16] + 1) % 250 + 1
-            return first[:16] + rest
-
-        plans = [
-            (first, 6, 0, 4),
-            (behind_prefix(stripe - 1), 1, 16, 7),
-            (behind_prefix(500), 8, 16, 7),
-        ]
-        mids = finals = 0
-        for ids, n_new, hit, n_mid in plans:
-            out = eng.generate(
-                prompt_token_ids=ids,
-                sampling_params=SamplingParams(
-                    max_tokens=n_new, temperature=0.0, ignore_eos=True
-                ),
-            )
-            assert out.metrics["prefix_hit_tokens"] == hit
-            # ``forward`` over the prompt and the engine's tokens, one pass:
-            # row n - 1 + i is what it makes of the i-th new token
-            logits = np.asarray(forward(
-                eng.params, jnp.asarray([ids + out.token_ids[:-1]], jnp.int32),
-                eng.model_cfg,
-            )[0, len(ids) - 1:], np.float32)
-            assert len(logits) == len(out.token_ids) == n_new
-            best = logits.argmax(-1)
-            assert out.token_ids[0] == best[0]
-            if dtype == "float32":
-                assert out.token_ids == best.tolist()
-            else:  # bf16 keeps 8 bits: four steps of the largest logit's rounding
-                behind = logits.max(-1) - logits[np.arange(n_new), out.token_ids]
-                assert (behind <= 4 * 2.0**-8 * np.abs(logits).max()).all(), behind
-            # a tiny model's argmax hardly feels a misplaced key: read the
-            # slot's keys and values back, against the prompt in one piece
-            n = len(ids)
-            _, ref = prefill(
-                eng.params, init_kv_cache(eng.model_cfg, 1, stripe),
-                jnp.asarray([ids], jnp.int32), eng.model_cfg,
-            )
-            slot = out.metrics["slot"]
-            for key in ("k", "v"):
-                np.testing.assert_allclose(
-                    np.asarray(eng._pools[0].cache[key][:, slot, :, :n]),
-                    np.asarray(ref[key][:, 0, :, :n]), rtol=2e-2, atol=2e-2,
-                )
-            mids, finals = mids + n_mid, finals + 1
-            chunks = eng.get_stats()["counters"]["prefill_chunks"]
-            assert chunks == {"mid": mids, "final": finals}
-    finally:
-        eng.shutdown()
-
-
-# ---- the engine's own device layout of the attention input projections -----
-
-_HELD = {
-    "dense": (dict(model_id="tiny"), ("wk", "wq", "wv")),
-    "moe": (
-        dict(model_id="tiny", model_kwargs={
-            "moe_experts": 4, "moe_top_k": 2, "moe_capacity_factor": 8.0}),
-        ("wk", "wq", "wv"),
-    ),
-    "laguna-tiny": (dict(model_id="laguna-tiny"), ("wk", "wq_full", "wq_sliding", "wv")),
-    # the head axis sharded over tp: the layout is each shard's
-    "dense-tp2-of-four-devices": (dict(model_id="tiny"), ("wk", "wq", "wv")),
-}
-
-
-def _orders(tree):
-    return {k: tuple(v.format.layout.major_to_minor) for k, v in tree.items()}
-
-
-@pytest.mark.parametrize("kind", sorted(_HELD))
-def test_engine_holds_attention_input_projections_head_major(kind, monkeypatch):
-    """``engine.params`` is the engine's to normalise: the stacked
-    ``[.., e, h, hd]`` leaves lie head-major on the device, everything seen
-    from outside stays, a swap relays and compiles nothing, and the tokens
-    are those of an engine that keeps the default layout."""
-    import jax
-    import jax.monitoring
-    import jax.numpy as jnp
-
-    from ray_tpu.models import llama
-    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
-
-    model, held = _HELD[kind]
-    tp = 2 if "tp2" in kind else 1
-    mesh = build_mesh(MeshSpec(dp=2, tp=2), devices=jax.devices()[:4]) if tp > 1 else None
-    cfg = LLMConfig(
-        model=ModelConfig(tokenizer="byte", seed=3, **model),
-        engine=EngineConfig(
-            max_num_seqs=4, max_seq_len=128, dtype="float32", prefill_chunk=16,
-            prefill_buckets=(8, 16, 32), tensor_parallel_degree=tp,
-            # keys and values another tree wrote would outlive the swap
-            enable_prefix_caching=False),
-    )
-    rng = np.random.default_rng(7)
-    prompts = [[int(t) for t in rng.integers(32, 127, n)] for n in (5, 37)]
-    greedy = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
-
-    def tokens(eng):
-        return [eng.generate(prompt_token_ids=ids, sampling_params=greedy).token_ids
-                for ids in prompts]
-
-    def fresh(seed, eng):
-        return llama.init_params(jax.random.PRNGKey(seed), eng.model_cfg, mesh=mesh)
-
-    compiled = []
-
-    def on_event(event, duration, **kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            compiled.append(str(kw.get("fun_name")))
-
-    eng = JaxEngine(cfg, mesh=mesh)
-    try:
-        made = fresh(3, eng)  # what ``init_params`` gave this engine
-        assert list(eng.params) == list(made)
-        for name, leaf in eng.params.items():
-            want = made[name]
-            assert (leaf.shape, leaf.dtype, leaf.sharding) == (want.shape, want.dtype, want.sharding), name
-            np.testing.assert_array_equal(np.asarray(leaf), np.asarray(want))
-        default = {k: tuple(range(v.ndim)) for k, v in made.items()}
-        assert _orders(made) == default
-        assert _orders(eng.params) == {**default, **dict.fromkeys(held, (0, 2, 1, 3))}
-        assert eng.get_stats()["params_relaid"] == {
-            "leaves": len(held), "bytes": sum(made[k].nbytes for k in held)}
-
-        tokens(eng), tokens(eng)  # every program these prompts use is compiled
-        jax.monitoring.register_event_duration_secs_listener(on_event)
-        try:
-            eng.params = None
-            assert eng.params is None
-            assert eng.get_stats()["params_relaid"] == {"leaves": 0, "bytes": 0}
-            other = fresh(11, eng)
-            eng.params = other
-            # copied, not donated: the caller's tree stays whole, as made
-            assert not any(v.is_deleted() for v in other.values())
-            assert _orders(other) == default
-            # the other leaves are the caller's own buffers, committed where they lie
-
-            def buffers(x):
-                return [shard.data.unsafe_buffer_pointer() for shard in x.addressable_shards]
-
-            assert all(buffers(eng.params[k]) == buffers(v) and eng.params[k].committed
-                       for k, v in other.items() if k not in held)
-            assert _orders(eng.params) == {**default, **dict.fromkeys(held, (0, 2, 1, 3))}
-            swapped = tokens(eng)
-        finally:
-            jax.monitoring.unregister_event_duration_listener(on_event)
-        programs = [n for n in compiled if n in ("decode_fn", "chunk_mid", "chunk_final")]
-        assert not programs, compiled
-
-        ids = jnp.asarray([prompts[1]], jnp.int32)
-        np.testing.assert_allclose(
-            np.asarray(llama.forward(eng.params, ids, eng.model_cfg)),
-            np.asarray(llama.forward(other, ids, eng.model_cfg)),
-            rtol=1e-5, atol=1e-5,
-        )
-        # a restored checkpoint's leaves are the host's: those the rule names
-        # go to the device as ``init_params`` would have placed them
-        eng.params = {k: np.asarray(v) for k, v in fresh(11, eng).items()}
-        for name in held:
-            leaf = eng.params[name]
-            assert tuple(leaf.format.layout.major_to_minor) == (0, 2, 1, 3)
-            assert leaf.sharding == made[name].sharding
-        assert eng.get_stats()["params_relaid"]["leaves"] == len(held)
-    finally:
-        eng.shutdown()
-
-    monkeypatch.setattr(llama, "serving_layouts", lambda names: {})
-    plain = JaxEngine(cfg, mesh=mesh)
-    try:
-        plain.params = fresh(11, plain)
-        assert _orders(plain.params) == default
-        assert plain.get_stats()["params_relaid"] == {"leaves": 0, "bytes": 0}
-        assert tokens(plain) == swapped
-    finally:
-        plain.shutdown()

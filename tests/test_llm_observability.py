@@ -36,6 +36,7 @@ from ray_tpu.models.training import make_train_step
 from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 from ray_tpu.util import metrics as app_metrics
 from ray_tpu.util import tracing
+from tests.engine_helpers import programs_replaced
 
 LAYER = ("embed", "norm", "attn_qkv", "attn_core", "attn_out")
 PROGRAM_SCOPES = {
@@ -294,14 +295,14 @@ def test_relaid_parameter_leaves_are_counted_and_follow_a_swap():
 
 
 @pytest.mark.parametrize("stage", ["admission", "decode"])
-def test_injected_failure_lands_under_its_stage(engine, monkeypatch, stage):
+def test_injected_failure_lands_under_its_stage(engine, stage):
     before = _counters(engine)
 
     def boom(*a, **kw):
         raise RuntimeError(f"injected {stage} failure")
 
-    with monkeypatch.context() as m:
-        m.setattr(engine, "_chunk_final_jit" if stage == "admission" else "_decode", boom)
+    program = "chunk_final" if stage == "admission" else "decode"
+    with programs_replaced(engine, program, lambda inner: boom):
         req = engine.submit(
             "fail me", sampling_params=SamplingParams(max_tokens=4, ignore_eos=True))
         engine._await_done(req)
@@ -455,12 +456,11 @@ def test_a_request_without_a_caller_roots_its_own_trace(engine, ring):
     assert [s for s in spans if s["name"] == "engine.request"][0]["parent_id"] is None
 
 
-def test_a_failed_request_has_the_phases_it_reached(engine, monkeypatch, ring):
+def test_a_failed_request_has_the_phases_it_reached(engine, ring):
     def boom(*a, **kw):
         raise RuntimeError("injected")
 
-    with monkeypatch.context() as m:
-        m.setattr(engine, "_chunk_final_jit", boom)
+    with programs_replaced(engine, "chunk_final", lambda inner: boom):
         req = engine.submit("fail", sampling_params=SamplingParams(max_tokens=2))
         engine._await_done(req)
     spans = {s["name"]: s for s in tracing.get_spans()}

@@ -24,7 +24,6 @@ from ray_tpu.models import (
 from ray_tpu.models.training import make_train_step
 from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 
-
 CFG = LlamaConfig.tiny()
 
 
@@ -73,7 +72,7 @@ def test_ring_attention_model_matches_full():
 
 
 # prefill and decode against ``forward``, and a padded prefill against an
-# unpadded one: cases of the one body's tests in ``tests/test_patterned.py``
+# unpadded one: cases of the one body's tests in ``tests/test_patterned_padded.py``
 
 
 def test_gqa_heads():
@@ -87,38 +86,6 @@ def test_param_count_formula():
     params = init_params(jax.random.PRNGKey(0), CFG)
     actual = sum(int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(params))
     assert actual == CFG.num_params()
-
-
-def test_pipeline_model_matches_sequential():
-    """pp>1 in the FLAGSHIP model: GPipe over the pp mesh axis produces the
-    same hidden states as the plain layer scan (same params)."""
-    from ray_tpu.models.llama import forward_hidden
-
-    cfg = LlamaConfig.tiny(n_layers=4, attention="full")
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    tokens = jnp.asarray(
-        np.random.default_rng(0).integers(0, cfg.vocab_size, (8, 16)), jnp.int32
-    )
-    ref = forward_hidden(params, tokens, cfg)
-    mesh = build_mesh(MeshSpec(dp=2, pp=2, tp=2))
-    out = jax.jit(lambda p, t: forward_hidden(p, t, cfg, mesh))(params, tokens)
-    np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=3e-4)
-
-
-def test_pipeline_train_step():
-    """Full train step through the pipelined model: finite loss, loss moves."""
-    cfg = LlamaConfig.tiny(n_layers=4, attention="full")
-    mesh = build_mesh(MeshSpec(dp=2, pp=2, tp=2))
-    init_fn, step_fn = make_train_step(cfg, mesh)
-    state = init_fn(jax.random.PRNGKey(0))
-    tokens = jnp.asarray(
-        np.random.default_rng(0).integers(0, cfg.vocab_size, (8, 33)), jnp.int32
-    )
-    state, m1 = step_fn(state, {"tokens": tokens})
-    for _ in range(3):
-        state, m2 = step_fn(state, {"tokens": tokens})
-    assert np.isfinite(float(m1["loss"]))
-    assert float(m2["loss"]) < float(m1["loss"])
 
 
 def test_moe_model_ep_mesh_matches_dense_path():
@@ -147,61 +114,6 @@ def test_moe_model_ep_mesh_matches_dense_path():
     # sharded path and of the whole batch on the dense one: the totals
     # differ by that much and no more
     np.testing.assert_allclose(*both(), rtol=5e-3)
-
-
-def test_pipeline_moe_matches_dense_path():
-    """pp×MoE in the FLAGSHIP model (VERDICT r4 missing #6): expert dispatch
-    inside the GPipe stage — the pp2-ep2 loss equals the single-device
-    dense-path evaluation of the same params (generous capacity → no
-    drops on either path)."""
-    cfg = LlamaConfig.tiny(
-        n_layers=4, moe_experts=4, moe_top_k=2, moe_capacity_factor=8.0,
-        moe_aux_weight=0.0,  # aux is a per-microbatch statistic under pp
-    )
-    params = init_params(jax.random.PRNGKey(1), cfg)
-    tokens = jnp.asarray(
-        np.random.default_rng(1).integers(0, cfg.vocab_size, (8, 16)), jnp.int32
-    )
-    dense = loss_fn(params, {"tokens": tokens}, cfg)
-    mesh = build_mesh(MeshSpec(dp=2, pp=2, ep=2))
-    sharded = jax.jit(
-        lambda p, b: loss_fn(p, b, cfg, mesh)
-    )(params, {"tokens": tokens})
-    np.testing.assert_allclose(float(dense), float(sharded), rtol=2e-3)
-
-
-def test_pipeline_moe_train_step_learns():
-    """pp2-ep2 full train step (WITH the aux loss): finite, decreasing."""
-    cfg = LlamaConfig.tiny(n_layers=4, moe_experts=4, moe_top_k=2)
-    mesh = build_mesh(MeshSpec(dp=2, pp=2, ep=2))
-    init_fn, step_fn = make_train_step(cfg, mesh)
-    state = init_fn(jax.random.PRNGKey(0))
-    tokens = jnp.asarray(
-        np.random.default_rng(0).integers(0, cfg.vocab_size, (8, 33)), jnp.int32
-    )
-    state, m1 = step_fn(state, {"tokens": tokens})
-    for _ in range(4):
-        state, m2 = step_fn(state, {"tokens": tokens})
-    assert np.isfinite(float(m1["loss"]))
-    assert float(m2["loss"]) < float(m1["loss"])
-
-
-def test_pipeline_ring_attention_matches_sequential():
-    """pp×ring (VERDICT r4 missing #6): the GPipe stage sees the real mesh,
-    so ring attention's sp collectives run inside the pipeline — hidden
-    states match the unsharded sequential reference."""
-    from ray_tpu.models.llama import forward_hidden
-
-    cfg = LlamaConfig.tiny(n_layers=4, attention="ring")
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    tokens = jnp.asarray(
-        np.random.default_rng(0).integers(0, cfg.vocab_size, (8, 16)), jnp.int32
-    )
-    ref_cfg = LlamaConfig.tiny(n_layers=4, attention="full")
-    ref = forward_hidden(params, tokens, ref_cfg)
-    mesh = build_mesh(MeshSpec(dp=2, pp=2, sp=2))
-    out = jax.jit(lambda p, t: forward_hidden(p, t, cfg, mesh))(params, tokens)
-    np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=3e-4)
 
 
 def test_moe_train_step_learns():

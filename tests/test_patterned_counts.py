@@ -1,0 +1,117 @@
+"""What an engine counts of a patterned model's decode steps, each test on
+engines of its own: no routing and no window for a dense model, the positions
+a step reads by the blocks each slot's bounds cover, and the form its decode
+steps take (kernel or einsum; one device, a mesh of one, a mesh of four)."""
+
+import jax
+import numpy as np
+import pytest
+
+from ray_tpu.llm import EngineConfig, JaxEngine, LLMConfig, ModelConfig, SamplingParams
+from tests.patterned_models import _count_kernel_calls
+
+
+def _routing(eng):
+    c = eng.get_stats()["counters"]
+    return {k: dict(c[k]) for k in c if k.startswith("moe_")}, c
+
+
+def test_a_dense_engine_counts_no_routing_and_no_window():
+    eng = JaxEngine(LLMConfig(model=ModelConfig(model_id="tiny", seed=1),
+                              engine=EngineConfig(max_num_seqs=2, max_seq_len=64, dtype="float32")))
+    try:
+        eng.generate("hello there", sampling_params=SamplingParams(max_tokens=4, ignore_eos=True))
+        routing, c = _routing(eng)
+        assert all(v == {"decode": 0, "chunk_mid": 0, "chunk_final": 0} for v in routing.values())
+        # the four routing counts, the assignments held (PR 35) and the blocks (PR 45)
+        assert len(routing) == 6
+        assert c["decode_kv_tokens_window"] == 0 < c["decode_kv_tokens_global"]
+    finally:
+        eng.shutdown()
+
+
+def test_positions_read_counts_the_blocks_each_slots_bounds_cover():
+    """Two requests at once in 256-position stripes, the longer crossing the
+    first block's end while it decodes: ``decode_kv_positions_read`` (and the
+    window layers' ``_window``) are ``ops/decode_attention.py positions_read``
+    summed over the lengths the loop held at each launch, no less than the
+    tokens needed and no more than the active slots' whole stripes."""
+    from ray_tpu.ops.decode_attention import BLOCK, positions_read
+
+    stripe = 2 * BLOCK
+    eng = JaxEngine(LLMConfig(
+        model=ModelConfig(model_id="laguna-tiny", seed=3),
+        engine=EngineConfig(max_num_seqs=4, max_seq_len=stripe, dtype="float32",
+                            prefill_chunk=64, prefill_buckets=(16, 32, 64)),
+    ))
+    try:
+        window = eng.model_cfg.sliding_window
+        want = {"full": 0, "window": 0, "whole": 0}
+        launch = eng._decode
+
+        def counting(pool, *args):  # called by the loop right before it counts
+            for r in pool.slots:
+                if r is not None:
+                    n = len(r.prompt_token_ids) + len(r.out_tokens)
+                    want["full"] += eng._decode_n_steps * positions_read(0, n, stripe)
+                    want["window"] += eng._decode_n_steps * positions_read(n - window, n, stripe)
+                    want["whole"] += eng._decode_n_steps * stripe
+            return launch(pool, *args)
+
+        eng._decode = counting
+        rng = np.random.default_rng(9)
+        p = SamplingParams(max_tokens=24, temperature=0.0, ignore_eos=True)
+        reqs = [eng.submit(prompt_token_ids=[int(t) for t in rng.integers(32, 127, n)],
+                           sampling_params=p) for n in (BLOCK - 10, 21)]
+        for r in reqs:
+            assert r.done.wait(timeout=120)
+        c = eng.get_stats()["counters"]
+        assert c["decode_kv_positions_read"] == want["full"] > 0
+        assert c["decode_kv_positions_read_window"] == want["window"] > 0
+        assert c["decode_kv_tokens_global"] <= c["decode_kv_positions_read"] <= want["whole"]
+        assert c["decode_kv_tokens_window"] <= c["decode_kv_positions_read_window"] <= want["whole"]
+        assert want["whole"] == c["decode_slot_steps"] * stripe
+        # some steps read one block of the long request's stripe, some both; the
+        # window never more than two
+        assert BLOCK * c["decode_slot_steps"] < c["decode_kv_positions_read"] < want["whole"]
+        assert c["decode_kv_positions_read_window"] <= c["decode_kv_positions_read"]
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("placed", ["no-mesh", "mesh-of-one-device", "tp2"])
+def test_an_engine_counts_the_form_its_decode_steps_take(monkeypatch, placed):
+    """The engine's counter and the body's choice are one answer
+    (``patterned.reads_blocks``, asked once a pool): where the traced decode
+    program calls the kernel, ``decode_kv_positions_read`` counts blocks;
+    where it keeps the einsum (parameters over a mesh), whole stripes. A mesh
+    of one device is one device on both sides."""
+    from ray_tpu.ops.decode_attention import BLOCK
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    mesh = {"no-mesh": None,
+            "mesh-of-one-device": build_mesh(MeshSpec(), devices=jax.devices()[:1]),
+            "tp2": build_mesh(MeshSpec(dp=2, tp=2), devices=jax.devices()[:4])}[placed]
+    traced = _count_kernel_calls(monkeypatch)
+    stripe = 2 * BLOCK
+    eng = JaxEngine(LLMConfig(
+        model=ModelConfig(model_id="tiny", tokenizer="byte", seed=3),
+        engine=EngineConfig(max_num_seqs=2, max_seq_len=stripe, dtype="float32",
+                            prefill_buckets=(16, 32), enable_prefix_caching=False,
+                            tensor_parallel_degree=2 if placed == "tp2" else 1),
+    ), mesh=mesh)
+    try:
+        out = eng.generate(prompt_token_ids=list(range(40, 60)), sampling_params=SamplingParams(
+            max_tokens=8, temperature=0.0, ignore_eos=True))
+        assert len(out.token_ids) == 8
+        c = eng.get_stats()["counters"]
+        [pool] = eng._pools
+        # the body's choice as the engine's own decode program is traced: here,
+        # where the engine restored its executables and traced none
+        eng._decode_jit.lower(eng.params, pool.cache, pool.dev_tokens, *pool.sampler(), pool.keys)
+        assert pool.reads_blocks == bool(traced) == (placed != "tp2")
+        per_slot_step = BLOCK if pool.reads_blocks else stripe  # 28 positions at most: one block
+        assert c["decode_kv_positions_read"] == c["decode_slot_steps"] * per_slot_step > 0
+        assert c["decode_kv_positions_read_window"] == 0  # no window layers in this model
+    finally:
+        eng.shutdown()

@@ -14,7 +14,10 @@ that nothing is traced for and loads it.
 - a file cut short or unreadable falls back, is written anew and is counted,
   and an executable that refuses its arguments gives way to its ``jit``;
 - donation survives the round trip;
-- with no cache directory configured the store is never touched.
+- with no cache directory configured the engine launches executables all the
+  same, the store finds none and keeps none, a burst compiles nothing more,
+  and an executable that refuses its arguments gives way to its ``jit`` once
+  and the request is served.
 
 The starts run once, in fresh subprocesses on a temporary cache directory
 (``starts``); each test reads what they printed.
@@ -44,11 +47,11 @@ lowered = []
 jax.monitoring.register_event_duration_secs_listener(
     lambda event, seconds, **kw: lowered.append(str(kw.get("fun_name")))
     if event.endswith("jaxpr_to_mlir_module_duration") else None)
-touched = []
+answered = []  # what the store said to each load and save
 for name in ("load", "save"):
     def spy(*args, _real=getattr(program_store, name), _name=name):
-        touched.append(_name)
-        return _real(*args)
+        answered.append((_name, _real(*args)))
+        return answered[-1][1]
     setattr(program_store, name, spy)
 
 
@@ -71,9 +74,26 @@ said = {
     "seeded": tokens(eng, temperature=0.9, top_k=20, seed=11),
     "relaid": eng.get_stats()["params_relaid"]["leaves"],
 }
-eng.shutdown()
+# a burst of unlike lengths, greedy and sampled, at once: nothing is left to compile
+burst = [eng.submit(prompt_token_ids=list(range(1, n)), sampling_params=SamplingParams(
+    max_tokens=3, ignore_eos=True, temperature=t)) for n, t in ((4, 0.0), (20, 0.8), (45, 0.0))]
+for req in burst:
+    eng._await_done(req)
+said["burst"] = {"errors": [str(req.error) for req in burst if req.error],
+                 "programs": dict(eng._program_counts)}
 said["forms"] = sorted(":".join(map(str, form)) for form in eng._programs)
 said["executables"] = sum(isinstance(p, jax.stages.Compiled) for p in eng._programs.values())
+if mode == "unconfigured":
+    # an executable that refuses its arguments (here: another form's) while
+    # the loop serves: the jit takes its place once and the request is served
+    eng._programs[("decode", 64)] = eng._programs[("new_stripe", 64)]
+    said["refused_in_the_loop"] = {
+        "tokens": [tokens(eng, temperature=0.0) for _ in range(2)],
+        "fallbacks": eng._program_counts["fallback"] - said["burst"]["programs"]["fallback"],
+        "compiled": eng._program_counts["compiled"] - said["burst"]["programs"]["compiled"],
+        "runs_the_jit": not isinstance(eng._programs[("decode", 64)], jax.stages.Compiled),
+    }
+eng.shutdown()
 # donation: a decode step leaves the pool's cache updated in place
 pool = eng._pools[0]
 before = pool.cache["k"]
@@ -83,7 +103,8 @@ said["donated"] = bool(before.is_deleted()) and not pool.cache["k"].is_deleted()
 said["lowered"] = [name for name in lowered if any(
     program in name for program in ("decode_fn", "chunk_mid", "chunk_final", "new_stripe",
                                     "seed_prefix", "store_snapshot"))]
-said["touched"] = sorted(set(touched))
+said["answered"] = {name: sorted({repr(got) for asked, got in answered if asked == name})
+                    for name in ("load", "save")}
 folder = program_store.directory()
 said["files"] = {os.path.basename(p): os.path.getsize(p) for p in glob.glob(f"{folder}/*.bin")} \
     if folder else None
@@ -113,7 +134,7 @@ if mode == "moved":
                          ("model configuration", dict(n_layers=3)),
                          ("dtype", dict(dtype="float32")),
                          ("package bytes", {})):
-        del touched[:], lowered[:]
+        del lowered[:]
         program_store.save = stop
         if what == "package bytes":
             program_store.package_digest = lambda: "another source"
@@ -224,12 +245,28 @@ def test_an_executable_that_refuses_its_arguments_gives_way_to_the_jit(starts):
     assert starts["damaged"]["refused"] == {"fallbacks": 1, "runs_the_jit": True, "stepped": True}
 
 
-def test_without_a_cache_directory_the_store_is_never_touched(starts):
+def test_without_a_cache_directory_an_engine_launches_executables_and_keeps_none(starts):
+    """The engine asks the store all the same (it does not ask whether there
+    is one); the store finds nothing and keeps nothing, anywhere."""
     said = starts["unconfigured"]
-    assert said["touched"] == [] and said["files"] is None
-    assert said["forms"] == [] and said["executables"] == 0
-    assert said["init"]["programs"] == {"restored": 0, "compiled": 0, "fallback": 0}
-    assert said["lowered"]  # its jits lower as they always did
+    assert said["answered"] == {"load": ["None"], "save": ["False"]} and said["files"] is None
+    assert len(said["forms"]) == said["executables"] == FORMS
+    assert said["init"]["programs"] == {"restored": 0, "compiled": FORMS, "fallback": 0}
+    assert said["lowered"]  # each form is lowered once, for its executable
+
+
+@pytest.mark.parametrize("start", ["first", "second", "damaged", "unconfigured"])
+def test_a_burst_behind_the_warm_up_compiles_and_restores_nothing_more(starts, start):
+    said = starts[start]
+    assert said["burst"] == {"errors": [], "programs": said["init"]["programs"]}
+
+
+def test_an_executable_refused_while_the_loop_serves_gives_way_once_and_the_request_is_served(
+        starts):
+    """In an engine with no cache directory, as tier-1's engines are."""
+    refused = starts["unconfigured"]["refused_in_the_loop"]
+    assert refused == {"tokens": [starts["first"]["greedy"]] * 2, "fallbacks": 1, "compiled": 0,
+                       "runs_the_jit": True}
 
 
 # -- the key, with no engine ---------------------------------------------------
@@ -290,9 +327,11 @@ def test_the_store_is_off_where_the_compile_cache_is(tmp_path):
     read and nothing is written."""
     was = jax.config.jax_compilation_cache_dir
     compiled = jax.jit(lambda x: x + 1).lower(jnp.ones(3)).compile()
-    assert program_store.directory() is None  # tests configure no directory
-    assert program_store.save("f", "k", compiled) is False
     try:
+        jax.config.update("jax_compilation_cache_dir", None)  # as the suite runs: none is set
+        assert program_store.directory() is None
+        assert program_store.load("f", "k", jax.devices()[0]) is None
+        assert program_store.save("f", "k", compiled) is False
         jax.config.update("jax_compilation_cache_dir", str(tmp_path))
         assert program_store.directory() == str(tmp_path / "programs")
         with jax_cache.bypassed():
