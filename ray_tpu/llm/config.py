@@ -100,7 +100,8 @@ class ModelConfig:
     # "llama3-8b" | "llama3.2-3b" | "llama3-70b" | "laguna-xs.2" |
     # "laguna-tiny" | "kanana-2-30b-a3b" | "kanana-tiny" |
     # "nemotron-3-super-120b-a12b" | "nemotron-tiny" | "solar-open2-250b" |
-    # "solar-tiny" | "granite-4.0-h-micro" | "granite-tiny"
+    # "solar-tiny" | "granite-4.0-h-micro" | "granite-tiny" | "zaya1-8b" |
+    # "zaya-tiny"
     model_id: str = "tiny"
     tokenizer: str = "byte"  # "byte" | transformers tokenizer path
     checkpoint_path: Optional[str] = None  # ray_tpu.train pytree checkpoint
@@ -143,6 +144,8 @@ def resolve_llama_config(model: "ModelConfig", engine: "EngineConfig", min_vocab
         "solar-tiny": LlamaConfig.solar_tiny,
         "granite-4.0-h-micro": LlamaConfig.granite4_h_micro,
         "granite-tiny": LlamaConfig.granite_tiny,
+        "zaya1-8b": LlamaConfig.zaya1_8b,
+        "zaya-tiny": LlamaConfig.zaya_tiny,
     }
     kw = dict(
         max_seq_len=engine.max_seq_len,
@@ -174,16 +177,18 @@ def refuse_latent(cfg, module: str) -> None:
 
 def refuse_stateful(cfg, module: str) -> None:
     """The same for a model with layers that keep a state (state-space or
-    delta-rule: any whose cache has ``models/patterned.py STATE_LEAVES``):
-    their slots hold a state and a convolution tail beside keys and values,
-    which those copies of the cache's programs, a mesh and a hand-over of keys
-    and values alone (``llm/disagg.py``) do not know."""
+    delta-rule, or attention whose queries and keys pass convolutions: any
+    whose cache has ``models/patterned.py STATE_LEAVES``): their slots hold a
+    state or a convolution tail beside keys and values, which those copies of
+    the cache's programs, a mesh and a hand-over of keys and values alone
+    (``llm/disagg.py``) do not know."""
     from ray_tpu.models.patterned import state_cache_shapes
 
     # the kinds by their leaves' names: ``ssm_state`` -> state-space
     kinds = dict.fromkeys(leaf.split("_")[0] for leaf in state_cache_shapes(cfg, 1))
     if kinds:
-        names = " and ".join({"ssm": "state-space", "kda": "delta-rule"}.get(k, k) for k in kinds)
+        names = " and ".join({"ssm": "state-space", "kda": "delta-rule",
+                              "cca": "convolved-attention"}.get(k, k) for k in kinds)
         raise NotImplementedError(
             f"{module}: a model with {names} layers is served on one device by "
             "llm/engine.py JaxEngine with tensor_parallel_degree=1; this path has no "

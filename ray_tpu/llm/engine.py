@@ -137,10 +137,12 @@ LATENCIES = ("queue_wait_s", "prefill_s", "token_gap_s")
 # the loop's own clock (``get_stats()["loop"]``): the stages of a pass in the
 # order it runs them, the bounds of its histogram of pass durations (the
 # latency bounds' rule, from 0.1 ms to 200 s), and how many wall seconds keep
-# their longest pass
+# their longest pass: ten minutes, because whoever asks may ask late (a
+# profiler that stops after a busy window of a model of many small operations
+# took 161 s to hand back its trace, and the seconds asked about came before)
 LOOP_STAGES = ("pull_waiting", "advance_admissions", "launch_decodes", "drain", "idle_sleep")
 PASS_BOUNDS = tuple(1e-4 * 1.25**i for i in range(66))
-LONGEST_PASS_SECONDS = 120
+LONGEST_PASS_SECONDS = 600
 _registered: dict = {}
 _registered_lock = threading.Lock()
 
@@ -364,8 +366,9 @@ class _Pool:
         tokens = n_slots * stripe_len
         self.kv_bytes_per_token = (self.cache["k"].nbytes + self.cache["v"].nbytes) / tokens
         # what a slot holds whatever its length (the state and convolution
-        # tails of the layers that keep one): 0 for a model whose slots are
-        # stripes alone
+        # tails of the layers that keep one, whether such a layer has a stripe
+        # of keys and values as well or none): 0 for a model whose slots are
+        # stripes alone. ``stateful`` is asked of the leaves, never of a kind
         state_bytes = sum(self.cache[k].nbytes for k in STATE_LEAVES if k in self.cache)
         self.state_bytes_per_slot = state_bytes // n_slots
         self.stateful = state_bytes > 0

@@ -192,6 +192,25 @@ class LlamaConfig:
     residual_multiplier: float = 1.0
     attention_multiplier: float = 0.0
     logits_scaling: float = 1.0
+    # --- compressed convolutional attention (layer type 'cca'; Zyphra ZAYA1,
+    # arXiv 2510.04476) ---
+    # a 'cca' layer is attention whose queries and keys pass two short causal
+    # convolutions over the sequence (``cca_taps``: a depthwise one, then one
+    # that mixes the channels of each head) and whose value heads' second half
+    # are the token before's, so a slot holds stripes of keys and values *and*,
+    # whatever its length, the last inputs of both convolutions and the last
+    # token's shifted value half (``models/patterned.py cca_dims``)
+    cca_taps: tuple = (2, 2)
+    # > 0: an expert layer's router is no matrix but a projection to this
+    # width, plus a learned multiple of the router's own vector of the expert
+    # layer before (a second stream through the depth), a norm and an MLP of
+    # three matrices with GELU; the chosen experts are weighted by their
+    # softmax probabilities (at ``moe_top_k`` 1 unrenormalised), chosen by
+    # probability plus ``moe_router_bias``
+    moe_router_hidden: int = 0
+    # a learned vector on the stream and one on the branch wherever a branch
+    # joins the stream: x <- a * x + b * branch (``attn_scale``, ``mlp_scale``)
+    residual_scales: bool = False
 
     def __post_init__(self):
         if self.attention not in ("full", "ring", "ulysses", "splash"):
@@ -202,6 +221,7 @@ class LlamaConfig:
             raise ValueError(f"unknown moe_activation {self.moe_activation!r}")
         if self.attn_gate not in (False, True, "head", "channel"):
             raise ValueError(f"unknown attn_gate {self.attn_gate!r}")
+        object.__setattr__(self, "cca_taps", tuple(self.cca_taps))
         for name in ("layer_types", "heads_per_layer", "mlp_types"):
             value = tuple(getattr(self, name))
             object.__setattr__(self, name, value)
@@ -212,6 +232,9 @@ class LlamaConfig:
         scalars = (self.embedding_multiplier, self.residual_multiplier, self.logits_scaling)
         if not self.layer_types and (scalars != (1.0, 1.0, 1.0) or self.attention_multiplier):
             raise ValueError("the Granite multipliers need layer_types (models/patterned.py)")
+        if not self.layer_types and (self.moe_router_hidden or self.residual_scales):
+            raise ValueError("moe_router_hidden and residual_scales need layer_types "
+                             "(models/patterned.py)")
 
     @property
     def head_dim(self) -> int:
@@ -522,6 +545,42 @@ class LlamaConfig:
             "full" if i % 4 == 1 else "ssm" for i in range(d["n_layers"])))
         return LlamaConfig.granite4_h_micro(**d)
 
+    @staticmethod
+    def zaya1_8b(**kw) -> "LlamaConfig":
+        """Zyphra ZAYA1-8B (``model_type: zaya``) as its config.json has it:
+        40 layers, each compressed convolutional attention (8 query and 2
+        key-value heads of 128 in a latent of 1,024 and 256, two convolutions
+        of 2 taps, half of every head rotated at theta 5e6) under 16 SwiGLU
+        experts of 2,048, one a token, chosen by an MLP router of width 256
+        with a stream of its own through the depth; learned scales at every
+        join; a tied head over 262,272 rows. A caller that cuts ``n_layers``
+        or gives ``moe_experts_held`` gets a device's share."""
+        d = dict(
+            vocab_size=262272, d_model=2048, n_layers=40, n_heads=8, n_kv_heads=2,
+            head_width=128, d_ff=2048, max_seq_len=131072, rms_eps=1e-5, rope_theta=5e6,
+            rope_partial=0.5, tie_embeddings=True, cca_taps=(2, 2), moe_experts=16, moe_top_k=1,
+            moe_d_ff=2048, moe_router_hidden=256, residual_scales=True,
+        )
+        d.update(kw)
+        n = d["n_layers"]
+        d.setdefault("layer_types", ("cca",) * n)
+        d.setdefault("heads_per_layer", (d["n_heads"],) * n)
+        d.setdefault("mlp_types", ("sparse",) * n)
+        return LlamaConfig(**d)
+
+    @staticmethod
+    def zaya_tiny(**kw) -> "LlamaConfig":
+        """Test-size model with ``zaya1_8b``'s layers: three of them, 4 query
+        heads over 2 key-value heads of 16 (a group of 2), 4 of 8 experts
+        held, a router of width 16."""
+        d = dict(
+            vocab_size=256, d_model=64, n_layers=3, n_heads=4, n_kv_heads=2, head_width=16,
+            d_ff=32, max_seq_len=128, dtype=jnp.float32, remat=False, rope_theta=10000.0,
+            moe_experts=8, moe_experts_held=4, moe_d_ff=32, moe_router_hidden=16,
+        )
+        d.update(kw)
+        return LlamaConfig.zaya1_8b(**d)
+
 
 # a block of a ``nemotron_h`` pattern: (mixer, feed-forward)
 _BLOCKS = {"M": ("ssm", "none"), "E": ("none", "sparse"), "*": ("full", "none")}
@@ -625,6 +684,21 @@ _PARAM_DIMS.update({
     **dict.fromkeys(("kda_conv_w", "kda_w_decay", "kda_w_gate"), (None, None, None)),
     **dict.fromkeys(("kda_dt_bias", "kda_a_log", "kda_norm"), (None, None)),
 })
+# a compressed-convolutional-attention layer's leaves, an MLP router's and the
+# learned scales at a join (one device too): the projections as any
+# attention's, everything else whole
+_PARAM_DIMS.update({
+    "wq_cca": _PARAM_DIMS["wq"],
+    "wo_cca": _PARAM_DIMS["wo"],
+    "cca_conv0_w": (None, None, None),
+    "cca_conv1_w": (None, None, None, None),
+    **dict.fromkeys(("cca_conv0_b", "cca_conv1_b", "cca_temp", "moe_router_norm",
+                     "moe_router_gamma", "moe_router_b1", "moe_router_b2", "moe_router_b3"),
+                    (None, None)),
+    "moe_router_down": (None, "embed", None),
+    **dict.fromkeys(("moe_router_w1", "moe_router_w2", "moe_router_w3", "attn_scale",
+                     "mlp_scale"), (None, None, None)),
+})
 
 
 def param_logical_dims(path, leaf):
@@ -693,6 +767,15 @@ _SSM_VECTORS = {
 }
 # a delta-rule mixer's decay: the same two draws, the bias a channel
 _SSM_VECTORS.update(kda_dt_bias=_SSM_VECTORS["ssm_dt_bias"], kda_a_log=_SSM_VECTORS["ssm_a_log"])
+# compressed convolutional attention, an MLP router and the scales at a join:
+# biases small, a key head's temperature and the scales one, the multiple of
+# the router's vector of the layer before a half
+_SSM_VECTORS.update({
+    **dict.fromkeys(("cca_conv0_b", "cca_conv1_b", "moe_router_b1", "moe_router_b2",
+                     "moe_router_b3"), _SSM_VECTORS["ssm_conv_b"]),
+    **dict.fromkeys(("cca_temp", "attn_scale", "mlp_scale"), _SSM_VECTORS["ssm_d"]),
+    "moe_router_gamma": lambda k, shape: jnp.full(shape, 0.5, jnp.float32),
+})
 
 
 def _inv_softplus(y):

@@ -18,7 +18,16 @@ other two, because the cache has one shape (``init_kv_cache``). Two kinds of
 mixer keep a state a sequence and no keys, whatever its length
 (``STATE_MIXERS``): ``ssm`` (Mamba-2; NVIDIA Nemotron-3-Super, whose blocks
 are a mixer or a feed-forward alone: kinds ``none``) and ``kda`` (the delta
-rule with a decay a channel; upstage Solar-Open2, ``ops/kda.py``).
+rule with a decay a channel; upstage Solar-Open2, ``ops/kda.py``). A kind may
+hold both a stripe and state leaves: ``cca`` (compressed convolutional
+attention; Zyphra ZAYA1-8B, ``LlamaConfig.zaya1_8b``) is grouped-query
+attention over stripes like a full layer's, whose queries and keys pass two
+short convolutions over the sequence and half of whose value heads are the
+token before's, so a slot carries the convolutions' last inputs and the last
+shifted value half beside its stripes (``STRIPE_STATE``, ``_cca_qkv``). That
+model's expert layers are routed by a small MLP with a stream of its own
+through the depth (``moe_router_hidden``, ``_mlp_route``) and its branches
+join the stream under learned scales (``residual_scales``, ``_joined``).
 
 ``models/llama.py`` is the entry point and imports this module, never the
 other way round: its ``prefill`` and ``decode_step`` call ``decode_forward``
@@ -82,7 +91,10 @@ scope = jax.named_scope
 # this, so that the slice starts on a tile of the cache's position axis
 _WINDOW_ALIGN = 128
 # the name under ``attn_core`` of a layer whose attention kind is named
-_SCOPE_OF_KIND = {"full": "global", "sliding": "window", "latent": "latent"}
+_SCOPE_OF_KIND = {"full": "global", "sliding": "window", "latent": "latent", "cca": "global"}
+# the kinds whose queries see every earlier position of their stripe, read as
+# a full layer reads its own (``_cache_reader``)
+_FULL_KINDS = ("full", "cca")
 # key positions a block when a prompt's chunk reads a latent cache: the
 # largest of these that divides the stripe (else the stripe whole). A block's
 # float32 scores are [B, heads, T, block]: 34 MB at 32 heads and a 256-token
@@ -148,6 +160,10 @@ class Plan:
         return sum(t == "kda" for t, _, _ in self.kinds)
 
     @property
+    def n_cca(self) -> int:
+        return sum(t == "cca" for t, _, _ in self.kinds)
+
+    @property
     def n_mixer(self) -> int:
         return sum(t != "none" for t, _, _ in self.kinds)
 
@@ -202,6 +218,14 @@ def plan(cfg) -> Plan:
     ):
         raise ValueError("kda layers need kda_heads, kda_head_dim and a kda_chunk that is a "
                          "multiple of 4")
+    if any(t == "cca" for t, _, _ in kinds) and not (
+        cfg.n_kv_heads % 2 == 0 and len(cfg.cca_taps) == 2 and min(cfg.cca_taps) >= 2
+        and not cfg.attn_gate
+    ):
+        raise ValueError("cca layers need an even n_kv_heads (half of the value heads are the "
+                         "token before's), two cca_taps of at least 2 and no attn_gate")
+    if cfg.moe_router_hidden and (cfg.moe_scoring != "softmax" or cfg.moe_latent_dim):
+        raise ValueError("moe_router_hidden: a softmax router over experts of the model's width")
     for t in _SCOPE_OF_KIND:
         if len({h for kt, h, _ in kinds if kt == t}) > 1:
             raise ValueError(f"{t} attention layers differ in their query heads")
@@ -261,7 +285,7 @@ def _moe_shapes(cfg, n: int) -> dict[str, tuple]:
     held, w = cfg.moe_experts_held or E, cfg.moe_latent_dim or e
     gated = cfg.moe_activation == "swiglu"
     shapes = {
-        "moe_router": (n, e, E),
+        **(_mlp_router_shapes(cfg, n) if cfg.moe_router_hidden else {"moe_router": (n, e, E)}),
         **({"moe_w_gate": (n, held, w, f)} if gated else {}),
         "moe_w_up": (n, held, w, f),
         "moe_w_down": (n, held, f, w),
@@ -278,6 +302,21 @@ def _moe_shapes(cfg, n: int) -> dict[str, tuple]:
             "moe_shared_down": (n, fs, e),
         })
     return shapes
+
+
+def _mlp_router_shapes(cfg, n: int) -> dict[str, tuple]:
+    """The leaves of ``n`` stacked routers that are an MLP
+    (``moe_router_hidden``): the projection of the stream to the router's
+    width, the multiple a channel of the router's vector of the expert layer
+    before, a norm, three matrices with their biases, and the selection bias."""
+    e, E, R = cfg.d_model, cfg.moe_experts, cfg.moe_router_hidden
+    return {
+        "moe_router_down": (n, e, R), "moe_router_gamma": (n, R), "moe_router_norm": (n, R),
+        "moe_router_w1": (n, R, R), "moe_router_b1": (n, R),
+        "moe_router_w2": (n, R, R), "moe_router_b2": (n, R),
+        "moe_router_w3": (n, R, E), "moe_router_b3": (n, E),
+        "moe_router_bias": (n, E),
+    }
 
 
 def ssm_dims(cfg) -> dict:
@@ -336,13 +375,48 @@ def _kda_shapes(cfg, n: int) -> dict[str, tuple]:
     }
 
 
+def cca_dims(cfg) -> dict:
+    """The widths of a compressed-convolutional-attention layer: ``heads``
+    (query and key heads side by side: what both convolutions run over),
+    ``qk`` (their channels), ``vprev`` (the value heads that are the token
+    before's: the second half of them), and the parts of a slot's tail in
+    order (``tail``: the last ``taps - 1`` inputs of each convolution, the
+    taps side by side on the lanes, then the last token's shifted values)."""
+    heads = max(h for t, h, _ in plan(cfg).kinds if t == "cca") + cfg.n_kv_heads
+    qk, vprev = heads * cfg.head_dim, cfg.n_kv_heads // 2 * cfg.head_dim
+    parts = ((cfg.cca_taps[0] - 1) * qk, (cfg.cca_taps[1] - 1) * qk, vprev)
+    return {"heads": heads, "qk": qk, "vprev": vprev, "parts": parts, "tail": sum(parts)}
+
+
+def _cca_shapes(cfg, n: int, h: int) -> dict[str, tuple]:
+    """The leaves of ``n`` stacked compressed-convolutional-attention layers
+    of ``h`` query heads beside ``wq_cca``, ``wo_cca`` and their rows of
+    ``wk`` and ``wv`` (``wv``'s first half of heads the token's own values,
+    its second half the token before's): the depthwise convolution [taps,
+    channels] (the published [channels, 1, taps]), the one that mixes each
+    head's channels [heads, taps x head width, head width] (the published
+    [channels, head width, taps] with groups = heads: row ``tap * width + i``
+    of a head's matrix multiplies channel ``i`` of that tap), their biases,
+    and a temperature a key head."""
+    d, hd = cca_dims(cfg), cfg.head_dim
+    return {
+        "cca_conv0_w": (n, cfg.cca_taps[0], d["qk"]),
+        "cca_conv0_b": (n, d["qk"]),
+        "cca_conv1_w": (n, d["heads"], cfg.cca_taps[1] * hd, hd),
+        "cca_conv1_b": (n, d["qk"]),
+        "cca_temp": (n, cfg.n_kv_heads),
+    }
+
+
 def state_cache_shapes(cfg, batch_size: int) -> dict:
     """name -> (shape, dtype) of the ``STATE_LEAVES`` a model's cache holds, a
     row a layer and slot: a state-space layer's state and the last
     ``ssm_conv - 1`` inputs of its convolution, a delta-rule layer's state a
     head and the last ``kda_conv - 1`` inputs of its three convolutions (the
     states float32: they sum a sequence's steps; the tails in the served type,
-    channels on the lanes). Empty for a model whose slots are stripes alone."""
+    channels on the lanes), a compressed-convolutional-attention layer's tail
+    (``cca_dims``; its keys and values are stripes). Empty for a model whose
+    slots are stripes alone."""
     pl, shapes = plan(cfg), {}
     if pl.n_ssm:
         shapes["ssm_state"] = ((pl.n_ssm, batch_size, cfg.ssm_heads, cfg.ssm_head_dim,
@@ -354,6 +428,8 @@ def state_cache_shapes(cfg, batch_size: int) -> dict:
                                 cfg.kda_head_dim), jnp.float32)
         shapes["kda_conv"] = ((pl.n_kda, batch_size, cfg.kda_conv - 1, kda_dims(cfg)["conv"]),
                               cfg.dtype)
+    if pl.n_cca:
+        shapes["cca_tail"] = ((pl.n_cca, batch_size, 1, cca_dims(cfg)["tail"]), cfg.dtype)
     return shapes
 
 
@@ -389,6 +465,8 @@ def _param_shapes(cfg) -> dict[str, tuple]:
             continue
         shapes[pl.leaf("wq", kind)] = (n, e, h, hd)
         shapes[pl.leaf("wo", kind)] = (n, h, hd, e)
+        if kind == "cca":
+            shapes.update(_cca_shapes(cfg, n, h))
         if cfg.attn_gate:  # a value a head, or a channel of each head
             shapes[pl.leaf("wg", kind)] = (n, e, h * hd if cfg.attn_gate == "channel" else h)
     n_dense = sum(m == "dense" for _, _, m in pl.kinds)
@@ -399,6 +477,8 @@ def _param_shapes(cfg) -> dict[str, tuple]:
     n_sparse = sum(m == "sparse" for _, _, m in pl.kinds)
     if n_sparse:
         shapes.update(_moe_shapes(cfg, n_sparse))
+    if cfg.residual_scales:  # [0] on the stream, [1] on the branch
+        shapes.update({"attn_scale": (pl.n_mixer, 2, e), "mlp_scale": (pl.n_ffn, 2, e)})
     if not cfg.tie_embeddings:
         shapes["unembed"] = (e, v)
     return shapes
@@ -425,6 +505,17 @@ def _times(x, m: float):
     ``LlamaConfig.embedding_multiplier`` on the looked-up rows,
     ``residual_multiplier`` on a branch before it joins the stream.)"""
     return x if m == 1.0 else x * jnp.asarray(m, x.dtype)
+
+
+def _joined(params, leaf: str, i, x, branch, cfg):
+    """The stream after a branch joins it: ``x + branch`` (the branch times
+    ``cfg.residual_multiplier``), or under learned scales (``residual_scales``;
+    ``leaf`` row ``i``: a vector on the stream and one on the branch)
+    ``a * x + b * branch``."""
+    if cfg.residual_scales:
+        a, b = params[leaf][i]
+        return a * x + b * branch
+    return x + _times(branch, cfg.residual_multiplier)
 
 
 @scope("embed")
@@ -507,7 +598,37 @@ def held_block(assignments: int, held: int, experts: int) -> int:
     return min(assignments, max(tile, -(-twice // tile) * tile))
 
 
-def _moe_decode_ffn(params, row, h, cfg):
+def _mlp_route(params, row, g, r_prev, cfg):
+    """A router that is an MLP with a stream of its own through the depth
+    (``moe_router_hidden``; ZAYA1's). g [G, e] the expert layer's normed
+    input, ``r_prev`` [G, R] the router's vector of the expert layer before
+    (zeros at the first). ``r = g W_d + gamma * r_prev``; the logits
+    ``W_3 gelu(W_2 gelu(W_1 rmsnorm(r)))`` with biases, in float32 (the
+    choice is an argmax: in bfloat16 near ties swap on rounding alone);
+    probabilities their softmax; the k experts chosen by probability plus the
+    selection bias and weighted by their probabilities themselves, which at
+    k = 1 are not renormalised (a renormalised single weight is 1: the layer
+    would lose the router's confidence, and its gradient). Returns
+    (gate_vals [G, k] f32, gate_idx [G, k], r [G, R] in ``g``'s type: what the
+    next expert layer's router adds, whatever was chosen)."""
+    f32 = jnp.float32
+
+    def leaf(name):
+        return params["moe_router_" + name][row]
+
+    r = g @ leaf("down") + leaf("gamma") * r_prev
+    u = _rmsnorm(r.astype(f32), leaf("norm").astype(f32), cfg.rms_eps)
+    for j in ("1", "2"):
+        u = jax.nn.gelu(u @ leaf("w" + j).astype(f32) + leaf("b" + j).astype(f32))
+    probs = jax.nn.softmax(u @ leaf("w3").astype(f32) + leaf("b3").astype(f32), axis=-1)
+    _, gate_idx = jax.lax.top_k(probs + leaf("bias").astype(f32), cfg.moe_top_k)
+    gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
+    if cfg.moe_top_k > 1:
+        gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    return gate_vals, gate_idx, r
+
+
+def _moe_decode_ffn(params, row, h, cfg, r_prev=None):
     """Dropless routed expert FFN for the serving path, and for ``forward``
     of a model whose layers are not alike. ``params`` holds the stacked
     ``moe_*`` leaves, ``row`` (static or traced) is this layer's row in them.
@@ -558,7 +679,11 @@ def _moe_decode_ffn(params, row, h, cfg):
     ``G*k`` rows of ``width`` in float32, which were 5 of the 12.5 ms of a
     1,024-token chunk's expert layers at 40 of 320 held: PERF.md section 6,
     PR 45.) A model that holds all its experts keeps the one pass over all
-    rows, operation for operation."""
+    rows, operation for operation.
+
+    ``r_prev`` [B, T, R]: the model's router is an MLP (``_mlp_route``) and
+    this is its vector of the expert layer before; the result then ends with
+    this layer's, ``(y, stats, r)``."""
     from ray_tpu.ops.grouped_matmul import grouped_matmul
     from ray_tpu.parallel.moe import topk_gates
 
@@ -574,10 +699,14 @@ def _moe_decode_ffn(params, row, h, cfg):
     with scope("router"):
         # float32 logits: in the model's own bf16 the 8th and 9th of 256
         # experts swap for some tokens on rounding alone
-        router = {"router": params["moe_router"][row].astype(jnp.float32)}
-        if cfg.moe_scoring == "sigmoid":
-            router["bias"] = params["moe_router_bias"][row].astype(jnp.float32)
-        _, gate_vals, gate_idx = topk_gates(router, g.astype(jnp.float32), k)
+        if r_prev is not None:
+            gate_vals, gate_idx, r = _mlp_route(
+                params, row, g, r_prev.reshape(G, r_prev.shape[-1]), cfg)
+        else:
+            router = {"router": params["moe_router"][row].astype(jnp.float32)}
+            if cfg.moe_scoring == "sigmoid":
+                router["bias"] = params["moe_router_bias"][row].astype(jnp.float32)
+            _, gate_vals, gate_idx = topk_gates(router, g.astype(jnp.float32), k)
         # tokens an expert: a one-hot sum (a scatter-add is slow on the chip)
         load = jax.nn.one_hot(gate_idx.reshape(-1), E, dtype=jnp.int32).sum(axis=0)
         chosen = gate_idx.reshape(-1)
@@ -655,6 +784,8 @@ def _moe_decode_ffn(params, row, h, cfg):
         y = y + _shared_expert(
             {n: params[n][row] for n in params if n.startswith("moe_shared_")}, g
         )
+    if r_prev is not None:
+        return y.reshape(B, T, e), stats, r.reshape(r_prev.shape)
     return y.reshape(B, T, e), stats
 
 
@@ -879,6 +1010,77 @@ def _qkv(params, lay: _Layer, h, positions, cfg, loras=None, adapter_ids=None):
     return _times(q, _score_rescale(cfg)), k, v
 
 
+def _cca_project(params, lay: _Layer, h):
+    """The projections of a compressed-convolutional-attention layer of h
+    [B, T, e], which multiply by a weight and so run on every set's rows as
+    one: queries and keys before their convolutions side by side
+    [B, T, heads, D] (the query heads first), and the values [B, T, K, D]
+    (the second half of the heads belong to the token after)."""
+    with scope("attn_qkv"):
+        q = jnp.einsum("bte,ehd->bthd", h, params[lay.wq][lay.attn_i])
+        k = jnp.einsum("bte,ehd->bthd", h, params["wk"][lay.kv_i])
+        v = jnp.einsum("bte,ehd->bthd", h, params["wv"][lay.kv_i])
+    return jnp.concatenate([q, k], axis=2), v
+
+
+def _cca_qkv(params, lay: _Layer, qk, v, tail, positions, valid, cfg):
+    """Queries, keys and values of one set of rows of a compressed-
+    convolutional-attention layer (Figliolia et al., arXiv 2510.04476), from
+    ``_cca_project``'s ``qk`` [B, T, heads, D] and ``v`` [B, T, K, D] and the
+    rows' ``tail`` [B, 1, ``cca_dims``' tail] (zeros for a new sequence), and
+    the rows' next tail.
+
+    ``qk`` passes a depthwise causal convolution over the sequence and then
+    one that mixes the channels of each head (``cca_taps``, no activation);
+    the mean of each query head and its group's key before the convolutions
+    (and that mean's mean over the group, for the key) is added back; each
+    head is brought to length ``sqrt(D)`` in float32, a key head times its
+    temperature; the first ``rope_partial`` of each head rotated. The value
+    heads' first half are the token's own, the second half the token
+    before's. ``valid`` [B, T] (or None: all) marks a row's real tokens, a
+    prefix of it: the next tail is cut where the row ends, and a row of no
+    real token keeps the one it came with. The first convolution's output is
+    rounded to the served type before the second reads it, as the tail holds
+    it: a token's numbers do not depend on where a chunk ended."""
+    from ray_tpu.ops.ssm import causal_conv
+
+    i, d, f32 = lay.attn_i, cca_dims(cfg), jnp.float32
+    B, T, heads, D = qk.shape
+    K = cfg.n_kv_heads
+    H, taps = heads - K, cfg.cca_taps
+    inv_freq, factor = rope_inv_freq(cfg, lay.kind)
+    with scope("attn_qkv"), scope("cca_conv"):
+        t0, t1, tv = (part.reshape(B, -1, width) for part, width in zip(
+            jnp.split(tail, np.cumsum(d["parts"])[:-1], axis=-1), (d["qk"], d["qk"], d["vprev"])))
+        flat = qk.reshape(B, T, d["qk"])
+        u, seen0 = causal_conv(t0, flat, params["cca_conv0_w"][i], params["cca_conv0_b"][i])
+        seen1 = jnp.concatenate([t1.astype(flat.dtype), u.astype(flat.dtype)], axis=1)
+        by_head = seen1.reshape(B, -1, heads, D)
+        # the taps of a head side by side: one product a head
+        w = jnp.einsum(
+            "bthc,hcd->bthd",
+            jnp.concatenate([by_head[:, j:j + T] for j in range(taps[1])], axis=-1),
+            params["cca_conv1_w"][i], preferred_element_type=f32,
+        ) + params["cca_conv1_b"][i].astype(f32).reshape(heads, D)
+        qk32 = qk.astype(f32)
+        mean_q = 0.5 * (qk32[:, :, :H] + jnp.repeat(qk32[:, :, H:], H // K, axis=2))
+        mean_k = mean_q.reshape(B, T, K, H // K, D).mean(axis=3)
+        q, k = w[:, :, :H] + mean_q, w[:, :, H:] + mean_k
+        q, k = (t * jax.lax.rsqrt((t * t).sum(axis=-1, keepdims=True) + _L2_EPS) * D ** 0.5
+                for t in (q, k))
+        k = k * params["cca_temp"][i].astype(f32)[:, None]
+        q = _rope(q, positions, inv_freq, factor).astype(qk.dtype)
+        k = _rope(k, positions, inv_freq, factor).astype(qk.dtype)
+        half = K // 2
+        seen_v = jnp.concatenate(
+            [tv.astype(v.dtype), v[:, :, half:].reshape(B, T, d["vprev"])], axis=1)
+        v = jnp.concatenate([v[:, :, :half], seen_v[:, :T].reshape(B, T, half, D)], axis=2)
+        tail = jnp.concatenate([
+            _next_tail(seen, n - 1, valid).reshape(B, 1, -1)
+            for seen, n in ((seen0, taps[0]), (seen1, taps[1]), (seen_v, 2))], axis=-1)
+    return _times(q, _score_rescale(cfg)), k, v, tail.astype(cfg.dtype)
+
+
 def _latent_qkv(params, lay: _Layer, h, positions, cfg):
     """A latent layer's projections of h [B, T, e]: each head's query in its
     two parts, (q_nope [B, T, H, nope], q_rope [B, T, H, rope], rotated), and
@@ -945,7 +1147,7 @@ def _attn_out(params, lay: _Layer, x, h, attn, cfg, from_latent: bool = False):
                 gate = gate.reshape(attn.shape) if cfg.attn_gate == "channel" else gate[..., None]
                 attn = (attn * gate).astype(attn.dtype)
         out = jnp.einsum("bthd,hde->bte", attn, params[lay.wo][lay.attn_i])
-        return x + _times(out, cfg.residual_multiplier)
+        return _joined(params, "attn_scale", lay.mixer_i, x, out, cfg)
 
 
 def _ssm_in(params, lay: _Layer, h, valid, cfg):
@@ -981,16 +1183,23 @@ def _conv_through_tail(conv_all, i, x, w, b, valid):
     makes it a gather."""
     from ray_tpu.ops.ssm import causal_conv
 
-    T, taps = x.shape[1], w.shape[0] - 1
     conv, seen = causal_conv(conv_all[i], x, w, b)
-    if valid is None:
-        tail = seen[:, T:]
-    else:
-        tail = jax.vmap(
-            lambda row, at: jax.lax.dynamic_slice(row, (at, 0), (taps, row.shape[1]))
-        )(seen, valid.sum(axis=1, dtype=jnp.int32))
+    tail = _next_tail(seen, w.shape[0] - 1, valid)
     return jax.nn.silu(conv), jax.lax.dynamic_update_index_in_dim(
         conv_all, tail.astype(conv_all.dtype), i, 0)
+
+
+def _next_tail(seen, taps: int, valid):
+    """The ``taps`` inputs up to each row's last real token, of ``seen``
+    [B, taps + T, channels]: the row's tail and its T new inputs behind it.
+    Every token real (``valid`` None): the last ones, a plain slice; a padded
+    row's end is its own, which makes it a gather, and a row of no real token
+    keeps the tail it came with."""
+    if valid is None:
+        return seen[:, seen.shape[1] - taps:]
+    return jax.vmap(
+        lambda row, at: jax.lax.dynamic_slice(row, (at, 0), (taps, row.shape[1]))
+    )(seen, valid.sum(axis=1, dtype=jnp.int32))
 
 
 def _ssm_mix(params, lay: _Layer, xbc, dt, state_all, conv_all, valid, cfg):
@@ -1133,7 +1342,9 @@ def _kda_out(params, lay: _Layer, o, gate_low, cfg):
                           params["kda_w_out"][i])
 
 
-# The mixers that keep a state a sequence, by layer kind: the three parts
+# The mixers that keep a state a sequence and no keys, by layer kind (a kind
+# that holds a stripe and state leaves both is in ``STRIPE_STATE``, below):
+# the three parts
 # ``decode_forward`` calls (the input projection of every set's rows as one,
 # ``-> (what the mixing takes a set at a time, what the output takes)``; the
 # mixing of one set, ``-> (y, *leaves)``; gate, norm and output projection of
@@ -1150,23 +1361,41 @@ STATE_MIXERS = {
 # with the stripes), ``init_kv_cache``, ``decode_forward`` and
 # ``llm/config.py refuse_stateful`` read: a further kind adds a row above and
 # its shapes, and nothing asks which kind.
-STATE_LEAVES = tuple(name for *_, names in STATE_MIXERS.values() for name in names)
+# An attention kind may hold a state leaf beside its stripe, what its layers
+# carry from token to token that is no key and no value: the projections of
+# every set's rows as one, ``-> parts``; queries, keys and values of one set
+# from its parts and its rows of the leaf, ``-> (q, k, v, the rows' next)``;
+# and the leaf.
+STRIPE_STATE = {"cca": (_cca_project, _cca_qkv, "cca_tail")}
+STATE_LEAVES = tuple(name for *_, names in STATE_MIXERS.values() for name in names) + tuple(
+    name for *_, name in STRIPE_STATE.values())
 
 
-def _feed_forward(params, lay: _Layer, x, cfg):
-    """x + feed-forward(norm(x)), and the layer's routing counts: zeros for
-    a dense layer of a model that has expert layers, None in a model with
-    none (which carries no counts)."""
+def _router_stream(cfg, x) -> tuple:
+    """What goes round the layer loop beside ``x`` [B, T, e] for the expert
+    layers' routers: nothing, or for a router that is an MLP
+    (``moe_router_hidden``) its vector of the expert layer before, zeros in
+    front of the first."""
+    if not cfg.moe_router_hidden:
+        return ()
+    return (jnp.zeros(x.shape[:-1] + (cfg.moe_router_hidden,), x.dtype),)
+
+
+def _feed_forward(params, lay: _Layer, x, cfg, route=()):
+    """x + feed-forward(norm(x)), the layer's routing counts (zeros for a
+    dense layer of a model that has expert layers, None in a model with none,
+    which carries no counts) and ``route`` (``_router_stream``) as an expert
+    layer's router left it."""
     h = _rmsnorm(x, params["mlp_norm"][lay.ffn_i], cfg.rms_eps, cfg.fused_rmsnorm)
     if lay.sparse:
         with scope("moe_ffn"):
-            y, stats = _moe_decode_ffn(params, lay.mlp_i, h, cfg)
-            return x + _times(y, cfg.residual_multiplier), stats
+            y, stats, *route = _moe_decode_ffn(params, lay.mlp_i, h, cfg, *route)
+            return _joined(params, "mlp_scale", lay.ffn_i, x, y, cfg), stats, tuple(route)
     with scope("ffn"):
         # a layer's slice of a stacked weight is taken where it is used
         y = _dense_ffn(h, lambda name: params[name][lay.mlp_i])
-        x = x + _times(y, cfg.residual_multiplier)
-    return x, (jnp.zeros((len(MOE_STATS),), jnp.int32) if cfg.moe_experts else None)
+        x = _joined(params, "mlp_scale", lay.ffn_i, x, y, cfg)
+    return x, (jnp.zeros((len(MOE_STATS),), jnp.int32) if cfg.moe_experts else None), route
 
 
 def _run_layers(cfg, layer_fn, carry):
@@ -1239,12 +1468,18 @@ def forward_hidden(params, tokens, cfg, mesh: Optional[Mesh] = None, positions=N
         "full": jnp.broadcast_to(back >= 0, (B, T, T)),
         "sliding": jnp.broadcast_to((back >= 0) & (back < cfg.sliding_window), (B, T, T)),
     }
-    masks["latent"] = masks["full"]
+    masks["latent"] = masks["cca"] = masks["full"]
 
-    def layer(lay: _Layer, x):
+    def layer(lay: _Layer, carry):
+        x, route = carry
         h = _rmsnorm(x, params["attn_norm"][lay.l], cfg.rms_eps, cfg.fused_rmsnorm)
         if lay.latent:
             q, k, v = _latent_qkv(params, lay, h, positions, cfg)
+        elif lay.kind in STRIPE_STATE:  # the whole sequence: nothing came before it
+            project, qkv, leaf = STRIPE_STATE[lay.kind]
+            shape, dtype = state_cache_shapes(cfg, B)[leaf]
+            q, k, v, _ = qkv(params, lay, *project(params, lay, h), jnp.zeros(shape[1:], dtype),
+                             positions, None, cfg)
         else:
             q, k, v = _qkv(params, lay, h, positions, cfg)
         with scope("attn_core"), lay.inner_scope():
@@ -1255,12 +1490,13 @@ def forward_hidden(params, tokens, cfg, mesh: Optional[Mesh] = None, positions=N
                     q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), masks[lay.kind]
                 )
         x = _attn_out(params, lay, x, h, attn, cfg)
-        return _feed_forward(params, lay, x, cfg)[0]
+        x, _, route = _feed_forward(params, lay, x, cfg, route)
+        return x, route
 
     if cfg.remat:
         plain = layer
         layer = lambda lay, x: jax.checkpoint(lambda y: plain(lay, y))(x)  # noqa: E731
-    x = _run_layers(cfg, layer, x)
+    x, _ = _run_layers(cfg, layer, (x, _router_stream(cfg, x)))
     return _rmsnorm(x, params["final_norm"], cfg.rms_eps, cfg.fused_rmsnorm)
 
 
@@ -1437,7 +1673,7 @@ def _cache_reader(cfg, params, cache, positions, kinds):
     W, A = cfg.sliding_window, _WINDOW_ALIGN
     if T == 1 and reads_blocks(S, cache["k"], *jax.tree.leaves(params)):
         hi = positions[:, 0] + 1
-        lo = {"full": jnp.zeros_like(hi), "sliding": jnp.maximum(hi - W, 0)}
+        lo = {**dict.fromkeys(_FULL_KINDS, jnp.zeros_like(hi)), "sliding": jnp.maximum(hi - W, 0)}
 
         def read(q, ck_all, cv_all, lay):
             return decode_attention(q[:, 0], ck_all, cv_all, lay.kv_i, lo[lay.kind], hi)[:, None]
@@ -1447,7 +1683,7 @@ def _cache_reader(cfg, params, cache, positions, kinds):
     qpos = positions[:, :, None]  # [B, T, 1]
     slot = jnp.arange(S)[None, None, :]
     span = -(-(W + T - 1 + A - 1) // A) * A  # covers the window from an aligned start
-    whole = {"full": True, "sliding": span >= S}
+    whole = {**dict.fromkeys(_FULL_KINDS, True), "sliding": span >= S}
     if "sliding" in kinds and not whole["sliding"]:
         first = jnp.clip((positions[:, 0] - W + 1) // A * A, 0, S - span)  # [B]
         wslot = first[:, None, None] + jnp.arange(span)[None, None, :]
@@ -1460,7 +1696,7 @@ def _cache_reader(cfg, params, cache, positions, kinds):
     masks = {kind: stripe_mask(kind) for kind in _SCOPE_OF_KIND if kind in kinds}
     B = positions.shape[0]
     bk = _FULL_KEY_BLOCK
-    heads = max((h for t, h, _ in plan(cfg).kinds if t == "full"), default=0)
+    heads = max((h for t, h, _ in plan(cfg).kinds if t in _FULL_KINDS), default=0)
     in_blocks = S % bk == 0 and S > bk and B * heads * T * S * 4 > _STRIPE_SCORES_MAX_BYTES
     if in_blocks:  # row b's queries are consecutive from positions[b, 0]: the last sees furthest
         n_blocks = jnp.minimum(jnp.max(positions[:, -1]) // bk + 1, S // bk)
@@ -1494,7 +1730,7 @@ def _cache_reader(cfg, params, cache, positions, kinds):
         return (acc / den[..., None]).transpose(0, 2, 1, 3, 4).reshape(B, T, K * G, D).astype(q.dtype)
 
     def read(q, ck_all, cv_all, lay):
-        if in_blocks and lay.kind == "full":
+        if in_blocks and lay.kind in _FULL_KINDS:
             return read_blocks(q, ck_all, cv_all, lay)
         if whole[lay.kind]:
             return _grouped_attention(q, ck_all[lay.kv_i], cv_all[lay.kv_i], masks[lay.kind])
@@ -1635,7 +1871,7 @@ def decode_forward(
     # the real tokens of all rows, where any set has tokens that are none
     real = None if all(rows.valid is None for rows in sets) else _join(
         [rows.real() for rows in sets])
-    if kinds & set(STATE_MIXERS) and any(
+    if kinds & (set(STATE_MIXERS) | set(STRIPE_STATE)) and any(
         jax.typeof(x).sharding.mesh.size > 1 for x in (cache["k"], *jax.tree.leaves(params))
     ):
         raise NotImplementedError(
@@ -1657,7 +1893,7 @@ def decode_forward(
         {name: rows.cache[name] for name in STATE_LEAVES if name in rows.cache} for rows in sets)
 
     def layer(lay: _Layer, carry):
-        x, kv, stats, state = carry
+        x, kv, stats, state, route = carry
         if lay.kind in STATE_MIXERS:
             project, mix, out, names = STATE_MIXERS[lay.kind]
             h = _rmsnorm(x, params["attn_norm"][lay.mixer_i], cfg.rms_eps, cfg.fused_rmsnorm)
@@ -1672,11 +1908,23 @@ def decode_forward(
             ys = [y for y, *_ in mixed]
             y = ys[0] if len(sets) == 1 else _join(
                 [y.reshape(rows.B, rows.T, -1) for rows, y in zip(sets, ys)])
-            x = x + _times(out(params, lay, y, gate, cfg), cfg.residual_multiplier)
+            x = _joined(params, "attn_scale", lay.mixer_i, x, out(params, lay, y, gate, cfg), cfg)
         elif lay.kind != "none":
             h = _rmsnorm(x, params["attn_norm"][lay.mixer_i], cfg.rms_eps, cfg.fused_rmsnorm)
             if lay.latent:  # k: the shared rotated key; v: the normed latent
                 q, k, v = _latent_qkv(params, lay, h, positions, cfg)
+            elif lay.kind in STRIPE_STATE:
+                # each set's rows behind their own row ``attn_i`` of the leaf
+                project, qkv, leaf = STRIPE_STATE[lay.kind]
+                q, k, v, carried = zip(*(
+                    qkv(params, lay, *parts, leaves[leaf][lay.attn_i], rows.positions,
+                        rows.valid, cfg)
+                    for rows, leaves, *parts in zip(
+                        sets, state, *(_split(t, shapes) for t in project(params, lay, h)))))
+                state = tuple(
+                    {**leaves, leaf: jax.lax.dynamic_update_index_in_dim(
+                        leaves[leaf], new, lay.attn_i, 0)}
+                    for leaves, new in zip(state, carried))
             else:
                 q, k, v = _qkv(params, lay, h, positions, cfg, loras, adapter_ids)
                 q, k, v = (_split(t, shapes) for t in (q, k, v))
@@ -1698,13 +1946,14 @@ def decode_forward(
             x = _attn_out(params, lay, x, h, _join(attn), cfg, from_latent)
         if lay.mlp != "none":
             if narrow and lay.last:
-                x = _split(x, shapes)[1]
-            x, layer_stats = _feed_forward(params, lay, x, cfg)
+                x, *route = (_split(t, shapes)[1] for t in (x, *route))
+            x, layer_stats, route = _feed_forward(params, lay, x, cfg, tuple(route))
             stats = tuple(s + layer_stats for s in stats)
-        return (x, kv, stats, state)
+        return (x, kv, stats, state, route)
 
-    x, kv, stats, state = _run_layers(
-        cfg, layer, (x, tuple((rows.cache["k"], rows.cache["v"]) for rows in sets), stats0, state0))
+    x, kv, stats, state, _ = _run_layers(
+        cfg, layer, (x, tuple((rows.cache["k"], rows.cache["v"]) for rows in sets), stats0, state0,
+                     _router_stream(cfg, x)))
     new_caches = []
     for rows, (new_k, new_v), leaves in zip(sets, kv, state):
         grew = rows.T if rows is sets[0] or rows.valid is None else rows.valid.sum(

@@ -52,6 +52,13 @@ def topk_gates(params, x, top_k: int):
     logit, the top k chosen by score plus bias, which is no part of their
     weights: those are the chosen scores themselves over their sum.
 
+    At ``top_k`` 1 the renormalised weight is 1 whatever the router scored:
+    the layer's output then ignores the router's confidence. No served model
+    routes one expert through this function; the one that does route one
+    (ZAYA1: an MLP router with a stream of its own through the depth, the
+    chosen expert weighted by its probability itself) goes through
+    ``models/patterned.py _mlp_route``, which renormalises only where k > 1.
+
     Returns (probs [G, E] f32, gate_vals [G, k] f32, gate_idx [G, k])."""
     logits = (x @ params["router"]).astype(jnp.float32)  # [G, E]
     if "bias" in params:

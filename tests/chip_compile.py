@@ -176,6 +176,14 @@ def _delta_rule_cut():
         n_layers=4, gqa_layers=(3,), moe_experts_held=40, vocab_size=24576, max_seq_len=8192)
 
 
+def _convolved_attention_cut():
+    """The cut the cell ``zaya1-8b-serve-long-chat`` serves: layers 0-19 of
+    40, experts 0-7 of the router's 16, the whole vocabulary."""
+    from ray_tpu.models.llama import LlamaConfig
+
+    return LlamaConfig.zaya1_8b(n_layers=20, moe_experts_held=8, max_seq_len=4608)
+
+
 def _engine_programs(served, one_chip, rows=1):
     """The engine's own program bodies at a serving cell's shapes, as
     ``JaxEngine._compile`` jits them: name -> (function, donated, described

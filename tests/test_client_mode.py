@@ -177,13 +177,17 @@ def test_client_windowed_push_under_chunk_chaos(tmp_path):
 def test_client_same_host_arena_probe(tmp_path):
     """A same-host client (launched WITHOUT the inherited arena env) probes
     and attaches the head's native arena, so its large puts ride shared
-    memory instead of the chunked push protocol."""
+    memory instead of the chunked push protocol. The head is named by its
+    address: ``'auto'`` reads one session file a user, which a head started by
+    another process of the same test run may have rewritten meanwhile, and
+    that head goes away under the client (``test_client_auto_address`` holds
+    ``'auto'``)."""
     ray_tpu.init(num_cpus=2, mode="process")
     try:
         code = (
             "import os\nos.environ['JAX_PLATFORMS']='cpu'\n"
             "import numpy as np\nimport ray_tpu\n"
-            "ray_tpu.init(address='auto')\n"
+            f"ray_tpu.init(address={ray_tpu.cluster_address()!r})\n"
             "print('ARENA:', os.environ.get('RAY_TPU_ARENA', ''))\n"
             "big = np.arange(400_000, dtype=np.float64)\n"
             "ref = ray_tpu.put(big)\n"
