@@ -225,7 +225,9 @@ class LLMConfig:
         per prefill bucket, the middle chunk at every row count up to
         ``max_concurrent_admissions``, and one decode program per KV pool; a
         checkout's first start compiles them all (4-11 s a chunk program on a
-        v5e, 160-215 s a serving cell's whole set: PERF.md section 6, PR 34).
+        v5e at PR 34; 22-26 s a final chunk of Granite's 40 layers and 47-55 s
+        one that carries the decode step, 350 s its whole set beside 100 s of
+        weights: PERF.md section 6, PR 49, which is what 45 s a program covers).
         Doubled for sharded (gang) meshes whose jax.distributed world must
         also rendezvous. Serve uses this as ``initial_health_grace_s`` so a
         slow first jit is STARTING, not dead."""
@@ -236,4 +238,4 @@ class LLMConfig:
         programs = (len(e.prefill_buckets) + min(e.max_concurrent_admissions, CHUNK_ROWS_MAX)
                     + 1) * pools
         sharded = e.tensor_parallel_degree * e.sequence_parallel_degree > 1
-        return 120.0 + 30.0 * programs * (2 if sharded else 1)
+        return 120.0 + 45.0 * programs * (2 if sharded else 1)

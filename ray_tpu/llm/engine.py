@@ -590,11 +590,11 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         reads, so no later program may overwrite them): the slot this chunk
         activates is no decode row of the same launch (``live[slot]`` is
         false), and whatever a dead row left in its stripe the copy
-        overwrites. A row's logits can then
-        differ in the last bit by what shares its launch; a pool whose
-        answers have to be the same to the token whatever runs beside them
-        (a latent pool: ``JaxEngine.__init__``) passes no rows and runs the
-        chunk alone."""
+        overwrites; behind a carried step the copy is a plain update (below).
+        A row's logits can then differ in the last bit by what shares its
+        launch; a pool whose answers have to be the same to the token
+        whatever runs beside them (a latent pool: ``JaxEngine.__init__``)
+        passes no rows and runs the chunk alone."""
         mid_stats = one.get("moe_stats")  # the prompt's middle chunks'
         last_logits, one, *rode = prefill(
             params, one, tokens, cfg, lengths=length, start_pos=start,
@@ -607,11 +607,11 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         if stats is not None:  # rows: chunk_mid, chunk_final
             stats = jnp.stack([mid_stats, stats - mid_stats])
         total = start[0] + length[0]
-        with jax.named_scope("kv_write"):
-            cache = {
-                **{k: cache[k].at[:, slot].set(one[k][:, 0]) for k in slot_leaves},
-                "length": cache["length"].at[slot].set(total),
-            }
+        with jax.named_scope("kv_write"):  # (a scatter keeps what it drops: two copies of a cache a step wrote)
+            put = (lambda x, row: x.at[:, slot].set(row)) if rows is None else (
+                lambda x, row: jax.lax.dynamic_update_index_in_dim(x, row, slot, 1))
+            cache = {**{k: put(cache[k], one[k][:, 0]) for k in slot_leaves},
+                     "length": cache["length"].at[slot].set(total)}
         with jax.named_scope("sampling"):
             tok, new_key = sample_row(last_logits[0], temp, top_k, key)
         if rows is None:
@@ -696,29 +696,29 @@ class JaxEngine:
         # A pool's chunk programs take its decode rows, so that a chunk launch
         # can carry the pool's decode step (``_advance_admissions``): one form
         # of each program a pool, the rows an input. Decided here, once, from
-        # what the pool and the model are:
+        # what the pool and the engine are, whatever the stack (layers alike
+        # under one loop or several traced bodies, with or without a state a
+        # slot: ``models/patterned.py decode_forward`` walks any of them once
+        # for a chunk's rows and the pool's):
         # - not a latent pool: a row's logits differ in the last bit by what
         #   shares its matmuls (PERF.md section 6, PR 34), and this pool's
         #   answers are held to be the same to the token for a prompt seeded
         #   from the prefix store and computed (a hit and a miss sent at once
         #   would decode beside different chunks); its launches stay the
         #   chunk's alone until that comparison allows a rounding (ROADMAP D12);
-        # - only a stack that is traced as one layer body (``plan(cfg).bodies``:
-        #   layers alike under one loop): every chunk form then holds a decode
-        #   program's worth of tracing and lowering more, and a start pays
-        #   that in Python for each form, fetched from the compile cache or
-        #   not. One body costs a v5e host half a second a form; Laguna's five
-        #   and Nemotron's eleven bodies, each with its own kernels, would
-        #   double 24 and 31 s of warm-up, a quarter of a replica's start
-        #   (PERF.md section 6, PR 41; ROADMAP S2: a start that restores its
-        #   executables lifts this);
         # - not with adapters loaded (a row's adapter is indexed by row of a
         #   batch), ``decode_steps`` over 1 (a carried step is one step) or
         #   over a mesh.
-        from ray_tpu.models.patterned import plan
-
+        # Until PR 49 a stack of several traced bodies (Laguna's five,
+        # Nemotron's eleven) did not carry either: a form that also holds the
+        # decode step is a decode program's worth of tracing and lowering
+        # more, which every start paid in Python for each form, compile cache
+        # or not. A start now restores each form as an executable with no
+        # trace and no lowering (``_launch``, ``_private/program_store.py``,
+        # PR 46), so a carrying form costs a warm start the larger file and a
+        # checkout's first start one compile (PERF.md section 6, PR 49).
         carries = (self.loras is None and config.engine.decode_steps <= 1
-                   and not self._spans_devices() and plan(self.model_cfg).bodies == 1)
+                   and not self._spans_devices())
         for pool in self._pools:
             pool.chunk_rows = 1 if pool.latent else rows
             pool.carries = carries and not pool.latent
@@ -1863,10 +1863,10 @@ class JaxEngine:
     def _takes_rows(pool: "_Pool", rows: int = 1) -> bool:
         """Whether ``pool``'s chunk program of ``rows`` prompt rows takes the
         pool's decode rows: in a pool that ``carries``, the final chunk and
-        the middle chunk of one row. A launch of several rows is rare where
-        prompts are short and its forms are as many as its row counts, each a
-        decode program's worth of tracing more at every start: it stays the
-        chunks' alone."""
+        the middle chunk of one row. A launch of several rows stays the
+        chunks' alone: each row count is a form and would hold the decode
+        program as well, a larger file to restore at every start; a later
+        launch of the pass that takes rows carries instead (ROADMAP S2 c)."""
         return pool.carries and rows == 1
 
     def _decode_rows(self, pool: "_Pool", carry: Optional[dict]) -> dict:

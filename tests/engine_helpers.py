@@ -20,10 +20,10 @@ FAMILIES = {
     "state-space": dict(model_id="nemotron-tiny"),
     "latent": dict(model_id="kanana-tiny"),
 }
-# the families whose pool carries its decode step in a chunk launch: layers
-# alike under one loop do, a stack of several traced bodies and a latent pool
-# do not (``JaxEngine.__init__``)
-CARRYING = ("dense", "routed")
+# the families whose pool carries its decode step in a chunk launch: every
+# stack does, layers alike under one loop or several traced bodies, with or
+# without a state a slot; a latent pool does not (``JaxEngine.__init__``)
+CARRYING = ("dense", "routed", "routed-window", "state-space")
 # ``tests/test_chunk_rows*.py`` knew this family as "patterned-moe": the cases keep that name
 ROUTED_WINDOW = pytest.param("routed-window", id="patterned-moe")
 
@@ -73,6 +73,15 @@ def programs_replaced(eng, name, make):
 def launched_forms(eng) -> collections.Counter:
     """How many forms of each program ``eng`` holds, by the program's name."""
     return collections.Counter(form[0] for form in eng._programs)
+
+
+def decoding(eng, ids, sampling_params):
+    """Submit a request and come back once it decodes (two tokens out): what
+    is admitted after that finds a live row to decode beside its chunks."""
+    req = eng.submit(prompt_token_ids=ids, sampling_params=sampling_params)
+    while len(req.out_tokens) < 2:
+        assert not req.done.wait(0.001)
+    return req
 
 
 def together(eng, requests):

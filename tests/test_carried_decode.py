@@ -6,8 +6,9 @@ carrying programs against the two programs they replace, on one pool's cache a
 kind of model (the three tests share that fixture and what it has run, so
 they stay one file: apart they took three times as long). The loop that
 launches them, the counters and the pool that never carries:
-``tests/test_carried_decode_loop.py``; what an engine compiles before it
-takes requests: ``tests/test_carried_decode_warm.py``."""
+``tests/test_carried_decode_loop.py``; requests admitted beside decoding rows,
+family by family: ``tests/test_carried_decode_beside.py``; what an engine
+compiles before it takes requests: ``tests/test_carried_decode_warm.py``."""
 
 import jax
 import jax.numpy as jnp

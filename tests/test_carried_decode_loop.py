@@ -1,8 +1,9 @@
 """The loop that launches carrying chunks (``llm/engine.py
-_advance_admissions``) against the loop that does not: requests admitted
-beside decoding rows, when a first token is taken, the counters of carrying
+_advance_admissions``): when a first token is taken, the counters of carrying
 launches, and the pool that never carries (a latent pool launches the chunk
-alone). The subject: ``tests/test_carried_decode.py``."""
+alone). Requests admitted beside decoding rows, family by family:
+``tests/test_carried_decode_beside.py``. The subject:
+``tests/test_carried_decode.py``."""
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +14,7 @@ from ray_tpu.llm import JaxEngine, SamplingParams
 from ray_tpu.llm.engine import programs
 from ray_tpu.models.llama import init_kv_cache, prefill
 from ray_tpu.models.patterned import moe_stats_names
-from tests.engine_helpers import CARRYING, programs_replaced, tiny_engine as _engine
+from tests.engine_helpers import decoding, programs_replaced, tiny_engine as _engine
 
 pytestmark = pytest.mark.timeout(900) if hasattr(pytest.mark, "timeout") else []
 
@@ -24,46 +25,6 @@ def _flat(eng):
 
 def _grew(eng, before):
     return {k: v - before[k] for k, v in eng._n.items() if v != before[k]}
-
-
-@pytest.mark.parametrize("family, runahead", [(name, 1) for name in CARRYING] + [("dense", 0)])
-def test_requests_admitted_beside_decoding_rows_get_the_tokens_they_get_alone(family, runahead):
-    """A request decodes a long answer while three more are admitted, their
-    prompts of one to four chunks: the chunk launches carry the first one's
-    (then the others') decode steps. Each request, greedy or seeded, gets at
-    float32 the tokens it gets when the engine serves it alone, where no
-    launch carries anything; also with no run-ahead, where a step that decoded
-    a slot is fetched in the pass after the chunk that gave it its first
-    token."""
-    eng = _engine(family, decode_runahead=runahead)
-    try:
-        assert all(pool.carries for pool in eng._pools)
-        rng = np.random.default_rng(23)
-        prompts = [[int(t) for t in rng.integers(1, 250, n)] for n in (7, 52, 21, 40)]
-        sampling = [
-            SamplingParams(max_tokens=60, temperature=0.0, ignore_eos=True),
-            SamplingParams(max_tokens=9, temperature=0.9, seed=4, ignore_eos=True),
-            SamplingParams(max_tokens=12, temperature=0.0, ignore_eos=True),
-            SamplingParams(max_tokens=7, temperature=1.1, top_k=6, seed=8, ignore_eos=True),
-        ]
-        before = _flat(eng)
-        alone = [eng.generate(prompt_token_ids=ids, sampling_params=sp).token_ids
-                 for ids, sp in zip(prompts, sampling)]
-        assert "decode_steps_in_chunk" not in _grew(eng, before)
-        before = _flat(eng)
-        first = eng.submit(prompt_token_ids=prompts[0], sampling_params=sampling[0])
-        while len(first.out_tokens) < 2:  # it decodes
-            assert not first.done.wait(0.001)
-        rest = [eng.submit(prompt_token_ids=ids, sampling_params=sp)
-                for ids, sp in zip(prompts[1:], sampling[1:])]
-        for req in (first, *rest):
-            eng._await_done(req)
-            assert req.error is None
-        assert [req.out_tokens for req in (first, *rest)] == alone
-        grew = _grew(eng, before)
-        assert 0 < grew["decode_steps_in_chunk"] <= grew["decode_steps"]
-    finally:
-        eng.shutdown()
 
 
 class _Token:
@@ -218,10 +179,7 @@ def test_a_latent_pool_launches_the_chunk_alone():
         sp = SamplingParams(max_tokens=40, temperature=0.0, ignore_eos=True)
         with programs_replaced(eng, "chunk_mid", recording("chunk_mid")), \
                 programs_replaced(eng, "chunk_final", recording("chunk_final")):
-            first = eng.submit(prompt_token_ids=[int(t) for t in rng.integers(1, 250, 6)],
-                               sampling_params=sp)
-            while len(first.out_tokens) < 2:
-                assert not first.done.wait(0.001)
+            first = decoding(eng, [int(t) for t in rng.integers(1, 250, 6)], sp)
             second = eng.submit(prompt_token_ids=[int(t) for t in rng.integers(1, 250, 40)],
                                 sampling_params=sp)
             for req in (first, second):

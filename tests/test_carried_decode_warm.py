@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm import SamplingParams
-from tests.engine_helpers import CARRYING, FAMILIES, Compiles, launched_forms
+from tests.engine_helpers import CARRYING, FAMILIES, Compiles, decoding, launched_forms
 from tests.engine_helpers import tiny_engine as _engine
 
 pytestmark = pytest.mark.timeout(900) if hasattr(pytest.mark, "timeout") else []
@@ -33,10 +33,7 @@ def test_an_engine_warms_one_form_of_each_program_and_a_burst_compiles_none(fami
         rng = np.random.default_rng(3)
         sp = SamplingParams(max_tokens=24, temperature=0.0, ignore_eos=True)
         with Compiles() as burst:
-            first = eng.submit(prompt_token_ids=[int(t) for t in rng.integers(1, 250, 5)],
-                               sampling_params=sp)
-            while len(first.out_tokens) < 2:
-                assert not first.done.wait(0.001)
+            first = decoding(eng, [int(t) for t in rng.integers(1, 250, 5)], sp)
             reqs = [first] + [
                 eng.submit(prompt_token_ids=[int(t) for t in rng.integers(1, 250, n)],
                            sampling_params=SamplingParams(max_tokens=4, ignore_eos=True,

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm import EngineConfig, JaxEngine, LLMConfig, ModelConfig, SamplingParams
+from tests.engine_helpers import decoding
 from tests.kda_models import PUBLISHED
 
 @pytest.fixture(scope="module")
@@ -102,6 +103,29 @@ def test_requests_admitted_together_answer_as_each_alone(engine):
     rows = now["prefill_chunks"]["mid"] - before["prefill_chunks"]["mid"]
     launches = now["prefill_programs"]["mid"] - before["prefill_programs"]["mid"]
     assert rows == 3 + 3 + 3 + 1 + 3 and launches < rows
+
+
+def test_requests_admitted_beside_decoding_rows_answer_as_each_alone(engine):
+    """One request decodes a long answer while two more are admitted, their
+    prompts of four chunks: the chunk launches carry its (then their) decode
+    steps, so a live row's delta-rule state and convolution tails advance
+    inside a chunk program, once a pass. Every request gets the tokens it
+    gets when the engine serves it alone, where no launch carries anything."""
+    assert all(pool.carries for pool in engine._pools)
+    prompts = [_prompt(40 + i, n) for i, n in enumerate((9, 29, 26))]
+    sampling = [SamplingParams(max_tokens=n, temperature=0.0, ignore_eos=True) for n in (40, 6, 9)]
+    before = engine.get_stats()["counters"]["decode_steps_in_chunk"]
+    alone = [engine.generate(prompt_token_ids=p, sampling_params=sp).token_ids
+             for p, sp in zip(prompts, sampling)]
+    assert engine.get_stats()["counters"]["decode_steps_in_chunk"] == before
+    first = decoding(engine, prompts[0], sampling[0])
+    rest = [engine.submit(prompt_token_ids=p, sampling_params=sp)
+            for p, sp in zip(prompts[1:], sampling[1:])]
+    for req in (first, *rest):
+        engine._await_done(req)
+        assert req.error is None
+    assert [list(req.out_tokens) for req in (first, *rest)] == [list(t) for t in alone]
+    assert engine.get_stats()["counters"]["decode_steps_in_chunk"] > before
 
 
 def test_engine_counts_the_state_a_slot_holds_and_the_assignments_held(engine):

@@ -42,23 +42,30 @@ def test_positions_read_counts_the_blocks_each_slots_bounds_cover():
     eng = JaxEngine(LLMConfig(
         model=ModelConfig(model_id="laguna-tiny", seed=3),
         engine=EngineConfig(max_num_seqs=4, max_seq_len=stripe, dtype="float32",
-                            prefill_chunk=64, prefill_buckets=(16, 32, 64)),
+                            prefill_chunk=64, prefill_buckets=(16, 32, 64),
+                            max_concurrent_admissions=1),  # nothing here speaks of rows
     ))
     try:
         window = eng.model_cfg.sliding_window
         want = {"full": 0, "window": 0, "whole": 0}
-        launch = eng._decode
+        launch, carried = eng._decode, eng._carried
 
-        def counting(pool, *args):  # called by the loop right before it counts
-            for r in pool.slots:
-                if r is not None:
-                    n = len(r.prompt_token_ids) + len(r.out_tokens)
-                    want["full"] += eng._decode_n_steps * positions_read(0, n, stripe)
-                    want["window"] += eng._decode_n_steps * positions_read(n - window, n, stripe)
-                    want["whole"] += eng._decode_n_steps * stripe
+        def count(requests):  # called by the loop right before it counts
+            for r in requests:
+                n = len(r.prompt_token_ids) + len(r.out_tokens)
+                want["full"] += eng._decode_n_steps * positions_read(0, n, stripe)
+                want["window"] += eng._decode_n_steps * positions_read(n - window, n, stripe)
+                want["whole"] += eng._decode_n_steps * stripe
+
+        def counting(pool, *args):
+            count(r for r in pool.slots if r is not None)
             return launch(pool, *args)
 
-        eng._decode = counting
+        def counting_carried(pool, carry, next_tokens):  # a step a chunk launch carried
+            count((carry or {}).values())
+            return carried(pool, carry, next_tokens)
+
+        eng._decode, eng._carried = counting, counting_carried
         rng = np.random.default_rng(9)
         p = SamplingParams(max_tokens=24, temperature=0.0, ignore_eos=True)
         reqs = [eng.submit(prompt_token_ids=[int(t) for t in rng.integers(32, 127, n)],

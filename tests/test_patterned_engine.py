@@ -23,7 +23,8 @@ def engine():
     eng = JaxEngine(LLMConfig(
         model=ModelConfig(model_id="laguna-tiny", seed=3),
         engine=EngineConfig(max_num_seqs=4, max_seq_len=128, dtype="float32",
-                            prefill_chunk=16, prefill_buckets=(8, 16, 32)),
+                            prefill_chunk=16, prefill_buckets=(8, 16, 32),
+                            max_concurrent_admissions=1),  # no test here speaks of rows
     ))
     yield eng
     eng.shutdown()
@@ -58,9 +59,14 @@ def test_routing_and_window_counters_on_a_known_batch(engine):
     assert c1["prefill_chunks"]["mid"] - c0["prefill_chunks"]["mid"] == 2
     # a prompt's middle chunks add theirs up on the device; its final chunk hands both out
     assert grew["moe_layer_steps"]["chunk_mid"] == 2 * expert_layers
-    assert grew["moe_assignments"]["chunk_mid"] == k * (16 + 16) * expert_layers
     assert grew["moe_layer_steps"]["chunk_final"] == expert_layers
-    assert grew["moe_assignments"]["chunk_final"] == k * 8 * expert_layers
+    # the pool carries, so a chunk program routes the pool's rows beside the
+    # chunk's tokens in every expert layer, live or not (none is, here: one
+    # request); a middle chunk's last feed-forward is those rows' alone,
+    # since no logits are read behind it
+    assert engine._pools[0].carries
+    assert grew["moe_assignments"]["chunk_mid"] == 2 * k * ((16 + slots) * expert_layers - 16)
+    assert grew["moe_assignments"]["chunk_final"] == k * (8 + slots) * expert_layers
     steps = c1["decode_steps"] - c0["decode_steps"]
     assert steps >= 4
     assert grew["moe_layer_steps"]["decode"] == steps * expert_layers
