@@ -14,7 +14,11 @@ the TPU engine keeps everything static for XLA:
   finished slots free instantly, waiting requests prefill into free slots
   while other slots keep decoding (no global barrier on admission);
 - sampling (greedy / temperature / top-k) runs in-program; only sampled
-  token ids cross back to the host each step.
+  token ids cross back to the host each step. A row's 64 candidates are the
+  one-stage ``lax.top_k``'s, values and indices; over a wide vocabulary they
+  are found in two exact stages (``ops/topk.py``: a maximum a 128-lane
+  block, then the 64 best among the 64 winning blocks' 8,192 logits), so no
+  program sorts a whole vocabulary.
 
 TP/SP: params and cache shard over a mesh via the model's logical rules
 (``parallel/mesh.py``) when ``tensor_parallel_degree > 1``.
@@ -435,6 +439,7 @@ def programs(cfg, decode_steps: int = 1) -> dict:
 
     from ray_tpu.models.llama import decode_step, init_kv_cache, prefill
     from ray_tpu.models.patterned import moe_stats_names, state_cache_shapes
+    from ray_tpu.ops import topk
 
     # what a slot holds, each leaf with the slot on axis 1: its stripes of keys
     # and values, and for a model with layers that keep a state their states
@@ -458,19 +463,34 @@ def programs(cfg, decode_steps: int = 1) -> dict:
             return cache
         return dict(cache, moe_stats=jnp.zeros((n_stats,), jnp.int32))
 
-    def sample_row(logits_row, temp, top_k, key):
-        """Sample one token from [V] fp32 logits: greedy where temp<=0,
-        else top-k/temperature categorical. The ONE sampler — the decode
-        program vmaps it and the prefill first token calls it directly,
-        so seeded runs cannot diverge at token 2."""
-        greedy = jnp.argmax(logits_row, -1)
-        vals, idxs = jax.lax.top_k(logits_row, K)
+    def candidates(logits):
+        """Of ``[..., V]`` fp32 logits the greedy token and the ``K`` largest
+        with their indices (``ops/topk.py``: the one-stage ``lax.top_k``'s
+        values and indices, from two stages where the vocabulary is wide)."""
+        return (jnp.argmax(logits, -1), *topk.top_k(logits, K))
+
+    def draw(greedy, vals, idxs, temp, top_k, key):
+        """One row's token from its candidates: greedy where temp<=0, else
+        top-k/temperature categorical over the first ``top_k`` of them."""
         rank_ok = jnp.arange(K) < top_k
         scaled = jnp.where(rank_ok, vals / jnp.maximum(temp, 1e-6), -jnp.inf)
         key, sub = jax.random.split(key)
         sampled = idxs[jax.random.categorical(sub, scaled)]
         tok = jnp.where(temp <= 0.0, greedy, sampled).astype(jnp.int32)
         return tok, key
+
+    def sample_row(logits_row, temp, top_k, key):
+        """Sample one token from [V] fp32 logits. The ONE sampler — the
+        decode program runs it over its rows (``sample_rows``) and the
+        prefill first token calls it directly, so seeded runs cannot diverge
+        at token 2."""
+        return draw(*candidates(logits_row), temp, top_k, key)
+
+    def sample_rows(logits, temps, top_ks, keys):
+        """``sample_row`` of each of ``[rows, V]``: the candidates of all rows
+        at once (the selection tiles the rows as the chip holds them), then a
+        draw a row."""
+        return jax.vmap(draw)(*candidates(logits), temps, top_ks, keys)
 
     def decode_fn(params, cache, tokens, temps, top_ks, keys,
                   loras=None, adapter_ids=None):
@@ -485,9 +505,7 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         )
         stats = cache.pop("moe_stats", None)
         with jax.named_scope("sampling"):
-            next_tokens, new_keys = jax.vmap(sample_row)(
-                logits, temps, top_ks, keys
-            )
+            next_tokens, new_keys = sample_rows(logits, temps, top_ks, keys)
         return next_tokens, cache, new_keys, stats
 
     def sample_riders(logits, rows):
@@ -496,7 +514,7 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         cache, and ``live``): each live row sampled by the one ``sample_row``,
         every other row's token and key as they came in."""
         with jax.named_scope("sampling"):
-            next_tokens, new_keys = jax.vmap(sample_row)(
+            next_tokens, new_keys = sample_rows(
                 logits, rows["temps"], rows["top_ks"], rows["keys"]
             )
             live = rows["live"]

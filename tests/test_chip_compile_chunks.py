@@ -100,18 +100,20 @@ def test_the_engines_decode_program_is_decode_step_and_the_one_sampler(
         served, one_chip, no_compile_cache, native_kernels):
     """``jit_decode_fn`` is not touched by what groups the chunk programs:
     the engine's ``decode_fn`` compiles to the text of ``decode_step`` over
-    every slot with the one sampler mapped over its rows, spelled out here as
-    the engine had it before chunk programs took rows (PR 34), source lines
-    apart."""
+    every slot with the one sampler over its rows, spelled out here as the
+    engine had it before chunk programs took rows (PR 34), source lines
+    apart; since PR 50 the rows' candidates are ``ops/topk.py top_k``'s, of
+    all rows at once (at these widths its two stages), and the draw is mapped
+    over the rows."""
     from ray_tpu.models.llama import decode_step
+    from ray_tpu.ops import topk
 
     cfg = _served_config(served)
     fn, donated, args = _engine_programs(served, one_chip)["decode_fn"]
     K = min(64, cfg.vocab_size)
+    assert topk.two_stage(cfg.vocab_size, K)
 
-    def sample_row(logits_row, temp, top_k, key):
-        greedy = jnp.argmax(logits_row, -1)
-        vals, idxs = jax.lax.top_k(logits_row, K)
+    def draw(greedy, vals, idxs, temp, top_k, key):
         rank_ok = jnp.arange(K) < top_k
         scaled = jnp.where(rank_ok, vals / jnp.maximum(temp, 1e-6), -jnp.inf)
         key, sub = jax.random.split(key)
@@ -125,7 +127,8 @@ def test_the_engines_decode_program_is_decode_step_and_the_one_sampler(
         logits, cache = decode_step(params, cache, tokens, cfg)
         stats = cache.pop("moe_stats", None)
         with jax.named_scope("sampling"):
-            next_tokens, new_keys = jax.vmap(sample_row)(logits, temps, top_ks, keys)
+            next_tokens, new_keys = jax.vmap(draw)(
+                jnp.argmax(logits, -1), *topk.top_k(logits, K), temps, top_ks, keys)
         return next_tokens, cache, new_keys, stats
 
     def same(text):  # a Mosaic kernel's bytecode names source lines too

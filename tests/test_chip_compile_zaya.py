@@ -1,8 +1,8 @@
 """The programs of the compressed-convolutional-attention cut (ZAYA1-8B, 20
 layers, 8 of 16 experts held, 64 slots of 4,608) compile at real widths for a
 described v5e (``tests/chip_compile.py`` says how, and what that proves): the
-decode step over every slot and the middle chunk at 1,024 tokens by one to
-four rows."""
+decode step over every slot, the engine's decode program with its sampler, and
+the middle chunk at 1,024 tokens by one to four rows."""
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +46,51 @@ def test_decode_step_reads_stripes_and_banks_through_their_kernels_and_the_tails
     whole = ("bf16[20,64,1,2688]", "bf16[20,64,2,4608,128]", "bf16[20,8,2048,2048]")
     assert [line.strip()[:120] for line in lines
             if " copy(" in line and line.split(" = ", 1)[-1].startswith(whole)] == []
+
+
+def test_decode_program_sorts_block_maxima_and_winning_blocks_never_the_vocabulary(
+        one_chip, no_compile_cache, native_kernels):
+    """The engine's decode program (the step and the sampler over 64 slots of a
+    262,272-wide vocabulary): the sampler's selection (``ops/topk.py``) sorts
+    the 2,049 block maxima a row and the 64 winning blocks' 8,192 numbers, and
+    nothing else under ``sampling`` is as wide as a row but the greedy
+    ``argmax``. The maxima leave the head's own fusion (the rows are viewed
+    in their tiles of eight, so no copy of the 67 MB of logits stands between
+    the head and the sorts)."""
+    import re
+
+    from ray_tpu.llm.engine import programs
+    from ray_tpu.ops import topk
+
+    cfg = _convolved_attention_cut()
+    params, cache, tokens = _served_programs(cfg, SLOTS, STRIPE, one_chip)["decode_step"][1]
+
+    def sds(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(programs(cfg)["decode_fn"], donate_argnums=(1,)).lower(
+        params, cache, tokens, sds(jnp.float32, SLOTS), sds(jnp.int32, SLOTS),
+        sds(jnp.uint32, SLOTS, 2)).compile()
+    lines = compiled.as_text().splitlines()
+    nb, k = cfg.vocab_size // topk.BLOCK, 64
+    assert cfg.vocab_size == 262272 and nb == 2049 and topk.two_stage(cfg.vocab_size, k)
+    shapes = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = \(?\w+\[([\d,]*)\]", "\n".join(lines), re.M))
+    sorted_widths = set()
+    for line in lines:
+        called = re.search(r" (?:sort|custom-call)\((%[\w.\-]+)", line)
+        if called and (" sort(" in line or 'custom_call_target="TopK"' in line):
+            sorted_widths.add(max(int(n) for n in shapes[called.group(1)].split(",")))
+    assert sorted_widths == {nb, k, k * topk.BLOCK}, sorted_widths
+    sampling = [line for line in lines if "/sampling/" in line]
+    assert sampling
+    # no operation under the scope copies or reshapes the logits
+    moved = [line.strip()[:160] for line in sampling
+             if re.search(r"= f32\[(64,262272|64,2049,128|8,8,2049,128)\]\S* (copy|reshape|transpose)\(", line)]
+    assert moved == []
+    # the maxima are an output of the head's fusion
+    assert any("f32[8,8,2049]" in line and "f32[64,262272]" in line and "lm_head" in line
+               and " fusion(" in line for line in lines)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
 
 
 @pytest.mark.parametrize("rows", [1, 2, 4])

@@ -199,6 +199,14 @@ _PARENT = {
 }
 
 
+def _histogram(text):
+    """Of a lowered program's text: operations in all, a digest of their
+    histogram by name, the histogram."""
+    ops = dict(sorted(collections.Counter(
+        re.findall(r"= \"?((?:stablehlo|func|chlo)\.[\w.]+)", text)).items()))
+    return sum(ops.values()), hashlib.sha1(json.dumps(ops).encode()).hexdigest()[:12], ops
+
+
 def _digest(cfg, chunk: bool):
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     cache = jax.eval_shape(lambda: init_kv_cache(cfg, 2, 128))
@@ -209,10 +217,8 @@ def _digest(cfg, chunk: bool):
     else:
         text = jax.jit(lambda p, c, t: decode_step(p, c, t, cfg)).lower(
             params, cache, i32(2)).as_text()
-    ops = dict(sorted(collections.Counter(
-        re.findall(r"= \"?((?:stablehlo|func|chlo)\.[\w.]+)", text)).items()))
-    return (sum(ops.values()), hashlib.sha1(json.dumps(ops).encode()).hexdigest()[:12],
-            ops.get("stablehlo.dot_general"))
+    total, digest, ops = _histogram(text)
+    return total, digest, ops.get("stablehlo.dot_general")
 
 
 @pytest.mark.parametrize("chunk", [False, True], ids=["decode", "chunk"])
@@ -222,3 +228,32 @@ def test_the_other_families_programs_are_what_the_parent_traced(preset, chunk):
     of any accepted family than before the four scalars came in: a scalar at
     its default is no operation."""
     assert _digest(getattr(LlamaConfig, preset)(), chunk) == _PARENT[preset][chunk]
+
+
+# the engine's decode program (``llm/engine.py programs`` ``decode_fn``: the
+# step and the sampler over 4 slots) of each family's tiny preset, as PR 49's
+# commit lowered it: operations in all and the digest of their histogram. A
+# 256-wide vocabulary is two blocks of the sampler's selection
+# (``ops/topk.py``), so its 64 candidates come from the plain ``lax.top_k``
+# and the program is the parent's, whatever the selection does to wide rows.
+_PARENT_ENGINE = {
+    "tiny": (2469, "d26e65b01eb9"),
+    "laguna_tiny": (11755, "57af7d8db220"),
+    "kanana_tiny": (5866, "bffe4e7d3de5"),
+    "nemotron_tiny": (5052, "a9610bc367f1"),
+    "solar_tiny": (5339, "8f4d1ddca193"),
+}
+
+
+@pytest.mark.parametrize("preset", sorted(_PARENT_ENGINE))
+def test_a_narrow_vocabularys_decode_program_is_what_the_parent_traced(preset):
+    from ray_tpu.llm.engine import programs
+
+    cfg = getattr(LlamaConfig, preset)()
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: init_kv_cache(cfg, 4, 128))
+    sds = jax.ShapeDtypeStruct
+    text = jax.jit(programs(cfg)["decode_fn"]).lower(
+        params, cache, sds((4,), jnp.int32), sds((4,), jnp.float32), sds((4,), jnp.int32),
+        sds((4, 2), jnp.uint32)).as_text()
+    assert _histogram(text)[:2] == _PARENT_ENGINE[preset]
