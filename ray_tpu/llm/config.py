@@ -101,7 +101,7 @@ class ModelConfig:
     # "laguna-tiny" | "kanana-2-30b-a3b" | "kanana-tiny" |
     # "nemotron-3-super-120b-a12b" | "nemotron-tiny" | "solar-open2-250b" |
     # "solar-tiny" | "granite-4.0-h-micro" | "granite-tiny" | "zaya1-8b" |
-    # "zaya-tiny"
+    # "zaya-tiny" | "dots3-note-prev" | "dots3-tiny"
     model_id: str = "tiny"
     tokenizer: str = "byte"  # "byte" | transformers tokenizer path
     checkpoint_path: Optional[str] = None  # ray_tpu.train pytree checkpoint
@@ -146,6 +146,8 @@ def resolve_llama_config(model: "ModelConfig", engine: "EngineConfig", min_vocab
         "granite-tiny": LlamaConfig.granite_tiny,
         "zaya1-8b": LlamaConfig.zaya1_8b,
         "zaya-tiny": LlamaConfig.zaya_tiny,
+        "dots3-note-prev": LlamaConfig.dots3_note_prev,
+        "dots3-tiny": LlamaConfig.dots3_tiny,
     }
     kw = dict(
         max_seq_len=engine.max_seq_len,
@@ -172,6 +174,23 @@ def refuse_latent(cfg, module: str) -> None:
             f"{cfg.kv_latent_rank}) is served on one device by llm/engine.py "
             "JaxEngine with tensor_parallel_degree=1; this path has no rule "
             "for its cache"
+        )
+
+
+def refuse_further_stripes(cfg, module: str) -> None:
+    """A hand-over of keys and values (``llm/disagg.py``) moves a slot's
+    ``k`` and ``v`` and nothing else: a model whose cache holds further
+    stripes (a second latent kind's, an indexer's keys: ``models/patterned.py
+    stripe_cache_shapes``) is refused by name rather than decoded over stripes
+    that never arrived."""
+    from ray_tpu.models.patterned import stripe_cache_shapes
+
+    more = [name for name in stripe_cache_shapes(cfg, 1, 1) if name not in ("k", "v")]
+    if more:
+        raise NotImplementedError(
+            f"{module}: a model whose cache holds the stripes {', '.join(more)} beside k and v "
+            "(latent attention of two widths, an indexer) is served on one device by "
+            "llm/engine.py JaxEngine; this path hands over k and v alone"
         )
 
 

@@ -20,7 +20,7 @@ from typing import Any, Optional
 import numpy as np
 
 from ray_tpu.llm.config import (
-    LLMConfig, SamplingParams, refuse_stateful, resolve_llama_config,
+    LLMConfig, SamplingParams, refuse_further_stripes, refuse_stateful, resolve_llama_config,
 )
 
 
@@ -34,7 +34,9 @@ class PrefillWorker:
         from ray_tpu.llm.engine import JaxEngine
 
         # the hand-over is keys and values alone: before any weight is made
-        refuse_stateful(resolve_llama_config(llm_config.model, llm_config.engine), "llm/disagg.py")
+        model_cfg = resolve_llama_config(llm_config.model, llm_config.engine)
+        refuse_stateful(model_cfg, "llm/disagg.py")
+        refuse_further_stripes(model_cfg, "llm/disagg.py")
         # reuse the engine's model construction, not its slot loop
         self._engine_shell = JaxEngine.__new__(JaxEngine)
         self._engine_shell.config = llm_config
@@ -87,7 +89,9 @@ class DecodeWorker:
         from ray_tpu.llm.engine import JaxEngine
         from ray_tpu.llm.tokenizer import get_tokenizer
 
-        refuse_stateful(resolve_llama_config(llm_config.model, llm_config.engine), "llm/disagg.py")
+        model_cfg = resolve_llama_config(llm_config.model, llm_config.engine)
+        refuse_stateful(model_cfg, "llm/disagg.py")
+        refuse_further_stripes(model_cfg, "llm/disagg.py")
         shell = JaxEngine.__new__(JaxEngine)
         shell.config = llm_config
         shell.tokenizer = get_tokenizer(llm_config.model.tokenizer)

@@ -108,6 +108,12 @@ COUNTERS = (
     # longer, ``ops/decode_attention.py LATENT_BLOCKS``); such a model has no
     # full or window layers, so the four above stay 0 for it
     "decode_kv_tokens_latent", "decode_kv_positions_read_latent",
+    # a model whose latent layers attend what an indexer picks
+    # (``LlamaConfig.index_topk``): the positions a decode launch's rows
+    # scored in an indexed layer (their lengths) and those they then attended
+    # (each row's length or ``index_topk``, the smaller), from the host's own
+    # lengths like the pairs above; 0 for every other model
+    "index_positions_scored", "index_positions_selected",
     # a prompt chunk's attention, by program: the chunk's real tokens, and
     # over them the cached positions each attends to (a token at position p
     # sees p + 1: what came before the chunk and the chunk up to itself)
@@ -355,7 +361,7 @@ class _Pool:
         import jax
 
         from ray_tpu.models.llama import init_kv_cache
-        from ray_tpu.models.patterned import STATE_LEAVES, reads_blocks
+        from ray_tpu.models.patterned import STATE_LEAVES, reads_blocks, stripe_cache_shapes
 
         self.stripe_len = stripe_len
         self.n_slots = n_slots
@@ -368,7 +374,10 @@ class _Pool:
         # the device holds them (a minor axis narrower than the chip's 128
         # lanes is padded to them); None where the backend reports no memory
         tokens = n_slots * stripe_len
-        self.kv_bytes_per_token = (self.cache["k"].nbytes + self.cache["v"].nbytes) / tokens
+        # every stripe a slot holds: ``k`` and ``v``, and whatever the model's
+        # layers keep beside them a token (``models/llama.py init_kv_cache``)
+        self.stripes = tuple(stripe_cache_shapes(model_cfg, 1, 1))
+        self.kv_bytes_per_token = sum(self.cache[name].nbytes for name in self.stripes) / tokens
         # what a slot holds whatever its length (the state and convolution
         # tails of the layers that keep one, whether such a layer has a stripe
         # of keys and values as well or none): 0 for a model whose slots are
@@ -438,14 +447,14 @@ def programs(cfg, decode_steps: int = 1) -> dict:
     import jax.numpy as jnp
 
     from ray_tpu.models.llama import decode_step, init_kv_cache, prefill
-    from ray_tpu.models.patterned import moe_stats_names, state_cache_shapes
+    from ray_tpu.models.patterned import moe_stats_names, state_cache_shapes, stripe_cache_shapes
     from ray_tpu.ops import topk
 
     # what a slot holds, each leaf with the slot on axis 1: its stripes of keys
     # and values, and for a model with layers that keep a state their states
     # and convolution tails (``STATE_LEAVES``), which are stacked, unstacked,
     # zeroed and copied into a slot with the stripes
-    slot_leaves = ("k", "v", *state_cache_shapes(cfg, 1))
+    slot_leaves = (*stripe_cache_shapes(cfg, 1, 1), *state_cache_shapes(cfg, 1))
     n_stats = len(moe_stats_names(cfg))
 
     # one static top-K for the decode program AND the prefill first-token
@@ -647,13 +656,15 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         return one
 
     @jax.named_scope("prefix_seed")
-    def seed_prefix(one, pk, pv, state=None):
-        """Copy a cached prefix KV [L, K, m, D] into the scratch stripe (``state``: below)."""
+    def seed_prefix(one, pk, pv, state=None, more=None):
+        """Copy a cached prefix KV [L, K, m, D] into the scratch stripe (``state``:
+        below; ``more``: the same cut of every further stripe the cache holds, by name)."""
         m = pk.shape[2]
         return {
             **one, **(state or {}),
             "k": one["k"].at[:, 0, :, :m].set(pk),
             "v": one["v"].at[:, 0, :, :m].set(pv),
+            **{name: one[name].at[:, 0, :, :m].set(cut) for name, cut in (more or {}).items()},
         }
 
     # A pool whose slots hold a state stores a prefix as a snapshot of the
@@ -1047,11 +1058,14 @@ class JaxEngine:
     def _new_stripe(self, stripe_len: int):
         return self._launch(("new_stripe", stripe_len), "_new_stripe_jit", static=(stripe_len,))
 
-    def _seed_prefix(self, one: dict, k, v, state: Optional[dict] = None):
-        """``one`` seeded with a stored prefix's keys and values (and, of a
-        snapshot, its ``state`` leaves)."""
+    def _seed_prefix(self, one: dict, k, v, state: Optional[dict] = None,
+                     more: Optional[dict] = None):
+        """``one`` seeded with a stored prefix's keys and values (of a
+        snapshot, its ``state`` leaves too; of a cache with further stripes,
+        ``more``: their cuts by name)."""
         form = ("seed_prefix", one["k"].shape[3], k.shape[2], state is not None)
-        return self._launch(form, "_seed_prefix_jit", one, k, v, *(() if state is None else (state,)))
+        return self._launch(form, "_seed_prefix_jit", one, k, v,
+                            *(() if state is None else (state,)), **({"more": more} if more else {}))
 
     def _store_snapshot(self, one: dict, positions: int):
         return self._launch(("store_snapshot", one["k"].shape[3], positions),
@@ -1186,8 +1200,7 @@ class JaxEngine:
                 for b in self.config.engine.prefill_buckets:
                     if b < pool.stripe_len:
                         book("seed_prefix", self._seed_prefix(
-                            self._new_stripe(stripe),
-                            pool.cache["k"][:, 0, :, :b], pool.cache["v"][:, 0, :, :b]))
+                            self._new_stripe(stripe), **self._prefix_cut(pool, 0, b)))
             for _ in range(kinds):  # the cache, keys and tokens as a chunk left them, then as a step did
                 out, pool.cache, pool.keys, _ = self._decode(
                     pool, pool.dev_tokens, *pool.sampler(), pool.keys,
@@ -1272,12 +1285,22 @@ class JaxEngine:
             if key in self._prefix_cache:
                 self._prefix_cache.move_to_end(key)
                 continue
-            k = pool.cache["k"][:, slot, :, :b]  # [L, K, b, D]
-            v = pool.cache["v"][:, slot, :, :b]
-            nbytes = int(k.nbytes + v.nbytes)
-            self._prefix_cache[key] = {"k": k, "v": v, "nbytes": nbytes}
-            self._prefix_bytes += nbytes
+            entry = self._prefix_cut(pool, slot, b)
+            entry["nbytes"] = sum(
+                int(x.nbytes) for x in (entry["k"], entry["v"], *entry.get("more", {}).values()))
+            self._prefix_cache[key] = entry
+            self._prefix_bytes += entry["nbytes"]
         self._prefix_evict()
+
+    @staticmethod
+    def _prefix_cut(pool: _Pool, slot: int, b: int) -> dict:
+        """The first ``b`` positions of every stripe of ``slot``, each
+        [layers, K, b, D]: ``k`` and ``v``, and under ``more`` by name the
+        further stripes of a cache that has any (``_seed_prefix`` takes them
+        so)."""
+        cut = {name: pool.cache[name][:, slot, :, :b] for name in pool.stripes}
+        k, v = cut.pop("k"), cut.pop("v")
+        return {"k": k, "v": v, **({"more": cut} if cut else {})}
 
     def _prefix_evict(self) -> int:
         """Drop entries from the front until both budgets hold; how many went."""
@@ -1821,7 +1844,8 @@ class JaxEngine:
         if prefix is not None:
             with self._device_call("launch", "seed_prefix", "engine.prefix_seed"):
                 # (a snapshot: the state leaves with the keys and values)
-                one = self._seed_prefix(one, prefix["k"], prefix["v"], prefix.get("state"))
+                one = self._seed_prefix(
+                    one, prefix["k"], prefix["v"], prefix.get("state"), prefix.get("more"))
                 if "state" in prefix:
                     self._count({"snapshots_hit": 1, "snapshot_seed_bytes": prefix["nbytes"]})
                 self._count({"prefix_seed_tokens": m})
@@ -2143,11 +2167,17 @@ class JaxEngine:
             ("decode_kv_tokens_latent", "decode_kv_positions_read_latent")
             if pool.latent else ("decode_kv_tokens_global", "decode_kv_positions_read")
         )
+        topk = self.model_cfg.index_topk
         counts = {
             "decode_steps": steps, "decode_slot_steps": steps * len(active),
             tokens: steps * int(lengths.sum()),
-            read: steps * pool.positions_read(0, lengths),
+            # (an indexed layer scores the index keys of a slot's whole stripe)
+            read: steps * (pool.stripe_len * len(active) if topk
+                           else pool.positions_read(0, lengths)),
         }
+        if topk:
+            counts["index_positions_scored"] = steps * int(lengths.sum())
+            counts["index_positions_selected"] = steps * int(np.minimum(lengths, topk).sum())
         window = self.model_cfg.sliding_window
         if window:  # a model without one has no window layers to count for
             counts["decode_kv_tokens_window"] = steps * int(

@@ -211,6 +211,30 @@ class LlamaConfig:
     # a learned vector on the stream and one on the branch wherever a branch
     # joins the stream: x <- a * x + b * branch (``attn_scale``, ``mlp_scale``)
     residual_scales: bool = False
+    # --- latent attention of two widths, a query latent and a learned indexer
+    # (dots-studio dots3-note-prev, ``LlamaConfig.dots3_note_prev``) ---
+    # ``q_latent_rank`` > 0: a latent layer's queries come through a normed
+    # latent of that width (DeepSeek-V3's ``q_lora_rank``). A second latent
+    # kind, layer type 'latent_sliding', has sizes of its own (the fields that
+    # end in ``_sliding``; its queries' heads in ``heads_per_layer``), rotates
+    # at ``rope_theta_sliding`` and sees ``sliding_window`` positions, its own
+    # among them. ``latent_rescale``: each normed latent times
+    # ``sqrt(d_model / its rank)``.
+    q_latent_rank: int = 0
+    latent_rescale: bool = False
+    kv_latent_rank_sliding: int = 0
+    q_latent_rank_sliding: int = 0
+    qk_nope_dim_sliding: int = 0
+    qk_rope_dim_sliding: int = 0
+    v_head_dim_sliding: int = 0
+    # ``index_topk`` > 0 (DeepSeek-V3.2's indexer, on the 'latent' layers): a
+    # token's cache entry holds one more key, ``index_head_dim`` wide, and a
+    # query attends only the ``index_topk`` positions whose keys score highest
+    # against its ``index_heads`` index queries (taken from the query latent),
+    # ties to the lower position; all of them while there are no more
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
 
     def __post_init__(self):
         if self.attention not in ("full", "ring", "ulysses", "splash"):
@@ -582,6 +606,56 @@ class LlamaConfig:
         return LlamaConfig.zaya1_8b(**d)
 
 
+    @staticmethod
+    def dots3_note_prev(**kw) -> "LlamaConfig":
+        """dots-studio dots3-note-prev's language model (``model_type:
+        dots3_note``, 288B-A17B) as its config.json has it: 46 layers of
+        width 5120; layers 0, 1, 5, 9 .. 45 latent attention with a query
+        latent of 1024 (128 heads of 128 + 64, a 512-wide key-value latent,
+        values 128, theta 8e7) under DeepSeek-V3.2's indexer (64 heads of 128,
+        2,048 positions a query), the other 33 latent attention of their own
+        sizes (64 heads of 192 + 64, both latents 1024, theta 50,000) over a
+        window of 513 positions; a sigmoid gate a head on every layer's
+        heads; both normed latents rescaled (``latent_rescale``); layer 0 a
+        dense SwiGLU of 13824, then 256 sigmoid-routed experts of width 1536,
+        8 a token, renormalised, beside one shared. A caller that cuts
+        ``n_layers`` gets the first entries of the per-layer lists. Not in
+        the config: the selection bias (seeded small), the vision and audio
+        towers and the multi-token-prediction module (left out)."""
+        d = dict(
+            vocab_size=152064, d_model=5120, n_layers=46, n_heads=128, n_kv_heads=1,
+            d_ff=13824, max_seq_len=524288, rms_eps=1e-5, rope_theta=8e7,
+            q_latent_rank=1024, kv_latent_rank=512, qk_nope_dim=128, qk_rope_dim=64,
+            v_head_dim=128, rope_interleave=True, latent_rescale=True, attn_gate=True,
+            index_heads=64, index_head_dim=128, index_topk=2048,
+            sliding_window=513, rope_theta_sliding=5e4, q_latent_rank_sliding=1024,
+            kv_latent_rank_sliding=1024, qk_nope_dim_sliding=192, qk_rope_dim_sliding=64,
+            v_head_dim_sliding=128, moe_experts=256, moe_top_k=8, moe_d_ff=1536,
+            moe_shared_d_ff=1536, moe_routed_scale=1.0, moe_scoring="sigmoid",
+        )
+        d.update(kw)
+        return LlamaConfig(**_sparse_latent_lists(d, sliding_heads=64))
+
+    @staticmethod
+    def dots3_tiny(**kw) -> "LlamaConfig":
+        """Test-size model with ``dots3_note_prev``'s pattern: two indexed
+        latent layers (the first under a dense feed-forward), then a period
+        of sliding latent ones; 8 positions a query, a window of 5."""
+        d = dict(
+            vocab_size=256, d_model=64, n_layers=5, n_heads=4, n_kv_heads=1, d_ff=128,
+            max_seq_len=128, dtype=jnp.float32, remat=False, rms_eps=1e-5, rope_theta=8e7,
+            q_latent_rank=32, kv_latent_rank=32, qk_nope_dim=16, qk_rope_dim=8,
+            v_head_dim=16, rope_interleave=True, latent_rescale=True, attn_gate=True,
+            index_heads=4, index_head_dim=16, index_topk=8,
+            sliding_window=5, rope_theta_sliding=5e4, q_latent_rank_sliding=32,
+            kv_latent_rank_sliding=48, qk_nope_dim_sliding=24, qk_rope_dim_sliding=8,
+            v_head_dim_sliding=16, moe_experts=16, moe_top_k=3, moe_d_ff=32,
+            moe_shared_d_ff=32, moe_routed_scale=1.0, moe_scoring="sigmoid",
+        )
+        d.update(kw)
+        return LlamaConfig(**_sparse_latent_lists(d, sliding_heads=2))
+
+
 # a block of a ``nemotron_h`` pattern: (mixer, feed-forward)
 _BLOCKS = {"M": ("ssm", "none"), "E": ("none", "sparse"), "*": ("full", "none")}
 
@@ -616,6 +690,20 @@ def _latent_lists(d: dict) -> dict:
     n = d["n_layers"]
     d.setdefault("layer_types", ("latent",) * n)
     d.setdefault("heads_per_layer", (d["n_heads"],) * n)
+    d.setdefault("mlp_types", ("dense",) + ("sparse",) * (n - 1))
+    return d
+
+
+def _sparse_latent_lists(d: dict, sliding_heads: int) -> dict:
+    """The per-layer lists of a model whose layers 0, 1, 5, 9 .. are indexed
+    latent attention and the others sliding latent attention of
+    ``sliding_heads`` heads, one dense feed-forward and then expert ones, for
+    its depth."""
+    n = d["n_layers"]
+    d.setdefault("layer_types", tuple(
+        "latent" if i == 0 or i % 4 == 1 else "latent_sliding" for i in range(n)))
+    d.setdefault("heads_per_layer", tuple(
+        d["n_heads"] if t == "latent" else sliding_heads for t in d["layer_types"]))
     d.setdefault("mlp_types", ("dense",) + ("sparse",) * (n - 1))
     return d
 
@@ -701,6 +789,21 @@ _PARAM_DIMS.update({
 })
 
 
+# latent attention of two widths under an indexer (one device too): the
+# sliding kind's leaves as the full kind's, a query latent's down-projection
+# and norm, the gate a head, and the indexer's leaves whole
+for _name in ("wq", "wo", "wkv_a", "kv_norm", "wuk", "wuv"):
+    _PARAM_DIMS[_name + "_latent_sliding"] = _PARAM_DIMS[_name + "_latent"]
+for _kind in ("latent", "latent_sliding"):
+    _PARAM_DIMS["wqa_" + _kind] = (None, "embed", None)
+    _PARAM_DIMS["q_norm_" + _kind] = (None, "norm")
+    _PARAM_DIMS["wg_" + _kind] = (None, "embed", "heads")
+_PARAM_DIMS.update({
+    **dict.fromkeys(("index_wq", "index_wk", "index_ww"), (None, None, None)),
+    **dict.fromkeys(("index_k_norm", "index_k_bias"), (None, None)),
+})
+
+
 def param_logical_dims(path, leaf):
     """For ``ray_tpu.parallel.mesh.shard_params``: path -> logical dims."""
     name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
@@ -740,7 +843,7 @@ def serving_layouts(names) -> dict[str, tuple]:
     is (``_PARAM_DIMS``): stacked, of rank 4, contracting its ``embed`` axis
     with the heads and the head width behind it."""
     return {
-        name: EMBED_MINOR if name == "wq_latent" else HEAD_MAJOR for name in names
+        name: EMBED_MINOR if name.startswith("wq_latent") else HEAD_MAJOR for name in names
         if len(dims := _PARAM_DIMS.get(name, ())) == 4 and dims[:2] == (None, "embed")
     }
 
@@ -794,10 +897,14 @@ def init_params(key, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
     # layers: 158 at 2 layers, 8.7e7 at 8, on the chip.)
     # (``wq_full``, ``wo_sliding``: a patterned model's leaves by kind)
     def fan_in_of(name, shape):
+        if name.startswith("wq_latent"):  # from the input, or from a query latent
+            return shape[1]
         if name.startswith(("wq", "wk", "wv")):
             return cfg.d_model
-        if name.startswith(("wuk", "wuv")):  # both expand the latent
-            return cfg.kv_latent_rank
+        if name.startswith("wuk"):  # both expand the latent
+            return shape[-1]
+        if name.startswith("wuv"):
+            return shape[-2]
         if name.startswith("wo"):
             return shape[-3] * shape[-2]
         return shape[-2] if len(shape) > 1 else shape[0]
@@ -822,7 +929,8 @@ def init_params(key, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
         else:
             # the selection bias is a buffer the training moves: small and
             # not zero, so that the choice and the weights can differ
-            std = 0.05 if name == "moe_router_bias" else fan_in_of(name, shape) ** -0.5
+            std = 0.05 if name in ("moe_router_bias", "index_k_bias") else fan_in_of(
+                name, shape) ** -0.5
             maker = lambda k=k, shape=shape, std=std: (
                 jax.random.normal(k, shape, jnp.float32) * std
             ).astype(cfg.dtype)
@@ -1167,6 +1275,13 @@ def init_kv_cache(cfg: LlamaConfig, batch_size: int, max_len: Optional[int] = No
     every leaf but ``length`` has the slot on axis 1, which is all that the
     engine's programs that stack, unstack and copy slots know of them.
 
+    A model whose attention layers are of two cache shapes, or hold a third
+    number a token, has further stripes under names of their own, each
+    ``[layers of its kind, B, 1, S, D]`` (``models/patterned.py
+    stripe_cache_shapes``: a sliding latent layer's ``k_sliding`` and
+    ``v_sliding``, an indexed one's ``k_index``); whatever moves a slot's
+    stripes moves every one of them.
+
     One rule for every model: ``k`` and ``v`` are two rank-5 leaves whose
     ``D`` need not be equal. A latent-attention model has one key-value
     "head": ``k`` holds the rotated key all heads share, ``v`` the normed
@@ -1178,15 +1293,9 @@ def init_kv_cache(cfg: LlamaConfig, batch_size: int, max_len: Optional[int] = No
     take part of a lane tile (the v5e compiler: "slice shape along dimension
     4 must be aligned to tiling (128), but is 64")."""
     max_len = max_len or cfg.max_seq_len
-    pl = patterned.plan(cfg)
-    lead = (pl.n_attention, batch_size, cfg.n_kv_heads, max_len)
-    k_dim, v_dim = (
-        (-(-cfg.qk_rope_dim // _LANES) * _LANES, cfg.kv_latent_rank) if cfg.kv_latent_rank
-        else (cfg.head_dim, cfg.head_dim)
-    )
+    stripes = patterned.stripe_cache_shapes(cfg, batch_size, max_len)
     cache = {
-        "k": jnp.zeros(lead + (k_dim,), cfg.dtype),
-        "v": jnp.zeros(lead + (v_dim,), cfg.dtype),
+        **{name: jnp.zeros(shape, cfg.dtype) for name, shape in stripes.items()},
         "length": jnp.zeros((batch_size,), jnp.int32),
     }
     cache.update({

@@ -61,7 +61,7 @@ import contextlib
 import dataclasses
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -72,6 +72,7 @@ from ray_tpu.ops.decode_attention import (
     block_size,
     decode_attention,
     latent_decode_attention,
+    sparse_latent_decode_attention,
     takes_heads_of,
 )
 from ray_tpu.parallel.mesh import with_sharding
@@ -80,7 +81,10 @@ from ray_tpu.parallel.mesh import with_sharding
 # engine's programs (which add ``kv_write``, ``sampling``, ``prefix_seed``):
 # embed, norm, attn_qkv (projections and rope), attn_core (the kernel; in
 # decode, attention over the cache), attn_out, ffn, moe_ffn, lm_head, loss,
-# optimizer, grad_norm. They sit inside the layer body, so every layer's work
+# optimizer, grad_norm (inside ``attn_qkv`` and ``attn_core`` an indexed latent
+# layer names its indexer's work ``attn_index`` and ``attn_select``; inside
+# known names, because a trace reader books an operation to the outermost name
+# it knows). They sit inside the layer body, so every layer's work
 # pools under one name, and are metadata only: each lands in the ``op_name``
 # of the operations traced under it, which is what a device trace is
 # attributed by. The backward pass needs none of its own: JAX writes
@@ -91,7 +95,13 @@ scope = jax.named_scope
 # this, so that the slice starts on a tile of the cache's position axis
 _WINDOW_ALIGN = 128
 # the name under ``attn_core`` of a layer whose attention kind is named
-_SCOPE_OF_KIND = {"full": "global", "sliding": "window", "latent": "latent", "cca": "global"}
+_SCOPE_OF_KIND = {"full": "global", "sliding": "window", "latent": "latent", "cca": "global",
+                  "latent_sliding": "latent_window"}
+# the latent kinds, and the stripes each keeps its rotated key and its latent
+# in (``stripe_cache_shapes``); a layer of another kind keeps ``k`` and ``v``
+_LATENT_KINDS = ("latent", "latent_sliding")
+_STRIPES_OF_KIND = {"latent": ("k", "v"), "latent_sliding": ("k_sliding", "v_sliding")}
+_LANES = 128  # the minor axis of a tile on the chip
 # the kinds whose queries see every earlier position of their stripe, read as
 # a full layer reads its own (``_cache_reader``)
 _FULL_KINDS = ("full", "cca")
@@ -100,6 +110,9 @@ _FULL_KINDS = ("full", "cca")
 # float32 scores are [B, heads, T, block]: 34 MB at 32 heads and a 256-token
 # chunk
 _LATENT_KEY_BLOCKS = (1024, 512, 256, 128)
+# ... of those whose float32 scores stay under this many bytes, where any does
+# (128 heads of a 4,544-token chunk at two rows are 4.7 MB a key position)
+_LATENT_SCORES_MAX_BYTES = 1 << 30
 # A prompt's chunk over a full layer's stripe scores every position of the
 # stripe at once (``_grouped_attention``: float32 [B, heads, T, S]) while that
 # is at most this many bytes, which holds every cell but one at the form its
@@ -142,6 +155,8 @@ class Plan:
     kv_index: tuple = ()
     mixer_index: tuple = ()
     ffn_index: tuple = ()
+    # the 'latent' layers attend the positions an indexer picks (``index_topk``)
+    indexed: bool = False
 
     @property
     def tail_from(self) -> int:
@@ -229,17 +244,35 @@ def plan(cfg) -> Plan:
     for t in _SCOPE_OF_KIND:
         if len({h for kt, h, _ in kinds if kt == t}) > 1:
             raise ValueError(f"{t} attention layers differ in their query heads")
-    if any(t == "sliding" for t, _, _ in kinds) and cfg.sliding_window <= 0:
+    if any(t in ("sliding", "latent_sliding") for t, _, _ in kinds) and cfg.sliding_window <= 0:
         raise ValueError("sliding layers need sliding_window")
-    if any(t == "latent" for t, _, _ in kinds) != bool(cfg.kv_latent_rank) or (
+    if any(t in _LATENT_KINDS for t, _, _ in kinds) != bool(cfg.kv_latent_rank) or (
         cfg.kv_latent_rank and (
-            any(t != "latent" for t, _, _ in kinds) or cfg.n_kv_heads != 1
-            or cfg.attn_gate or not (cfg.qk_nope_dim and cfg.qk_rope_dim and cfg.v_head_dim)
+            any(t not in _LATENT_KINDS for t, _, _ in kinds) or cfg.n_kv_heads != 1
+            or cfg.attn_gate == "channel"
+            or not (cfg.qk_nope_dim and cfg.qk_rope_dim and cfg.v_head_dim)
         )
     ):
         raise ValueError(
             "latent layers need kv_latent_rank, qk_nope_dim, qk_rope_dim and v_head_dim, "
-            "n_kv_heads 1 and no attn_gate, and do not mix with full or sliding layers"
+            "n_kv_heads 1 and a gate a head or none, and do not mix with full or sliding layers"
+        )
+    if any(t == "latent_sliding" for t, _, _ in kinds) and not (
+        any(t == "latent" for t, _, _ in kinds) and cfg.kv_latent_rank_sliding
+        and cfg.qk_nope_dim_sliding and cfg.qk_rope_dim_sliding and cfg.v_head_dim_sliding
+    ):
+        raise ValueError(
+            "latent_sliding layers need kv_latent_rank_sliding, qk_nope_dim_sliding, "
+            "qk_rope_dim_sliding and v_head_dim_sliding, beside at least one latent layer "
+            "(the cache's ``k`` and ``v`` are the latent layers')"
+        )
+    if cfg.index_topk and not (
+        cfg.kv_latent_rank and cfg.q_latent_rank and cfg.index_heads
+        and cfg.index_head_dim >= cfg.qk_rope_dim
+    ):
+        raise ValueError(
+            "index_topk: an indexer over latent layers needs q_latent_rank (its queries come "
+            "from the query latent), index_heads and an index_head_dim of at least qk_rope_dim"
         )
     if any(m == "sparse" for _, _, m in kinds) and not cfg.moe_experts:
         raise ValueError("sparse layers need moe_experts")
@@ -273,7 +306,7 @@ def plan(cfg) -> Plan:
             seen[key] += 1
     return Plan(lead, period, reps, tuple(kinds), tuple(rows["attn"]), tuple(rows["mlp"]),
                 bool(cfg.layer_types), tuple(rows["kv"]), tuple(rows["mixer"]),
-                tuple(rows["ffn"]))
+                tuple(rows["ffn"]), bool(cfg.index_topk))
 
 
 def _moe_shapes(cfg, n: int) -> dict[str, tuple]:
@@ -408,6 +441,63 @@ def _cca_shapes(cfg, n: int, h: int) -> dict[str, tuple]:
     }
 
 
+class LatentDims(NamedTuple):
+    """The sizes of one latent kind: the key-value latent's and the query
+    latent's rank (0: queries come from the input), a head's query and key in
+    its two parts, its value, the rotary base, and what both normed latents
+    are multiplied by (1 without ``latent_rescale``)."""
+
+    rank: int
+    q_rank: int
+    nope: int
+    rope: int
+    v: int
+    theta: float
+    q_scale: float
+    kv_scale: float
+
+
+def latent_dims(cfg, kind: str = "latent") -> LatentDims:
+    sliding = kind == "latent_sliding"
+    rank, q_rank, nope, rope, v, theta = (
+        (cfg.kv_latent_rank_sliding, cfg.q_latent_rank_sliding, cfg.qk_nope_dim_sliding,
+         cfg.qk_rope_dim_sliding, cfg.v_head_dim_sliding, cfg.rope_theta_sliding) if sliding
+        else (cfg.kv_latent_rank, cfg.q_latent_rank, cfg.qk_nope_dim, cfg.qk_rope_dim,
+              cfg.v_head_dim, cfg.rope_theta))
+    rescaled = cfg.latent_rescale
+    return LatentDims(
+        rank, q_rank, nope, rope, v, theta,
+        (cfg.d_model / q_rank) ** 0.5 if rescaled and q_rank else 1.0,
+        (cfg.d_model / rank) ** 0.5 if rescaled and rank else 1.0)
+
+
+def stripe_cache_shapes(cfg, batch_size: int, max_len: int) -> dict:
+    """name -> shape of every stripe a model's cache holds, each ``[layers of
+    its kind, B, K, S, D]``: ``k`` and ``v`` of the attention layers (a
+    latent model's: the latent layers' shared rotated key, at the front of a
+    row of whole lane tiles, and their normed latent), and where the model has
+    them the sliding latent layers' two (``k_sliding``, ``v_sliding``) and the
+    indexer's key a token and latent layer (``k_index``)."""
+    pl = plan(cfg)
+
+    def lanes(n):
+        return -(-n // _LANES) * _LANES
+
+    if not cfg.kv_latent_rank:
+        lead = (pl.n_attention, batch_size, cfg.n_kv_heads, max_len)
+        return {"k": lead + (cfg.head_dim,), "v": lead + (cfg.head_dim,)}
+    shapes = {}
+    for kind, (k, v) in _STRIPES_OF_KIND.items():
+        n = sum(t == kind for t, _, _ in pl.kinds)
+        if n:
+            d = latent_dims(cfg, kind)
+            shapes[k] = (n, batch_size, 1, max_len, lanes(d.rope))
+            shapes[v] = (n, batch_size, 1, max_len, d.rank)
+    if cfg.index_topk:
+        shapes["k_index"] = shapes["k"][:-1] + (lanes(cfg.index_head_dim),)
+    return shapes
+
+
 def state_cache_shapes(cfg, batch_size: int) -> dict:
     """name -> (shape, dtype) of the ``STATE_LEAVES`` a model's cache holds, a
     row a layer and slot: a state-space layer's state and the last
@@ -433,6 +523,37 @@ def state_cache_shapes(cfg, batch_size: int) -> dict:
     return shapes
 
 
+def _latent_shapes(cfg, kind: str, n: int, h: int) -> dict[str, tuple]:
+    """The leaves of ``n`` stacked latent layers of ``h`` heads, each named
+    with its kind behind it: the query projection (from the input, or with a
+    query latent the down-projection, its norm and the up-projection), the
+    key-value latent's down-projection and norm, a head's two halves of the
+    up-projection, the output projection, the gate a head, and for the
+    indexed kind the indexer's query projection from the query latent (a
+    matrix: heads x width side by side), its key projection with a LayerNorm's
+    scale and bias, and its weight a head."""
+    e, d = cfg.d_model, latent_dims(cfg, kind)
+    shapes = {
+        f"wq_{kind}": (n, d.q_rank or e, h, d.nope + d.rope),
+        f"wkv_a_{kind}": (n, e, d.rank + d.rope),
+        f"kv_norm_{kind}": (n, d.rank),
+        f"wuk_{kind}": (n, h, d.nope, d.rank),
+        f"wuv_{kind}": (n, h, d.rank, d.v),
+        f"wo_{kind}": (n, h, d.v, e),
+    }
+    if d.q_rank:
+        shapes.update({f"wqa_{kind}": (n, e, d.q_rank), f"q_norm_{kind}": (n, d.q_rank)})
+    if cfg.attn_gate:
+        shapes[f"wg_{kind}"] = (n, e, h)
+    if cfg.index_topk and kind == "latent":
+        Hi, Di = cfg.index_heads, cfg.index_head_dim
+        shapes.update({
+            "index_wq": (n, d.q_rank, Hi * Di), "index_wk": (n, e, Di),
+            "index_k_norm": (n, Di), "index_k_bias": (n, Di), "index_ww": (n, e, Hi),
+        })
+    return shapes
+
+
 def _param_shapes(cfg) -> dict[str, tuple]:
     pl = plan(cfg)  # validates the pattern
     e, v, L = cfg.d_model, cfg.vocab_size, cfg.n_layers
@@ -451,17 +572,8 @@ def _param_shapes(cfg) -> dict[str, tuple]:
         shapes.update(_kda_shapes(cfg, pl.n_kda))
     for kind, h in {t: h for t, h, _ in pl.kinds if t in _SCOPE_OF_KIND}.items():
         n = sum(t == kind for t, _, _ in pl.kinds)
-        if kind == "latent":
-            r, nope, rope, vd = (cfg.kv_latent_rank, cfg.qk_nope_dim, cfg.qk_rope_dim,
-                                 cfg.v_head_dim)
-            shapes.update({
-                "wq_latent": (n, e, h, nope + rope),
-                "wkv_a_latent": (n, e, r + rope),
-                "kv_norm_latent": (n, r),
-                "wuk_latent": (n, h, nope, r),
-                "wuv_latent": (n, h, r, vd),
-                "wo_latent": (n, h, vd, e),
-            })
+        if kind in _LATENT_KINDS:
+            shapes.update(_latent_shapes(cfg, kind, n, h))
             continue
         shapes[pl.leaf("wq", kind)] = (n, e, h, hd)
         shapes[pl.leaf("wo", kind)] = (n, h, hd, e)
@@ -907,8 +1019,9 @@ def rope_inv_freq(cfg, kind: str):
     at ``rope_theta``."""
     if kind == "sliding":
         rot, theta = cfg.head_dim, cfg.rope_theta_sliding
-    elif kind == "latent":  # the rotated part of a query, and the shared key
-        rot, theta = cfg.qk_rope_dim, cfg.rope_theta
+    elif kind in _LATENT_KINDS:  # the rotated part of a query, and the shared key
+        d = latent_dims(cfg, kind)
+        rot, theta = d.rope, d.theta
     else:
         rot, theta = int(cfg.head_dim * cfg.rope_partial), cfg.rope_theta
     inv = (1.0 / theta ** (np.arange(0, rot, 2, dtype=np.float32) / rot)).astype(np.float32)
@@ -964,14 +1077,18 @@ class _Layer:
         self.kv_i, self.mixer_i, self.ffn_i = (l, l, l) if pl.whole else rows
         self.wq, self.wo, self.wg = (pl.leaf(n, self.kind) for n in ("wq", "wo", "wg"))
         self.by_kind = pl.by_kind
-        self.latent = self.kind == "latent"
+        self.latent = self.kind in _LATENT_KINDS
+        # a latent layer whose queries attend what an indexer picks
+        self.indexed = pl.indexed and self.kind == "latent"
         # the stack's last layer, traced on its own (not a pass of the loop)
         self.last = l_static == len(pl.kinds) - 1 and isinstance(l, int)
 
     def inner_scope(self):
         """The name under ``attn_core``: ``global`` or ``window`` where the
         model has kinds to tell apart, none where its layers are alike."""
-        return scope(_SCOPE_OF_KIND[self.kind]) if self.by_kind else contextlib.nullcontext()
+        if not self.by_kind:
+            return contextlib.nullcontext()
+        return scope("latent_sparse" if self.indexed else _SCOPE_OF_KIND[self.kind])
 
 
 def _score_rescale(cfg) -> float:
@@ -1083,30 +1200,103 @@ def _cca_qkv(params, lay: _Layer, qk, v, tail, positions, valid, cfg):
 
 def _latent_qkv(params, lay: _Layer, h, positions, cfg):
     """A latent layer's projections of h [B, T, e]: each head's query in its
-    two parts, (q_nope [B, T, H, nope], q_rope [B, T, H, rope], rotated), and
-    what the cache holds of a token: the rotated key all heads share
-    [B, T, 1, rope] and the normed latent [B, T, 1, rank]."""
-    inv_freq, factor = rope_inv_freq(cfg, "latent")
-    r, nope = cfg.kv_latent_rank, cfg.qk_nope_dim
+    two parts, (q_nope [B, T, H, nope], q_rope [B, T, H, rope], rotated), what
+    the cache holds of a token: the rotated key all heads share
+    [B, T, 1, rope] and the normed latent [B, T, 1, rank], and the normed
+    query latent [B, T, q_rank] (None where the queries come from the input
+    itself). Both normed latents times the kind's rescale (``LatentDims``)."""
+    d = latent_dims(cfg, lay.kind)
+    inv_freq, factor = rope_inv_freq(cfg, lay.kind)
+    r, nope = d.rank, d.nope
     i = lay.attn_i
+
+    def leaf(name):
+        return params[f"{name}_{lay.kind}"][i]
+
     with scope("attn_qkv"):
-        q = jnp.einsum("bte,ehd->bthd", h, params["wq_latent"][i])
-        kv = jnp.einsum("bte,er->btr", h, params["wkv_a_latent"][i])
-        c = _rmsnorm(kv[..., :r], params["kv_norm_latent"][i], cfg.rms_eps, cfg.fused_rmsnorm)
+        cq = None
+        if d.q_rank:
+            cq = _times(_rmsnorm(jnp.einsum("bte,er->btr", h, leaf("wqa")), leaf("q_norm"),
+                                 cfg.rms_eps, cfg.fused_rmsnorm), d.q_scale)
+            q = jnp.einsum("btr,rhd->bthd", cq, leaf("wq"))
+        else:
+            q = jnp.einsum("bte,ehd->bthd", h, leaf("wq"))
+        kv = jnp.einsum("bte,er->btr", h, leaf("wkv_a"))
+        c = _times(_rmsnorm(kv[..., :r], leaf("kv_norm"), cfg.rms_eps, cfg.fused_rmsnorm),
+                   d.kv_scale)
         q_rope = _rope(q[..., nope:], positions, inv_freq, factor, cfg.rope_interleave)
         k_rope = _rope(kv[:, :, None, r:], positions, inv_freq, factor, cfg.rope_interleave)
-    return (q[..., :nope], q_rope), k_rope, c[:, :, None, :]
+    return (q[..., :nope], q_rope), k_rope, c[:, :, None, :], cq
 
 
-def _latent_scale(cfg) -> float:
-    return (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+def _index_qkw(params, lay: _Layer, h, cq, positions, cfg):
+    """The indexer's projections (DeepSeek-V3.2's) of a latent layer's normed
+    input h [B, T, e] and normed query latent cq [B, T, q_rank]: its queries
+    [B, T, Hi, Di], the weight a query head float32 [B, T, Hi], times
+    ``Hi ** -0.5 * Di ** -0.5``, and the token's index key [B, T, 1, Di] (a
+    LayerNorm with bias over the key); the first ``qk_rope_dim`` numbers of a
+    query and of the key rotated by halves at the layer's own base."""
+    Hi, Di = cfg.index_heads, cfg.index_head_dim
+    inv_freq, factor = rope_inv_freq(cfg, "latent")
+    i, f32 = lay.attn_i, jnp.float32
+    B, T, _ = h.shape
+    with scope("attn_qkv"), scope("attn_index"):
+        q = jnp.einsum("btr,rf->btf", cq, params["index_wq"][i]).reshape(B, T, Hi, Di)
+        k = jnp.einsum("bte,ed->btd", h, params["index_wk"][i], preferred_element_type=f32)
+        k = k - k.mean(axis=-1, keepdims=True)
+        k = k * jax.lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True) + cfg.rms_eps)
+        k = (k * params["index_k_norm"][i].astype(f32)
+             + params["index_k_bias"][i].astype(f32)).astype(h.dtype)
+        q = _rope(q, positions, inv_freq, factor)
+        k = _rope(k[:, :, None, :], positions, inv_freq, factor)
+        w = jnp.einsum("bte,eh->bth", h, params["index_ww"][i], preferred_element_type=f32)
+    return q, w * (Hi ** -0.5 * Di ** -0.5), k
+
+
+def _index_scores(q, w, keys):
+    """``I[t, s] = sum_j w[t, j] relu(q[t, j] . k[s])`` in float32. q
+    [B, T, Hi, Di], w [B, T, Hi] (scaled: ``_index_qkw``), keys [B, S, Di']
+    (zeros behind Di) -> [B, T, S]."""
+    q = jnp.pad(q, ((0, 0),) * 3 + ((0, keys.shape[-1] - q.shape[-1]),))
+    s = jnp.einsum("bthd,bsd->bths", q, keys, preferred_element_type=jnp.float32)
+    return jnp.einsum("bths,bth->bts", jax.nn.relu(s), w)
+
+
+def _kept(scores, k: int):
+    """Which of each row's ``scores`` [..., S] (float32) are among its ``k``
+    largest, ties to the lower position (``lax.top_k``'s order): everything
+    above the ``k``-th largest value, and of those equal to it the first that
+    the count leaves room for. The ``k``-th value is found without a sort, by
+    descent over the 32 bits of the scores' order-preserving integer image (a
+    pass of compares and a count a bit; on a v5e 0.36 ms for 256 rows of
+    24,576 where ``lax.top_k`` of 2,048 takes 4.67: PERF.md section 6, PR 51).
+    A position masked to ``-inf`` is kept only where fewer than ``k`` are not,
+    and is none the caller's mask allows."""
+    u32 = jnp.uint32
+    bits = jax.lax.bitcast_convert_type(jnp.where(scores == 0, 0.0, scores), u32)  # no -0.0
+    image = jnp.where(bits >> 31 == 1, ~bits, bits | u32(1 << 31))
+
+    def bit(i, kth):
+        raised = kth | (u32(1 << 31) >> i.astype(u32))
+        enough = (image >= raised[..., None]).sum(axis=-1, dtype=jnp.int32) >= k
+        return jnp.where(enough, raised, kth)
+
+    kth = jax.lax.fori_loop(0, 32, bit, jnp.zeros(scores.shape[:-1], u32))[..., None]
+    above, level = image > kth, image == kth
+    room = k - above.sum(axis=-1, keepdims=True, dtype=jnp.int32)
+    return above | (level & (jnp.cumsum(level, axis=-1, dtype=jnp.int32) <= room))
+
+
+def _latent_scale(cfg, kind: str = "latent") -> float:
+    d = latent_dims(cfg, kind)
+    return (d.nope + d.rope) ** -0.5
 
 
 def _latent_expand(params, lay: _Layer, c):
     """Every head's keys (the part that is not rotated) and values of the
     latents c [B, S, rank] -> ([B, S, H, nope], [B, S, H, v])."""
-    return (jnp.einsum("bsr,hnr->bshn", c, params["wuk_latent"][lay.attn_i]),
-            jnp.einsum("bsr,hrv->bshv", c, params["wuv_latent"][lay.attn_i]))
+    return (jnp.einsum("bsr,hnr->bshn", c, params[f"wuk_{lay.kind}"][lay.attn_i]),
+            jnp.einsum("bsr,hrv->bshv", c, params[f"wuv_{lay.kind}"][lay.attn_i]))
 
 
 def _latent_expanded(params, lay: _Layer, q, k_rope, c, mask, cfg):
@@ -1118,7 +1308,7 @@ def _latent_expanded(params, lay: _Layer, q, k_rope, c, mask, cfg):
     s = (
         jnp.einsum("bthn,bshn->bhts", q_nope, k_nope, preferred_element_type=jnp.float32)
         + jnp.einsum("bthd,bsd->bhts", q_rope, k_rope, preferred_element_type=jnp.float32)
-    ) * _latent_scale(cfg)
+    ) * _latent_scale(cfg, lay.kind)
     s = jnp.where(mask[:, None], s, -1e30)
     w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     return jnp.einsum("bhts,bshv->bthv", w, v)
@@ -1127,7 +1317,7 @@ def _latent_expanded(params, lay: _Layer, q, k_rope, c, mask, cfg):
 def _latent_absorb(params, lay: _Layer, q_nope):
     """Each head's query through its half of the key up-projection: scores
     against the latents themselves. [B, T, H, nope] -> [B, T, H, rank]."""
-    return jnp.einsum("bthn,hnr->bthr", q_nope, params["wuk_latent"][lay.attn_i])
+    return jnp.einsum("bthn,hnr->bthr", q_nope, params[f"wuk_{lay.kind}"][lay.attn_i])
 
 
 def _attn_out(params, lay: _Layer, x, h, attn, cfg, from_latent: bool = False):
@@ -1136,7 +1326,7 @@ def _attn_out(params, lay: _Layer, x, h, attn, cfg, from_latent: bool = False):
     through each head's half of the value up-projection first."""
     with scope("attn_out"):
         if from_latent:
-            attn = jnp.einsum("bthr,hrv->bthv", attn, params["wuv_latent"][lay.attn_i])
+            attn = jnp.einsum("bthr,hrv->bthv", attn, params[f"wuv_{lay.kind}"][lay.attn_i])
         if cfg.attn_gate:
             with scope("gate"):
                 # [B, T, H] a head, [B, T, H * D] a channel
@@ -1395,7 +1585,7 @@ def _feed_forward(params, lay: _Layer, x, cfg, route=()):
         # a layer's slice of a stacked weight is taken where it is used
         y = _dense_ffn(h, lambda name: params[name][lay.mlp_i])
         x = _joined(params, "mlp_scale", lay.ffn_i, x, y, cfg)
-    return x, (jnp.zeros((len(MOE_STATS),), jnp.int32) if cfg.moe_experts else None), route
+    return x, (jnp.zeros((len(moe_stats_names(cfg)),), jnp.int32) if cfg.moe_experts else None), route
 
 
 def _run_layers(cfg, layer_fn, carry):
@@ -1459,6 +1649,12 @@ def forward_hidden(params, tokens, cfg, mesh: Optional[Mesh] = None, positions=N
             "alone, and mixers that keep a state, run through the cache only (prefill, "
             "decode_step)"
         )
+    if cfg.index_topk or any(t == "latent_sliding" for t, _, _ in plan(cfg).kinds):
+        raise NotImplementedError(
+            "models/patterned.py forward_hidden: latent layers under an indexer or a window "
+            "(index_topk, latent_sliding) run through the cache only (prefill, decode_step): "
+            "no whole-sequence path, and so no training, selects or windows a latent"
+        )
     B, T = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
@@ -1474,7 +1670,7 @@ def forward_hidden(params, tokens, cfg, mesh: Optional[Mesh] = None, positions=N
         x, route = carry
         h = _rmsnorm(x, params["attn_norm"][lay.l], cfg.rms_eps, cfg.fused_rmsnorm)
         if lay.latent:
-            q, k, v = _latent_qkv(params, lay, h, positions, cfg)
+            q, k, v, _ = _latent_qkv(params, lay, h, positions, cfg)
         elif lay.kind in STRIPE_STATE:  # the whole sequence: nothing came before it
             project, qkv, leaf = STRIPE_STATE[lay.kind]
             shape, dtype = state_cache_shapes(cfg, B)[leaf]
@@ -1542,7 +1738,7 @@ def reads_blocks(stripe: int, *arrays, latent: bool = False) -> bool:
     return all(jax.typeof(x).sharding.mesh.size <= 1 for x in arrays)
 
 
-def _chunk_expands(cfg, T: int) -> bool:
+def _chunk_expands(cfg, T: int, kind: str = "latent") -> bool:
     """Which form ``T`` new tokens a row take over a latent cache: whether
     each block's keys and values are expanded from its latents first (2 *
     rank * heads * (nope + v) operations a key position, then 2 * heads *
@@ -1555,91 +1751,173 @@ def _chunk_expands(cfg, T: int) -> bool:
     / 20,480 cached tokens took 17.8 / 22.6 ms absorbed and 16.1 / 20.0 ms
     expanded (``benchmark/tools/latent_chunk_forms.py``; PERF.md section 6,
     PR 33): 13.6 against 17.8 M operations a key position."""
-    r, nope, v = cfg.kv_latent_rank, cfg.qk_nope_dim, cfg.v_head_dim
+    d = latent_dims(cfg, kind)
+    r, nope, v = d.rank, d.nope, d.v
     return T * (2 * r - nope - v) > r * (nope + v)
 
 
 def _latent_reader(cfg, params, cache, positions):
-    """``read(q, ck_all, cv_all, lay)`` for ``decode_forward`` over a latent
-    cache: ck_all [L, B, 1, S, 128] the shared rotated keys, cv_all
-    [L, B, 1, S, rank] the normed latents; q ``_latent_qkv``'s pair. Returns
-    the read and whether it hands out the context in the latent's space
-    [B, T, H, rank] (the absorbed form; ``_attn_out`` expands it) or each
-    head's own [B, T, H, v].
+    """``(read, select, absorbed)`` for ``decode_forward`` over a latent
+    cache. ``read(q, ck_all, cv_all, lay, chosen)``: ck_all [n, B, 1, S, 128]
+    the layer's kind's shared rotated keys, cv_all [n, B, 1, S, rank] its
+    normed latents (``_STRIPES_OF_KIND``), the layer at row ``lay.attn_i``; q
+    ``_latent_qkv``'s pair. ``absorbed[kind]``: whether the read hands out
+    the context in the latent's space [B, T, H, rank] (the absorbed form;
+    ``_attn_out`` expands it) or each head's own [B, T, H, v].
+
+    A sliding latent layer's query at position ``t`` sees ``t - window + 1 ..
+    t``. An indexed layer's sees what ``select(index, k_index_all, lay)``
+    chose for it (``index``: ``_index_qkw``'s queries and weights; k_index_all
+    [n, B, 1, S, 128] the index keys): the ``index_topk`` positions up to its
+    own of the largest index scores, ties to the lower position. ``select``
+    is None where the stripe holds no more than ``index_topk`` positions (all
+    are kept); it gives a decode step the positions themselves
+    [B, index_topk], anything else a mask [B, T, S].
 
     One new token a row, a stripe of whole blocks, one device: the absorbed
-    form through the decode kernel (``ops/decode_attention.py
-    latent_decode_attention``), row ``b`` between ``0`` and ``pos + 1``.
-    Anything else (a prompt's chunk, a tiny cache): blocks of key positions
-    up to the furthest row's last query and no further, with a running
-    maximum, sum and context in float32, so that neither the work nor any
-    temporary follows the stripe where the rows are shorter; absorbed or
-    expanded a block at a time, by the chunk's width (``_chunk_expands``)."""
+    form. A layer that is not indexed goes through the decode kernel
+    (``ops/decode_attention.py latent_decode_attention``), row ``b`` between
+    ``0`` (a sliding layer: the window's start) and ``pos + 1``; an indexed
+    one scores the row's index keys (scope ``attn_index``), takes the best
+    (``attn_select``: ``ops/topk.py``) and attends over those positions' keys
+    and latents, gathered out of the stripe (``ops/decode_attention.py
+    sparse_latent_decode_attention``). Anything else (a prompt's chunk, a
+    tiny cache): blocks of key positions up to the furthest row's last query
+    and no further (a sliding layer: from the block the earliest row's window
+    starts in), with a running maximum, sum and context in float32, so that
+    neither the work nor any temporary follows the stripe where the rows are
+    shorter; absorbed or expanded a block at a time, by the chunk's width
+    (``_chunk_expands``); an indexed layer's mask is made from the scores of
+    all blocks first."""
+    from ray_tpu.ops import topk
+
     B, T = positions.shape
     S = cache["k"].shape[3]
-    scale = _latent_scale(cfg)
-    # a cached key's row is the rotated key and zeros behind it
-    # (``init_kv_cache``): the rotated query gets the same zeros
-    pad = ((0, 0),) * 3 + ((0, cache["k"].shape[-1] - cfg.qk_rope_dim),)
+    W, K = cfg.sliding_window, cfg.index_topk
+    kinds = {t: h for t, h, _ in plan(cfg).kinds if t in _LATENT_KINDS}
+
+    def padded(q_rope, ck_all):
+        # a cached key's row is the rotated key and zeros behind it
+        # (``init_kv_cache``): the rotated query gets the same zeros
+        return jnp.pad(q_rope, ((0, 0),) * 3 + ((0, ck_all.shape[-1] - q_rope.shape[-1]),))
+
     if T == 1 and reads_blocks(S, cache["k"], *jax.tree.leaves(params), latent=True):
         hi = positions[:, 0] + 1
 
-        def read(q, ck_all, cv_all, lay):
-            q_nope, q_rope = q[0], jnp.pad(q[1], pad)
+        def select(index, k_index_all, lay):
+            q, w = index
+            with scope("attn_index"):
+                keys = jax.lax.dynamic_slice_in_dim(k_index_all, lay.attn_i, 1, 0)
+                scores = _index_scores(q, w, keys.reshape((B, S, keys.shape[-1])))[:, 0]  # [B, S]
+                # (no -0.0: it ties with 0.0, and goes to the lower position)
+                scores = jnp.where(jnp.arange(S)[None, :] < hi[:, None],
+                                   jnp.where(scores == 0, 0.0, scores), -jnp.inf)
+            with scope("attn_select"):
+                return topk.top_k(scores, K)[1]
+
+        def read(q, ck_all, cv_all, lay, chosen=None):
+            q_nope, q_rope = q[0], padded(q[1], ck_all)
             ql = _latent_absorb(params, lay, q_nope)
+            scale = _latent_scale(cfg, lay.kind)
+            if chosen is not None:
+                return sparse_latent_decode_attention(
+                    q_rope[:, 0], ql[:, 0], ck_all, cv_all, lay.attn_i, chosen, hi, scale
+                )[:, None]
+            lo = jnp.maximum(hi - W, 0) if lay.kind == "latent_sliding" else jnp.zeros_like(hi)
             return latent_decode_attention(
-                q_rope[:, 0], ql[:, 0], ck_all, cv_all, lay.l, jnp.zeros_like(hi), hi, scale
+                q_rope[:, 0], ql[:, 0], ck_all, cv_all, lay.attn_i, lo, hi, scale
             )[:, None]
 
-        return read, True
+        return read, (select if 0 < K < S else None), dict.fromkeys(kinds, True)
 
-    bk = next((b for b in _LATENT_KEY_BLOCKS if S % b == 0), S)
-    # row b's queries are consecutive from positions[b, 0]: the last sees furthest
-    n_blocks = jnp.minimum(jnp.max(positions[:, -1]) // bk + 1, S // bk)
-    expands = _chunk_expands(cfg, T)
+    def walk(heads):
+        """(key positions a block, blocks up to the furthest row's last query)."""
+        fits = [b for b in _LATENT_KEY_BLOCKS if S % b == 0]
+        bk = next((b for b in fits if B * heads * T * b * 4 <= _LATENT_SCORES_MAX_BYTES),
+                  fits[-1] if fits else S)
+        # row b's queries are consecutive from positions[b, 0]: the last sees furthest
+        return bk, jnp.minimum(jnp.max(positions[:, -1]) // bk + 1, S // bk)
 
-    def read(q, ck_all, cv_all, lay):
-        q_nope, q_rope = q[0], jnp.pad(q[1], pad)
+    walks = {kind: walk(max(h, cfg.index_heads if kind == "latent" and K else 0))
+             for kind, h in kinds.items()}
+    # (the plain kind asked as before it had a sibling: a tool swaps the function)
+    expands = {kind: _chunk_expands(cfg, T, *(() if kind == "latent" else (kind,)))
+               for kind in kinds}
+    if "latent_sliding" in kinds:  # the block the earliest row's window starts in
+        first_block = jnp.maximum(jnp.min(positions[:, 0]) - W + 1, 0) // walks["latent_sliding"][0]
+
+    def select(index, k_index_all, lay):
+        q, w = index
+        bk, n_blocks = walks[lay.kind]
+        with scope("attn_index"):
+            def block(i, scores):
+                at = (lay.attn_i, 0, 0, i * bk, 0)
+                kb = jax.lax.dynamic_slice(
+                    k_index_all, at, (1, B, 1, bk, k_index_all.shape[-1]))[0, :, 0]
+                seen = (i * bk + jnp.arange(bk))[None, None, :] <= positions[:, :, None]
+                return jax.lax.dynamic_update_slice(
+                    scores, jnp.where(seen, _index_scores(q, w, kb), -jnp.inf), (0, 0, i * bk))
+
+            scores = jnp.full((B, T, S), -jnp.inf, jnp.float32)
+            scores = block(0, scores) if bk == S else jax.lax.fori_loop(0, n_blocks, block, scores)
+        with scope("attn_select"):
+            return _kept(scores, K)
+
+    def read(q, ck_all, cv_all, lay, chosen=None):
+        q_nope, q_rope = q[0], padded(q[1], ck_all)
         H = q_nope.shape[2]
-        ql = None if expands else _latent_absorb(params, lay, q_nope)
-        width = cfg.v_head_dim if expands else cfg.kv_latent_rank
+        d = latent_dims(cfg, lay.kind)
+        bk, n_blocks = walks[lay.kind]
+        expanded = expands[lay.kind]
+        scale = _latent_scale(cfg, lay.kind)
+        ql = None if expanded else _latent_absorb(params, lay, q_nope)
+        width = d.v if expanded else d.rank
 
         def block(i, carry):
             m, den, acc = carry
-            at = (lay.l, 0, 0, i * bk, 0)
+            at = (lay.attn_i, 0, 0, i * bk, 0)
             kb = jax.lax.dynamic_slice(ck_all, at, (1, B, 1, bk, ck_all.shape[-1]))[0, :, 0]
             cb = jax.lax.dynamic_slice(cv_all, at, (1, B, 1, bk, cv_all.shape[-1]))[0, :, 0]
             s = jnp.einsum("bthd,bsd->bhts", q_rope, kb, preferred_element_type=jnp.float32)
-            if expands:
+            if expanded:
                 k_nope, vb = _latent_expand(params, lay, cb)
                 s = s + jnp.einsum("bthn,bshn->bhts", q_nope, k_nope,
                                    preferred_element_type=jnp.float32)
             else:
                 s = s + jnp.einsum("bthr,bsr->bhts", ql, cb, preferred_element_type=jnp.float32)
             seen = (i * bk + jnp.arange(bk))[None, None, :] <= positions[:, :, None]  # [B, T, bk]
+            if lay.kind == "latent_sliding":
+                seen = seen & (positions[:, :, None] - (i * bk + jnp.arange(bk)) < W)
+            if chosen is not None:
+                seen = seen & jax.lax.dynamic_slice(chosen, (0, 0, i * bk), (B, T, bk))
             s = jnp.where(seen[:, None], s * scale, -1e30)
             m_new = jnp.maximum(m, s.max(axis=-1))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new[..., None])
             den = alpha * den + p.sum(axis=-1)
             p = p.astype(cb.dtype)
-            if expands:
+            if expanded:
                 pv = jnp.einsum("bhts,bshv->bhtv", p, vb, preferred_element_type=jnp.float32)
             else:
                 pv = jnp.einsum("bhts,bsr->bhtr", p, cb, preferred_element_type=jnp.float32)
             return m_new, den, alpha[..., None] * acc + pv
 
-        # block 0 holds position 0, which every query sees: no row's maximum
-        # is still the mask's when a block past its last query comes
+        # every query sees a position of some block of the walk, its own at
+        # the latest, and what a block none of whose positions it sees left in
+        # its sums goes with the first it does see (``alpha`` is 0 then). Block
+        # 0 holds position 0, which every query of a layer without a window or
+        # an indexer sees
         init = (jnp.full((B, H, T), -1e30, jnp.float32), jnp.zeros((B, H, T), jnp.float32),
                 jnp.zeros((B, H, T, width), jnp.float32))
         if bk == S:
             _, den, acc = block(0, init)
         else:
-            _, den, acc = jax.lax.fori_loop(0, n_blocks, block, init)
+            start = first_block if lay.kind == "latent_sliding" else 0
+            _, den, acc = jax.lax.fori_loop(start, n_blocks, block, init)
         return (acc / den[..., None]).transpose(0, 2, 1, 3).astype(q_rope.dtype)
 
-    return read, not expands
+    return (read, (select if 0 < K < S else None),
+            {kind: not expanded for kind, expanded in expands.items()})
 
 
 def _cache_reader(cfg, params, cache, positions, kinds):
@@ -1752,10 +2030,12 @@ class _Rows:
         self.cache, self.tokens, self.positions, self.valid = cache, tokens, positions, valid
         self.B, self.T = tokens.shape
         self.write = _cache_writer(cfg, cache["k"].shape[3], positions, valid, start_pos)
-        if "latent" in kinds:  # every layer is one (``plan``)
-            self.read, self.from_latent = _latent_reader(cfg, params, cache, positions)
+        self.select = None  # an indexed latent layer's choice of positions
+        if "latent" in kinds:  # every layer is latent (``plan``); by kind
+            self.read, self.select, self.from_latent = _latent_reader(cfg, params, cache, positions)
         else:
-            self.read, self.from_latent = _cache_reader(cfg, params, cache, positions, kinds), False
+            self.read = _cache_reader(cfg, params, cache, positions, kinds)
+            self.from_latent = dict.fromkeys(kinds, False)
 
     def real(self):
         """``valid``, or all of them."""
@@ -1865,7 +2145,7 @@ def decode_forward(
         cache2, tokens2, live = beside
         sets.append(_Rows(cfg, params, kinds, cache2, tokens2[:, None], cache2["length"][:, None],
                           None if live is None else live[:, None], None))
-    from_latent = sets[0].from_latent
+    from_latent = sets[0].from_latent  # a latent model's rows are one set
     shapes = [(rows.B, rows.T) for rows in sets]
     positions = _join([rows.positions for rows in sets])
     # the real tokens of all rows, where any set has tokens that are none
@@ -1911,8 +2191,11 @@ def decode_forward(
             x = _joined(params, "attn_scale", lay.mixer_i, x, out(params, lay, y, gate, cfg), cfg)
         elif lay.kind != "none":
             h = _rmsnorm(x, params["attn_norm"][lay.mixer_i], cfg.rms_eps, cfg.fused_rmsnorm)
+            index = None
             if lay.latent:  # k: the shared rotated key; v: the normed latent
-                q, k, v = _latent_qkv(params, lay, h, positions, cfg)
+                q, k, v, cq = _latent_qkv(params, lay, h, positions, cfg)
+                if lay.indexed:
+                    *index, k_index = _index_qkw(params, lay, h, cq, positions, cfg)
             elif lay.kind in STRIPE_STATE:
                 # each set's rows behind their own row ``attn_i`` of the leaf
                 project, qkv, leaf = STRIPE_STATE[lay.kind]
@@ -1929,21 +2212,33 @@ def decode_forward(
                 q, k, v = _qkv(params, lay, h, positions, cfg, loras, adapter_ids)
                 q, k, v = (_split(t, shapes) for t in (q, k, v))
             attn, new_kv = [], []
+            # the stripes this layer's kind keeps, and its row in them
+            names, row = _STRIPES_OF_KIND.get(lay.kind, ("k", "v")), (
+                lay.attn_i if lay.latent else lay.kv_i)
             for j, rows in enumerate(sets):
-                ck_all, cv_all = kv[j]
+                ck_all, cv_all = (kv[j][name] for name in names)
                 # a latent model's q is a pair, and its rows are one set
                 qj, kj, vj = (q, k, v) if lay.latent else (q[j], k[j], v[j])
+                written, chosen = {}, ()
                 with scope("kv_write"):
                     if lay.latent:  # zeros up to the cache's row of whole lane tiles (``init_kv_cache``)
                         kj = jnp.pad(kj, ((0, 0),) * 3 + ((0, ck_all.shape[-1] - kj.shape[-1]),))
                     # the cache is head-major: the new [B, T, K, D] rows go in as [B, K, T, D]
-                    ck_all = rows.write(ck_all, kj.transpose(0, 2, 1, 3), lay.kv_i)
-                    cv_all = rows.write(cv_all, vj.transpose(0, 2, 1, 3), lay.kv_i)
+                    ck_all = rows.write(ck_all, kj.transpose(0, 2, 1, 3), row)
+                    cv_all = rows.write(cv_all, vj.transpose(0, 2, 1, 3), row)
+                    if index:
+                        ki_all = kv[j]["k_index"]
+                        k_index = jnp.pad(
+                            k_index, ((0, 0),) * 3 + ((0, ki_all.shape[-1] - k_index.shape[-1]),))
+                        written["k_index"] = rows.write(ki_all, k_index.transpose(0, 2, 1, 3), row)
+                if index and rows.select is not None:
+                    with scope("attn_core"):  # ``attn_index`` and ``attn_select`` inside it
+                        chosen = (rows.select(index, written["k_index"], lay),)
                 with scope("attn_core"), lay.inner_scope():
-                    attn.append(rows.read(qj, ck_all, cv_all, lay))
-                new_kv.append((ck_all, cv_all))
+                    attn.append(rows.read(qj, ck_all, cv_all, lay, *chosen))
+                new_kv.append({**kv[j], names[0]: ck_all, names[1]: cv_all, **written})
             kv = tuple(new_kv)
-            x = _attn_out(params, lay, x, h, _join(attn), cfg, from_latent)
+            x = _attn_out(params, lay, x, h, _join(attn), cfg, from_latent[lay.kind])
         if lay.mlp != "none":
             if narrow and lay.last:
                 x, *route = (_split(t, shapes)[1] for t in (x, *route))
@@ -1951,14 +2246,15 @@ def decode_forward(
             stats = tuple(s + layer_stats for s in stats)
         return (x, kv, stats, state, route)
 
+    stripes = tuple(stripe_cache_shapes(cfg, 1, 1))  # by name
     x, kv, stats, state, _ = _run_layers(
-        cfg, layer, (x, tuple((rows.cache["k"], rows.cache["v"]) for rows in sets), stats0, state0,
-                     _router_stream(cfg, x)))
+        cfg, layer, (x, tuple({name: rows.cache[name] for name in stripes} for rows in sets),
+                     stats0, state0, _router_stream(cfg, x)))
     new_caches = []
-    for rows, (new_k, new_v), leaves in zip(sets, kv, state):
+    for rows, written, leaves in zip(sets, kv, state):
         grew = rows.T if rows is sets[0] or rows.valid is None else rows.valid.sum(
             axis=1, dtype=jnp.int32)
-        new_cache = {"k": new_k, "v": new_v, "length": rows.cache["length"] + grew, **leaves}
+        new_cache = {**written, "length": rows.cache["length"] + grew, **leaves}
         _ride_stats(rows.cache, new_cache, stats)
         new_caches.append(new_cache)
     # the rows whose next token is asked for: one position a row of the first

@@ -250,3 +250,24 @@ def latent_decode_attention(q_rope, q_latent, ck_all, cv_all, layer, lo, hi, sca
     number of ``block_size(S, latent=True)`` blocks."""
     return _walk((q_rope, q_latent), ck_all, cv_all, layer, lo, hi, scale, True,
                  "latent_decode_attention")
+
+
+def sparse_latent_decode_attention(q_rope, q_latent, ck_all, cv_all, layer, chosen, hi,
+                                   scale: float):
+    """``latent_decode_attention`` over chosen positions only: row ``b``
+    attends ``chosen[b]`` [B, n] (an indexer's choice, in any order; an entry
+    at or past ``hi[b]`` is no position of the row and is masked). The chosen
+    rows of both leaves are gathered out of the layer's stripe (XLA's gather:
+    ``n`` rows of a key and a latent a row and layer, whatever the row's
+    length) and scored in one piece, float32, a plain softmax: the selection
+    has already bounded the work, so no block walk is needed. -> [B, H, R]."""
+    # out of the carried cache where it lies: a layer's slice handed to the
+    # gather is a copy of it (0.2 GB of latents a layer and step)
+    rows = jnp.arange(chosen.shape[0])[:, None]
+    keys, latents = ck_all[layer, rows, 0, chosen], cv_all[layer, rows, 0, chosen]  # [B, n, D]
+    s = (jnp.einsum("bhd,bnd->bhn", q_rope, keys, preferred_element_type=jnp.float32)
+         + jnp.einsum("bhr,bnr->bhn", q_latent, latents, preferred_element_type=jnp.float32))
+    s = jnp.where((chosen < hi[:, None])[:, None, :], s * scale, _MASKED)
+    p = jax.nn.softmax(s, axis=-1).astype(latents.dtype)
+    return jnp.einsum("bhn,bnr->bhr", p, latents,
+                      preferred_element_type=jnp.float32).astype(q_rope.dtype)

@@ -184,6 +184,15 @@ def _convolved_attention_cut():
     return LlamaConfig.zaya1_8b(n_layers=20, moe_experts_held=8, max_seq_len=4608)
 
 
+def _sparse_latent_cut():
+    """The cut the cell ``dots3-note-serve-docs-shared`` serves: layers 0-4 of
+    46, experts 0-15 of the router's 256, an eighth of the vocabulary."""
+    from ray_tpu.models.llama import LlamaConfig
+
+    return LlamaConfig.dots3_note_prev(
+        n_layers=5, moe_experts_held=16, vocab_size=19008, max_seq_len=24576)
+
+
 def _engine_programs(served, one_chip, rows=1):
     """The engine's own program bodies at a serving cell's shapes, as
     ``JaxEngine._compile`` jits them: name -> (function, donated, described
