@@ -1082,6 +1082,22 @@ class JaxEngine:
         finals = sorted({min(self._bucket(n), pool.stripe_len) for n in reach})
         return (chunk if 0 < chunk < longest else None), finals
 
+    def _chunk_walks(self, pool: _Pool) -> dict:
+        """width -> kind -> ``"kernel"`` or ``"einsum"``: which walk each of a
+        latent pool's chunk programs was built with (``models/patterned.py
+        chunk_walks``, the one place that decides it; a chunk runs on a stripe
+        of the pool's length, placed as the pool's cache is)."""
+        import jax
+
+        from ray_tpu.models.patterned import chunk_walks
+
+        if not pool.latent:
+            return {}
+        mid, finals = self._chunk_widths(pool)
+        return {str(width): chunk_walks(self.model_cfg, pool.stripe_len, width, pool.cache["k"],
+                                        *jax.tree.leaves(self.params))
+                for width in sorted({*finals, *([mid] if mid else [])})}
+
     def _warm_programs(self) -> None:
         """``_warm_pass``, and where the compile cache is in use what keeps a
         start that compiled its forms and one that restored them alike to
@@ -1610,7 +1626,11 @@ class JaxEngine:
                  "state_bytes_per_slot": p.state_bytes_per_slot,
                  # which form its state mixers take for a chunk and a step
                  # (``kernel`` or ``plain``): static a shape, asked where the trace asks
-                 "state_mixer_forms": state_mixer_forms(self.model_cfg)}
+                 "state_mixer_forms": state_mixer_forms(self.model_cfg),
+                 # how its chunk programs read a latent cache, by the chunk's
+                 # width and the layers' kind (``kernel`` or ``einsum``; {} for
+                 # any other pool): asked of what the trace asks, as ``reads_blocks``
+                 "chunk_walks": self._chunk_walks(p)}
                 for p in self._pools
             ],
             "prefix_cache_hits": self._prefix_hits,

@@ -68,6 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from ray_tpu.ops import latent_chunk_attention as chunk_kernel
 from ray_tpu.ops.decode_attention import (
     block_size,
     decode_attention,
@@ -105,14 +106,26 @@ _LANES = 128  # the minor axis of a tile on the chip
 # the kinds whose queries see every earlier position of their stripe, read as
 # a full layer reads its own (``_cache_reader``)
 _FULL_KINDS = ("full", "cca")
-# key positions a block when a prompt's chunk reads a latent cache: the
+# key positions a block when a prompt's chunk reads a latent cache in plain
+# XLA (what the kernel is not given, ``chunk_walks``: a tiny cache, a chunk of
+# no whole query tiles, small scores, a mesh; since PR 52 these two bound that
+# walk only): the
 # largest of these that divides the stripe (else the stripe whole). A block's
-# float32 scores are [B, heads, T, block]: 34 MB at 32 heads and a 256-token
-# chunk
+# float32 scores are [B, heads, T, block] in HBM: 34 MB at 32 heads and a
+# 256-token chunk
 _LATENT_KEY_BLOCKS = (1024, 512, 256, 128)
 # ... of those whose float32 scores stay under this many bytes, where any does
 # (128 heads of a 4,544-token chunk at two rows are 4.7 MB a key position)
 _LATENT_SCORES_MAX_BYTES = 1 << 30
+# ... and that walk stays where a row's float32 scores are under this many
+# bytes a key position (heads x T x 4: 64 KB is 128 heads of 128 queries, 64
+# heads of 256), whatever else the kernel would take. Under it the walk's
+# scores are small enough that it runs at 59% of its roofline (Kanana's 32
+# heads: 32 KB at most) and the kernel won 0.6 ms of a 13.6 ms chunk, nothing
+# end to end, while two of the kernel's five windows in that cell read 4% and
+# 7% under the range of the parent's four and I could not say why (PERF.md
+# section 6, PR 52; 7 ck): such a chunk's program stays the one it was
+_CHUNK_KERNEL_MIN_SCORE_BYTES = 1 << 16
 # A prompt's chunk over a full layer's stripe scores every position of the
 # stripe at once (``_grouped_attention``: float32 [B, heads, T, S]) while that
 # is at most this many bytes, which holds every cell but one at the form its
@@ -1735,6 +1748,12 @@ def reads_blocks(stripe: int, *arrays, latent: bool = False) -> bool:
         return False
     if not latent and not takes_heads_of(arrays[0]):
         return False
+    return _on_one_device(arrays)
+
+
+def _on_one_device(arrays) -> bool:
+    """No argument (an array or its tracer) carries a mesh of several devices
+    in its type (``reads_blocks`` says what a type does not carry)."""
     return all(jax.typeof(x).sharding.mesh.size <= 1 for x in arrays)
 
 
@@ -1754,6 +1773,31 @@ def _chunk_expands(cfg, T: int, kind: str = "latent") -> bool:
     d = latent_dims(cfg, kind)
     r, nope, v = d.rank, d.nope, d.v
     return T * (2 * r - nope - v) > r * (nope + v)
+
+
+def chunk_walks(cfg, stripe: int, T: int, *arrays) -> dict:
+    """kind -> ``"kernel"`` or ``"einsum"``: how ``T`` new tokens a row read a
+    latent cache of ``stripe`` positions a slot, each latent kind of ``cfg``.
+    The one place that decides it, from what is static at trace time, as
+    ``reads_blocks`` does for a decode step: ``_latent_reader`` asks with the
+    tracers of the arrays the chunk runs on, the engine once a pool and width
+    with the arrays themselves (``llm/engine.py get_stats()["pools"][i]
+    ["chunk_walks"]``), and both get the same answer.
+
+    The kernel (``ops/latent_chunk_attention.py``) wants ``T`` a whole number
+    of its query tiles, a stripe of whole key blocks, latents of whole lane
+    tiles on the chip and everything on one device (``reads_blocks`` says how
+    a mesh is seen), and is given the chunk where a row's float32 scores are
+    ``_CHUNK_KERNEL_MIN_SCORE_BYTES`` a key position or more (that is where the
+    walk's traffic through HBM binds). Anything else keeps the walk in plain
+    XLA: a decode step that is not the decode kernel's, a tiny cache, a chunk
+    of an odd width, few heads of few queries."""
+    one_device = _on_one_device(arrays)
+    return {
+        kind: "kernel" if (one_device and heads * T * 4 >= _CHUNK_KERNEL_MIN_SCORE_BYTES
+                           and chunk_kernel.tiles(heads, T, stripe) is not None
+                           and chunk_kernel.takes_widths(latent_dims(cfg, kind).rank)) else "einsum"
+        for kind, heads, _ in plan(cfg).kinds if kind in _LATENT_KINDS}
 
 
 def _latent_reader(cfg, params, cache, positions):
@@ -1781,14 +1825,28 @@ def _latent_reader(cfg, params, cache, positions):
     one scores the row's index keys (scope ``attn_index``), takes the best
     (``attn_select``: ``ops/topk.py``) and attends over those positions' keys
     and latents, gathered out of the stripe (``ops/decode_attention.py
-    sparse_latent_decode_attention``). Anything else (a prompt's chunk, a
-    tiny cache): blocks of key positions up to the furthest row's last query
-    and no further (a sliding layer: from the block the earliest row's window
-    starts in), with a running maximum, sum and context in float32, so that
-    neither the work nor any temporary follows the stripe where the rows are
-    shorter; absorbed or expanded a block at a time, by the chunk's width
-    (``_chunk_expands``); an indexed layer's mask is made from the scores of
-    all blocks first."""
+    sparse_latent_decode_attention``). Anything else walks blocks of key
+    positions under the mask (causal over absolute positions, a sliding
+    layer's window, an indexed layer's choice, made from the index scores of
+    all blocks first: ``_kept``) with a running maximum, sum and context in
+    float32, so that neither the work nor any temporary follows the stripe
+    where the rows are shorter. Which walk, ``chunk_walks`` says, a kind:
+
+    - ``T`` a whole number of query tiles, a stripe of whole blocks, one
+      device, float32 scores of 64 KB a key position or more (dots3's full
+      layers from 128 tokens a chunk, its sliding ones at 256): the chunk kernel
+      (``ops/latent_chunk_attention.py``), one call a layer, absorbed or
+      expanded by the chunk's width (``_chunk_expands``; absorbed where the
+      kernel has no expanded form for the shapes); a tile of queries walks
+      from its first query's window to its last query's block, and the
+      float32 scores never leave VMEM;
+    - else (a tiny cache, a chunk of an odd width, ``T`` = 1 off the decode
+      kernel's shapes, Kanana's 32 heads at any width): the same walk in
+      plain XLA under ``lax.fori_loop``, up to the furthest row's last query
+      (a sliding layer: from the block the earliest row's window starts in),
+      absorbed or expanded a block at a time, by the chunk's width
+      (``_chunk_expands``); its scores [B, heads, T, block] pass through HBM
+      (``_LATENT_KEY_BLOCKS``)."""
     from ray_tpu.ops import topk
 
     B, T = positions.shape
@@ -1841,8 +1899,12 @@ def _latent_reader(cfg, params, cache, positions):
     walks = {kind: walk(max(h, cfg.index_heads if kind == "latent" and K else 0))
              for kind, h in kinds.items()}
     # (the plain kind asked as before it had a sibling: a tool swaps the function)
-    expands = {kind: _chunk_expands(cfg, T, *(() if kind == "latent" else (kind,)))
-               for kind in kinds}
+    # the kernel or this walk, a kind (``chunk_walks``); the kernel expands
+    # where the rule says so and its expanded form takes the shapes
+    by = chunk_walks(cfg, S, T, cache["k"], *jax.tree.leaves(params))
+    expands = {kind: _chunk_expands(cfg, T, *(() if kind == "latent" else (kind,))) and (
+        by[kind] != "kernel" or chunk_kernel.expands(heads, T, S, latent_dims(cfg, kind)))
+        for kind, heads in kinds.items()}
     if "latent_sliding" in kinds:  # the block the earliest row's window starts in
         first_block = jnp.maximum(jnp.min(positions[:, 0]) - W + 1, 0) // walks["latent_sliding"][0]
 
@@ -1863,7 +1925,31 @@ def _latent_reader(cfg, params, cache, positions):
         with scope("attn_select"):
             return _kept(scores, K)
 
+    def kernel_read(q, ck_all, cv_all, lay, chosen):
+        """``read`` through ``ops/latent_chunk_attention.py``: the queries
+        head-major, the mask [B, T, S] in one elementwise pass (the kernel
+        reads it a block at a time and bounds its walk by ``positions``)."""
+        q_nope, q_rope = q[0], padded(q[1], ck_all)
+        sliding = lay.kind == "latent_sliding"
+        at = jnp.arange(S)[None, None, :]
+        seen = at <= positions[:, :, None]
+        if sliding:
+            seen = seen & (positions[:, :, None] - at < W)
+        if chosen is not None:
+            seen = seen & chosen
+        if expands[lay.kind]:  # the kernel takes the layer's row of both leaves where they lie
+            q, weights = q_nope, (params[f"wuk_{lay.kind}"], params[f"wuv_{lay.kind}"])
+        else:
+            q, weights = _latent_absorb(params, lay, q_nope), ()
+        out = chunk_kernel.latent_chunk_attention(
+            q_rope.transpose(0, 2, 1, 3), q.transpose(0, 2, 1, 3), seen.astype(jnp.int8), ck_all,
+            cv_all, lay.attn_i, positions, _latent_scale(cfg, lay.kind), W if sliding else None,
+            *weights)
+        return out.transpose(0, 2, 1, 3)
+
     def read(q, ck_all, cv_all, lay, chosen=None):
+        if by[lay.kind] == "kernel":
+            return kernel_read(q, ck_all, cv_all, lay, chosen)
         q_nope, q_rope = q[0], padded(q[1], ck_all)
         H = q_nope.shape[2]
         d = latent_dims(cfg, lay.kind)

@@ -28,7 +28,13 @@ all heads share in ``k`` (64 wide) and the normed latent in ``v`` (512 wide),
 which is also the value. ``latent_decode_attention`` hands the kernel a second
 query (the heads' queries absorbed through the key up-projection) for the
 latent, so a block's score is ``q . k + q_latent . v`` and each block of the
-latent is copied once for both uses."""
+latent is copied once for both uses.
+
+Its sibling for a prompt's chunk (``T`` > 1 new tokens a row over the same
+latent cache) is ``ops/latent_chunk_attention.py``: the same cache taken where
+it lies and the same running softmax, a tile of queries and heads against each
+key block under a mask, on the grid's pipeline instead of this walk's own
+copies (a chunk's rows are few and its blocks are many)."""
 
 from __future__ import annotations
 

@@ -67,8 +67,11 @@ def native_kernels(monkeypatch):
 
     import ray_tpu.ops.kda  # noqa: F401 — nor this one
 
+    import ray_tpu.ops.latent_chunk_attention  # noqa: F401 — nor this one
+
     for name in ("ray_tpu.ops.rmsnorm", "ray_tpu.ops.quant", "ray_tpu.ops.grouped_matmul",
-                 "ray_tpu.ops.decode_attention", "ray_tpu.ops.ssm", "ray_tpu.ops.kda"):
+                 "ray_tpu.ops.decode_attention", "ray_tpu.ops.ssm", "ray_tpu.ops.kda",
+                 "ray_tpu.ops.latent_chunk_attention"):
         monkeypatch.setattr(sys.modules[name], "interpret", lambda: False)
 
 

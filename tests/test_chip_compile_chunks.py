@@ -65,7 +65,7 @@ def test_latent_programs_hold_nothing_as_long_as_the_stripe_and_copy_no_leaf(
     expert layers' loop, beside the three grouped matmuls); neither program's
     temporaries follow the stripe (a [32, 256, 24576] float32 score block
     alone is 0.8 GB; the chunk walks 1,024-position key blocks up to its
-    row's length); and neither relays ``wq_latent`` whole, which both did
+    row's length, in plain XLA: PR 52's kernel is not given 32 heads); and neither relays ``wq_latent`` whole, which both did
     with the leaf head-major or in the default layout (a 192-wide head is no
     whole number of lane tiles: 0.45 of a 9.06 ms decode step on the chip,
     PERF.md section 6, PR 33)."""
@@ -88,6 +88,9 @@ def test_latent_programs_hold_nothing_as_long_as_the_stripe_and_copy_no_leaf(
     chunk = compiled("chunk_final")
     assert chunk.memory_analysis().temp_size_in_bytes < 256e6
     assert relays(chunk.as_text()) == []
+    # 32 heads of 256 queries are 32 KB of float32 scores a key position, under the size
+    # the chunk kernel is given (``models/patterned.py chunk_walks``): the walk stays
+    assert "latent_chunk_attention" not in chunk.as_text()
     # the guard sees the copy where the leaf is head-major as the other models' are
     from ray_tpu.models import llama
 

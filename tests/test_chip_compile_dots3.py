@@ -61,17 +61,23 @@ def test_decode_step_scores_selects_gathers_and_walks_the_windows(
 
 def test_final_chunk_behind_a_document_fits(one_chip, no_compile_cache, native_kernels):
     """256 tokens a row of one: the index scores of the whole stripe a query
-    (25 MB in float32), the choice without a sort (``_kept``), key blocks of
-    1,024 under the mask in the indexed layers and from the window's first
-    block in the sliding ones. Temporaries under 1 GB beside 5.15 GB of
-    weights."""
+    (25 MB in float32), the choice without a sort (``_kept``), then the chunk
+    kernel (``ops/latent_chunk_attention.py``) once a traced latent layer:
+    the two indexed ones under the mask, the sliding ones' loop body from the
+    window's first block. No block's float32 scores ([1, 128, 256, 1024]:
+    134 MB) lie in HBM: the temporaries are 146 MB beside 5.15 GB of weights
+    (403 MB with the walk in plain XLA, the parent's program; both figures
+    from this compile, PR 52)."""
     cfg = _sparse_latent_cut()
     fn, args = _served_programs(cfg, SLOTS, STRIPE, one_chip)["chunk_final"]
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
     lines = compiled.as_text().splitlines()
     assert not any(" sort(" in line and "attn_select" in line for line in lines)
     assert len(_kernels(lines, "moe_ffn/experts")) == 6
+    assert len(_kernels(lines, "attn_core/latent_sparse/latent_chunk_attention")) == 2
+    assert len(_kernels(lines, "attn_core/latent_window/latent_chunk_attention")) == 1
+    assert not any("f32[1,128,256,1024]" in line for line in lines)
 
 
 def test_the_probes_one_chunk_of_two_rows_fits_beside_a_resident_engine(
@@ -79,9 +85,12 @@ def test_the_probes_one_chunk_of_two_rows_fits_beside_a_resident_engine(
     """``benchmark/compare.py serve_program_logits``: two rows of 4,544 tokens
     in one chunk over a 4,608-position cache, beside an engine that holds
     9.1 GB then (weights and pool: the probe runs before the documents are
-    stored): 128 heads' float32 scores are 4.7 MB a key position, so the walk
-    takes blocks of 128 (``_LATENT_SCORES_MAX_BYTES``) and the temporaries
-    stay under 3.4 GB."""
+    stored). 4,544 tokens are 71 of the chunk kernel's tiles of 64 queries, so
+    every latent layer reads through it, absorbed (the expanded form takes one
+    tile of queries): the temporaries are 2.9 GB, the absorbed queries and the
+    context [2, 128, 4544, 512] 1.2 GB each among them (3.2 GB with the walk in
+    plain XLA, whose 4.7 MB of float32 scores a key position held it to blocks
+    of 128: ``_LATENT_SCORES_MAX_BYTES``)."""
     from ray_tpu.models.llama import init_kv_cache, prefill
 
     cfg = _sparse_latent_cut()
@@ -92,3 +101,4 @@ def test_the_probes_one_chunk_of_two_rows_fits_beside_a_resident_engine(
     compiled = jax.jit(lambda p, c, t, n: prefill(p, c, t, cfg, lengths=n)).lower(
         params, cache, i32(2, 4544), i32(2)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 3.4e9
+    assert len(_kernels(compiled.as_text().splitlines(), "latent_chunk_attention")) == 3

@@ -12,14 +12,22 @@ from tests.dots3_models import reference, seeded_params
 
 @pytest.fixture(scope="module")
 def engine():
-    eng = JaxEngine(LLMConfig(
-        model=ModelConfig(model_id="dots3-tiny"),
-        engine=EngineConfig(max_num_seqs=2, max_seq_len=256, dtype="float32",
-                            prefill_buckets=(32, 128), prefill_chunk=32),
-    ))
-    eng.params = seeded_params()
-    yield eng
-    eng.shutdown()
+    """Its chunk programs read the cache through the chunk kernel, as the
+    served model's do from 128 tokens a chunk: four heads of 32 queries are
+    far under the size ``chunk_walks`` gives the kernel, so the size is put
+    aside for this file."""
+    from ray_tpu.models import patterned
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(patterned, "_CHUNK_KERNEL_MIN_SCORE_BYTES", 0)
+        eng = JaxEngine(LLMConfig(
+            model=ModelConfig(model_id="dots3-tiny"),
+            engine=EngineConfig(max_num_seqs=2, max_seq_len=256, dtype="float32",
+                                prefill_buckets=(32, 128), prefill_chunk=32),
+        ))
+        eng.params = seeded_params()
+        yield eng
+        eng.shutdown()
 
 
 def test_a_hit_answers_as_its_miss_and_as_the_reference_and_seeds_every_stripe(engine):
@@ -53,6 +61,8 @@ def test_a_hit_answers_as_its_miss_and_as_the_reference_and_seeds_every_stripe(e
     assert delta("decode_kv_tokens_window") == 5 * rows
     (pool,) = engine.get_stats()["pools"]
     assert pool["kv_bytes_per_token"] == (2 * (128 + 32 + 128) + 3 * (128 + 48)) * 4
+    # both latent kinds' chunk programs (32 wide) went through the chunk kernel
+    assert pool["chunk_walks"] == {"32": {"latent": "kernel", "latent_sliding": "kernel"}}
 
 
 def test_requests_admitted_together_answer_as_each_alone(engine):

@@ -197,6 +197,9 @@ def test_engine_serves_a_hit_as_it_served_the_miss_and_counts_both(engine):
     # 3 layers of 128 (the rotated key's lane row) + 32 (latent) float32 numbers
     assert pool["kv_bytes_per_token"] == 3 * (128 + 32) * 4
     assert "kv_bytes_per_token_held" in pool and stats["prefix_cache_bytes"] > 0
+    # every chunk program (all 32 wide) walked the stripe in plain XLA: four heads of 32
+    # queries are under the size the chunk kernel is given (as the served model's 32 of 256)
+    assert pool["chunk_walks"] == {"32": {"latent": "einsum"}}
 
 
 def test_the_layout_rule_holds_the_latent_query_projection_embed_minor():

@@ -130,6 +130,7 @@ def test_a_model_whose_slots_are_stripes_alone_counts_no_state():
     assert first.token_ids == again.token_ids and again.metrics["prefix_hit_tokens"] == 32
     assert stats["pools"][0]["state_bytes_per_slot"] == 0
     assert stats["pools"][0]["state_mixer_forms"] == {}
+    assert stats["pools"][0]["chunk_walks"] == {}  # no latent cache
     assert stats["counters"]["snapshots_stored"] == stats["counters"]["snapshots_hit"] == 0
     assert set(stats["counters"]["moe_assignments_held"].values()) == {0}
     assert sum(stats["counters"]["moe_assignments"].values()) > 0
