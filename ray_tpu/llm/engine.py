@@ -65,6 +65,8 @@ logger = logging.getLogger(__name__)
 _MOE_COUNTERS = ("moe_layer_steps", "moe_assignments", "moe_experts_touched",
                  "moe_max_expert_load_sum", "moe_assignments_held", "moe_passes")
 _MOE_PROGRAMS = ("decode", "chunk_mid", "chunk_final")
+# why a chunk launch that takes the pool's decode rows carried no step
+DEAD_CAUSES = ("step_carried", "runahead_full", "no_slot")
 COUNTERS = (
     "requests_submitted",
     "requests_finished:stop", "requests_finished:length",
@@ -80,6 +82,13 @@ COUNTERS = (
     # of decode_steps, those a prompt chunk's launch carried (no ``decode_fn``
     # ran for them: the rows rode through ``chunk_mid`` or ``chunk_final``)
     "decode_steps_in_chunk",
+    # launches of a chunk program that takes the pool's decode rows
+    # (``_takes_rows``) and carried no step: the rows' arithmetic ran for no
+    # token (``_decode_rows``: no row is live), by what kept the step from
+    # riding: an earlier launch of the pass had carried it, the run-ahead was
+    # full, or no slot held a request. With ``decode_steps_in_chunk`` they are
+    # the launches of such a program
+    *(f"decode_steps_dead_in_chunk:{cause}" for cause in DEAD_CAUSES),
     # a prompt's chunks by kind, one a prompt chunk: a row of a chunk program
     "prefill_chunks:mid", "prefill_chunks:final",
     # launches of the chunk programs: admissions whose next chunks are of one
@@ -138,6 +147,7 @@ COUNTERS = (
 )
 _LABEL = {"requests_finished": "reason", "requests_failed": "stage",
           "prefill_chunks": "kind", "prefill_programs": "kind",
+          "decode_steps_dead_in_chunk": "cause",
           **dict.fromkeys((*_MOE_COUNTERS, "prefill_query_tokens",
                            "prefill_attended_positions"), "program")}
 # request latencies: 1 ms to 200 s, a quarter more each bucket, so a median
@@ -521,8 +531,10 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         """The next input tokens and keys of a pool whose decode step rode
         through a chunk program (``rows``: what ``decode_fn`` takes beside the
         cache, and ``live``): each live row sampled by the one ``sample_row``,
-        every other row's token and key as they came in."""
-        with jax.named_scope("sampling"):
+        every other row's token and key as they came in. Named as the rows'
+        other work is (``models/patterned.py decode_forward``): ``beside`` in
+        front of ``sampling``."""
+        with jax.named_scope("beside"), jax.named_scope("sampling"):
             next_tokens, new_keys = sample_rows(
                 logits, rows["temps"], rows["top_ks"], rows["keys"]
             )
@@ -1903,11 +1915,12 @@ class JaxEngine:
                 n * start + n * (n + 1) // 2 for _, n, start in plan),
         })
 
-    def _launch_mid_chunks(self, pool: "_Pool", adms: list, carry: Optional[dict] = None) -> None:
+    def _launch_mid_chunks(self, pool: "_Pool", adms: list,
+                           carry: "dict | str | None" = None) -> None:
         """Dispatch ONE ``chunk_mid`` (device-async) whose rows are the next
         middle chunks of ``adms``: each row's stripe comes back extended.
         ``carry``: the slots (slot -> request) whose decode step the launch
-        carries."""
+        carries, or why it carries none (``_advance_admissions``)."""
         plan = [self._next_chunk(adm) for adm in adms]
         ones = self._run_chunk_mid(
             ones=tuple(adm.one for adm in adms),
@@ -1928,28 +1941,37 @@ class JaxEngine:
         the middle chunk of one row. A launch of several rows stays the
         chunks' alone: each row count is a form and would hold the decode
         program as well, a larger file to restore at every start; a later
-        launch of the pass that takes rows carries instead (ROADMAP S2 c)."""
+        launch of the pass that takes rows carries instead (ROADMAP S2 c).
+        A launch that takes rows runs them whether or not a step rides: with
+        no step to carry no row is live and their arithmetic is done for no
+        token (``decode_steps_dead_in_chunk``)."""
         return pool.carries and rows == 1
 
-    def _decode_rows(self, pool: "_Pool", carry: Optional[dict]) -> dict:
+    def _decode_rows(self, pool: "_Pool", carry: "dict | str | None") -> dict:
         """What a chunk program of a pool that ``carries`` takes of the pool's
         decode step beside the cache: the inputs ``decode_fn`` takes, and the
-        rows that decode in this launch (none without ``carry``)."""
+        rows that decode in this launch (none unless ``carry`` is slots: the
+        program runs every row all the same, for no token)."""
         import jax.numpy as jnp
 
         live = np.zeros((pool.n_slots,), bool)
-        live[list(carry or ())] = True
+        if isinstance(carry, dict):
+            live[list(carry)] = True
         temps, top_ks = pool.sampler()
         return dict(tokens=pool.dev_tokens, temps=temps, top_ks=top_ks, keys=pool.keys,
                     live=jnp.asarray(live))
 
-    def _carried(self, pool: "_Pool", carry: Optional[dict], next_tokens) -> None:
-        """After a chunk launch of a pool that ``carries``: the pool's next
-        input tokens are the program's, and a step it carried goes on
-        ``pool.inflight`` with its binding as a decode launch's does (its
-        routing counts are among the chunk program's)."""
+    def _carried(self, pool: "_Pool", carry: "dict | str | None", next_tokens) -> None:
+        """After a launch of a chunk program that took ``pool``'s decode rows:
+        the pool's next input tokens are the program's, and a step it carried
+        goes on ``pool.inflight`` with its binding as a decode launch's does
+        (its routing counts are among the chunk program's). A launch of the
+        loop's that carried none is counted by what kept the step from riding
+        (``carry`` is then that one of ``DEAD_CAUSES``): its rows ran dead."""
         pool.dev_tokens = next_tokens
-        if not carry:
+        if not isinstance(carry, dict):
+            if carry is not None:
+                self._count({"decode_steps_dead_in_chunk:" + carry: 1})
             return
         try:
             next_tokens.copy_to_host_async()
@@ -1960,12 +1982,13 @@ class JaxEngine:
         self._count({**self._decode_counts(pool, carry, 1), "decode_steps_in_chunk": 1})
 
     def _run_chunk_mid(self, ones: tuple, toks, lens: list, starts: list, adapters: list,
-                       pool: Optional["_Pool"] = None, carry: Optional[dict] = None) -> tuple:
+                       pool: Optional["_Pool"] = None,
+                       carry: "dict | str | None" = None) -> tuple:
         """The device side of a middle-chunk launch, a row an entry. ``pool``:
         the stripes' pool (None: found by their length); where it ``carries``
-        the one-row program takes its cache and decode rows, and with
-        ``carry`` they decode in this launch (a launch of several rows is the
-        chunks' alone: ``_takes_rows``)."""
+        the one-row program takes its cache and decode rows, and with slots
+        for ``carry`` they decode in this launch (a launch of several rows is
+        the chunks' alone: ``_takes_rows``)."""
         import jax.numpy as jnp
 
         if pool is None:
@@ -1987,12 +2010,13 @@ class JaxEngine:
         return ones
 
     def _launch_final_chunk(self, pool: "_Pool", adm: _Admission,
-                            carry: Optional[dict] = None) -> None:
+                            carry: "dict | str | None" = None) -> None:
         """Dispatch a prompt's final chunk (device-async), one prompt a launch
         (``programs``' ``chunk_final`` says why): it samples the first token
         in-program and activates the slot. ``carry``: the slots (slot ->
-        request) whose decode step the launch carries; the slot it activates
-        is bound after the launch and is none of them."""
+        request) whose decode step the launch carries (or why it carries
+        none); the slot it activates is bound after the launch and is none of
+        them."""
         toks, eff_len, start = self._next_chunk(adm)
         req, slot = adm.req, adm.slot
         # decode truncates to the program's static top-K; clamp here so
@@ -2027,11 +2051,11 @@ class JaxEngine:
 
     def _run_chunk_final(self, pool: "_Pool", one, toks, eff_len: int, start: int, slot: int,
                          temperature: float, top_k: int, seed: Optional[int], adapter: int,
-                         carry: Optional[dict] = None):
+                         carry: "dict | str | None" = None):
         """The device side of a final-chunk launch: the pool's cache, keys
         and next input tokens take the slot's new values (and, in a pool that
-        ``carries``, with ``carry`` those of the rows that decode in this
-        launch). Returns the first token and the routing counts (or None),
+        ``carries``, with slots for ``carry`` those of the rows that decode in
+        this launch). Returns the first token and the routing counts (or None),
         both still on the device, and the scratch stripe as the chunk left it
         (what a snapshot of the prompt is cut from)."""
         import jax
@@ -2129,8 +2153,11 @@ class JaxEngine:
         pool's decode step where one is due (``_decode_due``: the rule
         ``_launch_decodes`` launches by, which then launches none for that
         pool in this pass): the rows that decode ride through the chunk's read
-        of the weights, one program where there were two. Later launches of
-        the pass, and passes with no chunk, run as they did."""
+        of the weights, one program where there were two. A later one-row
+        launch of the pass, or one that finds no step due, runs the same
+        program with no row live: the rows' kernels, scatter and sampler run
+        and nothing reads them (``decode_steps_dead_in_chunk``, by cause). A
+        launch of several rows and a pass with no chunk run as they did."""
         progressed = False
         for pool in self._pools:
             launches, mids = [], None  # mids: the middle-chunk launch with room left
@@ -2151,8 +2178,8 @@ class JaxEngine:
                 mids.append(adm)
             for is_final, adms in launches:
                 carry = None
-                if self._takes_rows(pool, len(adms)) and not pool.step_carried:
-                    carry = self._decode_due(pool)
+                if self._takes_rows(pool, len(adms)):
+                    carry = "step_carried" if pool.step_carried else self._decode_due(pool)
                 try:
                     with self._device_call(
                         "launch", "chunk_final" if is_final else "chunk_mid",
@@ -2168,12 +2195,15 @@ class JaxEngine:
                         self._fail_admission(pool, adm, e)
         return progressed
 
-    def _decode_due(self, pool: "_Pool") -> Optional[dict]:
-        """The slots (slot -> request) of ``pool``'s next decode step, or None
-        where none is due: no slot holds a request, or the run-ahead is full."""
+    def _decode_due(self, pool: "_Pool") -> "dict | str":
+        """The slots (slot -> request) of ``pool``'s next decode step, or
+        where none is due the reason, one of ``DEAD_CAUSES``: no slot holds a
+        request, or the run-ahead is full."""
         active = {s: r for s, r in enumerate(pool.slots) if r is not None}
-        if not active or len(pool.inflight) > max(0, self.config.engine.decode_runahead):
-            return None
+        if not active:
+            return "no_slot"
+        if len(pool.inflight) > max(0, self.config.engine.decode_runahead):
+            return "runahead_full"
         return active
 
     def _decode_counts(self, pool: "_Pool", active: dict, steps: int) -> dict:
@@ -2214,7 +2244,7 @@ class JaxEngine:
         for pool in self._pools:
             carried, pool.step_carried = pool.step_carried, False
             active = None if carried else self._decode_due(pool)
-            if active is None:
+            if not isinstance(active, dict):
                 continue
             try:
                 with self._device_call("launch", "decode", "engine.decode_launch"):

@@ -2112,8 +2112,10 @@ class _Rows:
     cache it writes into and reads, and the form of each (``_cache_writer``,
     ``_cache_reader`` or ``_latent_reader``), chosen from its own shapes."""
 
-    def __init__(self, cfg, params, kinds, cache, tokens, positions, valid, start_pos):
+    def __init__(self, cfg, params, kinds, cache, tokens, positions, valid, start_pos,
+                 second: bool = False):
         self.cache, self.tokens, self.positions, self.valid = cache, tokens, positions, valid
+        self.second = second
         self.B, self.T = tokens.shape
         self.write = _cache_writer(cfg, cache["k"].shape[3], positions, valid, start_pos)
         self.select = None  # an indexed latent layer's choice of positions
@@ -2126,6 +2128,11 @@ class _Rows:
     def real(self):
         """``valid``, or all of them."""
         return jnp.ones((self.B, self.T), bool) if self.valid is None else self.valid
+
+    def scope(self):
+        """The name in front of what these rows run alone: ``beside`` for a
+        second set of rows (``decode_forward``), none for the first."""
+        return scope("beside") if self.second else contextlib.nullcontext()
 
 
 def _join(parts):
@@ -2196,6 +2203,18 @@ def decode_forward(
     out with the first cache. Returns ``(logits, cache, logits2 [B2, 1, V],
     cache2)`` then. Not with ``loras``, nor over a latent cache.
 
+    What the second set's rows run alone is named so: one more part,
+    ``beside``, in front of the name it has without them (``_Rows.scope``:
+    ``beside/kv_write``, ``beside/attn_core/window``,
+    ``beside/attn_core/ssm_mixer/ssm_step``, ``beside/attn_qkv/cca_conv``; a
+    middle chunk's last feed-forward where ``narrow`` holds and its head,
+    ``beside/moe_ffn/experts`` and ``beside/lm_head``), so that a device trace
+    can tell a carried step's own operations from the chunk's
+    (``benchmark/carried.py``); a reader that knows no such part books the
+    operation to the known name behind it, as before. What multiplies both
+    sets' rows as one matrix (``_join``) keeps its name and is booked to the
+    chunk: 32-64 rows beside a chunk's 64-1,024 tokens.
+
     ``loras``/``adapter_ids``: stacked LoRA adapters + per-sequence adapter
     index (0 = base), over layers that are alike.
     ``with_logits=False`` (a prompt's middle chunk) only extends the cache
@@ -2230,7 +2249,7 @@ def decode_forward(
     if beside is not None:
         cache2, tokens2, live = beside
         sets.append(_Rows(cfg, params, kinds, cache2, tokens2[:, None], cache2["length"][:, None],
-                          None if live is None else live[:, None], None))
+                          None if live is None else live[:, None], None, second=True))
     from_latent = sets[0].from_latent  # a latent model's rows are one set
     shapes = [(rows.B, rows.T) for rows in sets]
     positions = _join([rows.positions for rows in sets])
@@ -2264,11 +2283,12 @@ def decode_forward(
             project, mix, out, names = STATE_MIXERS[lay.kind]
             h = _rmsnorm(x, params["attn_norm"][lay.mixer_i], cfg.rms_eps, cfg.fused_rmsnorm)
             parts, gate = project(params, lay, h, real, cfg)
-            mixed = [
-                mix(params, lay, *of_rows, *(leaves[name] for name in names), rows.valid, cfg)
-                for rows, leaves, *of_rows in zip(
-                    sets, state, *(_split(part, shapes) for part in parts))
-            ]
+            mixed = []
+            for rows, leaves, *of_rows in zip(
+                    sets, state, *(_split(part, shapes) for part in parts)):
+                with rows.scope():
+                    mixed.append(mix(params, lay, *of_rows, *(leaves[name] for name in names),
+                                     rows.valid, cfg))
             state = tuple({**leaves, **dict(zip(names, new))}
                           for leaves, (_, *new) in zip(state, mixed))
             ys = [y for y, *_ in mixed]
@@ -2285,11 +2305,13 @@ def decode_forward(
             elif lay.kind in STRIPE_STATE:
                 # each set's rows behind their own row ``attn_i`` of the leaf
                 project, qkv, leaf = STRIPE_STATE[lay.kind]
-                q, k, v, carried = zip(*(
-                    qkv(params, lay, *parts, leaves[leaf][lay.attn_i], rows.positions,
-                        rows.valid, cfg)
-                    for rows, leaves, *parts in zip(
-                        sets, state, *(_split(t, shapes) for t in project(params, lay, h)))))
+                of_sets = []
+                for rows, leaves, *parts in zip(
+                        sets, state, *(_split(t, shapes) for t in project(params, lay, h))):
+                    with rows.scope():
+                        of_sets.append(qkv(params, lay, *parts, leaves[leaf][lay.attn_i],
+                                           rows.positions, rows.valid, cfg))
+                q, k, v, carried = zip(*of_sets)
                 state = tuple(
                     {**leaves, leaf: jax.lax.dynamic_update_index_in_dim(
                         leaves[leaf], new, lay.attn_i, 0)}
@@ -2306,7 +2328,7 @@ def decode_forward(
                 # a latent model's q is a pair, and its rows are one set
                 qj, kj, vj = (q, k, v) if lay.latent else (q[j], k[j], v[j])
                 written, chosen = {}, ()
-                with scope("kv_write"):
+                with rows.scope(), scope("kv_write"):
                     if lay.latent:  # zeros up to the cache's row of whole lane tiles (``init_kv_cache``)
                         kj = jnp.pad(kj, ((0, 0),) * 3 + ((0, ck_all.shape[-1] - kj.shape[-1]),))
                     # the cache is head-major: the new [B, T, K, D] rows go in as [B, K, T, D]
@@ -2320,15 +2342,17 @@ def decode_forward(
                 if index and rows.select is not None:
                     with scope("attn_core"):  # ``attn_index`` and ``attn_select`` inside it
                         chosen = (rows.select(index, written["k_index"], lay),)
-                with scope("attn_core"), lay.inner_scope():
+                with rows.scope(), scope("attn_core"), lay.inner_scope():
                     attn.append(rows.read(qj, ck_all, cv_all, lay, *chosen))
                 new_kv.append({**kv[j], names[0]: ck_all, names[1]: cv_all, **written})
             kv = tuple(new_kv)
             x = _attn_out(params, lay, x, h, _join(attn), cfg, from_latent[lay.kind])
         if lay.mlp != "none":
-            if narrow and lay.last:
+            alone = narrow and lay.last  # the second set's rows alone
+            if alone:
                 x, *route = (_split(t, shapes)[1] for t in (x, *route))
-            x, layer_stats, route = _feed_forward(params, lay, x, cfg, tuple(route))
+            with (sets[1] if alone else sets[0]).scope():
+                x, layer_stats, route = _feed_forward(params, lay, x, cfg, tuple(route))
             stats = tuple(s + layer_stats for s in stats)
         return (x, kv, stats, state, route)
 
@@ -2354,8 +2378,10 @@ def decode_forward(
         heads = heads[1:]
     logits = []
     if heads:
-        x = _rmsnorm(_join(heads), params["final_norm"], cfg.rms_eps, cfg.fused_rmsnorm)
-        logits = _split(_project_logits(x, params, cfg, None), [h.shape[:2] for h in heads])
+        # a middle chunk's head is the second set's alone
+        with (sets[0] if with_logits else sets[-1]).scope():
+            x = _rmsnorm(_join(heads), params["final_norm"], cfg.rms_eps, cfg.fused_rmsnorm)
+            logits = _split(_project_logits(x, params, cfg, None), [h.shape[:2] for h in heads])
     if not with_logits:
         logits = [None] + logits
     if beside is None:

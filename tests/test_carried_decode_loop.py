@@ -1,6 +1,7 @@
 """The loop that launches carrying chunks (``llm/engine.py
 _advance_admissions``): when a first token is taken, the counters of carrying
-launches, and the pool that never carries (a latent pool launches the chunk
+launches and of those that took the rows and carried none, and the pool that
+never carries (a latent pool launches the chunk
 alone). Requests admitted beside decoding rows, family by family:
 ``tests/test_carried_decode_beside.py``. The subject:
 ``tests/test_carried_decode.py``."""
@@ -120,6 +121,69 @@ def test_the_counters_of_carrying_launches():
     assert n["decode_slot_steps"] == (
         n["tokens_generated"] - n["first_tokens"] + n["tokens_discarded"])
     assert a.error is None and b.error is None and len(a.out_tokens) == len(b.out_tokens) == 30
+
+
+def test_a_launch_that_takes_rows_and_carries_none_is_counted_by_its_cause():
+    """Every launch of a chunk program that takes the pool's rows runs them,
+    and one that carries no step counts why (``decode_steps_dead_in_chunk``):
+    no slot held a request, an earlier launch of the pass had carried the step
+    (two final chunks due in one pass: one carries, one runs dead), or the
+    run-ahead was full (``_drain`` leaves no more than ``decode_runahead`` steps
+    in flight, so the loop's own passes never find it so: here two launches go
+    undrained). ``decode_steps_in_chunk`` and the three causes are the
+    launches of the carrying forms, one ``engine.counts`` key each."""
+    eng = _engine("dense")
+    eng.shutdown()  # the loop thread is gone: the stages are the test's
+    pool = eng._pools[0]
+    took = []  # launches that were handed the pool's rows, by program
+
+    def recording(name, with_rows):
+        def make(inner):
+            def program(*args, **kw):
+                took.extend([name] * (len(args) == with_rows))
+                return inner(*args, **kw)
+            return program
+        return make
+
+    def dead(grew):
+        return {k.partition(":")[2]: v for k, v in grew.items()
+                if k.startswith("decode_steps_dead_in_chunk")}
+
+    rng = np.random.default_rng(6)
+    ids = lambda n: [int(t) for t in rng.integers(1, 250, n)]  # noqa: E731
+    sp = SamplingParams(max_tokens=40, temperature=0.0, ignore_eos=True)
+    with programs_replaced(eng, "chunk_mid", recording("chunk_mid", 7)), \
+            programs_replaced(eng, "chunk_final", recording("chunk_final", 11)):
+        before = _flat(eng)
+        a = eng.submit(prompt_token_ids=ids(5), sampling_params=sp)
+        _pass(eng)  # its final chunk: no slot holds a request yet
+        grew = _grew(eng, before)
+        assert dead(grew) == {"no_slot": 1} and "decode_steps_in_chunk" not in grew
+        before = _flat(eng)
+        b, c = (eng.submit(prompt_token_ids=ids(n), sampling_params=sp) for n in (6, 7))
+        _pass(eng)  # two final chunks due in one pass
+        grew = _grew(eng, before)
+        assert grew["prefill_programs:final"] == 2 and grew["decode_steps"] == 1
+        assert grew["decode_steps_in_chunk"] == 1 and dead(grew) == {"step_carried": 1}
+        for _ in range(2):  # launches nobody drains: one step more in flight than the run-ahead
+            eng._advance_admissions()
+            eng._launch_decodes()
+        assert len(pool.inflight) == eng.config.engine.decode_runahead + 1
+        before = _flat(eng)
+        d = eng.submit(prompt_token_ids=ids(40), sampling_params=sp)
+        eng._pull_waiting()
+        eng._advance_admissions()  # its first middle chunk, one row
+        grew = _grew(eng, before)
+        assert grew["prefill_programs:mid"] == 1 and "decode_steps" not in grew
+        assert dead(grew) == {"runahead_full": 1}
+        while not all(r.done.is_set() for r in (a, b, c, d)):
+            _pass(eng)
+    n = eng._n
+    assert took.count("chunk_final") == n["prefill_programs:final"] == 4
+    assert took.count("chunk_mid") == n["prefill_programs:mid"] == 2
+    assert n["decode_steps_in_chunk"] + sum(
+        v for k, v in n.items() if k.startswith("decode_steps_dead_in_chunk")) == len(took)
+    assert all(r.error is None and len(r.out_tokens) == 40 for r in (a, b, c, d))
 
 
 def _plain_chunk_final(cfg):

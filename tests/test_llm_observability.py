@@ -557,7 +557,10 @@ def test_a_profiler_sessions_count_events_sum_to_the_counters_growth(engine, tmp
             "prefill_programs:mid", "prefill_chunks:mid", "prefill_programs:final",
             "prefill_query_tokens:chunk_final", "prefill_attended_positions:chunk_mid",
             "decode_steps", "decode_slot_steps", "decode_kv_tokens_global",
-            "decode_kv_positions_read", "tokens_generated", "first_tokens"} <= set(sums)
+            "decode_kv_positions_read", "tokens_generated", "first_tokens",
+            # chunk launches that carried the pool's step, and the session's
+            # first, which found no slot decoding and ran its rows dead
+            "decode_steps_in_chunk", "decode_steps_dead_in_chunk:no_slot"} <= set(sums)
     # one event a launch and one a fetch, not one a token
     per_launch = [s for _, _, _, s in events if "decode_steps" in s]
     assert sum(s["decode_steps"] for s in per_launch) == len(per_launch) == growth["decode_steps"]
