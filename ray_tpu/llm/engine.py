@@ -113,8 +113,8 @@ COUNTERS = (
     # decode_slot_steps x stripe what it still touches of the stripe
     "decode_kv_positions_read", "decode_kv_positions_read_window",
     # the same pair for the layers of a latent-attention model (a token there
-    # is one shared rotated key and one latent; the kernel's blocks are
-    # longer, ``ops/decode_attention.py LATENT_BLOCKS``); such a model has no
+    # is one shared rotated key and one latent; the kernel's blocks follow the
+    # stripe alone there, ``ops/decode_attention.py block_size``); such a model has no
     # full or window layers, so the four above stay 0 for it
     "decode_kv_tokens_latent", "decode_kv_positions_read_latent",
     # a model whose latent layers attend what an indexer picks
@@ -372,6 +372,7 @@ class _Pool:
 
         from ray_tpu.models.llama import init_kv_cache
         from ray_tpu.models.patterned import STATE_LEAVES, reads_blocks, stripe_cache_shapes
+        from ray_tpu.ops.decode_attention import block_size, cache_position_bytes
 
         self.stripe_len = stripe_len
         self.n_slots = n_slots
@@ -416,16 +417,24 @@ class _Pool:
         self.inflight: "deque" = deque()
         # first tokens from final prefill chunks awaiting host arrival
         self.first_pending: list = []
-        # whether this pool's decode steps read its stripes through the
-        # decode kernel: asked once, of the layer that decides it, with the
-        # arrays the steps run on
         self.chunk_rows = 1  # the most rows of one middle-chunk launch (the engine sets it)
         # whether this pool's chunk programs take its decode rows (the engine
         # sets it), and whether a chunk launch of this pass carried its step
         self.carries = False
         self.step_carried = False
+        # whether this pool's decode steps read its stripes through the
+        # decode kernel: asked once, of the layer that decides it, with the
+        # arrays the steps run on
         self.reads_blocks = reads_blocks(
             stripe_len, self.cache["k"], *jax.tree.leaves(params), latent=self.latent
+        )
+        # what a position of a row holds in a layer of the cache the kernel is
+        # handed, and the positions a block of its walk takes of such a cache
+        # (``ops/decode_attention.py block_size``; None where the steps keep
+        # the einsum): the kernel's own rule, asked with the pool's own arrays
+        self.position_bytes = cache_position_bytes(self.cache["k"], self.cache["v"])
+        self.decode_block = (
+            block_size(stripe_len, self.position_bytes, self.latent) if self.reads_blocks else None
         )
 
     def sampler(self) -> tuple:
@@ -445,7 +454,8 @@ class _Pool:
             return self.stripe_len * len(hi)
         from ray_tpu.ops.decode_attention import positions_read
 
-        return int(positions_read(lo, hi, self.stripe_len, self.latent).sum())
+        return int(
+            positions_read(lo, hi, self.stripe_len, self.position_bytes, self.latent).sum())
 
 
 def programs(cfg, decode_steps: int = 1) -> dict:
@@ -1636,6 +1646,9 @@ class JaxEngine:
                  "kv_bytes_per_token": p.kv_bytes_per_token,
                  "kv_bytes_per_token_held": p.kv_bytes_per_token_held,
                  "state_bytes_per_slot": p.state_bytes_per_slot,
+                 # positions a block of the decode kernel's walk takes of this
+                 # pool's cache (None: its steps keep the einsum over the stripe)
+                 "decode_block": p.decode_block,
                  # which form its state mixers take for a chunk and a step
                  # (``kernel`` or ``plain``): static a shape, asked where the trace asks
                  "state_mixer_forms": state_mixer_forms(self.model_cfg),

@@ -70,11 +70,11 @@ from jax.sharding import Mesh
 
 from ray_tpu.ops import latent_chunk_attention as chunk_kernel
 from ray_tpu.ops.decode_attention import (
-    block_size,
     decode_attention,
     latent_decode_attention,
     sparse_latent_decode_attention,
     takes_heads_of,
+    takes_stripe,
 )
 from ray_tpu.parallel.mesh import with_sharding
 
@@ -1732,10 +1732,12 @@ def reads_blocks(stripe: int, *arrays, latent: bool = False) -> bool:
     (``llm/engine.py _Pool.reads_blocks``, for its counter), ``_cache_reader``
     with the tracers of the same arrays, and both get the same answer.
 
-    The kernel wants a stripe of whole blocks (``latent``: of a latent
-    model's, which are longer), heads as wide as its copies take (the first
-    of ``arrays`` is the cache's keys ``[.., D]``; a latent cache's rows are
-    whole lane tiles by ``init_kv_cache``) and everything on one device.
+    The kernel wants a stripe of whole blocks (``takes_stripe``: of the
+    shortest; how long a block then is follows from what a position of the
+    cache holds, ``ops/decode_attention.py block_size``), heads as wide as its
+    copies take (the first of ``arrays`` is the cache's keys ``[.., D]``; a
+    latent cache's rows are whole lane tiles by ``init_kv_cache``) and
+    everything on one device.
     An argument committed to a ``NamedSharding`` carries its mesh in its
     type, inside a trace too (``llm/spmd.py`` and ``llm/gang.py`` jit over a
     mesh with the key-value heads sharded over ``tp``; an engine under
@@ -1744,7 +1746,7 @@ def reads_blocks(stripe: int, *arrays, latent: bool = False) -> bool:
     uncommitted argument that only a ``jit``'s ``in_shardings`` spreads over
     a mesh reads as one device (no caller in this repo places its arrays
     so; ``tests/test_patterned_stack.py`` traces the ways they do)."""
-    if block_size(stripe, latent) is None:
+    if not takes_stripe(stripe):
         return False
     if not latent and not takes_heads_of(arrays[0]):
         return False

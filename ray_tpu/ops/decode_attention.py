@@ -49,29 +49,66 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops._common import _SUBLANE, interpret
 
-# Positions a block: all K key-value heads of one row, [K, BLOCK, D] (0.25 MB
-# of bf16 at K = 8, D = 128: 0.6 us of DMA for keys and values together).
-# Measured on a v5e at the serving cells' shapes (PERF.md section 6, PR 31; us
-# a layer at 128 / 256 / 512): 71 / 82 / 107 for 32 slots of 1,024 with 304
-# live, 222 / 228 / 246 for slots of 4,096 with 1,107 live, 105 / 119 / 152 for
-# a 512-position window: the copies stream at 690-730 GB/s at every size, so
-# the smallest block, which reads least past a row's bounds, wins. A stripe
-# that is no whole number of blocks is not this kernel's.
-BLOCK = 128
-# A latent layer's block is one head of 64 + 512 numbers a position: a quarter
-# of the bytes of 8 heads of 2 x 128, so it takes four times the positions to
-# keep the copies as long against the walk's fixed cost a block (reckoned from
-# the sizes above, not swept); shorter where the stripe is no multiple of it
-LATENT_BLOCKS = (512, 256, BLOCK)
+# Positions a block of the walk takes, longest first: all K key-value heads of
+# one row, [K, bs, D] of keys and as much of values, copied double-buffered.
+BLOCKS = (512, 256, 128)
+BLOCK = BLOCKS[-1]  # the shortest: a stripe that is no whole number of them is not this kernel's
+# Bytes of keys and values together a block is sized to. A step of the walk
+# costs its copies (bytes over the bandwidth) or a fixed part, whichever is
+# longer (the running maximum, ``exp``, sum and rescale are one serial chain a
+# block whatever its width; two copies to start, two semaphores to wait on),
+# and a row reads whole blocks past its bounds: so a block is the shortest at
+# which the copies are the longer of the two. Measured on a v5e, bfloat16, the
+# kernel's device time in a profiler trace (``tools/decode_block_sweep.py``;
+# PERF.md section 6, PR 54), us a block by what it holds: 0.46-0.48 at 128 KB,
+# 0.52-0.55 at 256, 0.71-0.74 at 512, 1.39-1.41 at 1,024, 2.78-2.81 at 2,048:
+# 730-750 GB/s from 512 KB up, a fixed 0.45-0.5 us under it. Us a layer at 128
+# / 256 / 512 positions, at the serving cells' shapes and live lengths:
+#   2 heads of 128 (1 KB a position): ZAYA1 (64 rows of 1,556 live in 4,608)
+#     371 / 218 / 163, Nemotron-3 (64 of 469 in 2,048; 16 query heads a group)
+#     128 / 80 / 64 (95 at 1,024);
+#   8 heads of 128 (4 KB a position): Mistral (32 of 245 in 1,024) 57 / 63 / 95,
+#     Laguna 185 / 191 / 217 (32 of 972 in 4,096) and 105 / 120 / 157 (its 512
+#     window), Solar (64 of 1,556 in 8,192) 584 / 586 / 634 (PR 31 read the
+#     same order: 71 / 82 / 107 at 304 live, 222 / 228 / 246, 105 / 119 / 152).
+# Each shape's best is the longest block of 512 KB or less. A sliding window
+# of few heads pays for the length (a window of 512 positions under
+# 512-position blocks reads two blocks for one; no served model has one).
+BLOCK_BYTES = 512 * 1024
 _MASKED = -1e30  # finite: exp(_MASKED - m) is 0 and nothing is inf - inf
 
 
-def block_size(stripe: int, latent: bool = False) -> Optional[int]:
-    """Positions a block of a ``stripe``-position cache (``latent``: of a
-    latent-attention model's), or None where the kernel does not apply (the
-    caller keeps the einsum)."""
-    for bs in LATENT_BLOCKS if latent else (BLOCK,):
-        if stripe % bs == 0:
+def cache_position_bytes(ck_all, cv_all) -> int:
+    """Bytes one position of a row holds in a layer of the cache ``ck_all``,
+    ``cv_all`` [L, B, K, S, D]: ``K`` heads of a key and of a value."""
+    return sum(x.shape[2] * x.shape[-1] * x.dtype.itemsize for x in (ck_all, cv_all))
+
+
+def takes_stripe(stripe: int) -> bool:
+    """Whether the walk applies to a cache of ``stripe`` positions a slot: a
+    whole number of blocks, of the shortest (and so of whichever divides it)."""
+    return stripe % BLOCK == 0
+
+
+def block_size(stripe: int, position_bytes: int, latent: bool = False) -> Optional[int]:
+    """Positions a block of a ``stripe``-position cache whose rows hold
+    ``position_bytes`` a position and layer (``cache_position_bytes``), or None
+    where the kernel does not apply (``takes_stripe``; the caller keeps the
+    einsum): the longest of ``BLOCKS`` that divides the stripe and holds
+    ``BLOCK_BYTES`` or less; the shortest is taken whatever it holds.
+
+    ``latent``: the longest that divides the stripe, whatever it holds. A
+    latent block's fixed part is the larger (every query head of the model, 32
+    to 128 of them, meets each position twice, as part of the key and as the
+    value), and its rows are documents of thousands of positions, where a long
+    block reads little past the end: at Kanana-2's shape (24 rows of 17,880
+    live, 1.25 KB a position) a layer takes 1,736 / 1,129 / 837 us at 128 / 256
+    / 512 positions (160 / 320 / 640 KB a block; 765 at 1,024, not offered), so
+    the 256 that ``BLOCK_BYTES`` would give it is a third slower than 512; at
+    dots3's sliding layers (16 rows, a window of 513 of a 2.25 KB latent)
+    61 / 53 / 57 (same sweep)."""
+    for bs in BLOCKS:
+        if stripe % bs == 0 and (latent or bs == BLOCK or bs * position_bytes <= BLOCK_BYTES):
             return bs
     return None
 
@@ -87,8 +124,8 @@ def takes_heads_of(cache_k) -> bool:
     return interpret() or cache_k.shape[-1] % 128 == 0
 
 
-def _whole_blocks(stripe: int, latent: bool = False) -> int:
-    bs = block_size(stripe, latent)
+def _whole_blocks(stripe: int, position_bytes: int, latent: bool = False) -> int:
+    bs = block_size(stripe, position_bytes, latent)
     if bs is None:
         raise ValueError(f"a {stripe}-position stripe is no whole number of {BLOCK}-position blocks")
     return bs
@@ -102,14 +139,15 @@ def _clamp(lo, hi, stripe: int, xp=jnp):
     return xp.clip(lo, 0, hi - 1), hi
 
 
-def positions_read(lo, hi, stripe: int, latent: bool = False):
+def positions_read(lo, hi, stripe: int, position_bytes: int, latent: bool = False):
     """Positions the kernel's blocks cover for rows bounded ``[lo, hi)`` in a
-    cache of ``stripe`` positions a slot (whole blocks): what it reads of
-    each of keys and values, a key-value head. Integers or NumPy arrays of
-    them, on the host: the engine counts with it. The bounds go through the
-    ``_clamp`` the kernel's go through, and the blocks between them are the
-    kernel's ``lo // bs`` to ``(hi - 1) // bs``."""
-    bs = _whole_blocks(stripe, latent)
+    cache of ``stripe`` positions a slot and ``position_bytes`` a position
+    (whole blocks, ``block_size``'s): what it reads of each of keys and
+    values, a key-value head. Integers or NumPy arrays of them, on the host:
+    the engine counts with it. The bounds go through the ``_clamp`` the
+    kernel's go through, and the blocks between them are the kernel's
+    ``lo // bs`` to ``(hi - 1) // bs``."""
+    bs = _whole_blocks(stripe, position_bytes, latent)
     lo, hi = _clamp(np.asarray(lo), np.asarray(hi), stripe, np)
     return ((hi - 1) // bs - lo // bs + 1) * bs
 
@@ -206,7 +244,7 @@ def _walk(queries, ck_all, cv_all, layer, lo, hi, scale: float, latent: bool, na
     B, H, _ = queries[0].shape
     _, _, K, S, Dk = ck_all.shape
     Dv = cv_all.shape[-1]
-    bs = _whole_blocks(S, latent)
+    bs = _whole_blocks(S, cache_position_bytes(ck_all, cv_all), latent)
     G = H // K
     # a key-value head's query heads are its matmul's rows: whole sublanes
     Gp = -(-G // _SUBLANE) * _SUBLANE
@@ -253,7 +291,7 @@ def latent_decode_attention(q_rope, q_latent, ck_all, cv_all, layer, lo, hi, sca
     are the values too -> each head's context in the latent's space
     [B, H, R]. ``scale``: one over the root of the width of a head's whole
     key, which the caller knows and the cache does not. ``S`` is a whole
-    number of ``block_size(S, latent=True)`` blocks."""
+    number of ``block_size(S, .., latent=True)`` blocks."""
     return _walk((q_rope, q_latent), ck_all, cv_all, layer, lo, hi, scale, True,
                  "latent_decode_attention")
 

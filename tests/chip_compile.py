@@ -160,6 +160,24 @@ def _served_programs(cfg, slots, stripe, one_chip, relaid=True):
     }
 
 
+def _decode_kernel_blocks(fn, *args):
+    """(kernel's name, positions a block of its walk) of every call of
+    ``ops/decode_attention.py``'s kernel in ``fn`` traced with ``args``, loop
+    bodies and all: the block is the kernel's double buffer of keys
+    ``[2, K, block, D]``, which a compiled program's text no longer shows."""
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call" and "decode_attention" in (eqn.params["name"] or ""):
+                yield eqn.params["name"], eqn.params["grid_mapping"].scratch_avals[0].shape[2]
+            for value in eqn.params.values():
+                for inner in value if isinstance(value, (list, tuple)) else [value]:
+                    inner = getattr(inner, "jaxpr", inner)  # a closed one's own
+                    if hasattr(inner, "eqns"):
+                        yield from calls(inner)
+
+    return sorted(calls(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
 def _program_text(program):
     fn, args = program
     return jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()

@@ -6,7 +6,13 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from tests.chip_compile import _served_config, native_kernels, no_compile_cache, one_chip
+from tests.chip_compile import (
+    _decode_kernel_blocks,
+    _served_config,
+    native_kernels,
+    no_compile_cache,
+    one_chip,
+)
 
 
 def _compiled_text(fn, one_chip, *shapes):
@@ -136,10 +142,10 @@ def _latent_decode_attention_shapes(layers=5, slots=24, stripe=24576, heads=32):
     )
 
 
-def _decode_attention_shapes(layers, stripe, heads, slots=32):
+def _decode_attention_shapes(layers, stripe, heads, slots=32, kv_heads=8):
     """One new token a slot over the serving cells' caches: ``heads`` query
-    heads over 8 key-value heads of width 128."""
-    cache = ((layers, slots, 8, stripe, 128), jnp.bfloat16)
+    heads over ``kv_heads`` key-value heads of width 128."""
+    cache = ((layers, slots, kv_heads, stripe, 128), jnp.bfloat16)
     bounds = ((slots,), jnp.int32)
     return (((slots, heads, 128), jnp.bfloat16), cache, cache, ((), jnp.int32), bounds, bounds)
 
@@ -168,6 +174,13 @@ KERNELS = {
     "decode_attention_4_a_group": (_decode_attention, _decode_attention_shapes(16, 1024, 32)),
     "decode_attention_6_a_group": (_decode_attention, _decode_attention_shapes(5, 4096, 48)),
     "decode_attention_8_a_group": (_decode_attention, _decode_attention_shapes(5, 4096, 64)),
+    # two key-value heads, whose block is 512 positions: ZAYA1-8B's 4 query
+    # heads a key-value head over 64 slots of 4,608, Nemotron-3-Super's 16
+    # over 64 slots of 2,048
+    "decode_attention_4_a_group_of_2_heads": (
+        _decode_attention, _decode_attention_shapes(20, 4608, 8, slots=64, kv_heads=2)),
+    "decode_attention_16_a_group_of_2_heads": (
+        _decode_attention, _decode_attention_shapes(1, 2048, 32, slots=64, kv_heads=2)),
     "latent_decode_attention_32_on_one_key": (
         _latent_decode_attention, _latent_decode_attention_shapes()),
     "ssm_step_in_place": (_ssm_step_in_place, _ssm_step_shapes()),
@@ -186,6 +199,18 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache, native_kernel
     fn, shapes = KERNELS[name]
     text = _compiled_text(fn, one_chip, *shapes)
     assert "tpu_custom_call" in text, f"{name}: no Pallas kernel in the program"
+
+
+@pytest.mark.parametrize("name, block", [
+    ("decode_attention_4_a_group", 128), ("decode_attention_6_a_group", 128),
+    ("decode_attention_8_a_group", 128), ("decode_attention_4_a_group_of_2_heads", 512),
+    ("decode_attention_16_a_group_of_2_heads", 512), ("latent_decode_attention_32_on_one_key", 512)])
+def test_decode_kernel_walks_the_block_its_cache_gives(name, block):
+    """Traced at the cells' shapes: 512 KB of keys and values a block at two
+    heads as at eight, the latent by its stripe."""
+    fn, shapes = KERNELS[name]
+    [(_, traced)] = _decode_kernel_blocks(fn, *(jax.ShapeDtypeStruct(*shape) for shape in shapes))
+    assert traced == block
 
 
 def test_chunk_mid_writes_its_cache_rows_without_a_scatter(one_chip, no_compile_cache):

@@ -1,8 +1,10 @@
 """The programs of the compressed-convolutional-attention cut (ZAYA1-8B, 20
 layers, 8 of 16 experts held, 64 slots of 4,608) compile at real widths for a
 described v5e (``tests/chip_compile.py`` says how, and what that proves): the
-decode step over every slot, the engine's decode program with its sampler, and
-the middle chunk at 1,024 tokens by one to four rows."""
+decode step over every slot, the engine's decode program with its sampler, a
+final chunk that carries the pool's step, and the middle chunk at 1,024 tokens
+by one to four rows. The decode kernel walks the stripes of two key-value
+heads in blocks of 512 positions (``ops/decode_attention.py block_size``)."""
 
 import jax
 import jax.numpy as jnp
@@ -10,6 +12,8 @@ import pytest
 
 from tests.chip_compile import (
     _convolved_attention_cut,
+    _decode_kernel_blocks,
+    _ops_outside_fusions,
     _served_programs,
     native_kernels,
     no_compile_cache,
@@ -34,6 +38,9 @@ def test_decode_step_reads_stripes_and_banks_through_their_kernels_and_the_tails
     or a bank's size is copied, and the tail leaf is updated where it lies."""
     cfg = _convolved_attention_cut()
     fn, args = _served_programs(cfg, SLOTS, STRIPE, one_chip)["decode_step"]
+    # two heads of 2 x 128 bfloat16 numbers a position: 512 positions are the
+    # 512 KB a block that eight heads hold in 128
+    assert _decode_kernel_blocks(fn, *args) == [("decode_attention", 512)]
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
     memory = compiled.memory_analysis()
     assert 11.3e9 < memory.argument_size_in_bytes < 11.5e9
@@ -91,6 +98,45 @@ def test_decode_program_sorts_block_maxima_and_winning_blocks_never_the_vocabula
     assert any("f32[8,8,2049]" in line and "f32[64,262272]" in line and "lm_head" in line
                and " fusion(" in line for line in lines)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+def test_a_final_chunk_that_carries_the_step_walks_512_position_blocks_in_place(
+        one_chip, no_compile_cache, native_kernels):
+    """The engine's ``chunk_final`` of 128 tokens with the pool's decode rows:
+    the rows' read of their stripes is the decode kernel under ``beside``, at
+    the block the decode program walks (two 1 MB double buffers in VMEM, which
+    the chip's compiler takes), the chunk's own attention stays the einsum over
+    its row's stripe, and the pool, donated, is written where it lies."""
+    import re
+
+    from ray_tpu.llm.engine import programs
+    from ray_tpu.models.llama import init_kv_cache
+    from ray_tpu.models.patterned import moe_stats_names
+
+    cfg = _convolved_attention_cut()
+    params, cache, tokens = _served_programs(cfg, SLOTS, STRIPE, one_chip)["decode_step"][1]
+
+    def sds(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32 = lambda *shape: sds(jnp.int32, *shape)  # noqa: E731
+    one = {k: sds(x.dtype, *x.shape)
+           for k, x in jax.eval_shape(lambda: init_kv_cache(cfg, 1, STRIPE)).items()}
+    one["moe_stats"] = i32(len(moe_stats_names(cfg)))
+    rows = dict(tokens=tokens, temps=sds(jnp.float32, SLOTS), top_ks=i32(SLOTS),
+                keys=sds(jnp.uint32, SLOTS, 2), live=sds(jnp.bool_, SLOTS))
+    args = (params, cache, one, i32(1, 128), i32(1), i32(1), i32(), sds(jnp.float32), i32(),
+            sds(jnp.uint32, 2), rows)
+    fn = programs(cfg)["chunk_final"]
+    assert _decode_kernel_blocks(fn, *args) == [("decode_attention", 512)]
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(_kernels(text.splitlines(), "beside/attn_core/global/decode_attention")) == 1
+    leaves = {",".join(map(str, x.shape)) for name, x in cache.items() if name != "length"}
+    copies = [line.strip()[:160] for _, result, op, line in _ops_outside_fusions(text)
+              if op == "copy" and (m := re.match(r"\w+\[([\d,]+)\]", result)) and m.group(1) in leaves]
+    assert copies == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
 @pytest.mark.parametrize("rows", [1, 2, 4])

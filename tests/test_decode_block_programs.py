@@ -1,0 +1,105 @@
+"""The decode kernel's block follows from what a position of the cache holds
+(``ops/decode_attention.py block_size``), so a change of the rule moves the
+programs of the cells whose rows it sizes anew and no other's. Here the
+engine's decode program and its carrying final chunk (``llm/engine.py
+programs``) at each serving cell's published shape, lowered on the CPU (the
+kernel interpreted: its buffers and loops are in the text) and read without
+locations: the cells at eight key-value heads and the latent ones lower to
+the parent's text, digest for digest (Granite's 64-wide heads never reach the
+kernel on the chip: ``tests/test_granite.py``; its 40 layers lower in a minute,
+so it is not here); the two at two key-value heads (ZAYA1-8B, Nemotron-3-Super) walk
+512-position blocks where the parent walked 128. Compilation of the moved
+programs for a described v5e: ``tests/test_chip_compile_zaya.py``."""
+
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.llm.engine import programs
+from ray_tpu.models.llama import init_kv_cache, init_params
+from tests.chip_compile import (
+    _convolved_attention_cut,
+    _delta_rule_cut,
+    _served_config,
+    _sparse_latent_cut,
+    _state_space_cut,
+)
+
+CHUNK = 128  # a final chunk's width: a prefill bucket of every cell
+# cell -> (its served cut, slots, stripe)
+CELLS = {
+    "mistral7b-serve-saturated": (lambda: _served_config("mistral-7b-serve-l16"), 32, 1024),
+    "laguna-xs2-serve-mixed": (lambda: _served_config("laguna-xs.2-serve-l5"), 32, 4096),
+    "solar-open2-serve-long-chat": (_delta_rule_cut, 64, 8192),
+    "kanana2-serve-docs-shared": (lambda: _served_config("kanana-2-30b-a3b-serve-l5"), 24, 24576),
+    "dots3-note-serve-docs-shared": (_sparse_latent_cut, 16, 24576),
+    "nemotron3-super-serve-chat": (_state_space_cut, 64, 2048),
+    "zaya1-8b-serve-long-chat": (_convolved_attention_cut, 64, 4608),
+}
+# the first 16 hex digits of the SHA-256 of each program's StableHLO without
+# locations (``lower(..).as_text()``) as the parent commit (PR 53) lowered it,
+# taken from a checkout of the parent with this file's ``_lowered``
+# (a latent pool's chunk programs never carry: ``llm/engine.py _takes_rows``;
+# the state-space and delta-rule cuts' chunks take a quarter of a minute to lower)
+_PARENT = {
+    ("mistral7b-serve-saturated", "decode_fn"): "feebb7d987dd8027",
+    ("laguna-xs2-serve-mixed", "decode_fn"): "af625bfacf07515b",
+    ("solar-open2-serve-long-chat", "decode_fn"): "93af8f49f281ce5a",
+    ("kanana2-serve-docs-shared", "decode_fn"): "f2abae5cfc9a02ef",
+    ("dots3-note-serve-docs-shared", "decode_fn"): "b255e7098b6ba86b",
+    ("nemotron3-super-serve-chat", "decode_fn"): "9aa337ad53fa4c5b",
+    ("zaya1-8b-serve-long-chat", "decode_fn"): "b8956e479f648cae",
+    ("mistral7b-serve-saturated", "chunk_final"): "52561f2c35675b20",
+    ("laguna-xs2-serve-mixed", "chunk_final"): "6d8d9cc0af48cef2",
+    ("zaya1-8b-serve-long-chat", "chunk_final"): "3a15e4d74eb12c90",
+}
+MOVED = ("nemotron3-super-serve-chat", "zaya1-8b-serve-long-chat")
+
+
+def _lowered(cell, program):
+    """The engine's ``program`` at ``cell``'s shape: every slot's decode step
+    with its sampler, or a 128-token final chunk of one prompt that carries
+    the pool's step."""
+    make, slots, stripe = CELLS[cell]
+    cfg = make()
+    fns, sds = programs(cfg), jax.ShapeDtypeStruct
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: init_kv_cache(cfg, slots, stripe))
+    sampler = (sds((slots,), jnp.float32), i32(slots), sds((slots, 2), jnp.uint32))
+    if program == "decode_fn":
+        args = (params, cache, i32(slots), *sampler)
+    else:
+        one = jax.eval_shape(lambda: fns["new_stripe"](stripe))
+        riders = dict(zip(("tokens", "temps", "top_ks", "keys", "live"),
+                          (i32(slots), *sampler, sds((slots,), jnp.bool_))))
+        args = (params, cache, one, i32(1, CHUNK), i32(1), i32(1), i32(), sds((), jnp.float32),
+                i32(), sds((2,), jnp.uint32), riders)
+    return jax.jit(fns[program]).lower(*args).as_text()
+
+
+def _digest(text):
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("cell, program", [key for key in _PARENT if key[0] not in MOVED])
+def test_a_cell_whose_rows_the_rule_sizes_as_before_lowers_to_the_parents_text(cell, program):
+    assert _digest(_lowered(cell, program)) == _PARENT[cell, program]
+
+
+@pytest.mark.parametrize("cell, program", [key for key in _PARENT if key[0] in MOVED])
+def test_two_key_value_heads_walk_512_position_blocks(cell, program):
+    """The kernel's double buffer of keys (two buffers of 2 heads of a block
+    of 128-wide rows) is in the interpreted text: 512 positions where the
+    parent's held 128, and nothing else of the program moved with it (the
+    text is as long but for the digits)."""
+    text = _lowered(cell, program)
+    assert _digest(text) != _PARENT[cell, program]
+    assert "tensor<2x2x512x128xbf16>" in text and "tensor<2x2x128x128xbf16>" not in text
+
+
+if __name__ == "__main__":  # ``python3 -m tests.test_decode_block_programs``: this checkout's digests
+    for key in _PARENT:
+        print(f'    {key}: "{_digest(_lowered(*key))}",', flush=True)

@@ -22,7 +22,7 @@ from ray_tpu.models.llama import (
 )
 from ray_tpu.models.patterned import _param_shapes
 from ray_tpu.ops.decode_attention import (
-    LATENT_BLOCKS,
+    BLOCKS,
     block_size,
     latent_decode_attention,
     positions_read,
@@ -121,7 +121,7 @@ def test_latent_kernel_equals_the_einsum_over_rows_of_unequal_length(monkeypatch
     four of them."""
     import ray_tpu.ops.decode_attention as da
 
-    monkeypatch.setattr(da, "LATENT_BLOCKS", (128,))
+    monkeypatch.setattr(da, "BLOCKS", (128,))
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
     B = len(BOUNDS)
     q_rope = jax.random.normal(ks[0], (B, H, DR), dtype)
@@ -140,11 +140,16 @@ def test_latent_kernel_equals_the_einsum_over_rows_of_unequal_length(monkeypatch
 
 
 def test_latent_blocks_are_the_longest_that_divide_the_stripe():
-    assert LATENT_BLOCKS == (512, 256, 128)
-    assert [block_size(s, latent=True) for s in (24576, 2560, 768, 128, 96)] == [512, 512, 256, 128, None]
-    assert block_size(24576) == 128
+    """Whatever a position holds (Kanana-2's 128-lane key and 512-wide latent,
+    dots3's 1,024-wide sliding latent), where eight plain heads of as many
+    bytes or more take 128."""
+    assert BLOCKS == (512, 256, 128)
+    for held in ((128 + 512) * 2, (128 + 1024) * 2):
+        assert [block_size(s, held, latent=True)
+                for s in (24576, 2560, 768, 128, 96)] == [512, 512, 256, 128, None]
+    assert block_size(24576, 8 * 256 * 2) == 128
     # a 700-token slot of a 24,576-position stripe: two 512-position blocks
-    assert positions_read(0, np.asarray([700, 1]), 24576, latent=True).tolist() == [1024, 512]
+    assert positions_read(0, np.asarray([700, 1]), 24576, 1280, latent=True).tolist() == [1024, 512]
 
 
 # ------------------------------------------------------------------ the engine

@@ -31,14 +31,16 @@ def test_a_dense_engine_counts_no_routing_and_no_window():
 
 
 def test_positions_read_counts_the_blocks_each_slots_bounds_cover():
-    """Two requests at once in 256-position stripes, the longer crossing the
-    first block's end while it decodes: ``decode_kv_positions_read`` (and the
-    window layers' ``_window``) are ``ops/decode_attention.py positions_read``
-    summed over the lengths the loop held at each launch, no less than the
-    tokens needed and no more than the active slots' whole stripes."""
+    """Two requests at once in stripes of three 128-position blocks (laguna-tiny's
+    rows hold few bytes a position, so its block is the longest that divides
+    the stripe: 128 of 384), the longer crossing the first block's end while it
+    decodes: ``decode_kv_positions_read`` (and the window layers' ``_window``)
+    are ``ops/decode_attention.py positions_read`` summed over the lengths the
+    loop held at each launch, no less than the tokens needed and no more than
+    the active slots' whole stripes."""
     from ray_tpu.ops.decode_attention import BLOCK, positions_read
 
-    stripe = 2 * BLOCK
+    stripe = 3 * BLOCK
     eng = JaxEngine(LLMConfig(
         model=ModelConfig(model_id="laguna-tiny", seed=3),
         engine=EngineConfig(max_num_seqs=4, max_seq_len=stripe, dtype="float32",
@@ -49,12 +51,14 @@ def test_positions_read_counts_the_blocks_each_slots_bounds_cover():
         window = eng.model_cfg.sliding_window
         want = {"full": 0, "window": 0, "whole": 0}
         launch, carried = eng._decode, eng._carried
+        held = eng._pools[0].position_bytes  # a position of a row, a layer
+        assert eng._pools[0].decode_block == BLOCK == eng.get_stats()["pools"][0]["decode_block"]
 
         def count(requests):  # called by the loop right before it counts
             for r in requests:
                 n = len(r.prompt_token_ids) + len(r.out_tokens)
-                want["full"] += eng._decode_n_steps * positions_read(0, n, stripe)
-                want["window"] += eng._decode_n_steps * positions_read(n - window, n, stripe)
+                want["full"] += eng._decode_n_steps * positions_read(0, n, stripe, held)
+                want["window"] += eng._decode_n_steps * positions_read(n - window, n, stripe, held)
                 want["whole"] += eng._decode_n_steps * stripe
 
         def counting(pool, *args):
@@ -86,23 +90,33 @@ def test_positions_read_counts_the_blocks_each_slots_bounds_cover():
         eng.shutdown()
 
 
-@pytest.mark.parametrize("placed", ["no-mesh", "mesh-of-one-device", "tp2"])
-def test_an_engine_counts_the_form_its_decode_steps_take(monkeypatch, placed):
+# (key-value heads, a head's width) of ``tiny``'s own rows and of rows as wide as
+# the eight-head cells': 256 and 4,096 float32 bytes a position
+HEADS = {"two-heads": {}, "eight-heads": {"n_heads": 8, "n_kv_heads": 8, "head_width": 64}}
+
+
+@pytest.mark.parametrize("placed, heads, block", [
+    ("no-mesh", "two-heads", 512), ("no-mesh", "eight-heads", 128),
+    ("mesh-of-one-device", "two-heads", 512), ("tp2", "two-heads", None)])
+def test_an_engine_counts_the_form_its_decode_steps_take(monkeypatch, placed, heads, block):
     """The engine's counter and the body's choice are one answer
     (``patterned.reads_blocks``, asked once a pool): where the traced decode
-    program calls the kernel, ``decode_kv_positions_read`` counts blocks;
-    where it keeps the einsum (parameters over a mesh), whole stripes. A mesh
-    of one device is one device on both sides."""
-    from ray_tpu.ops.decode_attention import BLOCK
+    program calls the kernel, ``decode_kv_positions_read`` counts the blocks
+    the pool's own ``decode_block`` covers (``ops/decode_attention.py
+    block_size`` of the pool's cache: two narrow heads walk a 1,024-position
+    stripe in blocks of 512, eight wide ones in blocks of 128), and
+    ``get_stats()["pools"]`` says the block; where it keeps the einsum
+    (parameters over a mesh), whole stripes and no block. A mesh of one device
+    is one device on both sides."""
     from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 
     mesh = {"no-mesh": None,
             "mesh-of-one-device": build_mesh(MeshSpec(), devices=jax.devices()[:1]),
             "tp2": build_mesh(MeshSpec(dp=2, tp=2), devices=jax.devices()[:4])}[placed]
     traced = _count_kernel_calls(monkeypatch)
-    stripe = 2 * BLOCK
+    stripe = 1024
     eng = JaxEngine(LLMConfig(
-        model=ModelConfig(model_id="tiny", tokenizer="byte", seed=3),
+        model=ModelConfig(model_id="tiny", tokenizer="byte", seed=3, model_kwargs=HEADS[heads]),
         engine=EngineConfig(max_num_seqs=2, max_seq_len=stripe, dtype="float32",
                             prefill_buckets=(16, 32), enable_prefix_caching=False,
                             tensor_parallel_degree=2 if placed == "tp2" else 1),
@@ -117,7 +131,8 @@ def test_an_engine_counts_the_form_its_decode_steps_take(monkeypatch, placed):
         # where the engine restored its executables and traced none
         eng._decode_jit.lower(eng.params, pool.cache, pool.dev_tokens, *pool.sampler(), pool.keys)
         assert pool.reads_blocks == bool(traced) == (placed != "tp2")
-        per_slot_step = BLOCK if pool.reads_blocks else stripe  # 28 positions at most: one block
+        assert pool.decode_block == block == eng.get_stats()["pools"][0]["decode_block"]
+        per_slot_step = block or stripe  # 28 positions at most: one block
         assert c["decode_kv_positions_read"] == c["decode_slot_steps"] * per_slot_step > 0
         assert c["decode_kv_positions_read_window"] == 0  # no window layers in this model
     finally:

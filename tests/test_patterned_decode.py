@@ -28,7 +28,8 @@ def test_decode_steps_through_the_kernel_give_the_einsums_tokens(monkeypatch, mo
     with the kernel's selection switched off: the einsum over the whole
     stripe that every decode step ran before. Row 0 crosses a block's end on
     its way, row 1 stays inside the first block, and the patterned model's
-    window starts mid-block."""
+    window starts mid-block (three blocks a stripe: the tiny models' rows hold
+    few bytes a position and take the longest block that divides it, 128)."""
     from ray_tpu.ops.decode_attention import BLOCK
 
     cfg, params, lora_kw, _, _ = _model(model)
@@ -41,7 +42,7 @@ def test_decode_steps_through_the_kernel_give_the_einsums_tokens(monkeypatch, mo
         if not read_blocks:
             monkeypatch.setattr(patterned, "reads_blocks", lambda *a: False)
         step = jax.jit(lambda cache, toks: decode_step(params, cache, toks, cfg, **lora_kw()))
-        logits, cache = prefill(params, init_kv_cache(cfg, 2, 2 * BLOCK), prompt, cfg,
+        logits, cache = prefill(params, init_kv_cache(cfg, 2, 3 * BLOCK), prompt, cfg,
                                 lengths=lengths, **lora_kw())
         tokens, rows = [], [logits]
         for _ in range(16):

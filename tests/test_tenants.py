@@ -242,11 +242,12 @@ def test_priority_preemption_drain_migrates_low_priority_gang(preempt_cluster):
     kills = list(a1.killed) + list(a2.killed)
     assert len(kills) == 1  # smallest victim set: exactly one gang member
     victim_agent = a1 if a1.killed else a2
-    victim = next(
-        a
-        for a in low
-        if ctrl.actors[a._actor_id].state in ("RESTARTING", "PENDING")
-    )
+
+    def queued():  # (the agent records the kill before the controller requeues the actor)
+        return [a for a in low if ctrl.actors[a._actor_id].state in ("RESTARTING", "PENDING")]
+
+    _wait(queued, msg="the victim queued")
+    victim = queued()[0]
     survivor = next(a for a in low if a is not victim)
 
     # the freed slot must serve the HIGH-priority creation first (priority
