@@ -28,6 +28,11 @@ class SamplingParams:
     stop_token_ids: Optional[list[int]] = None
     ignore_eos: bool = False
     seed: Optional[int] = None
+    # a model that generates by blocks (``LlamaConfig.block_length``): the
+    # denoising forwards a block is unmasked over (1 .. the block's length);
+    # None: the model's own (``LlamaConfig.denoise_steps``). Any other model
+    # takes no notice of it
+    denoise_steps: Optional[int] = None
 
 
 @dataclasses.dataclass
@@ -101,7 +106,8 @@ class ModelConfig:
     # "laguna-tiny" | "kanana-2-30b-a3b" | "kanana-tiny" |
     # "nemotron-3-super-120b-a12b" | "nemotron-tiny" | "solar-open2-250b" |
     # "solar-tiny" | "granite-4.0-h-micro" | "granite-tiny" | "zaya1-8b" |
-    # "zaya-tiny" | "dots3-note-prev" | "dots3-tiny"
+    # "zaya-tiny" | "dots3-note-prev" | "dots3-tiny" | "sdar-30b-a3b-chat" |
+    # "sdar-tiny"
     model_id: str = "tiny"
     tokenizer: str = "byte"  # "byte" | transformers tokenizer path
     checkpoint_path: Optional[str] = None  # ray_tpu.train pytree checkpoint
@@ -148,6 +154,8 @@ def resolve_llama_config(model: "ModelConfig", engine: "EngineConfig", min_vocab
         "zaya-tiny": LlamaConfig.zaya_tiny,
         "dots3-note-prev": LlamaConfig.dots3_note_prev,
         "dots3-tiny": LlamaConfig.dots3_tiny,
+        "sdar-30b-a3b-chat": LlamaConfig.sdar_30b_a3b,
+        "sdar-tiny": LlamaConfig.sdar_tiny,
     }
     kw = dict(
         max_seq_len=engine.max_seq_len,
@@ -212,6 +220,22 @@ def refuse_stateful(cfg, module: str) -> None:
             f"{module}: a model with {names} layers is served on one device by "
             "llm/engine.py JaxEngine with tensor_parallel_degree=1; this path has no "
             "rule for a slot's recurrent state"
+        )
+
+
+def refuse_blocks(cfg, module: str) -> None:
+    """The same for a model that generates by diffusion over blocks
+    (``LlamaConfig.block_length``): a slot's block (its tokens, which of them
+    are masked, the step within it) lives on the device beside the cache and
+    a step hands out a block and whether it committed, which those copies of
+    the loop, a mesh and a hand-over of keys and values alone
+    (``llm/disagg.py``) do not carry."""
+    if cfg.block_length:
+        raise NotImplementedError(
+            f"{module}: a model that generates by blocks (block_length="
+            f"{cfg.block_length}) is served on one device by llm/engine.py JaxEngine with "
+            "tensor_parallel_degree=1; this path has no block step and does not carry a "
+            "slot's block state"
         )
 
 

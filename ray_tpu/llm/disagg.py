@@ -20,7 +20,8 @@ from typing import Any, Optional
 import numpy as np
 
 from ray_tpu.llm.config import (
-    LLMConfig, SamplingParams, refuse_further_stripes, refuse_stateful, resolve_llama_config,
+    LLMConfig, SamplingParams, refuse_blocks, refuse_further_stripes, refuse_stateful,
+    resolve_llama_config,
 )
 
 
@@ -37,6 +38,7 @@ class PrefillWorker:
         model_cfg = resolve_llama_config(llm_config.model, llm_config.engine)
         refuse_stateful(model_cfg, "llm/disagg.py")
         refuse_further_stripes(model_cfg, "llm/disagg.py")
+        refuse_blocks(model_cfg, "llm/disagg.py")
         # reuse the engine's model construction, not its slot loop
         self._engine_shell = JaxEngine.__new__(JaxEngine)
         self._engine_shell.config = llm_config
@@ -92,6 +94,7 @@ class DecodeWorker:
         model_cfg = resolve_llama_config(llm_config.model, llm_config.engine)
         refuse_stateful(model_cfg, "llm/disagg.py")
         refuse_further_stripes(model_cfg, "llm/disagg.py")
+        refuse_blocks(model_cfg, "llm/disagg.py")
         shell = JaxEngine.__new__(JaxEngine)
         shell.config = llm_config
         shell.tokenizer = get_tokenizer(llm_config.model.tokenizer)

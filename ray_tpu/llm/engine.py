@@ -20,6 +20,14 @@ the TPU engine keeps everything static for XLA:
   block, then the 64 best among the 64 winning blocks' 8,192 logits), so no
   program sorts a whole vocabulary.
 
+- a model that generates by diffusion over blocks
+  (``LlamaConfig.block_length``) runs a forward of a block a slot where the
+  others run a decode step (``programs``' ``block_step``): the block's
+  tokens, which of them are masked and the denoising step live on the device
+  between launches (``_Pool.block``), so steps chain without the host as a
+  token a step does; a fetch brings a slot's committed block, up to
+  ``block_length`` tokens at once, or nothing (``_take_blocks``).
+
 TP/SP: params and cache shard over a mesh via the model's logical rules
 (``parallel/mesh.py``) when ``tensor_parallel_degree > 1``.
 """
@@ -78,6 +86,18 @@ COUNTERS = (
     "first_tokens",  # of tokens_generated, those a final prefill chunk sampled
     # decoded for a slot that had finished or was re-bound (run-ahead)
     "tokens_discarded",
+    # a model that generates by blocks (``LlamaConfig.block_length``): a step
+    # is one forward of every slot's block, and these count what the fetch of
+    # a step found for the slots still bound to the request that launched it
+    # (a forward a run-ahead launched for a request that had ended counts
+    # nowhere): forwards by kind (one that unmasked, one that committed the
+    # clean block's keys and values), the positions the denoise forwards
+    # unmasked, the blocks committed, their tokens that reached a request
+    # (less than a block's where it ended inside one; the rest are
+    # ``tokens_discarded``), and the prompt tokens that rode in a first block
+    # because they filled no whole block of their own
+    "block_forwards:denoise", "block_forwards:commit", "block_tokens_unmasked",
+    "blocks_committed", "block_tokens_emitted", "block_prompt_tail_tokens",
     "decode_steps", "decode_slot_steps",  # slot_steps: sum of active slots
     # of decode_steps, those a prompt chunk's launch carried (no ``decode_fn``
     # ran for them: the rows rode through ``chunk_mid`` or ``chunk_final``)
@@ -147,7 +167,7 @@ COUNTERS = (
 )
 _LABEL = {"requests_finished": "reason", "requests_failed": "stage",
           "prefill_chunks": "kind", "prefill_programs": "kind",
-          "decode_steps_dead_in_chunk": "cause",
+          "decode_steps_dead_in_chunk": "cause", "block_forwards": "kind",
           **dict.fromkeys((*_MOE_COUNTERS, "prefill_query_tokens",
                            "prefill_attended_positions"), "program")}
 # request latencies: 1 ms to 200 s, a quarter more each bucket, so a median
@@ -189,7 +209,7 @@ def _order_of(leaf) -> Optional[tuple]:
 _DONATED = {
     "_decode_jit": (1,), "_decode_multi_jit": (1,), "_chunk_mid_jit": (1, 5),
     "_chunk_final_jit": (1, 2), "_new_stripe_jit": (), "_seed_prefix_jit": (0,),
-    "_store_snapshot_jit": (),
+    "_store_snapshot_jit": (), "_block_step_jit": (1, 5), "_seed_block_jit": (0,),
 }
 
 # What a start spends deriving its programs, by phase: JAX's own duration
@@ -282,6 +302,8 @@ class _Request:
         self.prefix_hit_tokens = 0
         self.prefix_key = None  # of the snapshot it was seeded from
         self.pacer = TokenPacer()  # smooths multi-step token bursts for SSE
+        # prompt tokens at the front of its first block (block generation)
+        self.block_tail = 0
 
 
 def _between(start: Optional[float], end: Optional[float]) -> Optional[float]:
@@ -360,6 +382,9 @@ class _Admission:
         self.chunks = chunks  # [(tokens_np [1, C], eff_len, start, is_final)]
         self.idx = 0
         self.prefix_m = prefix_m
+        # of a model that generates by blocks: the prompt's last tokens, fewer
+        # than a block, which stand clean at the front of the first block
+        self.tail: list = []
 
 
 class _Pool:
@@ -377,6 +402,16 @@ class _Pool:
         self.stripe_len = stripe_len
         self.n_slots = n_slots
         self.latent = bool(model_cfg.kv_latent_rank)
+        # > 0: the pool's step is a forward of a block a slot (``programs``'
+        # ``block_step``), and ``block`` is what rides on the device between
+        # launches as ``dev_tokens`` does for a token a step: each slot's block
+        # (``tokens``, ``masked`` [slots, B]), the denoising step within it
+        # (``step``) and the request's ``steps`` [slots]
+        self.block_length = model_cfg.block_length
+        self.block = None
+        if self.block_length and stripe_len % self.block_length:
+            raise ValueError(f"a stripe of {stripe_len} positions is no whole number of "
+                             f"blocks of {self.block_length}")
         device = jax.local_devices()[0]
         before = (device.memory_stats() or {}).get("bytes_in_use")
         self.cache = jax.block_until_ready(init_kv_cache(model_cfg, n_slots, stripe_len))
@@ -466,7 +501,9 @@ def programs(cfg, decode_steps: int = 1) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models.llama import decode_step, init_kv_cache, prefill
+    from ray_tpu.models.llama import (
+        block_schedule, block_step as model_block_step, decode_step, init_kv_cache, prefill,
+    )
     from ray_tpu.models.patterned import moe_stats_names, state_cache_shapes, stripe_cache_shapes
     from ray_tpu.ops import topk
 
@@ -551,6 +588,68 @@ def programs(cfg, decode_steps: int = 1) -> dict:
             live = rows["live"]
             return (jnp.where(live, next_tokens, rows["tokens"]),
                     jnp.where(live[:, None], new_keys, rows["keys"]))
+
+    # a model that generates by diffusion over blocks (``cfg.block_length``)
+    blocks = cfg.block_length
+
+    def block_step(params, cache, block, temps, top_ks, keys):
+        """One forward of every slot's block (``models/llama.py block_step``)
+        and what the next forward starts from, with no word from the host: a
+        slot whose block is clean commits it and goes on to a block of masks;
+        any other unmasks what its step of the schedule gives it (``block``:
+        ``_Pool.block``). Every position's token goes through the one
+        ``draw``, under a key of its own split off the slot's. Hands out, a
+        slot, the block after the forward, whether it committed and how many
+        positions it unmasked ([slots, B + 2] int32: what the host fetches),
+        then the cache, the next state, the keys and the routing counts."""
+        masked = block["masked"]
+        commit = ~masked.any(axis=1)
+        split = jax.vmap(lambda key: jax.random.split(key, blocks + 1))(keys)
+
+        def sample(logits):  # [slots * B, V]: a slot's positions under its temperature
+            return jax.vmap(draw)(
+                *candidates(logits), jnp.repeat(temps, blocks), jnp.repeat(top_ks, blocks),
+                split[:, 1:].reshape(-1, *split.shape[2:]))[0]
+
+        tokens, still, _, cache = model_block_step(
+            params, stats_in(cache), block["tokens"], masked,
+            block_schedule(block["step"], block["steps"], blocks).astype(jnp.int32),
+            commit, cfg, sample=sample,
+        )
+        stats = cache.pop("moe_stats", None)
+        with jax.named_scope("sampling"), jax.named_scope("unmask"):
+            out = jnp.concatenate([
+                tokens, commit[:, None].astype(jnp.int32),
+                (masked & ~still).sum(axis=1, dtype=jnp.int32)[:, None],
+            ], axis=1)
+            fresh = commit[:, None]
+            block = dict(
+                block,
+                tokens=jnp.where(fresh, jnp.int32(cfg.mask_token_id), tokens),
+                masked=still | fresh,
+                step=jnp.where(commit, 0, block["step"] + 1),
+            )
+        return out, cache, block, split[:, 0], stats
+
+    def seed_block(cache, block, slot, length, tokens, masked, steps):
+        """Bind ``slot`` to a request that generates by blocks: its cache
+        holds ``length`` positions (the prompt's whole blocks, as the chunk
+        programs left them), and its first block starts as ``tokens`` [B] (the
+        prompt's last tokens in front, masks behind them: ``masked``) at step
+        0 of the request's ``steps``."""
+        cache = {**cache, "length": cache["length"].at[slot].set(length)}
+        new = dict(tokens=tokens, masked=masked, step=jnp.zeros((), jnp.int32), steps=steps)
+        return cache, {name: block[name].at[slot].set(new[name]) for name in block}
+
+    def new_block(n_slots: int) -> dict:
+        """``_Pool.block`` of a pool with no request: every slot a block of
+        masks at step 0 of the model's own steps."""
+        return dict(
+            tokens=jnp.full((n_slots, blocks), cfg.mask_token_id, jnp.int32),
+            masked=jnp.ones((n_slots, blocks), bool),
+            step=jnp.zeros((n_slots,), jnp.int32),
+            steps=jnp.full((n_slots,), cfg.denoise_steps or blocks, jnp.int32),
+        )
 
     n_steps = max(1, decode_steps)
 
@@ -647,7 +746,7 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         mid_stats = one.get("moe_stats")  # the prompt's middle chunks'
         last_logits, one, *rode = prefill(
             params, one, tokens, cfg, lengths=length, start_pos=start,
-            loras=loras, adapter_ids=adapter_ids,
+            loras=loras, adapter_ids=adapter_ids, with_logits=not blocks,
             beside=None if rows is None else (cache, rows["tokens"], rows["live"]),
         )
         if rode:
@@ -661,6 +760,10 @@ def programs(cfg, decode_steps: int = 1) -> dict:
                 lambda x, row: jax.lax.dynamic_update_index_in_dim(x, row, slot, 1))
             cache = {**{k: put(cache[k], one[k][:, 0]) for k in slot_leaves},
                      "length": cache["length"].at[slot].set(total)}
+        if blocks:
+            # nothing is sampled from a prompt: its whole blocks are kept, and
+            # the slot's first block is seeded by ``seed_block``
+            return jnp.zeros((), jnp.int32), key, cache, one, stats
         with jax.named_scope("sampling"):
             tok, new_key = sample_row(last_logits[0], temp, top_k, key)
         if rows is None:
@@ -704,7 +807,9 @@ def programs(cfg, decode_steps: int = 1) -> dict:
 
     return dict(decode_fn=decode_fn, decode_multi=decode_multi, chunk_mid=chunk_mid,
                 chunk_final=chunk_final, new_stripe=new_stripe, seed_prefix=seed_prefix,
-                store_snapshot=store_snapshot)
+                store_snapshot=store_snapshot,
+                **(dict(block_step=block_step, seed_block=seed_block, new_block=new_block)
+                   if blocks else {}))
 
 
 def top_k_static(cfg) -> int:
@@ -772,7 +877,9 @@ class JaxEngine:
                    and not self._spans_devices())
         for pool in self._pools:
             pool.chunk_rows = 1 if pool.latent else rows
-            pool.carries = carries and not pool.latent
+            # (nor a pool whose step is a forward of a block a slot: joining
+            # that step to a chunk launch is a later change, ROADMAP R11)
+            pool.carries = carries and not pool.latent and not pool.block_length
         self._init_phase(self._compile)
         self._init_phase(self._warm_programs)
         self._waiting: "queue.Queue[_Request]" = queue.Queue()
@@ -924,10 +1031,11 @@ class JaxEngine:
         )
         sharded = ec.tensor_parallel_degree > 1 or ec.sequence_parallel_degree > 1
         if sharded or (self._mesh is not None and self._mesh.size > 1):
-            from ray_tpu.llm.config import refuse_latent, refuse_stateful
+            from ray_tpu.llm.config import refuse_blocks, refuse_latent, refuse_stateful
 
             refuse_latent(self.model_cfg, "llm/engine.py over a mesh")
             refuse_stateful(self.model_cfg, "llm/engine.py over a mesh")
+            refuse_blocks(self.model_cfg, "llm/engine.py over a mesh")
         if sharded:
             from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 
@@ -957,6 +1065,18 @@ class JaxEngine:
             self.loras = init_lora_stack(
                 self.model_cfg, ec.max_loras, ec.lora_rank
             )
+        if self.model_cfg.block_length and ec.decode_steps > 1:
+            raise ValueError("decode_steps > 1: a model that generates by blocks runs a forward "
+                             "of a block a launch (llm/engine.py programs block_step)")
+        # under the block mask a query reads its whole block: a chunk or a
+        # stored prefix that ended inside one would have its last queries read
+        # keys nobody wrote yet (a former tenant's), and keep what came of it
+        cut = [n for n in (ec.prefill_chunk, *ec.prefill_buckets)
+               if self.model_cfg.block_length and n % self.model_cfg.block_length]
+        if cut:
+            raise ValueError(
+                f"prefill_chunk and prefill_buckets {cut}: no whole number of blocks of "
+                f"{self.model_cfg.block_length} (a model that generates by blocks)")
 
     def _compile(self):
         import jax
@@ -993,6 +1113,12 @@ class JaxEngine:
         )
         self._seed_prefix_jit = jax.jit(
             fns["seed_prefix"], donate_argnums=_DONATED["_seed_prefix_jit"])
+        if cfg.block_length:  # the step of a model that generates by blocks, and a slot's binding
+            self._block_step_jit = jax.jit(
+                fns["block_step"], donate_argnums=_DONATED["_block_step_jit"])
+            self._seed_block_jit = jax.jit(
+                fns["seed_block"], donate_argnums=_DONATED["_seed_block_jit"])
+            self._new_block = fns["new_block"]
         from ray_tpu.models.patterned import state_cache_shapes
 
         self._state_leaves = tuple(state_cache_shapes(cfg, 1))
@@ -1199,6 +1325,8 @@ class JaxEngine:
                 pool.n_slots,
             ))
             pool.dev_tokens = self._held(jnp.zeros((pool.n_slots,), jnp.int32))
+            if pool.block_length:
+                pool.block = self._held(self._new_block(pool.n_slots))
             self._sync_adapter_ids(pool)
             mid, finals = self._chunk_widths(pool)
             stripe = pool.stripe_len
@@ -1239,6 +1367,12 @@ class JaxEngine:
                     if b < pool.stripe_len:
                         book("seed_prefix", self._seed_prefix(
                             self._new_stripe(stripe), **self._prefix_cut(pool, 0, b)))
+            if pool.block_length:
+                self._bind_block(pool, 0, SamplingParams(), 0, [])
+                book("seed_block", pool.block)
+                self._block_step(pool)
+                book("block_step", (pool.cache, pool.block))
+                continue
             for _ in range(kinds):  # the cache, keys and tokens as a chunk left them, then as a step did
                 out, pool.cache, pool.keys, _ = self._decode(
                     pool, pool.dev_tokens, *pool.sampler(), pool.keys,
@@ -1261,6 +1395,37 @@ class JaxEngine:
         if self._decode_n_steps == 1:
             out = out[None]  # unify to [K, slots]
         return out, cache, keys, stats
+
+    def _block_step(self, pool: _Pool):
+        """One forward of every slot's block in ``pool``, chained on the block
+        state the last one left on the device. Returns ([slots, B + 2]: a
+        slot's block after the forward, whether it committed, the positions
+        it unmasked; the routing counts), both still on the device."""
+        out, pool.cache, pool.block, pool.keys, stats = self._launch(
+            ("block_step", pool.stripe_len), "_block_step_jit",
+            self.params, pool.cache, pool.block, *pool.sampler(), pool.keys)
+        return out, stats
+
+    def _bind_block(self, pool: _Pool, slot: int, params: SamplingParams, length: int,
+                    tail: list, key=None) -> None:
+        """``slot`` of a pool that generates by blocks starts a request's
+        first block behind ``length`` cached positions: the prompt's ``tail``
+        clean in front, masks behind it, the request's denoising steps (the
+        model's own where it names none), and with ``key`` the slot's key (a
+        final chunk's launch has set it otherwise)."""
+        import jax.numpy as jnp
+
+        cfg, B = self.model_cfg, pool.block_length
+        tokens = np.full((B,), cfg.mask_token_id, np.int32)
+        tokens[:len(tail)] = tail
+        steps = params.denoise_steps or cfg.denoise_steps or B
+        slot_dev = jnp.int32(slot)
+        if key is not None:
+            pool.keys = self._set_key_jit(pool.keys, slot_dev, key)
+        pool.cache, pool.block = self._launch(
+            ("seed_block", pool.stripe_len), "_seed_block_jit", pool.cache, pool.block,
+            slot_dev, jnp.int32(length), jnp.asarray(tokens), jnp.asarray(np.arange(B) >= len(tail)),
+            jnp.int32(min(max(1, steps), B)))
 
     def _lora_kw(self, adapter_ids: list) -> dict:
         """A chunk program's adapter arguments, an id a row (none in a
@@ -1866,10 +2031,18 @@ class JaxEngine:
                 prefix, m, req.prefix_key = self._prefix_lookup(ids, pool.stripe_len)
         else:
             prefix, m = None, 0
-        suffix = ids[m:]
+        # a pool that generates by blocks prefills the prompt's whole blocks;
+        # the tokens left, fewer than a block, stand clean at the front of
+        # the first block it generates. (A stored prefix that leaves no whole
+        # block to run is passed over: the chunk that copies a stripe into
+        # its slot has to have a block to run.)
+        whole = len(ids) - len(ids) % pool.block_length if pool.block_length else len(ids)
+        if pool.block_length and m == whole:
+            prefix, m, req.prefix_key = None, 0, None
+        suffix = ids[m:whole]
         req.prefix_hit_tokens = m
         self._count({"prompt_tokens": len(ids), "prompt_tokens_from_prefix": m})
-        chunk = self.config.engine.prefill_chunk or len(suffix)
+        chunk = self.config.engine.prefill_chunk or max(1, len(suffix))
         pieces = [suffix[i : i + chunk] for i in range(0, len(suffix), chunk)]
         chunks = []
         start = m
@@ -1894,7 +2067,8 @@ class JaxEngine:
                 if "state" in prefix:
                     self._count({"snapshots_hit": 1, "snapshot_seed_bytes": prefix["nbytes"]})
                 self._count({"prefix_seed_tokens": m})
-        pool.admitting[slot] = _Admission(req, slot, one, chunks, m)
+        pool.admitting[slot] = adm = _Admission(req, slot, one, chunks, m)
+        adm.tail = list(ids[whole:])
 
     @contextmanager
     def _device_call(self, kind: str, program: str, span: str):
@@ -2042,11 +2216,7 @@ class JaxEngine:
             req.params.temperature, top_k, req.params.seed, req.lora_idx, carry=carry,
         )
         self._count_chunks("final", [(toks, eff_len, start)])
-        pool.slots[slot] = req
-        pool.temps[slot] = req.params.temperature
-        pool.top_ks[slot] = top_k
-        pool.sampler_dev = None
-        del pool.admitting[slot]
+        self._bind_slot(pool, adm, length=start + eff_len)
         # LoRA'd prefixes are adapter-specific: never shared
         if req.lora_idx == 0 and pool.stateful:  # after a hit as after a miss
             with self._device_call("launch", "store_snapshot", "engine.prefix_store"):
@@ -2062,6 +2232,31 @@ class JaxEngine:
             pass
         pool.first_pending.append((slot, req, first_tok, stats))
 
+    def _bind_slot(self, pool: "_Pool", adm: _Admission, length: int, key=None) -> None:
+        """The host's side of an admission's end: the slot holds the request
+        from now on, under its own sampler. In a pool that generates by blocks
+        the slot's first block is seeded as well (``_bind_block``; ``key``:
+        the request's where no final chunk has set it)."""
+        req, slot = adm.req, adm.slot
+        if pool.block_length:
+            self._bind_block(pool, slot, req.params, length, adm.tail, key)
+            req.block_tail = len(adm.tail)
+            self._count({"block_prompt_tail_tokens": len(adm.tail)})
+        pool.slots[slot] = req
+        pool.temps[slot] = req.params.temperature
+        pool.top_ks[slot] = min(max(1, req.params.top_k), self._top_k_static)
+        pool.sampler_dev = None
+        del pool.admitting[slot]
+
+    def _request_key(self, seed: Optional[int]):
+        """The key a request's draws start from: its seed's, or the engine's next."""
+        import jax
+
+        if seed is not None:
+            return jax.random.PRNGKey(seed)
+        self._rng_key, key = jax.random.split(self._rng_key)
+        return key
+
     def _run_chunk_final(self, pool: "_Pool", one, toks, eff_len: int, start: int, slot: int,
                          temperature: float, top_k: int, seed: Optional[int], adapter: int,
                          carry: "dict | str | None" = None):
@@ -2075,10 +2270,7 @@ class JaxEngine:
         import jax.numpy as jnp
 
         with tracing.annotate("engine.chunk_transfer"):  # the host's arrays and scalars
-            if seed is not None:
-                req_key = jax.random.PRNGKey(seed)
-            else:
-                self._rng_key, req_key = jax.random.split(self._rng_key)
+            req_key = self._request_key(seed)
             slot_dev = jnp.int32(slot)
             args = (jnp.asarray(toks), jnp.asarray([eff_len], jnp.int32),
                     jnp.asarray([start], jnp.int32), slot_dev,
@@ -2176,6 +2368,13 @@ class JaxEngine:
             launches, mids = [], None  # mids: the middle-chunk launch with room left
             for adm in list(pool.admitting.values()):
                 try:
+                    if pool.block_length and not adm.chunks:
+                        # a prompt shorter than a block prefills nothing: the
+                        # slot starts at length 0 with the prompt in its block
+                        with self._device_call("launch", "seed_block", "engine.block_bind"):
+                            self._bind_slot(pool, adm, length=0, key=self._request_key(adm.req.params.seed))
+                        progressed = True
+                        continue
                     # an admission with no chunk (an empty prompt) fails that
                     # request, not the loop
                     is_final = adm.chunks[adm.idx][3]
@@ -2226,6 +2425,10 @@ class JaxEngine:
             (len(r.prompt_token_ids) + len(r.out_tokens) for r in active.values()),
             np.int64, len(active),
         )
+        if pool.block_length:
+            # a step is a forward of a block: it reads the slot's whole blocks
+            # (the prompt's tail rides in the first) and the block itself
+            lengths = lengths // pool.block_length * pool.block_length + pool.block_length
         tokens, read = (
             ("decode_kv_tokens_latent", "decode_kv_positions_read_latent")
             if pool.latent else ("decode_kv_tokens_global", "decode_kv_positions_read")
@@ -2260,14 +2463,19 @@ class JaxEngine:
             if not isinstance(active, dict):
                 continue
             try:
-                with self._device_call("launch", "decode", "engine.decode_launch"):
-                    out, pool.cache, pool.keys, stats = self._decode(
-                        pool,
-                        pool.dev_tokens,
-                        *pool.sampler(),
-                        pool.keys,
-                    )
-                    pool.dev_tokens = out[-1]
+                with self._device_call(
+                    "launch", *(("block_step", "engine.block_launch") if pool.block_length
+                                else ("decode", "engine.decode_launch"))):
+                    if pool.block_length:
+                        out, stats = self._block_step(pool)
+                    else:
+                        out, pool.cache, pool.keys, stats = self._decode(
+                            pool,
+                            pool.dev_tokens,
+                            *pool.sampler(),
+                            pool.keys,
+                        )
+                        pool.dev_tokens = out[-1]
                     try:
                         out.copy_to_host_async()
                         if stats is not None:
@@ -2300,6 +2508,8 @@ class JaxEngine:
         pool.step_carried = False
         pool.cache = self._held(init_kv_cache(self.model_cfg, pool.n_slots, pool.stripe_len))
         pool.dev_tokens = self._held(jax.numpy.zeros((pool.n_slots,), jax.numpy.int32))
+        if pool.block_length:
+            pool.block = self._held(self._new_block(pool.n_slots))
         # keys may already point at the failed program's poisoned output
         # (reassigned in _launch_decodes before the error surfaced at
         # fetch): without fresh keys every future admission fails too
@@ -2338,7 +2548,9 @@ class JaxEngine:
                         break
                     ended = None
                     try:
-                        if pool.slots[slot] is req:
+                        # (a prompt of a pool that generates by blocks samples
+                        # no token: what came is its chunks' routing counts)
+                        if pool.slots[slot] is req and not pool.block_length:
                             req.first_token_t = time.time()
                             # one token, or none where the first sampled was a stop
                             made, ended = self._emit(pool, slot, t)
@@ -2363,18 +2575,21 @@ class JaxEngine:
                 made = discarded = 0
                 done = []  # (request, when its last token was emitted)
                 try:
-                    for k in range(arr.shape[0]):
-                        for slot, req in binding.items():
-                            if pool.slots[slot] is req:
-                                appended, ended = self._emit(pool, slot, int(arr[k, slot]))
-                                made += appended
-                                if ended is not None:
-                                    done.append((req, ended))
-                                entry = applied.setdefault(id(req), [req, 0])
-                                entry[1] += 1
-                            else:
-                                discarded += 1
-                    counts.update(tokens_generated=made, tokens_discarded=discarded)
+                    if pool.block_length:  # a forward of a block a slot: [slots, B + 2]
+                        counts.update(self._take_blocks(pool, arr, binding, applied, done))
+                    else:
+                        for k in range(arr.shape[0]):
+                            for slot, req in binding.items():
+                                if pool.slots[slot] is req:
+                                    appended, ended = self._emit(pool, slot, int(arr[k, slot]))
+                                    made += appended
+                                    if ended is not None:
+                                        done.append((req, ended))
+                                    entry = applied.setdefault(id(req), [req, 0])
+                                    entry[1] += 1
+                                else:
+                                    discarded += 1
+                        counts.update(tokens_generated=made, tokens_discarded=discarded)
                     self._count(counts)
                 finally:  # a slot that was freed is closed, whatever came after it
                     for req, ended in done:
@@ -2383,6 +2598,48 @@ class JaxEngine:
                     req.pacer.note_block(n)
                 progressed = True
         return progressed
+
+    def _take_blocks(self, pool: "_Pool", arr, binding: dict, applied: dict, done: list) -> dict:
+        """What one fetched forward of a pool that generates by blocks brought
+        (``arr`` [slots, B + 2]: a slot's block after the forward, whether it
+        committed, the positions it unmasked), for the slots still bound to
+        the request the launch ran for: a denoise forward is counted; a
+        committed block's tokens are the request's next ones, but for the
+        prompt's tail at the front of its first block, each through ``_emit``,
+        which cuts the block at ``max_tokens``, at a stop token and at the
+        stripe's end (what is left of it is discarded, as is a block committed
+        for a request that had ended). ``applied`` and ``done`` as ``_drain``
+        keeps them. Returns the counts."""
+        B = pool.block_length
+        got = dict.fromkeys(("block_forwards:denoise", "block_forwards:commit",
+                           "block_tokens_unmasked", "blocks_committed", "block_tokens_emitted",
+                           "tokens_discarded"), 0)
+        for slot, req in binding.items():
+            *block, committed, unmasked = (int(x) for x in arr[slot])
+            if pool.slots[slot] is not req:
+                got["tokens_discarded"] += B * committed
+                continue
+            if not committed:
+                got["block_forwards:denoise"] += 1
+                got["block_tokens_unmasked"] += unmasked
+                continue
+            got["block_forwards:commit"] += 1
+            got["blocks_committed"] += 1
+            tokens, req.block_tail = block[req.block_tail:], 0
+            if req.first_token_t is None:
+                req.first_token_t = time.time()
+            emitted = 0
+            for at, token in enumerate(tokens):
+                appended, ended = self._emit(pool, slot, token)
+                emitted += appended
+                if ended is not None:
+                    done.append((req, ended))
+                    got["tokens_discarded"] += len(tokens) - at - 1
+                    break
+            got["block_tokens_emitted"] += emitted
+            applied.setdefault(id(req), [req, 0])[1] += emitted
+        got["tokens_generated"] = got["block_tokens_emitted"]
+        return got
 
     @staticmethod
     def _arrived(pool: "_Pool", pending: list, runahead: int) -> tuple:

@@ -166,13 +166,16 @@ class GangLLMServer:
         worker_env: Optional[dict] = None,
         pg_timeout: float = 120.0,
     ):
-        from ray_tpu.llm.config import refuse_latent, refuse_stateful, resolve_llama_config
+        from ray_tpu.llm.config import (
+            refuse_blocks, refuse_latent, refuse_stateful, resolve_llama_config,
+        )
         from ray_tpu.llm.tokenizer import get_tokenizer
 
         # before a placement group or a worker exists
         model_cfg = resolve_llama_config(llm_config.model, llm_config.engine)
         refuse_latent(model_cfg, "llm/gang.py")
         refuse_stateful(model_cfg, "llm/gang.py")
+        refuse_blocks(model_cfg, "llm/gang.py")
         self.llm_config = llm_config
         self.tokenizer = get_tokenizer(llm_config.model.tokenizer)
         self.num_workers = num_workers

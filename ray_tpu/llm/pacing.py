@@ -1,10 +1,12 @@
 """Per-token emission smoothing between the decode buffer and SSE writers.
 
 Multi-step decode (``EngineConfig.decode_steps`` > 1) and run-ahead deliver
-sampled tokens to the host in K-sized blocks: without smoothing an SSE
-client sees one burst per dispatched program and the intertoken p50
-collapses to ~0 (the intra-burst gap) while the p99 is the whole program
-interval — the worst of both worlds for perceived streaming latency
+sampled tokens to the host in K-sized blocks, and a model that generates by
+blocks (``LlamaConfig.block_length``) hands a request up to a whole block with
+the one fetch that finds it committed and nothing with the fetches between:
+without smoothing an SSE client sees one burst per dispatched program and
+the intertoken p50 collapses to ~0 (the intra-burst gap) while the p99 is the
+whole program interval — the worst of both worlds for perceived streaming latency
 (VERDICT r5 weak #3). The pacer spreads each block over the *observed*
 inter-block interval, so the client-visible token cadence approximates the
 true sustained rate with no throughput cost: the next block keeps arriving

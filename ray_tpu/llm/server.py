@@ -19,14 +19,17 @@ def sampling_from_body(body: dict) -> SamplingParams:
     """The sampling fields of an OpenAI-shaped request body. ``ignore_eos``
     and ``seed`` are this server's extensions (vLLM's names): a load
     generator that must get ``max_tokens`` tokens sets the first, a caller
-    that must get the same sample twice the second."""
+    that must get the same sample twice the second. ``denoise_steps`` is for
+    a model that generates by blocks (absent: the model's own)."""
     seed = body.get("seed")
+    steps = body.get("denoise_steps")
     return SamplingParams(
         max_tokens=int(body.get("max_tokens", 64)),
         temperature=float(body.get("temperature", 0.0)),
         top_k=int(body.get("top_k", 50)),
         ignore_eos=bool(body.get("ignore_eos", False)),
         seed=None if seed is None else int(seed),
+        denoise_steps=None if steps is None else int(steps),
     )
 
 

@@ -29,6 +29,7 @@ from typing import Optional
 from ray_tpu.llm.config import (
     LLMConfig,
     SamplingParams,
+    refuse_blocks,
     refuse_latent,
     refuse_stateful,
     resolve_llama_config,
@@ -64,6 +65,7 @@ class SPMDGenerator:
         )
         refuse_latent(self.model_cfg, "llm/spmd.py")
         refuse_stateful(self.model_cfg, "llm/spmd.py")
+        refuse_blocks(self.model_cfg, "llm/spmd.py")
         if mesh is None:
             n = len(jax.devices())
             if (

@@ -235,6 +235,24 @@ class LlamaConfig:
     index_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    # each head's query and key through an RMSNorm of the head's width with a
+    # learned scale before they are rotated: one scale for the queries and one
+    # for the keys a layer (Qwen3's; leaves ``q_head_norm``, ``k_head_norm``)
+    qk_norm: bool = False
+    # --- generation by diffusion over blocks (JetLM SDAR; ``block_step``) ---
+    # ``block_length`` > 0: a query at position t attends position s iff
+    # ``s // block_length <= t // block_length`` (causal across blocks, both
+    # ways inside one), the logits at a position are of the token at that
+    # position itself, and a sequence grows a block at a time: a block starts
+    # as ``mask_token_id`` and is unmasked by confidence over
+    # ``denoise_steps`` forwards (a request may ask for fewer), every position
+    # whose confidence passes ``confidence_threshold`` with them (1: none
+    # does), and its keys and values are kept by one more forward once it is
+    # clean. 0: every model that generates a token a step, untouched
+    block_length: int = 0
+    mask_token_id: int = 0
+    denoise_steps: int = 0
+    confidence_threshold: float = 1.0
 
     def __post_init__(self):
         if self.attention not in ("full", "ring", "ulysses", "splash"):
@@ -259,6 +277,14 @@ class LlamaConfig:
         if not self.layer_types and (self.moe_router_hidden or self.residual_scales):
             raise ValueError("moe_router_hidden and residual_scales need layer_types "
                              "(models/patterned.py)")
+        if not self.layer_types and (self.qk_norm or self.block_length):
+            raise ValueError("qk_norm and block_length need layer_types (models/patterned.py)")
+        if self.block_length and not (
+            0 < (self.denoise_steps or self.block_length) <= self.block_length
+            and 0 <= self.mask_token_id < self.vocab_size
+        ):
+            raise ValueError("block_length: denoise_steps of 1 .. block_length and a "
+                             "mask_token_id inside the vocabulary")
 
     @property
     def head_dim(self) -> int:
@@ -655,6 +681,51 @@ class LlamaConfig:
         d.update(kw)
         return LlamaConfig(**_sparse_latent_lists(d, sliding_heads=2))
 
+    @staticmethod
+    def sdar_30b_a3b(**kw) -> "LlamaConfig":
+        """JetLM SDAR-30B-A3B-Chat (``model_type: sdar_moe``) as its
+        config.json has it: 48 layers alike, 32 query heads over 4 key-value
+        heads of 128, 128 experts of width 768, 8 a token, a softmax router
+        renormalised over the chosen, no shared expert, head untied. Not in
+        the config (``benchmark/configs/sdar-30b-a3b-chat-serve-l6.json``
+        ``assumed``): the per-head query and key norms (the family's
+        backbone, Qwen3-MoE, has them), blocks of 4 unmasked over 4 denoising
+        steps, the confidence threshold 0.9 (which seeded weights never
+        reach), the mask token's id. A caller that cuts ``n_layers`` gets the
+        pattern's first entries."""
+        d = dict(
+            vocab_size=151936, d_model=2048, n_layers=48, n_heads=32, n_kv_heads=4,
+            head_width=128, d_ff=6144, max_seq_len=32768, rms_eps=1e-6, rope_theta=1e6,
+            qk_norm=True, moe_experts=128, moe_top_k=8, moe_d_ff=768,
+            block_length=4, mask_token_id=151669, denoise_steps=4, confidence_threshold=0.9,
+        )
+        d.update(kw)
+        return LlamaConfig(**_sdar_lists(d))
+
+    @staticmethod
+    def sdar_tiny(**kw) -> "LlamaConfig":
+        """Test-size model with ``sdar_30b_a3b``'s parts: grouped-query
+        attention under per-head norms over routed experts, blocks of 4."""
+        d = dict(
+            vocab_size=320, d_model=64, n_layers=3, n_heads=4, n_kv_heads=2,
+            head_width=16, d_ff=128, max_seq_len=128, dtype=jnp.float32, remat=False,
+            rms_eps=1e-6, rope_theta=1e6, qk_norm=True, moe_experts=8, moe_top_k=2,
+            moe_d_ff=32, block_length=4, mask_token_id=300, denoise_steps=4,
+            confidence_threshold=0.9,
+        )
+        d.update(kw)
+        return LlamaConfig(**_sdar_lists(d))
+
+
+def _sdar_lists(d: dict) -> dict:
+    """The per-layer lists of a model whose layers are all full attention over
+    routed experts, for its depth."""
+    n = d["n_layers"]
+    d.setdefault("layer_types", ("full",) * n)
+    d.setdefault("heads_per_layer", (d["n_heads"],) * n)
+    d.setdefault("mlp_types", ("sparse",) * n)
+    return d
+
 
 # a block of a ``nemotron_h`` pattern: (mixer, feed-forward)
 _BLOCKS = {"M": ("ssm", "none"), "E": ("none", "sparse"), "*": ("full", "none")}
@@ -722,6 +793,8 @@ _PARAM_DIMS = {
     "w_down": (None, "mlp", "embed"),
     "attn_norm": (None, "norm"),
     "mlp_norm": (None, "norm"),
+    "q_head_norm": (None, None),
+    "k_head_norm": (None, None),
     # MoE variant: per-layer expert banks (expert dim -> ep mesh axis)
     "moe_router": (None, "embed", None),
     "moe_router_bias": (None, None),
@@ -1375,3 +1448,73 @@ def decode_step(
         loras=loras, adapter_ids=adapter_ids,
     )
     return logits[:, -1], cache
+
+
+def block_schedule(step, steps, block_length: int):
+    """Positions denoising step ``step`` (0-based) of ``steps`` unmasks in a
+    block of ``block_length``: ``block_length // steps``, one more in the first
+    ``block_length % steps`` steps. Integers or arrays of them (NumPy's or
+    JAX's alike: the engine's host and its program both ask)."""
+    return block_length // steps + (step < block_length % steps)
+
+
+def block_step(
+    params, cache, tokens, masked, n_unmask, commit, cfg: LlamaConfig,
+    sample=None, with_logits: bool = False,
+):
+    """One forward of a block a slot of a model that generates by diffusion
+    over blocks (``cfg.block_length``; ``decode_step``'s place for it).
+    tokens [slots, B]: each slot's block at positions ``[length, length + B)``,
+    ``cfg.mask_token_id`` where ``masked`` [slots, B]; every query of a slot
+    attends the slot's cache and the whole block (``models/patterned.py
+    decode_forward`` under ``block``: the stripe is read once for all ``B``).
+
+    A *denoise* forward takes at each masked position the token ``x0``
+    (``sample``: [slots * B, V] float32 logits, a row a position -> [slots * B]
+    tokens; greedy where None) and its confidence, ``softmax(logits)[x0]`` in
+    float32, and writes ``x0`` into the ``n_unmask`` [slots] masked positions
+    of largest confidence (ties to the lower position) and into every further
+    one whose confidence passes ``cfg.confidence_threshold``, the model's own
+    constant. The mask token's own logit is set to minus infinity first, so
+    that no position unmasks to a mask.
+    A slot with ``commit`` [slots] (the caller sets it where the block is
+    clean) keeps the block's keys and values: its length advances by ``B``.
+    Every forward writes the block's keys and values at ``[length, length +
+    B)``, which is how its queries read them; behind a slot's length nothing
+    else reads, and the next forward of the block overwrites them, so what
+    stays in the cache is what a commit wrote. A free slot's arithmetic is
+    done and dropped as in ``decode_step``.
+
+    Returns (the block after the step [slots, B], which of it is still masked
+    [slots, B], the logits [slots, B, V] where ``with_logits`` else None, the
+    cache)."""
+    B = cfg.block_length
+    if not B or tokens.shape[1] != B:
+        raise ValueError(f"block_step: a model with block_length={B} and tokens [slots, {B}]")
+    positions = cache["length"][:, None] + jnp.arange(B, dtype=jnp.int32)[None, :]
+    logits, cache = decode_forward(params, cache, tokens, positions, cfg, block_commit=commit)
+    rows = logits[0]  # a row a position, [slots * B, V]: the head's own tiles
+    with scope("sampling"):
+        with scope("confidence"):
+            # one column written where the logits lie: a select over every
+            # logit was two more passes over the 155 MB of them
+            rows = jax.lax.dynamic_update_slice(
+                rows, jnp.full((rows.shape[0], 1), -jnp.inf, rows.dtype), (0, cfg.mask_token_id))
+            x0 = (jnp.argmax(rows, -1) if sample is None else sample(rows)).astype(jnp.int32)
+            top = rows.max(axis=-1)
+            norm = jnp.exp(rows - top[:, None]).sum(axis=-1)
+            chosen = jnp.take_along_axis(rows, x0[:, None], axis=-1)[:, 0]
+            x0 = x0.reshape(tokens.shape)
+            confidence = (jnp.exp(chosen - top) / norm).reshape(tokens.shape)
+        with scope("unmask"):
+            # a masked position's rank among the slot's masked ones, by
+            # confidence, ties to the lower position: those before it
+            c = jnp.where(masked, confidence, -1.0)
+            at = jnp.arange(B)
+            before = (c[:, None, :] > c[:, :, None]) | (
+                (c[:, None, :] == c[:, :, None]) & (at[None, None, :] < at[None, :, None]))
+            rank = before.sum(axis=-1)
+            take = masked & ((rank < n_unmask[:, None]) | (confidence > cfg.confidence_threshold))
+            tokens = jnp.where(take, x0, tokens)
+            masked = masked & ~take
+    return tokens, masked, (rows.reshape(tokens.shape + rows.shape[1:]) if with_logits else None), cache

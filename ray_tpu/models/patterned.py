@@ -289,6 +289,9 @@ def plan(cfg) -> Plan:
         )
     if any(m == "sparse" for _, _, m in kinds) and not cfg.moe_experts:
         raise ValueError("sparse layers need moe_experts")
+    if (cfg.qk_norm or cfg.block_length) and any(t != "full" for t, _, _ in kinds):
+        raise ValueError("qk_norm and block_length: over full attention layers alone (the "
+                         "per-head norms and the block mask are the full kind's)")
     if cfg.moe_experts_held and not (
         0 <= cfg.moe_experts_first <= cfg.moe_experts - cfg.moe_experts_held
     ):
@@ -579,6 +582,8 @@ def _param_shapes(cfg) -> dict[str, tuple]:
     }
     if not cfg.kv_latent_rank:
         shapes.update({"wk": (pl.n_attention, e, kv, hd), "wv": (pl.n_attention, e, kv, hd)})
+    if cfg.qk_norm:  # a scale a channel of a head, for the queries and for the keys
+        shapes.update({"q_head_norm": (pl.n_attention, hd), "k_head_norm": (pl.n_attention, hd)})
     if pl.n_ssm:
         shapes.update(_ssm_shapes(cfg, pl.n_ssm))
     if pl.n_kda:
@@ -1134,6 +1139,9 @@ def _qkv(params, lay: _Layer, h, positions, cfg, loras=None, adapter_ids=None):
                 jnp.einsum("bte,ber->btr", h, lp["wv_a"][adapter_ids]),
                 lp["wv_b"][adapter_ids],
             )
+        if cfg.qk_norm:  # each head's own width, before the rotation
+            q = _rmsnorm(q, params["q_head_norm"][lay.kv_i], cfg.rms_eps)
+            k = _rmsnorm(k, params["k_head_norm"][lay.kv_i], cfg.rms_eps)
         if cfg.attn_rope:
             q = _rope(q, positions, inv_freq, factor)
             k = _rope(k, positions, inv_freq, factor)
@@ -1673,6 +1681,8 @@ def forward_hidden(params, tokens, cfg, mesh: Optional[Mesh] = None, positions=N
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
     x = _embed_lookup(params["embed"], tokens, cfg, None)
     back = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]  # query - key
+    if cfg.block_length:  # causal across blocks, both ways inside one
+        back = positions[:, :, None] // cfg.block_length - positions[:, None, :] // cfg.block_length
     masks = {
         "full": jnp.broadcast_to(back >= 0, (B, T, T)),
         "sliding": jnp.broadcast_to((back >= 0) & (back < cfg.sliding_window), (B, T, T)),
@@ -2008,7 +2018,7 @@ def _latent_reader(cfg, params, cache, positions):
             {kind: not expanded for kind, expanded in expands.items()})
 
 
-def _cache_reader(cfg, params, cache, positions, kinds):
+def _cache_reader(cfg, params, cache, positions, kinds, block: bool = False):
     """``read(q, ck_all, cv_all, lay)`` for ``decode_forward``: attention of
     the queries [B, T, H, D] over layer ``lay.l`` of the carried cache
     [L, B, K, S, D] -> [B, T, H, D]. Two forms of one read, chosen from what
@@ -2033,10 +2043,31 @@ def _cache_reader(cfg, params, cache, positions, kinds):
     of ``_FULL_KEY_BLOCK`` key positions instead, up to the furthest row's
     last query and no further, with a running maximum, sum and context in
     float32 (as ``_latent_reader`` walks a latent cache): neither the work nor
-    any temporary follows the stripe where the rows are shorter."""
+    any temporary follows the stripe where the rows are shorter.
+
+    A model that generates by blocks (``cfg.block_length``) reads under the
+    block mask: a query sees every position up to the end of its own block.
+    Its block step (``block``: ``T`` new tokens a row, one block, already
+    written) takes the same kernel: the block's ``T`` queries lie beside each
+    key-value head's query heads as rows of one matmul a key block
+    (``T * H // K`` of them where a decode step has ``H // K``), all bounded
+    ``[0, first position + T)``, so a row's stripe is read once for all of
+    them."""
     T = positions.shape[1]
     S = cache["k"].shape[3]
     W, A = cfg.sliding_window, _WINDOW_ALIGN
+    if block and reads_blocks(S, cache["k"], *jax.tree.leaves(params)):
+        hi = positions[:, 0] + T
+        lo = jnp.zeros_like(hi)
+
+        def read(q, ck_all, cv_all, lay):
+            B, _, H, D = q.shape
+            K = ck_all.shape[2]
+            folded = q.reshape(B, T, K, H // K, D).transpose(0, 2, 1, 3, 4).reshape(B, T * H, D)
+            out = decode_attention(folded, ck_all, cv_all, lay.kv_i, lo, hi)
+            return out.reshape(B, K, T, H // K, D).transpose(0, 2, 1, 3, 4).reshape(B, T, H, D)
+
+        return read
     if T == 1 and reads_blocks(S, cache["k"], *jax.tree.leaves(params)):
         hi = positions[:, 0] + 1
         lo = {**dict.fromkeys(_FULL_KINDS, jnp.zeros_like(hi)), "sliding": jnp.maximum(hi - W, 0)}
@@ -2047,6 +2078,8 @@ def _cache_reader(cfg, params, cache, positions, kinds):
         return read
 
     qpos = positions[:, :, None]  # [B, T, 1]
+    if cfg.block_length:  # the last position a query sees: its own block's
+        qpos = (qpos // cfg.block_length + 1) * cfg.block_length - 1
     slot = jnp.arange(S)[None, None, :]
     span = -(-(W + T - 1 + A - 1) // A) * A  # covers the window from an aligned start
     whole = {**dict.fromkeys(_FULL_KINDS, True), "sliding": span >= S}
@@ -2115,7 +2148,7 @@ class _Rows:
     ``_cache_reader`` or ``_latent_reader``), chosen from its own shapes."""
 
     def __init__(self, cfg, params, kinds, cache, tokens, positions, valid, start_pos,
-                 second: bool = False):
+                 second: bool = False, block: bool = False):
         self.cache, self.tokens, self.positions, self.valid = cache, tokens, positions, valid
         self.second = second
         self.B, self.T = tokens.shape
@@ -2124,7 +2157,7 @@ class _Rows:
         if "latent" in kinds:  # every layer is latent (``plan``); by kind
             self.read, self.select, self.from_latent = _latent_reader(cfg, params, cache, positions)
         else:
-            self.read = _cache_reader(cfg, params, cache, positions, kinds)
+            self.read = _cache_reader(cfg, params, cache, positions, kinds, block)
             self.from_latent = dict.fromkeys(kinds, False)
 
     def real(self):
@@ -2158,9 +2191,9 @@ def _split(x, shapes):
 
 def decode_forward(
     params, cache, tokens, positions, cfg, valid=None, loras=None, adapter_ids=None,
-    with_logits: bool = True, logits_at=None, start_pos=None, beside=None,
+    with_logits: bool = True, logits_at=None, start_pos=None, beside=None, block_commit=None,
 ):
-    """The body of ``prefill`` and ``decode_step`` for every model. tokens:
+    """The body of ``prefill``, ``decode_step`` and ``block_step`` for every model. tokens:
     [B, T]; positions: [B, T]. New k/v are written into the cache before
     attention so new tokens attend to themselves and to all prior cache
     slots. ``valid`` [B, T] marks real (non-padding) tokens; padding writes
@@ -2217,6 +2250,13 @@ def decode_forward(
     sets' rows as one matrix (``_join``) keeps its name and is booked to the
     chunk: 32-64 rows beside a chunk's 64-1,024 tokens.
 
+    ``block_commit`` [B] (``models/llama.py block_step``): the rows are one
+    block each of a model that generates by blocks, every query of a row
+    reading the row's stripe up to the block's end (``_cache_reader``), and a
+    row's length advances by ``T`` where it commits and stays elsewhere: the
+    block's keys and values are written either way (the read needs them),
+    behind a length that did not move nothing reads them.
+
     ``loras``/``adapter_ids``: stacked LoRA adapters + per-sequence adapter
     index (0 = base), over layers that are alike.
     ``with_logits=False`` (a prompt's middle chunk) only extends the cache
@@ -2247,7 +2287,8 @@ def decode_forward(
             _join([tokens] if beside is None else [tokens, beside[1][:, None]])
         ].astype(cfg.dtype)
         x = _times(x, cfg.embedding_multiplier)
-    sets = [_Rows(cfg, params, kinds, cache, tokens, positions, valid, start_pos)]
+    sets = [_Rows(cfg, params, kinds, cache, tokens, positions, valid, start_pos,
+                  block=block_commit is not None)]
     if beside is not None:
         cache2, tokens2, live = beside
         sets.append(_Rows(cfg, params, kinds, cache2, tokens2[:, None], cache2["length"][:, None],
@@ -2344,7 +2385,9 @@ def decode_forward(
                 if index and rows.select is not None:
                     with scope("attn_core"):  # ``attn_index`` and ``attn_select`` inside it
                         chosen = (rows.select(index, written["k_index"], lay),)
-                with rows.scope(), scope("attn_core"), lay.inner_scope():
+                # (a block step's read has a name of its own under ``attn_core``)
+                with rows.scope(), scope("attn_core"), (
+                        lay.inner_scope() if block_commit is None else scope("block")):
                     attn.append(rows.read(qj, ck_all, cv_all, lay, *chosen))
                 new_kv.append({**kv[j], names[0]: ck_all, names[1]: cv_all, **written})
             kv = tuple(new_kv)
@@ -2366,6 +2409,8 @@ def decode_forward(
     for rows, written, leaves in zip(sets, kv, state):
         grew = rows.T if rows is sets[0] or rows.valid is None else rows.valid.sum(
             axis=1, dtype=jnp.int32)
+        if block_commit is not None:
+            grew = jnp.where(block_commit, rows.T, 0)
         new_cache = {**written, "length": rows.cache["length"] + grew, **leaves}
         _ride_stats(rows.cache, new_cache, stats)
         new_caches.append(new_cache)
@@ -2378,6 +2423,10 @@ def decode_forward(
         heads[0] = jnp.take_along_axis(heads[0], logits_at[:, None, None], axis=1)
     if not with_logits:
         heads = heads[1:]
+    if block_commit is not None:
+        # a row a position: the head's [1, B * T, V] lies in whole tiles of 8
+        # rows, where [B, T, V] pads each row's T positions to 8 and is relaid
+        heads = [h.reshape((1, -1) + h.shape[2:]) for h in heads]
     logits = []
     if heads:
         # a middle chunk's head is the second set's alone
