@@ -26,7 +26,16 @@ the TPU engine keeps everything static for XLA:
   tokens, which of them are masked and the denoising step live on the device
   between launches (``_Pool.block``), so steps chain without the host as a
   token a step does; a fetch brings a slot's committed block, up to
-  ``block_length`` tokens at once, or nothing (``_take_blocks``).
+  ``block_length`` tokens at once, or nothing (``_take_blocks``). Such a
+  pool's chunk launches carry its step as every other pool's that is not
+  latent do (``JaxEngine.__init__``): the one-row middle chunk and the final
+  chunk take the block state with the cache and run one forward of every
+  slot's block beside the chunk's tokens, the rows a block wide
+  (``models/patterned.py decode_forward``), so the prompt's read of the
+  weights and the expert banks is the slots' as well; what the forward hands
+  out is ``block_step``'s, row for row, and reaches ``_take_blocks`` the same
+  way. A latent pool still launches its chunks alone: its answers are held
+  to be the same to the token whatever runs beside them.
 
 TP/SP: params and cache shard over a mesh via the model's logical rules
 (``parallel/mesh.py``) when ``tensor_parallel_degree > 1``.
@@ -448,7 +457,8 @@ class _Pool:
         self.admitting: dict[int, _Admission] = {}
         # launched decode steps whose sampled tokens are still being fetched:
         # (out_dev [K, slots], {slot: _Request} binding snapshot, routing
-        # counts or None); a step that a chunk launch carried: ([slots], .., None)
+        # counts or None); a step that a chunk launch carried: ([slots], .., None);
+        # a forward of a block a slot, alone or carried: [slots, B + 2]
         self.inflight: "deque" = deque()
         # first tokens from final prefill chunks awaiting host arrival
         self.first_pending: list = []
@@ -502,7 +512,7 @@ def programs(cfg, decode_steps: int = 1) -> dict:
     import jax.numpy as jnp
 
     from ray_tpu.models.llama import (
-        block_schedule, block_step as model_block_step, decode_step, init_kv_cache, prefill,
+        block_forward, block_schedule, block_unmask, decode_step, init_kv_cache, prefill,
     )
     from ray_tpu.models.patterned import moe_stats_names, state_cache_shapes, stripe_cache_shapes
     from ray_tpu.ops import topk
@@ -592,18 +602,24 @@ def programs(cfg, decode_steps: int = 1) -> dict:
     # a model that generates by diffusion over blocks (``cfg.block_length``)
     blocks = cfg.block_length
 
-    def block_step(params, cache, block, temps, top_ks, keys):
-        """One forward of every slot's block (``models/llama.py block_step``)
-        and what the next forward starts from, with no word from the host: a
-        slot whose block is clean commits it and goes on to a block of masks;
-        any other unmasks what its step of the schedule gives it (``block``:
-        ``_Pool.block``). Every position's token goes through the one
-        ``draw``, under a key of its own split off the slot's. Hands out, a
-        slot, the block after the forward, whether it committed and how many
-        positions it unmasked ([slots, B + 2] int32: what the host fetches),
-        then the cache, the next state, the keys and the routing counts."""
-        masked = block["masked"]
-        commit = ~masked.any(axis=1)
+    def commits(block):
+        """The slots whose block is clean: their forward keeps its keys and values."""
+        return ~block["masked"].any(axis=1)
+
+    def block_after(logits, block, temps, top_ks, keys, live=None):
+        """What a forward of every slot's block does behind its logits
+        ([slots * B, V], a row a position), for the step launched alone
+        (``block_step``) and for one that rode through a prompt's chunk
+        (``ride_block``): a slot whose block was clean committed it and goes
+        on to a block of masks; any other unmasks what its step of the
+        schedule gives it (``block``: ``_Pool.block``). Every position's token
+        goes through the one ``draw``, under a key of its own split off the
+        slot's. Returns, a slot, the block after the forward, whether it
+        committed and how many positions it unmasked ([slots, B + 2] int32:
+        what the host fetches), the next state and the keys. A slot that is
+        not ``live`` [slots] keeps its state and its key (its hand-out is
+        nobody's: the launch's binding holds no such slot)."""
+        masked, commit = block["masked"], commits(block)
         split = jax.vmap(lambda key: jax.random.split(key, blocks + 1))(keys)
 
         def sample(logits):  # [slots * B, V]: a slot's positions under its temperature
@@ -611,25 +627,58 @@ def programs(cfg, decode_steps: int = 1) -> dict:
                 *candidates(logits), jnp.repeat(temps, blocks), jnp.repeat(top_ks, blocks),
                 split[:, 1:].reshape(-1, *split.shape[2:]))[0]
 
-        tokens, still, _, cache = model_block_step(
-            params, stats_in(cache), block["tokens"], masked,
-            block_schedule(block["step"], block["steps"], blocks).astype(jnp.int32),
-            commit, cfg, sample=sample,
-        )
-        stats = cache.pop("moe_stats", None)
+        tokens, still, _ = block_unmask(
+            logits, block["tokens"], masked,
+            block_schedule(block["step"], block["steps"], blocks).astype(jnp.int32), cfg, sample)
+        new_keys = split[:, 0]
         with jax.named_scope("sampling"), jax.named_scope("unmask"):
             out = jnp.concatenate([
                 tokens, commit[:, None].astype(jnp.int32),
                 (masked & ~still).sum(axis=1, dtype=jnp.int32)[:, None],
             ], axis=1)
             fresh = commit[:, None]
-            block = dict(
+            after = dict(
                 block,
                 tokens=jnp.where(fresh, jnp.int32(cfg.mask_token_id), tokens),
                 masked=still | fresh,
                 step=jnp.where(commit, 0, block["step"] + 1),
             )
-        return out, cache, block, split[:, 0], stats
+            if live is not None:
+                after = {name: jnp.where(live[:, None] if x.ndim > 1 else live, x, block[name])
+                         for name, x in after.items()}
+                new_keys = jnp.where(live[:, None], new_keys, keys)
+        return out, after, new_keys
+
+    def block_step(params, cache, block, temps, top_ks, keys):
+        """One forward of every slot's block (``models/llama.py block_step``)
+        and what the next forward starts from, with no word from the host
+        (``block_after``). Hands out the slots' [slots, B + 2] rows, then the
+        cache, the next state, the keys and the routing counts."""
+        logits, cache = block_forward(params, stats_in(cache), block["tokens"], commits(block), cfg)
+        stats = cache.pop("moe_stats", None)
+        out, block, keys = block_after(logits, block, temps, top_ks, keys)
+        return out, cache, block, keys, stats
+
+    def riders(cache, rows):
+        """What ``prefill`` takes of the pool's rows (``beside``): the rows of
+        a decode step, or of a model that generates by blocks the rows of a
+        block step with the slots that commit."""
+        if rows is None:
+            return None
+        if blocks:
+            with jax.named_scope("beside"):
+                commit = commits(rows["block"])
+            return cache, rows["block"]["tokens"], rows["live"], commit
+        return cache, rows["tokens"], rows["live"]
+
+    def ride_block(logits, rows):
+        """``sample_riders`` of a pool that generates by blocks: what
+        ``block_step`` does behind its logits, for the live rows, named as the
+        rows' other work is (``beside`` in front of ``sampling/confidence`` and
+        ``sampling/unmask``). -> (hand-out [slots, B + 2], next state, keys)"""
+        with jax.named_scope("beside"):
+            return block_after(logits, rows["block"], rows["temps"], rows["top_ks"],
+                               rows["keys"], rows["live"])
 
     def seed_block(cache, block, slot, length, tokens, masked, steps):
         """Bind ``slot`` to a request that generates by blocks: its cache
@@ -690,7 +739,13 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         decode_forward``), and the result ends with what ``decode_fn`` hands
         back: ``(stripes, next tokens [slots], cache, keys)``. A launch that
         carries none passes no live row; the counts of all its rows, the
-        decode rows' too, ride with the first stripe."""
+        decode rows' too, ride with the first stripe.
+
+        A pool that generates by blocks hands over its block state where the
+        others hand over tokens (``rows["block"]``: ``_Pool.block``), and the
+        launch carries one forward of every slot's block: ``(stripes, the
+        slots' hand-outs [slots, B + 2], cache, keys, next block state)``, the
+        first as ``block_step`` hands them out (``ride_block``)."""
         if len(ones) == 1:
             stripes = ones[0]
         else:
@@ -704,7 +759,7 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         _, stripes, *rode = prefill(
             params, stripes, tokens, cfg, lengths=lengths, start_pos=starts,
             loras=loras, adapter_ids=adapter_ids, with_logits=False,
-            beside=None if rows is None else (cache, rows["tokens"], rows["live"]),
+            beside=riders(cache, rows),
         )
         if len(ones) == 1:
             out = (stripes,)
@@ -719,6 +774,9 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         if rows is None:
             return out
         logits, cache = rode
+        if blocks:
+            handed, block, new_keys = ride_block(logits, rows)
+            return out, handed, cache, new_keys, block
         next_tokens, new_keys = sample_riders(logits, rows)
         return out, next_tokens, cache, new_keys
 
@@ -742,12 +800,18 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         A row's logits can then differ in the last bit by what shares its
         launch; a pool whose answers have to be the same to the token
         whatever runs beside them (a latent pool: ``JaxEngine.__init__``)
-        passes no rows and runs the chunk alone."""
+        passes no rows and runs the chunk alone.
+
+        In a pool that generates by blocks the rows are a forward of every
+        slot's block (``chunk_mid``), and the result ends with ``(..,
+        hand-outs [slots, B + 2], keys, next block state)``: ``slot``'s key is
+        the request's, and its block is seeded after the launch
+        (``seed_block``) on the cache and the state handed back here."""
         mid_stats = one.get("moe_stats")  # the prompt's middle chunks'
         last_logits, one, *rode = prefill(
             params, one, tokens, cfg, lengths=length, start_pos=start,
             loras=loras, adapter_ids=adapter_ids, with_logits=not blocks,
-            beside=None if rows is None else (cache, rows["tokens"], rows["live"]),
+            beside=riders(cache, rows),
         )
         if rode:
             logits, cache = rode
@@ -763,7 +827,11 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         if blocks:
             # nothing is sampled from a prompt: its whole blocks are kept, and
             # the slot's first block is seeded by ``seed_block``
-            return jnp.zeros((), jnp.int32), key, cache, one, stats
+            out = (jnp.zeros((), jnp.int32), key, cache, one, stats)
+            if rows is None:
+                return out
+            handed, block, new_keys = ride_block(logits, rows)
+            return (*out, handed, new_keys.at[slot].set(key), block)
         with jax.named_scope("sampling"):
             tok, new_key = sample_row(last_logits[0], temp, top_k, key)
         if rows is None:
@@ -865,6 +933,16 @@ class JaxEngine:
         # - not with adapters loaded (a row's adapter is indexed by row of a
         #   batch), ``decode_steps`` over 1 (a carried step is one step) or
         #   over a mesh.
+        # A pool whose step is a forward of a block a slot carries under the
+        # same conditions (it is never latent): its chunk launch runs one
+        # forward of every slot's block beside the chunk's tokens, the rows a
+        # block wide where another pool's are one token, so a prompt's read of
+        # the expert banks serves the slots' blocks too (SDAR's 128 banks of
+        # six layers, 7.25 GB a read, for 64 slots that waited behind it until
+        # PR 56). Its four limits stand 1.4 to 4.3 times over the cell's
+        # readings, so a row's last bit may follow what shares its launch; the
+        # latent pool's comparison is to the token, which is why it still
+        # does not.
         # Until PR 49 a stack of several traced bodies (Laguna's five,
         # Nemotron's eleven) did not carry either: a form that also holds the
         # decode step is a decode program's worth of tracing and lowering
@@ -877,9 +955,7 @@ class JaxEngine:
                    and not self._spans_devices())
         for pool in self._pools:
             pool.chunk_rows = 1 if pool.latent else rows
-            # (nor a pool whose step is a forward of a block a slot: joining
-            # that step to a chunk launch is a later change, ROADMAP R11)
-            pool.carries = carries and not pool.latent and not pool.block_length
+            pool.carries = carries and not pool.latent
         self._init_phase(self._compile)
         self._init_phase(self._warm_programs)
         self._waiting: "queue.Queue[_Request]" = queue.Queue()
@@ -1368,7 +1444,9 @@ class JaxEngine:
                         book("seed_prefix", self._seed_prefix(
                             self._new_stripe(stripe), **self._prefix_cut(pool, 0, b)))
             if pool.block_length:
-                self._bind_block(pool, 0, SamplingParams(), 0, [])
+                # (with a key, as a prompt shorter than a block binds its slot:
+                # no final chunk has set one)
+                self._bind_block(pool, 0, SamplingParams(), 0, [], self._request_key(None))
                 book("seed_block", pool.block)
                 self._block_step(pool)
                 book("block_step", (pool.cache, pool.block))
@@ -1811,6 +1889,9 @@ class JaxEngine:
                  "kv_bytes_per_token": p.kv_bytes_per_token,
                  "kv_bytes_per_token_held": p.kv_bytes_per_token_held,
                  "state_bytes_per_slot": p.state_bytes_per_slot,
+                 # whether its chunk launches take its decode rows and carry its
+                 # step (``decode_steps_in_chunk``): which scheduler a run measured
+                 "carries": p.carries,
                  # positions a block of the decode kernel's walk takes of this
                  # pool's cache (None: its steps keep the einsum over the stripe)
                  "decode_block": p.decode_block,
@@ -2136,35 +2217,42 @@ class JaxEngine:
 
     def _decode_rows(self, pool: "_Pool", carry: "dict | str | None") -> dict:
         """What a chunk program of a pool that ``carries`` takes of the pool's
-        decode step beside the cache: the inputs ``decode_fn`` takes, and the
-        rows that decode in this launch (none unless ``carry`` is slots: the
-        program runs every row all the same, for no token)."""
+        decode step beside the cache: the inputs ``decode_fn`` takes (of a pool
+        that generates by blocks ``block_step``'s: its block state where the
+        others hand over tokens), and the rows that decode in this launch
+        (none unless ``carry`` is slots: the program runs every row all the
+        same, for no token)."""
         import jax.numpy as jnp
 
         live = np.zeros((pool.n_slots,), bool)
         if isinstance(carry, dict):
             live[list(carry)] = True
         temps, top_ks = pool.sampler()
-        return dict(tokens=pool.dev_tokens, temps=temps, top_ks=top_ks, keys=pool.keys,
-                    live=jnp.asarray(live))
+        step = dict(block=pool.block) if pool.block_length else dict(tokens=pool.dev_tokens)
+        return dict(step, temps=temps, top_ks=top_ks, keys=pool.keys, live=jnp.asarray(live))
 
-    def _carried(self, pool: "_Pool", carry: "dict | str | None", next_tokens) -> None:
+    def _carried(self, pool: "_Pool", carry: "dict | str | None", handed, block=None) -> None:
         """After a launch of a chunk program that took ``pool``'s decode rows:
-        the pool's next input tokens are the program's, and a step it carried
+        the pool's next input is the program's (``handed``, the next tokens;
+        of a pool that generates by blocks ``block``, the next block state,
+        and ``handed`` the slots' [slots, B + 2] rows), and a step it carried
         goes on ``pool.inflight`` with its binding as a decode launch's does
         (its routing counts are among the chunk program's). A launch of the
         loop's that carried none is counted by what kept the step from riding
         (``carry`` is then that one of ``DEAD_CAUSES``): its rows ran dead."""
-        pool.dev_tokens = next_tokens
+        if pool.block_length:
+            pool.block = block
+        else:
+            pool.dev_tokens = handed
         if not isinstance(carry, dict):
             if carry is not None:
                 self._count({"decode_steps_dead_in_chunk:" + carry: 1})
             return
         try:
-            next_tokens.copy_to_host_async()
+            handed.copy_to_host_async()
         except Exception:  # noqa: BLE001
             pass
-        pool.inflight.append((next_tokens, carry, None))
+        pool.inflight.append((handed, carry, None))
         pool.step_carried = True
         self._count({**self._decode_counts(pool, carry, 1), "decode_steps_in_chunk": 1})
 
@@ -2192,8 +2280,8 @@ class JaxEngine:
                                self.params, ones, *args, **lora_kw)
         if not takes:
             return out
-        ones, next_tokens, pool.cache, pool.keys = out
-        self._carried(pool, carry, next_tokens)
+        ones, handed, pool.cache, pool.keys, *block = out
+        self._carried(pool, carry, handed, *block)
         return ones
 
     def _launch_final_chunk(self, pool: "_Pool", adm: _Admission,
@@ -2283,8 +2371,8 @@ class JaxEngine:
                 ("chunk_final", pool.stripe_len, toks.shape[1]), "_chunk_final_jit",
                 self.params, pool.cache, one, *args, **lora_kw)
         if rode:  # the program set the slot's key and next input token itself
-            next_tokens, pool.keys = rode
-            self._carried(pool, carry, next_tokens)
+            handed, pool.keys, *block = rode
+            self._carried(pool, carry, handed, *block)
             return first_tok, stats, one
         with tracing.annotate("engine.slot_set"):  # the slot's key and next input token
             pool.keys = self._set_key_jit(pool.keys, slot_dev, new_key)

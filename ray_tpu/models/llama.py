@@ -1408,7 +1408,10 @@ def prefill(
     ``beside``: ``(cache, tokens [B2], live [B2] or None)``, the rows of a
     decode step that ride through the same read of the weights
     (``models/patterned.py decode_forward``); the result then ends with their
-    ``decode_step``: ``(.., cache, logits [B2, vocab], their cache)``.
+    ``decode_step``: ``(.., cache, logits [B2, vocab], their cache)``. Of a
+    model that generates by blocks ``(cache, tokens [B2, T], live, commit
+    [B2])``, the rows of a block step; the result then ends with their
+    ``block_forward``: ``(.., logits [B2 * T, vocab], their cache)``.
 
     ``start_pos`` [B]: absolute position of tokens[:, 0] — the SUFFIX
     prefill used by prefix caching and chunked admission (the cache already
@@ -1429,8 +1432,8 @@ def prefill(
         start_pos=start_pos, beside=beside,
     )
     cache["length"] = start_pos + lengths
-    if rode:
-        rode = [rode[0][:, -1], rode[1]]
+    if rode:  # a token a row: its one position; a block a row: a row a position
+        rode = [rode[0][0] if len(beside) == 4 else rode[0][:, -1], rode[1]]
     if not with_logits:
         return (None, cache, *rode)
     return (logits[:, 0], cache, *rode)
@@ -1456,6 +1459,53 @@ def block_schedule(step, steps, block_length: int):
     ``block_length % steps`` steps. Integers or arrays of them (NumPy's or
     JAX's alike: the engine's host and its program both ask)."""
     return block_length // steps + (step < block_length % steps)
+
+
+def block_forward(params, cache, tokens, commit, cfg: LlamaConfig):
+    """The forward of ``block_step``: tokens [slots, B], each slot's block at
+    positions ``[length, length + B)`` -> (logits, a row a position [slots * B,
+    V]: the head's own tiles; the cache, a slot's length ``B`` further where
+    ``commit`` [slots])."""
+    B = cfg.block_length
+    if not B or tokens.shape[1] != B:
+        raise ValueError(f"block_step: a model with block_length={B} and tokens [slots, {B}]")
+    positions = cache["length"][:, None] + jnp.arange(B, dtype=jnp.int32)[None, :]
+    logits, cache = decode_forward(params, cache, tokens, positions, cfg, block_commit=commit)
+    return logits[0], cache
+
+
+def block_unmask(rows, tokens, masked, n_unmask, cfg: LlamaConfig, sample=None):
+    """What a denoise forward does behind its logits (``block_step``): ``rows``
+    [slots * B, V] float32, a row a position of the blocks ``tokens`` [slots,
+    B] -> (the blocks after the step, which of them is still masked, the
+    logits with the mask token's column at minus infinity). One function for
+    the block step launched alone and for one that rode through a prompt's
+    chunk (``llm/engine.py programs``)."""
+    B = cfg.block_length
+    with scope("sampling"):
+        with scope("confidence"):
+            # one column written where the logits lie: a select over every
+            # logit was two more passes over the 155 MB of them
+            rows = jax.lax.dynamic_update_slice(
+                rows, jnp.full((rows.shape[0], 1), -jnp.inf, rows.dtype), (0, cfg.mask_token_id))
+            x0 = (jnp.argmax(rows, -1) if sample is None else sample(rows)).astype(jnp.int32)
+            top = rows.max(axis=-1)
+            norm = jnp.exp(rows - top[:, None]).sum(axis=-1)
+            chosen = jnp.take_along_axis(rows, x0[:, None], axis=-1)[:, 0]
+            x0 = x0.reshape(tokens.shape)
+            confidence = (jnp.exp(chosen - top) / norm).reshape(tokens.shape)
+        with scope("unmask"):
+            # a masked position's rank among the slot's masked ones, by
+            # confidence, ties to the lower position: those before it
+            c = jnp.where(masked, confidence, -1.0)
+            at = jnp.arange(B)
+            before = (c[:, None, :] > c[:, :, None]) | (
+                (c[:, None, :] == c[:, :, None]) & (at[None, None, :] < at[None, :, None]))
+            rank = before.sum(axis=-1)
+            take = masked & ((rank < n_unmask[:, None]) | (confidence > cfg.confidence_threshold))
+            tokens = jnp.where(take, x0, tokens)
+            masked = masked & ~take
+    return tokens, masked, rows
 
 
 def block_step(
@@ -1488,33 +1538,6 @@ def block_step(
     Returns (the block after the step [slots, B], which of it is still masked
     [slots, B], the logits [slots, B, V] where ``with_logits`` else None, the
     cache)."""
-    B = cfg.block_length
-    if not B or tokens.shape[1] != B:
-        raise ValueError(f"block_step: a model with block_length={B} and tokens [slots, {B}]")
-    positions = cache["length"][:, None] + jnp.arange(B, dtype=jnp.int32)[None, :]
-    logits, cache = decode_forward(params, cache, tokens, positions, cfg, block_commit=commit)
-    rows = logits[0]  # a row a position, [slots * B, V]: the head's own tiles
-    with scope("sampling"):
-        with scope("confidence"):
-            # one column written where the logits lie: a select over every
-            # logit was two more passes over the 155 MB of them
-            rows = jax.lax.dynamic_update_slice(
-                rows, jnp.full((rows.shape[0], 1), -jnp.inf, rows.dtype), (0, cfg.mask_token_id))
-            x0 = (jnp.argmax(rows, -1) if sample is None else sample(rows)).astype(jnp.int32)
-            top = rows.max(axis=-1)
-            norm = jnp.exp(rows - top[:, None]).sum(axis=-1)
-            chosen = jnp.take_along_axis(rows, x0[:, None], axis=-1)[:, 0]
-            x0 = x0.reshape(tokens.shape)
-            confidence = (jnp.exp(chosen - top) / norm).reshape(tokens.shape)
-        with scope("unmask"):
-            # a masked position's rank among the slot's masked ones, by
-            # confidence, ties to the lower position: those before it
-            c = jnp.where(masked, confidence, -1.0)
-            at = jnp.arange(B)
-            before = (c[:, None, :] > c[:, :, None]) | (
-                (c[:, None, :] == c[:, :, None]) & (at[None, None, :] < at[None, :, None]))
-            rank = before.sum(axis=-1)
-            take = masked & ((rank < n_unmask[:, None]) | (confidence > cfg.confidence_threshold))
-            tokens = jnp.where(take, x0, tokens)
-            masked = masked & ~take
+    rows, cache = block_forward(params, cache, tokens, commit, cfg)
+    tokens, masked, rows = block_unmask(rows, tokens, masked, n_unmask, cfg, sample)
     return tokens, masked, (rows.reshape(tokens.shape + rows.shape[1:]) if with_logits else None), cache

@@ -2148,16 +2148,19 @@ class _Rows:
     ``_cache_reader`` or ``_latent_reader``), chosen from its own shapes."""
 
     def __init__(self, cfg, params, kinds, cache, tokens, positions, valid, start_pos,
-                 second: bool = False, block: bool = False):
+                 second: bool = False, commit=None):
         self.cache, self.tokens, self.positions, self.valid = cache, tokens, positions, valid
         self.second = second
+        # [B] where each row is one block of a model that generates by blocks:
+        # the rows whose length advances by the block (``decode_forward``)
+        self.commit = commit
         self.B, self.T = tokens.shape
         self.write = _cache_writer(cfg, cache["k"].shape[3], positions, valid, start_pos)
         self.select = None  # an indexed latent layer's choice of positions
         if "latent" in kinds:  # every layer is latent (``plan``); by kind
             self.read, self.select, self.from_latent = _latent_reader(cfg, params, cache, positions)
         else:
-            self.read = _cache_reader(cfg, params, cache, positions, kinds, block)
+            self.read = _cache_reader(cfg, params, cache, positions, kinds, commit is not None)
             self.from_latent = dict.fromkeys(kinds, False)
 
     def real(self):
@@ -2257,6 +2260,16 @@ def decode_forward(
     block's keys and values are written either way (the read needs them),
     behind a length that did not move nothing reads them.
 
+    Such a model's second set is a block a row as well: ``beside`` is then
+    ``(cache, tokens [B2, T], live [B2] or None, commit [B2])``, the pool's
+    block step beside a prompt's chunk. The rows stand at ``length +
+    arange(T)``, are written and read as ``block_step``'s are (the folded
+    decode kernel, a stripe read once for the block's queries, under
+    ``beside/attn_core/block``), and a row's length advances by ``T`` where it
+    commits *and* is live: a row that is not live writes its block behind a
+    length that did not move, as a forward that does not commit does.
+    ``logits2`` is then a row a position, ``[1, B2 * T, V]``.
+
     ``loras``/``adapter_ids``: stacked LoRA adapters + per-sequence adapter
     index (0 = base), over layers that are alike.
     ``with_logits=False`` (a prompt's middle chunk) only extends the cache
@@ -2284,12 +2297,20 @@ def decode_forward(
         )
     with scope("embed"):
         x = params["embed"][
-            _join([tokens] if beside is None else [tokens, beside[1][:, None]])
+            _join([tokens] if beside is None else [
+                tokens, beside[1] if len(beside) == 4 else beside[1][:, None]])
         ].astype(cfg.dtype)
         x = _times(x, cfg.embedding_multiplier)
     sets = [_Rows(cfg, params, kinds, cache, tokens, positions, valid, start_pos,
-                  block=block_commit is not None)]
-    if beside is not None:
+                  commit=block_commit)]
+    if beside is not None and len(beside) == 4:  # a block a row: written whole, live or not
+        cache2, tokens2, live, commit = beside
+        with scope("beside"):
+            at = cache2["length"][:, None] + jnp.arange(tokens2.shape[1], dtype=jnp.int32)[None, :]
+            commit = commit if live is None else commit & live
+        sets.append(_Rows(cfg, params, kinds, cache2, tokens2, at, None, None, second=True,
+                          commit=commit))
+    elif beside is not None:
         cache2, tokens2, live = beside
         sets.append(_Rows(cfg, params, kinds, cache2, tokens2[:, None], cache2["length"][:, None],
                           None if live is None else live[:, None], None, second=True))
@@ -2387,7 +2408,7 @@ def decode_forward(
                         chosen = (rows.select(index, written["k_index"], lay),)
                 # (a block step's read has a name of its own under ``attn_core``)
                 with rows.scope(), scope("attn_core"), (
-                        lay.inner_scope() if block_commit is None else scope("block")):
+                        lay.inner_scope() if rows.commit is None else scope("block")):
                     attn.append(rows.read(qj, ck_all, cv_all, lay, *chosen))
                 new_kv.append({**kv[j], names[0]: ck_all, names[1]: cv_all, **written})
             kv = tuple(new_kv)
@@ -2409,8 +2430,8 @@ def decode_forward(
     for rows, written, leaves in zip(sets, kv, state):
         grew = rows.T if rows is sets[0] or rows.valid is None else rows.valid.sum(
             axis=1, dtype=jnp.int32)
-        if block_commit is not None:
-            grew = jnp.where(block_commit, rows.T, 0)
+        if rows.commit is not None:
+            grew = jnp.where(rows.commit, rows.T, 0)
         new_cache = {**written, "length": rows.cache["length"] + grew, **leaves}
         _ride_stats(rows.cache, new_cache, stats)
         new_caches.append(new_cache)
@@ -2421,12 +2442,12 @@ def decode_forward(
         # the one requested hidden state a sequence BEFORE the vocab
         # projection: [B, T, e] -> [B, 1, e]
         heads[0] = jnp.take_along_axis(heads[0], logits_at[:, None, None], axis=1)
+    # a block's rows, a row a position: the head's [1, B * T, V] lies in whole
+    # tiles of 8 rows, where [B, T, V] pads each row's T positions to 8 and is relaid
+    heads = [h if h is None or rows.commit is None else h.reshape((1, -1) + h.shape[2:])
+             for rows, h in zip(sets, heads)]
     if not with_logits:
         heads = heads[1:]
-    if block_commit is not None:
-        # a row a position: the head's [1, B * T, V] lies in whole tiles of 8
-        # rows, where [B, T, V] pads each row's T positions to 8 and is relaid
-        heads = [h.reshape((1, -1) + h.shape[2:]) for h in heads]
     logits = []
     if heads:
         # a middle chunk's head is the second set's alone

@@ -214,6 +214,14 @@ def _sparse_latent_cut():
         n_layers=5, moe_experts_held=16, vocab_size=19008, max_seq_len=24576)
 
 
+def _block_diffusion_cut():
+    """The cut the cell ``sdar-30b-a3b-serve-block-chat`` serves: layers 0-5
+    of 48, all 128 experts, the whole vocabulary."""
+    from ray_tpu.models.llama import LlamaConfig
+
+    return LlamaConfig.sdar_30b_a3b(n_layers=6, max_seq_len=4096)
+
+
 def _engine_programs(served, one_chip, rows=1):
     """The engine's own program bodies at a serving cell's shapes, as
     ``JaxEngine._compile`` jits them: name -> (function, donated, described

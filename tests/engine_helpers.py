@@ -19,11 +19,14 @@ FAMILIES = {
     "routed-window": dict(model_id="laguna-tiny"),
     "state-space": dict(model_id="nemotron-tiny"),
     "latent": dict(model_id="kanana-tiny"),
+    # generation by diffusion over blocks of 4: the pool's step is a forward of a block a slot
+    "blocks": dict(model_id="sdar-tiny"),
 }
 # the families whose pool carries its decode step in a chunk launch: every
 # stack does, layers alike under one loop or several traced bodies, with or
-# without a state a slot; a latent pool does not (``JaxEngine.__init__``)
-CARRYING = ("dense", "routed", "routed-window", "state-space")
+# without a state a slot, and a pool that generates by blocks (its step a
+# forward of a block a slot); a latent pool does not (``JaxEngine.__init__``)
+CARRYING = ("dense", "routed", "routed-window", "state-space", "blocks")
 # ``tests/test_chunk_rows*.py`` knew this family as "patterned-moe": the cases keep that name
 ROUTED_WINDOW = pytest.param("routed-window", id="patterned-moe")
 

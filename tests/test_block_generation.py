@@ -3,11 +3,13 @@ SDAR's): the program against the plain reference on seeded weights, the
 unmasking schedule, the engine's loop over blocks, and the paths that refuse
 the model by name."""
 
+import contextlib
 import copy
 import dataclasses
 import functools
 import json
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +22,7 @@ from benchmark.kinds.block_closed_loop import expected_forwards
 from ray_tpu.llm import EngineConfig, JaxEngine, LLMConfig, ModelConfig, SamplingParams
 from ray_tpu.models import llama
 from ray_tpu.models.llama import LlamaConfig, block_schedule
+from tests.engine_helpers import decoding
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -144,6 +147,125 @@ def test_ties_go_to_the_lower_position_and_the_threshold_unmasks_beyond_the_sche
         np.asarray(new)[0][~np.asarray(still)[0]])
 
 
+# ------------------------------------- the block step beside a prompt's chunk
+
+
+@pytest.fixture(scope="module")
+def pool(tiny):
+    """(the engine's program bodies, a pool's cache of four slots behind
+    prompts of 8, 16, 20 and 12 tokens, its block state: a block half
+    unmasked at step 1 of 2, a clean one that commits, one of masks at step 0
+    of 4, a tenant's leftovers; the sampler's arrays, a prompt of 24)."""
+    from ray_tpu.llm.engine import programs
+
+    _, cfg, params, _ = tiny
+    fns = programs(cfg)
+    rng = np.random.default_rng(17)
+    lens = np.asarray([8, 16, 20, 12], np.int32)
+    _, cache = llama.prefill(params, llama.init_kv_cache(cfg, 4, 64),
+                             jnp.asarray(rng.integers(0, 256, (4, 20))), cfg,
+                             lengths=jnp.asarray(lens), with_logits=False)
+    mask = cfg.mask_token_id
+    block = dict(
+        tokens=jnp.asarray([[5, mask, 9, mask], [1, 2, 3, 4], [mask] * 4, [7, mask, mask, mask]],
+                           jnp.int32),
+        masked=jnp.asarray([[False, True, False, True], [False] * 4, [True] * 4,
+                            [False, True, True, True]]),
+        step=jnp.asarray([1, 2, 0, 1], jnp.int32), steps=jnp.asarray([2, 2, 4, 3], jnp.int32))
+    rows = dict(block=block, temps=jnp.asarray([0.0, 0.9, 0.7, 0.0], jnp.float32),
+                top_ks=jnp.asarray([1, 8, 4, 1], jnp.int32),
+                keys=jax.random.split(jax.random.PRNGKey(9), 4))
+    return fns, cache, rows, rng.integers(0, 256, 24).astype(np.int32)
+
+
+def _chunk(prompt, at, width=8):
+    piece = prompt[at:at + width]
+    return (jnp.asarray(piece[None]), jnp.asarray([len(piece)], jnp.int32),
+            jnp.asarray([at], jnp.int32))
+
+
+def _assert_the_live_rows_stepped_and_the_others_stayed(got, want, was, live, slots=range(4)):
+    """``got``, ``want``, ``was``: (hand-outs or None, cache, block state,
+    keys) of the carrying launch, of ``block_step`` alone, and as they went
+    in. A live row's are the step's; a row that is not live keeps its block
+    state, its key and its length bit for bit, and its block's keys and values
+    lie behind a length that did not move."""
+    for slot in slots:
+        side = want if live[slot] else was
+        if live[slot]:
+            np.testing.assert_array_equal(got[0][slot], want[0][slot])
+        for name in was[2]:
+            np.testing.assert_array_equal(got[2][name][slot], side[2][name][slot])
+        np.testing.assert_array_equal(got[3][slot], side[3][slot])
+        assert int(got[1]["length"][slot]) == int(side[1]["length"][slot])
+        for name in ("k", "v"):  # every forward writes its block: the step's bytes either way
+            np.testing.assert_allclose(got[1][name][:, slot], want[1][name][:, slot],
+                                       atol=2e-5, rtol=2e-5)
+            held = int(was[1]["length"][slot])
+            np.testing.assert_array_equal(np.asarray(got[1][name])[:, slot, :, :held],
+                                          np.asarray(was[1][name])[:, slot, :, :held])
+
+
+@pytest.mark.parametrize("live", [(True, True, False, True), (False,) * 4, (True,) * 4],
+                         ids=["one-row-dead", "none-live", "all-live"])
+def test_a_carrying_middle_chunk_is_the_chunk_and_the_block_step_of_the_live_rows(
+        tiny, pool, live):
+    """``chunk_mid`` with the pool's cache, block state and sampler arrays
+    against ``chunk_mid`` and ``block_step`` apart: the stripe is the chunk's,
+    each live row's hand-out, next block state, key, length and block of keys
+    and values are the step's (a commit, a denoise forward under a schedule of
+    2 and one of 3, greedy and drawn), and a row that is not live leaves its
+    state, key and ``cache["length"]`` as they were."""
+    _, cfg, params, _ = tiny
+    fns, cache, rows, prompt = pool
+    one = fns["new_stripe"](64)
+    want_one, = fns["chunk_mid"](params, (dict(one),), *_chunk(prompt, 0))
+    out, want_cache, want_block, want_keys, _ = fns["block_step"](
+        params, dict(cache), rows["block"], rows["temps"], rows["top_ks"], rows["keys"])
+    (got_one,), handed, got_cache, got_keys, got_block = fns["chunk_mid"](
+        params, (dict(one),), *_chunk(prompt, 0), dict(cache),
+        dict(rows, live=jnp.asarray(live)))
+    assert handed.shape == out.shape == (4, cfg.block_length + 2)
+    assert list(np.asarray(out)[:, cfg.block_length]) == [0, 1, 0, 0]  # the clean block commits
+    for name in ("k", "v", "length"):
+        np.testing.assert_allclose(got_one[name], want_one[name], atol=2e-5, rtol=2e-5)
+    _assert_the_live_rows_stepped_and_the_others_stayed(
+        (handed, got_cache, got_block, got_keys), (out, want_cache, want_block, want_keys),
+        (None, cache, rows["block"], rows["keys"]), live)
+    np.testing.assert_array_equal(  # the routing counts are of all the launch's rows
+        got_one["moe_stats"][1], want_one["moe_stats"][1] + 4 * cfg.block_length * cfg.moe_top_k * (
+            int(want_one["moe_stats"][0])))
+
+
+def test_a_carrying_final_chunk_activates_a_slot_that_is_no_live_row_of_its_launch(tiny, pool):
+    """``chunk_final`` with the pool's rows into slot 2: the slot holds the
+    prompt's stripe and length and the request's key, its block state waits
+    for ``seed_block`` as it was, and the other rows' forward is the step's."""
+    _, cfg, params, _ = tiny
+    fns, cache, rows, prompt = pool
+    one, = fns["chunk_mid"](params, (fns["new_stripe"](64),), *_chunk(prompt, 0, 16))
+    final = (*_chunk(prompt, 16), jnp.int32(2), jnp.float32(0.8), jnp.int32(5),
+             jax.random.PRNGKey(77))
+    live = (True, True, False, True)
+    out, step_cache, want_block, want_keys, _ = fns["block_step"](
+        params, dict(cache), rows["block"], rows["temps"], rows["top_ks"], rows["keys"])
+    _, _, want_cache, _, _ = fns["chunk_final"](params, dict(step_cache), dict(one), *final)
+    tok, key, got_cache, _, stats, handed, got_keys, got_block = fns["chunk_final"](
+        params, dict(cache), dict(one), *final, dict(rows, live=jnp.asarray(live)))
+    assert int(tok) == 0 and stats.shape[0] == 2  # the middle chunks' counts, the launch's
+    np.testing.assert_array_equal(key, jax.random.PRNGKey(77))
+    np.testing.assert_array_equal(got_keys[2], jax.random.PRNGKey(77))
+    assert list(np.asarray(got_cache["length"])) == [8, 20, 24, 12]
+    for name in ("k", "v"):
+        np.testing.assert_allclose(got_cache[name][:, 2], want_cache[name][:, 2],
+                                   atol=2e-5, rtol=2e-5)
+    for name in rows["block"]:
+        np.testing.assert_array_equal(got_block[name][2], rows["block"][name][2])
+    _assert_the_live_rows_stepped_and_the_others_stayed(
+        (handed, got_cache, got_block, got_keys), (out, step_cache, want_block, want_keys),
+        (None, cache, rows["block"], rows["keys"]), live, slots=(0, 1, 3))
+
+
 # ------------------------------------------------------------------ the engine
 
 
@@ -249,6 +371,69 @@ def test_requests_of_different_steps_share_a_launch_and_a_seed_repeats(engine):
     assert grown["decode_slot_steps"] >= sum(w[0] + w[1] for w in want)
 
 
+@contextlib.contextmanager
+def _steps_launched_alone(eng):
+    """While open, ``eng``'s pools do not carry: every chunk launch is the
+    chunk's alone and every block step a ``jit_block_step`` (the forms that
+    take no rows are compiled at their first launch; the carrying ones come
+    back at the end). The loop holds no request on the way in or out."""
+    assert not any(p.admitting or p.inflight or any(p.slots) for p in eng._pools)
+    chunks = {form: program for form, program in eng._programs.items()
+              if form[0].startswith("chunk_")}
+    for form in chunks:
+        del eng._programs[form]
+    for p in eng._pools:
+        p.carries = False
+    try:
+        yield
+    finally:
+        while any(p.admitting or p.inflight or p.first_pending for p in eng._pools):
+            time.sleep(0.001)  # the loop thread drains what ran ahead
+        for p in eng._pools:
+            p.carries = True
+        eng._programs.update(chunks)
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "seeded"])
+def test_chunk_launches_that_carry_give_the_tokens_of_steps_launched_alone(engine, temperature):
+    """A request generates a long answer while three more are admitted, their
+    prompts of one, two and three chunks and of every tail, at 4 and at 2
+    denoising steps in one launch: the chunk launches carry the pool's block
+    step, and each request gets, token for token, what it gets from the same
+    engine with every step launched alone; the forwards, the commits and the
+    positions unmasked are the schedule's either way."""
+    cases = [(9, 24, 4, 31), (34, 12, 2, 32), (47, 9, 4, 33), (16, 10, 2, 34)]
+
+    def serve():
+        before = _counters(engine)
+        sps = [SamplingParams(max_tokens=answer, ignore_eos=True, denoise_steps=steps,
+                              temperature=temperature, seed=seed)
+               for _, answer, steps, seed in cases]
+        first = decoding(engine, _prompt(cases[0][0], 7), sps[0])
+        rest = [engine.submit(prompt_token_ids=_prompt(n, 7), sampling_params=sp)
+                for (n, *_), sp in zip(cases[1:], sps[1:])]
+        for req in (first, *rest):
+            engine._await_done(req)
+            assert req.error is None
+        return [list(r.out_tokens) for r in (first, *rest)], _grown(engine, before)
+
+    carried, grown = serve()
+    assert 0 < grown["decode_steps_in_chunk"] < grown["decode_steps"]
+    want = [_forwards(prompt, answer, steps) for prompt, answer, steps, _ in cases]
+    assert grown["block_forwards:denoise"] == sum(w[0] for w in want)
+    assert grown["block_forwards:commit"] == sum(w[1] for w in want)
+    assert grown["block_tokens_unmasked"] == sum(w[2] for w in want)
+    assert grown["block_tokens_emitted"] == sum(answer for _, answer, _, _ in cases)
+    with _steps_launched_alone(engine):
+        alone, grown_alone = serve()
+    assert "decode_steps_in_chunk" not in grown_alone
+    assert not any(k.startswith("decode_steps_dead_in_chunk") for k in grown_alone)
+    assert carried == alone and [len(t) for t in alone] == [answer for _, answer, _, _ in cases]
+    for name in ("block_forwards:denoise", "block_forwards:commit", "block_tokens_unmasked",
+                 "block_tokens_emitted"):
+        assert grown[name] == grown_alone[name]
+
+
 def test_streamed_and_unary_agree_and_a_fetch_brings_several_tokens(engine):
     sp = SamplingParams(max_tokens=10, ignore_eos=True, denoise_steps=2)
     unary = engine.generate(prompt_token_ids=_prompt(18), sampling_params=sp)
@@ -308,7 +493,12 @@ def probed(engine, tiny):
              "denoise_steps": [4, 2], "judged_forwards": 4}
     requests = kind.probe_requests(5, probe, 4)
     assert [len(r["ids"]) % 4 for r in requests] == [1, 0, 0, 3]
-    return requests, kind.through_engine(engine, requests)
+    before = _counters(engine)
+    got = kind.through_engine(engine, requests)
+    # the hand-outs the check judges came out of chunk launches too: the pool
+    # carries, and the long prompt's chunks ran beside the others' blocks
+    assert engine._pools[0].carries and _grown(engine, before)["decode_steps_in_chunk"] > 0
+    return requests, got
 
 
 def _first_taken(handed, mask_id):

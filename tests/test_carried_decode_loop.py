@@ -1,6 +1,8 @@
 """The loop that launches carrying chunks (``llm/engine.py
 _advance_admissions``): when a first token is taken, the counters of carrying
-launches and of those that took the rows and carried none, and the pool that
+launches and of those that took the rows and carried none (a pool that
+generates by blocks counts its block steps the same way, and hands
+``_take_blocks`` what its chunk launches handed out), and the pool that
 never carries (a latent pool launches the chunk
 alone). Requests admitted beside decoding rows, family by family:
 ``tests/test_carried_decode_beside.py``. The subject:
@@ -123,7 +125,8 @@ def test_the_counters_of_carrying_launches():
     assert a.error is None and b.error is None and len(a.out_tokens) == len(b.out_tokens) == 30
 
 
-def test_a_launch_that_takes_rows_and_carries_none_is_counted_by_its_cause():
+@pytest.mark.parametrize("family", ["dense", "blocks"])
+def test_a_launch_that_takes_rows_and_carries_none_is_counted_by_its_cause(family):
     """Every launch of a chunk program that takes the pool's rows runs them,
     and one that carries no step counts why (``decode_steps_dead_in_chunk``):
     no slot held a request, an earlier launch of the pass had carried the step
@@ -131,8 +134,9 @@ def test_a_launch_that_takes_rows_and_carries_none_is_counted_by_its_cause():
     run-ahead was full (``_drain`` leaves no more than ``decode_runahead`` steps
     in flight, so the loop's own passes never find it so: here two launches go
     undrained). ``decode_steps_in_chunk`` and the three causes are the
-    launches of the carrying forms, one ``engine.counts`` key each."""
-    eng = _engine("dense")
+    launches of the carrying forms, one ``engine.counts`` key each. A pool
+    that generates by blocks counts its block steps the same way."""
+    eng = _engine(family)
     eng.shutdown()  # the loop thread is gone: the stages are the test's
     pool = eng._pools[0]
     took = []  # launches that were handed the pool's rows, by program
@@ -184,6 +188,64 @@ def test_a_launch_that_takes_rows_and_carries_none_is_counted_by_its_cause():
     assert n["decode_steps_in_chunk"] + sum(
         v for k, v in n.items() if k.startswith("decode_steps_dead_in_chunk")) == len(took)
     assert all(r.error is None and len(r.out_tokens) == 40 for r in (a, b, c, d))
+
+
+def test_a_block_pools_chunk_launches_carry_its_block_step():
+    """The loop's stages by hand in a pool that generates by blocks. One
+    request generates; a second of three chunks is admitted: each of its chunk
+    launches carries one forward of every slot's block, ``_launch_decodes``
+    launches no ``block_step`` in those passes, and what the launch handed
+    out reaches ``_take_blocks`` as a step's own does ([slots, B + 2], the
+    binding the launch's). The pool's block state is the chunk program's; a
+    pass with no chunk runs ``block_step`` again."""
+    eng = _engine("blocks")
+    eng.shutdown()  # the loop thread is gone: the stages are the test's
+    pool = eng._pools[0]
+    B = pool.block_length
+    assert pool.carries and eng.get_stats()["pools"][0]["carries"] is True
+    rng = np.random.default_rng(4)
+    sp = SamplingParams(max_tokens=24, temperature=0.0, ignore_eos=True, denoise_steps=2)
+    steps, taken = [], []
+    inner, take = eng._block_step, eng._take_blocks
+    eng._block_step = lambda *a, **kw: steps.append(1) or inner(*a, **kw)
+
+    def taking(pool, arr, binding, *rest):
+        taken.append((arr.shape, sorted(binding)))
+        return take(pool, arr, binding, *rest)
+
+    eng._take_blocks = taking
+    a = eng.submit(prompt_token_ids=[int(t) for t in rng.integers(1, 250, 6)], sampling_params=sp)
+    _pass(eng)  # its final chunk: no slot holds a request yet
+    assert pool.slots[0] is a and eng._n["decode_steps_in_chunk"] == 0
+    assert eng._n["decode_steps"] == len(steps) == 1 and eng._n["decode_steps_dead_in_chunk:no_slot"] == 1
+    b = eng.submit(prompt_token_ids=[int(t) for t in rng.integers(1, 250, 43)], sampling_params=sp)
+    for kind in ("mid", "mid", "final"):
+        before, state = _flat(eng), pool.block
+        a_blocks = (6 + len(a.out_tokens)) // B * B + B  # the length the loop holds at the launch
+        _pass(eng)
+        grew = _grew(eng, before)
+        assert grew["decode_steps_in_chunk"] == grew["decode_steps"] == grew["decode_slot_steps"] == 1
+        assert grew["decode_kv_tokens_global"] == a_blocks
+        assert grew["prefill_programs:" + kind] == 1
+        assert len(steps) == 1, "a block step beside a carrying chunk launch"
+        assert pool.block is not state and set(pool.block) == set(state)
+    assert eng._n["decode_steps_in_chunk"] == 3
+    before = _flat(eng)
+    _pass(eng)  # no chunk is due: the block step, both requests' rows
+    grew = _grew(eng, before)
+    assert len(steps) == 2 and "decode_steps_in_chunk" not in grew
+    assert grew["decode_steps"] == 1 and grew["decode_slot_steps"] == 2
+    while not (a.done.is_set() and b.done.is_set()):
+        _pass(eng)
+    while pool.inflight:
+        eng._drain()
+    n = eng._n
+    assert all(shape == (pool.n_slots, B + 2) for shape, _ in taken)
+    assert len(taken) == n["decode_steps"] and [0] in [slots for _, slots in taken]
+    # every forward a request was bound for is a denoise or a commit, whoever launched it
+    assert n["block_forwards:denoise"] + n["block_forwards:commit"] <= n["decode_slot_steps"]
+    assert n["block_tokens_emitted"] == n["tokens_generated"] == 48
+    assert a.error is None and b.error is None and len(a.out_tokens) == len(b.out_tokens) == 24
 
 
 def _plain_chunk_final(cfg):

@@ -28,6 +28,8 @@ FAMILIES = {
     "state-space": ("nemotron_tiny", "attn_core/ssm_mixer/ssm_step"),
     "delta-rule": ("solar_tiny", "attn_core/kda_mixer/kda_step"),
     "convolutional-tails": ("zaya_tiny", "attn_qkv/cca_conv"),
+    # generation by diffusion over blocks: the rows are a forward of a block a slot
+    "blocks": ("sdar_tiny", "attn_core/block"),
 }
 # the first 16 hex digits of the SHA-256 of each carrying form's StableHLO
 # without locations (``lower(..).as_text()``) as the parent commit (PR 52)
@@ -43,6 +45,9 @@ _PARENT = {
     ("delta-rule", "chunk_mid"): "37d39aafb2cac1f1",
     ("convolutional-tails", "chunk_final"): "a5c80515f49adf17",
     ("convolutional-tails", "chunk_mid"): "0d881a78bb076337",
+    # a block pool's chunk programs carry since PR 56: its own text, taken there
+    ("blocks", "chunk_final"): "9e7f8acc18a57b03",
+    ("blocks", "chunk_mid"): "6deeb8815fa2ef6e",
 }
 CASES = [(family, program) for family in FAMILIES for program in ("chunk_final", "chunk_mid")]
 
@@ -70,9 +75,14 @@ def _lowered(cfg, program, rows):
     sampler = (sds((SLOTS,), jnp.float32), i32(SLOTS), sds((SLOTS, 2), jnp.uint32))
     riders = dict(zip(("tokens", "temps", "top_ks", "keys", "live"),
                       (i32(SLOTS), *sampler, sds((SLOTS,), jnp.bool_))))
+    if cfg.block_length:  # the pool hands over its block state where the others hand over tokens
+        block = jax.eval_shape(lambda: fns["new_block"](SLOTS))
+        riders = dict(riders, block=block)
+        del riders["tokens"]
     chunk = (i32(1, CHUNK), i32(1), i32(1))
     args = {
         "decode_fn": (params, cache, i32(SLOTS), *sampler),
+        "block_step": (params, cache, riders.get("block"), *sampler),
         "chunk_mid": (params, (one,), *chunk, *((cache, riders) if rows else ())),
         "chunk_final": (params, cache, one, *chunk, i32(), sds((), jnp.float32), i32(),
                         sds((2,), jnp.uint32), *((riders,) if rows else ())),
@@ -149,7 +159,9 @@ def test_what_the_rows_run_alone_lies_under_beside_and_nothing_of_the_chunks_doe
     assert _under(ops, "sampling", False) == _under(alone, "sampling", False)
     if program == "chunk_mid":
         assert _under(ops, "kv_write", False) == _under(alone, "kv_write", False)
-        # nothing reads the chunk's rows behind the last layer: the head is the rows' alone
+    if program == "chunk_mid" or family == "blocks":
+        # nothing reads the chunk's rows behind the last layer (a prompt of a
+        # model that generates by blocks samples nothing): the head is the rows' alone
         assert _under(ops, "lm_head", True) and not _under(ops, "lm_head", False)
     else:  # a final chunk's head multiplies the prompt's last token and the rows as one matrix
         assert _under(ops, "lm_head", False) and not _under(ops, "lm_head", True)
@@ -159,6 +171,34 @@ def test_what_the_rows_run_alone_lies_under_beside_and_nothing_of_the_chunks_doe
         assert not [path for path in paths if scan in path]
     if family == "convolutional-tails":
         assert [path for _, path in ops if "cca_conv" in path and not carried.is_beside(path)]
+
+
+def _bare(path):
+    """A location's name path without the parts JAX writes for a traced
+    function (``jit(..)``) and without ``beside``."""
+    return "/".join(part for part in path.split("/")
+                    if part != carried.PART and not re.match(r"p?jit\(", part))
+
+
+@pytest.mark.parametrize("program", ["chunk_final", "chunk_mid"])
+def test_what_only_the_block_rows_run_is_named_as_in_the_block_step(program, lowered):
+    """A pool that generates by blocks: every operation under ``beside`` in a
+    carrying chunk program is, by operation and name, one ``jit_block_step``
+    runs under the name without that part (the rows' scatter, the folded read
+    under ``attn_core/block``, the head, ``sampling/confidence`` and
+    ``sampling/unmask``: one function behind the logits for both,
+    ``llm/engine.py programs block_after``), and none of the block step's own
+    scopes stands outside ``beside``."""
+    ops = _operations(lowered("sdar_tiny", program))
+    step = {(op, _bare(path)) for op, path in _operations(lowered("sdar_tiny", "block_step"))}
+    beside = {(op, _bare(path)) for op, path in ops if carried.is_beside(path)}
+    assert beside and beside <= step, sorted(beside - step)[:10]
+    for name in ("attn_core/block/decode_attention", "kv_write/scatter", "lm_head",
+                 "sampling/confidence", "sampling/unmask"):
+        assert any(name in path for _, path in beside), name
+    for name in ("attn_core/block", "lm_head", "sampling"):
+        assert not [path for _, path in ops if name in path and not carried.is_beside(path)], name
+    assert "beside" not in lowered("sdar_tiny", "block_step").as_text(debug_info=True)
 
 
 def test_a_middle_chunks_last_feed_forward_is_the_rows_alone_where_it_is_traced_on_its_own(lowered):
@@ -174,7 +214,7 @@ def test_a_middle_chunks_last_feed_forward_is_the_rows_alone_where_it_is_traced_
 
 
 @pytest.mark.parametrize("preset, program, rows", [
-    *((preset, "decode_fn", True) for preset, _ in FAMILIES.values()),
+    *((preset, "decode_fn", True) for preset, _ in FAMILIES.values() if preset != "sdar_tiny"),
     ("kanana_tiny", "chunk_mid", False), ("kanana_tiny", "chunk_final", False),
     ("kanana_tiny", "decode_fn", True),
 ])

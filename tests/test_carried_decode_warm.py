@@ -26,8 +26,10 @@ def test_an_engine_warms_one_form_of_each_program_and_a_burst_compiles_none(fami
         pool = eng._pools[0]
         mid, finals = eng._chunk_widths(pool)
         held = launched_forms(eng)
-        assert {name: held.get(name, 0) for name in ("chunk_mid", "chunk_final", "decode")} == {
-            "chunk_mid": pool.chunk_rows if mid else 0, "chunk_final": len(finals), "decode": 1}
+        # (a pool that generates by blocks steps by ``block_step`` where the others decode)
+        step = "block_step" if pool.block_length else "decode"
+        assert {name: held.get(name, 0) for name in ("chunk_mid", "chunk_final", step)} == {
+            "chunk_mid": pool.chunk_rows if mid else 0, "chunk_final": len(finals), step: 1}
         ready = eng.get_stats()["init"]["programs"]
         assert ready["compiled"] + ready["restored"] == len(eng._programs) and not ready["fallback"]
         rng = np.random.default_rng(3)

@@ -5,13 +5,16 @@
 block state) compiles, its attention one decode kernel whose rows are the
 block's four queries beside each key-value head's eight query heads; the chunk
 programs lower under the block mask, the final one with no head and no
-sampler."""
+sampler of its own; the final chunk that carries the pool's block step
+compiles with the rows' one folded kernel and copies no stripe, cache or
+bank."""
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from tests.chip_compile import (
+    _block_diffusion_cut as _cut,
     _decode_kernel_blocks,
     _served_programs,
     native_kernels,
@@ -20,12 +23,6 @@ from tests.chip_compile import (
 )
 
 SLOTS, STRIPE = 64, 4096
-
-
-def _cut():
-    from ray_tpu.models.llama import LlamaConfig
-
-    return LlamaConfig.sdar_30b_a3b(n_layers=6, max_seq_len=STRIPE)
 
 
 def _described(one_chip, tree):
@@ -64,6 +61,54 @@ def test_block_step_compiles_with_one_folded_kernel_and_no_copy_of_a_stripe_or_a
         assert any(scope in line for line in lines), scope
     whole = ("bf16[6,64,4,4096,128]", "bf16[6,128,2048,768]", "bf16[6,128,768,2048]",
              "bf16[768,2048,768]", "bf16[768,768,2048]")
+    assert [line.strip()[:120] for line in lines
+            if " copy(" in line and line.split(" = ", 1)[-1].startswith(whole)] == []
+
+
+def test_a_final_chunk_that_carries_the_block_step_holds_one_folded_kernel_and_copies_nothing(
+        one_chip, no_compile_cache, native_kernels):
+    """The engine's ``chunk_final`` of 256 tokens with the pool's block state
+    and sampler arrays (``llm/engine.py programs``: a forward of every slot's
+    block rides through the chunk's read of the banks): the rows' attention
+    is one folded decode kernel a layer, under ``beside/attn_core/block``, the
+    grouped matmuls are the chunk's three (the 256 block rows are rows of
+    them), and no operation yields a copy of a stripe, of the pool's cache or
+    of a bank: the slot's stripe goes into the pool the rows have written by
+    a plain update (``tests/test_chip_compile_carried_final.py``)."""
+    from ray_tpu.llm.engine import programs
+    from ray_tpu.models.patterned import moe_stats_names
+
+    cfg = _cut()
+    fns = programs(cfg)
+    served = _served_programs(cfg, SLOTS, STRIPE, one_chip)
+    params, cache, _ = served["decode_step"][1]
+    _, one, tokens, lengths, starts = served["chunk_mid"][1]
+
+    def sds(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    one = dict(one, moe_stats=sds(jnp.int32, len(moe_stats_names(cfg))))
+    rows = dict(block=_described(one_chip, jax.eval_shape(lambda: fns["new_block"](SLOTS))),
+                temps=sds(jnp.float32, SLOTS), top_ks=sds(jnp.int32, SLOTS),
+                keys=sds(jnp.uint32, SLOTS, 2), live=sds(jnp.bool_, SLOTS))
+    args = (params, cache, one, tokens, lengths, starts, sds(jnp.int32), sds(jnp.float32),
+            sds(jnp.int32), sds(jnp.uint32, 2), rows)
+    assert _decode_kernel_blocks(fns["chunk_final"], *args) == [("decode_attention", 256)]
+    compiled = jax.jit(fns["chunk_final"], donate_argnums=(1, 2)).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    # weights 8.72 GB, the pool's stripes 3.22 GB, a scratch stripe 50 MB
+    assert 11.8e9 < memory.argument_size_in_bytes < 12.2e9
+    assert memory.temp_size_in_bytes < 0.3e9  # the rows' float32 logits and the chunk's own
+    lines = compiled.as_text().splitlines()
+    kernels = [line for line in lines if 'custom_call_target="tpu_custom_call"' in line]
+    assert len([k for k in kernels if "beside/attn_core/block/decode_attention" in k]) == 1
+    assert len([k for k in kernels if "decode_attention" in k]) == 1
+    assert len([k for k in kernels if "moe_ffn/experts" in k]) == 3
+    for scope in ("beside/kv_write", "beside/lm_head", "beside/sampling/confidence",
+                  "beside/sampling/unmask"):
+        assert any(scope in line for line in lines), scope
+    whole = ("bf16[6,64,4,4096,128]", "bf16[6,1,4,4096,128]", "bf16[6,128,2048,768]",
+             "bf16[6,128,768,2048]", "bf16[768,2048,768]", "bf16[768,768,2048]")
     assert [line.strip()[:120] for line in lines
             if " copy(" in line and line.split(" = ", 1)[-1].startswith(whole)] == []
 

@@ -9,7 +9,9 @@ the parent's text, digest for digest (Granite's 64-wide heads never reach the
 kernel on the chip: ``tests/test_granite.py``; its 40 layers lower in a minute,
 so it is not here); the two at two key-value heads (ZAYA1-8B, Nemotron-3-Super) walk
 512-position blocks where the parent walked 128. Compilation of the moved
-programs for a described v5e: ``tests/test_chip_compile_zaya.py``."""
+programs for a described v5e: ``tests/test_chip_compile_zaya.py``. Since PR 56
+the block cell's carrying chunk programs (SDAR-30B-A3B-Chat: the pool's block
+step rides through them) are pinned here too, at their own text."""
 
 import hashlib
 
@@ -20,6 +22,7 @@ import pytest
 from ray_tpu.llm.engine import programs
 from ray_tpu.models.llama import init_kv_cache, init_params
 from tests.chip_compile import (
+    _block_diffusion_cut,
     _convolved_attention_cut,
     _delta_rule_cut,
     _served_config,
@@ -37,6 +40,7 @@ CELLS = {
     "dots3-note-serve-docs-shared": (_sparse_latent_cut, 16, 24576),
     "nemotron3-super-serve-chat": (_state_space_cut, 64, 2048),
     "zaya1-8b-serve-long-chat": (_convolved_attention_cut, 64, 4608),
+    "sdar-30b-a3b-serve-block-chat": (_block_diffusion_cut, 64, 4096),
 }
 # the first 16 hex digits of the SHA-256 of each program's StableHLO without
 # locations (``lower(..).as_text()``) as the parent commit (PR 53) lowered it,
@@ -56,12 +60,20 @@ _PARENT = {
     ("zaya1-8b-serve-long-chat", "chunk_final"): "3a15e4d74eb12c90",
 }
 MOVED = ("nemotron3-super-serve-chat", "zaya1-8b-serve-long-chat")
+# the block pool's chunk programs carry its block step since PR 56 (the rows a
+# block wide: ``llm/engine.py programs``): this checkout's own text, pinned so
+# that a later change to what they lower to shows here
+_BLOCKS = {
+    ("sdar-30b-a3b-serve-block-chat", "chunk_final"): "48a4e5837c05de7b",
+    ("sdar-30b-a3b-serve-block-chat", "chunk_mid"): "a9fe887b0a27645f",
+}
 
 
 def _lowered(cell, program):
     """The engine's ``program`` at ``cell``'s shape: every slot's decode step
-    with its sampler, or a 128-token final chunk of one prompt that carries
-    the pool's step."""
+    with its sampler, or a 128-token chunk of one prompt (the final one, or
+    of a block pool the one-row middle one too) that carries the pool's
+    step."""
     make, slots, stripe = CELLS[cell]
     cfg = make()
     fns, sds = programs(cfg), jax.ShapeDtypeStruct
@@ -75,8 +87,13 @@ def _lowered(cell, program):
         one = jax.eval_shape(lambda: fns["new_stripe"](stripe))
         riders = dict(zip(("tokens", "temps", "top_ks", "keys", "live"),
                           (i32(slots), *sampler, sds((slots,), jnp.bool_))))
-        args = (params, cache, one, i32(1, CHUNK), i32(1), i32(1), i32(), sds((), jnp.float32),
-                i32(), sds((2,), jnp.uint32), riders)
+        if cfg.block_length:  # its block state where the others hand over tokens
+            del riders["tokens"]
+            riders["block"] = jax.eval_shape(lambda: fns["new_block"](slots))
+        chunk = (i32(1, CHUNK), i32(1), i32(1))
+        args = (params, (one,), *chunk, cache, riders) if program == "chunk_mid" else (
+            params, cache, one, *chunk, i32(), sds((), jnp.float32), i32(), sds((2,), jnp.uint32),
+            riders)
     return jax.jit(fns[program]).lower(*args).as_text()
 
 
@@ -100,6 +117,16 @@ def test_two_key_value_heads_walk_512_position_blocks(cell, program):
     assert "tensor<2x2x512x128xbf16>" in text and "tensor<2x2x128x128xbf16>" not in text
 
 
+@pytest.mark.parametrize("cell, program", list(_BLOCKS))
+def test_the_block_cells_carrying_chunks_hold_one_folded_kernel_for_the_rows(cell, program):
+    """The kernel's double buffer of keys for the 64 slots' blocks (four
+    key-value heads: 256 positions a block of the walk) is in the interpreted
+    text once a layer loop, beside no buffer of another block."""
+    text = _lowered(cell, program)
+    assert _digest(text) == _BLOCKS[cell, program]
+    assert "tensor<2x4x256x128xbf16>" in text and "tensor<2x4x128x128xbf16>" not in text
+
+
 if __name__ == "__main__":  # ``python3 -m tests.test_decode_block_programs``: this checkout's digests
-    for key in _PARENT:
+    for key in (*_PARENT, *_BLOCKS):
         print(f'    {key}: "{_digest(_lowered(*key))}",', flush=True)
