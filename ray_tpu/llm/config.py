@@ -107,7 +107,7 @@ class ModelConfig:
     # "nemotron-3-super-120b-a12b" | "nemotron-tiny" | "solar-open2-250b" |
     # "solar-tiny" | "granite-4.0-h-micro" | "granite-tiny" | "zaya1-8b" |
     # "zaya-tiny" | "dots3-note-prev" | "dots3-tiny" | "sdar-30b-a3b-chat" |
-    # "sdar-tiny"
+    # "sdar-tiny" | "ouro-2.6b" | "ouro-tiny"
     model_id: str = "tiny"
     tokenizer: str = "byte"  # "byte" | transformers tokenizer path
     checkpoint_path: Optional[str] = None  # ray_tpu.train pytree checkpoint
@@ -156,6 +156,8 @@ def resolve_llama_config(model: "ModelConfig", engine: "EngineConfig", min_vocab
         "dots3-tiny": LlamaConfig.dots3_tiny,
         "sdar-30b-a3b-chat": LlamaConfig.sdar_30b_a3b,
         "sdar-tiny": LlamaConfig.sdar_tiny,
+        "ouro-2.6b": LlamaConfig.ouro_2_6b,
+        "ouro-tiny": LlamaConfig.ouro_tiny,
     }
     kw = dict(
         max_seq_len=engine.max_seq_len,
@@ -236,6 +238,22 @@ def refuse_blocks(cfg, module: str) -> None:
             f"{cfg.block_length}) is served on one device by llm/engine.py JaxEngine with "
             "tensor_parallel_degree=1; this path has no block step and does not carry a "
             "slot's block state"
+        )
+
+
+def refuse_looped(cfg, module: str) -> None:
+    """The same for a model whose stack runs several times a token
+    (``LlamaConfig.loop_passes``): its cache has a row of keys and values for
+    every pass and layer and its head reads the pass an exit gate picks, which
+    those copies of the cache's programs do not know, no rule places on a
+    mesh, and a hand-over made for a row a layer (``llm/disagg.py``) has not
+    been held to."""
+    if cfg.loop_passes > 1:
+        raise NotImplementedError(
+            f"{module}: a model whose stack runs several times a token (loop_passes="
+            f"{cfg.loop_passes}) is served on one device by llm/engine.py JaxEngine with "
+            "tensor_parallel_degree=1; this path has no rule for a cache row a pass and "
+            "layer, nor for the exit gate"
         )
 
 

@@ -20,8 +20,8 @@ from typing import Any, Optional
 import numpy as np
 
 from ray_tpu.llm.config import (
-    LLMConfig, SamplingParams, refuse_blocks, refuse_further_stripes, refuse_stateful,
-    resolve_llama_config,
+    LLMConfig, SamplingParams, refuse_blocks, refuse_further_stripes, refuse_looped,
+    refuse_stateful, resolve_llama_config,
 )
 
 
@@ -39,6 +39,7 @@ class PrefillWorker:
         refuse_stateful(model_cfg, "llm/disagg.py")
         refuse_further_stripes(model_cfg, "llm/disagg.py")
         refuse_blocks(model_cfg, "llm/disagg.py")
+        refuse_looped(model_cfg, "llm/disagg.py")
         # reuse the engine's model construction, not its slot loop
         self._engine_shell = JaxEngine.__new__(JaxEngine)
         self._engine_shell.config = llm_config
@@ -95,6 +96,7 @@ class DecodeWorker:
         refuse_stateful(model_cfg, "llm/disagg.py")
         refuse_further_stripes(model_cfg, "llm/disagg.py")
         refuse_blocks(model_cfg, "llm/disagg.py")
+        refuse_looped(model_cfg, "llm/disagg.py")
         shell = JaxEngine.__new__(JaxEngine)
         shell.config = llm_config
         shell.tokenizer = get_tokenizer(llm_config.model.tokenizer)

@@ -82,6 +82,8 @@ logger = logging.getLogger(__name__)
 _MOE_COUNTERS = ("moe_layer_steps", "moe_assignments", "moe_experts_touched",
                  "moe_max_expert_load_sum", "moe_assignments_held", "moe_passes")
 _MOE_PROGRAMS = ("decode", "chunk_mid", "chunk_final")
+# the most passes a looped model's exits are counted over (a label each)
+LOOP_PASSES_MAX = 8
 # why a chunk launch that takes the pool's decode rows carried no step
 DEAD_CAUSES = ("step_carried", "runahead_full", "no_slot")
 COUNTERS = (
@@ -173,10 +175,22 @@ COUNTERS = (
     # fetched with them. Every row a program routes counts, a dead slot's and
     # a padded chunk's too: they touch experts as live ones do
     *(f"{name}:{program}" for name in _MOE_COUNTERS for program in _MOE_PROGRAMS),
+    # a model whose stack runs several times a token (``LlamaConfig.loop_passes``;
+    # ``models/patterned.py LOOP_STATS``), as its programs hand them out beside
+    # their tokens: the forwards that reported (a decode step, a prompt's chunk
+    # with whatever step it carried), the passes their stacks ran
+    # (``loop_passes`` a forward while every pass is run: the name says
+    # ``stack`` because the engine loop's own passes are ``get_stats()["loop"]``'s),
+    # and the rows whose head read pass t: a final chunk's sampled row and a
+    # step's rows in the slots that held a request at its launch (``live``; a
+    # step of ``decode_steps`` > 1 counts every slot's). 0 for every other model
+    "loop_forwards", "loop_stack_passes",
+    *(f"loop_exit_rows:{t}" for t in range(LOOP_PASSES_MAX)),
 )
 _LABEL = {"requests_finished": "reason", "requests_failed": "stage",
           "prefill_chunks": "kind", "prefill_programs": "kind",
           "decode_steps_dead_in_chunk": "cause", "block_forwards": "kind",
+          "loop_exit_rows": "pass",
           **dict.fromkeys((*_MOE_COUNTERS, "prefill_query_tokens",
                            "prefill_attended_positions"), "program")}
 # request latencies: 1 ms to 200 s, a quarter more each bucket, so a median
@@ -514,7 +528,9 @@ def programs(cfg, decode_steps: int = 1) -> dict:
     from ray_tpu.models.llama import (
         block_forward, block_schedule, block_unmask, decode_step, init_kv_cache, prefill,
     )
-    from ray_tpu.models.patterned import moe_stats_names, state_cache_shapes, stripe_cache_shapes
+    from ray_tpu.models.patterned import (
+        LOOP_STATS, moe_stats_names, state_cache_shapes, stripe_cache_shapes,
+    )
     from ray_tpu.ops import topk
 
     # what a slot holds, each leaf with the slot on axis 1: its stripes of keys
@@ -522,7 +538,6 @@ def programs(cfg, decode_steps: int = 1) -> dict:
     # and convolution tails (``STATE_LEAVES``), which are stacked, unstacked,
     # zeroed and copied into a slot with the stripes
     slot_leaves = (*stripe_cache_shapes(cfg, 1, 1), *state_cache_shapes(cfg, 1))
-    n_stats = len(moe_stats_names(cfg))
 
     # one static top-K for the decode program AND the prefill first-token
     # sampler — they must agree or seeded runs diverge at token 2
@@ -531,13 +546,17 @@ def programs(cfg, decode_steps: int = 1) -> dict:
     # a model with routed experts: each program takes a zeroed
     # ``moe_stats`` leaf in with its cache and hands the counts out
     # beside its tokens (``models/llama.py _ride_stats``). A dense
-    # model's programs hand out ``None`` there, which is no output.
-    routed = bool(cfg.moe_experts)
+    # model's programs hand out ``None`` there, which is no output. A model
+    # whose stack runs several times a token hands out its passes and exits
+    # the same way, in a leaf of its own (``models/patterned.py _loop_stats``;
+    # it has no routed experts: ``plan``).
+    stats_leaf = "moe_stats" if cfg.moe_experts else "loop_stats" if cfg.loop_passes > 1 else None
+    n_stats = len(moe_stats_names(cfg)) if cfg.moe_experts else len(LOOP_STATS) + cfg.loop_passes
 
     def stats_in(cache):
-        if not routed:
+        if stats_leaf is None:
             return cache
-        return dict(cache, moe_stats=jnp.zeros((n_stats,), jnp.int32))
+        return {**cache, stats_leaf: jnp.zeros((n_stats,), jnp.int32)}
 
     def candidates(logits):
         """Of ``[..., V]`` fp32 logits the greedy token and the ``K`` largest
@@ -569,17 +588,21 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         return jax.vmap(draw)(*candidates(logits), temps, top_ks, keys)
 
     def decode_fn(params, cache, tokens, temps, top_ks, keys,
-                  loras=None, adapter_ids=None):
+                  loras=None, adapter_ids=None, live=None):
         """Decode + in-program sampling with per-slot PRNG keys: a request's
         key is its own, so what its seed draws does not depend on what else
         is in the batch. Its logits can: on a chip a row's numbers differ in
         the last bit by what shares its launch (the other slots of a step,
-        and in a pool whose chunk launches carry the step, a prompt's chunk)."""
+        and in a pool whose chunk launches carry the step, a prompt's chunk).
+        ``live`` [slots] (a looped model's alone): the slots whose exits count."""
+        cache = stats_in(cache)
+        if live is not None:
+            cache = {**cache, "loop_live": live}
         logits, cache = decode_step(
-            params, stats_in(cache), tokens, cfg,
+            params, cache, tokens, cfg,
             loras=loras, adapter_ids=adapter_ids,
         )
-        stats = cache.pop("moe_stats", None)
+        stats = cache.pop(stats_leaf, None)
         with jax.named_scope("sampling"):
             next_tokens, new_keys = sample_rows(logits, temps, top_ks, keys)
         return next_tokens, cache, new_keys, stats
@@ -655,7 +678,7 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         (``block_after``). Hands out the slots' [slots, B + 2] rows, then the
         cache, the next state, the keys and the routing counts."""
         logits, cache = block_forward(params, stats_in(cache), block["tokens"], commits(block), cfg)
-        stats = cache.pop("moe_stats", None)
+        stats = cache.pop(stats_leaf, None)
         out, block, keys = block_after(logits, block, temps, top_ks, keys)
         return out, cache, block, keys, stats
 
@@ -754,8 +777,8 @@ def programs(cfg, decode_steps: int = 1) -> dict:
                     k: jnp.concatenate([one[k] for one in ones], axis=0 if k == "length" else 1)
                     for k in (*slot_leaves, "length")
                 }
-            if routed:
-                stripes["moe_stats"] = ones[0]["moe_stats"]
+            if stats_leaf:
+                stripes[stats_leaf] = ones[0][stats_leaf]
         _, stripes, *rode = prefill(
             params, stripes, tokens, cfg, lengths=lengths, start_pos=starts,
             loras=loras, adapter_ids=adapter_ids, with_logits=False,
@@ -768,7 +791,7 @@ def programs(cfg, decode_steps: int = 1) -> dict:
                 out = tuple(
                     {**one, **{k: stripes[k][:, i:i + 1] for k in slot_leaves},
                      "length": stripes["length"][i:i + 1],
-                     **({"moe_stats": stripes["moe_stats"]} if routed and i == 0 else {})}
+                     **({stats_leaf: stripes[stats_leaf]} if stats_leaf and i == 0 else {})}
                     for i, one in enumerate(ones)
                 )
         if rows is None:
@@ -807,7 +830,7 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         hand-outs [slots, B + 2], keys, next block state)``: ``slot``'s key is
         the request's, and its block is seeded after the launch
         (``seed_block``) on the cache and the state handed back here."""
-        mid_stats = one.get("moe_stats")  # the prompt's middle chunks'
+        mid_stats = one.get(stats_leaf)  # the prompt's middle chunks'
         last_logits, one, *rode = prefill(
             params, one, tokens, cfg, lengths=length, start_pos=start,
             loras=loras, adapter_ids=adapter_ids, with_logits=not blocks,
@@ -815,7 +838,7 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         )
         if rode:
             logits, cache = rode
-        stats = one.pop("moe_stats", None)
+        stats = one.pop(stats_leaf, None)
         if stats is not None:  # rows: chunk_mid, chunk_final
             stats = jnp.stack([mid_stats, stats - mid_stats])
         total = start[0] + length[0]
@@ -844,8 +867,8 @@ def programs(cfg, decode_steps: int = 1) -> dict:
         """A zeroed scratch stripe (a program, so that it can be placed:
         ``JaxEngine._compile``)."""
         one = init_kv_cache(cfg, 1, stripe_len)
-        if routed:  # the prompt's chunks add their routing counts up in here
-            one["moe_stats"] = jnp.zeros((n_stats,), jnp.int32)
+        if stats_leaf:  # the prompt's chunks add their counts up in here
+            one[stats_leaf] = jnp.zeros((n_stats,), jnp.int32)
         return one
 
     @jax.named_scope("prefix_seed")
@@ -1107,11 +1130,17 @@ class JaxEngine:
         )
         sharded = ec.tensor_parallel_degree > 1 or ec.sequence_parallel_degree > 1
         if sharded or (self._mesh is not None and self._mesh.size > 1):
-            from ray_tpu.llm.config import refuse_blocks, refuse_latent, refuse_stateful
+            from ray_tpu.llm.config import (
+                refuse_blocks, refuse_latent, refuse_looped, refuse_stateful,
+            )
 
             refuse_latent(self.model_cfg, "llm/engine.py over a mesh")
             refuse_stateful(self.model_cfg, "llm/engine.py over a mesh")
             refuse_blocks(self.model_cfg, "llm/engine.py over a mesh")
+            refuse_looped(self.model_cfg, "llm/engine.py over a mesh")
+        if self.model_cfg.loop_passes > LOOP_PASSES_MAX:
+            raise ValueError(f"loop_passes={self.model_cfg.loop_passes}: the engine counts a "
+                             f"looped model's exits over at most {LOOP_PASSES_MAX} passes")
         if sharded:
             from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 
@@ -1466,6 +1495,11 @@ class JaxEngine:
         # (a no-LoRA configuration's program has no adapter arguments)
         adapters = {} if self.loras is None else dict(
             loras=self.loras, adapter_ids=pool.adapter_ids_dev)
+        if self.model_cfg.loop_passes > 1 and self._decode_n_steps == 1:
+            import jax.numpy as jnp
+
+            # a free slot's row picks a pass as well: not one to count
+            adapters["live"] = jnp.asarray([req is not None for req in pool.slots])
         out, cache, keys, stats = self._launch(
             ("decode", pool.stripe_len),
             "_decode_multi_jit" if self._decode_n_steps > 1 else "_decode_jit",
@@ -2747,15 +2781,20 @@ class JaxEngine:
             (now if due else later).append(entry)
         return now, later
 
-    @staticmethod
-    def _routing_counts(stats, programs: tuple) -> dict:
+    def _routing_counts(self, stats, programs: tuple) -> dict:
         """The routing counts a program handed out (None for a dense model; a
         row a program in ``programs``), by counter name. Called inside the
         fetch of the tokens they came out beside; their own copy to the host
         was started with the tokens', so this waits for nothing the tokens did
-        not wait for."""
+        not wait for. Of a model whose stack runs several times a token the
+        rows are its forwards, passes and exits (``models/patterned.py
+        _loop_stats``), counted whatever program ran them."""
         if stats is None:
             return {}
+        if self.model_cfg.loop_passes > 1:
+            forwards, passes, *exits = (int(n) for n in np.atleast_2d(np.asarray(stats)).sum(axis=0))
+            return {"loop_forwards": forwards, "loop_stack_passes": passes,
+                    **{f"loop_exit_rows:{t}": n for t, n in enumerate(exits)}}
         return {
             f"{name}:{program}": int(value)
             for program, row in zip(programs, np.atleast_2d(np.asarray(stats)))

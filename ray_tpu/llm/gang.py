@@ -167,7 +167,7 @@ class GangLLMServer:
         pg_timeout: float = 120.0,
     ):
         from ray_tpu.llm.config import (
-            refuse_blocks, refuse_latent, refuse_stateful, resolve_llama_config,
+            refuse_blocks, refuse_latent, refuse_looped, refuse_stateful, resolve_llama_config,
         )
         from ray_tpu.llm.tokenizer import get_tokenizer
 
@@ -176,6 +176,7 @@ class GangLLMServer:
         refuse_latent(model_cfg, "llm/gang.py")
         refuse_stateful(model_cfg, "llm/gang.py")
         refuse_blocks(model_cfg, "llm/gang.py")
+        refuse_looped(model_cfg, "llm/gang.py")
         self.llm_config = llm_config
         self.tokenizer = get_tokenizer(llm_config.model.tokenizer)
         self.num_workers = num_workers

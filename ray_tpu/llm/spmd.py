@@ -31,6 +31,7 @@ from ray_tpu.llm.config import (
     SamplingParams,
     refuse_blocks,
     refuse_latent,
+    refuse_looped,
     refuse_stateful,
     resolve_llama_config,
 )
@@ -66,6 +67,7 @@ class SPMDGenerator:
         refuse_latent(self.model_cfg, "llm/spmd.py")
         refuse_stateful(self.model_cfg, "llm/spmd.py")
         refuse_blocks(self.model_cfg, "llm/spmd.py")
+        refuse_looped(self.model_cfg, "llm/spmd.py")
         if mesh is None:
             n = len(jax.devices())
             if (

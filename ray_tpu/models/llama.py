@@ -253,6 +253,22 @@ class LlamaConfig:
     mask_token_id: int = 0
     denoise_steps: int = 0
     confidence_threshold: float = 1.0
+    # --- a stack run several times a token (ByteDance Ouro, arXiv 2510.25741) ---
+    # ``loop_passes`` > 1: the whole stack of ``n_layers`` runs that many
+    # times over a token with the same weights, pass t + 1 starting from pass
+    # t's stream under the final norm, and every pass keeps keys and values of
+    # its own (cache row ``t * n_layers + l``: ``init_kv_cache`` has
+    # ``n_layers * loop_passes`` rows). A gate (``exit_w``, ``exit_b``: a
+    # sigmoid of a ``Linear(d_model, 1)`` on each pass's normed stream) gives
+    # the chance that a position stops after a pass; the head reads the first
+    # pass at which those chances add up to ``exit_threshold`` (1: the last,
+    # unless float32 rounds to 1 before it), every pass run whatever it picks.
+    # ``branch_norm``: an RMSNorm on each branch's way out as well as in
+    # (``attn_out_norm``, ``mlp_out_norm``), x + norm(branch(norm(x))).
+    # 1 and False: every other model, untouched
+    loop_passes: int = 1
+    branch_norm: bool = False
+    exit_threshold: float = 1.0
 
     def __post_init__(self):
         if self.attention not in ("full", "ring", "ulysses", "splash"):
@@ -279,6 +295,8 @@ class LlamaConfig:
                              "(models/patterned.py)")
         if not self.layer_types and (self.qk_norm or self.block_length):
             raise ValueError("qk_norm and block_length need layer_types (models/patterned.py)")
+        if not self.layer_types and (self.loop_passes != 1 or self.branch_norm):
+            raise ValueError("loop_passes and branch_norm need layer_types (models/patterned.py)")
         if self.block_length and not (
             0 < (self.denoise_steps or self.block_length) <= self.block_length
             and 0 <= self.mask_token_id < self.vocab_size
@@ -717,6 +735,47 @@ class LlamaConfig:
         return LlamaConfig(**_sdar_lists(d))
 
 
+    @staticmethod
+    def ouro_2_6b(**kw) -> "LlamaConfig":
+        """ByteDance Ouro-2.6B (``model_type: ouro``) as its config.json has
+        it: 48 layers alike, 16 query and 16 key-value heads of 128, SwiGLU of
+        5,632, vocabulary 49,152, head untied, ``rope_theta`` 1e6, no window,
+        the stack run ``total_ut_steps`` = 4 times a token, ``early_exit_threshold``
+        1. Not in the config but in the published modelling file
+        (``benchmark/configs/ouro-2.6b-serve-l48.json`` ``assumed``): the norm
+        on each branch's way out, the final norm inside the loop, a cache row
+        a pass and layer, the exit gate and its rule."""
+        d = dict(
+            vocab_size=49152, d_model=2048, n_layers=48, n_heads=16, n_kv_heads=16,
+            head_width=128, d_ff=5632, max_seq_len=65536, rms_eps=1e-6, rope_theta=1e6,
+            loop_passes=4, branch_norm=True, exit_threshold=1.0,
+        )
+        d.update(kw)
+        return LlamaConfig(**_dense_lists(d))
+
+    @staticmethod
+    def ouro_tiny(**kw) -> "LlamaConfig":
+        """Test-size model with ``ouro_2_6b``'s parts: two layers run three
+        times, norms on both sides of each branch, the exit gate."""
+        d = dict(
+            vocab_size=320, d_model=64, n_layers=2, n_heads=4, n_kv_heads=4,
+            head_width=16, d_ff=128, max_seq_len=128, dtype=jnp.float32, remat=False,
+            rms_eps=1e-6, rope_theta=1e6, loop_passes=3, branch_norm=True,
+        )
+        d.update(kw)
+        return LlamaConfig(**_dense_lists(d))
+
+
+def _dense_lists(d: dict) -> dict:
+    """The per-layer lists of a model whose layers are all full attention over
+    a dense feed-forward, for its depth."""
+    n = d["n_layers"]
+    d.setdefault("layer_types", ("full",) * n)
+    d.setdefault("heads_per_layer", (d["n_heads"],) * n)
+    d.setdefault("mlp_types", ("dense",) * n)
+    return d
+
+
 def _sdar_lists(d: dict) -> dict:
     """The per-layer lists of a model whose layers are all full attention over
     routed experts, for its depth."""
@@ -795,6 +854,12 @@ _PARAM_DIMS = {
     "mlp_norm": (None, "norm"),
     "q_head_norm": (None, None),
     "k_head_norm": (None, None),
+    # a looped model's norms on the branches' way out and its exit gate (one
+    # device: ``llm/config.py refuse_looped``)
+    "attn_out_norm": (None, "norm"),
+    "mlp_out_norm": (None, "norm"),
+    "exit_w": (None,),
+    "exit_b": (None,),
     # MoE variant: per-layer expert banks (expert dim -> ep mesh axis)
     "moe_router": (None, "embed", None),
     "moe_router_bias": (None, None),
@@ -951,6 +1016,8 @@ _SSM_VECTORS.update({
                      "moe_router_b3"), _SSM_VECTORS["ssm_conv_b"]),
     **dict.fromkeys(("cca_temp", "attn_scale", "mlp_scale"), _SSM_VECTORS["ssm_d"]),
     "moe_router_gamma": lambda k, shape: jnp.full(shape, 0.5, jnp.float32),
+    # a looped model's exit gate starts unbiased
+    "exit_b": lambda k, shape: jnp.zeros(shape, jnp.float32),
 })
 
 
@@ -1304,6 +1371,12 @@ def loss_fn(params, batch, cfg: LlamaConfig, mesh: Optional[Mesh] = None):
         mask = batch.get("mask")
         if mask is not None:
             mask = mask[:, 1:]
+    if cfg.loop_passes > 1:
+        raise NotImplementedError(
+            "models/llama.py loss_fn: a stack run several times a token (loop_passes="
+            f"{cfg.loop_passes}) is served, not trained: its loss is over the exit "
+            "distribution's expected logits, which no path here computes"
+        )
     x, aux = forward_hidden(params, tokens, cfg, mesh, with_aux=True)
     if cfg.fused_ce:
         from ray_tpu.ops.cross_entropy import fused_cross_entropy

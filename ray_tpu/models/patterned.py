@@ -292,6 +292,12 @@ def plan(cfg) -> Plan:
     if (cfg.qk_norm or cfg.block_length) and any(t != "full" for t, _, _ in kinds):
         raise ValueError("qk_norm and block_length: over full attention layers alone (the "
                          "per-head norms and the block mask are the full kind's)")
+    if cfg.loop_passes != 1 and not (
+        cfg.loop_passes > 1 and all(k == ("full", cfg.n_heads, "dense") for k in kinds)
+        and not (cfg.block_length or cfg.residual_scales) and 0.0 <= cfg.exit_threshold <= 1.0
+    ):
+        raise ValueError("loop_passes: a stack of full attention layers alike over dense "
+                         "feed-forwards, run 2 or more times, with an exit_threshold in [0, 1]")
     if cfg.moe_experts_held and not (
         0 <= cfg.moe_experts_first <= cfg.moe_experts - cfg.moe_experts_held
     ):
@@ -493,14 +499,16 @@ def stripe_cache_shapes(cfg, batch_size: int, max_len: int) -> dict:
     latent model's: the latent layers' shared rotated key, at the front of a
     row of whole lane tiles, and their normed latent), and where the model has
     them the sliding latent layers' two (``k_sliding``, ``v_sliding``) and the
-    indexer's key a token and latent layer (``k_index``)."""
+    indexer's key a token and latent layer (``k_index``). A model whose stack
+    runs ``cfg.loop_passes`` times a token keeps every pass's keys and values:
+    row ``t * layers + l`` is layer ``l``'s in pass ``t``."""
     pl = plan(cfg)
 
     def lanes(n):
         return -(-n // _LANES) * _LANES
 
-    if not cfg.kv_latent_rank:
-        lead = (pl.n_attention, batch_size, cfg.n_kv_heads, max_len)
+    if not cfg.kv_latent_rank:  # (a looped model: a row a pass and layer, pass-major)
+        lead = (pl.n_attention * cfg.loop_passes, batch_size, cfg.n_kv_heads, max_len)
         return {"k": lead + (cfg.head_dim,), "v": lead + (cfg.head_dim,)}
     shapes = {}
     for kind, (k, v) in _STRIPES_OF_KIND.items():
@@ -609,6 +617,10 @@ def _param_shapes(cfg) -> dict[str, tuple]:
         shapes.update(_moe_shapes(cfg, n_sparse))
     if cfg.residual_scales:  # [0] on the stream, [1] on the branch
         shapes.update({"attn_scale": (pl.n_mixer, 2, e), "mlp_scale": (pl.n_ffn, 2, e)})
+    if cfg.branch_norm:  # a norm on each branch's way out (``_joined``)
+        shapes.update({"attn_out_norm": (pl.n_mixer, e), "mlp_out_norm": (pl.n_ffn, e)})
+    if cfg.loop_passes > 1:  # the exit gate: Linear(d_model, 1) with a bias
+        shapes.update({"exit_w": (e,), "exit_b": (1,)})
     if not cfg.tie_embeddings:
         shapes["unembed"] = (e, v)
     return shapes
@@ -637,11 +649,14 @@ def _times(x, m: float):
     return x if m == 1.0 else x * jnp.asarray(m, x.dtype)
 
 
-def _joined(params, leaf: str, i, x, branch, cfg):
+def _joined(params, leaf: str, out_norm: str, i, x, branch, cfg):
     """The stream after a branch joins it: ``x + branch`` (the branch times
     ``cfg.residual_multiplier``), or under learned scales (``residual_scales``;
     ``leaf`` row ``i``: a vector on the stream and one on the branch)
-    ``a * x + b * branch``."""
+    ``a * x + b * branch``. With ``branch_norm`` the branch passes a norm of
+    its own first (``out_norm`` row ``i``)."""
+    if cfg.branch_norm:
+        branch = _rmsnorm(branch, params[out_norm][i], cfg.rms_eps, cfg.fused_rmsnorm)
     if cfg.residual_scales:
         a, b = params[leaf][i]
         return a * x + b * branch
@@ -1086,13 +1101,16 @@ def _rope(x, positions, inv_freq, factor, interleave: bool = False):
 class _Layer:
     """One layer's static kind and its (static or traced) indices."""
 
-    def __init__(self, pl: Plan, l_static: int, l, attn_i, mlp_i, rows=None):
+    def __init__(self, pl: Plan, l_static: int, l, attn_i, mlp_i, rows=None, row0=None):
         self.kind, _, self.mlp = pl.kinds[l_static]
         self.sparse = self.mlp == "sparse"
         self.l, self.attn_i, self.mlp_i = l, attn_i, mlp_i
         # the layer's row among the layers with attention, with a mixer, with
         # a feed-forward: its own number where every block has all of them
         self.kv_i, self.mixer_i, self.ffn_i = (l, l, l) if pl.whole else rows
+        # its keys' and values' row in the cache: ``kv_i``, and in a stack run
+        # several times a token ``row0``, the pass's first row, further
+        self.cache_i = self.kv_i if row0 is None else row0 + self.kv_i
         self.wq, self.wo, self.wg = (pl.leaf(n, self.kind) for n in ("wq", "wo", "wg"))
         self.by_kind = pl.by_kind
         self.latent = self.kind in _LATENT_KINDS
@@ -1358,7 +1376,7 @@ def _attn_out(params, lay: _Layer, x, h, attn, cfg, from_latent: bool = False):
                 gate = gate.reshape(attn.shape) if cfg.attn_gate == "channel" else gate[..., None]
                 attn = (attn * gate).astype(attn.dtype)
         out = jnp.einsum("bthd,hde->bte", attn, params[lay.wo][lay.attn_i])
-        return _joined(params, "attn_scale", lay.mixer_i, x, out, cfg)
+        return _joined(params, "attn_scale", "attn_out_norm", lay.mixer_i, x, out, cfg)
 
 
 def _ssm_in(params, lay: _Layer, h, valid, cfg):
@@ -1601,28 +1619,30 @@ def _feed_forward(params, lay: _Layer, x, cfg, route=()):
     if lay.sparse:
         with scope("moe_ffn"):
             y, stats, *route = _moe_decode_ffn(params, lay.mlp_i, h, cfg, *route)
-            return _joined(params, "mlp_scale", lay.ffn_i, x, y, cfg), stats, tuple(route)
+            x = _joined(params, "mlp_scale", "mlp_out_norm", lay.ffn_i, x, y, cfg)
+            return x, stats, tuple(route)
     with scope("ffn"):
         # a layer's slice of a stacked weight is taken where it is used
         y = _dense_ffn(h, lambda name: params[name][lay.mlp_i])
-        x = _joined(params, "mlp_scale", lay.ffn_i, x, y, cfg)
+        x = _joined(params, "mlp_scale", "mlp_out_norm", lay.ffn_i, x, y, cfg)
     return x, (jnp.zeros((len(moe_stats_names(cfg)),), jnp.int32) if cfg.moe_experts else None), route
 
 
-def _run_layers(cfg, layer_fn, carry):
+def _run_layers(cfg, layer_fn, carry, row0=None):
     """``carry = layer_fn(lay, carry)`` over the stack as ``plan`` splits it:
     the only loop over layers on the cache path. The repeated period is one
     ``fori_loop`` with the whole carry (for ``decode_forward`` the whole
     cache) going round: the per-layer cache writes alias in place (donated
     buffers), where a ``lax.scan`` carrying per-layer cache slices as ys
     re-materializes the whole cache every step (decode measured 1.6x slower
-    from those copies alone at 3B/B=16 on v5e)."""
+    from those copies alone at 3B/B=16 on v5e). ``row0``: the cache row of
+    the stack's first layer where it is not 0 (``_run_passes``)."""
     pl = plan(cfg)
 
     tables = (pl.kv_index, pl.mixer_index, pl.ffn_index)
 
     def static(l):
-        return _Layer(pl, l, l, pl.attn_index[l], pl.mlp_index[l], [t[l] for t in tables])
+        return _Layer(pl, l, l, pl.attn_index[l], pl.mlp_index[l], [t[l] for t in tables], row0)
 
     for l in range(pl.lead):
         carry = layer_fn(static(l), carry)
@@ -1643,6 +1663,7 @@ def _run_layers(cfg, layer_fn, carry):
                     pl.attn_index[l] + i * step_attn[j],
                     pl.mlp_index[l] + i * step_mlp[j],
                     [t[l] + i * step for t, step in zip(tables, step_rows)],
+                    row0,
                 )
                 carry = layer_fn(lay, carry)
             return carry
@@ -1653,15 +1674,94 @@ def _run_layers(cfg, layer_fn, carry):
     return carry
 
 
+# ----------------------------------------- a stack run several times a token
+
+
+def _run_passes(cfg, layer_fn, end_pass, carry, ex):
+    """A stack run ``cfg.loop_passes`` times a token: ``_run_layers`` under one
+    more ``fori_loop``, the carry going round both. Pass ``t`` is the stack
+    with the same weights on the cache's rows from ``t * layers``
+    (``_Layer.cache_i``), and ``carry, ex = end_pass(t, carry, ex)`` behind its
+    last layer (``_loop_exit``; ``ex``: what the exit rule carries from pass to
+    pass, which no layer sees). -> ``(carry, ex)``"""
+    rows = plan(cfg).n_attention
+    return jax.lax.fori_loop(
+        0, cfg.loop_passes,
+        lambda t, both: end_pass(t, _run_layers(cfg, layer_fn, both[0], t * rows), both[1]),
+        (carry, ex))
+
+
+# what ``loop_stats`` counts (int32 [2 + passes]): forwards, the passes their
+# stacks ran, then the rows whose head read pass 0, 1, ..
+LOOP_STATS = ("forwards", "passes")
+
+
+def _loop_exit_start(cfg, like, keep: bool = False) -> dict:
+    """What goes round the passes beside the stream for the rows ``like``
+    [.., e] whose next token is asked for (``_loop_exit``): the hidden state
+    the exit rule has chosen so far, the pass it is from and whether it has
+    chosen, the shares added up, the chance that no earlier pass stopped, and
+    the passes run. ``keep``: every pass's normed stream and share as well
+    (the whole-sequence path hands them out)."""
+    lead, f32 = like.shape[:-1], jnp.float32
+    ex = {"h": jnp.zeros(like.shape, like.dtype), "at": jnp.zeros(lead, jnp.int32),
+          "done": jnp.zeros(lead, bool), "cum": jnp.zeros(lead, f32),
+          "alive": jnp.ones(lead, f32), "passes": jnp.zeros((), jnp.int32)}
+    if keep:
+        ex.update(hidden=jnp.zeros((cfg.loop_passes,) + like.shape, like.dtype),
+                  pdf=jnp.zeros((cfg.loop_passes,) + lead, f32))
+    return ex
+
+
+def _loop_exit(params, cfg, t, x, ex: dict, rows=lambda x: x):
+    """The end of pass ``t`` of a stack run ``cfg.loop_passes`` times: the
+    stream under the model's final norm (what pass ``t + 1`` starts from and
+    what the head reads), and for ``rows(x)`` the exit rule: the gate's
+    chance ``lam`` that a position stops here, its share ``lam * prod(1 -
+    lam[s], s < t)`` of the exit distribution (the last pass takes what is
+    left), and the first pass at which the shares add up to
+    ``cfg.exit_threshold`` (else the last) as the one the head reads. All in
+    float32, a position its own. Named ``loop_exit`` inside ``norm``: a
+    reader that knows no such name books it all to the norm it mostly is."""
+    with scope("norm"), scope("loop_exit"):
+        x = _rmsnorm(x, params["final_norm"], cfg.rms_eps, cfg.fused_rmsnorm)
+        ex = dict(ex, passes=ex["passes"] + 1)
+        if "at" not in ex:  # no row's next token is asked for
+            return x, ex
+        h = rows(x)
+        lam = jax.nn.sigmoid(
+            jnp.einsum("...e,e->...", h, params["exit_w"].astype(h.dtype),
+                       preferred_element_type=jnp.float32)
+            + params["exit_b"].astype(jnp.float32)[0])
+        last = t == cfg.loop_passes - 1
+        share = jnp.where(last, ex["alive"], lam * ex["alive"])
+        cum = ex["cum"] + share
+        # (the shares of all passes add up to 1 but for rounding: the last takes the rest)
+        here = ~ex["done"] & ((cum >= cfg.exit_threshold) | last)
+        ex = dict(ex, h=jnp.where(here[..., None], h, ex["h"]), at=jnp.where(here, t, ex["at"]),
+                  done=ex["done"] | here, cum=cum, alive=ex["alive"] * (1.0 - lam))
+        if "hidden" in ex:
+            ex.update(hidden=jax.lax.dynamic_update_index_in_dim(ex["hidden"], h, t, 0),
+                      pdf=jax.lax.dynamic_update_index_in_dim(ex["pdf"], share, t, 0))
+    return x, ex
+
+
 # ------------------------------------------------------------ whole sequence
 
 
-def forward_hidden(params, tokens, cfg, mesh: Optional[Mesh] = None, positions=None):
+def forward_hidden(params, tokens, cfg, mesh: Optional[Mesh] = None, positions=None,
+                   passes: bool = False):
     """tokens: [B, T] -> final hidden states [B, T, d_model] of a model whose
     layers are not alike, every expert layer dropless on this device
     (``_moe_decode_ffn``). One device: a mesh with an axis over 1 is refused
     (training this model over ``ep`` is not here yet). A uniform model's
-    whole-sequence path is ``models/llama.py forward_hidden``."""
+    whole-sequence path is ``models/llama.py forward_hidden``.
+
+    Of a stack run several times a token (``cfg.loop_passes``) the hidden
+    state is each position's own pass's, as its exit rule picks (``_loop_exit``),
+    and with ``passes`` the result is ``(that, {"hidden": every pass's normed
+    stream [passes, B, T, e], "pdf": the exit distribution [passes, B, T],
+    "exit": the pass picked [B, T]})``."""
     if mesh is not None and any(s > 1 for s in mesh.shape.values()):
         raise NotImplementedError("models/patterned.py runs on one device")
     if not plan(cfg).whole:
@@ -1715,6 +1815,16 @@ def forward_hidden(params, tokens, cfg, mesh: Optional[Mesh] = None, positions=N
     if cfg.remat:
         plain = layer
         layer = lambda lay, x: jax.checkpoint(lambda y: plain(lay, y))(x)  # noqa: E731
+    if cfg.loop_passes > 1:
+        def end_pass(t, carry, ex):
+            x, ex = _loop_exit(params, cfg, t, carry[0], ex)
+            return (x, carry[1]), ex
+
+        _, ex = _run_passes(cfg, layer, end_pass, (x, _router_stream(cfg, x)),
+                            _loop_exit_start(cfg, x, keep=passes))
+        if passes:
+            return ex["h"], {"hidden": ex["hidden"], "pdf": ex["pdf"], "exit": ex["at"]}
+        return ex["h"]
     x, _ = _run_layers(cfg, layer, (x, _router_stream(cfg, x)))
     return _rmsnorm(x, params["final_norm"], cfg.rms_eps, cfg.fused_rmsnorm)
 
@@ -2064,7 +2174,7 @@ def _cache_reader(cfg, params, cache, positions, kinds, block: bool = False):
             B, _, H, D = q.shape
             K = ck_all.shape[2]
             folded = q.reshape(B, T, K, H // K, D).transpose(0, 2, 1, 3, 4).reshape(B, T * H, D)
-            out = decode_attention(folded, ck_all, cv_all, lay.kv_i, lo, hi)
+            out = decode_attention(folded, ck_all, cv_all, lay.cache_i, lo, hi)
             return out.reshape(B, K, T, H // K, D).transpose(0, 2, 1, 3, 4).reshape(B, T, H, D)
 
         return read
@@ -2073,7 +2183,7 @@ def _cache_reader(cfg, params, cache, positions, kinds, block: bool = False):
         lo = {**dict.fromkeys(_FULL_KINDS, jnp.zeros_like(hi)), "sliding": jnp.maximum(hi - W, 0)}
 
         def read(q, ck_all, cv_all, lay):
-            return decode_attention(q[:, 0], ck_all, cv_all, lay.kv_i, lo[lay.kind], hi)[:, None]
+            return decode_attention(q[:, 0], ck_all, cv_all, lay.cache_i, lo[lay.kind], hi)[:, None]
 
         return read
 
@@ -2107,7 +2217,7 @@ def _cache_reader(cfg, params, cache, positions, kinds, block: bool = False):
 
         def block(i, carry):
             m, den, acc = carry
-            at = (lay.kv_i, 0, 0, i * bk, 0)
+            at = (lay.cache_i, 0, 0, i * bk, 0)
             kb = jax.lax.dynamic_slice(ck_all, at, (1, B, K, bk, D))[0]
             vb = jax.lax.dynamic_slice(cv_all, at, (1, B, K, bk, D))[0]
             s = jnp.einsum("btkgd,bksd->bktgs", qg, kb, preferred_element_type=jnp.float32)
@@ -2132,10 +2242,10 @@ def _cache_reader(cfg, params, cache, positions, kinds, block: bool = False):
         if in_blocks and lay.kind in _FULL_KINDS:
             return read_blocks(q, ck_all, cv_all, lay)
         if whole[lay.kind]:
-            return _grouped_attention(q, ck_all[lay.kv_i], cv_all[lay.kv_i], masks[lay.kind])
+            return _grouped_attention(q, ck_all[lay.cache_i], cv_all[lay.cache_i], masks[lay.kind])
         return _grouped_attention(
-            q, _window_slice(ck_all, lay.kv_i, first, span),
-            _window_slice(cv_all, lay.kv_i, first, span), window_mask,
+            q, _window_slice(ck_all, lay.cache_i, first, span),
+            _window_slice(cv_all, lay.cache_i, first, span), window_mask,
         )
 
     return read
@@ -2334,7 +2444,7 @@ def decode_forward(
     # of it at all, and the compiler drops it whole; a layer that is a pass of
     # the loop runs for every row either way)
     narrow = (beside is not None and not with_logits and pl.kinds[-1][2] != "none"
-              and (pl.reps == 0 or pl.tail_from < cfg.n_layers))
+              and (pl.reps == 0 or pl.tail_from < cfg.n_layers) and cfg.loop_passes == 1)
     # a model with routed experts carries its routing counts beside x, one
     # with layers that keep a state each set's ``STATE_LEAVES`` behind those
     stats0 = (jnp.zeros((len(moe_stats_names(cfg)),), jnp.int32),) if cfg.moe_experts else ()
@@ -2358,7 +2468,8 @@ def decode_forward(
             ys = [y for y, *_ in mixed]
             y = ys[0] if len(sets) == 1 else _join(
                 [y.reshape(rows.B, rows.T, -1) for rows, y in zip(sets, ys)])
-            x = _joined(params, "attn_scale", lay.mixer_i, x, out(params, lay, y, gate, cfg), cfg)
+            x = _joined(params, "attn_scale", "attn_out_norm", lay.mixer_i, x,
+                        out(params, lay, y, gate, cfg), cfg)
         elif lay.kind != "none":
             h = _rmsnorm(x, params["attn_norm"][lay.mixer_i], cfg.rms_eps, cfg.fused_rmsnorm)
             index = None
@@ -2386,7 +2497,7 @@ def decode_forward(
             attn, new_kv = [], []
             # the stripes this layer's kind keeps, and its row in them
             names, row = _STRIPES_OF_KIND.get(lay.kind, ("k", "v")), (
-                lay.attn_i if lay.latent else lay.kv_i)
+                lay.attn_i if lay.latent else lay.cache_i)
             for j, rows in enumerate(sets):
                 ck_all, cv_all = (kv[j][name] for name in names)
                 # a latent model's q is a pair, and its rows are one set
@@ -2423,9 +2534,38 @@ def decode_forward(
         return (x, kv, stats, state, route)
 
     stripes = tuple(stripe_cache_shapes(cfg, 1, 1))  # by name
-    x, kv, stats, state, _ = _run_layers(
-        cfg, layer, (x, tuple({name: rows.cache[name] for name in stripes} for rows in sets),
-                     stats0, state0, _router_stream(cfg, x)))
+    carry = (x, tuple({name: rows.cache[name] for name in stripes} for rows in sets),
+             stats0, state0, _router_stream(cfg, x))
+
+    def head_rows(x):
+        """The rows of ``x`` whose next token is asked for: one position a row
+        of the first set (``logits_at``) or all of them, and every row of a
+        second set; of a middle chunk the second set's alone."""
+        heads = [None, x] if narrow else _split(x, shapes)
+        if logits_at is not None:
+            # the one requested hidden state a sequence BEFORE the vocab
+            # projection: [B, T, e] -> [B, 1, e]
+            heads[0] = jnp.take_along_axis(heads[0], logits_at[:, None, None], axis=1)
+        # a block's rows, a row a position: the head's [1, B * T, V] lies in whole
+        # tiles of 8 rows, where [B, T, V] pads each row's T positions to 8 and is relaid
+        heads = [h if h is None or rows.commit is None else h.reshape((1, -1) + h.shape[2:])
+                 for rows, h in zip(sets, heads)]
+        return heads if with_logits else heads[1:]
+
+    if cfg.loop_passes > 1:
+        # the stack several times: each pass ends under the final norm, and the
+        # rows the head will read pick their pass as they go (``_loop_exit``)
+        heads = jax.eval_shape(head_rows, x)  # their shapes: the streams are the passes'
+        ex = _loop_exit_start(cfg, jax.eval_shape(_join, heads)) if heads else {
+            "passes": jnp.zeros((), jnp.int32)}
+
+        def end_pass(t, carry, ex):
+            x, ex = _loop_exit(params, cfg, t, carry[0], ex, lambda x: _join(head_rows(x)))
+            return (x, *carry[1:]), ex
+
+        (x, kv, stats, state, _), ex = _run_passes(cfg, layer, end_pass, carry, ex)
+    else:
+        x, kv, stats, state, _ = _run_layers(cfg, layer, carry)
     new_caches = []
     for rows, written, leaves in zip(sets, kv, state):
         grew = rows.T if rows is sets[0] or rows.valid is None else rows.valid.sum(
@@ -2434,31 +2574,45 @@ def decode_forward(
             grew = jnp.where(rows.commit, rows.T, 0)
         new_cache = {**written, "length": rows.cache["length"] + grew, **leaves}
         _ride_stats(rows.cache, new_cache, stats)
+        if cfg.loop_passes > 1 and "loop_stats" in rows.cache:
+            # a second set's rows count where they are live, a first set's all
+            # unless its cache says which (``loop_live`` [B]: a decode step's
+            # slots that hold a request, as the engine knows them at its launch)
+            counted = [r.real() if r is not sets[0] else r.cache.get(
+                "loop_live", jnp.ones((r.B,), bool))[:, None]
+                       for r in (sets if with_logits else sets[1:])]
+            new_cache["loop_stats"] = rows.cache["loop_stats"] + _loop_stats(cfg, ex, counted)
         new_caches.append(new_cache)
-    # the rows whose next token is asked for: one position a row of the first
-    # set (``logits_at``) or all of them, and every row of a second set
-    heads = [None, x] if narrow else _split(x, shapes)
-    if logits_at is not None:
-        # the one requested hidden state a sequence BEFORE the vocab
-        # projection: [B, T, e] -> [B, 1, e]
-        heads[0] = jnp.take_along_axis(heads[0], logits_at[:, None, None], axis=1)
-    # a block's rows, a row a position: the head's [1, B * T, V] lies in whole
-    # tiles of 8 rows, where [B, T, V] pads each row's T positions to 8 and is relaid
-    heads = [h if h is None or rows.commit is None else h.reshape((1, -1) + h.shape[2:])
-             for rows, h in zip(sets, heads)]
-    if not with_logits:
-        heads = heads[1:]
+    if cfg.loop_passes == 1:
+        heads = head_rows(x)
     logits = []
     if heads:
         # a middle chunk's head is the second set's alone
         with (sets[0] if with_logits else sets[-1]).scope():
-            x = _rmsnorm(_join(heads), params["final_norm"], cfg.rms_eps, cfg.fused_rmsnorm)
+            x = ex["h"] if cfg.loop_passes > 1 else _rmsnorm(
+                _join(heads), params["final_norm"], cfg.rms_eps, cfg.fused_rmsnorm)
             logits = _split(_project_logits(x, params, cfg, None), [h.shape[:2] for h in heads])
     if not with_logits:
         logits = [None] + logits
     if beside is None:
         return logits[0], new_caches[0]
     return logits[0], new_caches[0], logits[1], new_caches[1]
+
+
+def _loop_stats(cfg, ex: dict, counted: list):
+    """What one forward of a stack run several times adds to a cache's
+    ``loop_stats`` leaf (int32 [2 + passes], ``LOOP_STATS`` and then a count a
+    pass; how the engine's programs get them out beside their tokens, as
+    ``_ride_stats``'s): one forward, the passes its stack ran, and of the rows
+    whose next token was asked for (``counted``: a mask [B, 1] each set of
+    them, false where a row is not live) how many read each pass. A first
+    set's rows with all their positions asked for count their last."""
+    hist = jnp.zeros((cfg.loop_passes,), jnp.int32)
+    if "at" in ex:
+        at = ex["at"][:, -1:] if len(counted) == 1 else ex["at"]
+        picked = at.reshape(-1)[:, None] == jnp.arange(cfg.loop_passes)[None, :]
+        hist = (picked & _join(counted).reshape(-1)[:, None]).sum(axis=0, dtype=jnp.int32)
+    return jnp.concatenate([jnp.ones((1,), jnp.int32), ex["passes"][None], hist])
 
 
 def state_mixer_forms(cfg) -> dict:

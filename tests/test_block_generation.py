@@ -377,6 +377,9 @@ def _steps_launched_alone(eng):
     chunk's alone and every block step a ``jit_block_step`` (the forms that
     take no rows are compiled at their first launch; the carrying ones come
     back at the end). The loop holds no request on the way in or out."""
+    deadline = time.monotonic() + 30  # (a step launched ahead of the last request's end
+    while any(p.inflight or p.first_pending for p in eng._pools) and time.monotonic() < deadline:
+        time.sleep(0.001)  # is fetched after the request returned: one run in seven met it here)
     assert not any(p.admitting or p.inflight or any(p.slots) for p in eng._pools)
     chunks = {form: program for form, program in eng._programs.items()
               if form[0].startswith("chunk_")}
