@@ -419,7 +419,9 @@ class _Pool:
         import jax
 
         from ray_tpu.models.llama import init_kv_cache
-        from ray_tpu.models.patterned import STATE_LEAVES, reads_blocks, stripe_cache_shapes
+        from ray_tpu.models.patterned import (
+            STATE_LEAVES, reads_blocks, stripe_cache_shapes, writes_rows,
+        )
         from ray_tpu.ops.decode_attention import block_size, cache_position_bytes
 
         self.stripe_len = stripe_len
@@ -486,6 +488,13 @@ class _Pool:
         # arrays the steps run on
         self.reads_blocks = reads_blocks(
             stripe_len, self.cache["k"], *jax.tree.leaves(params), latent=self.latent
+        )
+        # whether its steps write their new keys and values through the write
+        # kernel (a token a row; a block pool's rows are a block wide), asked
+        # the same way
+        self.writes_rows = writes_rows(
+            self.block_length or 1, False, self.cache["k"], *jax.tree.leaves(params),
+            latent=self.latent,
         )
         # what a position of a row holds in a layer of the cache the kernel is
         # handed, and the positions a block of its walk takes of such a cache
@@ -1929,6 +1938,9 @@ class JaxEngine:
                  # positions a block of the decode kernel's walk takes of this
                  # pool's cache (None: its steps keep the einsum over the stripe)
                  "decode_block": p.decode_block,
+                 # the form its steps' write of their new keys and values takes:
+                 # ``kernel`` (``ops/cache_write.py``, one call a layer) or ``scatter``
+                 "decode_write": "kernel" if p.writes_rows else "scatter",
                  # which form its state mixers take for a chunk and a step
                  # (``kernel`` or ``plain``): static a shape, asked where the trace asks
                  "state_mixer_forms": state_mixer_forms(self.model_cfg),
@@ -2482,9 +2494,10 @@ class JaxEngine:
         pool in this pass): the rows that decode ride through the chunk's read
         of the weights, one program where there were two. A later one-row
         launch of the pass, or one that finds no step due, runs the same
-        program with no row live: the rows' kernels, scatter and sampler run
-        and nothing reads them (``decode_steps_dead_in_chunk``, by cause). A
-        launch of several rows and a pass with no chunk run as they did."""
+        program with no row live: the rows' kernels and sampler run (their
+        write starts no copy) and nothing reads them
+        (``decode_steps_dead_in_chunk``, by cause). A launch of several rows
+        and a pass with no chunk run as they did."""
         progressed = False
         for pool in self._pools:
             launches, mids = [], None  # mids: the middle-chunk launch with room left

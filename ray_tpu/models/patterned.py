@@ -69,6 +69,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from ray_tpu.ops import latent_chunk_attention as chunk_kernel
+from ray_tpu.ops.cache_write import rows_in_stripe, takes_cache, write_rows_in_place
 from ray_tpu.ops.decode_attention import (
     decode_attention,
     latent_decode_attention,
@@ -939,12 +940,21 @@ def _moe_decode_ffn(params, row, h, cfg, r_prev=None):
 # block each. On a v5e (PERF.md section 6, PR 27; a tensor and layer) a block
 # costs about 2 us and 5 ns for each of the row's K*T cache rows (a window
 # read, a select, an in-place ``dynamic_update_slice``: 11 us for a 256-token
-# chunk), the scatter 73-90 ns a cache row (150 us for the same chunk), both
+# chunk), the scatter 73-100 ns a cache row (150 us for the same chunk), both
 # linear in B. So the block wins wherever a row brings more than ~32 cache
 # rows, as every prompt chunk does, and loses at decode's T = 1 (B = 32: 69 us
 # against 23). The blocks are unrolled into the layer loop's body and the cap
 # only bounds that program: the engine's scratch stripe has B = 1, the
 # benchmark's probe B = 2; a wider gang batch (``llm/spmd.py``) is scattered.
+# A decode step's rows are neither: ``writes_rows`` hands them to the write
+# kernel (``ops/cache_write.py``), one call a layer for keys and values
+# together. Us a layer, the two scatters against the kernel, at the serving
+# cells' shapes (``tools/cache_write_sweep.py``, a v5e; PERF.md section 6,
+# PR 58): 12 slots of 16 heads (Ouro) 37.6 / 6.4; 32 of 8 (Mistral, Laguna)
+# 46.1 / 8.4-8.8; 64 of 8 (Solar) 83.7 / 18.3; 64 of 2 (Nemotron, ZAYA1) 22.6-25.7
+# / 12.7-14.4; 64 of 4 46.5 / 13.0: the scatter by the (slot, head) pair, the
+# kernel some 4 us and 0.15-0.2 us a slot, so no served shape keeps the scatter
+# for its cost and the gate asks only what the kernel's copies can take.
 _BLOCK_WRITE_MAX_BATCH = 8
 
 
@@ -984,18 +994,31 @@ def _ride_stats(cache, new_cache, stats) -> None:
         new_cache["moe_stats"] = cache["moe_stats"] + stats[0]
 
 
-def _cache_writer(cfg, S: int, positions, valid, start_pos):
-    """``write(c_all, new, l)`` for ``decode_forward``: new keys or values
-    [B, K, T, D] into layer ``l`` of the carried cache [L, B, K, S, D], as
-    blocks or as the scatter (see ``decode_forward``)."""
+def _cache_writer(cfg, cache, params, positions, valid, start_pos):
+    """``write(l, caches, news)`` for ``decode_forward``: each of ``news``
+    [B, T, K, D] (new keys, new values) into layer ``l`` of the carried cache
+    [L, B, K, S, D] beside it in ``caches`` -> the caches, as blocks, through
+    the kernel or as the scatter (see ``decode_forward``)."""
     B, T = positions.shape
+    S = cache["k"].shape[3]
+    if writes_rows(T, start_pos is not None, cache["k"], *jax.tree.leaves(params),
+                   latent=bool(cfg.kv_latent_rank)):
+        # which rows write, and where: once, outside the layer loop
+        at, ok = rows_in_stripe(positions[:, 0], None if valid is None else valid[:, 0], S)
+
+        def write(l, caches, news):  # keys and values: one call for both
+            ck_all, cv_all = caches
+            return tuple(write_rows_in_place(
+                ck_all, cv_all, l, *(new[:, 0] for new in news), at, ok))
+
+        return write
     as_blocks = (
         start_pos is not None and B <= _BLOCK_WRITE_MAX_BATCH and T <= S
     )
     if as_blocks:
         ok = jnp.ones((B, T), bool) if valid is None else valid
 
-        def write(c_all, new, l):
+        def one(c_all, new, l):
             for b in range(B):
                 c_all = _write_block(c_all, new[b], l, b, start_pos[b], ok[b])
             return c_all
@@ -1009,8 +1032,13 @@ def _cache_writer(cfg, S: int, positions, valid, start_pos):
         ki = jnp.arange(cfg.n_kv_heads)[None, :, None]
         pi = write_pos[:, None, :]  # [B, 1, T]
 
-        def write(c_all, new, l):
+        def one(c_all, new, l):
             return c_all.at[l, bi, ki, pi].set(new, mode="drop")
+
+    def write(l, caches, news):
+        # the cache is head-major: the new [B, T, K, D] rows go in as [B, K, T, D]
+        return tuple(one(c_all, new.transpose(0, 2, 1, 3), l) for c_all, new in zip(caches, news))
+
     return write
 
 
@@ -1873,6 +1901,30 @@ def reads_blocks(stripe: int, *arrays, latent: bool = False) -> bool:
     return _on_one_device(arrays)
 
 
+def writes_rows(T: int, from_start: bool, *arrays, latent: bool = False) -> bool:
+    """Whether a set of rows of ``T`` new tokens each writes its keys and
+    values through the kernel (``ops/cache_write.py``: one call a layer for
+    both tensors and every row) or keeps the form it has (``_write_block`` or
+    the scatter): the one place that decides it, asked as ``reads_blocks`` is,
+    by ``_cache_writer`` with the tracers of the arrays the rows run on and by
+    the engine once a pool with the arrays themselves (``llm/engine.py
+    _Pool.writes_rows``, for ``get_stats()["pools"][i]["decode_write"]``).
+
+    The kernel is a decode step's: one new token a row at a position of the
+    row's own (``T == 1`` and no ``from_start``, the caller's word that a
+    row's positions are consecutive: a prompt's one-token chunk is a block
+    like any other), into a stripe cache (the first of ``arrays``, the cache's
+    keys ``[L, B, K, S, D]``; not ``latent``: a latent pool's write of one
+    256-lane row a slot is 0.03-0.04 ms a step as the scatter) whose heads are
+    whole lane tiles and whose stripe is whole tiles of positions
+    (``ops/cache_write.py takes_cache``: a tiny model's narrow heads keep the
+    scatter on the CPU as on the chip), with everything on one device
+    (``reads_blocks`` says how a mesh is seen)."""
+    if T != 1 or from_start or latent or not takes_cache(arrays[0]):
+        return False
+    return _on_one_device(arrays)
+
+
 def _on_one_device(arrays) -> bool:
     """No argument (an array or its tracer) carries a mesh of several devices
     in its type (``reads_blocks`` says what a type does not carry)."""
@@ -2265,7 +2317,7 @@ class _Rows:
         # the rows whose length advances by the block (``decode_forward``)
         self.commit = commit
         self.B, self.T = tokens.shape
-        self.write = _cache_writer(cfg, cache["k"].shape[3], positions, valid, start_pos)
+        self.write = _cache_writer(cfg, cache, params, positions, valid, start_pos)
         self.select = None  # an indexed latent layer's choice of positions
         if "latent" in kinds:  # every layer is latent (``plan``); by kind
             self.read, self.select, self.from_latent = _latent_reader(cfg, params, cache, positions)
@@ -2315,14 +2367,20 @@ def decode_forward(
     stripe-to-slot copy, the prefix cache's store, the disaggregated
     hand-over) carries none.
 
-    Two forms of one write, chosen from what is static at trace time, with
-    the same bytes in the same slots. ``start_pos`` [B] is the caller's word
-    that row ``b``'s positions are ``start_pos[b] + arange(T)`` (every call
-    through ``prefill``): while ``B <= _BLOCK_WRITE_MAX_BATCH`` and the chunk
-    fits the cache (``T <= S``) each row is one contiguous block a tensor and
-    layer (``_write_block``: padding and the stripe's end keep old bytes).
-    Otherwise (``decode_step``: T = 1, every row at an unrelated position;
-    a wide batch) the ``[B, K, T]``-index scatter with ``mode="drop"``.
+    Three forms of one write, chosen from what is static at trace time, with
+    the same bytes in the same slots (``_cache_writer``). ``start_pos`` [B] is
+    the caller's word that row ``b``'s positions are ``start_pos[b] +
+    arange(T)`` (every call through ``prefill``): while ``B <=
+    _BLOCK_WRITE_MAX_BATCH`` and the chunk fits the cache (``T <= S``) each
+    row is one contiguous block a tensor and layer (``_write_block``: padding
+    and the stripe's end keep old bytes). A decode step's rows (``T == 1``,
+    every row at an unrelated position, no ``start_pos``; alone or beside a
+    chunk) go through the write kernel wherever ``writes_rows`` says the
+    shapes take it (``ops/cache_write.py``: one call a layer for keys and
+    values and every row, the cache where it lies). Anything else (a wide
+    batch, rows a block wide, a latent cache, heads narrower than a lane
+    tile, a cache or parameters on a mesh) is the ``[B, K, T]``-index scatter
+    with ``mode="drop"``.
     The cache's position axis is never sharded (``llm/spmd.py`` shards the
     key-value heads), so a block partitions over heads as the scatter does.
 
@@ -2342,7 +2400,7 @@ def decode_forward(
     and the touched experts' banks are read once for both; whatever reads or
     writes a cache runs for each set in its own form, under the scope it has
     alone: the chunk's block write and attention over its stripe, the decode
-    rows' scatter and kernels between each row's own bounds. A row that is
+    rows' write and kernels between each row's own bounds. A row that is
     not ``live`` writes nothing and leaves its length and state where they
     were (its arithmetic is done and dropped, as a free slot's is in a decode
     step). Every row gets the arithmetic it gets alone, in matmuls of more
@@ -2506,14 +2564,12 @@ def decode_forward(
                 with rows.scope(), scope("kv_write"):
                     if lay.latent:  # zeros up to the cache's row of whole lane tiles (``init_kv_cache``)
                         kj = jnp.pad(kj, ((0, 0),) * 3 + ((0, ck_all.shape[-1] - kj.shape[-1]),))
-                    # the cache is head-major: the new [B, T, K, D] rows go in as [B, K, T, D]
-                    ck_all = rows.write(ck_all, kj.transpose(0, 2, 1, 3), row)
-                    cv_all = rows.write(cv_all, vj.transpose(0, 2, 1, 3), row)
+                    ck_all, cv_all = rows.write(row, (ck_all, cv_all), (kj, vj))
                     if index:
                         ki_all = kv[j]["k_index"]
                         k_index = jnp.pad(
                             k_index, ((0, 0),) * 3 + ((0, ki_all.shape[-1] - k_index.shape[-1]),))
-                        written["k_index"] = rows.write(ki_all, k_index.transpose(0, 2, 1, 3), row)
+                        written["k_index"], = rows.write(row, (ki_all,), (k_index,))
                 if index and rows.select is not None:
                     with scope("attn_core"):  # ``attn_index`` and ``attn_select`` inside it
                         chosen = (rows.select(index, written["k_index"], lay),)

@@ -69,9 +69,11 @@ def native_kernels(monkeypatch):
 
     import ray_tpu.ops.latent_chunk_attention  # noqa: F401 — nor this one
 
+    import ray_tpu.ops.cache_write  # noqa: F401 — nor this one
+
     for name in ("ray_tpu.ops.rmsnorm", "ray_tpu.ops.quant", "ray_tpu.ops.grouped_matmul",
                  "ray_tpu.ops.decode_attention", "ray_tpu.ops.ssm", "ray_tpu.ops.kda",
-                 "ray_tpu.ops.latent_chunk_attention"):
+                 "ray_tpu.ops.latent_chunk_attention", "ray_tpu.ops.cache_write"):
         monkeypatch.setattr(sys.modules[name], "interpret", lambda: False)
 
 
@@ -176,6 +178,20 @@ def _decode_kernel_blocks(fn, *args):
                         yield from calls(inner)
 
     return sorted(calls(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def _kv_writes(text, beside=False):
+    """(the write kernel's calls, the scatters) among a compiled program's
+    instructions named under ``kv_write`` (a decode program's rows, or a
+    chunk's own) or, with ``beside``, under ``beside/kv_write`` (the decode
+    rows a chunk program carries): a decode step's new keys and values go
+    through ``ops/cache_write.py``'s kernel, one call a traced layer for both
+    tensors, and leave no scatter there."""
+    named = [line for line in text.splitlines()
+             if "/kv_write/" in line and ("beside/kv_write/" in line) == beside]
+    return ([line for line in named
+             if 'custom_call_target="tpu_custom_call"' in line and "cache_write_rows" in line],
+            [line for line in named if " scatter(" in line])
 
 
 def _program_text(program):

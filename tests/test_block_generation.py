@@ -501,6 +501,8 @@ def probed(engine, tiny):
     # the hand-outs the check judges came out of chunk launches too: the pool
     # carries, and the long prompt's chunks ran beside the others' blocks
     assert engine._pools[0].carries and _grown(engine, before)["decode_steps_in_chunk"] > 0
+    # (its rows are a block wide: their write stays the scatter)
+    assert engine.get_stats()["pools"][0]["decode_write"] == "scatter"
     return requests, got
 
 

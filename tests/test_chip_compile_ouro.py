@@ -2,10 +2,11 @@
 a token, 12 slots of 384) at real widths for a described v5e
 (``tests/chip_compile.py`` says how, and what that proves): the engine's
 ``decode_fn`` and the final chunk that carries the pool's decode step compile
-with the decode kernel traced once (one layer body under the loop over layers
-under the loop over passes), the 7.25 GB cache riding through both loops as a
-donated carry that no operation copies, and every pass's end named
-``norm/loop_exit``."""
+with the decode kernel and the write kernel (``ops/cache_write.py``: a step's
+new keys and values, no scatter under ``kv_write``) traced once each (one layer
+body under the loop over layers under the loop over passes), the 7.25 GB cache
+riding through both loops and both kernels as a donated carry that no operation
+copies, and every pass's end named ``norm/loop_exit``."""
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +14,7 @@ import pytest
 
 from tests.chip_compile import (
     _decode_kernel_blocks,
+    _kv_writes,
     _served_programs,
     native_kernels,
     no_compile_cache,
@@ -55,9 +57,14 @@ def test_decode_fn_compiles_with_one_kernel_body_and_no_copy_of_the_cache(
     # weights 5.34 GB, stripes 7.25 GB
     assert 12.5e9 < memory.argument_size_in_bytes < 12.7e9
     assert memory.temp_size_in_bytes < 0.2e9
-    lines = compiled.as_text().splitlines()
+    text = compiled.as_text()
+    lines = text.splitlines()
     kernels = [line for line in lines if 'custom_call_target="tpu_custom_call"' in line]
     assert len([k for k in kernels if "attn_core/global/decode_attention" in k]) == 1
+    # the twelve new rows of a pass and layer: one call of the write kernel for
+    # keys and values, in the place of two scatters of 192 index rows
+    written, scattered = _kv_writes(text)
+    assert (len(written), scattered) == (1, [])
     for scope in ("attn_qkv", "kv_write", "attn_out", "ffn", "norm/loop_exit", "lm_head",
                   "sampling"):
         assert any(scope in line for line in lines), scope
@@ -88,9 +95,14 @@ def test_a_final_chunk_that_carries_the_step_compiles_and_copies_no_cache(
     # weights 5.34 GB, the pool's stripes 7.25 GB, a scratch stripe 0.60 GB
     assert 13.1e9 < memory.argument_size_in_bytes < 13.3e9
     assert memory.temp_size_in_bytes < 0.5e9
-    lines = compiled.as_text().splitlines()
+    text = compiled.as_text()
+    lines = text.splitlines()
     kernels = [line for line in lines if 'custom_call_target="tpu_custom_call"' in line]
     assert len([k for k in kernels if "beside/attn_core/global/decode_attention" in k]) == 1
+    # the carried rows write through the kernel; the chunk's own 256 tokens as a block
+    written, scattered = _kv_writes(text, beside=True)
+    assert (len(written), scattered) == (1, [])
+    assert _kv_writes(text) == ([], [])
     assert any("norm/loop_exit" in line for line in lines)
     assert _whole_copies(lines, CACHE, "bf16[192,1,16,384,128]") == []
     out = jax.eval_shape(fns["chunk_final"], *args)

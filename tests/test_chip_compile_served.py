@@ -8,6 +8,7 @@ import pytest
 
 from tests.chip_compile import (
     _SERVED,
+    _kv_writes,
     _ops_outside_fusions,
     _program_text,
     _served_config,
@@ -95,7 +96,14 @@ def test_decode_step_reads_the_cache_where_it_lies(
     # are the leading one and one period
     assert len(kernels) == (cfg.n_layers if cfg.layer_types else 1), kernels
     assert _yields_a_layer_of_the_cache(text, slots, stripe) == []
+    # and as many calls of the write kernel (keys and values together) in the
+    # place of two scatters a traced layer
+    written, scattered = _kv_writes(text)
+    assert (len(written), scattered) == (len(kernels), [])
 
     monkeypatch.setattr(patterned, "reads_blocks", lambda *a: False)
+    monkeypatch.setattr(patterned, "writes_rows", lambda *a, **kw: False)
     einsum = _program_text(_served_programs(cfg, slots, stripe, one_chip)["decode_step"])
     assert _yields_a_layer_of_the_cache(einsum, slots, stripe)  # the guard sees the slices
+    written, scattered = _kv_writes(einsum)  # and the scatters
+    assert (written, len(scattered)) == ([], 2 * len(kernels))

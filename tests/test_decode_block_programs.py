@@ -1,18 +1,21 @@
-"""The decode kernel's block follows from what a position of the cache holds
-(``ops/decode_attention.py block_size``), so a change of the rule moves the
-programs of the cells whose rows it sizes anew and no other's. Here the
-engine's decode program and its carrying final chunk (``llm/engine.py
+"""The engine's decode program and its carrying final chunk (``llm/engine.py
 programs``) at each serving cell's published shape, lowered on the CPU (the
-kernel interpreted: its buffers and loops are in the text) and read without
-locations: the cells at eight key-value heads and the latent ones lower to
-the parent's text, digest for digest (Granite's 64-wide heads never reach the
-kernel on the chip: ``tests/test_granite.py``; its 40 layers lower in a minute,
-so it is not here); the two at two key-value heads (ZAYA1-8B, Nemotron-3-Super) walk
-512-position blocks where the parent walked 128. Compilation of the moved
-programs for a described v5e: ``tests/test_chip_compile_zaya.py``. Since PR 56
-the block cell's carrying chunk programs (SDAR-30B-A3B-Chat: the pool's block
-step rides through them) are pinned here too, at their own text."""
+kernels interpreted: their buffers and loops are in the text) and read without
+locations, so that a change shows in the cells it moves and in no other.
+PR 54: the decode kernel's block follows from what a position of the cache
+holds (``ops/decode_attention.py block_size``); the two cells at two key-value
+heads (ZAYA1-8B, Nemotron-3-Super) walk 512-position blocks where that PR's
+parent walked 128 (compilation of those for a described v5e:
+``tests/test_chip_compile_zaya.py``). PR 56: the block cell's carrying chunk
+programs (SDAR-30B-A3B-Chat: the pool's block step rides through them) are
+pinned at their own text. PR 58: a decode step's new keys and values go through
+``ops/cache_write.py``'s kernel in the cells whose cache is stripes of 128-wide
+heads (pinned at their own text), and the latent cells' and the block cell's
+programs are the parent's, digest for digest (Granite's 64-wide heads never
+reach a kernel on the chip: ``tests/test_granite.py``; its 40 layers lower in a
+minute, so it is not here)."""
 
+import functools
 import hashlib
 
 import jax
@@ -60,8 +63,24 @@ _PARENT = {
     ("zaya1-8b-serve-long-chat", "chunk_final"): "3a15e4d74eb12c90",
 }
 MOVED = ("nemotron3-super-serve-chat", "zaya1-8b-serve-long-chat")
+# since PR 58 a decode step's rows of a token each write their new keys and
+# values through ``ops/cache_write.py``'s kernel (interpreted here: its loops
+# and its buffers of a tile a slot are in the text) wherever the cache is
+# stripes of 128-wide heads: those cells' programs at this checkout's own text.
+# The latent cells' stay the parent's (``_PARENT``), the block cell's too
+# (``_BLOCKS``: its rows are a block wide)
+_WRITTEN = {
+    ("mistral7b-serve-saturated", "decode_fn"): "666be791594f5522",
+    ("laguna-xs2-serve-mixed", "decode_fn"): "b3020969e9ccf43a",
+    ("solar-open2-serve-long-chat", "decode_fn"): "cf9f1c059ed04fef",
+    ("nemotron3-super-serve-chat", "decode_fn"): "bf32985579735571",
+    ("zaya1-8b-serve-long-chat", "decode_fn"): "dcc40671d92523dd",
+    ("mistral7b-serve-saturated", "chunk_final"): "6524e780ab0cb542",
+    ("laguna-xs2-serve-mixed", "chunk_final"): "29fb189a2e4fbad0",
+    ("zaya1-8b-serve-long-chat", "chunk_final"): "48eff01acf7f50bf",
+}
 # the block pool's chunk programs carry its block step since PR 56 (the rows a
-# block wide: ``llm/engine.py programs``): this checkout's own text, pinned so
+# block wide: ``llm/engine.py programs``): that checkout's own text, pinned so
 # that a later change to what they lower to shows here
 _BLOCKS = {
     ("sdar-30b-a3b-serve-block-chat", "chunk_final"): "48a4e5837c05de7b",
@@ -69,6 +88,7 @@ _BLOCKS = {
 }
 
 
+@functools.lru_cache(maxsize=None)
 def _lowered(cell, program):
     """The engine's ``program`` at ``cell``'s shape: every slot's decode step
     with its sampler, or a 128-token chunk of one prompt (the final one, or
@@ -101,9 +121,21 @@ def _digest(text):
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
-@pytest.mark.parametrize("cell, program", [key for key in _PARENT if key[0] not in MOVED])
-def test_a_cell_whose_rows_the_rule_sizes_as_before_lowers_to_the_parents_text(cell, program):
+@pytest.mark.parametrize("cell, program", [key for key in _PARENT if key not in _WRITTEN])
+def test_a_latent_cell_lowers_to_the_parents_text(cell, program):
     assert _digest(_lowered(cell, program)) == _PARENT[cell, program]
+
+
+@pytest.mark.parametrize("cell, program", list(_WRITTEN))
+def test_a_cell_whose_steps_write_through_the_kernel_is_pinned_at_its_own_text(cell, program):
+    """The write kernel's buffer of keys (a tile of 16 positions of every
+    key-value head a slot) is in the interpreted text, and the text is no
+    longer the one ``_PARENT`` holds."""
+    _, slots, _ = CELLS[cell]
+    heads = 2 if cell in MOVED else 8
+    text = _lowered(cell, program)
+    assert _digest(text) == _WRITTEN[cell, program] != _PARENT[cell, program]
+    assert f"tensor<{slots}x{heads}x16x128xbf16>" in text
 
 
 @pytest.mark.parametrize("cell, program", [key for key in _PARENT if key[0] in MOVED])
@@ -128,5 +160,5 @@ def test_the_block_cells_carrying_chunks_hold_one_folded_kernel_for_the_rows(cel
 
 
 if __name__ == "__main__":  # ``python3 -m tests.test_decode_block_programs``: this checkout's digests
-    for key in (*_PARENT, *_BLOCKS):
+    for key in (*_PARENT, *_BLOCKS):  # (``_WRITTEN``'s keys are among ``_PARENT``'s)
         print(f'    {key}: "{_digest(_lowered(*key))}",', flush=True)

@@ -199,6 +199,7 @@ def test_engine_serves_a_hit_as_it_served_the_miss_and_counts_both(engine):
     assert engine._pools[0].reads_blocks
     assert delta("decode_kv_positions_read_latent") == 256 * delta("decode_slot_steps")
     (pool,) = stats["pools"]
+    assert pool["decode_write"] == "scatter"  # a latent pool's one row a slot
     # 3 layers of 128 (the rotated key's lane row) + 32 (latent) float32 numbers
     assert pool["kv_bytes_per_token"] == 3 * (128 + 32) * 4
     assert "kv_bytes_per_token_held" in pool and stats["prefix_cache_bytes"] > 0

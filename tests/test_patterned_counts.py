@@ -132,6 +132,9 @@ def test_an_engine_counts_the_form_its_decode_steps_take(monkeypatch, placed, he
         eng._decode_jit.lower(eng.params, pool.cache, pool.dev_tokens, *pool.sampler(), pool.keys)
         assert pool.reads_blocks == bool(traced) == (placed != "tp2")
         assert pool.decode_block == block == eng.get_stats()["pools"][0]["decode_block"]
+        # (heads narrower than a lane tile: ``patterned.writes_rows`` keeps the scatter;
+        # a pool of 128-wide heads: ``tests/test_cache_write.py``)
+        assert eng.get_stats()["pools"][0]["decode_write"] == "scatter"
         per_slot_step = block or stripe  # 28 positions at most: one block
         assert c["decode_kv_positions_read"] == c["decode_slot_steps"] * per_slot_step > 0
         assert c["decode_kv_positions_read_window"] == 0  # no window layers in this model
